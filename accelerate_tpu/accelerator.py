@@ -375,9 +375,9 @@ class TrainEngine:
 
     def _cast_params(self, params):
         if self.sharding_config.offload_params_to_host:
-            from .parallel.sharding import device_memory_space, transfer_tree
+            from .parallel.sharding import transfer_tree
 
-            params = transfer_tree(params, device_memory_space())
+            params = transfer_tree(params, jax.memory.Space.Device)
         c = self.precision.compute_dtype
         return jax.tree_util.tree_map(
             lambda p: p.astype(c) if jnp.issubdtype(p.dtype, jnp.floating) else p, params
@@ -581,7 +581,6 @@ class TrainEngine:
 
     def attach_optimizer(self, optimizer: optax.GradientTransformation, schedule=None):
         from .parallel.sharding import (
-            device_memory_space,
             infer_opt_state_sharding,
             transfer_tree,
             tree_with_memory_kind,
@@ -600,7 +599,7 @@ class TrainEngine:
         self.opt_state_sharding = infer_opt_state_sharding(
             optimizer, self.params, base_param_sharding, self.mesh
         )
-        device_space = device_memory_space()
+        device_space = jax.memory.Space.Device
         init = self._get_jit(
             "opt_init",
             lambda p: optimizer.init(transfer_tree(p, device_space)),
@@ -640,14 +639,14 @@ class TrainEngine:
     def _update_fn(self, params, opt_state, grads, scale_state, finite, max_norm):
         """One optimizer update: clip -> optax -> apply; fp16 skip via cond.
         Host-offloaded state streams HBM-ward here and back at the end."""
-        from .parallel.sharding import device_memory_space, transfer_tree
+        from .parallel.sharding import transfer_tree
 
         offload_opt = self.sharding_config.offload_optimizer_state
         offload_p = self.sharding_config.offload_params_to_host
         if offload_opt:
-            opt_state = transfer_tree(opt_state, device_memory_space())
+            opt_state = transfer_tree(opt_state, jax.memory.Space.Device)
         if offload_p:
-            params = transfer_tree(params, device_memory_space())
+            params = transfer_tree(params, jax.memory.Space.Device)
         if max_norm is not None:
             gnorm = optax.global_norm(grads)
             clip_scale = jnp.minimum(1.0, max_norm / (gnorm + 1e-6))
@@ -873,8 +872,8 @@ class TrainEngine:
         MaxText-style train loop. The returned runner then takes a batch
         whose leaves carry a leading [K, ...] axis (K stacked per-step
         batches) and returns the LAST step's metrics plus ``loss_mean`` over
-        the K steps. This amortizes per-dispatch host latency, which
-        dominates for sub-50ms steps on remote-attached runtimes."""
+        the K steps. This amortizes per-dispatch host latency, which is
+        a visible share of sub-50ms steps."""
         micro = micro_steps or self.gradient_state.num_steps
         if (
             (
@@ -1126,7 +1125,7 @@ class TrainEngine:
         like the GSPMD path."""
         from jax.sharding import PartitionSpec as P
 
-        from .parallel.sharding import shard_map_compat as shard_map
+        from jax import shard_map
         from .utils.serialization import flatten_pytree, unflatten_to_like
 
         mesh = self.mesh
@@ -1687,7 +1686,9 @@ class Accelerator:
                 self.profile_handler = handler
 
         self.compile_plugin = compile_plugin or CompilePlugin()
-        self.compile_plugin.apply_cache()
+        from .utils.compile_cache import ensure_persistent_compile_cache
+
+        ensure_persistent_compile_cache()
 
         self.state = AcceleratorState(
             mixed_precision=mixed_precision,

@@ -10,8 +10,7 @@ hand (and PR 11 did, again). This module is the single source of truth:
 - ``JAX_FREE_MODULES`` — modules that must import with no jax/flax/optax
   anywhere in their *static* import closure;
 - ``PALLAS_FREE_MODULES`` — modules that may pull jax but must defer
-  pallas to first trace (pallas costs ~0.2 s at import and CPU-only
-  jaxlib builds may lack the TPU backend).
+  pallas to first trace (pallas costs ~0.2 s at import).
 
 ``tests/test_imports.py`` derives its subprocess probes from these
 tuples, and ``accelerate-tpu audit`` additionally *statically* walks the

@@ -95,7 +95,7 @@ def _closed_jaxprs(closed):
     """The top-level ClosedJaxpr plus every nested one (pjit bodies, scan
     carries, cond branches, custom-derivative calls), depth-first in
     deterministic order."""
-    from jax import core
+    from jax.extend import core
 
     out = []
 
@@ -339,7 +339,7 @@ def _scalar_literals(closed) -> list:
     """Ordered (eqn_index, prim, position, value) scalar int/float
     Literal operands across all nested jaxprs — the values a python
     computation baked into the trace."""
-    from jax import core
+    from jax.extend import core
 
     out = []
     for i, eqn in enumerate(_all_eqns(closed)):

@@ -397,17 +397,11 @@ class DispatchedModel:
         shardings = self._target_shardings()
         stream = self._STREAM
 
-        from .parallel.sharding import device_memory_space
-
-        device_space = device_memory_space()
-
         def _place(leaf, sh):
             if isinstance(sh, str):
                 if sh == stream:
                     return leaf
-                if device_space is None:
-                    return jax.device_put(leaf, jax.local_devices()[0])
-                return jax.device_put(leaf, device_space)
+                return jax.device_put(leaf, jax.memory.Space.Device)
             return jax.device_put(leaf, sh)
 
         def placer(p):

@@ -308,11 +308,10 @@ def generate(
     last, cache = prefill(params, input_ids, prefill_rng)
     if return_prefill_seconds:
         # Force completion by device_get of a tiny scalar reduction rather
-        # than block_until_ready (which does not actually block through
-        # remote-attached runtimes) or device_get(last) (which would fail on
-        # multi-host meshes where `last` spans non-addressable devices, and
-        # on one host would re-commit `last` to the default device, dropping
-        # its sharding and retracing the decode loop). The scalar jit output
+        # than device_get(last) (which would fail on multi-host meshes
+        # where `last` spans non-addressable devices, and on one host would
+        # re-commit `last` to the default device, dropping its sharding and
+        # retracing the decode loop). The scalar jit output
         # is fully replicated, so every host can fetch it; `last` itself is
         # left untouched for the decode loop.
         jax.device_get(_sync_probe(last))

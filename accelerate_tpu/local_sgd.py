@@ -32,7 +32,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from .parallel.sharding import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 _DATA_AXES = ("replica", "data", "fsdp")

@@ -112,12 +112,9 @@ def _stream_params_to_device(tree):
     this runs on the per-layer *slice*, so only the live layer's weights
     occupy HBM (the per-layer-streaming capability of reference
     hooks.py:323-390); on already-device-resident params it is a no-op."""
-    from ..parallel.sharding import device_memory_space
-
-    space = device_memory_space()
-    if space is None:  # jax without memory spaces: nothing can be host-pinned
-        return tree
-    return jax.tree_util.tree_map(lambda x: jax.device_put(x, space), tree)
+    return jax.tree_util.tree_map(
+        lambda x: jax.device_put(x, jax.memory.Space.Device), tree
+    )
 
 
 def _maybe_streaming(body, cfg):
@@ -489,8 +486,11 @@ class DecoderAttention(nn.Module):
 
             out = ring_attention_sharded(q, k, v, self.mesh, causal=True)
         else:
-            out = dot_product_attention(
-                q, k, v, causal=self.causal, kv_mask=kv_mask, impl=cfg.attention_impl
+            from ..parallel.context import dot_product_attention_sharded
+
+            out = dot_product_attention_sharded(
+                q, k, v, self.mesh, causal=self.causal, kv_mask=kv_mask,
+                impl=cfg.attention_impl,
             )
         out = _constrain(out, ("batch", "heads", "seq", "head_dim"), self.mesh)
         if getattr(cfg, "use_fp8", False):

@@ -69,30 +69,7 @@ class _LazyModule:
 
 
 pl = _LazyModule("jax.experimental.pallas")
-_pltpu_lazy = _LazyModule("jax.experimental.pallas.tpu")
-
-
-class _PltpuProxy:
-    """pallas TPU backend is absent on some CPU-only jaxlib builds; probe
-    lazily. Truthiness mirrors availability so `if pltpu:` keeps the old
-    None semantics."""
-
-    def __getattr__(self, attr):
-        return getattr(_pltpu_lazy._resolve(), attr)
-
-    def __bool__(self):
-        return _has_pltpu()
-
-
-pltpu = _PltpuProxy()
-
-
-def _has_pltpu() -> bool:
-    try:
-        _pltpu_lazy._resolve()
-        return True
-    except Exception:  # pragma: no cover
-        return False
+pltpu = _LazyModule("jax.experimental.pallas.tpu")
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() semantics with no NaN risk
 
@@ -361,21 +338,13 @@ def _pick_block(s: int, preferred: int) -> int:
     return 0  # no valid block → caller falls back to XLA
 
 
-def _compiler_params(dimension_semantics):
-    """Mosaic compiler params across jax versions: 0.4.x spells the class
-    ``TPUCompilerParams``; newer builds renamed it ``CompilerParams``."""
-    mod = _pltpu_lazy._resolve()
-    cls = getattr(mod, "CompilerParams", None) or getattr(mod, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
-
-
 def _grid_params(
     interpret: bool,
     semantics=("parallel", "parallel", "parallel", "arbitrary"),
 ):
     kw = {"interpret": interpret}
-    if not interpret and _has_pltpu():
-        kw["compiler_params"] = _compiler_params(semantics)
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(dimension_semantics=semantics)
     return kw
 
 
@@ -515,8 +484,6 @@ def _flash_bwd_call(q, k, v, out, lse, do, masks, causal, sm_scale, bq, bk, inte
 
 
 def _vmem(shape):
-    if not _has_pltpu():  # pragma: no cover
-        raise RuntimeError("pallas TPU memory spaces unavailable in this jaxlib build")
     return pltpu.VMEM(shape, jnp.float32)
 
 
@@ -735,9 +702,6 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
         return False, False
     if blk <= 0:
         _warn_decode_fallback("no valid kv block size for this cache length")
-        return False, False
-    if not _has_pltpu():
-        _warn_decode_fallback("pallas TPU support missing from this jaxlib")
         return False, False
     if mode == "interpret":
         return True, True
@@ -1138,7 +1102,7 @@ def paged_decode_attention(
     per step is the slot's live tokens (page-rounded), not its whole
     ``P * page_size`` reservation, which is the decode-bandwidth lever at
     high occupancy with mixed lengths. Otherwise (``impl='dense'`` /
-    ``ATT_DECODE_KERNEL=dense`` / pallas TPU absent — warn-once) the
+    ``ATT_DECODE_KERNEL=dense`` / no TPU backend — warn-once) the
     gather maps each slot's pages back into position order and the read is
     exactly :func:`decode_attention`'s masked-dense path: the CPU-sim
     fallback and the bit-exactness reference the kernel is asserted
@@ -1261,9 +1225,6 @@ def _prefill_kernel_gate(mode: str, d: int, ps: int, bt: int,
     if ps <= 0 or bt <= 0:
         _warn_prefill_fallback("no valid page/token block size")
         return False, False
-    if not _has_pltpu():
-        _warn_prefill_fallback("pallas TPU support missing from this jaxlib")
-        return False, False
     if mode == "interpret":
         return True, True
     if jax.default_backend() != "tpu":
@@ -1313,26 +1274,17 @@ def prefill_kernel_active(config) -> bool:
 
 
 def _quantize_block(x, bits):
-    """In-register quantize-on-write on one [rows, D] block: the EXACT
-    ``utils.quantization.quantize_kv`` op sequence (symmetric per-row
-    scale over D; int4 packs value pairs low-nibble-first). Returns
-    (payload int8 [rows, D or D/2], scale fp32 [rows, 1], deq fp32
-    [rows, D] — exactly what ``dequantize_kv`` hands a reader, so the
-    tail attends the same values the cache serves later)."""
-    qmax = (1 << (bits - 1)) - 1
-    x32 = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0)
-    qf = jnp.clip(jnp.round(x32 / scale), -qmax, qmax)
-    q = qf.astype(jnp.int8)
-    deq = qf * scale
-    if bits == 4:
-        r, dd = q.shape
-        pairs = q.reshape(r, dd // 2, 2)
-        payload = (pairs[:, :, 0] & 0x0F) | ((pairs[:, :, 1] & 0x0F) << 4)
-    else:
-        payload = q
-    return payload, scale, deq
+    """In-register quantize-on-write on one [rows, D] block through the
+    SAME functions the jitted cache writes call
+    (``utils.quantization.quantize_kv_values`` / ``kv_payload``), so
+    the bytes are identical by construction. Returns (payload int8
+    [rows, D or D/2], scale fp32 [rows, 1], deq fp32 [rows, D] — exactly
+    what ``dequantize_kv`` hands a reader, so the tail attends the same
+    values the cache serves later)."""
+    from ..utils.quantization import kv_payload, quantize_kv_values
+
+    qf, scale = quantize_kv_values(x, bits)
+    return kv_payload(qf, bits), scale, qf * scale
 
 
 def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
@@ -1363,11 +1315,10 @@ def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
     slot = bslot_ref[i]
     hist = bhist_ref[i]
     n_hist_blocks = (hist + ps - 1) // ps
-    # per-row (token, head-group) query positions: row r is token r//group
-    qpos = qpos_ref[0, 0]  # [bt]
-    rowpos = jnp.broadcast_to(
-        qpos.reshape(bt, 1), (bt, group)
-    ).reshape(bt * group, 1)
+    # per-row (token, head-group) query positions, expanded per folded
+    # row by the caller: a (bt, group) -> (bt*group, 1) reshape in here
+    # is a shape cast Mosaic cannot lay out
+    rowpos = qpos_ref[0]  # [bt*group, 1]
 
     # fresh K/V of the block this cell's fresh window points at (clamped
     # to block 0 during the arena phase): quantize-on-write runs every
@@ -1487,6 +1438,8 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         blk_slot >= 0, slot_hist[jnp.maximum(blk_slot, 0)], 0
     ).astype(jnp.int32)
     pos_in = row_pos.reshape(ntb, 1, bt).astype(jnp.int32)
+    # row r of a folded q block is token r // group
+    pos_rows = jnp.repeat(row_pos.astype(jnp.int32), group).reshape(ntb, g, 1)
 
     entry = _prefill_quant_kernel_entry if quant_bits else _prefill_kernel_body
     kernel = functools.partial(
@@ -1528,11 +1481,11 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
     in_specs += [
         _fresh_spec(d),
         _fresh_spec(d),
-        pl.BlockSpec((1, 1, bt), lambda i, h_, j, bs, bh, tb: (i, 0, 0)),
+        pl.BlockSpec((1, g, 1), lambda i, h_, j, bs, bh, tb: (i, 0, 0)),
         pl.BlockSpec((1, 1, bt),
                      lambda i, h_, j, bs, bh, tb: (jnp.clip(j - npb, 0, ntb - 1), 0, 0)),
     ]
-    operands += [kn_r, vn_r, pos_in, pos_in]
+    operands += [kn_r, vn_r, pos_rows, pos_in]
 
     out_specs = [
         pl.BlockSpec((1, 1, g, d), lambda i, h_, j, bs, bh, tb: (h_, i, 0, 0)),
@@ -1714,6 +1667,21 @@ def ragged_prefill_attention(
     )
 
 
+def flash_kernel_engaged(q, k, *, impl: str = "auto", bias=None, interpret: bool = False) -> bool:
+    """Whether ``dot_product_attention`` takes the pallas kernel for these
+    operands. Depends on the sequence lengths and head_dim only, so it
+    answers the same per shard of the batch and head axes — a caller that
+    must wrap the kernel for its mesh (``parallel.context``) asks here."""
+    if impl == "xla" or bias is not None:
+        return False
+    if impl == "flash":
+        return True
+    blocks_ok = (
+        _pick_block(q.shape[2], 1024) and _pick_block(k.shape[2], 1024) and q.shape[-1] % 128 == 0
+    )
+    return bool((jax.default_backend() == "tpu" or interpret) and blocks_ok)
+
+
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -1734,7 +1702,11 @@ def dot_product_attention(
 
     Padding should arrive as ``kv_mask`` and packed sequences as
     ``segment_ids`` — both stay on the flash path. An arbitrary additive
-    ``bias`` falls back to XLA (the kernel implements masks, not biases)."""
+    ``bias`` falls back to XLA (the kernel implements masks, not biases).
+
+    Mesh-free: the XLA reference partitions like any other op, but the SPMD
+    partitioner cannot split the kernel, so a program partitioned over a
+    multi-device mesh calls ``parallel.context.dot_product_attention_sharded``."""
     if impl == "flash" and bias is not None:
         raise ValueError("flash impl does not support arbitrary bias; use kv_mask/segment_ids or impl='xla'")
 
@@ -1751,16 +1723,10 @@ def dot_product_attention(
             bias_parts.append(jnp.where(same, 0.0, NEG_INF))
         return sum(bias_parts)
 
-    if impl == "xla" or bias is not None:
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale, bias=_fold_masks_into_bias(bias))
-    on_tpu = jax.default_backend() == "tpu"
-    blocks_ok = (
-        _pick_block(q.shape[2], 1024) and _pick_block(k.shape[2], 1024) and q.shape[-1] % 128 == 0
-    )
-    if impl == "flash" or (impl == "auto" and (on_tpu or interpret) and blocks_ok):
+    if flash_kernel_engaged(q, k, impl=impl, bias=bias, interpret=interpret):
         return flash_attention(
             q, k, v, causal=causal, sm_scale=sm_scale,
             kv_mask=kv_mask, q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
-            interpret=interpret or not on_tpu,
+            interpret=interpret or jax.default_backend() != "tpu",
         )
     return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale, bias=_fold_masks_into_bias(bias))

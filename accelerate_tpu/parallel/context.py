@@ -25,9 +25,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from .sharding import shard_map_compat as shard_map
+from jax import shard_map
 
-from ..ops.attention import NEG_INF
+from ..ops.attention import NEG_INF, dot_product_attention, flash_kernel_engaged
 
 
 def _local_attn_with_lse(q, k, v, bias, sm_scale):
@@ -230,6 +230,86 @@ def _ring_flash_vjp_bwd(axis_name, axis_size, causal, sm_scale, interpret, res, 
 _ring_flash.defvjp(_ring_flash_vjp_fwd, _ring_flash_vjp_bwd)
 
 
+def attention_partition_specs(
+    mesh: Mesh, q, k, seq_axis: Optional[str] = None, manual: frozenset = frozenset()
+):
+    """(q_spec, kv_spec, row_spec) for attention over q [B, H, S, D] and
+    k/v [B, KVH, S, D] on ``mesh``: batch over the data axes that divide it,
+    heads over "tensor", the sequence over ``seq_axis`` (None = whole).
+    ``row_spec`` is for [B, S] companions (padding mask, segment ids).
+    Axes in ``manual`` are left out: an enclosing shard_map has already
+    split the operands over them."""
+    size = lambda a: 1 if a in manual else mesh.shape.get(a, 1)
+
+    def _batch_axes(dim: int) -> tuple:
+        kept, prod = [], 1
+        for a in ("replica", "data", "fsdp"):
+            sz = size(a)
+            if sz > 1 and dim % (prod * sz) == 0:
+                kept.append(a)
+                prod *= sz
+        return tuple(kept)
+
+    # Head sharding: q and kv must shard consistently or the GQA grouping
+    # silently changes. Shard both over "tensor" iff both divide; the MQA
+    # special case (kv_heads=1 replicated, q heads sharded) is also exact
+    # because every q head maps to the single kv head.
+    tp = size("tensor")
+    h, kvh = q.shape[1], k.shape[1]
+    if tp > 1 and h % tp == 0 and kvh % tp == 0:
+        q_head, kv_head = "tensor", "tensor"
+    elif tp > 1 and h % tp == 0 and kvh == 1:
+        q_head, kv_head = "tensor", None
+    else:
+        q_head, kv_head = None, None
+
+    qb = _batch_axes(q.shape[0]) or None
+    return (
+        P(qb, q_head, seq_axis, None),
+        P(qb, kv_head, seq_axis, None),
+        P(qb, seq_axis),
+    )
+
+
+_ROW_OPERANDS = ("kv_mask", "q_segment_ids", "kv_segment_ids")
+
+
+def dot_product_attention_sharded(q, k, v, mesh: Optional[Mesh], **kwargs):
+    """``dot_product_attention`` for a program partitioned over ``mesh``.
+    The XLA reference partitions like any other op and is called as is. The
+    kernel runs per shard of the batch and head axes: attention is
+    independent per (batch row, head), so this is exact — and it is what a
+    Mosaic kernel needs, the SPMD partitioner cannot split a
+    ``tpu_custom_call`` ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map").
+
+    Inside an enclosing shard_map (the compressed-replica train step,
+    LocalSGD) only the axes that are still automatic are mapped, over the
+    context mesh; where every axis is manual already the kernel is called
+    bare."""
+    context_mesh = jax.sharding.get_abstract_mesh()
+    manual = frozenset(context_mesh.manual_axes)
+    auto = frozenset(mesh.axis_names) - manual if mesh is not None and mesh.size > 1 else ()
+    if not auto or not flash_kernel_engaged(
+        q, k, impl=kwargs.get("impl", "auto"), bias=kwargs.get("bias"),
+        interpret=kwargs.get("interpret", False),
+    ):
+        return dot_product_attention(q, k, v, **kwargs)
+    # the [B, S] companions are split with the batch; the rest is static
+    rows = tuple(kwargs.pop(name, None) for name in _ROW_OPERANDS)
+    q_spec, kv_spec, row_spec = attention_partition_specs(mesh, q, k, manual=manual)
+    return shard_map(
+        lambda q, k, v, *rows: dot_product_attention(
+            q, k, v, **dict(zip(_ROW_OPERANDS, rows)), **kwargs
+        ),
+        mesh=context_mesh if manual else mesh,
+        in_specs=(q_spec, kv_spec, kv_spec, *(None if r is None else row_spec for r in rows)),
+        out_specs=q_spec,
+        axis_names=auto,
+        check_vma=False,
+    )(q, k, v, *rows)
+
+
 def ring_attention_sharded(
     q: jax.Array,
     k: jax.Array,
@@ -248,35 +328,9 @@ def ring_attention_sharded(
     n = mesh.shape.get(seq_axis, 1)
     if n == 1 or q.shape[2] % n or k.shape[2] % n:
         # trivial axis, or sequence not divisible by the ring: dense fallback
-        from ..ops.attention import dot_product_attention
-
         return dot_product_attention(q, k, v, causal=causal, sm_scale=sm_scale)
 
-    def _batch_axes(dim: int) -> tuple:
-        kept, prod = [], 1
-        for a in ("replica", "data", "fsdp"):
-            sz = mesh.shape.get(a, 1)
-            if sz > 1 and dim % (prod * sz) == 0:
-                kept.append(a)
-                prod *= sz
-        return tuple(kept)
-
-    # Head sharding: q and kv must shard consistently or the GQA grouping
-    # silently changes. Shard both over "tensor" iff both divide; the MQA
-    # special case (kv_heads=1 replicated, q heads sharded) is also exact
-    # because every q head maps to the single kv head.
-    tp = mesh.shape.get("tensor", 1)
-    h, kvh = q.shape[1], k.shape[1]
-    if tp > 1 and h % tp == 0 and kvh % tp == 0:
-        q_head, kv_head = "tensor", "tensor"
-    elif tp > 1 and h % tp == 0 and kvh == 1:
-        q_head, kv_head = "tensor", None
-    else:
-        q_head, kv_head = None, None
-
-    qb = _batch_axes(q.shape[0])
-    q_spec = P(qb if qb else None, q_head, seq_axis, None)
-    kv_spec = P(qb if qb else None, kv_head, seq_axis, None)
+    q_spec, kv_spec, _ = attention_partition_specs(mesh, q, k, seq_axis=seq_axis)
     fn = shard_map(
         partial(
             ring_attention,

@@ -173,43 +173,11 @@ def tree_with_memory_kind(shardings, kind: str):
     return jax.tree_util.tree_map(lambda s: with_memory_kind(s, kind), shardings)
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=False):
-    """``jax.shard_map`` on jax builds where it has been promoted; the
-    ``jax.experimental.shard_map`` spelling otherwise. The old API has no
-    ``axis_names`` (it always binds every mesh axis — equivalent for our
-    call sites, which pass all of them) and calls ``check_vma``
-    ``check_rep``."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=bool(check_vma))
-    kwargs = {"check_vma": check_vma}
-    if axis_names is not None:
-        kwargs["axis_names"] = axis_names
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def device_memory_space():
-    """``jax.memory.Space.Device`` on jax builds that expose memory spaces
-    (the host-offload plumbing needs it), else None — callers treat None as
-    "no explicit space": transfers become no-ops, which is correct because
-    offload configs can't produce host-resident arrays on such builds."""
-    mem = getattr(jax, "memory", None)
-    return getattr(getattr(mem, "Space", None), "Device", None)
-
-
 def transfer_tree(tree, space):
     """In-graph transfer of array leaves to a jax.memory.Space (call inside
     jit; XLA's latency-hiding scheduler places the copies). Scalars stay put
     — the SPMD partitioner rejects placement annotations on rank-0 buffers,
-    and offloading a scalar saves nothing. ``space=None`` (jax without
-    memory spaces — see device_memory_space) passes the tree through."""
-    if space is None:
-        return tree
+    and offloading a scalar saves nothing."""
     return jax.tree_util.tree_map(
         lambda x: jax.device_put(x, space) if getattr(x, "ndim", 0) >= 1 else x, tree
     )
@@ -256,24 +224,7 @@ def constrain_activation(x, logical_names: tuple, mesh: Optional[Mesh], rules=No
     # under a shard_map (e.g. the compressed-replica train step or LocalSGD),
     # manual axes must not appear in sharding constraints — the body already
     # IS per-shard on those axes
-    try:
-        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-    except Exception:
-        # pre-abstract-mesh jax: shard_map binds its axes as mapped axis
-        # frames, so probe the axis env instead (axis_frame raises on
-        # unbound names)
-        import jax.core as _core
-
-        probe = getattr(jax.lax, "axis_size", None) or _core.axis_frame
-
-        def _bound(name):
-            try:
-                probe(name)
-                return True
-            except Exception:
-                return False
-
-        manual = {a for a in mesh.axis_names if _bound(a)}
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     parts = []
     for i, dim in enumerate(x.shape):
         entry = spec[i] if i < len(spec) else None

@@ -64,7 +64,7 @@ from .pages import (
     set_table_entry,
     set_table_row,
 )
-from .tiers import TierConfig, TieredStore, TierEntry, entry_nbytes
+from .tiers import KV_WIRE_VERSION, TierConfig, TieredStore, TierEntry, entry_nbytes
 from .scheduler import (
     SHED_DRAINING,
     SHED_PAGE_EXHAUSTED,
@@ -274,9 +274,9 @@ class ServingEngine:
         self.top_k = top_k
         self.eos_token_id = eos_token_id
         # fuse up to K decode steps into one dispatch (a lax.scan of the
-        # SAME step body — bit-identical tokens): through a remote-attached
-        # runtime the per-dispatch host round trip otherwise dominates
-        # ms/token, the same reason build_train_step grew steps_per_call.
+        # SAME step body — bit-identical tokens): the per-dispatch host
+        # round trip is otherwise part of every token's latency, the same
+        # reason build_train_step grew steps_per_call.
         # Bursts only run when they cannot delay an admission or overshoot
         # a request's budget, so scheduling behavior is unchanged.
         self.steps_per_call = max(1, int(steps_per_call))
@@ -2021,7 +2021,7 @@ class ServingEngine:
             })
         self.kv_pages_exported += n_pages
         return {
-            "version": 1,
+            "version": KV_WIRE_VERSION,
             "page_size": self.page_size,
             "kv_cache_dtype": self.kv_cache_dtype,
             "token_len": int(hit_len),
@@ -2038,8 +2038,11 @@ class ServingEngine:
         raises ValueError on any mismatch. Shared by the import
         endpoint and the peer-tier restore path — one validator, so a
         peer pull can never install what an import would reject."""
-        if handoff.get("version") != 1:
-            raise ValueError(f"unknown KV handoff version {handoff.get('version')!r}")
+        if handoff.get("version") != KV_WIRE_VERSION:
+            raise ValueError(
+                f"KV handoff version {handoff.get('version')!r} != {KV_WIRE_VERSION} "
+                "(the stored bytes differ between versions)"
+            )
         if int(handoff["page_size"]) != self.page_size:
             raise ValueError(
                 f"KV handoff page_size {handoff['page_size']} != engine "

@@ -45,6 +45,13 @@ import numpy as np
 
 from .pages import _digest
 
+# Version of the KV wire format: the handoff dict of ``export_prefix_kv`` /
+# ``entry_to_handoff`` and every disk blob. It covers the BYTES of the leaves,
+# not only the schema. 2: int4 payloads pack split halves (byte j = dims j and
+# j + D/2), where 1 interleaved even/odd dims — same dtype, shape and checksum,
+# different values, so a v1 blob or peer must be refused, never installed.
+KV_WIRE_VERSION = 2
+
 # tier names, probe order (hbm is the PrefixCache itself; this module
 # owns the three below it)
 TIERS = ("hbm", "host", "disk", "peer")
@@ -164,7 +171,7 @@ def entry_to_handoff(entry: TierEntry, *, page_size: int, kv_cache_dtype: str,
             ).decode("ascii"),
         })
     return {
-        "version": 1,
+        "version": KV_WIRE_VERSION,
         "page_size": int(page_size),
         "kv_cache_dtype": kv_cache_dtype,
         "token_len": int(length),
@@ -339,17 +346,18 @@ class TieredStore:
         self.disk = _LruIndex(
             config.disk_entries if config.disk_dir else 0, config.disk_bytes
         )
-        if config.disk_dir:
-            os.makedirs(config.disk_dir, exist_ok=True)
-            self._scan_disk()
         # peer directory cache: name -> (fetched_at, {digest_hex: token_len})
         self._peer_dirs: dict = {}
-        # counters (engine merges these into serving/ metrics)
+        # counters (engine merges these into serving/ metrics); before the
+        # scan, which counts the blobs it drops
         self.demotions_host = 0
         self.demotions_disk = 0
         self.disk_corrupt_dropped = 0
         self.peer_pulls = 0
         self.peer_pull_failures = 0
+        if config.disk_dir:
+            os.makedirs(config.disk_dir, exist_ok=True)
+            self._scan_disk()
 
     # -- byte accounting ----------------------------------------------------
 
@@ -492,7 +500,7 @@ class TieredStore:
         except (OSError, ValueError):
             self._reject_blob(path)
             return None
-        if not isinstance(doc, dict) or doc.get("version") != 1 \
+        if not isinstance(doc, dict) or doc.get("version") != KV_WIRE_VERSION \
                 or int(doc.get("page_size") or 0) != self.page_size \
                 or (doc.get("kv_cache_dtype") or "bf16") != self.kv_cache_dtype \
                 or doc.get("checksum") != blob_checksum(doc):

@@ -64,10 +64,7 @@ def _maybe_init_jax_distributed():
         # Setting it only configures the CPU client, so it is safe to set
         # unconditionally — also covers hosts where CPU is the default
         # platform without JAX_PLATFORMS being set.
-        try:
-            jax.config.update("jax_cpu_collectives", "gloo")
-        except Exception:  # pragma: no cover - older jaxlib
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coord,
             num_processes=nproc,

@@ -729,7 +729,7 @@ class TelemetrySession:
         out["time_unix_s"] = round(time.time(), 3)
         if rec.get("tokens") and rec.get("wall_s"):
             out["tokens_per_s"] = rec["tokens"] / rec["wall_s"]
-        if rec.get("flops") and rec.get("wall_s"):
+        if rec.get("flops") and rec.get("wall_s") and self.peak_flops():
             out["mfu_pct"] = 100.0 * rec["flops"] / rec["wall_s"] / self.peak_flops()
         loss = self._resolve(rec.get("_loss"))
         if loss is not None:
@@ -739,30 +739,26 @@ class TelemetrySession:
             out["grad_norm"] = gn
         self._metrics_fh.write_line(json.dumps(out))
 
-    def peak_flops(self) -> float:
+    def peak_flops(self) -> Optional[float]:
+        """Peak bf16 FLOP/s of device 0; None for an unknown device kind
+        (no MFU is reported then)."""
         if self._peak is None:
+            import jax
+
             from .metrics import peak_flops
 
-            try:
-                import jax
-
-                self._peak = peak_flops(jax.devices()[0])
-            except Exception:
-                self._peak = 200e12
+            self._peak = peak_flops(jax.devices()[0])
         return self._peak
 
-    def peak_hbm_bw(self) -> float:
+    def peak_hbm_bw(self) -> Optional[float]:
         """Peak HBM bandwidth of device 0 (the roofline ridge's
-        denominator; conservative default when the probe fails)."""
+        denominator); None for an unknown device kind."""
         if self._peak_bw is None:
+            import jax
+
             from .costs import peak_hbm_bw
 
-            try:
-                import jax
-
-                self._peak_bw = peak_hbm_bw(jax.devices()[0])
-            except Exception:
-                self._peak_bw = 819e9
+            self._peak_bw = peak_hbm_bw(jax.devices()[0])
         return self._peak_bw
 
     def rollup(self) -> dict:

@@ -45,22 +45,19 @@ PEAK_HBM_BW = {
 }
 
 
-def peak_hbm_bw(device) -> float:
-    """Peak HBM bytes/s for a jax device (conservative default otherwise)."""
+def peak_hbm_bw(device) -> Optional[float]:
+    """Peak HBM bytes/s for a jax device; None for a ``device_kind`` not in
+    the table (the CPU included), so no roofline is drawn against a
+    made-up peak."""
     kind = getattr(device, "device_kind", "cpu").lower()
     for name, bw in sorted(PEAK_HBM_BW.items(), key=lambda kv: -len(kv[0])):
         if name.lower() in kind:
             return bw
-    return 819e9  # v5e-class default for unknown TPU; CPU runs report vs this
+    return None
 
 
 def _cost_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (list of
-    one dict on 0.4.x, plain dict on newer builds)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    return dict(compiled.cost_analysis() or {})
 
 
 class CostRegistry:

@@ -32,14 +32,16 @@ PEAK_FLOPS = {
 }
 
 
-def peak_flops(device) -> float:
-    """Peak bf16 FLOP/s for a jax device (conservative default otherwise)."""
+def peak_flops(device) -> Optional[float]:
+    """Peak bf16 FLOP/s for a jax device; None for a ``device_kind`` not in
+    the table (the CPU included) — MFU is then not reported rather than
+    reported against a made-up peak, the ``flops_per_token_fn`` idiom."""
     kind = getattr(device, "device_kind", "cpu").lower()
     # most-specific (longest) name first: "TPU v5 lite" must win over "TPU v5"
     for name, flops in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
         if name.lower() in kind:
             return flops
-    return 200e12  # conservative default for unknown TPU; CPU runs report vs this
+    return None
 
 
 def decoder_flops_per_token(num_params: int, num_layers: int, seq_len: int,

@@ -8,14 +8,19 @@ that a one-time cost per (program, topology): every later process — including
 restarts after preemption (SURVEY §5 failure recovery) — deserializes the
 executable instead of recompiling.
 
-``ensure_persistent_compile_cache()`` is called by the dispatch path
-(big_modeling), generation, and the Accelerator when a CompilePlugin enables
-it; set ``ATT_COMPILE_CACHE=0`` to disable or to a path to relocate.
+One directory, placed from outside: where ``JAX_COMPILATION_CACHE_DIR`` is
+set the cache lives there and nothing in this package sets another; where
+it is not, the cache is ``.xla_cache/`` at the root of the checkout — a
+fixed path (the directory is part of what a cache entry is found by), never
+a temporary name. ``ensure_persistent_compile_cache()`` is called by the
+Accelerator, the serving engine, generation and the dispatch path, whose
+jax.export artifacts live in ``exports/`` under the same directory.
+``JAX_ENABLE_COMPILATION_CACHE=0`` (jax's own switch) runs uncached.
 
 This module also owns the **compile-activity counters** the telemetry
 session reads per step: ``install_compile_listeners()`` subscribes (once)
 to ``jax.monitoring``'s event streams and tallies backend-compile events,
-their total seconds, and persistent-cache hits. A step whose record shows
+the seconds spent tracing, lowering and compiling, and persistent-cache hits. A step whose record shows
 ``compile_events > 0`` paid a trace/compile — the classic silent cause of
 a 100x step-time outlier — and ``compile_cache_hits`` says whether the
 persistent cache absorbed it.
@@ -26,132 +31,49 @@ from __future__ import annotations
 import os
 import threading
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "accelerate_tpu", "xla_cache"
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".xla_cache",
 )
-_enabled_dir: str | None = None
-_warned: set = set()
 
 
-def _warn_once(key: str, msg: str, *args):
-    """A disabled persistent cache means EVERY restart pays full
-    recompiles — a recurring silent regression. Name the cause once
-    instead of silently falling back."""
-    if key in _warned:
-        return
-    _warned.add(key)
-    import logging
+def ensure_persistent_compile_cache() -> str | None:
+    """Idempotently point jax's persistent compilation cache at the one
+    directory this process uses and return it (None when the user switched
+    the cache off through ``jax_enable_compilation_cache``).
 
-    logging.getLogger(__name__).warning(msg, *args)
-
-
-def _activate(cache_dir: str, set_thresholds: bool) -> str | None:
-    """Point jax at ``cache_dir``; warn-once (naming the resolved path)
-    and return None when the dir is unwritable or this jax build lacks
-    the compilation-cache config knobs."""
+    ``JAX_COMPILATION_CACHE_DIR`` wins — re-applied through ``jax.config``
+    because jax reads the variable only at import; else a directory the
+    user's own code already configured stays; else :data:`REPO_CACHE_DIR`,
+    caching everything that takes noticeable time. A directory that cannot
+    be created raises: carrying on uncached would make every restart
+    recompile with nothing to show for it."""
     import jax
 
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    current = jax.config.jax_compilation_cache_dir
+    cache_dir = env_dir or current or REPO_CACHE_DIR
     try:
         os.makedirs(cache_dir, exist_ok=True)
     except OSError as e:
-        if not os.path.isdir(cache_dir):
-            _warn_once(
-                f"unusable:{cache_dir}",
-                "persistent XLA compile cache DISABLED: cache dir %s is not "
-                "usable (%s) — every process restart will recompile from "
-                "scratch. Point ATT_COMPILE_CACHE (or "
-                "JAX_COMPILATION_CACHE_DIR) at a writable path.",
-                cache_dir, e,
-            )
-            return None
-    if not os.access(cache_dir, os.W_OK):
-        # a read-only but populated dir (pre-baked image cache) still
-        # serves cache HITS — activate it, but say why misses won't stick
-        _warn_once(
-            f"readonly:{cache_dir}",
-            "persistent XLA compile cache dir %s is not writable: cached "
-            "executables will still be read, but NEW compiles cannot be "
-            "saved there — cache misses will recompile on every restart. "
-            "Point ATT_COMPILE_CACHE (or JAX_COMPILATION_CACHE_DIR) at a "
-            "writable path to persist them.",
-            cache_dir,
-        )
-    try:
+        raise OSError(
+            f"persistent XLA compile cache dir {cache_dir} is not usable ({e}); "
+            "point JAX_COMPILATION_CACHE_DIR at a writable path, or set "
+            "JAX_ENABLE_COMPILATION_CACHE=0 to run uncached by choice"
+        ) from e
+    if cache_dir != current:
+        from jax.experimental.compilation_cache import compilation_cache
+
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        if set_thresholds:
-            # cache everything that takes noticeable time; entries are
-            # content-hashed
+        if not env_dir:
+            # our own directory, so our thresholds; entries are content-hashed
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except (AttributeError, KeyError, ValueError) as e:
-        _warn_once(
-            "no-config-knobs",
-            "persistent XLA compile cache DISABLED: this jax build (%s) "
-            "lacks the compilation-cache config knobs (%s); cache dir %s "
-            "will not be used and every restart recompiles.",
-            jax.__version__, e, cache_dir,
-        )
-        return None
+        # jax opens the cache once, at the directory configured then
+        compilation_cache.reset_cache()
     return cache_dir
-
-
-def ensure_persistent_compile_cache(cache_dir: str | None = None) -> str | None:
-    """Idempotently enable the JAX persistent compilation cache.
-
-    Resolution order: explicit ``cache_dir`` arg > ``ATT_COMPILE_CACHE`` env
-    ("0"/"false"/"" disables, "1"/"true" enables at the default location,
-    anything else is a path) > a cache dir the user already configured via
-    ``JAX_COMPILATION_CACHE_DIR`` / ``jax.config`` (respected, not clobbered)
-    > ``~/.cache/accelerate_tpu/xla_cache``.
-    Returns the active cache dir (None when disabled)."""
-    global _enabled_dir
-    env = os.environ.get("ATT_COMPILE_CACHE")
-    import jax
-
-    if cache_dir is None:
-        if env is not None and env.lower() in ("0", "false", ""):
-            return None
-        if env is not None and env.lower() in ("1", "true"):
-            env = _DEFAULT_DIR
-        if env is None:
-            if _enabled_dir is not None:
-                # already enabled by us — don't re-read jax.config (it now
-                # holds OUR dir, which must not be misread as user config)
-                return _enabled_dir
-            # Respect a cache the user configured themselves: keep their dir
-            # and their thresholds. jax only reads JAX_COMPILATION_CACHE_DIR
-            # at import, so re-apply it through jax.config (idempotent) in
-            # case the env var was set after `import jax`.
-            user_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or getattr(
-                jax.config, "jax_compilation_cache_dir", None
-            )
-            if user_dir:
-                # user-configured dir: keep their thresholds, only re-apply
-                # the dir (idempotent) in case the env var was set post-import
-                _enabled_dir = _activate(user_dir, set_thresholds=False)
-                return _enabled_dir
-        cache_dir = env or _DEFAULT_DIR
-    if _enabled_dir == cache_dir:
-        return _enabled_dir
-
-    _enabled_dir = _activate(cache_dir, set_thresholds=True)
-    return _enabled_dir
-
-
-def active_cache_dir() -> str | None:
-    """The persistent cache dir jax is currently pointed at — ours or
-    user-configured — or None. Introspection for callers deciding whether
-    an AOT re-compile would be a cache deserialize or a cold backend
-    compile (NB: entries under the min-compile-time threshold are never
-    persisted, so an active dir is necessary but not sufficient)."""
-    if _enabled_dir:
-        return _enabled_dir
-    try:
-        import jax
-
-        return getattr(jax.config, "jax_compilation_cache_dir", None)
-    except Exception:
-        return None
 
 
 # ---------------------------------------------------------------------------
@@ -182,9 +104,19 @@ def record_compile_event(seconds: float = 0.0, cache_hit: bool = False):
 
 
 def _on_event_duration(event, duration, **_kw):
+    """jax reports three durations per compiled program (trace, lowering,
+    backend compile). All three add to ``seconds``; only the backend
+    compile — which also fires when the persistent cache serves the
+    executable — is a compile EVENT. Counting traces made "nothing compiles
+    after warm-up" unprovable on the chip: every eager ``fold_in`` of an
+    ``rbg`` key (the TPU default, two per train step) reports a ~15 us
+    trace of its inner jit although nothing is compiled."""
     name = str(event)
     if "compile" in name and "cache" not in name:
-        record_compile_event(float(duration))
+        with _counter_lock:
+            _COMPILE_COUNTERS["seconds"] += float(duration)
+            if "backend_compile" in name:
+                _COMPILE_COUNTERS["count"] += 1
 
 
 def _on_event(event, **_kw):
@@ -193,19 +125,12 @@ def _on_event(event, **_kw):
         record_compile_event(cache_hit=True)
 
 
-def install_compile_listeners() -> bool:
-    """Subscribe the counters to jax.monitoring (idempotent). Returns False
-    when this jax build has no monitoring hooks — counters then only move
-    through explicit record_compile_event calls."""
+def install_compile_listeners() -> None:
+    """Subscribe the counters to jax.monitoring (idempotent)."""
     global _listeners_installed
-    if _listeners_installed:
-        return True
-    try:
+    if not _listeners_installed:
         from jax import monitoring
 
         monitoring.register_event_duration_secs_listener(_on_event_duration)
         monitoring.register_event_listener(_on_event)
-    except Exception:  # pragma: no cover - jax without monitoring
-        return False
-    _listeners_installed = True
-    return True
+        _listeners_installed = True

@@ -457,20 +457,14 @@ class MixedPrecisionConfig:
 class CompilePlugin(KwargsHandler):
     """Reference TorchDynamoPlugin (:887-922). Under JAX everything is
     jit-compiled already; this controls HOW:
-    ``donate_state``: donate params/opt buffers to the step (halves HBM churn);
-    ``cache_dir``: persistent XLA compilation cache.
+    ``donate_state``: donate params/opt buffers to the step (halves HBM churn).
+    The persistent XLA compilation cache is not a plugin field: it is placed
+    from outside through ``JAX_COMPILATION_CACHE_DIR`` (utils/compile_cache.py).
     """
 
     enabled: bool = True
     donate_state: bool = True
-    cache_dir: Optional[str] = None
     fullgraph: bool = True  # parity no-op: jit is always full-graph
-
-    def apply_cache(self):
-        if self.cache_dir:
-            from jax.experimental.compilation_cache import compilation_cache
-
-            compilation_cache.set_cache_dir(self.cache_dir)
 
 
 def add_model_config_to_megatron_parity(*_a, **_k):  # pragma: no cover

@@ -199,19 +199,12 @@ def make_global_batch(
 # ---------------------------------------------------------------------------
 
 def _axis_is_bound(name) -> bool:
-    """True iff ``name`` is a mapped axis in the current trace context.
-    ``jax.lax.axis_size`` where available; ``core.axis_frame`` (raises on
-    unbound names) on older jax builds without it."""
+    """True iff ``name`` is a mapped axis in the current trace context
+    (``jax.lax.axis_size`` raises NameError on an unbound name)."""
     try:
-        probe = jax.lax.axis_size
-    except AttributeError:
-        import jax.core as _core
-
-        probe = _core.axis_frame
-    try:
-        probe(name)
+        jax.lax.axis_size(name)
         return True
-    except (NameError, KeyError, Exception):
+    except NameError:
         return False
 
 

@@ -467,6 +467,46 @@ def kv_cache_bits(kv_dtype) -> int:
     )
 
 
+def quantize_kv_values(x, bits: int):
+    """The value half of :func:`quantize_kv`: ``x [..., D]`` -> ``(q fp32
+    [..., D] — rounded, clipped integers in [-qmax, qmax] — , scale fp32
+    [..., 1])``. One function for the jitted cache writes AND the ragged
+    prefill kernel's in-register quantize-on-write, so both emit identical
+    bytes by construction."""
+    if bits not in (8, 4):
+        raise ValueError(f"KV quantization supports 8 or 4 bits, got {bits}")
+    qmax = float(2 ** (bits - 1) - 1)
+    x32 = x.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
+    scale = jnp.where(amax > 0, amax / qmax, 1.0)
+    q = jnp.clip(jnp.round(x32 * (1.0 / scale)), -qmax, qmax)
+    return q, scale
+
+
+def pack_int4_kv(q):
+    """[..., D] integer values in [-7, 7] -> [..., D//2] int8 bytes: value
+    ``i`` of the FIRST half of head_dim in the low nibble, value
+    ``i + D//2`` in the high nibble. Split-halves, not even/odd
+    interleave: Mosaic cannot lay out a trailing-dimension interleave
+    (``stack`` + ``reshape``), while two contiguous half-width lane slices
+    compile. The arithmetic is int32 because the v5e vector unit has no
+    int8 shifts; ``(hi << 4) | (lo & 0xF)`` of signed nibbles already fits
+    int8, so the final cast never wraps."""
+    if q.shape[-1] % 2:
+        raise ValueError(
+            f"int4 KV packing needs an even head_dim, got {q.shape[-1]}"
+        )
+    half = q.shape[-1] // 2
+    q = q.astype(jnp.int32)
+    return ((q[..., half:] << 4) | (q[..., :half] & 0x0F)).astype(jnp.int8)
+
+
+def kv_payload(q, bits: int):
+    """Storage bytes of quantized values ``q [..., D]``: int8 as they are,
+    int4 packed two per byte (:func:`pack_int4_kv`)."""
+    return pack_int4_kv(q) if bits == 4 else q.astype(jnp.int8)
+
+
 def quantize_kv(x, bits: int):
     """In-graph symmetric quantization of fresh K/V values along the LAST
     axis (head_dim): ``x [..., D]`` -> ``(payload int8 [..., D] (int8) or
@@ -474,32 +514,19 @@ def quantize_kv(x, bits: int):
     ``x ~= payload * scale``. Zero rows quantize to payload 0 / scale 1.0
     (exact round trip). Traced-friendly: this runs inside the jitted decode
     step / prefill chunk programs."""
-    if bits not in (8, 4):
-        raise ValueError(f"KV quantization supports 8 or 4 bits, got {bits}")
-    qmax = float(2 ** (bits - 1) - 1)
-    x32 = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0)
-    q = jnp.clip(jnp.round(x32 * (1.0 / scale)), -qmax, qmax).astype(jnp.int8)
-    if bits == 4:
-        if x.shape[-1] % 2:
-            raise ValueError(
-                f"int4 KV packing needs an even head_dim, got {x.shape[-1]}"
-            )
-        lo = q[..., 0::2] & 0x0F
-        hi = (q[..., 1::2] & 0x0F) << 4
-        q = (lo | hi).astype(jnp.int8)
-    return q, scale
+    q, scale = quantize_kv_values(x, bits)
+    return kv_payload(q, bits), scale
 
 
 def unpack_int4_kv(payload):
-    """[..., D//2] packed nibbles -> [..., D] signed int8 values (even
-    head_dim indices in the low nibble, odd in the high — the inverse of
-    :func:`quantize_kv`'s interleave)."""
-    lo = (payload << 4).astype(jnp.int8) >> 4          # sign-extend low nibble
-    hi = payload >> 4                                   # arithmetic shift
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(*payload.shape[:-1], 2 * payload.shape[-1])
+    """[..., D//2] packed bytes -> [..., D] signed int32 values, the
+    inverse of :func:`pack_int4_kv` (low nibbles are the first half of
+    head_dim, high nibbles the second). Runs unchanged inside the pallas
+    kernels: int32 shifts and a lane concatenate are what Mosaic accepts."""
+    p = payload.astype(jnp.int32)
+    lo = (p << 28) >> 28                                # sign-extend low nibble
+    hi = p >> 4                                         # arithmetic shift
+    return jnp.concatenate([lo, hi], axis=-1)
 
 
 def dequantize_kv(payload, scale, bits: int, dtype):

@@ -24,7 +24,7 @@ def _parse(version: str) -> tuple:
 
 
 def compare_versions(library_or_version, operation: str, requirement_version: str) -> bool:
-    """compare_versions("jax", ">=", "0.4.30") or compare_versions("0.9.0", "<", "1.0")."""
+    """compare_versions("jax", ">=", "0.9.0") or compare_versions("0.9.0", "<", "1.0")."""
     if operation not in _OPS:
         raise ValueError(f"operation must be one of {sorted(_OPS)}, got {operation!r}")
     if isinstance(library_or_version, str) and not library_or_version[0].isdigit():

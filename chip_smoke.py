@@ -1,0 +1,708 @@
+#!/usr/bin/env python3
+"""The standing proof that the trainer and the paged server run on the chip.
+
+    python chip_smoke.py              # one TPU v5e chip: kernels, train, serve
+    python chip_smoke.py --chips 4    # four chips: sharded training only
+
+One process, one pass through the entry points a user calls, at the full
+widths of ``DecoderConfig.llama_7b`` (embed 4096, 32 heads x 128, mlp 11008,
+vocab 32000, untied); only depth is cut. Weights and data come from
+``--seed``; nothing is read from the network. Phases on one chip:
+
+- **kernels** — the pallas paged-decode and ragged-prefill kernels against
+  their in-repo references (the gathered masked-dense read and
+  ``_ragged_prefill_reference``), bf16 / int8 / int4 KV, MHA and GQA.
+- **train** — ``Accelerator(mixed_precision="bf16")`` -> ``prepare`` ->
+  ``build_train_step()``, a few steps on one fixed batch: the loss falls,
+  the compiled step holds the flash kernel, nothing compiles after step 1.
+- **serve** — ``ServingEngine(page_size=...)`` -> ``warmup()`` -> requests of
+  mixed prompt length through ``submit``/``run``: every request finishes,
+  nothing compiles after warm-up, the compiled decode and prefill programs
+  hold their kernels, and the greedy tokens are compared with an engine
+  built on the dense reference paths.
+
+``--chips 4`` runs only the sharded trainer on a
+``ShardingConfig(fsdp=2, tensor_parallel=2)`` mesh and the one-device plain
+jax/optax run it is compared with (same weights, same batch).
+
+Any failed check raises, so the exit code is non-zero and no result line is
+printed. Without a TPU the script exits at once: ``--cpu-rehearsal`` is the
+only way onto the CPU (tiny widths, kernels through the pallas interpreter),
+and every line it prints says so — it checks control flow, never speed. The
+last line on the chip is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import dataclasses
+import gc
+import json
+import logging
+import math
+import os
+import re
+import sys
+import time
+
+_PREFIX = ""
+
+
+def say(msg: str = "") -> None:
+    print(_PREFIX + msg, flush=True)
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """What one run holds. ``real`` is the chip; ``tiny`` the CPU rehearsal."""
+
+    widths: dict           # overrides of DecoderConfig.llama_7b (none on the chip)
+    kernel_mode: object    # decode/prefill kernel knob: None = compiled on the chip
+    train_attention: str   # "auto" takes the flash kernel on the chip only
+    seq_len: int
+    train_layers: int
+    train_batch: int
+    train_steps: int
+    serve_layers: int
+    page_size: int
+    num_slots: int
+    max_cache_len: int
+    prompt_lens: tuple     # plus two more that share a prefix of prompt_lens[-1] // 3
+    new_tokens: int
+    kv_heads_gqa: int
+    kv_bits: tuple         # KV storage the kernel checks cover (0 = bf16)
+    decode_widths: tuple   # query widths: 1 = decode step, 5 = a speculative verify
+
+
+# Depths come from compiled.memory_analysis() of the AOT rehearsal for
+# v5e:2x2 (compile only, PR 21): the train step at 2 layers x batch 4 x 2048
+# holds 7.45 GiB of fp32 params + Adam state and 5.76 GiB of temporaries
+# (13.2 GiB of the chip's 16; 3 layers do not fit); the serve programs at 8
+# layers hold 3.5 GiB of bf16 weights, a 2 GiB KV arena and <= 3 GiB of
+# temporaries (8.5 GiB).
+REAL = Sizes(
+    widths={}, kernel_mode=None, train_attention="auto", seq_len=2048,
+    train_layers=2, train_batch=4, train_steps=6,
+    serve_layers=8, page_size=16, num_slots=8, max_cache_len=2048,
+    prompt_lens=(24, 57, 180, 640, 1500), new_tokens=64, kv_heads_gqa=8,
+    kv_bits=(0, 8, 4), decode_widths=(1, 5),
+)
+TINY = Sizes(
+    widths=dict(vocab_size=512, embed_dim=256, num_heads=2, mlp_dim=512),
+    kernel_mode="interpret", train_attention="flash", seq_len=128,
+    train_layers=2, train_batch=4, train_steps=5,
+    serve_layers=2, page_size=8, num_slots=4, max_cache_len=256,  # >= the 256 prefill bucket
+    prompt_lens=(5, 11, 30, 150), new_tokens=6, kv_heads_gqa=1,
+    # the interpreter is slow: int4 only (the serve phase reads a bf16 arena)
+    kv_bits=(4,), decode_widths=(5,),
+)
+
+# bf16 has an 8-bit mantissa: outputs of O(1) agree to a few 2^-8 steps
+BF16_ATOL = BF16_RTOL = 2e-2
+BF16_STEP = 2.0 ** -7  # spacing of bf16 values in [1, 2)
+
+
+def compile_counts() -> dict:
+    from accelerate_tpu.utils.compile_cache import compile_event_counters
+
+    return compile_event_counters()
+
+
+@contextlib.contextmanager
+def steady_window(what: str):
+    """A window in which nothing may compile. The count is the library's own
+    (``compile_event_counters``: one event for each backend compile, a
+    persistent-cache hit included); jax's compile log is captured alongside,
+    so a failure names the program."""
+    import jax
+
+    names = []
+
+    class Collect(logging.Handler):
+        def emit(self, record):
+            msg = record.getMessage()
+            if msg.startswith(("Compiling ", "Finished tracing")):
+                names.append(msg.split(" with global shapes")[0].split(" for pjit")[0])
+
+    logger, handler = logging.getLogger("jax"), Collect()
+    logger.addHandler(handler)
+    propagate, logger.propagate = logger.propagate, False
+    before = compile_counts()["count"]
+    try:
+        with jax.log_compiles():
+            yield
+    finally:
+        logger.removeHandler(handler)
+        logger.propagate = propagate
+    events = compile_counts()["count"] - before
+    if events:
+        raise AssertionError(f"{events} compile events {what}: {names}")
+
+
+def assert_kernel_in(compiled_text: str, what: str, on_chip: bool) -> None:
+    """The compiled program must hold the Mosaic kernel, not a reference path."""
+    if on_chip:
+        if "tpu_custom_call" not in compiled_text:
+            raise AssertionError(f"{what}: no tpu_custom_call in the compiled program")
+        say(f"  {what}: tpu_custom_call present")
+    else:
+        say(f"  {what}: interpreter run, no Mosaic kernel to look for")
+
+
+def peak_memory(dev) -> str:
+    stats = dev.memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    return f"{peak / 2**30:.2f} GiB" if peak is not None else "not reported by this backend"
+
+
+def llama_cfg(S: Sizes, num_layers: int, **kw):
+    from accelerate_tpu.models import DecoderConfig
+
+    return DecoderConfig.llama_7b(
+        num_layers=num_layers, max_seq_len=S.max_cache_len, scan_layers=True,
+        **S.widths, **kw,
+    )
+
+
+def train_cfg(S: Sizes):
+    # remat + scan as the bench flagship uses
+    return llama_cfg(S, S.train_layers, remat=True, remat_policy="save_dots",
+                     attention_impl=S.train_attention)
+
+
+# ---------------------------------------------------------------------------
+# kernels against their references
+# ---------------------------------------------------------------------------
+
+
+def _quantized_arena(rng, shape, bits):
+    """(payload, scale) the way the cache holds them: quantize_kv of N(0,1)."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.utils.quantization import quantize_kv
+
+    x = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    return jax.jit(quantize_kv, static_argnums=1)(x, bits) if bits else (x, None)
+
+
+def check_paged_decode(S: Sizes, rng, *, kvh, bits) -> dict:
+    """{query width: max |kernel - reference|} over one arena."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.attention import paged_decode_attention
+
+    cfg = llama_cfg(S, 1)
+    h, d, ps = cfg.num_heads, cfg.head_dim, S.page_size
+    slots, per = S.num_slots, S.max_cache_len // S.page_size
+    pages = 1 + slots * per
+    k_pages, k_scale = _quantized_arena(rng, (pages, kvh, ps, d), bits)
+    v_pages, v_scale = _quantized_arena(rng, (pages, kvh, ps, d), bits)
+    kw = dict(page_table=jnp.asarray(
+        1 + rng.permutation(slots * per).reshape(slots, per).astype(np.int32)))
+    if bits:
+        kw.update(k_scale=k_scale, v_scale=v_scale, kv_quant_bits=bits)
+    errs = {}
+    for sq in S.decode_widths:
+        last = rng.randint(sq, S.max_cache_len, size=(slots,))
+        last[0], last[-1] = sq, S.max_cache_len - 1  # shortest and longest cache
+        pos = jnp.asarray((last[:, None] - np.arange(sq)[::-1][None, :]).astype(np.int32))
+        q = jnp.asarray(rng.standard_normal((slots, h, sq, d)), jnp.bfloat16)
+        run = lambda impl: jax.jit(
+            lambda q, k, v: paged_decode_attention(q, k, v, impl=impl, q_positions=pos, **kw)
+        )(q, k_pages, v_pages)
+        out = np.asarray(run(S.kernel_mode), np.float32)
+        ref = np.asarray(run("dense"), np.float32)
+        np.testing.assert_allclose(out, ref, atol=BF16_ATOL, rtol=BF16_RTOL)
+        errs[sq] = float(np.max(np.abs(out - ref)))
+    return errs
+
+
+def check_ragged_prefill(S: Sizes, rng, *, kvh, bits):
+    """A packed dispatch of four admissions: a cold tail, two tails behind a
+    cached prefix (one ending on a page boundary), a one-token tail."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.attention import _PREFILL_TOKEN_BLOCK as bt
+    from accelerate_tpu.ops.attention import ragged_prefill_attention
+    from accelerate_tpu.utils.quantization import unpack_int4_kv
+
+    cfg = llama_cfg(S, 1)
+    h, d, ps = cfg.num_heads, cfg.head_dim, S.page_size
+    per = S.max_cache_len // ps
+    unit = S.max_cache_len // 16
+    packs = [(0, unit - 3), (4 * unit, unit // 2 + 1), (6 * unit - unit // 2, unit // 2), (9 * unit + 5, 1)]
+    cap = sum(-(-t // bt) * bt for _, t in packs)
+    row_slot = np.full((cap,), -1, np.int32)
+    row_pos = np.full((cap,), -1, np.int32)
+    slot_hist = np.zeros((len(packs),), np.int32)
+    table = np.zeros((len(packs), per), np.int32)
+    r = 0
+    for s, (hist, tail) in enumerate(packs):
+        rows = -(-tail // bt) * bt
+        row_slot[r:r + rows] = s
+        row_pos[r:r + tail] = np.arange(hist, hist + tail)
+        r += rows
+        slot_hist[s] = hist
+        need = -(-(hist + tail) // ps)
+        table[s, :need] = 1 + s * per + np.arange(need)
+    pages = 1 + len(packs) * per
+    k_pages, k_scale = _quantized_arena(rng, (pages, kvh, ps, d), bits)
+    v_pages, v_scale = _quantized_arena(rng, (pages, kvh, ps, d), bits)
+    q = jnp.asarray(rng.standard_normal((1, h, cap, d)), jnp.bfloat16)
+    k_new = jnp.asarray(rng.standard_normal((1, kvh, cap, d)), jnp.bfloat16)
+    v_new = jnp.asarray(rng.standard_normal((1, kvh, cap, d)), jnp.bfloat16)
+    kw = dict(page_table=jnp.asarray(table), row_slot=jnp.asarray(row_slot),
+              row_pos=jnp.asarray(row_pos), slot_hist=jnp.asarray(slot_hist),
+              kv_quant_bits=bits)
+    if bits:
+        kw.update(k_scale=k_scale, v_scale=v_scale)
+    run = lambda impl: jax.jit(
+        lambda *a: ragged_prefill_attention(*a, impl=impl, **kw)
+    )(q, k_new, v_new, k_pages, v_pages)
+    got, ref = run(S.kernel_mode), run("dense")
+    valid = (row_slot >= 0) & (row_pos >= 0)
+    out = np.asarray(got[0], np.float32)[0][:, valid]
+    out_ref = np.asarray(ref[0], np.float32)[0][:, valid]
+    np.testing.assert_allclose(out, out_ref, atol=BF16_ATOL, rtol=BF16_RTOL)
+    byte_mismatch = 0.0
+    if bits:
+        # quantize-on-write: same scales, and payloads that differ by at most
+        # one step on a vanishing share of values (Mosaic and XLA may round
+        # x * (1 / scale) differently on an exact tie)
+        for pay, scl, pay_ref, scl_ref in ((got[1], got[2], ref[1], ref[2]),
+                                           (got[3], got[4], ref[3], ref[4])):
+            np.testing.assert_allclose(
+                np.asarray(scl)[valid], np.asarray(scl_ref)[valid], rtol=1e-6)
+            if bits == 4:
+                pay, pay_ref = unpack_int4_kv(pay), unpack_int4_kv(pay_ref)
+            diff = np.abs(np.asarray(pay, np.int32)[valid] - np.asarray(pay_ref, np.int32)[valid])
+            if diff.max() > 1:
+                raise AssertionError(f"quantize-on-write payload off by {diff.max()} steps")
+            byte_mismatch = max(byte_mismatch, float((diff > 0).mean()))
+        if byte_mismatch > 1e-3:
+            raise AssertionError(f"quantize-on-write payload mismatch share {byte_mismatch:.2e}")
+    return float(np.max(np.abs(out - out_ref))), byte_mismatch
+
+
+def kernels_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    heads = llama_cfg(S, 1).num_heads
+    say(f"  tolerance: atol={BF16_ATOL} rtol={BF16_RTOL} (bf16 outputs), "
+        f"kernel mode {S.kernel_mode or 'compiled (Mosaic)'}")
+    for kvh in (heads, S.kv_heads_gqa):
+        for bits in S.kv_bits:
+            kv = {0: "bf16", 8: "int8", 4: "int4"}[bits]
+            for sq, err in check_paged_decode(S, rng, kvh=kvh, bits=bits).items():
+                say(f"  paged decode   {heads}q/{kvh}kv {kv} Sq={sq}: max|kernel-ref|={err:.4f}")
+            err, mism = check_ragged_prefill(S, rng, kvh=kvh, bits=bits)
+            say(f"  ragged prefill {heads}q/{kvh}kv {kv}: max|kernel-ref|={err:.4f}"
+                + (f", payload mismatch share={mism:.1e}" if bits else ""))
+
+
+# ---------------------------------------------------------------------------
+# train
+# ---------------------------------------------------------------------------
+
+
+def init_variables(model_def, seed: int):
+    import jax
+
+    return model_def.init_variables(jax.random.PRNGKey(seed), batch_size=1, seq_len=128)
+
+
+def train_batch(S: Sizes, cfg, seed: int):
+    import numpy as np
+
+    ids = np.random.RandomState(seed + 1).randint(0, cfg.vocab_size, (S.train_batch, S.seq_len))
+    return {"input_ids": ids, "labels": ids}
+
+
+def accelerator_train(S: Sizes, seed: int, on_chip: bool, sharding=None, variables=None):
+    """The library's path. Returns (losses, compiled step text, accelerator, model)."""
+    import jax
+    import optax
+
+    from accelerate_tpu import Accelerator, Model
+    from accelerate_tpu.models import DecoderLM
+    from accelerate_tpu.state import AcceleratorState
+
+    AcceleratorState._reset_state(reset_partial_state=True)
+    accelerator = Accelerator(mixed_precision="bf16", sharding_config=sharding)
+    cfg = train_cfg(S)
+    model_def = DecoderLM(cfg, mesh=accelerator.mesh)
+    if variables is None:
+        variables = init_variables(model_def, seed)
+    say(f"  depth {cfg.num_layers} layers, {cfg.num_params / 1e9:.3f}B params, batch "
+        f"{S.train_batch} x {S.seq_len} tokens, mesh {accelerator.state.mesh_shape}")
+    model, _ = accelerator.prepare(Model(model_def, variables), optax.adamw(3e-4))
+    del variables
+    step = accelerator.build_train_step()
+    batch = accelerator.prepare_for_eval(train_batch(S, cfg, seed))
+
+    losses, walls = [], []
+
+    def one_step():
+        t0 = time.perf_counter()
+        loss = float(jax.block_until_ready(step(batch)["loss"]))
+        walls.append(time.perf_counter() - t0)
+        losses.append(loss)
+        say(f"  step {len(losses)}: loss {loss:.4f}  wall {walls[-1]:.3f}s")
+
+    one_step()
+    # the same program again through the AOT path (served by the persistent
+    # cache the call above filled) to read its text
+    (spec,) = accelerator.audit_entrypoints(step, batch)
+    text = spec["fn"].lower(*spec["args"]).compile().as_text()
+    assert_kernel_in(text, "train step (flash attention)", on_chip)
+    with steady_window("after step 1"):
+        for _ in range(S.train_steps - 1):
+            one_step()
+    if not all(math.isfinite(l) for l in losses):
+        raise AssertionError(f"non-finite loss: {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"loss did not fall on a fixed batch: {losses}")
+    steady_wall = sorted(walls[1:])[len(walls[1:]) // 2]
+    say(f"  compile+first step {walls[0]:.1f}s, steady median {steady_wall:.3f}s/step, "
+        f"0 compile events after step 1")
+    return losses, text, accelerator, model
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+
+def serve_params(model_def, seed: int):
+    """Seeded weights in the serving dtype, made on the device in one program."""
+    import jax
+    import jax.numpy as jnp
+
+    from accelerate_tpu.parallel.sharding import unbox_params
+
+    dt = model_def.config.dtype
+
+    @jax.jit
+    def init(key):
+        params, _ = unbox_params(model_def.init(key, jnp.zeros((1, 8), jnp.int32))["params"])
+        return jax.tree_util.tree_map(
+            lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+
+    return init(jax.random.PRNGKey(seed))
+
+
+def serve_prompts(S: Sizes, vocab: int, seed: int) -> list:
+    import numpy as np
+
+    rng = np.random.RandomState(seed + 2)
+    prompts = [rng.randint(0, vocab, (n,)).astype(np.int32) for n in S.prompt_lens]
+    shared = prompts[-1][: S.prompt_lens[-1] // 3]
+    for extra in (S.prompt_lens[0], S.prompt_lens[1]):  # two more behind one prefix
+        prompts.append(np.concatenate([shared, rng.randint(0, vocab, (extra,)).astype(np.int32)]))
+    return prompts
+
+
+def run_engine(S: Sizes, cfg, params, prompts, on_chip: bool, label: str):
+    """warmup -> submit -> run on one engine. Returns the generated tokens."""
+    import jax
+
+    from accelerate_tpu.models import DecoderLM
+    from accelerate_tpu.ops.attention import decode_kernel_active, prefill_kernel_active
+    from accelerate_tpu.serving import ServingEngine
+
+    t0 = time.perf_counter()
+    engine = ServingEngine(
+        DecoderLM(cfg), params, page_size=S.page_size, num_slots=S.num_slots,
+        max_cache_len=S.max_cache_len,
+    )
+    engine.warmup()
+    warm = time.perf_counter() - t0
+    say(f"  [{label}] engine + warmup (all compiles) {warm:.1f}s, "
+        f"KV arena {engine.arena_bytes / 2**30:.2f} GiB in {engine.num_pages} pages")
+    paged_cfg = dataclasses.replace(
+        cfg, kv_page_size=S.page_size, kv_num_pages=engine.num_pages)
+    kernels_on = (decode_kernel_active(paged_cfg), prefill_kernel_active(paged_cfg))
+    if label == "kernel":
+        if kernels_on != (True, True):
+            raise AssertionError(f"decode/prefill kernel gates are {kernels_on}, want both on")
+        say("  decode_kernel_active and prefill_kernel_active: True")
+        for spec in engine.audit_entrypoints():
+            if spec["name"] == "decode_step" or spec["name"].startswith("ragged_prefill_"):
+                text = spec["fn"].lower(*spec["args"], **spec.get("kwargs", {})).compile().as_text()
+                assert_kernel_in(text, spec["name"], on_chip)
+    elif kernels_on != (False, False):
+        raise AssertionError(f"the dense engine resolved to kernels: {kernels_on}")
+
+    engine.mark_steady()
+    t0 = time.perf_counter()
+    with steady_window("after warmup()"):
+        reqs = [engine.submit(p, max_new_tokens=S.new_tokens, seed=i) for i, p in enumerate(prompts)]
+        engine.run()
+    wall = time.perf_counter() - t0
+    if engine.admission_recompiles:
+        raise AssertionError(f"admission_recompiles = {engine.admission_recompiles}")
+    for r in reqs:
+        if r.outcome != "finished" or len(r.tokens) != S.new_tokens:
+            raise AssertionError(
+                f"request {r.id}: outcome {r.outcome}, {len(r.tokens)}/{S.new_tokens} tokens")
+        ttft = (r.first_token_t - r.submit_t) if r.first_token_t else float("nan")
+        say(f"  [{label}] request {r.id}: prompt {len(r.prompt)}, prefix hit {r.prefix_hit}, "
+            f"prefill {r.prefill_kernel}, first token after {ttft:.3f}s, {len(r.tokens)} tokens")
+    total = sum(len(r.tokens) for r in reqs)
+    say(f"  [{label}] {len(reqs)} requests, {total} tokens in {wall:.2f}s after warm-up "
+        f"({engine.step_count} engine steps), 0 compile events after warmup()")
+    tokens = [list(r.tokens) for r in reqs]
+    del engine, reqs
+    gc.collect()
+    jax.clear_caches()
+    return tokens
+
+
+def serve_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.models import DecoderLM
+
+    cfg = llama_cfg(S, S.serve_layers, remat=False, decode_kernel=S.kernel_mode,
+                    prefill_kernel=S.kernel_mode)
+    params = serve_params(DecoderLM(cfg), seed)
+    say(f"  depth {cfg.num_layers} layers, {cfg.num_params / 1e9:.3f}B params in "
+        f"{cfg.dtype.__name__}, page size {S.page_size}, {S.num_slots} slots x {S.max_cache_len}")
+    prompts = serve_prompts(S, cfg.vocab_size, seed)
+    kernel = run_engine(S, cfg, params, prompts, on_chip, "kernel")
+    dense_cfg = dataclasses.replace(cfg, decode_kernel="dense", prefill_kernel="dense")
+    dense = run_engine(S, dense_cfg, params, prompts, on_chip, "dense")
+    same = sum(a == b for k, d in zip(kernel, dense) for a, b in zip(k, d))
+    total = sum(len(k) for k in kernel)
+    firsts = [k[0] == d[0] for k, d in zip(kernel, dense)]
+    say(f"  greedy tokens, kernel engine vs dense engine: {same}/{total} agree "
+        f"({same / total:.1%}); first token agrees on {sum(firsts)}/{len(firsts)} requests")
+    # Random weights give near-flat logits over the vocabulary, so the largest
+    # one can change on rounding. A first token may differ only where the
+    # plain forward pass itself cannot order the two candidates: their logits
+    # no further apart than one bf16 step at the largest logit's magnitude.
+    model_def = DecoderLM(cfg)
+    for i, (k, d) in enumerate(zip(kernel, dense)):
+        if k[0] == d[0]:
+            continue
+        logits = np.asarray(
+            model_def.apply({"params": params}, jnp.asarray(prompts[i])[None])["logits"][0, -1],
+            np.float32)
+        gap = abs(logits[k[0]] - logits[d[0]])
+        tie = BF16_STEP * 2.0 ** math.floor(math.log2(np.abs(logits).max()))
+        say(f"  request {i}: first token {k[0]} (kernel) vs {d[0]} (dense); plain-forward logits "
+            f"{logits[k[0]]:.4f} vs {logits[d[0]]:.4f} (largest {logits.max():.4f}), "
+            f"gap {gap:.4f}, one bf16 step {tie:.4f}")
+        if gap > tie:
+            raise AssertionError(f"request {i}: first tokens differ and it is not a tie")
+
+
+# ---------------------------------------------------------------------------
+# four chips: the sharded trainer against a one-device run
+# ---------------------------------------------------------------------------
+
+
+def reference_train(S: Sizes, cfg, variables, batch) -> list:
+    """Plain jax + optax on ONE device: same weights, batch, optimizer and
+    bf16-compute / fp32-master recipe, none of the library's train engine."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from accelerate_tpu.models import DecoderLM
+    from accelerate_tpu.parallel.sharding import unbox_params
+
+    model_def = DecoderLM(cfg)  # no mesh
+    dev = jax.devices()[0]
+    params = jax.device_put(unbox_params(variables["params"])[0], dev)
+    tx = optax.adamw(3e-4)
+    opt_state = tx.init(params)
+    ids = jax.device_put(jnp.asarray(batch["input_ids"]), dev)
+
+    def step(params, opt_state, ids):
+        def loss_fn(p):
+            p16 = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), p)
+            return model_def.apply({"params": p16}, ids, labels=ids)["loss"].astype(jnp.float32)
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    step = jax.jit(step, donate_argnums=(0, 1))
+    losses = []
+    for i in range(S.train_steps):
+        params, opt_state, loss = step(params, opt_state, ids)
+        losses.append(float(jax.block_until_ready(loss)))
+        say(f"  one-device step {i + 1}: loss {losses[-1]:.4f}")
+    return losses
+
+
+def check_placement(model, devices) -> None:
+    """Every parameter is addressable on all four devices, every sharded one
+    as four distinct quarter-size shards; what stays replicated (norm
+    scales) is a negligible share, so each device holds about a quarter."""
+    import jax
+
+    per_device = {d: 0 for d in devices}
+    total = replicated = n_sharded = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(model.params)[0]:
+        name = jax.tree_util.keystr(path)
+        shards = leaf.addressable_shards
+        on = {s.device for s in shards}
+        if on != set(devices):
+            raise AssertionError(f"{name} lives on {len(on)} of {len(devices)} devices")
+        total += leaf.nbytes
+        for s in shards:
+            per_device[s.device] += s.data.nbytes
+        if leaf.sharding.is_fully_replicated:
+            replicated += leaf.nbytes
+            continue
+        n_sharded += 1
+        distinct = {tuple((sl.start, sl.stop) for sl in s.index) for s in shards}
+        frac = shards[0].data.nbytes / leaf.nbytes
+        if len(distinct) != len(devices) or frac != 1 / len(devices):
+            raise AssertionError(
+                f"{name} {leaf.shape} {leaf.sharding.spec}: {len(distinct)} distinct shards of "
+                f"{frac:.3f} of the bytes, want {len(devices)} of {1 / len(devices):.3f}")
+    held = [b / total for b in per_device.values()]
+    say(f"  parameters: {total / 2**30:.3f} GiB in {n_sharded} sharded leaves, each as "
+        f"{len(devices)} distinct shards of 1/{len(devices)} on {len(devices)} devices; replicated "
+        f"leaves hold {replicated / total:.2%}; share of the bytes on each device "
+        f"{[f'{h:.3f}' for h in held]}")
+    if max(held) > 0.26:
+        raise AssertionError(f"a device holds {max(held):.3f} of the parameter bytes, want about 1/4")
+
+
+def four_chip_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    import jax
+
+    from accelerate_tpu.models import DecoderLM
+    from accelerate_tpu.utils.dataclasses import ShardingConfig
+
+    cfg = train_cfg(S)
+    # one set of seeded fp32 weights, on the host, for both runs
+    variables = jax.device_get(init_variables(DecoderLM(cfg), seed))
+    ref = reference_train(S, cfg, variables, train_batch(S, cfg, seed))
+    gc.collect()
+    jax.clear_caches()
+
+    losses, text, accelerator, model = accelerator_train(
+        S, seed, on_chip, sharding=ShardingConfig(fsdp=2, tensor_parallel=2), variables=variables)
+    check_placement(model, jax.devices())
+    found = {op: len(re.findall(rf"\b{op}(?:-start)?\(", text))
+             for op in ("all-reduce", "all-gather", "reduce-scatter", "collective-permute", "all-to-all")}
+    say(f"  collectives in the compiled sharded step: {found}")
+    if not sum(found.values()):
+        raise AssertionError("no collective in the compiled sharded step")
+    say(f"  loss, one device: {[round(l, 4) for l in ref]}")
+    say(f"  loss, fsdp2 x tp2: {[round(l, 4) for l in losses]}")
+    if abs(ref[0] - losses[0]) > BF16_ATOL:
+        raise AssertionError(f"first-step loss differs: {ref[0]} vs {losses[0]}")
+    # the trajectories track: every step inside bf16 tolerance of the one-device
+    # loss (absolute + relative, as allclose; late losses on a memorised batch
+    # are near zero, where a purely relative bound means nothing)
+    drift = [abs(a - b) for a, b in zip(ref, losses)]
+    say(f"  |one device - sharded| per step: {[round(x, 5) for x in drift]}")
+    bad = [i + 1 for i, (a, x) in enumerate(zip(ref, drift)) if x > BF16_ATOL + BF16_RTOL * abs(a)]
+    if bad:
+        raise AssertionError(f"loss trajectories drift apart at steps {bad}")
+
+
+# ---------------------------------------------------------------------------
+
+
+def main() -> int:
+    global _PREFIX
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                        help="4: only the sharded trainer and its one-device comparison")
+    parser.add_argument("--cpu-rehearsal", action="store_true",
+                        help="tiny widths on the CPU, kernels interpreted; never a result")
+    args = parser.parse_args()
+    if args.cpu_rehearsal:
+        _PREFIX = "[CPU REHEARSAL, not a chip run] "
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        if args.chips > 1:
+            os.environ["XLA_FLAGS"] = (
+                os.environ.get("XLA_FLAGS", "")
+                + f" --xla_force_host_platform_device_count={args.chips}")
+    t_start = time.perf_counter()
+
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    want = "cpu" if args.cpu_rehearsal else "tpu"
+    if dev.platform != want or len(devices) != args.chips:
+        print(f"chip_smoke: found {len(devices)} x {dev.platform} ({dev.device_kind}), need "
+              f"{args.chips} x {want}; there is no fallback "
+              "(--cpu-rehearsal is the tiny CPU run and says so on every line)", file=sys.stderr)
+        return 2
+    on_chip = not args.cpu_rehearsal
+    S = REAL if on_chip else TINY
+
+    import flax
+    import jaxlib
+    import optax
+
+    from accelerate_tpu.runtime.native import native_available
+    from accelerate_tpu.utils.compile_cache import (
+        ensure_persistent_compile_cache,
+        install_compile_listeners,
+    )
+
+    install_compile_listeners()
+    cache_dir = ensure_persistent_compile_cache()
+    from importlib import metadata
+
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:  # a CPU-only image; only printed
+        libtpu = "not installed"
+    say(f"python {sys.version.split()[0]}  jax {jax.__version__}  jaxlib {jaxlib.__version__}  "
+        f"libtpu {libtpu}  flax {flax.__version__}  optax {optax.__version__}")
+    say(f"device: {len(devices)} x {dev.platform} ({dev.device_kind}); seed {args.seed}")
+    cached = len(os.listdir(cache_dir)) if cache_dir else 0
+    say(f"compile cache: {cache_dir} ({cached} entries at start; "
+        f"JAX_COMPILATION_CACHE_DIR {'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'})")
+    t0 = time.perf_counter()
+    say(f"csrc/att_runtime.cpp native library loaded: {native_available()} "
+        f"({time.perf_counter() - t0:.1f}s incl. build on first use)")
+
+    phases = (
+        [("four chips: fsdp2 x tp2 trainer vs one device", four_chip_phase)]
+        if args.chips == 4 else
+        [("kernels vs references", kernels_phase), ("train", accelerator_train), ("serve", serve_phase)]
+    )
+    for name, fn in phases:
+        say(f"== {name}")
+        before, t0 = compile_counts(), time.perf_counter()
+        fn(S, args.seed, on_chip)
+        after = compile_counts()
+        say(f"== {name}: ok in {time.perf_counter() - t0:.1f}s "
+            f"(compile events {after['count'] - before['count']}, {after['seconds'] - before['seconds']:.1f}s "
+            f"in them, persistent-cache hits {after['cache_hits'] - before['cache_hits']}); "
+            f"peak device memory {peak_memory(dev)}")
+        gc.collect()
+        jax.clear_caches()
+
+    counts = compile_counts()
+    say(f"total wall {time.perf_counter() - t_start:.1f}s; compile events {counts['count']} "
+        f"({counts['seconds']:.1f}s), persistent-cache hits {counts['cache_hits']}")
+    say(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind, "count": len(devices)}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

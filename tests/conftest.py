@@ -21,28 +21,25 @@ if "host_platform_device_count" not in flags:
 # seconds of XLA work. Budget on a SINGLE CPU core: full non-slow suite
 # ~9 min (was >20 min before these levers); per-file runs are seconds to a
 # minute. On multicore hosts pytest-xdist (-n auto) divides the compile
-# bill. Two levers keep wall time sane; both are overridable:
+# bill. Two levers keep wall time sane:
 # - skip XLA's optimization pipeline: tests assert semantics, not speed
 #   (~35-65% off the worst tests' compile time)
-# - persist compiled executables across runs in a repo-local cache, so
-#   re-runs (CI retries, local iteration, review) skip backend compiles
+# - persist compiled executables across runs, so re-runs (CI retries, local
+#   iteration, review) skip backend compiles. The directory is the one the
+#   package resolves (utils/compile_cache.py): an outside
+#   JAX_COMPILATION_CACHE_DIR stays; unset, the fixed .xla_cache/ of the
+#   checkout. It goes through the ENVIRONMENT (not jax.config.update) so
+#   LAUNCHED SUBPROCESSES — the most compile-heavy tests — inherit it too;
+#   JAX_ENABLE_COMPILATION_CACHE=0 turns it off.
 os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
-if os.environ.get("ATT_TEST_XLA_CACHE", "1").lower() not in ("0", "false", ""):
-    _cache_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)), ".xla_cache")
-    os.environ.setdefault("ATT_COMPILE_CACHE", _cache_dir)
-    # env (not jax.config.update) so LAUNCHED SUBPROCESSES — the most
-    # compile-heavy tests — inherit the cache too
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _cache_dir)
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
-    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
+_repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_repo_root, ".xla_cache")
+)
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
-    def _enable_test_compile_cache():
-        os.makedirs(_cache_dir, exist_ok=True)
-else:
-    def _enable_test_compile_cache():
-        pass
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, _repo_root)
 
 if "jax" in sys.modules:
     import jax
@@ -55,8 +52,6 @@ if "jax" in sys.modules:
     )
 
 import pytest  # noqa: E402
-
-_enable_test_compile_cache()
 
 
 @pytest.fixture(autouse=True)

@@ -388,7 +388,7 @@ class TestCheckpointing:
         for k in params_run1:
             np.testing.assert_allclose(params_run1[k], params_run2[k], rtol=1e-6)
 
-    def test_training_continues_identically_warm_compile_cache(self, tmp_path):
+    def test_training_continues_identically_warm_compile_cache(self, tmp_path, monkeypatch):
         """test_training_continues_identically with every executable forced
         through the persistent compilation cache. The post-restore update is
         then a cache-DESERIALIZED executable donating device_put-restored
@@ -409,6 +409,8 @@ class TestCheckpointing:
 
             compilation_cache.reset_cache()
 
+        # Accelerator() re-applies the env's directory, so place it there
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla_cache"))
         _cache_config(str(tmp_path / "xla_cache"), 0.0, 0)
         try:
             self.test_training_continues_identically(tmp_path)
@@ -725,6 +727,42 @@ class TestGradCompression:
         out = [step(batch) for _ in range(steps)]
         losses = [float(jax.device_get(m["loss"])) for m in out]
         return losses, float(jax.device_get(out[0]["grad_norm"]))
+
+    @pytest.mark.parametrize(
+        "step_kind", ["grad_compression", pytest.param("local_sgd", marks=pytest.mark.slow)]
+    )
+    def test_flash_kernel_inside_the_manual_step(self, step_kind):
+        """Both steps run the model inside a shard_map that is manual over
+        every mesh axis. The kernel's own per-shard wrapper
+        (``dot_product_attention_sharded``) must see that and call the
+        kernel bare: a second shard_map over the concrete mesh is refused
+        there ("context mesh ... should match"), which ``auto`` hid off-chip
+        by taking the XLA reference. ``flash`` interprets the kernel here;
+        the first loss equals a plain XLA forward's. (Both take the same
+        branch, so one is enough for the fast tier; the partly-manual case
+        is compiled for the chip in test_tpu_compile.py.)"""
+        from accelerate_tpu import Accelerator, LocalSGD, Model
+        from accelerate_tpu.models import DecoderConfig, DecoderLM
+        from accelerate_tpu.state import AcceleratorState
+
+        AcceleratorState._reset_state(reset_partial_state=True)
+        sc = (ShardingConfig(replica=2, data_parallel=4, grad_compression_dtype="bf16")
+              if step_kind == "grad_compression" else ShardingConfig(data_parallel=8))
+        accelerator = Accelerator(sharding_config=sc)
+        cfg = DecoderConfig.tiny(num_layers=1, attention_impl="flash")
+        model_def = DecoderLM(cfg, mesh=accelerator.mesh)
+        variables = model_def.init_variables(jax.random.PRNGKey(0), batch_size=8, seq_len=128)
+        ids = np.random.RandomState(1).randint(0, cfg.vocab_size, (8, 128))
+        want = DecoderLM(DecoderConfig.tiny(num_layers=1, attention_impl="xla")).apply(
+            variables, ids, labels=ids)["loss"]
+        model, _ = accelerator.prepare(Model(model_def, variables), optax.adamw(1e-3))
+        batch = accelerator.prepare_for_eval({"input_ids": ids, "labels": ids})
+        if step_kind == "grad_compression":
+            loss = accelerator.build_train_step()(batch)["loss"]
+        else:
+            with LocalSGD(accelerator, model, local_sgd_steps=2) as loc:
+                loss = loc.build_local_step()(batch)["loss"]
+        np.testing.assert_allclose(float(jax.device_get(loss)), float(want), rtol=1e-5)
 
     @pytest.mark.slow
     def test_fsdp_inside_slice_matches_pure_dp(self):

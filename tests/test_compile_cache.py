@@ -1,61 +1,41 @@
-"""ATT_COMPILE_CACHE / JAX_COMPILATION_CACHE_DIR resolution in
-utils/compile_cache.py (library must not clobber user cache config)."""
+"""Compile-cache resolution in utils/compile_cache.py: one directory,
+placed from outside. ``JAX_COMPILATION_CACHE_DIR`` set -> that directory
+and nothing set in code; unset -> the fixed in-checkout path."""
 
 import os
+import subprocess
+import sys
 
 import jax
 import pytest
 
 import accelerate_tpu.utils.compile_cache as cc
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture()
 def cache_state(monkeypatch, tmp_path):
-    """Snapshot/restore the module + jax config state these tests mutate
-    (conftest enables a shared test cache for the whole suite)."""
-    prev_enabled = cc._enabled_dir
-    prev_jax_dir = jax.config.jax_compilation_cache_dir
-    monkeypatch.delenv("ATT_COMPILE_CACHE", raising=False)
+    """Snapshot/restore the jax config state these tests mutate (conftest
+    points the whole suite at one shared cache dir through the env)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = {
+        k: getattr(jax.config, k)
+        for k in ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+                  "jax_persistent_cache_min_compile_time_secs",
+                  "jax_persistent_cache_min_entry_size_bytes")
+    }
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    # hermetic: the suite conftest legitimately pre-sets a shared cache dir,
-    # which the user-config branch would (correctly) respect — clear it so
-    # these tests see a pristine process regardless of ordering
     jax.config.update("jax_compilation_cache_dir", None)
     yield monkeypatch, tmp_path
-    cc._enabled_dir = prev_enabled
-    jax.config.update("jax_compilation_cache_dir", prev_jax_dir)
+    for k, v in prev.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
 
 
-def test_env_1_means_default_dir_not_a_path(cache_state):
-    monkeypatch, _ = cache_state
-    cc._enabled_dir = None
-    monkeypatch.setenv("ATT_COMPILE_CACHE", "1")
-    assert cc.ensure_persistent_compile_cache() == cc._DEFAULT_DIR
-    assert not os.path.exists(os.path.join(os.getcwd(), "1"))
-    cc._enabled_dir = None
-    monkeypatch.setenv("ATT_COMPILE_CACHE", "true")
-    assert cc.ensure_persistent_compile_cache() == cc._DEFAULT_DIR
-
-
-def test_env_0_disables(cache_state):
-    monkeypatch, _ = cache_state
-    cc._enabled_dir = None
-    monkeypatch.setenv("ATT_COMPILE_CACHE", "0")
-    assert cc.ensure_persistent_compile_cache() is None
-
-
-def test_env_path_relocates(cache_state):
+def test_env_dir_is_the_cache_dir(cache_state):
     monkeypatch, tmp_path = cache_state
-    cc._enabled_dir = None
-    target = str(tmp_path / "relocated")
-    monkeypatch.setenv("ATT_COMPILE_CACHE", target)
-    assert cc.ensure_persistent_compile_cache() == target
-    assert os.path.isdir(target)
-
-
-def test_user_jax_cache_dir_respected_and_applied(cache_state):
-    monkeypatch, tmp_path = cache_state
-    cc._enabled_dir = None
     user = str(tmp_path / "usercache")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", user)
     assert cc.ensure_persistent_compile_cache() == user
@@ -64,42 +44,91 @@ def test_user_jax_cache_dir_respected_and_applied(cache_state):
     assert os.path.isdir(user)
 
 
-def test_unusable_dir_warns_once_and_disables(cache_state, caplog):
-    """A cache dir that cannot be created must disable the cache with ONE
-    warning naming the resolved path — the silent-fallback recurrence was
-    every restart paying full recompiles with nothing in the logs."""
-    import logging
+def test_env_dir_keeps_the_users_thresholds(cache_state):
+    monkeypatch, tmp_path = cache_state
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 7.0)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+    cc.ensure_persistent_compile_cache()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 7.0
 
+
+def test_env_dir_outranks_a_dir_set_in_code(cache_state):
+    """The acceptance rule: with the variable set, no other directory is
+    used — even one some code configured after import."""
+    monkeypatch, tmp_path = cache_state
+    env_dir = str(tmp_path / "from_env")
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "from_code"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    assert cc.ensure_persistent_compile_cache() == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert not os.path.exists(tmp_path / "from_code")
+
+
+def test_unset_means_the_fixed_in_checkout_dir(cache_state):
+    assert cc.REPO_CACHE_DIR == os.path.join(REPO, ".xla_cache")
+    assert cc.ensure_persistent_compile_cache() == cc.REPO_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == cc.REPO_CACHE_DIR
+    # our directory, our thresholds
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
+    # idempotent: generate() calls this on every invocation
+    assert cc.ensure_persistent_compile_cache() == cc.REPO_CACHE_DIR
+
+
+def test_unusable_dir_raises_naming_the_path(cache_state):
+    """A cache dir that cannot be created must fail loudly — carrying on
+    uncached was every restart paying full recompiles with nothing in the
+    logs."""
     monkeypatch, tmp_path = cache_state
     blocker = tmp_path / "a_file"
     blocker.write_text("not a dir")
     target = str(blocker / "cache")  # parent is a regular file
-    cc._enabled_dir = None
-    cc._warned.discard(f"unusable:{target}")
-    monkeypatch.setenv("ATT_COMPILE_CACHE", target)
-    with caplog.at_level(logging.WARNING, logger="accelerate_tpu.utils.compile_cache"):
-        assert cc.ensure_persistent_compile_cache() is None
-        assert cc.ensure_persistent_compile_cache() is None  # idempotent
-    hits = [r for r in caplog.records if "DISABLED" in r.getMessage()]
-    assert len(hits) == 1  # once, not per call
-    assert target in hits[0].getMessage()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+    with pytest.raises(OSError, match="a_file/cache"):
+        cc.ensure_persistent_compile_cache()
 
 
-def test_active_cache_dir_reports_enabled_dir(cache_state):
+def test_disabled_by_jax_switch_returns_none(cache_state):
+    jax.config.update("jax_enable_compilation_cache", False)
+    assert cc.ensure_persistent_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_accelerator_leaves_jax_on_the_env_dir(cache_state):
+    """``Accelerator()`` turns the cache on, at the directory the environment
+    names — also when some code had configured another one before."""
+    from accelerate_tpu import Accelerator
+
     monkeypatch, tmp_path = cache_state
-    cc._enabled_dir = None
-    target = str(tmp_path / "active")
-    monkeypatch.setenv("ATT_COMPILE_CACHE", target)
-    assert cc.ensure_persistent_compile_cache() == target
-    assert cc.active_cache_dir() == target
+    env_dir = str(tmp_path / "outside")
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "from_code"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    Accelerator()
+    assert jax.config.jax_compilation_cache_dir == env_dir
 
 
-def test_self_set_dir_not_misread_as_user_config(cache_state):
-    """After we enable the default dir, later no-arg calls must hit the
-    idempotent early-return, not re-classify our own dir as user config
-    (generate() calls this on every invocation, incl. from the AOT thread)."""
-    monkeypatch, _ = cache_state
-    cc._enabled_dir = None
-    first = cc.ensure_persistent_compile_cache()
-    assert first == cc._DEFAULT_DIR
-    assert cc.ensure_persistent_compile_cache() is first
+_PROBE = (
+    "import jax; from accelerate_tpu.utils.compile_cache import "
+    "ensure_persistent_compile_cache as ensure; "
+    "print('DIR=' + str(ensure()) + '|' + str(jax.config.jax_compilation_cache_dir))"
+)
+
+
+def test_same_fixed_dir_from_two_processes(tmp_path):
+    """The directory is part of what an entry is found by: with the variable
+    unset, two fresh processes started in different places must resolve the
+    same in-checkout one."""
+    env = dict(os.environ, PYTHONPATH=REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", _PROBE], env=env, cwd=str(cwd),
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        )
+        for cwd in (tmp_path, REPO)  # both at once: they share nothing but the answer
+    ]
+    seen = []
+    for proc in procs:
+        out, err = proc.communicate(timeout=300)
+        assert proc.returncode == 0, err[-2000:]
+        seen.append(out.strip().splitlines()[-1])
+    assert seen == [f"DIR={cc.REPO_CACHE_DIR}|{cc.REPO_CACHE_DIR}"] * 2

@@ -60,8 +60,7 @@ class TestDeclaredModuleSets:
         """The decode-kernel surfaces (ops + the serving engine) may pull
         jax but must defer pallas to first trace (the _LazyModule
         contract): pallas costs ~0.2 s at import — billed to every
-        worker's proc_startup_imports — and CPU-only jaxlib builds may
-        lack the TPU backend entirely."""
+        worker's proc_startup_imports."""
         hygiene = _declared()
         imports = "\n".join(f"import {m}" for m in hygiene.PALLAS_FREE_MODULES)
         _probe(

@@ -283,6 +283,34 @@ class TestDiskBlobIntegrity:
         assert store.disk_corrupt_dropped == 1
         assert not os.path.exists(blob)
 
+    def test_blob_of_the_earlier_int4_layout_is_dropped_at_startup(self, tmp_path):
+        """A disk directory that outlives the upgrade: a version-1 int4 blob
+        (even/odd nibble interleave) is intact by its own checksum and has
+        the dtype and shape of today's split-half payload, so only the wire
+        version keeps its scrambled nibbles out of the arena. The start-up
+        scan drops and counts it; a blob written today is kept."""
+        from accelerate_tpu.serving.tiers import (
+            BLOB_SUFFIX, KV_WIRE_VERSION, blob_checksum, entry_to_handoff,
+        )
+
+        disk_dir = tmp_path / "kv"
+        disk_dir.mkdir()
+        for name, version, toks in (("old", 1, np.arange(3, 19)),
+                                    ("new", KV_WIRE_VERSION, np.arange(40, 56))):
+            doc = entry_to_handoff(_store_entry(toks, dtype=np.int8), page_size=PS,
+                                   kv_cache_dtype="int4")
+            doc["version"] = version
+            doc["checksum"] = blob_checksum(doc)
+            (disk_dir / (name + BLOB_SUFFIX)).write_text(json.dumps(doc))
+        store = TieredStore(
+            TierConfig(disk_dir=str(disk_dir), host_entries=1, disk_entries=8),
+            page_size=PS, kv_cache_dtype="int4",
+        )
+        assert store.disk_corrupt_dropped == 1
+        assert sorted(os.listdir(disk_dir)) == ["new" + BLOB_SUFFIX]
+        assert store.probe(np.arange(3, 19)) is None
+        assert store.probe(np.arange(40, 56))["tier"] == "disk"
+
     def test_corrupt_blob_cold_fallback_end_to_end(self, served_model,
                                                    tmp_path, monkeypatch):
         """Engine-level: a corrupt blob must not crash or skew tokens —

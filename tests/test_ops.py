@@ -170,6 +170,41 @@ class TestFlashAttention:
         ref = mha_reference(q, k, v)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
+    def test_kernel_under_mesh_runs_per_shard(self):
+        """Under a multi-device mesh the kernel must sit inside a shard_map
+        over the batch and head axes: the chip's SPMD partitioner refuses to
+        split a Mosaic custom call ("cannot be automatically partitioned"),
+        which the CPU sim's reference path never showed. Forward and grads
+        match the unsharded reference (with a padding mask, so a [B, S]
+        companion is sharded too; the mask-free form is what
+        ``chip_smoke.py --cpu-rehearsal --chips 4`` trains with), and the
+        program really contains the shard_map."""
+        from accelerate_tpu.ops.attention import NEG_INF
+        from accelerate_tpu.parallel.context import dot_product_attention_sharded
+        from accelerate_tpu.parallel.mesh import build_mesh
+
+        mesh = build_mesh({"fsdp": 2, "tensor": 2}, devices=jax.devices()[:4])
+        q, k, v = _rand_qkv(jax.random.PRNGKey(11), b=2, h=4, kvh=2, s=256)
+        kv_mask = jnp.asarray(np.tile((np.arange(256) < 192).astype(np.int32), (2, 1)))
+        bias = jnp.where(kv_mask[:, None, None, :] != 0, 0.0, NEG_INF)
+
+        def loss_kernel(q, k, v):
+            out = dot_product_attention_sharded(
+                q, k, v, mesh, causal=True, kv_mask=kv_mask, interpret=True
+            )
+            return jnp.sum(out ** 2), out
+
+        def loss_ref(q, k, v):
+            out = mha_reference(q, k, v, causal=True, bias=bias)
+            return jnp.sum(out ** 2), out
+
+        assert "shard_map" in str(jax.make_jaxpr(loss_kernel)(q, k, v))
+        (_, out), gk = jax.jit(jax.value_and_grad(loss_kernel, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        (_, ref), gr = jax.value_and_grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        for a, b in zip(gk, gr):
+            np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
     @pytest.mark.parametrize("with_bias", [False, True])
     def test_xla_impl_honors_kv_mask(self, with_bias):
         # regression (advisor r4): impl="xla" used to early-return before the

@@ -159,15 +159,6 @@ class TestPipelineParity:
 
 
 class TestPreparePippy:
-    @pytest.mark.xfail(
-        strict=False,
-        reason="container jax-0.4.37: the SPMD partitioner silently "
-        "mis-lowers the GPipe belt when the mesh has BOTH stage>1 and "
-        "tensor>1 (stage-only/data-only/tensor-only and stage x data are "
-        "bit-exact; no warning logged). Environmental, not repo-side — "
-        "recorded in CHANGES.md PR 2 / tests/TIMINGS.md; passes on jax "
-        "builds without the mis-lowering, hence strict=False.",
-    )
     def test_pipelined_inference_matches_dense(self):
         from accelerate_tpu.inference import prepare_pippy
         from accelerate_tpu.parallel.sharding import unbox_params

@@ -266,6 +266,11 @@ class TestKvHandoff:
         bad = dict(handoff, page_size=PAGE * 2)
         with pytest.raises(ValueError, match="page_size"):
             b.import_prefix_kv(bad)
+        # a peer on the build before the int4 layout change: same schema,
+        # other bytes — the version is what tells them apart
+        bad = dict(handoff, version=1)
+        with pytest.raises(ValueError, match="version"):
+            b.import_prefix_kv(bad)
         bad = dict(handoff, kv_cache_dtype="int8")
         with pytest.raises(ValueError, match="kv_cache_dtype"):
             b.import_prefix_kv(bad)

@@ -113,7 +113,8 @@ class TestFlopsAccounting:
         v5p = types.SimpleNamespace(device_kind="TPU v5p")
         assert peak_flops(v5e) == 197e12
         assert peak_flops(v5p) == 459e12
-        assert peak_flops(types.SimpleNamespace(device_kind="cpu")) == 200e12
+        # unknown kind -> no peak (and so no MFU), never a made-up default
+        assert peak_flops(types.SimpleNamespace(device_kind="cpu")) is None
 
     def test_batch_token_count(self):
         ids = np.zeros((4, 16), np.int32)
@@ -784,7 +785,7 @@ class TestCostRegistry:
             self._flops, self._hbm, self._temp = flops, hbm, temp
 
         def cost_analysis(self):
-            return [{"flops": self._flops, "bytes accessed": self._hbm}]
+            return {"flops": self._flops, "bytes accessed": self._hbm}
 
         def memory_analysis(self):
             class MA:
@@ -848,7 +849,7 @@ class TestCostRegistry:
 
         assert peak_hbm_bw(types.SimpleNamespace(device_kind="TPU v5 lite")) == 819e9
         assert peak_hbm_bw(types.SimpleNamespace(device_kind="TPU v5p")) == 2.765e12
-        assert peak_hbm_bw(types.SimpleNamespace(device_kind="cpu")) == 819e9
+        assert peak_hbm_bw(types.SimpleNamespace(device_kind="cpu")) is None
 
 
 class TestDeviceMemoryStats:
@@ -1112,9 +1113,11 @@ class TestEngineIntegration:
         step(acc.prepare_for_eval({"input_ids": ids_v, "labels": ids_v}))
 
         values = acc.log_system_metrics()
-        for key in ("sys/step_time_s", "sys/tokens_per_s", "sys/mfu_pct",
+        for key in ("sys/step_time_s", "sys/tokens_per_s",
                     "sys/loss", "sys/grad_norm", "sys/step"):
             assert key in values, key
+        # the CPU has no entry in the peak table: no MFU against a made-up peak
+        assert "sys/mfu_pct" not in values
         assert values["sys/step"] == 4
         assert values["sys/tokens_per_s"] > 0
 
@@ -1147,7 +1150,8 @@ class TestEngineIntegration:
         assert [r["step"] for r in per_step] == [1, 2, 3, 4]
         for rec in per_step[:3]:
             assert rec["tokens"] == 8 * 16
-            assert "tokens_per_s" in rec and "mfu_pct" in rec and "wall_s" in rec
+            assert "tokens_per_s" in rec and "wall_s" in rec
+            assert "mfu_pct" not in rec  # no peak for the CPU, so no MFU
 
         # (b) the span file is a loadable Chrome trace with engine steps
         trace = spans_mod.load_chrome_trace(str(tel_dir / "trace-host0.jsonl"))
