@@ -1,0 +1,13 @@
+"""Paged decode attention against its memory bound (metriclib.decode_attn_roofline_pct)."""
+
+import metriclib
+
+LAYER = "kernels (ops/attention.py)"
+UNIT = "%"
+MOVES = "out_tokens_per_s"
+SOURCE = "device_trace"
+CELLS = ("mistral7b_serve_batch",)
+
+
+def read(trace, spans, counters, cell):
+    return metriclib.decode_attn_roofline_pct(trace, counters, cell)

@@ -1,0 +1,13 @@
+"""Median device duration of the decode program in the trace."""
+
+import metriclib
+
+LAYER = "model step (the engine's jitted programs over models/decoder.py)"
+UNIT = "ms"
+MOVES = "itl_p95_ms"
+SOURCE = "device_trace"
+CELLS = ("mistral7b_serve_chat_closed",)
+
+
+def read(trace, spans, counters, cell):
+    return metriclib.decode_step_device_ms(trace)
