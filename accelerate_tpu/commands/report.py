@@ -127,34 +127,12 @@ def load_costs(target: str) -> dict:
             else:
                 cur["wall_s"] = round(cur.get("wall_s", 0.0) + (row.get("wall_s") or 0.0), 4)
                 cur["calls"] = cur.get("calls", 0) + (row.get("calls") or 0)
-                # dynamic rows (per-call cost varies with runtime state —
-                # the paged decode kernel) merge by TOTALS, not by the
-                # first host's per-call average
-                for key in ("flops_total", "hbm_bytes_total"):
-                    if row.get(key) is not None or cur.get(key) is not None:
-                        cur[key] = (cur.get(key) or 0.0) + (row.get(key) or 0.0)
                 for k, v in row.items():
                     cur.setdefault(k, v)
     rows = sorted(merged.values(), key=lambda r: -(r.get("wall_s") or 0.0))
     # re-derive the utilization numbers over the merged wall
     pf, pb = peaks.get("peak_flops"), peaks.get("peak_hbm_bw")
     for row in rows:
-        if row.get("dynamic") and row.get("calls"):
-            for total, per_call in (("flops_total", "flops_per_call"),
-                                    ("hbm_bytes_total", "hbm_bytes_per_call")):
-                if row.get(total) is not None:
-                    row[per_call] = row[total] / row["calls"]
-            # AI / roofline class must come from the merged totals too, or
-            # the row would pair fleet-total throughput numbers with host
-            # 0's classification
-            if row.get("flops_total") and row.get("hbm_bytes_total"):
-                ai = row["flops_total"] / row["hbm_bytes_total"]
-                row["arith_intensity"] = round(ai, 4)
-                ridge = row.get("ridge_intensity") or peaks.get("ridge_intensity")
-                if ridge:
-                    row["roofline"] = (
-                        "compute-bound" if ai >= ridge else "memory-bound"
-                    )
         wall, calls = row.get("wall_s") or 0.0, row.get("calls") or 0
         if wall > 0 and calls > 0:
             if row.get("flops_per_call") and pf:

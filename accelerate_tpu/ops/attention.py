@@ -401,6 +401,7 @@ def _flash_fwd_call(q, k, v, masks, causal, sm_scale, bq, bk, interpret):
             jax.ShapeDtypeStruct((b, h, 8, sq), jnp.float32),
         ],
         scratch_shapes=[_vmem((bq, d)), _vmem((bq, 128)), _vmem((bq, 128))],
+        name="flash_attn_fwd",
         **_grid_params(interpret),
     )(q, k, v, *mask_arrays)
     return out, lse
@@ -436,6 +437,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, masks, causal, sm_scale, bq, bk, inte
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[_vmem((bq, d))],
+        name="flash_attn_dq",
         **_grid_params(interpret),
     )(q, k, v, do, lse, delta, *mask_arrays)
 
@@ -478,6 +480,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, masks, causal, sm_scale, bq, bk, inte
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[_vmem((bk, d)), _vmem((bk, d))],
+        name="flash_attn_dkv",
         **_grid_params(interpret),
     )(q, k, v, do, lse, delta, *mask_arrays)
     return dq, dk, dv
@@ -730,11 +733,10 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
 def decode_kernel_active(config, sq: int = 1) -> bool:
     """Would a paged decode dispatch of query width ``sq`` (1 = the plain
     decode step; spec_draft_len+1 = the verify program) on a model with
-    this config run the pallas kernel in this process? The serving engine
-    and bench use this to decide whether a dispatch bills the
-    ``paged_decode_kernel`` roofline row — it must mirror
-    :func:`paged_decode_attention`'s gate exactly, or the row would claim
-    bandwidth a fallback path never achieved."""
+    this config run the pallas kernel in this process? The serving engine's
+    ``serving/decode_kernel_active`` gauge and bench read it — it must
+    mirror :func:`paged_decode_attention`'s gate exactly, or the gauge
+    would claim a kernel a fallback path never ran."""
     page_size = getattr(config, "kv_page_size", None)
     if not page_size:
         return False
@@ -1252,9 +1254,8 @@ def prefill_kernel_active(config) -> bool:
     """Would a packed ragged prefill dispatch on a model with this config
     run the pallas kernel in this process? The serving engine's admission
     planner keys its SHAPE of work off this (packed ragged dispatch vs
-    per-slot bucket chunks) and bench/telemetry use it to decide whether
-    a dispatch bills the ``ragged_prefill_kernel`` roofline row — it must
-    mirror :func:`ragged_prefill_attention`'s gate exactly."""
+    per-slot bucket chunks) — it must mirror
+    :func:`ragged_prefill_attention`'s gate exactly."""
     page_size = getattr(config, "kv_page_size", None)
     if not page_size:
         return False
@@ -1508,6 +1509,7 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        name="ragged_prefill_attn",
         # token blocks revisit the quantize-on-write output windows, so
         # the grid's outer dim must stay sequential ("arbitrary")
         **_grid_params(interpret, ("arbitrary", "parallel", "arbitrary")),

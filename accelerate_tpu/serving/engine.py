@@ -43,6 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..generation import _sample, _sized_definition, depipeline
+from ..telemetry.spans import emit as _emit_span
+from ..telemetry.spans import span as _span
 from ..ops.attention import (
     _PREFILL_TOKEN_BLOCK,
     decode_kernel_active,
@@ -119,8 +121,10 @@ class Request:
     done: bool = False
     slot: Optional[int] = None
     submit_t: float = 0.0
+    admit_t: Optional[float] = None      # popped from the queue and given a slot
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
+    prefill_dispatches: int = 0          # prefill dispatches that carried its rows
     outcome: Optional[str] = None        # finished | shed | cancelled
     finish_reason: Optional[str] = None  # eos | budget | timeout | ...
     shed_reason: Optional[str] = None
@@ -361,33 +365,10 @@ class ServingEngine:
                 self._paged_def, params, self.num_slots, self.pages_per_slot,
                 self._placer,
             )
-            # paged decode-kernel cost model (CostRegistry dynamic row):
-            # the kernel's HBM read per step is the live page set, which
-            # XLA's static cost_analysis (operand sizes = the whole arena)
-            # cannot see — so the engine bills modeled live-page bytes and
-            # flops per dispatch from its host-side lengths instead.
-            from .pages import _is_kv
-
-            kv_leaves = [
-                l for l in jax.tree_util.tree_leaves(self._arena) if _is_kv(l)
-            ]
-            self._kv_token_bytes = sum(
-                int(l.size) * l.dtype.itemsize // (self.num_pages * self.page_size)
-                for l in kv_leaves
-            )
+            # whether the decode step rides the paged pallas kernel (the
+            # serving/decode_kernel_active gauge)
             pcfg = self._paged_def.config
-            # qk + pv matmuls per attended token per query row, all layers
-            self._kernel_flops_per_token = (
-                4 * pcfg.num_heads * pcfg.head_dim * pcfg.num_layers
-            )
             self._kernel_costed = decode_kernel_active(pcfg)
-            # the verify program dispatches at query width K+1, which may
-            # fail the kernel's Sq gate even when the plain decode step
-            # rides the kernel — a dense-fallback verify must not bill the
-            # kernel's roofline row
-            self._kernel_costed_verify = bool(self.spec_k) and decode_kernel_active(
-                pcfg, sq=self.spec_k + 1
-            )
             # packed ragged prefill (ops/attention.ragged_prefill_attention):
             # when the flash prefill kernel (or its interpreter) engages,
             # the admission planner packs every pending tail into ONE
@@ -437,7 +418,6 @@ class ServingEngine:
             self._drafter = None
             self._verify_step = None
             self._kernel_costed = False
-            self._kernel_costed_verify = False
             self._ragged_prefill = False
             self._ragged_bt = _PREFILL_TOKEN_BLOCK
             self._ragged_caps = ()
@@ -522,6 +502,8 @@ class ServingEngine:
         self._admit_state = jax.jit(_admit_state_fn)
 
         # metrics
+        self.iterations = 0  # scheduler iterations (calls of step() that had work)
+        self.pages_allocated = 0  # pages handed out by _alloc_page, lifetime
         self.step_count = 0
         self.requests_completed = 0
         self.requests_shed = 0
@@ -615,6 +597,7 @@ class ServingEngine:
         definition = self._paged_def
         last_pos = self.max_cache_len - 1
 
+        @jax.named_scope("spec_verify")
         def verify(params, arena, tokens, drafts, lengths, active, rngs, page_tables):
             n, k = drafts.shape
             seq = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [N, K+1]
@@ -782,7 +765,21 @@ class ServingEngine:
         ``warmup(); mark_steady()``, ``admission_recompiles`` staying 0 is
         deterministic, not a function of what traffic happened to arrive.
         All-inactive decode steps park their writes (see the step body), so
-        warmup leaves no observable state behind."""
+        warmup leaves no observable state behind. The whole of it is one
+        ``serving/warmup`` span, with how many programs were compiled or
+        loaded from the persistent cache (``programs``), how many of them
+        were compiled (``compiles``) and the compile seconds."""
+        with _span("serving/warmup") as sp:
+            mark = self._counters()
+            self._warmup()
+            now = self._counters()
+            programs = now["count"] - mark["count"]
+            sp.args["programs"] = programs
+            sp.args["compiles"] = programs - (now["cache_hits"] - mark["cache_hits"])
+            sp.args["compile_s"] = round(now["seconds"] - mark["seconds"], 3)
+        return self
+
+    def _warmup(self):
         if self._slot_req or self._queued_depth() or self._admitting is not None:
             raise RuntimeError("warmup() needs an idle engine")
         rng = jax.random.PRNGKey(0)
@@ -842,11 +839,6 @@ class ServingEngine:
             # the parking page; nothing observable), so a post-steady
             # eviction can demote into the host tier with zero recompiles
             jax.device_get(self._gather_page(self._arena, 0))
-            if self._kernel_costed and costs is not None:
-                # seed the kernel's dynamic roofline row at warmup so a
-                # rollup/report taken before traffic already lists the
-                # executable (wall/bytes accumulate per decode dispatch)
-                costs.note_dynamic("paged_decode_kernel", 0.0, calls=0)
             if self._ragged_prefill:
                 # the packed ragged-prefill programs, one per fixed grid
                 # capacity. All-pad warm args are safe: both kernel kv
@@ -879,11 +871,6 @@ class ServingEngine:
                                 ))
                         except Exception:
                             pass
-                if costs is not None:
-                    # the kernel's dynamic roofline row, billed from
-                    # host-side packed-token counts per dispatch
-                    costs.note_dynamic("ragged_prefill_kernel", 0.0,
-                                       calls=0)
         self._tokens, self._lengths, self._rngs = self._admit_state(
             self._tokens, self._lengths, self._rngs, 0, 0, 0, rng
         )
@@ -939,7 +926,6 @@ class ServingEngine:
         # the persistent compile cache the jit call above just populated,
         # so this costs a deserialize, not a second compile
         self.executable_memory_stats()
-        return self
 
     def audit_entrypoints(self) -> list:
         """Entry-point specs for the static program auditor
@@ -1186,17 +1172,45 @@ class ServingEngine:
         decisions (shed, preempt), advance prefill admission within the
         ITL-budget, then run one batched decode step over every active
         slot. Returns whether any work happened (False = fully idle)."""
-        if self._faults is not None:
-            self._faults.on_step(self)
-        if self._draining and self._queued_depth():
-            # request_drain() only sets the flag (it may fire from a
-            # signal handler); the queue shed always runs here, on the
-            # loop thread
-            self._shed_queue_for_drain()
-        progressed = self._reap()
+        if self._faults is None and not self._pending():
+            # an idle poll (serve() between requests) does nothing below and
+            # records no span: a thousand of them a second would wash the
+            # last busy iterations out of the span ring
+            return False
+        with _span("serving/step") as sp:
+            emitted0 = self.generated_tokens
+            progressed = self._step_phases()
+            self.iterations += 1
+            args = sp.args
+            args["iteration"] = self.iterations
+            args["queued"] = self._queued_depth()
+            args["live"] = len(self._slot_req)
+            if self.page_size:
+                args["pages_in_use"] = self._allocator.in_use
+                args["pages_free"] = self._allocator.free_count
+            args["emitted"] = self.generated_tokens - emitted0
+        return progressed
+
+    def _step_phases(self) -> bool:
+        """The iteration's phases, each under its own ``serving/`` span
+        (docs/telemetry.md lists them with their counts)."""
+        with _span("serving/reap") as sp:
+            gone0 = (self.requests_cancelled, self.requests_shed, self.preemptions)
+            if self._faults is not None:
+                self._faults.on_step(self)
+            if self._draining and self._queued_depth():
+                # request_drain() only sets the flag (it may fire from a
+                # signal handler); the queue shed always runs here, on the
+                # loop thread
+                self._shed_queue_for_drain()
+            progressed = self._reap()
+            if self._sched is not None:
+                progressed = self._shed_on_pressure() or progressed
+                progressed = self._maybe_preempt() or progressed
+            sp.args["reaped"] = self.requests_cancelled - gone0[0]
+            sp.args["shed"] = self.requests_shed - gone0[1]
+            sp.args["preempted"] = self.preemptions - gone0[2]
         if self._sched is not None:
-            progressed = self._shed_on_pressure() or progressed
-            progressed = self._maybe_preempt() or progressed
             budget = (
                 self._controller.budget if self._controller is not None
                 else self._sched.config.prefill_budget
@@ -1634,6 +1648,7 @@ class ServingEngine:
                 f"{len(self._slot_req)} live slots): raise num_pages or "
                 "lower num_slots/max_new_tokens for this overcommit ratio"
             )
+        self.pages_allocated += 1
         return page
 
     def _ensure_writable(self, req, slot: int, lo_pos: int, hi_pos: int):
@@ -2149,8 +2164,84 @@ class ServingEngine:
             [req.prompt, np.asarray(req.tokens[:-1], np.int32)]
         )
 
+    def _note_admission(self, req: Request, slot: int, tr):
+        """``req`` left the queue for ``slot``: its ``admit_t`` stamp, the
+        one ``serving/queue_wait`` span and the tracer's record."""
+        now = time.perf_counter()
+        req.admit_t = now
+        wait = now - req.submit_t
+        _emit_span("serving/queue_wait", req.submit_t, wait,
+                   {"request_id": req.id, "slot": slot}, cat="serving")
+        if tr is not None:
+            tr.on_admission(req, slot, wait)
+
+    def _note_prefill_chunk(self, req: Request, slot: int, start: int,
+                            rows: int, t0: float, wall: float, tr):
+        """One prefill dispatch carried ``rows`` of ``req`` from ``start``:
+        the request's count, the one ``serving/prefill_chunk`` span (the
+        dispatch's start and wall, shared by every request it packed) and
+        the tracer's record."""
+        req.prefill_dispatches += 1
+        _emit_span("serving/prefill_chunk", t0, wall,
+                   {"request_id": req.id, "slot": slot, "start": start,
+                    "bucket": rows}, cat="serving")
+        if tr is not None:
+            tr.on_prefill_chunk(req, slot, start, rows, wall)
+
+    def _note_first_token(self, req: Request, now: float, tr):
+        req.first_token_t = now
+        ttft = now - req.submit_t
+        _emit_span("serving/first_token", req.submit_t, ttft,
+                   {"request_id": req.id, "prompt_len": int(req.prompt.size),
+                    "prefix_hit": int(req.prefix_hit),
+                    "dispatches": req.prefill_dispatches,
+                    "queue_wait_ms": round(1e3 * (req.admit_t - req.submit_t), 3)},
+                   cat="serving")
+        if tr is not None:
+            tr.on_first_token(req, ttft)
+
     def _advance_admission(self) -> bool:
+        """One admission dispatch: plan and pack on the host, dispatch the
+        prefill program, fetch the first tokens, commit them."""
         tr = self._tracer()
+        with _span("serving/admit_plan"):
+            work = self._plan_dispatch(tr)
+        if isinstance(work, bool):
+            return work  # nothing to admit, or progress without a dispatch
+        if self._ragged_prefill:
+            return self._ragged_dispatch(tr, *work)
+        return self._dense_dispatch(tr, *work)
+
+    def _admission_writable(self, req: Request, slot: int, lo: int, hi: int) -> bool:
+        """Pages for the admission's next write range. Same ladder as
+        live-slot growth (_grow_or_resolve): LRU eviction already failed
+        inside _ensure_writable, so try paging out a strictly
+        lower-priority victim before giving up — shedding the admission
+        first would drop the highest-priority work under pressure. Only
+        when no victim qualifies is the admission shed (never a raise out
+        of step()); False then."""
+        try:
+            self._ensure_writable(req, slot, lo, hi)
+            return True
+        except PagePressure:
+            if self._relieve_pressure(req, slot):
+                try:
+                    self._ensure_writable(req, slot, lo, hi)
+                    return True
+                except PagePressure:
+                    pass
+        self._abort_admission(time.perf_counter(), "shed", SHED_PAGE_EXHAUSTED)
+        flight = getattr(self.telemetry, "flight", None)
+        if flight is not None:
+            flight.note("request_shed", request_id=req.id,
+                        reason=SHED_PAGE_EXHAUSTED)
+        return False
+
+    def _plan_dispatch(self, tr):
+        """Everything of an admission step that comes before the prefill
+        program is called: pop the next request, plan its pages, pack the
+        rows on the host and upload them. Returns the dispatch's arguments,
+        or a bool where there is none (False: nothing to admit)."""
         if self._admitting is None:
             if not self._free:
                 return False
@@ -2183,11 +2274,11 @@ class ServingEngine:
             else:
                 plan = self._plan_chunks(seq.size)
             self._admitting = [req, slot, plan, 0, prefill_rng, decode_rng, seq]
-            if tr is not None:
-                if req._resume is not None:
+            if req._resume is not None:
+                if tr is not None:
                     tr.on_resume(req, slot)
-                else:
-                    tr.on_admission(req, slot, time.perf_counter() - req.submit_t)
+            else:
+                self._note_admission(req, slot, tr)
         req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
         if plan is None:
             # restore in flight: one page batch per scheduler iteration,
@@ -2198,7 +2289,7 @@ class ServingEngine:
             # flash prefill kernel engaged: one packed ragged dispatch
             # replaces this iteration's bucket chunk (and may co-admit
             # further queued tails into the same grid)
-            return self._ragged_advance(tr)
+            return self._ragged_pack(tr)
         start, bucket = plan[idx]
         chunk = np.zeros((1, bucket), np.int32)
         seg = seq[start:start + bucket]
@@ -2208,110 +2299,96 @@ class ServingEngine:
         self._note_forensics(f"prefill_{bucket}", {"chunk_ids": chunk_dev})
         if self._faults is not None:
             self._faults.before_prefill(self)
-        t0 = time.perf_counter()
-        if self.page_size:
-            try:
-                self._ensure_writable(req, slot, start, start + bucket - 1)
-            except PagePressure:
-                # same ladder as live-slot growth (_grow_or_resolve): LRU
-                # eviction already failed inside _ensure_writable, so try
-                # paging out a strictly lower-priority victim before
-                # giving up — shedding the admission first would drop the
-                # highest-priority work under pressure. Only when no
-                # victim qualifies is the admission shed (never a raise
-                # out of step())
-                resolved = self._relieve_pressure(req, slot)
-                if resolved:
-                    try:
-                        self._ensure_writable(req, slot, start, start + bucket - 1)
-                    except PagePressure:
-                        resolved = False
-                if not resolved:
-                    self._abort_admission(
-                        time.perf_counter(), "shed", SHED_PAGE_EXHAUSTED
-                    )
-                    flight = getattr(self.telemetry, "flight", None)
-                    if flight is not None:
-                        flight.note("request_shed", request_id=req.id,
-                                    reason=SHED_PAGE_EXHAUSTED)
-                    return True
-            self._arena, first = self._prefill_fn(bucket)(
-                self.params, self._arena, chunk_dev, slot, start, last_idx,
-                prefill_rng, page_tables=self._page_tables,
-            )
-        else:
-            self._arena, first = self._prefill_fn(bucket)(
-                self.params, self._arena, chunk_dev, slot, start, last_idx,
-                prefill_rng,
-            )
-        wall = time.perf_counter() - t0
-        if tr is not None:
-            tr.on_prefill_chunk(req, slot, start, bucket, t0, wall)
-        if self.telemetry is not None and getattr(self.telemetry, "costs", None) is not None:
-            self.telemetry.costs.note_wall(f"prefill_{bucket}", wall)
-        usage = self._usage()
-        if usage is not None:
-            # actual tokens this chunk prefilled (padding excluded) plus
-            # the dispatch wall, billed to the admitting tenant
-            usage.note_prefill(req.tenant, int(seg.size))
-            usage.note_compute(req.tenant, wall * 1e3)
-        # pad-waste accounting, comparable with the ragged path: the
-        # bucket is the dispatched row count, the segment is what's live
-        self._prefill_rows_dispatched += bucket
-        self._prefill_tokens_dispatched += int(seg.size)
-        idx += 1
-        if idx < len(plan):
-            self._admitting[3] = idx
+        if self.page_size and not self._admission_writable(
+            req, slot, start, start + bucket - 1
+        ):
             return True
-        # final chunk done -> the slot goes live with its first token
-        self._admitting = None
+        return start, bucket, int(seg.size), last_idx, chunk_dev
+
+    def _dense_dispatch(self, tr, start: int, bucket: int, live: int,
+                        last_idx: int, chunk_dev) -> bool:
+        """One bucketed chunk of the admission singleton (the fallback and
+        bit-exactness oracle of the ragged path)."""
+        req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
+        pk = {"page_tables": self._page_tables} if self.page_size else {}
+        with _span("serving/prefill_dispatch", rows=bucket, tokens=live,
+                   requests=1) as sp:
+            self._arena, first = self._prefill_fn(bucket)(
+                self.params, self._arena, chunk_dev, slot, start, last_idx,
+                prefill_rng, **pk,
+            )
+        final = idx + 1 == len(plan)
         resume = req._resume is not None
-        if self.page_size and not resume:
-            self._insert_prefix(req, slot)
-        if resume:
-            # the replayed slot continues where it was paged out: last
-            # emitted token, restored chain, no new emission
-            first_tok = int(req.tokens[-1])
-            length = int(seq.size)
-            req._resume = None
-            self.resumptions += 1
-        else:
-            first_tok = int(jax.device_get(first))
-            length = int(req.prompt.size)
-        self._tokens, self._lengths, self._rngs = self._admit_state(
-            self._tokens, self._lengths, self._rngs, slot, first_tok, length,
-            decode_rng,
-        )
-        req.slot = slot
-        req.prefill_kernel = "dense"
-        self._slot_req[slot] = req
-        self._active[slot] = True
-        if resume:
-            # the paged-out + requeued + replay wait is scheduling latency
-            # (the record's preemptions field owns it), not an inter-token
-            # gap: clearing the reference clock makes the first post-resume
-            # token gap-less, so one preemption cannot fake an ITL-p99
-            # breach and trip the AIMD controller into cutting the budget
-            req._last_token_t = 0.0
-            return True
-        now = time.perf_counter()
-        req.first_token_t = now
-        if tr is not None:
-            tr.on_first_token(req, now - req.submit_t)
-        # _last_token_t stays 0.0 until _emit sets it: the first token has
-        # no preceding token, so it must not record a spurious 0.0 ITL gap
-        self._emit(req, first_tok, now)
+        if final and not resume:
+            with _span("serving/prefill_fetch"):
+                first_tok = int(jax.device_get(first))
+        with _span("serving/prefill_commit") as sp_c:
+            wall = sp.t1 - sp.t0
+            self._note_prefill_chunk(req, slot, start, bucket, sp.t0, wall, tr)
+            if self.telemetry is not None and getattr(self.telemetry, "costs", None) is not None:
+                self.telemetry.costs.note_wall(f"prefill_{bucket}", wall)
+            usage = self._usage()
+            if usage is not None:
+                # actual tokens this chunk prefilled (padding excluded) plus
+                # the dispatch wall, billed to the admitting tenant
+                usage.note_prefill(req.tenant, live)
+                usage.note_compute(req.tenant, wall * 1e3)
+            # pad-waste accounting, comparable with the ragged path: the
+            # bucket is the dispatched row count, the segment is what's live
+            self._prefill_rows_dispatched += bucket
+            self._prefill_tokens_dispatched += live
+            sp_c.args["first_tokens"] = 0
+            if not final:
+                self._admitting[3] = idx + 1
+                return True
+            # final chunk done -> the slot goes live with its first token
+            self._admitting = None
+            if self.page_size and not resume:
+                self._insert_prefix(req, slot)
+            if resume:
+                # the replayed slot continues where it was paged out: last
+                # emitted token, restored chain, no new emission
+                first_tok = int(req.tokens[-1])
+                length = int(seq.size)
+                req._resume = None
+                self.resumptions += 1
+            else:
+                length = int(req.prompt.size)
+            self._tokens, self._lengths, self._rngs = self._admit_state(
+                self._tokens, self._lengths, self._rngs, slot, first_tok, length,
+                decode_rng,
+            )
+            req.slot = slot
+            req.prefill_kernel = "dense"
+            self._slot_req[slot] = req
+            self._active[slot] = True
+            if resume:
+                # the paged-out + requeued + replay wait is scheduling latency
+                # (the record's preemptions field owns it), not an inter-token
+                # gap: clearing the reference clock makes the first post-resume
+                # token gap-less, so one preemption cannot fake an ITL-p99
+                # breach and trip the AIMD controller into cutting the budget
+                req._last_token_t = 0.0
+                return True
+            now = time.perf_counter()
+            self._note_first_token(req, now, tr)
+            # _last_token_t stays 0.0 until _emit sets it: the first token has
+            # no preceding token, so it must not record a spurious 0.0 ITL gap
+            self._emit(req, first_tok, now)
+            sp_c.args["first_tokens"] = 1
         return True
 
-    def _ragged_advance(self, tr) -> bool:
-        """One packed ragged-prefill dispatch: the primary admission's
-        next tail segment plus — when capacity remains — the WHOLE tails
-        of further queued requests, packed token-block-aligned into the
-        smallest compiled grid capacity that fits. Replaces the per-slot
-        bucket chunks of the dense path (which stays compiled as the
-        fallback and bit-exactness oracle); preserves the interleave
+    def _ragged_pack(self, tr):
+        """The host side of one packed ragged-prefill dispatch: the primary
+        admission's next tail segment plus — when capacity remains — the
+        WHOLE tails of further queued requests, packed token-block-aligned
+        into the smallest compiled grid capacity that fits. Replaces the
+        per-slot bucket chunks of the dense path (which stays compiled as
+        the fallback and bit-exactness oracle); preserves the interleave
         discipline (one dispatch per scheduler iteration) and the
-        zero-recompile invariant (grid capacities fixed at warmup)."""
+        zero-recompile invariant (grid capacities fixed at warmup).
+        Returns ``_ragged_dispatch``'s arguments, or True where the
+        admission was shed for pages."""
         req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
         bt = self._ragged_bt
         cap_max = self._ragged_caps[-1]
@@ -2323,26 +2400,8 @@ class ServingEngine:
         n = min(seq.size - cur, cap_max)
         if self._faults is not None:
             self._faults.before_prefill(self)
-        try:
-            self._ensure_writable(req, slot, cur, cur + n - 1)
-        except PagePressure:
-            # same ladder as the chunked dispatch: page out a strictly
-            # lower-priority victim before shedding the admission
-            resolved = self._relieve_pressure(req, slot)
-            if resolved:
-                try:
-                    self._ensure_writable(req, slot, cur, cur + n - 1)
-                except PagePressure:
-                    resolved = False
-            if not resolved:
-                self._abort_admission(
-                    time.perf_counter(), "shed", SHED_PAGE_EXHAUSTED
-                )
-                flight = getattr(self.telemetry, "flight", None)
-                if flight is not None:
-                    flight.note("request_shed", request_id=req.id,
-                                reason=SHED_PAGE_EXHAUSTED)
-                return True
+        if not self._admission_writable(req, slot, cur, cur + n - 1):
+            return True
         # packs: [request, slot, s0, s1, prefill_rng, decode_rng, seq,
         # primary]. The primary may be mid-tail (longer than the largest
         # grid); co-admitted tails are always whole, so every co-admit
@@ -2388,10 +2447,7 @@ class ServingEngine:
                         nxt.prefix_hit = 0
                     self._queue.appendleft(nxt)
                     break
-                if tr is not None:
-                    tr.on_admission(
-                        nxt, slot2, time.perf_counter() - nxt.submit_t
-                    )
+                self._note_admission(nxt, slot2, tr)
                 packs.append([nxt, slot2, hit2, hit2 + n2, p_rng, d_rng,
                               nxt.prompt, False])
                 used += -(-n2 // bt) * bt
@@ -2402,8 +2458,7 @@ class ServingEngine:
         hist = np.zeros((self.num_slots,), np.int32)
         last_rows = np.zeros((self.num_slots,), np.int32)
         rngs = np.zeros((self.num_slots, 2), np.uint32)
-        fresh = attended = read_tok = 0
-        ps = self.page_size
+        fresh = 0
         r = 0
         for preq, psl, s0, s1, prng, _, pseq, _ in packs:
             nseg = s1 - s0
@@ -2418,85 +2473,83 @@ class ServingEngine:
             last_rows[psl] = r + nseg - 1
             rngs[psl] = np.asarray(jax.device_get(prng), np.uint32)
             r += nb * bt
-            # host-side roofline counts for the dynamic cost row: causal
-            # qk pairs actually attended, and kv tokens streamed (each
-            # token block walks the slot's prefix pages plus the packed
-            # fresh blocks at or below it)
             fresh += nseg
-            attended += (s1 * (s1 + 1) - s0 * (s0 + 1)) // 2
-            read_tok += nb * (-(-s0 // ps) * ps) + bt * nb * (nb + 1) // 2
         ids_dev = jnp.asarray(ids)
         self._note_forensics(f"ragged_prefill_{rcap}", {"ids": ids_dev})
-        t0 = time.perf_counter()
-        self._arena, firsts = self._ragged_prefill_fn(rcap)(
-            self.params, self._arena, ids_dev, jnp.asarray(row_slot),
-            jnp.asarray(row_pos), jnp.asarray(hist), self._page_tables,
-            jnp.asarray(last_rows), jnp.asarray(rngs),
-        )
-        firsts_h = np.asarray(jax.device_get(firsts))
-        wall = time.perf_counter() - t0
-        costs = (getattr(self.telemetry, "costs", None)
-                 if self.telemetry is not None else None)
-        if costs is not None:
-            costs.note_wall(f"ragged_prefill_{rcap}", wall)
-            costs.note_dynamic(
-                "ragged_prefill_kernel", wall,
-                flops=float(self._kernel_flops_per_token * attended),
-                hbm_bytes=float(self._kv_token_bytes * (read_tok + fresh)),
-                calls=1,
+        return (packs, rcap, fresh, ids_dev, jnp.asarray(row_slot),
+                jnp.asarray(row_pos), jnp.asarray(hist), jnp.asarray(last_rows),
+                jnp.asarray(rngs))
+
+    def _ragged_dispatch(self, tr, packs: list, rcap: int, fresh: int, ids_dev,
+                         row_slot, row_pos, hist, last_rows, rngs) -> bool:
+        """Dispatch one packed grid, fetch its first tokens, and put every
+        pack that completed into its slot."""
+        with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
+                   requests=len(packs)) as sp:
+            self._arena, firsts = self._ragged_prefill_fn(rcap)(
+                self.params, self._arena, ids_dev, row_slot, row_pos, hist,
+                self._page_tables, last_rows, rngs,
             )
-        usage = self._usage()
-        self.prefill_packed_tokens += fresh
-        self._prefill_tokens_dispatched += fresh
-        self._prefill_rows_dispatched += rcap
-        now = time.perf_counter()
-        for preq, psl, s0, s1, prng, drng, pseq, primary in packs:
-            if tr is not None:
-                tr.on_prefill_chunk(preq, psl, s0, s1 - s0, t0, wall)
-            if usage is not None:
-                usage.note_prefill(preq.tenant, s1 - s0)
-                # the shared dispatch wall is billed proportionally to
-                # each tenant's live tokens in the pack
-                usage.note_compute(
-                    preq.tenant, wall * 1e3 * (s1 - s0) / max(fresh, 1)
+        with _span("serving/prefill_fetch") as sp_f:
+            firsts_h = np.asarray(jax.device_get(firsts))
+        t0, wall = sp.t0, sp_f.t1 - sp.t0
+        with _span("serving/prefill_commit") as sp_c:
+            costs = (getattr(self.telemetry, "costs", None)
+                     if self.telemetry is not None else None)
+            if costs is not None:
+                costs.note_wall(f"ragged_prefill_{rcap}", wall)
+            usage = self._usage()
+            self.prefill_packed_tokens += fresh
+            self._prefill_tokens_dispatched += fresh
+            self._prefill_rows_dispatched += rcap
+            now = time.perf_counter()
+            first_tokens = 0
+            for preq, psl, s0, s1, prng, drng, pseq, primary in packs:
+                self._note_prefill_chunk(preq, psl, s0, s1 - s0, t0, wall, tr)
+                if usage is not None:
+                    usage.note_prefill(preq.tenant, s1 - s0)
+                    # the shared dispatch wall is billed proportionally to
+                    # each tenant's live tokens in the pack
+                    usage.note_compute(
+                        preq.tenant, wall * 1e3 * (s1 - s0) / max(fresh, 1)
+                    )
+                if primary and s1 < pseq.size:
+                    # mid-tail: the primary stays the admission singleton
+                    # and resumes at position s1 next scheduler iteration
+                    # (a mid-tail primary fills the whole grid, so it never
+                    # coexists with co-admits)
+                    self._admitting[3] = s1
+                    continue
+                if primary:
+                    self._admitting = None
+                resume = preq._resume is not None
+                if not resume:
+                    self._insert_prefix(preq, psl)
+                if resume:
+                    # the replayed slot continues where it was paged out:
+                    # last emitted token, restored chain, no new emission
+                    first_tok = int(preq.tokens[-1])
+                    length = int(pseq.size)
+                    preq._resume = None
+                    self.resumptions += 1
+                else:
+                    first_tok = int(firsts_h[psl])
+                    length = int(preq.prompt.size)
+                self._tokens, self._lengths, self._rngs = self._admit_state(
+                    self._tokens, self._lengths, self._rngs, psl, first_tok,
+                    length, drng,
                 )
-            if primary and s1 < pseq.size:
-                # mid-tail: the primary stays the admission singleton
-                # and resumes at position s1 next scheduler iteration
-                # (a mid-tail primary fills the whole grid, so it never
-                # coexists with co-admits)
-                self._admitting[3] = s1
-                continue
-            if primary:
-                self._admitting = None
-            resume = preq._resume is not None
-            if not resume:
-                self._insert_prefix(preq, psl)
-            if resume:
-                # the replayed slot continues where it was paged out:
-                # last emitted token, restored chain, no new emission
-                first_tok = int(preq.tokens[-1])
-                length = int(pseq.size)
-                preq._resume = None
-                self.resumptions += 1
-            else:
-                first_tok = int(firsts_h[psl])
-                length = int(preq.prompt.size)
-            self._tokens, self._lengths, self._rngs = self._admit_state(
-                self._tokens, self._lengths, self._rngs, psl, first_tok,
-                length, drng,
-            )
-            preq.slot = psl
-            preq.prefill_kernel = "ragged"
-            self._slot_req[psl] = preq
-            self._active[psl] = True
-            if resume:
-                preq._last_token_t = 0.0
-                continue
-            preq.first_token_t = now
-            if tr is not None:
-                tr.on_first_token(preq, now - preq.submit_t)
-            self._emit(preq, first_tok, now)
+                preq.slot = psl
+                preq.prefill_kernel = "ragged"
+                self._slot_req[psl] = preq
+                self._active[psl] = True
+                if resume:
+                    preq._last_token_t = 0.0
+                    continue
+                self._note_first_token(preq, now, tr)
+                self._emit(preq, first_tok, now)
+                first_tokens += 1
+            sp_c.args["first_tokens"] = first_tokens
         return True
 
     def _burst_len(self) -> int:
@@ -2517,25 +2570,11 @@ class ServingEngine:
         decode step writes the PREVIOUS token before sampling the next)."""
         return req.prompt.size + len(req.tokens) - 1
 
-    def _kernel_step_cost(self, steps: int, width: int, extra: int = 0) -> dict:
-        """Modeled cost of the paged decode kernel for ``steps`` fused
-        dispatches of query width ``width`` over the current live slots
-        (``extra`` = additional positions written past the frontier this
-        round: k-1 for a burst, K for a verify). Token count is page-
-        rounded per slot — exactly the pages the kernel walks — so the
-        roofline row's achieved bytes/s tracks LIVE tokens, while the
-        static ``decode_step`` row keeps billing the arena-shaped program
-        (the gap between the two is the kernel's win, made attributable)."""
+    def _walked_tokens(self, pos: int) -> int:
+        """Tokens the paged decode kernel walks for a slot whose last write
+        of this round lands at ``pos``: whole pages up to that one."""
         ps = self.page_size
-        toks = 0
-        for req in self._slot_req.values():
-            pos = self._next_write_pos(req) + extra
-            toks += (pos // ps + 1) * ps
-        return {
-            "flops": float(self._kernel_flops_per_token * toks * steps * width),
-            "hbm_bytes": float(self._kv_token_bytes * toks * steps),
-            "calls": steps,
-        }
+        return (pos // ps + 1) * ps
 
     def _spec_verify_once(self) -> bool:
         """One speculative round: host drafter proposes K tokens per slot,
@@ -2549,68 +2588,71 @@ class ServingEngine:
         # tokens, so build just the context tail — rebuilding the full
         # prompt+generation history every round is O(T^2) over a generation
         lb = int(getattr(self._drafter, "lookback", 0) or 0)
-        for slot, req in list(self._slot_req.items()):
-            if slot not in self._slot_req:
-                continue  # shed/preempted while relieving another slot
-            gen = np.asarray(req.tokens[-lb:] if lb else req.tokens, np.int32)
-            if lb and gen.size >= lb:
-                ctx = gen
-            else:
-                head = req.prompt[-(lb - gen.size):] if lb else req.prompt
-                ctx = np.concatenate([np.asarray(head, np.int32), gen])
-            drafts[slot] = self._drafter.propose(ctx, k)
-            pos = self._next_write_pos(req)
-            if not self._grow_or_resolve(req, slot, pos, pos + k):
-                continue
-        if not self._slot_req:
-            return True  # every live slot was shed under page pressure
-        kernel_cost = (
-            self._kernel_step_cost(1, k + 1, extra=k)
-            if self._kernel_costed_verify else None
-        )
-        drafts_dev = jnp.asarray(drafts)
-        self._note_forensics(
-            "spec_verify",
-            {"tokens": self._tokens, "drafts": drafts_dev,
-             "lengths": self._lengths, "active": self._active,
-             "rngs": self._rngs},
-        )
-        t0 = time.perf_counter()
-        (self._arena, self._tokens, self._lengths, self._rngs, cand, m) = (
-            self._verify_step(
-                self.params, self._arena, self._tokens, drafts_dev,
-                self._lengths, self._active, self._rngs, self._page_tables,
+        with _span("serving/decode_grow") as sp:
+            pages0 = self.pages_allocated
+            walked = 0
+            for slot, req in list(self._slot_req.items()):
+                if slot not in self._slot_req:
+                    continue  # shed/preempted while relieving another slot
+                gen = np.asarray(req.tokens[-lb:] if lb else req.tokens, np.int32)
+                if lb and gen.size >= lb:
+                    ctx = gen
+                else:
+                    head = req.prompt[-(lb - gen.size):] if lb else req.prompt
+                    ctx = np.concatenate([np.asarray(head, np.int32), gen])
+                drafts[slot] = self._drafter.propose(ctx, k)
+                pos = self._next_write_pos(req)
+                if self._grow_or_resolve(req, slot, pos, pos + k):
+                    walked += self._walked_tokens(pos + k)
+            sp.args["pages_allocated"] = self.pages_allocated - pages0
+            sp.args["walked_tokens"] = walked
+            if not self._slot_req:
+                return True  # every live slot was shed under page pressure
+            drafts_dev = jnp.asarray(drafts)
+            self._note_forensics(
+                "spec_verify",
+                {"tokens": self._tokens, "drafts": drafts_dev,
+                 "lengths": self._lengths, "active": self._active,
+                 "rngs": self._rngs},
             )
-        )
-        cand_h = np.asarray(jax.device_get(cand))  # [N, K+1]; forces the step
-        m_h = np.asarray(jax.device_get(m))
-        now = time.perf_counter()
-        wall = now - t0
-        self.step_count += 1
-        self._usage_note_step(wall)
-        emitted = 0
-        for slot, req in list(self._slot_req.items()):
-            accepted = int(m_h[slot])
-            n_emit = accepted + 1
-            req.spec_proposed += k
-            req.spec_accepted += accepted
-            self.spec_proposed += k
-            self.spec_accepted += accepted
-            for i in range(n_emit):
-                # amortize the verify wall across this slot's emitted run
-                # (same reasoning as the fused-burst ITL amortization)
-                self._emit(req, int(cand_h[slot, i]), t0 + wall * (i + 1) / n_emit)
-                emitted += 1
-                if req.done:
-                    break  # budget/eos hit mid-run: drop the rest
-        self._step_samples.append((wall, emitted, 1))
-        if self.telemetry is not None:
-            self.telemetry.on_step(self, wall, tokens=emitted, steps=1)
-            costs = getattr(self.telemetry, "costs", None)
-            if costs is not None:
-                costs.note_wall("spec_verify", wall)
-                if kernel_cost is not None:
-                    costs.note_dynamic("paged_decode_kernel", wall, **kernel_cost)
+        with _span("serving/decode_dispatch", slots=len(self._slot_req)) as sp_d:
+            (self._arena, self._tokens, self._lengths, self._rngs, cand, m) = (
+                self._verify_step(
+                    self.params, self._arena, self._tokens, drafts_dev,
+                    self._lengths, self._active, self._rngs, self._page_tables,
+                )
+            )
+        with _span("serving/token_fetch") as sp_f:
+            cand_h = np.asarray(jax.device_get(cand))  # [N, K+1]; forces the step
+            m_h = np.asarray(jax.device_get(m))
+        t0, wall = sp_d.t0, sp_f.t1 - sp_d.t0
+        with _span("serving/emit") as sp_e:
+            done0 = self.requests_completed
+            self.step_count += 1
+            self._usage_note_step(wall)
+            emitted = 0
+            for slot, req in list(self._slot_req.items()):
+                accepted = int(m_h[slot])
+                n_emit = accepted + 1
+                req.spec_proposed += k
+                req.spec_accepted += accepted
+                self.spec_proposed += k
+                self.spec_accepted += accepted
+                for i in range(n_emit):
+                    # amortize the verify wall across this slot's emitted run
+                    # (same reasoning as the fused-burst ITL amortization)
+                    self._emit(req, int(cand_h[slot, i]), t0 + wall * (i + 1) / n_emit)
+                    emitted += 1
+                    if req.done:
+                        break  # budget/eos hit mid-run: drop the rest
+            self._step_samples.append((wall, emitted, 1))
+            if self.telemetry is not None:
+                self.telemetry.on_step(self, wall, tokens=emitted, steps=1)
+                costs = getattr(self.telemetry, "costs", None)
+                if costs is not None:
+                    costs.note_wall("spec_verify", wall)
+            sp_e.args["emitted"] = emitted
+            sp_e.args["finished"] = self.requests_completed - done0
         return True
 
     def _grow_or_resolve(self, req: Request, slot: int, lo: int, hi: int) -> bool:
@@ -2641,72 +2683,79 @@ class ServingEngine:
             return self._spec_verify_once()
         k = self._burst_len()
         if self.page_size:
-            for slot, req in list(self._slot_req.items()):
-                if slot not in self._slot_req:
-                    continue  # shed/preempted while relieving another slot
-                pos = self._next_write_pos(req)
-                self._grow_or_resolve(req, slot, pos, pos + k - 1)
+            with _span("serving/decode_grow") as sp:
+                pages0 = self.pages_allocated
+                # page-rounded tokens the decode kernel walks this round,
+                # counted as each slot is grown (a slot preempted later in
+                # this same loop, for another's pages, stays counted)
+                walked = 0
+                for slot, req in list(self._slot_req.items()):
+                    if slot not in self._slot_req:
+                        continue  # shed/preempted while relieving another slot
+                    pos = self._next_write_pos(req)
+                    if self._grow_or_resolve(req, slot, pos, pos + k - 1):
+                        walked += self._walked_tokens(pos + k - 1)
+                sp.args["pages_allocated"] = self.pages_allocated - pages0
+                sp.args["walked_tokens"] = walked
             if not self._slot_req:
                 return True  # every live slot was shed under page pressure
         if self._faults is not None:
             self._faults.before_decode(self)
-        # snapshot BEFORE dispatch/emission: finished requests leave
-        # _slot_req during _emit, but their pages were walked this round
-        kernel_cost = (
-            self._kernel_step_cost(k, 1, extra=k - 1)
-            if self._kernel_costed else None
-        )
         self._note_forensics(
             "decode_step" if k == 1 else f"decode_burst{k}",
             {"tokens": self._tokens, "lengths": self._lengths,
              "active": self._active, "rngs": self._rngs},
         )
         step_extra = (self._page_tables,) if self.page_size else ()
-        t0 = time.perf_counter()
-        if k > 1:
-            self._arena, self._tokens, self._lengths, self._rngs, toks = (
-                self._decode_burst(k)(
-                    self.params, self._arena, self._tokens, self._lengths,
-                    self._active, self._rngs, *step_extra,
+        with _span("serving/decode_dispatch", slots=len(self._slot_req)) as sp_d:
+            if k > 1:
+                self._arena, self._tokens, self._lengths, self._rngs, toks = (
+                    self._decode_burst(k)(
+                        self.params, self._arena, self._tokens, self._lengths,
+                        self._active, self._rngs, *step_extra,
+                    )
                 )
-            )
-            host = np.asarray(jax.device_get(toks))  # [K, N]; forces the burst
-        else:
-            self._arena, self._tokens, self._lengths, self._rngs = self._decode_step(
-                self.params, self._arena, self._tokens, self._lengths, self._active,
-                self._rngs, *step_extra,
-            )
-            host = np.asarray(jax.device_get(self._tokens))[None]  # [1, N]
-        now = time.perf_counter()
-        wall = now - t0
-        self.step_count += k
-        self._usage_note_step(wall)
-        emitted = 0
-        for i in range(k):
-            # a fused burst delivers k tokens in one host RTT; amortize the
-            # burst wall across them so ITL samples measure the chip's
-            # per-token pace instead of k-1 zeros plus one k-sized spike
-            # (the gaps feeding both the engine deque and the serving/itl
-            # SLO histogram — and through it the p99 profiler trigger)
-            ts = t0 + wall * (i + 1) / k
-            for slot, req in list(self._slot_req.items()):
-                self._emit(req, int(host[i, slot]), ts)
-                emitted += 1
-        # count DELIVERED tokens, not n_active*k: an eos finish mid-burst
-        # drops its slot's remaining burst tokens, and tokens/s must not
-        # claim them
-        self._step_samples.append((wall, emitted, k))
-        if self.telemetry is not None:
-            self.telemetry.on_step(self, wall, tokens=emitted, steps=k)
-            costs = getattr(self.telemetry, "costs", None)
-            if costs is not None:
-                # a fused burst is a lax.scan of k step BODIES, so its wall
-                # bills the captured decode_step program as k executions —
-                # the roofline row keeps accumulating in burst mode instead
-                # of splitting into an uncaptured decode_burst<k> row
-                costs.note_wall("decode_step", wall, calls=k)
-                if kernel_cost is not None:
-                    costs.note_dynamic("paged_decode_kernel", wall, **kernel_cost)
+            else:
+                self._arena, self._tokens, self._lengths, self._rngs = self._decode_step(
+                    self.params, self._arena, self._tokens, self._lengths, self._active,
+                    self._rngs, *step_extra,
+                )
+                toks = self._tokens
+        with _span("serving/token_fetch") as sp_f:
+            host = np.asarray(jax.device_get(toks))  # forces the step or burst
+            if k == 1:
+                host = host[None]  # [1, N]
+        t0, wall = sp_d.t0, sp_f.t1 - sp_d.t0
+        with _span("serving/emit") as sp_e:
+            done0 = self.requests_completed
+            self.step_count += k
+            self._usage_note_step(wall)
+            emitted = 0
+            for i in range(k):
+                # a fused burst delivers k tokens in one host RTT; amortize the
+                # burst wall across them so ITL samples measure the chip's
+                # per-token pace instead of k-1 zeros plus one k-sized spike
+                # (the gaps feeding both the engine deque and the serving/itl
+                # SLO histogram — and through it the p99 profiler trigger)
+                ts = t0 + wall * (i + 1) / k
+                for slot, req in list(self._slot_req.items()):
+                    self._emit(req, int(host[i, slot]), ts)
+                    emitted += 1
+            # count DELIVERED tokens, not n_active*k: an eos finish mid-burst
+            # drops its slot's remaining burst tokens, and tokens/s must not
+            # claim them
+            self._step_samples.append((wall, emitted, k))
+            if self.telemetry is not None:
+                self.telemetry.on_step(self, wall, tokens=emitted, steps=k)
+                costs = getattr(self.telemetry, "costs", None)
+                if costs is not None:
+                    # a fused burst is a lax.scan of k step BODIES, so its wall
+                    # bills the captured decode_step program as k executions —
+                    # the roofline row keeps accumulating in burst mode instead
+                    # of splitting into an uncaptured decode_burst<k> row
+                    costs.note_wall("decode_step", wall, calls=k)
+            sp_e.args["emitted"] = emitted
+            sp_e.args["finished"] = self.requests_completed - done0
         return True
 
     def _usage_note_step(self, wall_s: float):

@@ -108,8 +108,7 @@ class TelemetryConfig:
     flush_every: int = 0                   # auto-flush to trackers every N steps (0 = manual)
     trace_dir: Optional[str] = None
     spans: bool = True                     # stream engine/user spans to JSONL
-    span_ring: int = 64                    # in-memory closed-span ring (watchdog dump)
-    annotate_device: bool = False          # bridge spans into jax.profiler timeline
+    span_ring: int = 64                    # newest spans of the in-memory ring a watchdog dump prints
     metrics_jsonl: bool = False            # per-step records to metrics-host<i>.jsonl
     metrics_path: Optional[str] = None     # exact per-step JSONL path (overrides)
     device_memory: bool = True
@@ -262,8 +261,7 @@ class TelemetrySession:
 
             self.recorder = _spans.arm(
                 os.path.join(self.trace_dir, f"trace-host{self.process_index}.jsonl"),
-                self.process_index, ring=config.span_ring,
-                annotate_device=config.annotate_device,
+                self.process_index,
             )
 
         self._metrics_fh = None
@@ -290,7 +288,6 @@ class TelemetrySession:
         self.forensics = None
         if config.forensics:
             from . import forensics as _forensics
-            from . import spans as _spans_mod
 
             fpath = None
             if self.trace_dir:
@@ -298,7 +295,7 @@ class TelemetrySession:
                     self.trace_dir, f"forensics-host{self.process_index}.jsonl"
                 )
             self.forensics = _forensics.arm(_forensics.ForensicsRecorder(
-                fpath, self.process_index, span_recorder=_spans_mod.recorder,
+                fpath, self.process_index,
             ))
         self.costs = None
         if config.cost_registry:
@@ -651,9 +648,10 @@ class TelemetrySession:
         self.window.add(rec)
         self._heartbeat(step)
         if self.recorder is not None:
-            self.recorder.emit("engine/train_step",
-                               time.perf_counter() - wall_s, wall_s,
-                               cat="engine", args={"step": step, "steps": steps})
+            from . import spans as _spans
+
+            _spans.emit("engine/train_step", time.perf_counter() - wall_s, wall_s,
+                        {"step": step, "steps": steps}, cat="engine")
         if self._metrics_fh is not None:
             self._write_step_record(rec)
         if self.flight is not None:

@@ -166,37 +166,6 @@ class CostRegistry:
             row["wall_s"] = row.get("wall_s", 0.0) + float(wall_s)
             row["calls"] = row.get("calls", 0) + int(calls)
 
-    def note_dynamic(self, name: str, wall_s: float, *, flops: float = 0.0,
-                     hbm_bytes: float = 0.0, calls: int = 1):
-        """Attribute dispatches of an executable whose per-call cost varies
-        with runtime state — the paged decode kernel's HBM read is the live
-        page set, which XLA's static ``cost_analysis()`` (operand sizes:
-        the WHOLE arena) cannot see. Flop/byte totals accumulate alongside
-        wall; per-call values are kept as running averages so the static-row
-        roofline math in :meth:`rows` (and the offline report merge) stays
-        valid, and the roofline class re-derives from the running totals."""
-        with self._lock:
-            row = self.entries.get(name)
-            if row is None:
-                row = self.entries[name] = {"name": name, "wall_s": 0.0, "calls": 0}
-            row["dynamic"] = True
-            row["wall_s"] = row.get("wall_s", 0.0) + float(wall_s)
-            row["calls"] = row.get("calls", 0) + int(calls)
-            row["flops_total"] = row.get("flops_total", 0.0) + float(flops)
-            row["hbm_bytes_total"] = row.get("hbm_bytes_total", 0.0) + float(hbm_bytes)
-            n = max(row["calls"], 1)
-            row["flops_per_call"] = row["flops_total"] / n
-            row["hbm_bytes_per_call"] = row["hbm_bytes_total"] / n
-            if row["flops_total"] > 0 and row["hbm_bytes_total"] > 0:
-                ai = row["flops_total"] / row["hbm_bytes_total"]
-                row["arith_intensity"] = round(ai, 4)
-                ridge = self.ridge()
-                if ridge is not None:
-                    row["ridge_intensity"] = round(ridge, 4)
-                    row["roofline"] = (
-                        "compute-bound" if ai >= ridge else "memory-bound"
-                    )
-
     # -- consumers ---------------------------------------------------------
 
     def executable_names(self) -> list:
@@ -223,10 +192,7 @@ class CostRegistry:
                 if flops and pf:
                     row["mfu_model_pct"] = round(100.0 * flops * calls / wall / pf, 3)
                 if hbm:
-                    # achieved HBM bytes/s over the attributed wall — for
-                    # dynamic rows this is the kernel's modeled live-byte
-                    # traffic over the step wall (a lower bound on the
-                    # kernel's own bandwidth)
+                    # achieved HBM bytes/s over the attributed wall
                     row["hbm_gbps"] = round(hbm * calls / wall / 1e9, 3)
                     if pb:
                         row["bw_util_pct"] = round(100.0 * hbm * calls / wall / pb, 3)

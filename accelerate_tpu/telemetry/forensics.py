@@ -160,10 +160,9 @@ class ForensicsRecorder:
     """
 
     def __init__(self, path: Optional[str] = None, process_index: int = 0,
-                 span_recorder=None, max_signatures: int = 64):
+                 max_signatures: int = 64):
         self.path = path
         self.process_index = process_index
-        self.span_recorder = span_recorder
         self.max_signatures = max(2, int(max_signatures))
         self.records: list = []   # diagnosed events (in-memory mirror)
         self._seen: dict = {}     # fn -> {sig_key: signature}
@@ -251,16 +250,12 @@ class ForensicsRecorder:
         self.records.append(rec)
         if self._fh is not None and not self._fh.closed:
             self._fh.write_line(json.dumps(rec))
-        span = self.span_recorder() if callable(self.span_recorder) else self.span_recorder
-        if span is not None:
-            try:
-                span.emit(
-                    f"forensics/{rec['event']}", pend["t0"],
-                    max(rec["compile_s"], 1e-6), cat="forensics",
-                    args={"fn": rec["fn"], "cause": rec["cause"]},
-                )
-            except Exception:
-                pass
+        from . import spans
+
+        spans.emit(
+            f"forensics/{rec['event']}", pend["t0"], max(rec["compile_s"], 1e-6),
+            {"fn": rec["fn"], "cause": rec["cause"]}, cat="forensics",
+        )
 
     def flush(self):
         """Finalize any pending event (attributes its compile delta)."""

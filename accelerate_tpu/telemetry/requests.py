@@ -12,10 +12,12 @@ publishes it three ways:
   ``TelemetryConfig.itl_series_max``), finish reason, and how many XLA
   compiles fired while the request was in flight (a nonzero delta names
   the recompile that ate the latency budget);
-- **nestable spans** in the same Chrome-trace JSONL stream the engine
-  already writes: a ``serving/request`` span covering submit→finish plus
-  ``serving/queue_wait`` and ``serving/prefill_chunk`` children, all
-  carrying ``request_id`` args so the ``trace`` CLI can filter one
+- **spans** in the one span layer (``spans.py``: the in-memory ring, and
+  the Chrome-trace JSONL stream when a file is armed): a
+  ``serving/request`` span covering submit→finish from here, beside the
+  ``serving/queue_wait``, ``serving/prefill_chunk`` and
+  ``serving/first_token`` spans the engine emits where it decides them,
+  all carrying ``request_id`` args so the ``trace`` CLI can filter one
   request out of a merged multi-host trace. Per-token spans are behind
   the ``token_span_every`` sampling knob (1-in-N requests) because at
   production token rates they dominate the file;
@@ -34,6 +36,8 @@ import json
 import threading
 import time
 from typing import Optional
+
+from . import spans
 
 
 class RequestTracer:
@@ -67,9 +71,6 @@ class RequestTracer:
         from ..utils.compile_cache import compile_event_counters
 
         return compile_event_counters()["count"]
-
-    def _recorder(self):
-        return self.session.recorder if self.session is not None else None
 
     @staticmethod
     def _exemplar(rec: dict) -> dict:
@@ -129,13 +130,9 @@ class RequestTracer:
         self.session.histogram("serving/queue_wait").observe(
             queue_wait_s, exemplar=self._exemplar(rec)
         )
-        recorder = self._recorder()
-        if recorder is not None:
-            recorder.emit("serving/queue_wait", req.submit_t, queue_wait_s,
-                          cat="serving", args={"request_id": req.id, "slot": slot})
 
     def on_prefill_chunk(self, req, slot: int, start: int, bucket: int,
-                         t0: float, wall_s: float):
+                         wall_s: float):
         """One bucketed prefill chunk dispatched. ``wall_s`` is the host
         dispatch wall (async backends return before the compute lands;
         the final chunk's device_get makes that one chunk's wall real)."""
@@ -147,11 +144,6 @@ class RequestTracer:
              "ms": round(wall_s * 1e3, 3)}
         )
         rec["last_event"] = ("prefill_chunk", time.time())
-        recorder = self._recorder()
-        if recorder is not None:
-            recorder.emit("serving/prefill_chunk", t0, wall_s, cat="serving",
-                          args={"request_id": req.id, "slot": slot,
-                                "start": start, "bucket": bucket})
 
     def on_preempt(self, req):
         """A live request was paged out (its slot and KV pages released,
@@ -206,11 +198,8 @@ class RequestTracer:
         # sampling property without constraining the id type
         rid = req.id if isinstance(req.id, int) else abs(hash(req.id))
         if n and rid % n == 0:
-            recorder = self._recorder()
-            if recorder is not None:
-                recorder.emit("serving/decode_token",
-                              time.perf_counter() - gap_s, gap_s, cat="serving",
-                              args={"request_id": req.id, "token": token_index})
+            spans.emit("serving/decode_token", time.perf_counter() - gap_s, gap_s,
+                       {"request_id": req.id, "token": token_index}, cat="serving")
 
     def on_finish(self, req, reason: str):
         with self._lock:
@@ -267,12 +256,10 @@ class RequestTracer:
             if self._fh is not None and not self._fh.closed:
                 self._fh.write_line(json.dumps(rec))
             self.records_written += 1
-        recorder = self._recorder()
-        if recorder is not None:
-            recorder.emit("serving/request", req.submit_t, total_s, cat="serving",
-                          args={"request_id": req.id, "slot": rec.get("slot"),
-                                "prompt_len": rec["prompt_len"],
-                                "tokens": rec["tokens"], "reason": reason})
+        spans.emit("serving/request", req.submit_t, total_s,
+                   {"request_id": req.id, "slot": rec.get("slot"),
+                    "prompt_len": rec["prompt_len"],
+                    "tokens": rec["tokens"], "reason": reason}, cat="serving")
         flight = getattr(self.session, "flight", None)
         if flight is not None:
             flight.note("request_finish", request_id=req.id, reason=reason,

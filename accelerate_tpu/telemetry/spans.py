@@ -1,9 +1,25 @@
-"""Nestable span tracing emitted as a Chrome-trace-compatible JSONL per host.
+"""Nestable span tracing: one process-wide ring in memory, always on, and an
+optional Chrome-trace-compatible JSONL stream per host.
 
-Generalizes the flat TTFT phase timing of ``utils/phases.py`` into spans
-that nest (per-thread), carry attributes, and stream to disk as they
-close. Each line of the output file is one complete Chrome trace event
-(``"ph": "X"``), so the file doubles as
+Every closed span lands in a bounded ring (``RING_SPANS`` entries) as
+``(id, parent_id, name, t0, t1, args)`` on ``time.perf_counter``;
+``parent_id`` is the span that enclosed it on the same thread, and
+request-level spans carry ``request_id`` in ``args`` so the spans of one
+request share an identifier. ``snapshot()`` returns the ring's content,
+``dropped()`` how many spans the ring has forgotten since the process began,
+``last_spans()`` the newest few as the watchdog and the flight recorder
+print them. There is no switch: the serving engine's iteration spans
+(``serving/step`` and its children, docs/telemetry.md) are recorded whether
+or not anyone listens, at about two microseconds each (PERF.md, section 6).
+
+Every span also enters ``jax.profiler.TraceAnnotation``, which the runtime
+ignores while no profiler runs; under ``jax.profiler.start_trace`` the spans
+lie in the xplane on the device events' clock, so XProf shows what the host
+was doing in each idle gap of the device.
+
+``arm(path)`` additionally streams closed spans to ``path``. Each line of
+that file is one complete Chrome trace event (``"ph": "X"``), so the file
+doubles as
 
 - a JSONL stream (tail it, grep it, load line-by-line), and
 - the body of a Chrome ``traceEvents`` array: ``load_chrome_trace()``
@@ -12,38 +28,124 @@ close. Each line of the output file is one complete Chrome trace event
   the missing brackets too).
 
 Spans on the same thread nest by time containment — exactly how the trace
-viewers render them — so no name mangling is needed. ``span(...,
-annotate=True)`` (or arming the recorder with ``annotate_device=True``)
-additionally brackets the region with ``jax.profiler.TraceAnnotation`` so
-host spans line up with the device timeline in XProf captures.
-
-The recorder also keeps an in-memory ring of the most recently *closed*
-spans (``last_spans()``) — the watchdog dumps it when a stall fires, so
-the post-mortem shows what the host was doing right before the hang.
+viewers render them — so no name mangling is needed.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Optional
+
+RING_SPANS = 8192
 
 _RECORDER: Optional["SpanRecorder"] = None
 _tls = threading.local()
+_ring: deque = deque(maxlen=RING_SPANS)
+_ring_lock = threading.Lock()
+_ids = itertools.count(1)
+_closed = 0  # spans ever recorded; what the ring no longer holds was dropped
+
+
+def _record(span_id, parent_id, name, t0, t1, args, cat):
+    global _closed
+    with _ring_lock:
+        _ring.append((span_id, parent_id, name, t0, t1, args))
+        _closed += 1
+    rec = _RECORDER
+    if rec is not None:
+        rec.write_span(name, t0, t1 - t0, cat, args)
+
+
+def snapshot() -> list:
+    """The ring's content, oldest first: ``(id, parent_id, name, t0, t1,
+    args)`` tuples on ``time.perf_counter`` (``args`` a dict or None)."""
+    with _ring_lock:
+        return list(_ring)
+
+
+def dropped() -> int:
+    """Spans the ring has forgotten (it wrapped) since the process began."""
+    with _ring_lock:
+        return _closed - len(_ring)
+
+
+def last_spans(n: int = 16) -> list:
+    """The most recently closed spans (newest last) as ``{"name",
+    "end_unix_s", "dur_s"}`` — what a stall report prints."""
+    with _ring_lock:
+        recent = list(_ring)[-n:] if n > 0 else []
+    unix_minus_perf = time.time() - time.perf_counter()
+    return [{"name": name, "end_unix_s": t1 + unix_minus_perf, "dur_s": t1 - t0}
+            for _, _, name, t0, t1, _ in recent]
+
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    # jax is imported at the first span, not with the module: the telemetry
+    # package stays importable on a machine that only holds the log files
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
+def _parent_id():
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
+
+
+def emit(name: str, t0: float, dur_s: float, args: Optional[dict] = None,
+         cat: str = "span") -> None:
+    """Record a span that was timed elsewhere (a queue wait: the stamps lie
+    iterations apart). Its parent is the span open on this thread now."""
+    _record(next(_ids), _parent_id(), name, t0, t0 + dur_s, args, cat)
+
+
+class span:
+    """Time a nestable region: ``with span("serving/step") as s: ...;
+    s.args["emitted"] = n``. ``args`` (keyword arguments, and whatever the
+    body adds before the region closes) are recorded with the span."""
+
+    __slots__ = ("name", "cat", "args", "id", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, cat: str = "span", **args):
+        self.name, self.cat, self.args = name, cat, args
+
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self.id = next(_ids)
+        stack.append(self.id)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        stack = _tls.stack
+        stack.pop()
+        _record(self.id, stack[-1] if stack else None, self.name, self.t0,
+                self.t1, self.args or None, self.cat)
+        return False
 
 
 class SpanRecorder:
     """Streams closed spans to ``path`` (one Chrome trace event per line)."""
 
-    def __init__(self, path: str, process_index: int = 0, ring: int = 64,
-                 annotate_device: bool = False):
+    def __init__(self, path: str, process_index: int = 0):
         self.path = path
         self.process_index = process_index
-        self.annotate_device = annotate_device
-        self.ring: deque = deque(maxlen=ring)
         # one clock for every ts in this file: perf_counter, rebased so the
         # trace starts near 0 (viewers dislike 10^9-microsecond offsets)
         self._epoch = time.perf_counter()
@@ -56,9 +158,9 @@ class SpanRecorder:
             "args": {"name": f"host{process_index}", "epoch_unix_s": time.time()},
         })
 
-    def emit(self, name: str, t0: float, dur_s: float, cat: str = "span",
-             args: Optional[dict] = None):
-        """Record one closed span (``t0`` on the perf_counter clock)."""
+    def write_span(self, name: str, t0: float, dur_s: float, cat: str = "span",
+                   args: Optional[dict] = None):
+        """One closed span (``t0`` on the perf_counter clock) to the file."""
         evt = {
             "name": name,
             "ph": "X",
@@ -70,7 +172,6 @@ class SpanRecorder:
         }
         if args:
             evt["args"] = args
-        self.ring.append({"name": name, "end_unix_s": time.time(), "dur_s": dur_s})
         self._write(evt)
 
     def _write(self, obj: dict):
@@ -85,14 +186,12 @@ class SpanRecorder:
                 self._fh.close()
 
 
-def arm(path: str, process_index: int = 0, ring: int = 64,
-        annotate_device: bool = False) -> SpanRecorder:
-    """Install the process-global recorder (replacing any previous one)."""
+def arm(path: str, process_index: int = 0) -> SpanRecorder:
+    """Stream spans to ``path`` from now on (replacing any previous file)."""
     global _RECORDER
     if _RECORDER is not None:
         _RECORDER.close()
-    _RECORDER = SpanRecorder(path, process_index, ring=ring,
-                             annotate_device=annotate_device)
+    _RECORDER = SpanRecorder(path, process_index)
     return _RECORDER
 
 
@@ -105,46 +204,6 @@ def disarm():
 
 def recorder() -> Optional[SpanRecorder]:
     return _RECORDER
-
-
-def last_spans(n: int = 16) -> list:
-    """The most recently closed spans (newest last); [] when nothing armed."""
-    rec = _RECORDER
-    if rec is None:
-        return []
-    return list(rec.ring)[-n:]
-
-
-@contextmanager
-def span(name: str, annotate: bool = False, cat: str = "span", **args):
-    """Time a nestable region. No-op (one global read) when nothing is armed."""
-    rec = _RECORDER
-    if rec is None:
-        yield
-        return
-    depth = getattr(_tls, "depth", 0)
-    _tls.depth = depth + 1
-    ann = None
-    if annotate or rec.annotate_device:
-        try:
-            from ..utils.profiler import annotate as _annotate
-
-            ann = _annotate(name)
-            ann.__enter__()
-        except Exception:
-            ann = None
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dur = time.perf_counter() - t0
-        _tls.depth = depth
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
-        rec.emit(name, t0, dur, cat=cat, args={**args, "depth": depth} if args or depth else None)
 
 
 def load_chrome_trace(path: str) -> dict:

@@ -1,0 +1,14 @@
+"""Share of the traced window in which the chip idles while the host waits for a result to cross
+(``serving/prefill_fetch``, ``serving/token_fetch``): program_spans.idle_share_pct."""
+
+import program_spans
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "out_tokens_per_s"
+SOURCE = "device_trace"
+CELLS = ("mistral7b_serve_batch",)
+
+
+def read(trace, spans, counters, cell):
+    return program_spans.idle_share_pct(trace, spans, counters, fetches=True)

@@ -264,49 +264,3 @@ class TestDecodeKernelDispatch:
 
         with pytest.raises(ValueError, match="decode_kernel"):
             DecoderConfig.tiny(decode_kernel="flash")
-
-
-class TestKernelCostRow:
-    def test_note_dynamic_roofline_row(self):
-        """CostRegistry.note_dynamic accumulates per-call-varying bytes /
-        flops into one roofline row: achieved bytes/s, bandwidth
-        utilization, memory-bound classification, and the rollup keys the
-        Prometheus exposition exports."""
-        from accelerate_tpu.telemetry.costs import CostRegistry
-
-        reg = CostRegistry(peak_flops=100e12, peak_bw=1e12)
-        reg.note_dynamic("paged_decode_kernel", 0.0, calls=0)  # warmup seed
-        reg.note_dynamic("paged_decode_kernel", 0.01,
-                         flops=2e9, hbm_bytes=1e9, calls=1)
-        reg.note_dynamic("paged_decode_kernel", 0.01,
-                         flops=4e9, hbm_bytes=2e9, calls=2)
-        row = {r["name"]: r for r in reg.rows()}["paged_decode_kernel"]
-        assert row["dynamic"] and row["calls"] == 3
-        assert row["roofline"] == "memory-bound"  # AI 2 << ridge 100
-        assert row["hbm_gbps"] == pytest.approx(3e9 / 0.02 / 1e9)
-        assert row["bw_util_pct"] == pytest.approx(100 * 3e9 / 0.02 / 1e12)
-        keys = reg.rollup_keys()
-        assert keys["exe/paged_decode_kernel_bw_util_pct"] == row["bw_util_pct"]
-        assert keys["exe/paged_decode_kernel_hbm_gbps"] == row["hbm_gbps"]
-        assert keys["exe/paged_decode_kernel_compute_bound"] is False
-
-    def test_report_merges_dynamic_rows_by_totals(self, tmp_path):
-        """Multi-host report merge: dynamic rows (per-call cost varies per
-        host) must merge by totals — keeping host 0's per-call average
-        would mis-state the fleet's achieved bytes/s."""
-        from accelerate_tpu.commands.report import load_costs
-        from accelerate_tpu.telemetry.costs import CostRegistry
-
-        a = CostRegistry(peak_flops=1e12, peak_bw=1e12)
-        a.note_dynamic("paged_decode_kernel", 0.5,
-                       flops=1e9, hbm_bytes=1e9, calls=10)
-        a.write_snapshot(str(tmp_path / "costs-host0.json"))
-        b = CostRegistry(peak_flops=1e12, peak_bw=1e12)
-        b.note_dynamic("paged_decode_kernel", 0.5,
-                       flops=9e9, hbm_bytes=9e9, calls=10)
-        b.write_snapshot(str(tmp_path / "costs-host1.json"))
-        merged = load_costs(str(tmp_path))
-        row = {r["name"]: r for r in merged["executables"]}["paged_decode_kernel"]
-        assert row["calls"] == 20
-        assert row["hbm_bytes_per_call"] == pytest.approx(0.5e9)
-        assert row["hbm_gbps"] == pytest.approx(10.0)  # 1e10 B over 1 s

@@ -1,0 +1,238 @@
+"""The spans and counts ``ServingEngine.step()`` records (docs/telemetry.md,
+"Serving iteration spans"): one ``serving/step`` an iteration with its phases
+as children, in order, and the request-level stamps and spans beside them.
+
+One engine run a path (ragged prefill, dense chunks, flat arena, fused
+burst, speculative verify), read back from the process-wide span ring.
+"""
+
+import dataclasses
+import json
+
+import numpy as np
+import pytest
+
+import jax
+
+from accelerate_tpu.models import DecoderConfig, DecoderLM
+from accelerate_tpu.parallel.sharding import unbox_params
+from accelerate_tpu.serving import ServingEngine
+from accelerate_tpu.telemetry import spans as spans_mod
+
+# a step's children, in the order they can occur (the admission group
+# repeats when a scheduler grants more than one dispatch an iteration)
+PHASES = ("serving/reap", "serving/admit_plan", "serving/prefill_dispatch",
+          "serving/prefill_fetch", "serving/prefill_commit", "serving/decode_grow",
+          "serving/decode_dispatch", "serving/token_fetch", "serving/emit")
+PROMPT_LENS = (20, 5, 12, 3, 9)
+NEW_TOKENS = 5
+
+PATHS = {
+    "ragged": dict(page_size=8, kernels=True),
+    "dense": dict(page_size=8),
+    "flat": dict(),
+    "burst": dict(page_size=8, kernels=True, steps_per_call=2),
+    "verify": dict(page_size=8, kernels=True, spec_draft_len=2),
+}
+
+
+@pytest.fixture(scope="module")
+def model_and_params():
+    cfg = DecoderConfig.tiny(max_seq_len=64)
+    model = DecoderLM(cfg)
+    variables = model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)
+    params, _ = unbox_params(variables["params"])
+    return model, cfg, params
+
+
+class Run:
+    """One engine driven to the end, with what the ring holds of it."""
+
+    def __init__(self, model, cfg, params, *, kernels=False, telemetry=None, **kw):
+        if kernels:
+            cfg = dataclasses.replace(cfg, decode_kernel="interpret", decode_kernel_block=8,
+                                      prefill_kernel="interpret")
+            model = model.clone(config=cfg)
+        spans_mod.emit("mark", 0.0, 0.0)
+        mark = spans_mod.snapshot()[-1][0]
+        self.engine = ServingEngine(model, params, num_slots=2, max_cache_len=64,
+                                    prefill_chunks=(8, 16), telemetry=telemetry, **kw)
+        self.engine.warmup()
+        rng = np.random.RandomState(0)
+        self.requests = [self.engine.submit(rng.randint(3, cfg.vocab_size, (n,)),
+                                            max_new_tokens=NEW_TOKENS, seed=i)
+                         for i, n in enumerate(PROMPT_LENS)]
+        self.engine.run()
+        ring = spans_mod.snapshot()
+        assert ring[0][0] <= mark  # the mark is still there: the ring did not wrap inside this run
+        self.spans = [s for s in ring if s[0] > mark]
+
+    def named(self, name):
+        return [s for s in self.spans if s[2] == name]
+
+    def children(self, parent):
+        return sorted((s for s in self.spans if s[1] == parent[0] and s[2] in PHASES),
+                      key=lambda s: s[3])
+
+    def of_request(self, name, req):
+        return sorted((s for s in self.named(name) if s[5]["request_id"] == req.id),
+                      key=lambda s: s[3])
+
+
+@pytest.fixture(scope="module")
+def runs(model_and_params):
+    cache = {}
+
+    def get(path):
+        if path not in cache:
+            cache[path] = Run(*model_and_params, **PATHS[path])
+        return cache[path]
+
+    return get
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_every_step_has_its_phases_in_order_and_they_cover_it(runs, path):
+    run = runs(path)
+    steps = run.named("serving/step")
+    assert len(steps) == run.engine.iterations > 0
+    assert [s[5]["iteration"] for s in steps] == list(range(1, len(steps) + 1))
+    dispatched, uncovered = 0, 0.0
+    for step in steps:
+        kids = run.children(step)
+        names = [k[2] for k in kids]
+        assert len(kids) + 1 <= 12, names  # the budget: at most 12 spans an iteration
+        # in order, none twice (no scheduler here: one admission an iteration)
+        order = [PHASES.index(n) for n in names]
+        assert order == sorted(set(order)), names
+        assert names[:2] == ["serving/reap", "serving/admit_plan"]
+        if "serving/prefill_dispatch" in names:
+            dispatched += 1
+            assert "serving/prefill_commit" in names
+            # the packed path fetches its first tokens after every dispatch;
+            # a dense chunk only after the prompt's last one
+            assert ("serving/prefill_fetch" in names) or path not in ("ragged", "burst", "verify")
+        if "serving/decode_dispatch" in names:
+            assert names[-3:] == ["serving/decode_dispatch", "serving/token_fetch", "serving/emit"]
+            assert ("serving/decode_grow" in names) == (path != "flat")
+        # children lie inside the step, one after another, and cover it
+        for a, b in zip(kids, kids[1:]):
+            assert a[4] <= b[3]
+        assert step[3] <= kids[0][3] and kids[-1][4] <= step[4]
+        # ... to within 5% of its duration (and 2 ms of scheduling noise: the
+        # suite's workers share this host's cores; on the chip an iteration is
+        # 330-900 ms and its own time 0.3 ms)
+        own = (step[4] - step[3]) - sum(k[4] - k[3] for k in kids)
+        assert own <= 0.05 * (step[4] - step[3]) + 2e-3, names
+        uncovered += own
+    assert uncovered <= 0.05 * sum(s[4] - s[3] for s in steps)
+    assert dispatched == len(run.named("serving/prefill_dispatch")) > 0
+    # no span per token or per slot: everything recorded is one of these
+    allowed = set(PHASES) | {"serving/step", "serving/warmup", "serving/queue_wait",
+                             "serving/prefill_chunk", "serving/first_token"}
+    assert {s[2] for s in run.spans} <= allowed
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
+    run = runs(path)
+    eng = run.engine
+    dispatches = run.named("serving/prefill_dispatch")
+    assert sum(s[5]["tokens"] for s in dispatches) == eng._prefill_tokens_dispatched
+    assert sum(s[5]["rows"] for s in dispatches) == eng._prefill_rows_dispatched
+    assert all(0 < s[5]["tokens"] <= s[5]["rows"] and s[5]["requests"] >= 1 for s in dispatches)
+    if path in ("ragged", "burst", "verify"):
+        assert sum(s[5]["tokens"] for s in dispatches) == eng.prefill_packed_tokens
+    if path == "ragged":
+        assert any(s[5]["requests"] > 1 for s in dispatches)  # the long prompt's tail and a short prompt share a grid
+    assert sum(s[5]["emitted"] for s in run.named("serving/step")) == eng.generated_tokens
+    firsts = sum(s[5]["first_tokens"] for s in run.named("serving/prefill_commit"))
+    decoded = sum(s[5]["emitted"] for s in run.named("serving/emit"))
+    assert firsts == len(PROMPT_LENS) and firsts + decoded == eng.generated_tokens
+    assert sum(s[5]["finished"] for s in run.named("serving/emit")) + sum(
+        1 for r in run.requests if len(r.tokens) == 1) == eng.requests_completed == len(PROMPT_LENS)
+    last = run.named("serving/step")[-1][5]
+    assert last["queued"] == 0 and last["live"] == 0
+    if path != "flat":
+        grows = run.named("serving/decode_grow")
+        assert sum(s[5]["pages_allocated"] for s in grows) <= eng.pages_allocated
+        assert all(s[5]["walked_tokens"] % 8 == 0 and s[5]["walked_tokens"] > 0 for s in grows)
+        assert last["pages_in_use"] + last["pages_free"] > 0
+    reaps = run.named("serving/reap")
+    assert all(s[5] == {"reaped": 0, "shed": 0, "preempted": 0} for s in reaps)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_request_stamps_and_spans_agree(runs, path):
+    run = runs(path)
+    steps = run.named("serving/step")
+    waited_behind = 0
+    for req in run.requests:
+        assert req.outcome == "finished"
+        chunks = run.of_request("serving/prefill_chunk", req)
+        assert req.prefill_dispatches == len(chunks) >= 1
+        assert req.submit_t <= req.admit_t <= chunks[0][3] <= req.first_token_t
+        (wait,) = run.of_request("serving/queue_wait", req)
+        assert (wait[3], wait[4]) == (req.submit_t, req.admit_t)
+        (first,) = run.of_request("serving/first_token", req)
+        assert (first[3], first[4]) == (req.submit_t, req.first_token_t)
+        assert first[5]["dispatches"] == req.prefill_dispatches
+        assert first[5]["prompt_len"] == req.prompt.size and first[5]["prefix_hit"] == req.prefix_hit
+        assert first[5]["queue_wait_ms"] == pytest.approx(1e3 * (req.admit_t - req.submit_t), abs=1e-3)
+        # rows of one request lie end to end
+        assert [c[5]["start"] for c in chunks] == sorted(c[5]["start"] for c in chunks)
+        iterations = sum(1 for s in steps if s[3] <= req.first_token_t and s[4] >= req.submit_t)
+        assert req.prefill_dispatches <= iterations
+        waited_behind += req.prefill_dispatches < iterations
+    # five requests through two slots: the later ones wait for iterations in
+    # which nothing of theirs is dispatched, which an outside count cannot tell
+    assert waited_behind >= 2
+    # the longest prompt needs more than one dispatch of at most 16 rows
+    assert run.requests[0].prefill_dispatches == 2
+
+
+def test_warmup_is_one_span_with_its_compile_counts(runs):
+    (warm,) = runs("ragged").named("serving/warmup")
+    assert warm[1] is None and warm[4] > warm[3]
+    assert warm[5]["programs"] >= warm[5]["compiles"] >= 0 and warm[5]["compile_s"] >= 0
+
+
+def test_an_idle_poll_records_nothing(runs):
+    engine = runs("flat").engine
+    spans_mod.emit("mark", 0.0, 0.0)
+    mark, before = spans_mod.snapshot()[-1][0], engine.iterations
+    assert engine.step() is False and engine.step() is False
+    assert [s for s in spans_mod.snapshot() if s[0] > mark] == []
+    assert engine.iterations == before
+
+
+def test_request_spans_land_once_in_ring_and_file(model_and_params, tmp_path):
+    """Under a telemetry session the file holds what the ring holds: one
+    ``serving/queue_wait`` a request and one ``serving/prefill_chunk`` a
+    request and dispatch, from the engine's one call site each; the request
+    tracer keeps its record and histogram."""
+    from accelerate_tpu.telemetry import TelemetryConfig, TelemetrySession
+
+    session = TelemetrySession(TelemetryConfig(trace_dir=str(tmp_path), watchdog=False,
+                                               flight_hooks=False))
+    try:
+        run = Run(*model_and_params, telemetry=session, **PATHS["ragged"])
+        hist = session.histogram("serving/queue_wait")
+    finally:
+        session.close()
+    (trace_file,) = tmp_path.glob("trace-host*.jsonl")
+    events = [json.loads(l) for l in open(trace_file) if l.strip()]
+    for name in ("serving/queue_wait", "serving/prefill_chunk", "serving/first_token",
+                 "serving/step", "serving/request"):
+        in_file = [e for e in events if e["name"] == name]
+        in_ring = [s for s in run.spans if s[2] == name]
+        assert len(in_file) == len(in_ring) > 0, name
+        assert [e.get("args") for e in in_file] == [s[5] for s in in_ring], name
+    assert len(run.named("serving/queue_wait")) == len(PROMPT_LENS)
+    assert sum(r.prefill_dispatches for r in run.requests) == len(run.named("serving/prefill_chunk"))
+    assert hist.count == len(PROMPT_LENS)
+    (req_file,) = tmp_path.glob("requests-host*.jsonl")
+    records = [json.loads(l) for l in open(req_file) if l.strip()]
+    assert sorted(len(r["prefill_chunks"]) for r in records) == sorted(
+        r.prefill_dispatches for r in run.requests)
+    assert all("queue_wait_ms" in r for r in records)

@@ -248,18 +248,14 @@ class TestKvQuantServing:
 
     def test_arena_shrinks_with_bits(self, served_model):
         model, cfg, params, prompts = served_model
-        sizes, token_bytes = {}, {}
+        sizes = {}
         for kvq in ("bf16", "int8", "int4"):
             engine = _engine(model, params, kv_cache_dtype=kvq)
             sizes[kvq] = engine.arena_bytes
-            # what the paged_decode_kernel roofline row bills per walked
-            # token — must shrink with the payload (true quantized bytes)
-            token_bytes[kvq] = engine._kv_token_bytes
             del engine
         # the >=1.8x slots-per-chip contract, at arena-byte granularity
         assert sizes["bf16"] / sizes["int8"] >= 1.8, sizes
         assert sizes["int8"] / sizes["int4"] >= 1.3, sizes
-        assert token_bytes["bf16"] > token_bytes["int8"] > token_bytes["int4"]
 
     def test_drift_harness_int8_greedy_bounds(self, served_model):
         from accelerate_tpu.serving import kv_quant_drift

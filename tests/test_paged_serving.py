@@ -485,36 +485,6 @@ class TestPagedDecodeKernelServing:
         np.testing.assert_array_equal(req.result(), ref)
         assert req.spec_accepted > 0
 
-    def test_kernel_roofline_row_in_registry_and_rollup(
-        self, kernel_model, tmp_path
-    ):
-        """The kernel lands as its own CostRegistry row: dynamic live-page
-        bytes accumulate per decode dispatch, achieved bytes/s and the
-        exe/paged_decode_kernel_* keys ride the rollup (and through it the
-        Prometheus exposition and `accelerate-tpu report` snapshots)."""
-        from accelerate_tpu.telemetry import TelemetryConfig, TelemetrySession
-
-        model, cfg, params, prompts = kernel_model
-        session = TelemetrySession(TelemetryConfig(
-            trace_dir=str(tmp_path), watchdog=False, flight_hooks=False,
-        ))
-        try:
-            engine = self._kengine(model, params, telemetry=session)
-            engine.warmup()
-            engine.generate_batched(prompts[:2], max_new_tokens=4)
-            row = session.costs.entries["paged_decode_kernel"]
-            assert row["dynamic"] and row["calls"] > 0
-            assert row["hbm_bytes_total"] > 0
-            # live-page traffic, not the arena reservation: a step over two
-            # short slots must bill far below 2 full slot reservations
-            arena_kv = engine._kv_token_bytes * engine.num_pages * PS
-            assert row["hbm_bytes_per_call"] < arena_kv
-            rollup = session.rollup()
-            assert rollup["exe/paged_decode_kernel_wall_s"] > 0
-            assert rollup["exe/paged_decode_kernel_hbm_gbps"] > 0
-        finally:
-            session.close()
-
 
 @pytest.mark.slow
 class TestPagedBurstIntegration:

@@ -144,10 +144,21 @@ class TestFp8Health:
         assert fp8_amax_health({}) == {}
 
 
+def _ring_since(mark: int) -> list:
+    """Spans recorded after ``mark`` (an id): the ring is process-wide and
+    other tests write to it."""
+    return [s for s in spans_mod.snapshot() if s[0] > mark]
+
+
+def _ring_mark() -> int:
+    spans_mod.emit("mark", 0.0, 0.0)
+    return spans_mod.snapshot()[-1][0]
+
+
 class TestSpans:
     def test_jsonl_is_chrome_trace(self, tmp_path):
         path = str(tmp_path / "trace.jsonl")
-        spans_mod.arm(path, process_index=3, ring=8)
+        spans_mod.arm(path, process_index=3)
         with spans_mod.span("outer", phase="demo"):
             with spans_mod.span("inner"):
                 time.sleep(0.01)
@@ -169,17 +180,109 @@ class TestSpans:
         trace = spans_mod.load_chrome_trace(path)
         assert isinstance(trace["traceEvents"], list) and len(trace["traceEvents"]) == 3
 
-    def test_span_noop_when_disarmed(self):
+    def test_span_noop_when_disarmed(self, tmp_path):
+        """Nothing armed: no file is written, and the span is in the ring."""
+        assert spans_mod.recorder() is None
+        mark = _ring_mark()
         with spans_mod.span("nothing"):
             pass
-        assert spans_mod.last_spans() == []
+        assert [s[2] for s in _ring_since(mark)] == ["nothing"]
+        assert spans_mod.last_spans(1)[0]["name"] == "nothing"
+        assert list(tmp_path.iterdir()) == []
 
-    def test_last_spans_ring(self, tmp_path):
-        spans_mod.arm(str(tmp_path / "t.jsonl"), ring=2)
+    def test_ring_ids_and_parents_nest(self):
+        mark = _ring_mark()
+        with spans_mod.span("a") as a:
+            with spans_mod.span("b") as b:
+                with spans_mod.span("c"):
+                    pass
+            with spans_mod.span("d"):
+                pass
+        got = {s[2]: s for s in _ring_since(mark)}
+        assert [s[2] for s in _ring_since(mark)] == ["c", "b", "d", "a"]  # as they close
+        assert got["a"][1] is None and got["a"][0] == a.id
+        assert got["b"][1] == a.id and got["d"][1] == a.id and got["c"][1] == b.id
+        assert len({s[0] for s in got.values()}) == 4
+        for child, parent in (("b", "a"), ("c", "b"), ("d", "a")):
+            assert got[parent][3] <= got[child][3] <= got[child][4] <= got[parent][4]
+
+    def test_parents_are_per_thread(self):
+        import threading
+
+        mark = _ring_mark()
+
+        def other():
+            with spans_mod.span("elsewhere"):
+                pass
+
+        with spans_mod.span("here"):
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+        got = {s[2]: s for s in _ring_since(mark)}
+        assert got["elsewhere"][1] is None and got["here"][1] is None
+
+    def test_args_set_before_close_are_kept(self):
+        mark = _ring_mark()
+        with spans_mod.span("counted", rows=256) as sp:
+            sp.args["tokens"] = 200
+        with spans_mod.span("bare"):
+            pass
+        counted, bare = _ring_since(mark)
+        assert counted[5] == {"rows": 256, "tokens": 200}
+        assert bare[5] is None
+
+    def test_emit_lands_once_in_ring_and_file(self, tmp_path):
+        path = str(tmp_path / "t.jsonl")
+        spans_mod.arm(path)
+        mark = _ring_mark()
+        with spans_mod.span("serving/step") as step:
+            spans_mod.emit("serving/queue_wait", 1.0, 0.25, {"request_id": 7}, cat="serving")
+        spans_mod.disarm()
+        spans_mod.emit("serving/queue_wait", 2.0, 0.5, {"request_id": 8}, cat="serving")
+        waits = [s for s in _ring_since(mark) if s[2] == "serving/queue_wait"]
+        assert [(s[3], s[4], s[5]) for s in waits] == [
+            (1.0, 1.25, {"request_id": 7}), (2.0, 2.5, {"request_id": 8})]
+        # the span that caused it: the one open on this thread, none outside
+        assert waits[0][1] == step.id and waits[1][1] is None
+        events = [json.loads(l) for l in open(path) if l.strip()]
+        in_file = [e for e in events if e["name"] == "serving/queue_wait"]
+        assert len(in_file) == 1 and in_file[0]["args"] == {"request_id": 7}
+        assert in_file[0]["dur"] == pytest.approx(0.25e6) and in_file[0]["cat"] == "serving"
+
+    def test_ring_wraps_and_counts_dropped(self):
+        before = spans_mod.dropped()
+        mark = _ring_mark()
+        n = spans_mod.RING_SPANS + 10
+        for i in range(n):
+            spans_mod.emit("filler", float(i), 0.0)
+        ring = spans_mod.snapshot()
+        assert len(ring) == spans_mod.RING_SPANS
+        assert ring[-1][3] == float(n - 1) and ring[0][3] == 10.0
+        assert all(s[0] > mark for s in ring)
+        # at least the mark and the first ten fillers went
+        assert spans_mod.dropped() - before >= 11
+
+    def test_last_spans_ring(self):
         for name in ("a", "b", "c"):
             with spans_mod.span(name):
                 pass
-        assert [s["name"] for s in spans_mod.last_spans()] == ["b", "c"]
+        last = spans_mod.last_spans(2)
+        assert [s["name"] for s in last] == ["b", "c"]
+        assert all(abs(s["end_unix_s"] - time.time()) < 5 and s["dur_s"] >= 0 for s in last)
+        assert spans_mod.last_spans(0) == []
+
+    def test_span_survives_an_exception_and_keeps_the_stack(self):
+        mark = _ring_mark()
+        with pytest.raises(ValueError):
+            with spans_mod.span("outer"):
+                with spans_mod.span("raises"):
+                    raise ValueError("boom")
+        with spans_mod.span("after"):
+            pass
+        got = {s[2]: s for s in _ring_since(mark)}
+        assert got["raises"][1] == got["outer"][0] and got["after"][1] is None
 
     def test_phases_bridge(self, tmp_path):
         from accelerate_tpu.utils import phases

@@ -150,6 +150,32 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the names the benchmark's trace reduction finds the kernels by: an
+# operation in the device trace carries its HLO instruction's name, which is
+# the innermost scope of the call (the kernel's own name, or, for the paged
+# decode kernel, which has none yet, the decoder's "attn" module). Under
+# jax.grad the scope is wrapped by the transform, jvp(flash_attn_fwd), which
+# XLA spells jvp_flash_attn_fwd_.
+KERNEL_NAMES = {
+    "flash_32k_context_fwd": {"flash_attn_fwd"},
+    "flash_gqa_32q8kv_fwd_bwd": {"jvp_flash_attn_fwd_", "jvp_flash_attn_dq_", "jvp_flash_attn_dkv_"},
+    "ragged_prefill_gqa_32q8kv_bf16": {"ragged_prefill_attn"},
+    "paged_decode_bf16_d128_sq1": {"attn"},
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_NAMES))
+def test_kernels_carry_their_names_into_the_hlo(chip, case):
+    import re
+
+    build, kw = CASES[case]
+    fn, args = build(chip, **kw)
+    text = jax.jit(jax.named_scope("attn")(fn)).lower(*args).compile().as_text()
+    names = {re.sub(r"(\.\d+)+$", "", m.group(1))
+             for m in re.finditer(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+    assert names == KERNEL_NAMES[case]
+
+
 @pytest.mark.parametrize("outer_manual", [(), ("fsdp", "tensor"), ("fsdp",)],
                          ids=["jit", "inside_all_manual", "inside_partly_manual"])
 def test_flash_under_a_four_chip_mesh_compiles(v5e, monkeypatch, outer_manual):
