@@ -167,7 +167,11 @@ class DecoderAttention(nn.Module):
     gather + masked-dense reference elsewhere (``config.decode_kernel`` /
     ``ATT_DECODE_KERNEL``). Sharing one physical page across slots'
     tables is copy-on-write prefix sharing; the serving engine forks
-    pages before divergent writes.
+    pages before divergent writes. ``kv_lengths`` ([B] int32, optional)
+    is each slot's count of live tokens, the bound of that kernel's walk
+    (absent: the last row position + 1); the serving engine passes 0 for
+    an inactive slot, whose parked write position would otherwise read as
+    a request at the end of the cache.
 
     ``config.kv_cache_dtype`` ("int8"/"int4") makes the cache STORAGE
     quantized on both layouts: writes quantize the fresh K/V rows (one
@@ -206,7 +210,7 @@ class DecoderAttention(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, deterministic: bool = True, kv_mask=None,
                  cache_positions=None, page_table=None, ragged_slots=None,
-                 slot_hist=None):
+                 slot_hist=None, kv_lengths=None):
         cfg = self.config
         e, h, kv, d = cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         b, s = x.shape[0], x.shape[1]
@@ -420,7 +424,7 @@ class DecoderAttention(nn.Module):
                     out = paged_decode_attention(
                         q, k_pages, v_pages,
                         page_table=page_table, q_positions=pos2d,
-                        impl=dk_impl, **scale_kw,
+                        kv_lengths=kv_lengths, impl=dk_impl, **scale_kw,
                     )
                 else:
                     from ..ops.attention import decode_attention
@@ -533,7 +537,7 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, sin, cos, deterministic: bool = True, cache_positions=None,
-                 page_table=None, ragged_slots=None, slot_hist=None):
+                 page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None):
         cfg = self.config
         ln1 = self.param("ln_attn", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
         ln2 = self.param("ln_mlp", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
@@ -541,7 +545,7 @@ class DecoderBlock(nn.Module):
         y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
             y, sin, cos, deterministic, cache_positions=cache_positions,
             page_table=page_table, ragged_slots=ragged_slots,
-            slot_hist=slot_hist,
+            slot_hist=slot_hist, kv_lengths=kv_lengths,
         )
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
@@ -573,15 +577,15 @@ class _ScanBlock(nn.Module):
 
     @nn.compact
     def __call__(self, carry, _):
-        # cpos/ptab/rslots/shist ride the carry like sin/cos (broadcast
-        # inputs every layer reads unchanged); None when the slot-arena /
-        # ragged-prefill paths are off
-        x, aux, sin, cos, cpos, ptab, rslots, shist = carry
+        # cpos/ptab/rslots/shist/klens ride the carry like sin/cos
+        # (broadcast inputs every layer reads unchanged); None when the
+        # slot-arena / ragged-prefill paths are off
+        x, aux, sin, cos, cpos, ptab, rslots, shist, klens = carry
         x, block_aux = DecoderBlock(self.config, self.mesh, self.use_cache, self.decode, name="block")(
             x, sin, cos, self.deterministic, cache_positions=cpos, page_table=ptab,
-            ragged_slots=rslots, slot_hist=shist,
+            ragged_slots=rslots, slot_hist=shist, kv_lengths=klens,
         )
-        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist), None
+        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens), None
 
 
 class StageStack(nn.Module):
@@ -604,9 +608,9 @@ class StageStack(nn.Module):
             length=cfg.num_layers // cfg.pipeline_stages,
             metadata_params={nn.PARTITION_NAME: "layer"},
         )
-        (x, aux, _, _, _, _, _, _), _ = Stack(
+        (x, aux, *_), _ = Stack(
             cfg, self.mesh, deterministic=deterministic, name="layers"
-        )((x, jnp.float32(0.0), sin, cos, None, None, None, None), None)
+        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None), None)
         if cfg.moe_num_experts > 1:
             # per-(stage, microbatch) router load-balance sum over this
             # stage's layers; the schedule accumulates and renormalizes
@@ -637,6 +641,7 @@ class DecoderLM(nn.Module):
         page_table: Optional[jax.Array] = None,
         ragged_slots: Optional[jax.Array] = None,
         slot_hist: Optional[jax.Array] = None,
+        kv_lengths: Optional[jax.Array] = None,
     ):
         cfg = self.config
         b, s = input_ids.shape
@@ -743,10 +748,10 @@ class DecoderLM(nn.Module):
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layer"},
             )
-            (x, moe_aux, _, _, _, _, _, _), _ = ScanStack(
+            (x, moe_aux, *_), _ = ScanStack(
                 cfg, self.mesh, use_cache, decode, deterministic, name="layers"
             )((x, jnp.float32(0.0), sin, cos, cache_positions, page_table,
-               ragged_slots, slot_hist), None)
+               ragged_slots, slot_hist, kv_lengths), None)
         else:
             block_cls = _maybe_streaming(DecoderBlock, cfg)
             if cfg.remat:
@@ -755,7 +760,7 @@ class DecoderLM(nn.Module):
                 x, block_aux = block_cls(cfg, self.mesh, use_cache, decode, name=f"layer_{i}")(
                     x, sin, cos, deterministic, cache_positions=cache_positions,
                     page_table=page_table, ragged_slots=ragged_slots,
-                    slot_hist=slot_hist,
+                    slot_hist=slot_hist, kv_lengths=kv_lengths,
                 )
                 moe_aux = moe_aux + block_aux
 

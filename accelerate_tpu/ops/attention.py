@@ -618,15 +618,17 @@ def flash_attention_bwd(
 #
 # The masked-dense decode read streams the WHOLE arena reservation through
 # HBM every step — decode bandwidth scales with capacity, not live tokens.
-# These kernels walk only each slot's live KV blocks: a (slots × kv-heads ×
-# kv-blocks) grid with flash-style online softmax, where blocks past a
-# slot's frontier are clamped to the last live block in the BlockSpec index
-# map (the pipeline elides the re-fetch of an unchanged block, so dead
-# blocks cost neither DMA nor compute) and skipped by ``pl.when``. The
-# paged variant reads K/V straight from the physical page arena
-# ([num_pages, KVH, page_size, D]) through each slot's device page table
-# (scalar-prefetched so the table drives the index maps); the dense variant
-# walks a [B, KVH, L, D] arena in fixed blocks — the same win for the
+# These kernels walk only each slot's live KV with flash-style online
+# softmax. The paged variant reads K/V straight from the physical page
+# arena ([num_pages, KVH, page_size, D], left in HBM) through each slot's
+# device page table (scalar-prefetched): one grid step a slot, and inside
+# it a loop over blocks of many consecutive table entries, each live page
+# fetched by one asynchronous copy that brings all its kv heads, double
+# buffered — its work is the live pages, and a slot with no live tokens
+# costs nothing. The dense variant walks a [B, KVH, L, D] arena in fixed
+# blocks over a (slots × kv-heads × kv-blocks) grid, blocks past a slot's
+# frontier clamped in the BlockSpec index map (the pipeline elides the
+# re-fetch) and skipped by ``pl.when`` — the same win for the
 # single-stream decode loop and the flat slot arena. GQA folds the query
 # head group (× the Sq query rows: the multi-query form spec_verify and
 # fused bursts use) into one [group*Sq, D] block per kv head, so K/V are
@@ -683,7 +685,7 @@ def _warn_decode_fallback(reason: str):
 
 
 def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
-                        quant_bits: int = 0):
+                        quant_bits: int = 0, paged: bool = False):
     """(use_kernel, interpret) for one dispatch. Falls back silently for
     by-design exclusions (``dense`` mode, prefill-size Sq) and with a
     warn-once for environment/shape gates. ``quant_bits`` extends the
@@ -698,7 +700,16 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
     trading lane occupancy on the K/V loads for keeping the live-token
     walk — still far ahead of the masked-dense read that streams the
     whole arena reservation. int4 packs the payload to ``d // 2``, so
-    its compiled floor is head_dim 128 (was 256)."""
+    its compiled floor is head_dim 128 (was 256).
+
+    ``paged`` is the page-table kernel, which copies whole pages out of
+    the arena in HBM itself: Mosaic refuses a slice of an HBM array whose
+    last dimension is not a 128-multiple ("Slice shape along dimension 3
+    must be aligned to tiling (128)"), so compiled it takes head_dim
+    128-multiples of unquantized pages only. A 64-wide head, an int4
+    payload (head_dim / 2 wide) and the ``[.., page_size, 1]`` scale
+    pages of any quantized arena resolve to the dense path there
+    (tests/test_tpu_compile.py holds both halves by name)."""
     if mode == "dense":
         return False, False
     if sq > _DECODE_KERNEL_MAX_SQ:
@@ -727,6 +738,16 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
             "resolves to the gathered dequant + masked-dense read"
         )
         return False, False
+    if paged and (d % 128 != 0 or quant_bits):
+        _warn_decode_fallback(
+            f"shape gate: the paged kernel copies whole pages out of the "
+            f"arena in HBM, and Mosaic refuses a slice of an HBM array "
+            f"whose last dimension is not a 128-multiple: head_dim {d}"
+            + (f", int{quant_bits} KV (its scale pages end in a dimension "
+               "of 1)" if quant_bits else "")
+            + "; this dispatch resolves to the gathered masked-dense read"
+        )
+        return False, False
     return True, False
 
 
@@ -747,7 +768,8 @@ def decode_kernel_active(config, sq: int = 1) -> bool:
     quant_bits = {"int8": 8, "int4": 4}.get(
         getattr(config, "kv_cache_dtype", "bf16"), 0
     )
-    use, _ = _decode_kernel_gate(mode, sq, head_dim, int(page_size), quant_bits)
+    use, _ = _decode_kernel_gate(
+        mode, sq, head_dim, int(page_size), quant_bits, paged=True)
     return use
 
 
@@ -767,13 +789,48 @@ def _pick_decode_block(length: int, preferred: Optional[int], interpret: bool) -
     return 0
 
 
+def _fold_row_positions(pos_ref, b, sq, shape, bound=None):
+    """Position of each row of a ``[group x Sq, kv]`` score block: row r of
+    the fold is query token t = r % sq of slot ``b``. ``sq`` is
+    compile-time small (<= _DECODE_KERNEL_MAX_SQ), so the scalar reads
+    unroll; ``bound`` caps every position (the paged walk's live length)."""
+    def at(t):
+        return pos_ref[b, t] if bound is None else jnp.minimum(pos_ref[b, t], bound)
+
+    if sq == 1:
+        return jnp.full(shape, at(0), jnp.int32)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 0) % sq
+    rowpos = jnp.zeros(shape, jnp.int32)
+    for t in range(sq):
+        rowpos = jnp.where(t_idx == t, at(t), rowpos)
+    return rowpos
+
+
+def _online_softmax_step(s, v, m_scr, l_scr, acc):
+    """Fold one block of masked fp32 scores ``s`` [G, kv] and its values
+    ``v`` [kv, D] into the running max, sum and accumulator (fp32; the
+    probabilities are cast to the value dtype before PV)."""
+    m_prev = m_scr[...][:, :1]
+    l_prev = l_scr[...][:, :1]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next)
+    l_scr[...] = jnp.broadcast_to(
+        l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape
+    )
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
+
+
 def _decode_kernel_body(maxblk_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                         acc, m_scr, l_scr, *, sm_scale, bk, sq, group,
                         quant_bits=0, out_dtype=None,
                         ks_ref=None, vs_ref=None):
-    """Online-softmax accumulation over one slot's kv blocks — shared by
-    the paged and dense-arena variants (only the BlockSpec index maps
-    differ). Grid is (B, KVH, n_blocks) with the block dim innermost
+    """Online-softmax accumulation over one slot's kv blocks of the
+    dense-arena kernel. Grid is (B, KVH, n_blocks) with the block dim innermost
     ("arbitrary"); blocks past ``maxblk_ref[b]`` (the slot's last live
     block) are skipped — their operand fetch was already elided by the
     clamped index map. Per-element validity is ``kv position <= the query
@@ -812,53 +869,15 @@ def _decode_kernel_body(maxblk_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale
         kvpos = ib * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
-        if sq == 1:
-            rowpos = jnp.full((g, bk), pos_ref[b, 0], jnp.int32)
-        else:
-            # row r of the [group, Sq] fold is query token t = r % sq;
-            # sq is compile-time small (<= _DECODE_KERNEL_MAX_SQ), so the
-            # scalar reads unroll
-            t_idx = jax.lax.broadcasted_iota(jnp.int32, (g, bk), 0) % sq
-            rowpos = jnp.zeros((g, bk), jnp.int32)
-            for t in range(sq):
-                rowpos = jnp.where(t_idx == t, pos_ref[b, t], rowpos)
+        rowpos = _fold_row_positions(pos_ref, b, sq, (g, bk))
         s = jnp.where(kvpos <= rowpos, s, NEG_INF)
-        m_prev = m_scr[...][:, :1]
-        l_prev = l_scr[...][:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        l_scr[...] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape
-        )
-        acc[...] = acc[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
+        _online_softmax_step(s, v, m_scr, l_scr, acc)
 
     @pl.when(ib == nb - 1)
     def _out():
         l = l_scr[...][:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc[...] / safe_l).astype(o_ref.dtype)
-
-
-def _paged_kernel_entry(maxblk_ref, pos_ref, table_ref, q_ref, k_ref, v_ref,
-                        o_ref, acc, m_scr, l_scr, **kw):
-    # the page table is consumed by the index maps only
-    _decode_kernel_body(maxblk_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                        acc, m_scr, l_scr, **kw)
-
-
-def _paged_quant_kernel_entry(maxblk_ref, pos_ref, table_ref, q_ref, k_ref,
-                              v_ref, ks_ref, vs_ref, o_ref, acc, m_scr,
-                              l_scr, **kw):
-    # quantized arena: two extra scale operands ride the same clamped
-    # page-table index maps as their payloads
-    _decode_kernel_body(maxblk_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                        acc, m_scr, l_scr, ks_ref=ks_ref, vs_ref=vs_ref, **kw)
 
 
 def _dense_quant_kernel_entry(maxblk_ref, pos_ref, q_ref, k_ref, v_ref,
@@ -889,54 +908,250 @@ def _positions_2d(q_positions, b):
     return pos
 
 
-def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos,
+# VMEM the paged decode kernel may spend on its K/V page buffers (two
+# halves each, scale pages included), and the most pages one block holds.
+# From the chip sweep of PR 25 at the serving cells' shape (PERF.md
+# section 6): the block size follows from the page's bytes, so a 64-wide
+# head or an int4 payload doubles the pages within the same budget.
+_PAGED_DECODE_VMEM_BUDGET = 8 * 1024 * 1024
+_PAGED_DECODE_MAX_BLOCK_PAGES = 64
+
+
+def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
+    """Bytes a [rows, cols] slab takes in VMEM: lanes pad to 128, sublanes
+    to the dtype's tile (8 rows of 32 bits, 16 of 16, 32 of 8)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // itemsize)
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def _paged_decode_block_pages(kvh: int, ps: int, pd: int, dtype,
+                              quant_bits: int, table_len: int) -> int:
+    """Pages a block of the paged decode walk holds: the largest power of
+    two whose double-buffered K and V pages (all kv heads of a page, plus
+    their fp32 scale pages when quantized) fit the VMEM budget, at most
+    ``_PAGED_DECODE_MAX_BLOCK_PAGES`` and no more than the page table is
+    long."""
+    page = kvh * _vmem_tile_bytes(ps, pd, dtype)
+    if quant_bits:
+        page += kvh * _vmem_tile_bytes(ps, 1, jnp.float32)
+    fit = _PAGED_DECODE_VMEM_BUDGET // (4 * page)
+    cap = max(1, min(fit, _PAGED_DECODE_MAX_BLOCK_PAGES, table_len))
+    return 1 << (cap.bit_length() - 1)
+
+
+def paged_decode_block_pages(config, table_len: int) -> int:
+    """Pages a block of the paged decode kernel's walk holds for a model
+    with this config and a page table ``table_len`` entries long: what the
+    serving engine counts ``walked_blocks`` in."""
+    bits = {"int8": 8, "int4": 4}.get(getattr(config, "kv_cache_dtype", "bf16"), 0)
+    width = config.head_dim // 2 if bits == 4 else config.head_dim
+    return _paged_decode_block_pages(
+        config.num_kv_heads, int(config.kv_page_size), width,
+        jnp.int8 if bits else config.dtype, bits, table_len,
+    )
+
+
+def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
+                         sm_scale, sq, group, block_pages, quant_bits,
+                         out_dtype):
+    """One slot a grid step; inside, a loop over blocks of ``block_pages``
+    consecutive table entries. Every live page of a block comes from the
+    arena (left in HBM) by one asynchronous copy that brings all kv heads
+    of the page, into one half of a double buffer, while the other half is
+    computed: the next block's copies, or the first block of the next slot
+    that has live tokens, fly during this block's matmuls. The work is
+    ``ceil(live pages / block_pages)`` blocks a slot; a slot with no live
+    tokens costs no copy and no matmul, and its output rows are zeros.
+
+    The mathematics is ``_decode_kernel_body``'s: fp32 scores, fp32 online
+    softmax and accumulator, probabilities cast to the value dtype before
+    PV, validity ``kv position <= row position`` (bounded by the slot's
+    live length, beyond which no page was copied), quantized pages
+    dequantized in-register by ``utils.quantization.dequantize_kv``."""
+    if quant_bits:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+         kbuf, vbuf, ksbuf, vsbuf, sems, state, acc, m_scr, l_scr) = refs
+    else:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, state, acc, m_scr, l_scr = refs
+        ks_hbm = vs_hbm = ksbuf = vsbuf = None
+    b, nslots = pl.program_id(0), pl.num_programs(0)
+    kvh, ps = k_hbm.shape[1], k_hbm.shape[2]
+    g = group * sq
+    bk = block_pages * ps  # kv positions a block spans
+    live = len_ref[b]
+    n_pages = (live + ps - 1) // ps
+    n_blocks = (n_pages + block_pages - 1) // block_pages
+
+    def page_copies(slot, blk, half, j):
+        page = table_ref[slot, blk * block_pages + j]
+        pairs = [(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)]
+        if quant_bits:
+            pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
+        return [
+            pltpu.make_async_copy(src.at[page], dst.at[half, j], sems.at[half, which])
+            for src, dst, which in pairs
+        ]
+
+    def for_each_live_page(slot, blk, half, act):
+        pages = (len_ref[slot] + ps - 1) // ps
+        count = jnp.minimum(block_pages, pages - blk * block_pages)
+
+        def one(j, _):
+            for copy in page_copies(slot, blk, half, j):
+                act(copy)
+            return _
+
+        jax.lax.fori_loop(0, count, one, None)
+
+    def start(slot, blk, half):
+        for_each_live_page(slot, blk, half, lambda copy: copy.start())
+
+    def wait(slot, blk, half):
+        for_each_live_page(slot, blk, half, lambda copy: copy.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        # state: [half the next block lands in, 1 if this slot's first
+        # block was started by the slot before it]. The buffers start as
+        # zeros: pages past a slot's frontier are never copied, and what
+        # they leave in a block's tail is masked to probability zero,
+        # which only holds against finite values.
+        state[0] = 0
+        state[1] = 0
+        for buf in (kbuf, vbuf, ksbuf, vsbuf):
+            if buf is not None:
+                buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(n_blocks == 0)
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _walk():
+        first_half = state[0]
+
+        @pl.when(state[1] == 0)
+        def _():
+            start(b, 0, first_half)
+
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc[...] = jnp.zeros_like(acc)
+
+        def block(ib, half):
+            other = 1 - half
+
+            @pl.when(ib + 1 < n_blocks)
+            def _():
+                start(b, ib + 1, other)
+
+            @pl.when(ib + 1 == n_blocks)
+            def _():
+                nxt = jax.lax.fori_loop(
+                    b + 1, nslots,
+                    lambda s_, found: jnp.where(
+                        (found == nslots) & (len_ref[s_] > 0), s_, found),
+                    nslots,
+                )
+
+                @pl.when(nxt < nslots)
+                def _():
+                    start(nxt, 0, other)
+
+                state[1] = (nxt < nslots).astype(jnp.int32)
+
+            wait(b, ib, half)
+            kvpos = ib * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
+            # a row sees kv position c iff c <= its own position, and never
+            # past the live length: beyond it no page was copied
+            rowpos = _fold_row_positions(pos_ref, b, sq, (g, bk), bound=live - 1)
+            valid = kvpos <= rowpos
+
+            def load(buf, sbuf, h_):
+                x = buf[half, :, h_]  # [block_pages, ps, pd]
+                if not quant_bits:
+                    return x.reshape(bk, x.shape[-1])
+                from ..utils.quantization import dequantize_kv
+
+                # widen before the pages merge into rows: a page is a
+                # whole number of 32-bit sublane tiles, not of 8-bit ones
+                x = x.astype(jnp.int32).reshape(bk, x.shape[-1])
+                return dequantize_kv(
+                    x, sbuf[half, :, h_].reshape(bk, 1), quant_bits, out_dtype)
+
+            def head(h_):
+                q = q_ref[0, h_]  # [G, D]: the kv head's query group x Sq rows
+                k = load(kbuf, ksbuf, h_)
+                v = load(vbuf, vsbuf, h_)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * sm_scale
+                s = jnp.where(valid, s, NEG_INF)
+                _online_softmax_step(s, v, m_scr.at[h_], l_scr.at[h_], acc.at[h_])
+
+            # eight heads at a time as straight-line code: their matmuls
+            # and softmaxes then overlap (1.2-2.3x on the chip at 8 kv
+            # heads against a loop over single heads)
+            together = next(c for c in (8, 4, 2, 1) if kvh % c == 0)
+
+            def heads(i, _):
+                for j in range(together):
+                    head(i * together + j)
+                return _
+
+            if together == kvh:  # no loop at all: what the chip sweep timed
+                heads(0, None)
+            else:
+                jax.lax.fori_loop(0, kvh // together, heads, None)
+            return other
+
+        state[0] = jax.lax.fori_loop(0, n_blocks, block, first_half)
+        # kv position 0 is valid for every row, so the sum is never zero
+        o_ref[0] = (acc[...] / l_scr[...][:, :, :1]).astype(o_ref.dtype)
+
+
+def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
                               sm_scale, interpret, k_scale=None,
                               v_scale=None, quant_bits=0):
     b, h, sq, d = q.shape
     _, kvh, ps, pd = k_pages.shape  # pd: payload width (d, or d/2 packed int4)
     group = h // kvh
     g = group * sq
-    n_blocks = page_table.shape[1]
+    n = _paged_decode_block_pages(
+        kvh, ps, pd, k_pages.dtype, quant_bits, page_table.shape[1])
     q_r = _fold_q_heads(q, kvh)
-    # last live BLOCK per slot: index maps clamp here so dead grid steps
-    # re-address the same page (fetch elided), pl.when skips their compute
-    maxblk = (jnp.max(pos, axis=1) // ps).astype(jnp.int32)
-    entry = _paged_quant_kernel_entry if quant_bits else _paged_kernel_entry
     kernel = functools.partial(
-        entry, sm_scale=sm_scale, bk=ps, sq=sq, group=group,
-        quant_bits=quant_bits, out_dtype=q.dtype,
+        _paged_decode_kernel, sm_scale=sm_scale, sq=sq, group=group,
+        block_pages=n, quant_bits=quant_bits, out_dtype=q.dtype,
     )
-
-    def _page_spec(width):
-        return pl.BlockSpec(
-            (1, 1, ps, width),
-            lambda b_, h_, ib, mb, po, tb: (tb[b_, jnp.minimum(ib, mb[b_])], h_, 0, 0),
-        )
-
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h_, ib, mb, po, tb: (b_, h_, 0, 0)),
-        _page_spec(pd),
-        _page_spec(pd),
-    ]
+    slot_spec = pl.BlockSpec((1, kvh, g, d), lambda b_, ln, po, tb: (b_, 0, 0, 0))
+    arena = pl.BlockSpec(memory_space=pl.ANY)
     operands = [q_r, k_pages, v_pages]
+    buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype)] * 2
     if quant_bits:
-        # per-(page, kv-head, token) fp32 scales ride the same clamped
-        # table walk as their payload pages
-        in_specs += [_page_spec(1), _page_spec(1)]
+        # per-(page, kv-head, token) fp32 scales ride the same walk
         operands += [k_scale, v_scale]
+        buffers += [pltpu.VMEM((2, n, kvh, ps, 1), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kvh, n_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h_, ib, mb, po, tb: (b_, h_, 0, 0)),
-        scratch_shapes=[_vmem((g, d)), _vmem((g, 128)), _vmem((g, 128))],
+        grid=(b,),
+        in_specs=[slot_spec] + [arena] * (len(operands) - 1),
+        out_specs=slot_spec,
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            _vmem((kvh, g, d)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
+        ],
     )
+    # the slots run in order: each starts the next one's first copies
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
-        **_decode_grid_params(interpret),
-    )(maxblk, pos, page_table.astype(jnp.int32), *operands)
+        **_grid_params(interpret, ("arbitrary",)),
+    )(lengths.astype(jnp.int32), pos, page_table.astype(jnp.int32), *operands)
     return out.reshape(b, h, sq, d)
 
 
@@ -1088,6 +1303,7 @@ def paged_decode_attention(
     *,
     page_table: jax.Array,
     q_positions: jax.Array,
+    kv_lengths: Optional[jax.Array] = None,
     sm_scale: Optional[float] = None,
     impl: Optional[str] = None,
     k_scale: Optional[jax.Array] = None,
@@ -1098,6 +1314,13 @@ def paged_decode_attention(
 
     q: [B, H, Sq, D]; k_pages/v_pages: [num_pages, KVH, page_size, D];
     ``page_table`` [B, P] int32; ``q_positions`` [B, Sq] global positions.
+    ``kv_lengths`` [B] int32 is each slot's count of live tokens, the bound
+    of the kernel's walk (absent: the slot's last row position + 1). A slot
+    given 0 is not walked at all and its output rows are zeros: the serving
+    engine's way to say "inactive" for a slot whose parked write position
+    would otherwise read as a request at the end of the cache. The mask is
+    ``kv position <= row position`` either way, and the dense path ignores
+    the lengths: an inactive slot's rows are discarded by the caller.
 
     On TPU (or under ``impl='interpret'``) the pallas paged kernel walks
     each slot's live pages DIRECTLY from the physical arena — the HBM read
@@ -1124,13 +1347,15 @@ def paged_decode_attention(
     if mode != "dense":
         sq, d = q.shape[2], q.shape[3]
         use, interpret = _decode_kernel_gate(
-            mode, sq, d, k_pages.shape[2], kv_quant_bits
+            mode, sq, d, k_pages.shape[2], kv_quant_bits, paged=True
         )
         if use:
             scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
             pos = _positions_2d(q_positions, q.shape[0])
+            if kv_lengths is None:
+                kv_lengths = jnp.max(pos, axis=1) + 1
             return _paged_decode_kernel_call(
-                q, k_pages, v_pages, page_table, pos, scale, interpret,
+                q, k_pages, v_pages, page_table, pos, kv_lengths, scale, interpret,
                 k_scale=k_scale, v_scale=v_scale, quant_bits=kv_quant_bits,
             )
     k_full = gather_kv_pages(k_pages, page_table)
@@ -1181,6 +1406,23 @@ _PREFILL_KERNEL_MODES = ("ragged", "dense", "interpret")
 # default q token block: one sublane tile; the packer pads each tail to
 # this granule (vs a whole prefill bucket on the chunked path)
 _PREFILL_TOKEN_BLOCK = 8
+# the tallest block a serving engine packs with. The kernel's grid is
+# (capacity / block) x kv heads x (table entries + capacity / block) steps
+# of some 0.2-0.5 us each, nearly all of them dead, so its time falls
+# almost in proportion to the block: on the chip, at the serving cells'
+# shape (PERF.md section 6, PR 25), 8 -> 16 -> 32 -> 64 rows cut the chat
+# cell's time to first token 4,455 -> 2,265 -> 1,740 -> 1,416 ms.
+_PREFILL_TOKEN_BLOCK_MAX = 64
+
+
+def prefill_token_block(capacities) -> int:
+    """Token block of a packed ragged dispatch compiled at these row
+    ``capacities``: as tall as the smallest allows (a taller block would
+    only pad it) while the largest still holds four, so that several
+    tails pack into one dispatch; in sublane tiles, at most
+    ``_PREFILL_TOKEN_BLOCK_MAX``. A slot's tail pads to this granule."""
+    rows = min(min(capacities), max(capacities) // 4, _PREFILL_TOKEN_BLOCK_MAX)
+    return max(_PREFILL_TOKEN_BLOCK, rows // _PREFILL_TOKEN_BLOCK * _PREFILL_TOKEN_BLOCK)
 
 
 def resolve_prefill_kernel(impl: Optional[str] = None) -> str:

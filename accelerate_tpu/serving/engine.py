@@ -48,7 +48,9 @@ from ..telemetry.spans import span as _span
 from ..ops.attention import (
     _PREFILL_TOKEN_BLOCK,
     decode_kernel_active,
+    paged_decode_block_pages,
     prefill_kernel_active,
+    prefill_token_block,
 )
 from .arena import arena_nbytes, init_arena, slot_view, write_slot
 from .pages import (
@@ -316,9 +318,17 @@ class ServingEngine:
             )
             if self.num_pages < 2:
                 raise ValueError(f"num_pages ({self.num_pages}) must be >= 2")
+            # the packed prefill dispatch's token block follows from the
+            # capacities compiled for it, unless the config names one;
+            # the model hands the same block to the kernel
+            self._ragged_bt = int(
+                getattr(definition.config, "prefill_kernel_block", None)
+                or prefill_token_block(self.prefill_chunks)
+            )
             self._paged_def = definition.clone(config=dataclasses.replace(
                 definition.config,
                 kv_page_size=self.page_size, kv_num_pages=self.num_pages,
+                prefill_kernel_block=self._ragged_bt,
             ))
             self._allocator = PageAllocator(self.num_pages, reserved=1)
             self._tables_host = PagedTables(
@@ -369,6 +379,7 @@ class ServingEngine:
             # serving/decode_kernel_active gauge)
             pcfg = self._paged_def.config
             self._kernel_costed = decode_kernel_active(pcfg)
+            self._walk_block_pages = paged_decode_block_pages(pcfg, self.pages_per_slot)
             # packed ragged prefill (ops/attention.ragged_prefill_attention):
             # when the flash prefill kernel (or its interpreter) engages,
             # the admission planner packs every pending tail into ONE
@@ -376,10 +387,6 @@ class ServingEngine:
             # padding only — instead of per-slot bucketed chunks. The
             # chunked path stays compiled as the fallback/oracle.
             self._ragged_prefill = prefill_kernel_active(pcfg)
-            self._ragged_bt = int(
-                getattr(pcfg, "prefill_kernel_block", None)
-                or _PREFILL_TOKEN_BLOCK
-            )
             rb = self._ragged_bt
             # fixed grid capacities compiled at warmup (the zero-recompile
             # invariant): each chunk bucket rounded up to the token block,
@@ -551,7 +558,10 @@ class ServingEngine:
             # slot's table row is reset to the parking page, so a parked
             # write can never land in another request's page.)
             write_pos = jnp.where(active, lengths, last_pos)
-            kwargs = {"page_table": page_tables} if paged else {}
+            # the paged decode kernel walks a slot's live pages only: an
+            # inactive slot, parked at the end of the cache, has none
+            kwargs = {"page_table": page_tables,
+                      "kv_lengths": jnp.where(active, lengths + 1, 0)} if paged else {}
             out, mutated = definition.apply(
                 {"params": placer(params), "cache": arena},
                 tokens[:, None],
@@ -611,6 +621,7 @@ class ServingEngine:
                 decode=True,
                 cache_positions=write_pos,
                 page_table=page_tables,
+                kv_lengths=jnp.where(active, lengths + k + 1, 0),
                 mutable=["cache"],
             )
             logits = out["logits"]  # [N, K+1, V]
@@ -2576,6 +2587,17 @@ class ServingEngine:
         ps = self.page_size
         return (pos // ps + 1) * ps
 
+    def _note_walk(self, sp, walked: list) -> None:
+        """What the decode kernel is handed this round, on the
+        ``serving/decode_grow`` span: ``walked`` holds each grown slot's
+        page-rounded tokens. A block is ``_walk_block_pages`` table
+        entries; a slot that is free or mid-admission has no live tokens
+        and is skipped whole."""
+        block = self._walk_block_pages * self.page_size
+        sp.args["walked_tokens"] = sum(walked)
+        sp.args["walked_blocks"] = sum(-(-w // block) for w in walked)
+        sp.args["skipped_slots"] = self.num_slots - len(self._slot_req)
+
     def _spec_verify_once(self) -> bool:
         """One speculative round: host drafter proposes K tokens per slot,
         one batched verify dispatch checks them all, the longest accepted
@@ -2590,7 +2612,7 @@ class ServingEngine:
         lb = int(getattr(self._drafter, "lookback", 0) or 0)
         with _span("serving/decode_grow") as sp:
             pages0 = self.pages_allocated
-            walked = 0
+            walked = []
             for slot, req in list(self._slot_req.items()):
                 if slot not in self._slot_req:
                     continue  # shed/preempted while relieving another slot
@@ -2603,9 +2625,9 @@ class ServingEngine:
                 drafts[slot] = self._drafter.propose(ctx, k)
                 pos = self._next_write_pos(req)
                 if self._grow_or_resolve(req, slot, pos, pos + k):
-                    walked += self._walked_tokens(pos + k)
+                    walked.append(self._walked_tokens(pos + k))
             sp.args["pages_allocated"] = self.pages_allocated - pages0
-            sp.args["walked_tokens"] = walked
+            self._note_walk(sp, walked)
             if not self._slot_req:
                 return True  # every live slot was shed under page pressure
             drafts_dev = jnp.asarray(drafts)
@@ -2688,15 +2710,15 @@ class ServingEngine:
                 # page-rounded tokens the decode kernel walks this round,
                 # counted as each slot is grown (a slot preempted later in
                 # this same loop, for another's pages, stays counted)
-                walked = 0
+                walked = []
                 for slot, req in list(self._slot_req.items()):
                     if slot not in self._slot_req:
                         continue  # shed/preempted while relieving another slot
                     pos = self._next_write_pos(req)
                     if self._grow_or_resolve(req, slot, pos, pos + k - 1):
-                        walked += self._walked_tokens(pos + k - 1)
+                        walked.append(self._walked_tokens(pos + k - 1))
                 sp.args["pages_allocated"] = self.pages_allocated - pages0
-                sp.args["walked_tokens"] = walked
+                self._note_walk(sp, walked)
             if not self._slot_req:
                 return True  # every live slot was shed under page pressure
         if self._faults is not None:

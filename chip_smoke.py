@@ -189,12 +189,16 @@ def _quantized_arena(rng, shape, bits):
 
 
 def check_paged_decode(S: Sizes, rng, *, kvh, bits) -> dict:
-    """{query width: max |kernel - reference|} over one arena."""
+    """{query width: (max |kernel - reference|, whether the gate let the
+    kernel run)} over one arena. On the chip the paged kernel takes
+    unquantized 128-wide pages only; the rest compares the dense path with
+    itself, and the line says so."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from accelerate_tpu.ops.attention import paged_decode_attention
+    from accelerate_tpu.ops.attention import (
+        _decode_kernel_gate, paged_decode_attention, resolve_decode_kernel)
 
     cfg = llama_cfg(S, 1)
     h, d, ps = cfg.num_heads, cfg.head_dim, S.page_size
@@ -218,7 +222,9 @@ def check_paged_decode(S: Sizes, rng, *, kvh, bits) -> dict:
         out = np.asarray(run(S.kernel_mode), np.float32)
         ref = np.asarray(run("dense"), np.float32)
         np.testing.assert_allclose(out, ref, atol=BF16_ATOL, rtol=BF16_RTOL)
-        errs[sq] = float(np.max(np.abs(out - ref)))
+        kernel, _ = _decode_kernel_gate(
+            resolve_decode_kernel(S.kernel_mode), sq, d, ps, bits, paged=True)
+        errs[sq] = float(np.max(np.abs(out - ref))), kernel
     return errs
 
 
@@ -301,8 +307,9 @@ def kernels_phase(S: Sizes, seed: int, on_chip: bool) -> None:
     for kvh in (heads, S.kv_heads_gqa):
         for bits in S.kv_bits:
             kv = {0: "bf16", 8: "int8", 4: "int4"}[bits]
-            for sq, err in check_paged_decode(S, rng, kvh=kvh, bits=bits).items():
-                say(f"  paged decode   {heads}q/{kvh}kv {kv} Sq={sq}: max|kernel-ref|={err:.4f}")
+            for sq, (err, kernel) in check_paged_decode(S, rng, kvh=kvh, bits=bits).items():
+                say(f"  paged decode   {heads}q/{kvh}kv {kv} Sq={sq}: max|kernel-ref|={err:.4f}"
+                    + ("" if kernel else " (gated to the dense path: no kernel ran)"))
             err, mism = check_ragged_prefill(S, rng, kvh=kvh, bits=bits)
             say(f"  ragged prefill {heads}q/{kvh}kv {kv}: max|kernel-ref|={err:.4f}"
                 + (f", payload mismatch share={mism:.1e}" if bits else ""))

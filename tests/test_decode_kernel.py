@@ -153,6 +153,103 @@ class TestPagedKernelExactness:
                                    atol=ATOL, rtol=1e-5)
 
 
+_EDGE_PS, _EDGE_BLOCK, _EDGE_TABLE = 8, 4, 10  # page, pages a block, table entries
+_EDGES = {  # live length of the slot under test
+    "1": 1,
+    "page-1": _EDGE_PS - 1,
+    "page": _EDGE_PS,
+    "block-1": _EDGE_BLOCK * _EDGE_PS - 1,
+    "block": _EDGE_BLOCK * _EDGE_PS,
+    "block+1": _EDGE_BLOCK * _EDGE_PS + 1,
+    "table_end": _EDGE_TABLE * _EDGE_PS,
+}
+
+
+def _edge_setup(rng, *, h, kvh, sq, kv, live):
+    """Four slots: the length under test, a slot with NO live tokens, a
+    second live one, and one more inactive at the end; shuffled,
+    non-monotonic tables whose unallocated entries all park on page 0."""
+    from accelerate_tpu.utils.quantization import quantize_kv
+
+    ps, per, d = _EDGE_PS, _EDGE_TABLE, 32
+    lens = np.array([live, 0, max(live // 2, sq) + 3 * ps, 0])
+    lens = np.maximum(lens, np.where(lens > 0, sq, 0))  # Sq rows need Sq positions
+    num_pages = 1 + len(lens) * per
+    dtype = jnp.bfloat16 if kv == "bf16" else jnp.float32
+    q = _rand(rng, (len(lens), h, sq, d)).astype(dtype)
+    kp = _rand(rng, (num_pages, kvh, ps, d)).astype(dtype)
+    vp = _rand(rng, (num_pages, kvh, ps, d)).astype(dtype)
+    kw = {}
+    if kv != "bf16":
+        bits = int(kv[3:])
+        (kp, ks), (vp, vs) = quantize_kv(kp, bits), quantize_kv(vp, bits)
+        kw = {"k_scale": ks, "v_scale": vs, "kv_quant_bits": bits}
+    free = list(rng.permutation(np.arange(1, num_pages)))
+    table = np.zeros((len(lens), per), np.int32)  # 0: the parking page
+    for s, n in enumerate(lens):
+        for e in range(-(-int(n) // ps)):
+            table[s, e] = free.pop()
+    # the last Sq positions of a live slot; an inactive slot is handed the
+    # engine's parked position, the end of the cache
+    pos = np.where(lens[:, None] > 0, lens[:, None] - sq + np.arange(sq)[None, :], per * ps - 1)
+    return q, kp, vp, jnp.asarray(table), jnp.asarray(pos, jnp.int32), jnp.asarray(lens, jnp.int32), kw
+
+
+class TestPagedWalk:
+    """The walk of live pages in blocks of many (PR 25): parity with the
+    masked-dense reference at every edge a block boundary can meet."""
+
+    @pytest.mark.parametrize("kv", ["bf16", "int8", "int4"])
+    @pytest.mark.parametrize("sq,h,kvh", [(1, 4, 4), (5, 4, 4), (1, 32, 8), (5, 32, 8)],
+                             ids=["sq1-mha", "sq5-mha", "sq1-gqa32q8kv", "sq5-gqa32q8kv"])
+    @pytest.mark.parametrize("edge", sorted(_EDGES))
+    def test_block_edges_zero_live_slots_shuffled_tables(self, monkeypatch, edge, sq, h, kvh, kv):
+        import accelerate_tpu.ops.attention as A
+
+        monkeypatch.setattr(A, "_PAGED_DECODE_MAX_BLOCK_PAGES", _EDGE_BLOCK)
+        rng = np.random.RandomState(sorted(_EDGES).index(edge))
+        q, kp, vp, table, pos, lens, kw = _edge_setup(
+            rng, h=h, kvh=kvh, sq=sq, kv=kv, live=_EDGES[edge])
+        out = paged_decode_attention(
+            q, kp, vp, page_table=table, q_positions=pos, kv_lengths=lens,
+            impl="interpret", **kw)
+        ref = paged_decode_attention(
+            q, kp, vp, page_table=table, q_positions=pos, impl="dense", **kw)
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        live = np.asarray(lens) > 0
+        tol = dict(atol=2e-2, rtol=2e-2) if kv == "bf16" else dict(atol=ATOL, rtol=1e-5)
+        np.testing.assert_allclose(out[live], ref[live], **tol)
+        # a slot with no live tokens is not walked: zeros, whatever its
+        # parked position and the parking page hold
+        np.testing.assert_array_equal(out[~live], 0.0)
+
+    def test_lengths_default_to_the_last_row_position(self):
+        """Absent ``kv_lengths`` the walk is bounded by the positions, as
+        before: the same output as with the lengths spelled out."""
+        rng = np.random.RandomState(11)
+        q, kp, vp, table, pos, lens, _ = _edge_setup(rng, h=4, kvh=2, sq=3, kv="bf16", live=21)
+        live = np.asarray(lens) > 0
+        kw = dict(page_table=table[live], q_positions=pos[live], impl="interpret")
+        np.testing.assert_array_equal(
+            np.asarray(paged_decode_attention(q[live], kp, vp, **kw), np.float32),
+            np.asarray(paged_decode_attention(q[live], kp, vp, kv_lengths=lens[live], **kw), np.float32))
+
+    def test_block_tails_never_read_uninitialized_memory(self):
+        """Pages past a slot's frontier are not copied, so the tail of a
+        block's buffer holds what was there before; the TPU interpreter
+        fills fresh buffers with NaN, which the kernel must have cleared
+        (a masked probability of zero times NaN would still be NaN)."""
+        import accelerate_tpu.ops.attention as A
+        from jax.experimental.pallas import tpu as pltpu
+
+        rng = np.random.RandomState(12)
+        q, kp, vp, table, pos, lens, _ = _edge_setup(rng, h=4, kvh=2, sq=1, kv="bf16", live=3)
+        out = A._paged_decode_kernel_call(
+            q, kp, vp, table, pos, lens, 0.25,
+            pltpu.InterpretParams(uninitialized_memory="nan"))
+        assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
 class TestDenseArenaKernel:
     def test_shared_positions_single_stream_form(self):
         """[Sq] shared positions — the single-stream generate() decode

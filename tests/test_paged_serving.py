@@ -486,6 +486,48 @@ class TestPagedDecodeKernelServing:
         assert req.spec_accepted > 0
 
 
+    def test_free_and_mid_admission_slots_are_skipped_whole(self, kernel_model):
+        """Decode steps run while one slot is free and another is in the
+        middle of a ragged admission of several dispatches: both are handed
+        to the kernel with no live tokens (their parked write position is
+        the end of the cache). Tokens equal the dense decode path's, and
+        ``serving/decode_grow`` says what the kernel was handed."""
+        import dataclasses
+
+        from accelerate_tpu.telemetry import spans
+
+        model, cfg, params, prompts = kernel_model
+        rng = np.random.RandomState(5)
+        short, long_ = prompts[0], rng.randint(3, cfg.vocab_size, (21,))
+
+        def serve(decode_kernel):
+            kcfg = dataclasses.replace(cfg, decode_kernel=decode_kernel, prefill_kernel="interpret")
+            engine = self._kengine(model.clone(config=kcfg), params, num_slots=3, prefill_chunks=(8,))
+            first = engine.submit(short, max_new_tokens=12, seed=0)
+            engine.step()  # admitted and decoding before the long prompt arrives
+            second = engine.submit(long_, max_new_tokens=4, seed=1)
+            mark = max(s[0] for s in spans.snapshot())  # the ring may be full: go by id
+            engine.run()
+            grows = [s[5] for s in spans.snapshot() if s[0] > mark and s[2] == "serving/decode_grow"]
+            return engine, [first.result(), second.result()], grows
+
+        engine, outs, grows = serve("interpret")
+        assert engine.metrics()["serving/decode_kernel_active"] is True
+        for out, ref in zip(outs, serve("dense")[1]):
+            np.testing.assert_array_equal(out, ref)
+        block = engine._walk_block_pages * PS
+        assert block == 8 * PS  # the whole table of 64 / 8 entries: one block a live slot
+        # the long prompt takes three dispatches of 8 rows, the last one
+        # in the iteration whose decode step already carries it: two decode
+        # steps of the first request alone (2 of 3 slots skipped), then
+        # both live until the long one's four tokens are out
+        assert [g["skipped_slots"] for g in grows[:6]] == [2, 2, 1, 1, 1, 2]
+        for g in grows:
+            live = 3 - g["skipped_slots"]
+            assert g["walked_blocks"] == live
+            assert live * PS <= g["walked_tokens"] <= live * block
+
+
 @pytest.mark.slow
 class TestPagedBurstIntegration:
     def test_long_mixed_burst_exact_and_leak_free(self, served_model):

@@ -294,6 +294,44 @@ ENG_KW = dict(num_slots=2, max_cache_len=64, prefill_chunks=(4, 8),
               page_size=8)
 
 
+@pytest.mark.parametrize("capacities,block", [
+    ((64, 256), 64),    # the engine's default chunks: the serving cells
+    ((256, 1024), 64),  # never above the sweep's largest
+    ((32, 128), 32),    # the smallest capacity is one block
+    ((64,), 16),        # a single capacity still holds four blocks
+    ((100,), 24),       # whole sublane tiles
+    ((16,), 8), ((4, 8), 8), ((8, 32), 8),  # never under one tile
+])
+def test_token_block_follows_the_compiled_capacities(capacities, block):
+    from accelerate_tpu.ops.attention import prefill_token_block
+
+    assert prefill_token_block(capacities) == block
+
+
+def test_engine_packs_in_the_block_its_capacities_give(ragged_models):
+    """Chunks (16, 64) give a 16-row block: the engine pads tails to it,
+    hands the model the same block (the kernel refuses a capacity the
+    block does not divide), a named ``prefill_kernel_block`` still wins,
+    and tokens equal the chunked dense engine's."""
+    import dataclasses
+
+    from accelerate_tpu.serving import ServingEngine
+
+    model_k, model_d, cfg_k, params = ragged_models
+    kw = dict(ENG_KW, prefill_chunks=(16, 64))
+    eng_k = ServingEngine(model_k, params, **kw)
+    assert eng_k._ragged_bt == eng_k._paged_def.config.prefill_kernel_block == 16
+    assert eng_k._ragged_caps == (16, 64)
+    named = model_k.clone(config=dataclasses.replace(cfg_k, prefill_kernel_block=8))
+    assert ServingEngine(named, params, **kw)._ragged_bt == 8
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(3, 16, (n,)) for n in (37, 5, 18, 23)]
+    outs_d = ServingEngine(model_d, params, **kw).generate_batched(prompts, max_new_tokens=4)
+    for out_k, out_d in zip(eng_k.generate_batched(prompts, max_new_tokens=4), outs_d):
+        np.testing.assert_array_equal(out_k, out_d)
+    assert eng_k.metrics()["serving/prefill_packed_tokens"] == sum(p.size for p in prompts)
+
+
 class TestEngineRaggedAdmission:
     def test_token_parity_and_gauges(self, ragged_models):
         """Ragged engine == chunked engine == single-stream generate(),

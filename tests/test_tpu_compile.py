@@ -73,16 +73,18 @@ def _kv_operands(chip, shape, d, bits):
     return payload, (S((*shape, 1), jnp.float32) if bits else None)
 
 
-def _paged_decode(chip, *, h=32, kvh=32, d=128, ps=16, sq=1, bits=0):
+def _paged_decode(chip, *, h=32, kvh=32, d=128, ps=16, sq=1, bits=0,
+                  slots=SLOTS, pages=PAGES, table=None):
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    kp, ks = _kv_operands(chip, (PAGES, kvh, ps), d, bits)
+    kp, ks = _kv_operands(chip, (pages, kvh, ps), d, bits)
 
-    def fn(q, kp, vp, table, pos, ks, vs):
+    def fn(q, kp, vp, table, pos, lengths, ks, vs):
         return A._paged_decode_kernel_call(
-            q, kp, vp, table, pos, SM_SCALE, False, k_scale=ks, v_scale=vs, quant_bits=bits)
+            q, kp, vp, table, pos, lengths, SM_SCALE, False, k_scale=ks, v_scale=vs, quant_bits=bits)
 
-    return fn, (S((SLOTS, h, sq, d), jnp.bfloat16), kp, kp,
-                S((SLOTS, 2048 // ps), jnp.int32), S((SLOTS, sq), jnp.int32), ks, ks)
+    return fn, (S((slots, h, sq, d), jnp.bfloat16), kp, kp,
+                S((slots, table or 2048 // ps), jnp.int32), S((slots, sq), jnp.int32),
+                S((slots,), jnp.int32), ks, ks)
 
 
 def _dense_decode(chip, *, bits=0):
@@ -118,21 +120,23 @@ CASES = {
     "flash_d64_kv_mask_fwd_bwd": (_flash, dict(b=8, h=12, kvh=12, s=512, d=64, mask="kv_mask")),
     "flash_d64_segment_ids_fwd_bwd": (_flash, dict(b=8, h=12, kvh=12, s=512, d=64, mask="segments")),
     "flash_32k_context_fwd": (_flash, dict(b=1, h=8, kvh=8, s=32768, d=128, grad=False)),
-    # paged decode: KV storage x head_dim x query width (1 = decode, 5 = verify)
-    **{
-        f"paged_decode_{kv}_d{d}_sq{sq}": (_paged_decode, dict(h=h, kvh=h, d=d, sq=sq, bits=bits))
-        for kv, bits in (("bf16", 0), ("int8", 8), ("int4", 4))
-        for d, h in ((128, 32), (64, 12))
-        for sq in (1, 5)
-    },
-    "paged_decode_int4_gqa_32q8kv": (_paged_decode, dict(kvh=8, bits=4)),
-    "paged_decode_int4_page128": (_paged_decode, dict(ps=128, bits=4)),
+    # paged decode, query width 1 = decode, 5 = verify; the serving cells'
+    # own shape (benchmarks/configs/mistral-7b-v0.3-serve-16l.json)
+    "paged_decode_bf16_d128_sq1": (_paged_decode, dict(sq=1)),
+    "paged_decode_bf16_d128_sq5": (_paged_decode, dict(sq=5)),
+    "paged_decode_bf16_gqa_32q8kv_sq5": (_paged_decode, dict(kvh=8, sq=5)),
+    "paged_decode_bf16_page8": (_paged_decode, dict(kvh=8, ps=8)),
+    "paged_decode_serving_cell": (_paged_decode, dict(kvh=8, slots=32, pages=3584, table=256)),
     # ragged prefill with quantize-on-write: MHA and GQA x KV storage
     **{
         f"ragged_prefill_{name}_{kv}": (_ragged_prefill, dict(kvh=kvh, bits=bits))
         for name, kvh in (("mha", 32), ("gqa_32q8kv", 8))
         for kv, bits in (("bf16", 0), ("int8", 8), ("int4", 4))
     },
+    # the token block a serving engine's default capacities give (64 rows)
+    "ragged_prefill_gqa_32q8kv_bf16_block64": (_ragged_prefill, dict(kvh=8, bt=64)),
+    "ragged_prefill_gqa_32q8kv_int8_block64": (_ragged_prefill, dict(kvh=8, bits=8, bt=64)),
+    "ragged_prefill_mha_int4_block64": (_ragged_prefill, dict(bits=4, bt=64)),
     "ragged_prefill_mha_d64": (_ragged_prefill, dict(h=12, kvh=12, d=64)),
     "ragged_prefill_gqa_d64_int8": (_ragged_prefill, dict(h=12, kvh=4, d=64, bits=8)),
     "ragged_prefill_gqa_int4_page128": (_ragged_prefill, dict(kvh=8, ps=128, bits=4)),
@@ -142,12 +146,46 @@ CASES = {
 }
 
 
+# paged decode variants the chip's compiler refuses: the kernel copies whole
+# pages out of the arena in HBM, and a slice of an HBM array whose last
+# dimension is not a 128-multiple is not one Mosaic lays out: a 64-wide
+# head, an int4 payload (head_dim / 2 wide), and the [.., page_size, 1]
+# scale pages of every quantized arena. The gate keeps each off the kernel.
+REFUSED = {
+    **{
+        f"paged_decode_{kv}_d{d}_sq{sq}": dict(h=h, kvh=h, d=d, sq=sq, bits=bits)
+        for kv, bits in (("bf16", 0), ("int8", 8), ("int4", 4))
+        for d, h in ((128, 32), (64, 12))
+        for sq in (1, 5)
+        if bits or d == 64
+    },
+    "paged_decode_int4_gqa_32q8kv": dict(kvh=8, bits=4),
+    "paged_decode_int4_page128": dict(ps=128, bits=4),
+}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(chip, case):
     build, kw = CASES[case]
     fn, args = build(chip, **kw)
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_paged_decode_the_compiler_refuses_is_gated_off(chip, case, monkeypatch):
+    """Both halves by name: Mosaic refuses the variant (when it stops, the
+    gate can open), and the gate sends it to the dense path, so a server
+    never meets the MosaicError at warmup."""
+    kw = REFUSED[case]
+    fn, args = _paged_decode(chip, **kw)
+    with pytest.raises(Exception, match="must be aligned to tiling"):
+        jax.jit(fn).lower(*args).compile()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(A, "_decode_fallback_warned", set())
+    gate = A._decode_kernel_gate(
+        "paged", kw.get("sq", 1), kw.get("d", 128), kw.get("ps", 16), kw.get("bits", 0), paged=True)
+    assert gate == (False, False)
 
 
 # the names the benchmark's trace reduction finds the kernels by: an
@@ -224,3 +262,6 @@ def test_gates_admit_only_what_compiles(monkeypatch):
             admitted = not (bits == 4 and d == 64)
             assert A._decode_kernel_gate("paged", 1, d, 16, bits) == (admitted, False)
             assert A._prefill_kernel_gate("ragged", d, 16, 8, bits) == (admitted, False)
+            # the page-table kernel: CASES has what it admits, REFUSED the rest
+            paged = A._decode_kernel_gate("paged", 1, d, 16, bits, paged=True)
+            assert paged == (d == 128 and not bits, False)
