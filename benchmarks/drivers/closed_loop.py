@@ -18,10 +18,8 @@ import time
 import numpy as np
 
 import costs
-import program_adapter
 import traffic_gen
 import weights
-from reference import mistral
 
 
 class Rec:
@@ -44,9 +42,10 @@ class Rec:
 
 
 class ClosedLoop:
-    def __init__(self, engine, traffic: dict, seed: int, vocab: int, page_size: int, spans):
-        self.engine, self.traffic, self.seed, self.vocab = engine, traffic, seed, vocab
-        self.page_size, self.spans = page_size, spans
+    def __init__(self, engine, traffic: dict, seed: int, arch, config: dict, spans):
+        self.engine, self.traffic, self.seed, self.spans = engine, traffic, seed, spans
+        self.arch, self.config = arch, config
+        self.vocab, self.page_size = arch.vocab(config), config["serving"]["page_size"]
         self.trace = traffic_gen.expand(traffic)
         self.clients = int(traffic["clients"])
         self.cursor = 0                       # next session of the trace
@@ -101,7 +100,7 @@ class ClosedLoop:
         with self.spans.span("bench/emit"):
             i = len(self.iters)
             prefill = eng.prefill_packed_tokens > packed0
-            walked = decoded = 0
+            walked = kv_bytes = decoded = 0
             comp = []
             for rec in self.live:
                 if rec is None:
@@ -113,13 +112,16 @@ class ClosedLoop:
                 if n and rec.first_iter is None:
                     rec.first_iter = i
                 if n - max(rec.seen, 1) >= 1:  # a decode step wrote at prompt + n - 2
-                    walked += costs.page_rounded(len(req.prompt) + n - 2, self.page_size)
+                    write_pos = len(req.prompt) + n - 2
+                    walked += costs.page_rounded(write_pos, self.page_size)
+                    kv_bytes += self.arch.decode_kv_bytes(self.config, write_pos, self.page_size)
                     decoded += 1
                 if n:
                     comp.append((rec.session, rec.ask, n))
                 rec.seen = n
             it = {"t0": t0, "t1": t1, "prefill": prefill, "emitted": eng.generated_tokens - gen0,
-                  "decoded": decoded, "walked_tokens": walked, "live": len(comp), "comp": tuple(comp)}
+                  "decoded": decoded, "walked_tokens": walked, "kv_bytes": kv_bytes, "live": len(comp),
+                  "comp": tuple(comp)}
             if self.poll_pages:
                 it["pages_in_use"] = eng.metrics().get("serving/pages_in_use")
             self.iters.append(it)
@@ -130,16 +132,15 @@ def build_engine(ctx):
     configuration's deployment settings and the engine's defaults."""
     import jax.numpy as jnp
 
-    from accelerate_tpu.models import DecoderLM
     from accelerate_tpu.serving import ServingEngine
 
-    c, s = ctx.model, ctx.settings["serving"]
+    arch, c, s = ctx.arch, ctx.settings, ctx.settings["serving"]
     kernel = "interpret" if ctx.rehearsal else None
-    cfg = program_adapter.decoder_config(
+    cfg = arch.decoder_config(
         c, max_seq_len=s["max_cache_len"], remat=False, decode_kernel=kernel, prefill_kernel=kernel)
-    params = weights.make_jit(c, ctx.seed, jnp.bfloat16, adapt=program_adapter.to_program_tree(c))
+    params = weights.make_jit(arch.reference, c, ctx.seed, jnp.bfloat16, adapt=arch.to_program_tree(c))
     engine = ServingEngine(
-        DecoderLM(cfg), params, page_size=s["page_size"], num_slots=s["num_slots"],
+        arch.module(cfg), params, page_size=s["page_size"], num_slots=s["num_slots"],
         max_cache_len=s["max_cache_len"], num_pages=s["num_pages"],
         **s.get("engine_kwargs", {}))
     del params
@@ -157,13 +158,13 @@ def run(ctx) -> dict:
 
     from accelerate_tpu.utils.compile_cache import compile_event_counters
 
-    traffic, c, s = ctx.traffic, ctx.model, ctx.settings["serving"]
+    traffic, c, s = ctx.traffic, ctx.settings, ctx.settings["serving"]
     ctx.say(f"traffic {traffic['name']}: {traffic['clients']} clients, longest request "
             f"{traffic_gen.longest_request(traffic, s['max_cache_len'])} tokens")
     engine = build_engine(ctx)
     ctx.say(f"engine warm: arena {engine.arena_bytes / 2**30:.2f} GiB in {engine.num_pages} pages, "
             f"{time.perf_counter() - ctx.t_start:.1f}s since start")
-    loop = ClosedLoop(engine, traffic, ctx.seed, c["vocab_size"], s["page_size"], ctx.spans)
+    loop = ClosedLoop(engine, traffic, ctx.seed, ctx.arch, c, ctx.spans)
     loop.poll_pages = ctx.trace
     warm_in = int(traffic["warm_in_iterations"] if not ctx.rehearsal else traffic.get("rehearsal_warm_in", 8))
     for _ in range(warm_in):
@@ -233,7 +234,6 @@ def run(ctx) -> dict:
         "pages_in_use_peak": max((it.get("pages_in_use") or 0 for it in loop.iters), default=0),
         "num_pages": engine.num_pages,
         "live_tokens_page_rounded_peak": max(it["walked_tokens"] for it in loop.iters),
-        "kv_bytes_per_token": costs.kv_bytes_per_token(c),
         "compiles_in_window": compiles,
     }
     if traced_from is not None:
@@ -241,7 +241,7 @@ def run(ctx) -> dict:
         counters["traced"] = {
             "t0": traced[0]["t0"], "t1": traced[-1]["t1"],
             "decode_steps": sum(1 for it in traced if it["decoded"]),
-            "decode_walked_tokens": sum(it["walked_tokens"] for it in traced),
+            "decode_kv_bytes": sum(it["kv_bytes"] for it in traced),
             "prefill_dispatches": sum(it["prefill"] for it in traced),
         }
 
@@ -252,7 +252,10 @@ def run(ctx) -> dict:
     cases = [(np.asarray(r.req.prompt), np.asarray(r.req.tokens, np.int32), r.req.prefix_hit) for r in sample]
     iteration_log = {"warm_in": i0, "live": [it["live"] for it in loop.iters],
                      "prefill": [int(it["prefill"]) for it in loop.iters],
-                     "ms": [round(1e3 * (it["t1"] - it["t0"]), 3) for it in loop.iters]}
+                     "ms": [round(1e3 * (it["t1"] - it["t0"]), 3) for it in loop.iters],
+                     # (client, submitted at, first token at, prompt tokens found cached): where a mix's
+                     # warm-in ends is read from these, once
+                     "requests": [(r.client, r.submit_iter, r.first_iter, int(r.req.prefix_hit)) for r in loop.recs]}
     n_attempted, n_failed, n_bad = len(submitted), len(failed), len(failed) + len(shed_any)
     del loop, engine, firsts, submitted, failed, shed_any, done, sample
     gc.collect()
@@ -260,8 +263,11 @@ def run(ctx) -> dict:
 
     check = compare(ctx, cases)
     ok = check["ok"] and compiles == 0 and n_bad == 0
+    compared = {"served_logit_gap": (check["served_logit_gap"], float(ctx.limits["served_logit_gap"])),
+                "nothing_compared": (int(not cases), 0), "compiles_in_window": (compiles, 0),
+                "requests_not_finished": (n_bad, 0)}
     return {"values": values, "counters": counters, "attempted": n_attempted, "failed": n_failed,
-            "correct": ok, "memory_peak_bytes": int(peak), "check": check,
+            "correct": ok, "memory_peak_bytes": int(peak), "check": check, "compared": compared,
             "iterations": iteration_log}
 
 
@@ -288,23 +294,23 @@ def compare(ctx, cases: list) -> dict:
     gap by which a served token's logit lies below the reference's best."""
     import jax.numpy as jnp
 
-    c = ctx.model
+    c, reference = ctx.settings, ctx.arch.reference
     limit = float(ctx.limits["served_logit_gap"])
     t0 = time.perf_counter()
-    w = weights.make_jit(c, ctx.seed, jnp.bfloat16)
+    w = weights.make_jit(reference, c, ctx.seed, jnp.bfloat16)
     worst = worst_control = 0.0
     tokens = agree = 0
     for prompt, served, hit in cases:
         ids = np.concatenate([prompt, served[:-1]])
         rows = np.arange(len(prompt) - 1, len(prompt) - 1 + len(served))
-        ref = np.asarray(mistral.logits_at(c, w, ids, rows, "float32"))
+        ref = np.asarray(reference.logits_at(c, w, ids, rows, "float32"))
         best = ref.max(axis=-1)
         gap = best - ref[np.arange(len(served)), served]
         worst = max(worst, float(gap.max()))
         agree += int((gap == 0).sum())
         tokens += len(served)
         if ctx.control:
-            low = np.asarray(mistral.logits_at(c, w, ids, rows, ctx.control)).argmax(axis=-1)
+            low = np.asarray(reference.logits_at(c, w, ids, rows, ctx.control)).argmax(axis=-1)
             worst_control = max(worst_control, float((best - ref[np.arange(len(served)), low]).max()))
     took = time.perf_counter() - t0
     ok = bool(cases) and worst <= limit

@@ -14,8 +14,6 @@ import time
 
 import numpy as np
 
-import costs
-import program_adapter
 import weights
 from reference import train as ref_train
 
@@ -42,39 +40,38 @@ def build(ctx):
 
     from accelerate_tpu import Accelerator, Model
     from accelerate_tpu.data import DataLoader
-    from accelerate_tpu.models import DecoderLM
     from accelerate_tpu.parallel.sharding import infer_param_sharding, unbox_params
     from accelerate_tpu.state import AcceleratorState
     from accelerate_tpu.utils.dataclasses import ShardingConfig
 
-    c, t, traffic = ctx.model, ctx.settings["training"], ctx.traffic
+    arch, c, t, traffic = ctx.arch, ctx.settings, ctx.settings["training"], ctx.traffic
     AcceleratorState._reset_state(reset_partial_state=True)
     sharding = ShardingConfig(fsdp=t["fsdp"], tensor_parallel=t["tensor_parallel"])
     accelerator = Accelerator(mixed_precision=t["mixed_precision"], sharding_config=sharding)
-    cfg = program_adapter.decoder_config(
+    cfg = arch.decoder_config(
         c, max_seq_len=traffic["sequence_length"], remat=t["remat"], remat_policy=t["remat_policy"],
         **({"attention_impl": "xla"} if ctx.rehearsal else {}))
-    model_def = DecoderLM(cfg, mesh=accelerator.mesh)
+    model_def = arch.module(cfg, mesh=accelerator.mesh)
     boxed = jax.eval_shape(lambda k: model_def.init(k, jnp.zeros((1, 8), jnp.int32)), jax.random.PRNGKey(0))["params"]
     raw, axes = unbox_params(boxed)
     shardings = infer_param_sharding(raw, accelerator.mesh, sharding, axes)
-    params = weights.make_jit(c, ctx.seed, jnp.float32, adapt=program_adapter.to_program_tree(c),
+    params = weights.make_jit(arch.reference, c, ctx.seed, jnp.float32, adapt=arch.to_program_tree(c),
                               out_shardings=shardings)
     is_boxed = lambda l: hasattr(l, "unbox")
     variables = {"params": jax.tree_util.tree_map(
         lambda box, value: box.replace_boxed(value) if is_boxed(box) else value, boxed, params, is_leaf=is_boxed)}
     tx = optax.adamw(t["learning_rate"], b1=t["b1"], b2=t["b2"], eps=t["eps"], weight_decay=t["weight_decay"])
-    rows = PackedRows(ctx.seed, c["vocab_size"], traffic["sequence_length"], traffic["sequences_per_step"] * 100_000)
+    rows = PackedRows(ctx.seed, arch.vocab(c), traffic["sequence_length"], traffic["sequences_per_step"] * 100_000)
     loader = DataLoader(rows, batch_size=traffic["sequences_per_step"])
     model, optimizer, loader = accelerator.prepare(Model(model_def, variables), tx, loader)
     del params, variables
     return accelerator, model, optimizer, iter(loader), accelerator.build_train_step(), shardings
 
 
-def program_leaf_norms(c, tree) -> dict:
+def program_leaf_norms(arch, c, tree) -> dict:
     import jax
 
-    norms = jax.jit(lambda p: ref_train.leaf_norms(program_adapter.from_program_tree(c, p)))(tree)
+    norms = jax.jit(lambda p: ref_train.leaf_norms(arch.reference, arch.from_program_tree(c, p)))(tree)
     return {k: float(v) for k, v in norms.items()}
 
 
@@ -84,10 +81,10 @@ def run(ctx) -> dict:
 
     from accelerate_tpu.utils.compile_cache import compile_event_counters
 
-    c, t, traffic = ctx.model, ctx.settings["training"], ctx.traffic
+    arch, c, t, traffic = ctx.arch, ctx.settings, ctx.settings["training"], ctx.traffic
     tokens_per_step = traffic["sequences_per_step"] * traffic["sequence_length"]
     accelerator, model, optimizer, batches, step, shardings = build(ctx)
-    ctx.say(f"prepared: mesh {accelerator.state.mesh_shape}, {costs.total_params(c) / 1e9:.3f}B parameters, "
+    ctx.say(f"prepared: mesh {accelerator.state.mesh_shape}, {arch.total_params(c) / 1e9:.3f}B parameters, "
             f"{tokens_per_step} tokens a step, {time.perf_counter() - ctx.t_start:.1f}s since start")
 
     def one_step():
@@ -106,12 +103,12 @@ def run(ctx) -> dict:
         if n == 0:  # Adam's first moment after one step is (1 - b1) * gradient
             mu = [s.mu for s in jax.tree_util.tree_leaves(
                 optimizer.state, is_leaf=lambda s: hasattr(s, "mu")) if hasattr(s, "mu")][0]
-            grad_norms = {k: v / (1 - t["b1"]) for k, v in program_leaf_norms(c, mu).items()}
+            grad_norms = {k: v / (1 - t["b1"]) for k, v in program_leaf_norms(arch, c, mu).items()}
         if n == n_ref - 1:
-            p0 = weights.make_jit(c, ctx.seed, jnp.float32, adapt=program_adapter.to_program_tree(c),
+            p0 = weights.make_jit(arch.reference, c, ctx.seed, jnp.float32, adapt=arch.to_program_tree(c),
                                   out_shardings=shardings)
             delta = jax.jit(lambda a, b: jax.tree_util.tree_map(lambda x, y: x - y, a, b))(model.params, p0)
-            update_norms = program_leaf_norms(c, delta)
+            update_norms = program_leaf_norms(arch, c, delta)
             del p0, delta
     ctx.setup_done()
 
@@ -139,7 +136,7 @@ def run(ctx) -> dict:
     ctx.say(f"window {window_s:.3f}s, {n_window} steps, {rate:.1f} tokens/s, loss {losses[0]:.4f} -> "
             f"{losses[n_window - 1]:.4f}, {len(bad)} non-finite, {compiles} compiles in the window")
     counters = {"kind": "train", "window_s": window_s, "steps": n_window, "train_tokens_per_s": rate,
-                "flops_per_token": costs.train_flops_per_token(c, traffic["sequence_length"]),
+                "flops_per_token": arch.train_flops_per_token(c, traffic["sequence_length"]),
                 "next_batch_s": ctx.spans.total("bench/next_batch", since=t0, until=ends[-1]),
                 "compiles_in_window": compiles, "traced": traced}
 
@@ -159,8 +156,11 @@ def run(ctx) -> dict:
     ctx.say(f"loss of the first {n_ref} steps {sum(first_losses[:n_ref]) / n_ref:.4f}, of the window's last "
             f"{len(tail)} {sum(tail) / len(tail):.4f} ({'not rising' if falling else 'RISING'})")
     ok = check["ok"] and compiles == 0 and not bad and falling
+    compared = {k: (v, ctx.limits[k]) for k, v in check["numbers"].items()}
+    compared.update(compiles_in_window=(compiles, 0), non_finite_losses=(len(bad), 0),
+                    loss_last_quarter_over_first_steps=(sum(tail) / len(tail) / (sum(first_losses[:n_ref]) / n_ref), 1.02))
     return {"values": {"train_tokens_per_s": rate}, "counters": counters, "attempted": n_window,
-            "failed": len(bad), "correct": ok, "memory_peak_bytes": int(peak), "check": check}
+            "failed": len(bad), "correct": ok, "memory_peak_bytes": int(peak), "check": check, "compared": compared}
 
 
 def compare(ctx, program: dict, shardings) -> dict:
@@ -171,14 +171,15 @@ def compare(ctx, program: dict, shardings) -> dict:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    c, t, traffic, lim = ctx.model, ctx.settings["training"], ctx.traffic, ctx.limits
+    c, t, traffic, lim = ctx.settings, ctx.settings["training"], ctx.traffic, ctx.limits
+    reference = ctx.arch.reference
     t0 = time.perf_counter()
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("x",))
-    shard = {k: NamedSharding(mesh, P(*([None] * (len(s) - 1 + (k in weights.LAYER_LEAVES)) + ["x"])))
+    shard = {k: NamedSharding(mesh, P(*([None] * (len(s) - 1 + (k in reference.LAYER_LEAVES)) + ["x"])))
              if (s[-1] % len(jax.devices()) == 0 and len(s) > 1) else NamedSharding(mesh, P())
-             for k, s in weights.shapes(c).items()}
-    make_w0 = lambda: weights.make_jit(c, ctx.seed, jnp.float32, out_shardings=shard)
-    rows = PackedRows(ctx.seed, c["vocab_size"], traffic["sequence_length"], 1 << 30)
+             for k, s in reference.shapes(c).items()}
+    make_w0 = lambda: weights.make_jit(reference, c, ctx.seed, jnp.float32, out_shardings=shard)
+    rows = PackedRows(ctx.seed, ctx.arch.vocab(c), traffic["sequence_length"], 1 << 30)
     n, b = len(program["losses"]), traffic["sequences_per_step"]
     # groups of one row a device, taken one after another
     per = len(jax.devices()) if b % len(jax.devices()) == 0 else b
@@ -192,7 +193,7 @@ def compare(ctx, program: dict, shardings) -> dict:
     place = lambda x, kind: jax.lax.with_sharding_constraint(x, whole if kind == "weight" else by_row)
     results = {}
     for name, precision in [("reference", "float32")] + ([("control", ctx.control)] if ctx.control else []):
-        results[name] = ref_train.follow(c, t, make_w0, batches, precision, place, ctx.say)
+        results[name] = ref_train.follow(reference, c, t, make_w0, batches, precision, place, ctx.say)
     ref = results["reference"]
 
     def numbers(got):

@@ -1,7 +1,8 @@
 """Finds everything a cell needs by the names in ``BENCHMARK.json``.
 
-A later PR adds a configuration, a traffic mix, a driver or a per-layer
-metric as a new file plus a manifest entry; nothing here names any of them.
+A later PR adds a configuration, an architecture, a traffic mix, a driver or
+a per-layer metric as a new file plus a manifest entry; nothing here names
+any of them.
 """
 
 from __future__ import annotations
@@ -31,6 +32,22 @@ def load_driver(name: str, bench_dir: str = BENCH_DIR):
     if not os.path.isfile(path):
         raise FileNotFoundError(f"no driver {name!r}: {path} is missing")
     return _load_module(path, f"bench_driver_{name}")
+
+
+def load_arch(model_type: str, bench_dir: str = BENCH_DIR):
+    """The one seam by architecture, found by a configuration's ``model_type``:
+    ``arch/<model_type>.py`` (counts from shapes, the adapter to the program)
+    with the published layout and plain reference ``reference/<model_type>.py``
+    beside it as ``.reference``. Drivers reach an architecture through this
+    and through nothing else."""
+    found = {}
+    for kind in ("arch", "reference"):
+        path = os.path.join(bench_dir, kind, f"{model_type}.py")
+        if not os.path.isfile(path):
+            raise FileNotFoundError(f"no {kind} for model_type {model_type!r}: {path} is missing")
+        found[kind] = _load_module(path, f"bench_{kind}_" + model_type.replace(".", "_").replace("-", "_"))
+    found["arch"].reference = found["reference"]
+    return found["arch"]
 
 
 def load_metric_reader(name: str, bench_dir: str = BENCH_DIR):

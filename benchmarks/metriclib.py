@@ -74,9 +74,10 @@ def decode_step_device_ms(trace):
 
 
 def decode_attn_roofline_pct(trace, counters, cell):
-    """Memory-bound: the page-rounded live cache bytes of the traced decode
-    steps over the peak bandwidth, divided by the kernel's device time
-    inside the decode program."""
+    """Memory-bound: the cache bytes the traced decode steps had to read (the
+    architecture's ``decode_kv_bytes``, page-rounded and by layer kind) over
+    the peak bandwidth, divided by the kernel's device time inside the decode
+    program."""
     dev_id, dev = first_device(trace)
     traced = (counters or {}).get("traced")
     if dev is None or not traced or not cell.get("peaks"):
@@ -84,7 +85,7 @@ def decode_attn_roofline_pct(trace, counters, cell):
     kernel_s = kernel_seconds_inside(trace, dev_id, DECODE_PROGRAM, DECODE_ATTN_KERNEL)
     if kernel_s <= 0:
         return None
-    least_s = traced["decode_walked_tokens"] * counters["kv_bytes_per_token"] / cell["peaks"]["hbm_bytes_per_s"]
+    least_s = traced["decode_kv_bytes"] / cell["peaks"]["hbm_bytes_per_s"]
     return pct(least_s, kernel_s)
 
 

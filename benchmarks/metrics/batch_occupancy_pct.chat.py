@@ -6,7 +6,6 @@ LAYER = "serving scheduler (serving/engine.py admission, serving/scheduler.py)"
 UNIT = "%"
 MOVES = "itl_p95_ms"
 SOURCE = "program_counter"
-CELLS = ("mistral7b_serve_chat_closed",)
 
 
 def read(trace, spans, counters, cell):

@@ -6,7 +6,6 @@ LAYER = "input (data.py)"
 UNIT = "%"
 MOVES = "train_tokens_per_s"
 SOURCE = "program_span"
-CELLS = ("mistral7b_train_4chip",)
 
 
 def read(trace, spans, counters, cell):

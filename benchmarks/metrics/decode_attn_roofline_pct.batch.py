@@ -6,7 +6,6 @@ LAYER = "kernels (ops/attention.py)"
 UNIT = "%"
 MOVES = "out_tokens_per_s"
 SOURCE = "device_trace"
-CELLS = ("mistral7b_serve_batch",)
 
 
 def read(trace, spans, counters, cell):

@@ -6,7 +6,6 @@ LAYER = "kernels (ops/attention.py)"
 UNIT = "%"
 MOVES = "itl_p95_ms"
 SOURCE = "device_trace"
-CELLS = ("mistral7b_serve_chat_closed",)
 
 
 def read(trace, spans, counters, cell):

@@ -6,7 +6,6 @@ LAYER = "model step (the engine's jitted programs over models/decoder.py)"
 UNIT = "ms"
 MOVES = "out_tokens_per_s"
 SOURCE = "device_trace"
-CELLS = ("mistral7b_serve_batch",)
 
 
 def read(trace, spans, counters, cell):

@@ -4,7 +4,7 @@ import metriclib
 
 LAYER = "device"
 UNIT = "%"
-MOVES = "out_tokens_per_s"
+MOVES = "ttft_p50_ms"
 SOURCE = "device_trace"
 
 

@@ -6,7 +6,6 @@ LAYER = "device"
 UNIT = "%"
 MOVES = "train_tokens_per_s"
 SOURCE = "device_trace"
-CELLS = ("mistral7b_train_4chip",)
 
 
 def read(trace, spans, counters, cell):

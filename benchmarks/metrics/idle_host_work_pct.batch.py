@@ -7,7 +7,6 @@ LAYER = "device"
 UNIT = "%"
 MOVES = "out_tokens_per_s"
 SOURCE = "device_trace"
-CELLS = ("mistral7b_serve_batch",)
 
 
 def read(trace, spans, counters, cell):

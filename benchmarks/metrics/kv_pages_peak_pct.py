@@ -6,7 +6,6 @@ LAYER = "KV pages and prefix cache (serving/pages.py, serving/arena.py)"
 UNIT = "%"
 MOVES = "out_tokens_per_s"
 SOURCE = "program_counter"
-CELLS = ("mistral7b_serve_batch",)
 
 
 def read(trace, spans, counters, cell):

@@ -6,7 +6,6 @@ LAYER = "train step (accelerator.py build_train_step, optimizer.py)"
 UNIT = "%"
 MOVES = "train_tokens_per_s"
 SOURCE = "host_clock"
-CELLS = ("mistral7b_train_4chip",)
 
 
 def read(trace, spans, counters, cell):

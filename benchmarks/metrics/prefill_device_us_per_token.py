@@ -8,7 +8,6 @@ LAYER = "model step (the engine's jitted programs over models/decoder.py)"
 UNIT = "us"
 MOVES = "ttft_p50_ms"
 SOURCE = "device_trace"
-CELLS = ("mistral7b_serve_chat_closed",)
 
 
 def read(trace, spans, counters, cell):

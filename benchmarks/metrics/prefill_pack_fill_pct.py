@@ -7,7 +7,6 @@ LAYER = "serving scheduler (serving/engine.py admission, serving/scheduler.py)"
 UNIT = "%"
 MOVES = "ttft_p50_ms"
 SOURCE = "program_counter"
-CELLS = ("mistral7b_serve_chat_closed",)
 
 
 def read(trace, spans, counters, cell):

@@ -6,7 +6,6 @@ LAYER = "KV pages and prefix cache (serving/pages.py, serving/arena.py)"
 UNIT = "%"
 MOVES = "ttft_p50_ms"
 SOURCE = "program_counter"
-CELLS = ("mistral7b_serve_chat_closed",)
 
 
 def read(trace, spans, counters, cell):

@@ -14,18 +14,31 @@ run at ``precision``:
 - ``"fp8"``: inputs rounded to float8_e4m3fn after a per-tensor scale, the
   nearest precision below bfloat16 -- the control that has to fail.
 
-Departure from the published code: weights are x @ W with the layout of
-``benchmarks/weights.py`` (the published checkpoints store W transposed).
+Departure from the published code: weights are x @ W (the published
+checkpoints store W transposed). The layout, which ``weights.py`` fills from
+the seed: ``embed`` [V, E]; a layer, stacked on a leading layer axis, ``q``
+[E, H*D], ``k``/``v`` [E, KV*D], ``o`` [H*D, E], ``gate``/``up`` [E, F],
+``down`` [F, E], ``norm_attn``/``norm_mlp`` [E]; ``norm_final`` [E]; ``head``
+[E, V].
 """
 
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 
-from weights import LAYER_LEAVES as _LAYER_LEAVES  # the benchmark's own layout, not the program's
+LAYER_LEAVES = ("q", "k", "v", "o", "gate", "up", "down", "norm_attn", "norm_mlp")
+
+
+def shapes(c: dict) -> dict:
+    e, f, v = c["hidden_size"], c["intermediate_size"], c["vocab_size"]
+    hd, kvd = c["num_attention_heads"] * c["head_dim"], c["num_key_value_heads"] * c["head_dim"]
+    return {"embed": (v, e), "q": (e, hd), "k": (e, kvd), "v": (e, kvd), "o": (hd, e),
+            "gate": (e, f), "up": (e, f), "down": (f, e), "norm_attn": (e,), "norm_mlp": (e,),
+            "norm_final": (e,), "head": (e, v)}
 
 
 def _round(x, precision: str):
@@ -101,22 +114,23 @@ def layer(c: dict, precision: str, h, w, query_block: int = QUERY_BLOCK):
     return h + _mm(y, w["down"], precision)
 
 
+def head_logits(c: dict, precision: str, w: dict, h):
+    """Final norm and output head over hidden states h [..., E]; ``w`` holds
+    the leaves outside the layers."""
+    return _mm(rms_norm(h, w["norm_final"], c["rms_norm_eps"]), w["head"], precision)
+
+
 @functools.lru_cache(maxsize=None)
-def _compiled(frozen_c: tuple, precision: str):
-    c = dict(frozen_c)
+def _compiled(frozen_c: str, precision: str):
+    c = json.loads(frozen_c)
     embed = jax.jit(lambda table, ids: jnp.take(table, ids, axis=0).astype(jnp.float32))
     # the layer's weights are cut from the stacks inside the program: cut
     # outside, every layer index would be a small program of its own, made
     # anew in every process
     one = jax.jit(lambda h, stacks, i: layer(c, precision, h, jax.tree_util.tree_map(
         lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False), stacks)))
-    head = jax.jit(lambda h, wn, wh, rows: _mm(
-        rms_norm(jnp.take(h, rows, axis=0), wn, c["rms_norm_eps"]), wh, precision))
+    head = jax.jit(lambda h, top, rows: head_logits(c, precision, top, jnp.take(h, rows, axis=0)))
     return embed, one, head
-
-
-def _freeze(c: dict) -> tuple:
-    return tuple(sorted((k, v) for k, v in c.items() if isinstance(v, (int, float, bool))))
 
 
 def logits_at(c: dict, weights: dict, ids, rows, precision: str = "float32", pad_to: int = 256):
@@ -125,14 +139,15 @@ def logits_at(c: dict, weights: dict, ids, rows, precision: str = "float32", pad
     sequence is padded at its end to a multiple of ``pad_to`` (causal
     attention never lets a position see what follows it), and ``rows`` to a
     multiple of 64, so that few shapes compile."""
-    embed, one, head = _compiled(_freeze(c), precision)
+    embed, one, head = _compiled(json.dumps(c, sort_keys=True), precision)
     n = len(ids)
     t = -(-n // pad_to) * pad_to
     padded = jnp.zeros((t,), jnp.int32).at[:n].set(jnp.asarray(ids, jnp.int32))
     h = embed(weights["embed"], padded)
-    stacks = {name: weights[name] for name in _LAYER_LEAVES}
+    stacks = {name: weights[name] for name in LAYER_LEAVES}
     for i in range(c["num_hidden_layers"]):
         h = one(h, stacks, i)
     r = -(-len(rows) // 64) * 64
     rows_p = jnp.zeros((r,), jnp.int32).at[: len(rows)].set(jnp.asarray(rows, jnp.int32))
-    return head(h, weights["norm_final"], weights["head"], rows_p)[: len(rows)]
+    top = {name: x for name, x in weights.items() if name not in LAYER_LEAVES and name != "embed"}
+    return head(h, top, rows_p)[: len(rows)]

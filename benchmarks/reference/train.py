@@ -1,7 +1,9 @@
 """The plain reference for training: loss, gradients and AdamW in float32.
 
-The forward pass is ``reference/mistral.py``'s layer over each row of the
-batch (``jax.vmap``), the loss is the mean cross-entropy of every position's
+The forward pass is the architecture's plain reference (``model``: the
+module ``reference/<model_type>.py``, handed in by the caller; it gives
+``LAYER_LEAVES``, ``layer`` and ``head_logits``) with its layer over each row
+of the batch (``jax.vmap``), the loss is the mean cross-entropy of every position's
 next token, gradients are ``jax.grad`` of that (``loss_and_grad``; at the
 timed size the same gradient is taken layer by layer, ``LayerByLayer``, so
 that it fits beside the optimizer's state), and AdamW is written out
@@ -19,32 +21,31 @@ import time
 import jax
 import jax.numpy as jnp
 
-from reference import mistral
-
-_LAYER_LEAVES = mistral._LAYER_LEAVES
-
-
 LOSS_BLOCK = 1024  # positions whose logits are held at once
 
 
-def loss_fn(c: dict, precision: str, w: dict, ids):
+def _top(model, w: dict) -> dict:
+    """The leaves outside the layers that the head reads."""
+    return {k: x for k, x in w.items() if k not in model.LAYER_LEAVES and k != "embed"}
+
+
+def loss_fn(model, c: dict, precision: str, w: dict, ids):
     """ids [B, T] -> mean next-token cross-entropy over B * (T - 1)."""
-    one_layer = jax.checkpoint(lambda h, lw: jax.vmap(lambda row: mistral.layer(c, precision, row, lw))(h))
+    one_layer = jax.checkpoint(lambda h, lw: jax.vmap(lambda row: model.layer(c, precision, row, lw))(h))
     h = jnp.take(w["embed"], ids, axis=0).astype(jnp.float32)
     for i in range(c["num_hidden_layers"]):
-        h = one_layer(h, {k: w[k][i] for k in _LAYER_LEAVES})
-    return _head_loss(c, precision, w, h, ids)
+        h = one_layer(h, {k: w[k][i] for k in model.LAYER_LEAVES})
+    return _head_loss(model, c, precision, _top(model, w), h, ids)
 
 
-def _head_loss(c: dict, precision: str, w: dict, h, ids):
+def _head_loss(model, c: dict, precision: str, top: dict, h, ids):
     """Final norm, output head and cross-entropy of the rows' last hidden
     states h [B, T, E], the logits a block of positions at a time."""
     b, t = ids.shape
 
     @jax.checkpoint
     def block_sum(hx, targets, valid):
-        x = mistral.rms_norm(hx, w["norm_final"], c["rms_norm_eps"])
-        logp = jax.nn.log_softmax(mistral._mm(x, w["head"], precision), axis=-1)
+        logp = jax.nn.log_softmax(model.head_logits(c, precision, top, hx), axis=-1)
         return -jnp.sum(jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0] * valid)
 
     targets = jnp.concatenate([ids[:, 1:], jnp.zeros((b, 1), ids.dtype)], axis=1)
@@ -55,11 +56,11 @@ def _head_loss(c: dict, precision: str, w: dict, h, ids):
     return total / (b * (t - 1))
 
 
-def loss_and_grad(c: dict, precision: str, w: dict, ids):
+def loss_and_grad(model, c: dict, precision: str, w: dict, ids):
     """ids [M, B, T]: the mean loss and its gradient over M equal groups of
     rows taken one after another (what is held at once is one group's)."""
     def micro(carry, group):
-        loss, g = jax.value_and_grad(lambda p: loss_fn(c, precision, p, group))(w)
+        loss, g = jax.value_and_grad(lambda p: loss_fn(model, c, precision, p, group))(w)
         return (carry[0] + loss, jax.tree_util.tree_map(jnp.add, carry[1], g)), None
 
     zero = (jnp.zeros((), jnp.float32), jax.tree_util.tree_map(jnp.zeros_like, w))
@@ -79,16 +80,16 @@ class LayerByLayer:
     about to be used ("weight") or the rows' activations ("rows") live, and
     ``layout`` where each gradient does; neither changes a value."""
 
-    def __init__(self, c: dict, precision: str, place=None, layout=None):
+    def __init__(self, model, c: dict, precision: str, place=None, layout=None):
+        self.model = model
         place = place or (lambda x, kind: x)
         cut = lambda stacks, i: {k: place(jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False), "weight")
                                  for k, x in stacks.items()}
-        rows = lambda h, lw: jax.vmap(lambda row: mistral.layer(c, precision, row, lw))(h)
+        rows = lambda h, lw: jax.vmap(lambda row: model.layer(c, precision, row, lw))(h)
         keep = (lambda g, k: jax.lax.with_sharding_constraint(g, layout[k])) if layout is not None else (lambda g, k: g)
 
-        def head(h, norm_final, head_w, ids):
-            w = {"norm_final": place(norm_final, "weight"), "head": place(head_w, "weight")}
-            return _head_loss(c, precision, w, h, ids)
+        def head(h, top, ids):
+            return _head_loss(model, c, precision, {k: place(x, "weight") for k, x in top.items()}, h, ids)
 
         def backward(h, stacks, i, dh, grads):
             _, vjp = jax.vjp(lambda hh, lw: rows(hh, lw), h, cut(stacks, i))
@@ -101,24 +102,25 @@ class LayerByLayer:
         self.embed = jax.jit(lambda table, ids: place(jnp.take(place(table, "weight"), ids, axis=0)
                                                       .astype(jnp.float32), "rows"))
         self.forward = jax.jit(lambda h, stacks, i: place(rows(h, cut(stacks, i)), "rows"))
-        self.head = jax.jit(jax.value_and_grad(head, argnums=(0, 1, 2)))
+        self.head = jax.jit(jax.value_and_grad(head, argnums=(0, 1)))
         self.backward = jax.jit(backward, donate_argnums=(3, 4))
         self.embed_back = jax.jit(lambda g, ids, dh: keep(g.at[ids].add(dh), "embed"), donate_argnums=(0,))
         self.layers = c["num_hidden_layers"]
 
     def __call__(self, w: dict, ids):
         """ids [M, B, T] -> (mean loss, gradients), group after group."""
-        stacks = {k: w[k] for k in _LAYER_LEAVES}
+        leaves, top = self.model.LAYER_LEAVES, _top(self.model, w)
+        stacks = {k: w[k] for k in leaves}
         grads = jax.tree_util.tree_map(jnp.zeros_like, w)
-        g_stacks = {k: grads[k] for k in _LAYER_LEAVES}
+        g_stacks = {k: grads[k] for k in leaves}
         loss = 0.0
         for group in ids:
             inputs = [self.embed(w["embed"], group)]
             for i in range(self.layers):
                 inputs.append(self.forward(inputs[-1], stacks, i))
-            part, (dh, d_norm, d_head) = self.head(inputs.pop(), w["norm_final"], w["head"], group)
+            part, (dh, d_top) = self.head(inputs.pop(), top, group)
             loss = loss + part
-            grads["norm_final"], grads["head"] = grads["norm_final"] + d_norm, grads["head"] + d_head
+            grads.update({k: grads[k] + d for k, d in d_top.items()})
             for i in reversed(range(self.layers)):
                 dh, g_stacks = self.backward(inputs.pop(), stacks, i, dh, g_stacks)
             grads["embed"] = self.embed_back(grads["embed"], group, dh)
@@ -139,13 +141,13 @@ def adamw(hyper: dict, step: int, p, g, m, v):
     return p, m, v
 
 
-def leaf_norms(tree: dict) -> dict:
+def leaf_norms(model, tree: dict) -> dict:
     """Norm of every leaf, a layer's slice of a stacked leaf counting as one:
     {"q/0": ..., "embed": ...} as float32 scalars."""
     out = {}
     for name, x in tree.items():
         x = x.astype(jnp.float32)
-        if name in _LAYER_LEAVES:
+        if name in model.LAYER_LEAVES:
             per = jnp.sqrt(jnp.sum(jnp.square(x), axis=tuple(range(1, x.ndim))))
             for i in range(x.shape[0]):
                 out[f"{name}/{i}"] = per[i]
@@ -154,19 +156,19 @@ def leaf_norms(tree: dict) -> dict:
     return out
 
 
-def follow(c: dict, hyper: dict, make_w0, batches: list, precision: str = "float32", place=None,
+def follow(model, c: dict, hyper: dict, make_w0, batches: list, precision: str = "float32", place=None,
            say=lambda msg: None) -> dict:
     """The first ``len(batches)`` steps from the weights ``make_w0()`` gives
-    (float32, in the layout of ``weights.py``; made again at the end rather
+    (float32, in the published layout; made again at the end rather
     than kept): each step's loss, the per-leaf norm of the first gradient,
     and the per-leaf norm of the parameters' change after the last step.
     Each batch is [M, B, T]: M groups of rows taken one after another."""
     t0 = time.perf_counter()
     p = make_w0()
     layout = jax.tree_util.tree_map(lambda x: x.sharding, p) if place is not None else None
-    grad = LayerByLayer(dict(mistral._freeze(c)), precision, place, layout)
+    grad = LayerByLayer(model, c, precision, place, layout)
     update = jax.jit(lambda n, p, g, m, v: adamw(hyper, n, p, g, m, v), static_argnums=0, donate_argnums=(1, 2, 3, 4))
-    norms = jax.jit(leaf_norms)
+    norms = jax.jit(lambda tree: leaf_norms(model, tree))
     m, v = (jax.tree_util.tree_map(jnp.zeros_like, p) for _ in range(2))
     losses, grad_norms = [], None
     for n, ids in enumerate(batches, start=1):
@@ -178,7 +180,7 @@ def follow(c: dict, hyper: dict, make_w0, batches: list, precision: str = "float
         p, m, v = update(n, p, g, m, v)
         del g
     del m, v
-    delta = jax.jit(lambda a, b: leaf_norms(jax.tree_util.tree_map(lambda x, y: x - y, a, b)))(p, make_w0())
+    delta = jax.jit(lambda a, b: leaf_norms(model, jax.tree_util.tree_map(lambda x, y: x - y, a, b)))(p, make_w0())
     return {"losses": losses, "grad_norms": grad_norms,
             "update_norms": {k: float(x) for k, x in delta.items()}}
 

@@ -58,14 +58,16 @@ class Context:
                     settings[group] = over
             traffic.update(traffic.pop("rehearsal", {}))
         self.settings, self.traffic = settings, traffic
-        self.model = {k: v for k, v in settings.items() if not isinstance(v, (dict, list))}
+        # the configuration whole goes to the architecture's module, found by its model_type
+        self.arch = manifest_mod.load_arch(settings["model_type"], cell["bench_dir"])
         self.limits = settings["limits"]
         self.trace_seconds = float(getattr(args, "trace_seconds", None) or traffic.get("trace_seconds", 5))
         self.spans = Spans()
         self.t_start = T_START
         self.setup_s = None
         self.prefix = REHEARSAL if self.rehearsal else ""
-        self.trace_dir = os.path.join(OUT_DIR, "trace", cell["name"])
+        # a directory a process: two runs of a cell side by side (the tests' workers) would empty each other's
+        self.trace_dir = os.path.join(OUT_DIR, "trace", f"{cell['name']}.{os.getpid()}")
         self.trace_file = None
 
     def say(self, msg: str):
@@ -194,7 +196,11 @@ def main(argv=None) -> int:
         metrics = {f"rehearsal:{k}": v for k, v in metrics.items()}
     line.update(metrics=metrics, device=device)
     ordered = {k: line[k] for k in ("correct", "attempted", "failed", "metrics", "device", "breakdown") if k in line}
+    # every number compared beside its limit: last in the line, and the last lines on standard error
+    ordered["compared"] = {k: {"value": v, "limit": lim} for k, (v, lim) in result.get("compared", {}).items()}
     print(ctx.prefix + json.dumps(ordered), flush=True)
+    for k, c in ordered["compared"].items():
+        print(f"{ctx.prefix}compared {k}: {c['value']} limit {c['limit']}", file=sys.stderr, flush=True)
     return 0
 
 

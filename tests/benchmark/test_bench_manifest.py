@@ -10,6 +10,7 @@ import sys
 import pytest
 
 import manifest as M
+import published_widths
 
 ROOT, BENCH = M.ROOT, M.BENCH_DIR
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -72,17 +73,94 @@ def test_every_cell_finds_its_files(man):
     assert used == {c["name"] for c in man["configs"]}
 
 
+def _configs(man, root=ROOT):
+    for entry in man["configs"]:
+        with open(os.path.join(root, entry["file"])) as f:
+            yield entry, json.load(f)
+
+
 def test_published_widths_are_untouched(man):
-    published = {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
-                 "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 32768, "rope_theta": 1e6,
-                 "rms_norm_eps": 1e-5, "max_position_embeddings": 32768, "sliding_window": None,
-                 "tie_word_embeddings": False}
-    for c in man["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        for k, v in published.items():
-            assert cfg[k] == v, (c["name"], k)
-        assert cfg["published"]["num_hidden_layers"] == 32 and c["reduced"] == ["num_hidden_layers"]
+    """Every configuration against the public values of its own architecture
+    (``data/published/<model_type>.json``): a key the public file has and
+    ``reduced`` does not list is equal to it; ``reduced`` holds depth, experts
+    held and vocabulary only, each with its public value under ``published``;
+    the floors of the model-configs guide where they apply. A configuration
+    whose ``model_type`` has no public-values file fails."""
+    checked = 0
+    for entry, cfg in _configs(man):
+        published_widths.check(cfg, entry, published_widths.load_public(cfg["model_type"]))
+        checked += 1
+    assert checked == len(man["configs"]) >= 1
+
+
+def _alter(case: str, cfg: dict, entry: dict, public: dict):
+    """One fault a configuration's PR could bring; each has to fail the check."""
+    roles, values = public["roles"], public["values"]
+    depth = roles["depth"]
+    if case == "width_altered":
+        width = next(k for k in values if published_widths.WIDTH.search(k) and isinstance(values[k], int))
+        cfg[width] += 1
+    elif case == "width_left_out":
+        del cfg[next(k for k in values if published_widths.WIDTH.search(k))]
+    elif case == "reduced_key_lacks_its_public_value":
+        del cfg["published"][entry["reduced"][0]]
+    elif case == "reduced_key_states_another_public_value":
+        cfg["published"][entry["reduced"][0]] += 1
+    elif case == "reduced_names_a_width":
+        width = next(k for k in values if published_widths.WIDTH.search(k) and isinstance(values[k], int))
+        for e in (cfg, entry):
+            e["reduced"] = e["reduced"] + [width]
+        cfg["published"][width], cfg[width] = values[width], values[width] // 2
+    elif case == "reduced_names_a_key_that_is_no_cut_of_scale":
+        for e in (cfg, entry):
+            e["reduced"] = e["reduced"] + ["rope_theta"]
+        cfg["published"]["rope_theta"] = values["rope_theta"]
+    elif case == "depth_under_the_floor":
+        cfg[depth] = roles["leading_dense_layers"] + max(4, roles["layer_period"]) - 1
+    elif case == "vocabulary_under_an_eighth":
+        vocab = roles["vocabulary"]
+        for e in (cfg, entry):
+            e["reduced"] = sorted(set(e["reduced"]) | {vocab})
+        cfg["published"][vocab], cfg[vocab] = values[vocab], values[vocab] // 8 - 1
+    elif case == "source_is_another_model":
+        cfg["source"] = entry["source"] = public["source"] + "x"
+    else:
+        raise ValueError(case)
+
+
+ALTERED = ["width_altered", "width_left_out", "reduced_key_lacks_its_public_value",
+           "reduced_key_states_another_public_value", "reduced_names_a_width",
+           "reduced_names_a_key_that_is_no_cut_of_scale", "depth_under_the_floor", "vocabulary_under_an_eighth",
+           "source_is_another_model"]
+
+
+def _architectures(man):
+    """(entry, configuration, public values) of one configuration of each
+    architecture: the manifest's, and the toy one the seam's test adds."""
+    seen = {}
+    for entry, cfg in _configs(man):
+        seen.setdefault(cfg["model_type"], (entry, cfg, published_widths.load_public(cfg["model_type"])))
+    toy = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "toy_arch")
+    with open(os.path.join(toy, "config.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(toy, "published.json")) as f:
+        seen["toy"] = ({"source": cfg["source"], "reduced": cfg["reduced"]}, cfg, json.load(f))
+    return list(seen.values())
+
+
+@pytest.mark.parametrize("case", ALTERED)
+def test_an_altered_configuration_fails_the_published_widths(man, case):
+    for entry, cfg, public in _architectures(man):
+        published_widths.check(cfg, entry, public)  # sound as committed
+        cfg, entry = json.loads(json.dumps(cfg)), dict(entry)
+        _alter(case, cfg, entry, public)
+        with pytest.raises(AssertionError):
+            published_widths.check(cfg, entry, public)
+
+
+def test_a_model_type_without_public_values_fails():
+    with pytest.raises(AssertionError, match="no public values"):
+        published_widths.load_public("an-architecture-nobody-published")
 
 
 def test_per_layer_metrics_move_what_their_cells_report(man):
@@ -92,7 +170,6 @@ def test_per_layer_metrics_move_what_their_cells_report(man):
         reader = M.load_metric_reader(m["name"])
         assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
             m["layer"], m["unit"], m["moves"], m["source"]), m["name"]
-        assert list(reader.CELLS) == m["workloads"], m["name"]
         assert m["moves"] in e2e and m["moves"] != "setup_s"
         target = e2e[m["moves"]]
         for w in m["workloads"]:
@@ -120,7 +197,7 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path):
         "    return {'values': {'ticks_per_s': n / ctx.seconds}, 'counters': {'ticks': n}, 'attempted': n,\n"
         "            'failed': 0, 'correct': True, 'memory_peak_bytes': 0}\n")
     (root / "benchmarks/metrics/ticks_seen.py").write_text(
-        "LAYER, UNIT, MOVES, SOURCE, CELLS = 'toy', 'count', 'ticks_per_s', 'program_counter', ('toy_ticks',)\n"
+        "LAYER, UNIT, MOVES, SOURCE = 'toy', 'count', 'ticks_per_s', 'program_counter'\n"
         "def read(trace, spans, counters, cell):\n    return counters.get('ticks')\n")
     man["configs"].append({"name": "toy", "source": "https://example.org/toy", "file": "benchmarks/configs/toy.json",
                            "reduced": [], "why": "test"})
@@ -137,7 +214,7 @@ def test_a_later_pr_adds_files_and_entries_only(tmp_path):
     assert M.load_metric_reader("ticks_seen", cell["bench_dir"]).read(None, None, {"ticks": 21}, cell) == 21
     # ... and the copy holds only BENCHMARK.json and the files under paths:
     # no program to import, so a run fails and prints no result line
-    proc = subprocess.run([sys.executable, str(root / "benchmarks/run.py"), "--workload", "mistral7b_serve_batch",
+    proc = subprocess.run([sys.executable, str(root / "benchmarks/run.py"), "--workload", man["workloads"][0]["name"],
                            "--seed", "1", "--seconds", "1", "--trace", "0", "--cpu-rehearsal"],
                           capture_output=True, text=True, cwd=str(root),
                           env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})

@@ -10,17 +10,19 @@ import types
 
 import pytest
 
+import cells
 import manifest as M
 import program_spans as P
 
 RUN = os.path.join(M.BENCH_DIR, "run.py")
 PREFIX = "[CPU REHEARSAL, not a chip run] "
-NEW = {
-    "mistral7b_serve_chat_closed": {
-        "queue_wait_p50_ms", "prefill_own_dispatches_p50", "prefill_pack_fill_pct",
-        "prefill_device_us_per_token", "idle_host_work_pct.chat", "idle_result_wait_pct.chat"},
-    "mistral7b_serve_batch": {"idle_host_work_pct.batch", "idle_result_wait_pct.batch"},
-}
+# the metrics read from the program's own spans, and for each served cell of
+# the manifest those of them that list it
+FROM_SPANS = {"queue_wait_p50_ms", "prefill_own_dispatches_p50", "prefill_pack_fill_pct",
+              "prefill_device_us_per_token", "idle_host_work_pct", "idle_result_wait_pct"}
+NEW = {w: {m["name"] for m in cells.find(w)["per_layer"] if m["name"].split(".")[0] in FROM_SPANS}
+       for w in cells.by_driver("closed_loop")}
+ALL_FROM_SPANS = set().union(*NEW.values())
 OFFSET = 5_000_000_000.0  # the trace's clock runs 5 s ahead of perf_counter
 
 
@@ -146,8 +148,7 @@ def test_readers_take_the_windows_spans(monkeypatch):
     assert _read("queue_wait_p50_ms", None, spans, counters) == pytest.approx(2150.0)
     assert _read("prefill_own_dispatches_p50", None, spans, counters) == 3.0
     assert _read("prefill_pack_fill_pct", None, spans, counters) == pytest.approx(100 * 576 / 640)
-    for name in NEW["mistral7b_serve_chat_closed"] - {"queue_wait_p50_ms", "prefill_own_dispatches_p50",
-                                                      "prefill_pack_fill_pct"}:
+    for name in ALL_FROM_SPANS - {"queue_wait_p50_ms", "prefill_own_dispatches_p50", "prefill_pack_fill_pct"}:
         assert _read(name, None, spans, counters) is None  # no trace
     # a trace of the last iteration: one prefill dispatch of 100 tokens in it, 0.3 s on the device
     trace = _trace(spans, 1, gaps=[(_ns(19.0), _ns(19.05))])
@@ -168,7 +169,7 @@ def test_readers_return_none_without_what_they_need(monkeypatch, ring, dropped):
     monkeypatch.setattr(P, "program_ring", lambda: (ring, dropped))
     trace = _trace(spans, 1, gaps=[(_ns(19.0), _ns(19.05))])
     trace["reduced"]["devices"][0].update(module_s={"jit_ragged_prefill": 0.3}, busy_s=0.95)
-    for name in set().union(*NEW.values()):
+    for name in ALL_FROM_SPANS:
         value = _read(name, trace, spans, counters)
         if ring and not dropped and name.startswith("idle_"):
             continue  # the traced window's idle time is read from the trace and the bench/ spans
@@ -201,11 +202,13 @@ def test_traced_rehearsal_prints_the_new_metrics(workload):
     line = json.loads([l for l in proc.stdout.splitlines() if l.strip()][-1][len(PREFIX):])
     got = {k.split(":", 1)[1]: v["value"] for k, v in line["metrics"].items() if k.startswith("rehearsal:")}
     assert len(got) == len(line["metrics"]) and NEW[workload] <= set(got)
-    kind = workload.rsplit("_", 1)[-1] if workload.endswith("batch") else "chat"
-    host, wait, idle = (got[f"{n}.{kind}"] for n in ("idle_host_work_pct", "idle_result_wait_pct", "device_idle_pct"))
-    assert host >= 0 and wait >= 0 and host + wait <= idle + 1e-9
-    assert idle - (host + wait) <= 0.3  # what no span covers, in points of the window
-    if kind == "chat":
+    (idle,) = [v for k, v in got.items() if k.startswith("device_idle_pct")]
+    shares = {k.split(".")[0]: v for k, v in got.items() if k.startswith("idle_")}
+    if shares:
+        host, wait = shares["idle_host_work_pct"], shares["idle_result_wait_pct"]
+        assert host >= 0 and wait >= 0 and host + wait <= idle + 1e-9
+        assert idle - (host + wait) <= 0.3  # what no span covers, in points of the window
+    if "queue_wait_p50_ms" in got:
         assert got["queue_wait_p50_ms"] >= 0 and got["prefill_own_dispatches_p50"] >= 1
         assert 0 < got["prefill_pack_fill_pct"] <= 100
 
@@ -220,13 +223,12 @@ def test_walked_tokens_inside_equal_the_drivers_iteration_by_iteration(optimized
     from accelerate_tpu.telemetry import spans as program
 
     args = types.SimpleNamespace(seed=7, seconds=2.0, trace=0, cpu_rehearsal=True, control=None)
-    ctx = R.Context(M.find_cell(M.load_manifest(), "mistral7b_serve_batch"), args)
+    ctx = R.Context(cells.find(cells.by_driver("closed_loop")[0]), args)
     driver = M.load_driver("closed_loop")
     engine = driver.build_engine(ctx)
     program.emit("mark", 0.0, 0.0)
     mark = program.snapshot()[-1][0]
-    loop = driver.ClosedLoop(engine, ctx.traffic, ctx.seed, ctx.model["vocab_size"],
-                             ctx.settings["serving"]["page_size"], ctx.spans)
+    loop = driver.ClosedLoop(engine, ctx.traffic, ctx.seed, ctx.arch, ctx.settings, ctx.spans)
     for _ in range(30):
         loop.iterate()
     ring = [s for s in program.snapshot() if s[0] > mark]

@@ -10,11 +10,13 @@ import types
 import numpy as np
 import pytest
 
+import cells
 import manifest as M
 
+SERVE, TRAIN = cells.by_driver("closed_loop"), cells.by_driver("train_steps")
 RUN = os.path.join(M.BENCH_DIR, "run.py")
 PREFIX = "[CPU REHEARSAL, not a chip run] "
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def _run(*extra, workload, trace=0, seconds=3):
@@ -31,9 +33,9 @@ def _last(proc):
     return json.loads(lines[-1][len(PREFIX):]), lines
 
 
-@pytest.mark.parametrize("workload,trace", [("mistral7b_serve_chat_closed", 0), ("mistral7b_serve_batch", 1),
-                                            ("mistral7b_train_4chip", 0)])
+@pytest.mark.parametrize("workload,trace", [(w, int(i == 0)) for i, w in enumerate(cells.all_cells())])
 def test_rehearsal_prints_the_contracts_line(workload, trace):
+    """Every cell of the manifest, the first of them traced."""
     proc = _run("--cpu-rehearsal", workload=workload, trace=trace)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line, lines = _last(proc)
@@ -41,7 +43,7 @@ def test_rehearsal_prints_the_contracts_line(workload, trace):
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert set(line["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
     assert line["device"]["platform"] == "cpu"
-    assert line["device"]["count"] == (4 if workload.endswith("4chip") else 1)
+    assert line["device"]["count"] == cells.find(workload)["chips"]
     # never a metric under its name
     assert line["metrics"] and all(k.startswith("rehearsal:") for k in line["metrics"])
     man = M.load_manifest()
@@ -52,12 +54,15 @@ def test_rehearsal_prints_the_contracts_line(workload, trace):
         assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     else:
         assert "rehearsal:setup_s" in line["metrics"]
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit: in the log, last in the line, last on standard error
     assert any("limit" in l and "compared" in l for l in lines)
+    assert list(line)[-1] == "compared" and len(line["compared"]) >= 3
+    assert all(set(c) == {"value", "limit"} for c in line["compared"].values())
+    assert proc.stderr.rstrip().splitlines()[-1].startswith(PREFIX + "compared ")
 
 
 def test_without_the_chip_there_is_no_result():
-    proc = _run(workload="mistral7b_serve_batch")
+    proc = _run(workload=SERVE[0])
     assert proc.returncode != 0
     assert '"correct"' not in proc.stdout and "need" in proc.stderr
 
@@ -66,7 +71,7 @@ def _context(workload, seed=5, **config_overrides):
     import run as R
 
     args = types.SimpleNamespace(seed=seed, seconds=2.0, trace=0, cpu_rehearsal=True, control=None)
-    cell = M.find_cell(M.load_manifest(), workload)
+    cell = cells.find(workload)
     for group, values in config_overrides.items():
         cell["config_values"]["rehearsal"].setdefault(group, {}).update(values)
     return R.Context(cell, args)
@@ -80,9 +85,9 @@ def test_sound_engine_is_correct_and_a_lower_precision_engine_is_not(optimized_x
     less than bfloat16 rounding does, so the test takes the int4 cache; the
     control at the cell's own size is the fp8 reference (PERF.md)."""
     driver = M.load_driver("closed_loop")
-    sound = driver.run(_context("mistral7b_serve_batch"))
+    sound = driver.run(_context(SERVE[0]))
     assert sound["correct"] is True and sound["check"]["served_logit_gap"] <= 0.02
-    low = driver.run(_context("mistral7b_serve_batch", serving={"engine_kwargs": {"kv_cache_dtype": "int4"}}))
+    low = driver.run(_context(SERVE[0], serving={"engine_kwargs": {"kv_cache_dtype": "int4"}}))
     print("sound", sound["check"]["served_logit_gap"], "int4 cache", low["check"]["served_logit_gap"])
     assert low["correct"] is False
     assert low["check"]["served_logit_gap"] > 3 * max(sound["check"]["served_logit_gap"], 0.01)
@@ -100,7 +105,7 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch, optimi
         return emit(self, req, (token + 1) % 512 if count[0] % 5 == 0 else token, now)
 
     monkeypatch.setattr(ServingEngine, "_emit", broken)
-    out = M.load_driver("closed_loop").run(_context("mistral7b_serve_chat_closed"))
+    out = M.load_driver("closed_loop").run(_context(SERVE[1]))
     assert out["correct"] is False and out["failed"] == 0
 
 
@@ -117,7 +122,7 @@ def test_training_step_that_leaves_its_state_unchanged_is_not_correct(monkeypatc
             tx.init, lambda g, s, p=None: (lambda u, s2: (optax.tree_utils.tree_scale(0.0, u), s2))(*tx.update(g, s, p)))
 
     monkeypatch.setattr(optax, "adamw", frozen)
-    out = M.load_driver("train_steps").run(_context("mistral7b_train_4chip"))
+    out = M.load_driver("train_steps").run(_context(TRAIN[0]))
     assert out["correct"] is False
     assert out["check"]["numbers"]["update_norm_rel"] > 0.9
 
@@ -130,7 +135,7 @@ def test_control_training_in_fp8_fails_a_limit(seed, optimized_xla):
     import run as R
 
     args = types.SimpleNamespace(seed=seed, seconds=1.0, trace=0, cpu_rehearsal=True, control="fp8")
-    ctx = R.Context(M.find_cell(M.load_manifest(), "mistral7b_train_4chip"), args)
+    ctx = R.Context(cells.find(TRAIN[0]), args)
     out = M.load_driver("train_steps").run(ctx)
     assert out["correct"] is True
     lim, control = ctx.limits, out["check"]["control"]
@@ -143,7 +148,7 @@ def test_control_serving_in_fp8_fails_the_limit(seed, optimized_xla):
     import run as R
 
     args = types.SimpleNamespace(seed=seed, seconds=2.0, trace=0, cpu_rehearsal=True, control="fp8")
-    ctx = R.Context(M.find_cell(M.load_manifest(), "mistral7b_serve_batch"), args)
+    ctx = R.Context(cells.find(SERVE[0]), args)
     out = M.load_driver("closed_loop").run(ctx)
     assert out["correct"] is True
     assert out["check"]["control_gap"] > ctx.limits["served_logit_gap"] >= 2 * out["check"]["served_logit_gap"]
@@ -158,12 +163,13 @@ def test_layer_by_layer_gradient_is_the_whole_models(optimized_xla):
     import weights
     from reference import train as ref_train
 
+    model = _context(TRAIN[0]).arch.reference
     c = {"hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4, "num_key_value_heads": 2,
          "head_dim": 32, "vocab_size": 256, "num_hidden_layers": 3, "rope_theta": 1e6, "rms_norm_eps": 1e-5}
-    w = weights.make_jit(c, 11, jnp.float32)
+    w = weights.make_jit(model, c, 11, jnp.float32)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (2, 2, 64), dtype=np.int32))
-    loss, grads = jax.jit(lambda p, i: ref_train.loss_and_grad(c, "float32", p, i))(w, ids)
-    loss2, grads2 = ref_train.LayerByLayer(c, "float32")(w, ids)
+    loss, grads = jax.jit(lambda p, i: ref_train.loss_and_grad(model, c, "float32", p, i))(w, ids)
+    loss2, grads2 = ref_train.LayerByLayer(model, c, "float32")(w, ids)
     assert float(loss2) == pytest.approx(float(loss), rel=1e-6)
     for k in grads:
         scale = float(jnp.max(jnp.abs(grads[k])))

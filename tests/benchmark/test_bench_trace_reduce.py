@@ -91,7 +91,7 @@ def test_hand_made_plane():
     assert metriclib.prefill_device_share_pct(both) == pytest.approx(50.0)
     assert metriclib.collective_exposed_pct(both) == pytest.approx(20.0)
     # 2 us of decode kernel for 819e9 B/s * 1e-6 s of page-rounded cache: 50% of the memory bound
-    counters = {"traced": {"decode_walked_tokens": 100}, "kv_bytes_per_token": 8190}
+    counters = {"traced": {"decode_kv_bytes": 819000}}
     cell = {"peaks": {"hbm_bytes_per_s": 819e9}}
     assert metriclib.decode_attn_roofline_pct(both, counters, cell) == pytest.approx(50.0)
     names = [n for n, _ in T.breakdown(r)["device_ops"]]
@@ -100,7 +100,7 @@ def test_hand_made_plane():
 
 
 def test_trace_recorded_on_the_chip():
-    """Two scheduler iterations of mistral7b_serve_batch on one TPU v5e
+    """Two scheduler iterations of the batch serving cell on one TPU v5e
     (chip call 1 of PR 23, seed 101; cut to the device's two XLA lines and
     the bench/ spans): each iteration one ragged prefill dispatch and one
     decode step. The numbers are this reduction's reading of that file."""

@@ -1,27 +1,24 @@
 """Every serving mix is a replayed trace: the same for every ``--seed``."""
 
-import json
-import os
-
 import numpy as np
 import pytest
 
+import cells
 import manifest as M
 import traffic_gen
 
-MIXES = ["batch", "chat-closed"]
-# a mix with a shared document in front of every ask of a session (the re-ask
-# mix of PERF.md's Open questions), at the rehearsal's size: the generator and
-# the loop carry it, though no cell of this PR does
+SERVING = cells.by_driver("closed_loop")  # every served cell of the manifest, with its own mix and configuration
+# a mix with a shared document in front of every ask of a session, at the
+# rehearsal's size, beside the manifest's own
 REASK_TINY = {"name": "reask-tiny", "driver": "closed_loop", "clients": 2, "trace_seed": 7, "trace_sessions": 64,
               "document_tokens": 96, "asks_per_session": 4, "start": {"cut_first_session": True},
               "prompt": {"dist": "uniform", "min": 8, "max": 16},
               "output": {"dist": "fixed", "value": 4, "min": 4, "max": 4}, "check_requests": 4}
 
 
-@pytest.mark.parametrize("mix", MIXES + [REASK_TINY])
+@pytest.mark.parametrize("mix", SERVING + [REASK_TINY], ids=lambda m: m if isinstance(m, str) else m["name"])
 def test_trace_is_a_function_of_the_file_alone(mix):
-    traffic = mix if isinstance(mix, dict) else M.load_traffic(mix)
+    traffic = mix if isinstance(mix, dict) else cells.find(mix)["traffic_values"]
     a, b = traffic_gen.expand(traffic), traffic_gen.expand(dict(traffic))
     assert a == b and len(a) == traffic["trace_sessions"]
     p, o = traffic["prompt"], traffic["output"]
@@ -40,12 +37,12 @@ def test_trace_is_a_function_of_the_file_alone(mix):
     assert np.array_equal(traffic_gen.ask_tokens(2, s, 0, 32768), traffic_gen.ask_tokens(2, s, 0, 32768))
 
 
-@pytest.mark.parametrize("mix", MIXES)
-def test_mix_fits_the_configuration(mix):
-    """The longest request fits a slot, and the mix's medians are the issue's."""
-    with open(os.path.join(M.BENCH_DIR, "configs", "mistral-7b-v0.3-serve-16l.json")) as f:
-        s = json.load(f)["serving"]
-    traffic = M.load_traffic(mix)
+@pytest.mark.parametrize("workload", SERVING)
+def test_mix_fits_the_configuration(workload):
+    """The longest request fits a slot of the cell's own configuration, and
+    the mix's medians are the issue's."""
+    cell = cells.find(workload)
+    s, traffic = cell["config_values"]["serving"], cell["traffic_values"]
     assert traffic_gen.longest_request(traffic, s["max_cache_len"]) <= s["max_cache_len"]
     assert traffic["clients"] <= s["num_slots"]
     if traffic["prompt"]["dist"] == "lognormal":
@@ -61,8 +58,9 @@ def test_stagger_spreads_the_population():
     assert [len(traffic_gen.stagger(four, i, 8).asks) for i in range(8)] == [4, 3, 2, 1, 4, 3, 2, 1]
 
 
-@pytest.mark.parametrize("mix", ["chat-closed", "reask"])
-def test_closed_loop_repeats_on_the_real_engine(mix, optimized_xla):
+@pytest.mark.parametrize("workload,mix", [(SERVING[1], None), (SERVING[1], REASK_TINY)],
+                         ids=lambda x: x if isinstance(x, str) else "" if x is None else x["name"])
+def test_closed_loop_repeats_on_the_real_engine(workload, mix, optimized_xla):
     """Tiny widths through the real ServingEngine, twice with different
     seeds and no look at the clock: the same sequence of batch compositions
     and of prefill dispatches."""
@@ -75,12 +73,11 @@ def test_closed_loop_repeats_on_the_real_engine(mix, optimized_xla):
     runs = []
     for seed in (11, 12):
         args = types.SimpleNamespace(seed=seed, seconds=1.0, trace=0, cpu_rehearsal=True, control=None)
-        ctx = R.Context(M.find_cell(M.load_manifest(), "mistral7b_serve_chat_closed"), args)
-        if mix == "reask":
-            ctx.traffic = REASK_TINY
+        ctx = R.Context(cells.find(workload), args)
+        if mix is not None:
+            ctx.traffic = mix
         engine = closed_loop.build_engine(ctx)
-        loop = closed_loop.ClosedLoop(engine, ctx.traffic, seed, ctx.model["vocab_size"],
-                                      ctx.settings["serving"]["page_size"], Spans())
+        loop = closed_loop.ClosedLoop(engine, ctx.traffic, seed, ctx.arch, ctx.settings, Spans())
         for _ in range(40):
             loop.iterate()
         runs.append(([it["comp"] for it in loop.iters], [it["prefill"] for it in loop.iters],
@@ -88,5 +85,5 @@ def test_closed_loop_repeats_on_the_real_engine(mix, optimized_xla):
         assert all(r.req.outcome in (None, "finished") for r in loop.recs)
     assert runs[0] == runs[1]
     assert any(runs[0][1]) and any(len(c) > 1 for c in runs[0][0])
-    if mix == "reask":
+    if ctx.traffic.get("document_tokens"):
         assert sum(h > 0 for h in runs[0][2]) >= len(runs[0][2]) // 2
