@@ -2,10 +2,16 @@
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
 from typing import Optional
 
 import jax.numpy as jnp
+
+
+# the collection an expert layer writes its load vector to when the caller
+# makes it mutable (models/moe.py writes it, the serving engine reads it)
+MOE_LOAD_COLLECTION = "moe_load"
 
 
 @dataclass
@@ -127,8 +133,51 @@ class DecoderConfig:
     # 0 = dense MLP
     moe_num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
     moe_aux_loss_weight: float = 0.01
+    # routing (models/moe.py: sorted pairs, grouped products, no capacity
+    # and no dropped token). ``moe_scoring``: "softmax" over the router's
+    # outputs, or "sigmoid" of each. ``moe_selection_bias``: a learned
+    # [outputs] vector added to the scores for the choice of the top k
+    # only, never to the weights. ``moe_router_outputs``: the router's
+    # width where it is not the number of experts held (None: the same).
+    # ``moe_experts_held``: (first, count) -- this program holds experts
+    # first..first+count-1 of the router's outputs (``moe_num_experts`` is
+    # that count) and computes their part of the result; the weights stay
+    # normalised over all top k chosen.
+    moe_scoring: str = "softmax"
+    moe_selection_bias: bool = False
+    moe_router_outputs: Optional[int] = None
+    moe_experts_held: Optional[tuple] = None
+    # -- attention by layer kind. The five fields describe every layer of
+    # a model with one kind, and one kind's layers where ``layer_kinds``
+    # states several. ``v_head_dim``: the values' width (None: head_dim).
+    # ``rope_dim``: the leading dimensions of a head that are rotated
+    # (None: all). ``attn_window``: a query sees its own position and the
+    # window - 1 before it. ``attn_sink``: a learned scalar per query head
+    # in the softmax's denominator. ``attn_value_scale`` multiplies the
+    # values.
+    v_head_dim: Optional[int] = None
+    rope_dim: Optional[int] = None
+    attn_window: Optional[int] = None
+    attn_sink: bool = False
+    attn_value_scale: float = 1.0
+    # ``residual_dtype``: the dtype the residual stream is carried and added
+    # in between the layers (None: ``dtype``, as every model before). With
+    # experts, float32: the top-k choice is discrete, and bfloat16's rounding
+    # of the stream (2^-9 a layer, relative) is wide enough against the
+    # spacing of 256 router scores to change the last chosen expert of about
+    # one token in ten a layer, which moves that token's logits far more
+    # than rounding does (PERF.md section 6, PR 28). The matrix
+    # multiplications still take ``dtype`` inputs.
+    residual_dtype: Optional[jnp.dtype] = None
+    # ``layer_kinds``: ((name, {field: value, ...}), ...), each a set of
+    # overrides of this config's fields (num_kv_heads, head_dim,
+    # v_head_dim, rope_theta, rope_dim, attn_window, attn_sink,
+    # attn_value_scale, mlp_dim, moe_num_experts, ...) for the layers of
+    # that kind; ``layer_pattern`` gives each layer's kind by index, in
+    # published order. Empty: one kind, the stack every caller has today.
+    layer_kinds: tuple = ()
+    layer_pattern: tuple = ()
 
     def __post_init__(self):
         if self.num_kv_heads is None:
@@ -213,36 +262,122 @@ class DecoderConfig:
                 f"prefill_kernel_block must be a positive token-block size, "
                 f"got {self.prefill_kernel_block}"
             )
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid', got {self.moe_scoring!r}")
+        if self.moe_experts_held is not None:
+            first, count = self.moe_experts_held
+            outputs = self.moe_router_outputs or self.moe_num_experts
+            if count != self.moe_num_experts or first < 0 or first + count > outputs:
+                raise ValueError(
+                    f"moe_experts_held={self.moe_experts_held!r} must be (first, "
+                    f"moe_num_experts={self.moe_num_experts}) within the router's "
+                    f"{outputs} outputs")
+        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.head_dim:
+            raise ValueError(
+                f"rope_dim must be even and at most head_dim {self.head_dim}, "
+                f"got {self.rope_dim}")
+        if self.attn_window is not None and self.attn_window < 1:
+            raise ValueError(f"attn_window must be >= 1, got {self.attn_window}")
+        self.layer_kinds = tuple((str(n), dict(o)) for n, o in self.layer_kinds)
+        self.layer_pattern = tuple(int(i) for i in self.layer_pattern)
+        if bool(self.layer_kinds) != bool(self.layer_pattern):
+            raise ValueError("layer_kinds and layer_pattern must be set together")
+        if self.layer_kinds:
+            if len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern names {len(self.layer_pattern)} layers, "
+                    f"num_layers is {self.num_layers}")
+            if not all(0 <= i < len(self.layer_kinds) for i in self.layer_pattern):
+                raise ValueError("layer_pattern indexes past layer_kinds")
+            if not self.scan_layers or self.pipeline_stages > 1:
+                raise NotImplementedError(
+                    "layer kinds run as scanned stacks outside a pipeline "
+                    "(scan_layers=True, pipeline_stages=1)")
+            for i in range(len(self.layer_kinds)):
+                self.kind_config(i)  # every kind's overrides validate now
         if self.moe_num_experts == 1:
             raise ValueError("moe_num_experts must be 0 (dense) or >= 2")
-        if self.moe_num_experts > 1 and not (1 <= self.moe_top_k <= self.moe_num_experts):
+        outputs = self.moe_router_outputs or self.moe_num_experts
+        if self.moe_num_experts > 1 and not (1 <= self.moe_top_k <= outputs):
             raise ValueError(
                 f"moe_top_k={self.moe_top_k} must be in [1, moe_num_experts="
-                f"{self.moe_num_experts}]"
+                f"{outputs}]"
             )
+
+    # -- layer kinds -------------------------------------------------------
+
+    def kind_config(self, index: int, num_layers: int = 1) -> "DecoderConfig":
+        """The config of ``num_layers`` consecutive layers of kind
+        ``index``: this one with the kind's overrides, itself of one kind."""
+        return dataclasses.replace(
+            self, **self.layer_kinds[index][1], layer_kinds=(), layer_pattern=(),
+            num_layers=num_layers)
+
+    def kind_runs(self) -> list:
+        """[(kind index, number of layers)] for each run of consecutive
+        layers of one kind, in published order. A model of one kind is one
+        run."""
+        if not self.layer_kinds:
+            return [(None, self.num_layers)]
+        runs = []
+        for k in self.layer_pattern:
+            if runs and runs[-1][0] == k:
+                runs[-1][1] += 1
+            else:
+                runs.append([k, 1])
+        return [tuple(r) for r in runs]
+
+    def run_configs(self) -> list:
+        """One config a run of :meth:`kind_runs` (this one where there are
+        no kinds)."""
+        return [self if k is None else self.kind_config(k, n) for k, n in self.kind_runs()]
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rotary_dim(self) -> int:
+        return self.rope_dim or self.head_dim
+
+    @property
+    def cache_kind(self) -> str:
+        """Name of the kind of state this (one-kind) config's attention
+        layers keep, for the serving cache: layers of one name share a
+        page pool and a page table."""
+        return "full" if self.attn_window is None else f"window{self.attn_window}"
+
+    def _layer_params(self, active: bool = False) -> int:
+        e, h, kv = self.embed_dim, self.num_heads, self.num_kv_heads
+        attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
+            + h * self.value_dim * e + (h if self.attn_sink else 0)
+        if self.moe_num_experts > 1:
+            # per-expert gate/up/down + the router (and its selection bias)
+            outputs = self.moe_router_outputs or self.moe_num_experts
+            experts = self.moe_top_k if active else self.moe_num_experts
+            mlp = experts * 3 * e * self.mlp_dim + e * outputs \
+                + (outputs if self.moe_selection_bias else 0)
+        else:
+            mlp = 3 * e * self.mlp_dim
+        return attn + mlp + 2 * e  # + the two norms
+
+    def _count_params(self, active: bool) -> int:
+        layers = sum(c.num_layers * c._layer_params(active) for c in self.run_configs())
+        head = 0 if self.tie_embeddings else self.embed_dim * self.vocab_size
+        return layers + self.vocab_size * self.embed_dim + head + self.embed_dim  # + final norm
 
     @property
     def num_params(self) -> int:
-        """Parameter count (for estimate CLI / MFU math)."""
-        e, h, kv, d, m, v = (
-            self.embed_dim,
-            self.num_heads,
-            self.num_kv_heads,
-            self.head_dim,
-            self.mlp_dim,
-            self.vocab_size,
-        )
-        attn = e * h * d + 2 * e * kv * d + h * d * e
-        if self.moe_num_experts > 1:
-            # per-expert gate/up/down + the router
-            mlp = self.moe_num_experts * 3 * e * m + e * self.moe_num_experts
-        else:
-            mlp = 3 * e * m
-        norms = 2 * e
-        per_layer = attn + mlp + norms
-        embed = v * e
-        head = 0 if self.tie_embeddings else e * v
-        return self.num_layers * per_layer + embed + head + e  # + final norm
+        """Parameters held (for the estimate CLI): every layer by its kind,
+        every expert held here."""
+        return self._count_params(active=False)
+
+    @property
+    def num_active_params(self) -> int:
+        """Parameters a token passes through: as :attr:`num_params` with
+        ``moe_top_k`` experts a layer in place of those held (MFU math)."""
+        return self._count_params(active=True)
 
     @classmethod
     def tiny(cls, **kw):

@@ -23,10 +23,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
-from ..ops.attention import dot_product_attention
+from ..ops.attention import dot_product_attention, mha_reference
 from ..ops.layers import apply_rotary_embedding, rms_norm, rotary_embedding_tables, swiglu
 from ..ops.losses import fused_linear_cross_entropy
 from ..parallel.sharding import DEFAULT_AXIS_RULES, logical_to_spec
+from .configs import MOE_LOAD_COLLECTION as MOE_LOAD
 from .configs import DecoderConfig
 
 
@@ -213,11 +214,27 @@ class DecoderAttention(nn.Module):
                  slot_hist=None, kv_lengths=None):
         cfg = self.config
         e, h, kv, d = cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        # a layer kind's own value width, window, sink and value scale
+        # (getattr: Seq2SeqConfig reuses this module and has none of them)
+        dv = getattr(cfg, "value_dim", d)
+        window = getattr(cfg, "attn_window", None)
+        value_scale = float(getattr(cfg, "attn_value_scale", 1.0))
         b, s = x.shape[0], x.shape[1]
         wq = self.param("wq", nn.with_logical_partitioning(_dense_init(), ("embed", "heads", "head_dim")), (e, h, d))
         wk = self.param("wk", nn.with_logical_partitioning(_dense_init(), ("embed", "kv_heads", "head_dim")), (e, kv, d))
-        wv = self.param("wv", nn.with_logical_partitioning(_dense_init(), ("embed", "kv_heads", "head_dim")), (e, kv, d))
-        wo = self.param("wo", nn.with_logical_partitioning(_dense_init(), ("heads", "head_dim", "embed")), (h, d, e))
+        wv = self.param("wv", nn.with_logical_partitioning(_dense_init(), ("embed", "kv_heads", "head_dim")), (e, kv, dv))
+        wo = self.param("wo", nn.with_logical_partitioning(_dense_init(), ("heads", "head_dim", "embed")), (h, dv, e))
+        sink = None
+        if getattr(cfg, "attn_sink", False):
+            sink = self.param(
+                "sink", nn.with_logical_partitioning(nn.initializers.zeros, ("heads",)),
+                (h,), jnp.float32)
+        # what the kernels and references take beyond q, k, v; empty for a
+        # plain layer, whose calls are then the ones of before
+        extras = {}
+        if window is not None or sink is not None or value_scale != 1.0:
+            extras = {"window": window, "sink": sink, "value_scale": value_scale}
+        plain = not extras and dv == d
 
         dt = cfg.dtype
         if getattr(cfg, "use_fp8", False):
@@ -253,14 +270,25 @@ class DecoderAttention(nn.Module):
                 getattr(cfg, "kv_cache_dtype", "bf16"), 0
             )
             pd = d // 2 if kvq_bits == 4 else d
+            pdv = dv // 2 if kvq_bits == 4 else dv
             store_dt = jnp.int8 if kvq_bits else k.dtype
             cached_ks = cached_vs = None
             if paged:
+                from ..ops.attention import paged_key_lanes
+
+                if not kvq_bits and paged_key_lanes(d) != d:
+                    # key pages as Mosaic takes them: zero lanes up to the
+                    # next 128-multiple, on the queries too, so the scores
+                    # are the same numbers (ops/attention.paged_key_lanes)
+                    pd = paged_key_lanes(d)
+                    pad = ((0, 0), (0, 0), (0, 0), (0, pd - d))
+                    q, k = jnp.pad(q, pad), jnp.pad(k, pad)
+                    extras = dict(extras, sm_scale=d ** -0.5)
                 page_shape = (cfg.kv_num_pages, kv, cfg.kv_page_size)
                 cached_k = self.variable(
                     "cache", "cached_key", jnp.zeros, page_shape + (pd,), store_dt)
                 cached_v = self.variable(
-                    "cache", "cached_value", jnp.zeros, page_shape + (pd,), store_dt)
+                    "cache", "cached_value", jnp.zeros, page_shape + (pdv,), store_dt)
                 if kvq_bits:
                     cached_ks = self.variable(
                         "cache", "cached_key_scale", jnp.zeros,
@@ -270,7 +298,7 @@ class DecoderAttention(nn.Module):
                         page_shape + (1,), jnp.float32)
             else:
                 cached_k = self.variable("cache", "cached_key", jnp.zeros, (b, kv, max_len, pd), store_dt)
-                cached_v = self.variable("cache", "cached_value", jnp.zeros, (b, kv, max_len, pd), store_dt)
+                cached_v = self.variable("cache", "cached_value", jnp.zeros, (b, kv, max_len, pdv), store_dt)
                 if kvq_bits:
                     cached_ks = self.variable(
                         "cache", "cached_key_scale", jnp.zeros,
@@ -310,7 +338,10 @@ class DecoderAttention(nn.Module):
                     cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k, (0, 0, 0, 0))
                     cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v, (0, 0, 0, 0))
                 cache_index.value = jnp.asarray(s, jnp.int32)
-                out = dot_product_attention(q, k, v, causal=True, impl=cfg.attention_impl)
+                if plain:
+                    out = dot_product_attention(q, k, v, causal=True, impl=cfg.attention_impl)
+                else:
+                    out = mha_reference(q, k, v, causal=True, **extras)
             elif ragged_slots is not None:
                 # packed ragged prefill over the paged arena (serving/):
                 # the batch axis is ONE packed dispatch of every pending
@@ -351,7 +382,7 @@ class DecoderAttention(nn.Module):
                     row_pos=row_pos, slot_hist=slot_hist,
                     impl=getattr(cfg, "prefill_kernel", None),
                     token_block=getattr(cfg, "prefill_kernel_block", None),
-                    **scale_kw,
+                    **scale_kw, **extras,
                 )
                 # fused scatter through the page table. Pad rows (-1) route
                 # to physical page 0 — the arena's reserved parking page —
@@ -424,7 +455,7 @@ class DecoderAttention(nn.Module):
                     out = paged_decode_attention(
                         q, k_pages, v_pages,
                         page_table=page_table, q_positions=pos2d,
-                        kv_lengths=kv_lengths, impl=dk_impl, **scale_kw,
+                        kv_lengths=kv_lengths, impl=dk_impl, **scale_kw, **extras,
                     )
                 else:
                     from ..ops.attention import decode_attention
@@ -443,7 +474,7 @@ class DecoderAttention(nn.Module):
                                     "kv_quant_bits": kvq_bits}
                     out = decode_attention(
                         q, k_full, v_full, q_positions=pos2d,
-                        impl=dk_impl, block_kv=dk_blk, **scale_kw,
+                        impl=dk_impl, block_kv=dk_blk, **scale_kw, **extras,
                     )
             else:
                 scale_kw = {}
@@ -478,8 +509,16 @@ class DecoderAttention(nn.Module):
                     q, k_full, v_full, q_positions=cur + jnp.arange(s),
                     impl=getattr(cfg, "decode_kernel", None) if s == 1 else "dense",
                     block_kv=getattr(cfg, "decode_kernel_block", None),
-                    **scale_kw,
+                    **scale_kw, **extras,
                 )
+        elif not plain:
+            # a layer kind the flash kernel has no form of: the plain
+            # reference (forward passes of such a model; it is not trained)
+            if not self.causal or kv_mask is not None:
+                raise NotImplementedError(
+                    "a window, a sink, a value scale or a value width of its own "
+                    "needs causal attention without a key mask")
+            out = mha_reference(q, k, v, causal=True, **extras)
         elif (
             self.causal
             and kv_mask is None
@@ -537,11 +576,14 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, sin, cos, deterministic: bool = True, cache_positions=None,
-                 page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None):
+                 page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None,
+                 token_mask=None):
         cfg = self.config
         ln1 = self.param("ln_attn", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
         ln2 = self.param("ln_mlp", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
-        y = rms_norm(x, ln1, cfg.norm_eps)
+        # the stream may be carried wider than the activations
+        # (config.residual_dtype); the layers' inputs are cfg.dtype either way
+        y = rms_norm(x, ln1, cfg.norm_eps).astype(cfg.dtype)
         y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
             y, sin, cos, deterministic, cache_positions=cache_positions,
             page_table=page_table, ragged_slots=ragged_slots,
@@ -549,18 +591,22 @@ class DecoderBlock(nn.Module):
         )
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
-        x = x + y
-        y = rms_norm(x, ln2, cfg.norm_eps)
+        x = x + y.astype(x.dtype)
+        y_stream = rms_norm(x, ln2, cfg.norm_eps)  # in the stream's dtype
+        y = y_stream.astype(cfg.dtype)
         if cfg.moe_num_experts > 1:
             from .moe import MoeMLP
 
-            y, aux = MoeMLP(cfg, self.mesh, name="moe_mlp")(y)
+            # the router reads the normed stream as it is carried (float32
+            # with residual_dtype): its choice is discrete
+            y, aux = MoeMLP(cfg, self.mesh, self.decode, name="moe_mlp")(
+                y, token_mask, router_input=y_stream)
         else:
             y = DecoderMLP(cfg, self.mesh, name="mlp")(y)
             aux = jnp.float32(0.0)
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
-        return x + y, aux
+        return x + y.astype(x.dtype), aux
 
 
 class _ScanBlock(nn.Module):
@@ -577,15 +623,15 @@ class _ScanBlock(nn.Module):
 
     @nn.compact
     def __call__(self, carry, _):
-        # cpos/ptab/rslots/shist/klens ride the carry like sin/cos
+        # cpos/ptab/rslots/shist/klens/tmask ride the carry like sin/cos
         # (broadcast inputs every layer reads unchanged); None when the
         # slot-arena / ragged-prefill paths are off
-        x, aux, sin, cos, cpos, ptab, rslots, shist, klens = carry
+        x, aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask = carry
         x, block_aux = DecoderBlock(self.config, self.mesh, self.use_cache, self.decode, name="block")(
             x, sin, cos, self.deterministic, cache_positions=cpos, page_table=ptab,
-            ragged_slots=rslots, slot_hist=shist, kv_lengths=klens,
+            ragged_slots=rslots, slot_hist=shist, kv_lengths=klens, token_mask=tmask,
         )
-        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens), None
+        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask), None
 
 
 class StageStack(nn.Module):
@@ -610,7 +656,7 @@ class StageStack(nn.Module):
         )
         (x, aux, *_), _ = Stack(
             cfg, self.mesh, deterministic=deterministic, name="layers"
-        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None), None)
+        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None, None), None)
         if cfg.moe_num_experts > 1:
             # per-(stage, microbatch) router load-balance sum over this
             # stage's layers; the schedule accumulates and renormalizes
@@ -679,10 +725,20 @@ class DecoderLM(nn.Module):
             (cfg.vocab_size, cfg.embed_dim),
         )
         x = _embed_lookup(embedding, input_ids, cfg, self.mesh)
+        if getattr(cfg, "residual_dtype", None) is not None:
+            x = x.astype(cfg.residual_dtype)
 
         if positions is None:
             positions = jnp.arange(s)
-        sin, cos = rotary_embedding_tables(positions, cfg.head_dim, theta=cfg.rope_theta, dtype=cfg.dtype)
+        sin, cos = rotary_embedding_tables(positions, cfg.rotary_dim, theta=cfg.rope_theta, dtype=cfg.dtype)
+        # the tokens that are real, for the experts' routing and load: a
+        # packed prefill's rows (pads carry position -1), a decode step's
+        # live slots (an idle slot's live length is 0)
+        token_mask = None
+        if ragged_slots is not None:
+            token_mask = jnp.reshape(cache_positions, (b, s)) >= 0
+        elif kv_lengths is not None:
+            token_mask = jnp.broadcast_to((kv_lengths > 0)[:, None], (b, s))
 
         block_cls = DecoderBlock
         moe_aux = jnp.float32(0.0)  # router load-balance loss, summed over layers
@@ -741,17 +797,31 @@ class DecoderLM(nn.Module):
                     static_argnums=(),
                     policy=_remat_policy(cfg),
                 )
-            ScanStack = nn.scan(
-                scan_body,
-                variable_axes={"params": 0, "cache": 0, "fp8_stats": 0},
-                split_rngs={"params": True, "dropout": True},
-                length=cfg.num_layers,
-                metadata_params={nn.PARTITION_NAME: "layer"},
-            )
-            (x, moe_aux, *_), _ = ScanStack(
-                cfg, self.mesh, use_cache, decode, deterministic, name="layers"
-            )((x, jnp.float32(0.0), sin, cos, cache_positions, page_table,
-               ragged_slots, slot_hist, kv_lengths), None)
+            # one scanned stack a run of consecutive layers of one kind, in
+            # published order: a model of one kind is the one stack
+            # "layers" of before; layer kinds give "layers_0", "layers_1",
+            # ..., each with its kind's widths, rotary tables and, where
+            # the cache is paged, its kind's page table
+            for i, run_cfg in enumerate(cfg.run_configs()):
+                if cfg.layer_kinds:
+                    sin, cos = rotary_embedding_tables(
+                        positions, run_cfg.rotary_dim, theta=run_cfg.rope_theta, dtype=cfg.dtype)
+                ptab = page_table
+                if isinstance(page_table, dict):
+                    ptab = page_table[run_cfg.cache_kind]
+                ScanStack = nn.scan(
+                    scan_body,
+                    variable_axes={"params": 0, "cache": 0, "fp8_stats": 0, MOE_LOAD: 0},
+                    split_rngs={"params": True, "dropout": True},
+                    length=run_cfg.num_layers,
+                    metadata_params={nn.PARTITION_NAME: "layer"},
+                )
+                (x, run_aux, *_), _ = ScanStack(
+                    run_cfg, self.mesh, use_cache, decode, deterministic,
+                    name=f"layers_{i}" if cfg.layer_kinds else "layers",
+                )((x, jnp.float32(0.0), sin, cos, cache_positions, ptab,
+                   ragged_slots, slot_hist, kv_lengths, token_mask), None)
+                moe_aux = moe_aux + run_aux
         else:
             block_cls = _maybe_streaming(DecoderBlock, cfg)
             if cfg.remat:
@@ -760,7 +830,7 @@ class DecoderLM(nn.Module):
                 x, block_aux = block_cls(cfg, self.mesh, use_cache, decode, name=f"layer_{i}")(
                     x, sin, cos, deterministic, cache_positions=cache_positions,
                     page_table=page_table, ragged_slots=ragged_slots,
-                    slot_hist=slot_hist, kv_lengths=kv_lengths,
+                    slot_hist=slot_hist, kv_lengths=kv_lengths, token_mask=token_mask,
                 )
                 moe_aux = moe_aux + block_aux
 
@@ -775,11 +845,11 @@ class DecoderLM(nn.Module):
 
         if labels is not None:
             loss = _head_ce_loss(x, ln_f, embedding, lm_head, labels, cfg, self.mesh)
-            if cfg.moe_num_experts > 1:
+            if cfg.moe_num_experts > 1:  # (a model of layer kinds is served, not trained)
                 aux = cfg.moe_aux_loss_weight * moe_aux / cfg.num_layers
                 return {"loss": loss + aux, "lm_loss": loss, "aux_loss": aux}
             return {"loss": loss}
-        x = rms_norm(x, ln_f, cfg.norm_eps)
+        x = rms_norm(x, ln_f, cfg.norm_eps).astype(cfg.dtype)
         vocab_kernel = _tied_vocab_kernel(embedding, lm_head, cfg)
         out = {"logits": _constrain((x @ vocab_kernel).astype(jnp.float32), ("batch", "seq", "vocab"), self.mesh)}
         if cfg.moe_num_experts > 1:

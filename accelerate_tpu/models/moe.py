@@ -1,26 +1,36 @@
-"""Mixture-of-Experts FFN with expert parallelism over the mesh "expert" axis.
+"""Mixture-of-experts FFN: one routing for every caller, without a capacity
+and without a dropped token.
 
 The reference only passes MoE through to DeepSpeed
 (/root/reference/src/accelerate/utils/dataclasses.py:978-984,
-`transformer_moe_cls_names`); there is no in-repo MoE runtime. This is a
-fresh TPU-first design (SURVEY §2.3 EP row): GShard/Switch-style
-capacity-bounded routing expressed as einsums —
+`transformer_moe_cls_names`); there is no in-repo MoE runtime. This one
+routes by sorting:
 
-- tokens are routed per GROUP (one group per batch row), so the dispatch
-  tensors are [groups, group_size, experts, capacity] with capacity
-  independent of the global batch — memory stays linear in tokens;
-- per-expert FFN weights carry the logical axis ("expert", ...) and shard
-  over the mesh "expert" axis (each device group holds only its experts);
-- the grouped dispatch/combine einsums against batch-sharded activations
-  and expert-sharded weights are what GSPMD lowers to the all-to-all over
-  ICI — no hand-written collective;
-- the router runs in fp32 (numerics, with int32 queue positions so routing
-  stays exact at any batch size) and contributes the Switch load-balancing
-  auxiliary loss.
+- the router runs in float32 at the highest matmul precision over its
+  ``moe_router_outputs`` outputs; the scores are a softmax over them or a
+  sigmoid of each (``moe_scoring``); the top ``moe_top_k`` are chosen by
+  score plus an optional learned selection bias, and the chosen scores,
+  without the bias, are normalised to sum to one;
+- every (token, chosen expert) pair whose expert is held here
+  (``moe_experts_held = (first, count)``; all of them by default) is sorted
+  by expert, the tokens' rows are gathered in that order, and the three
+  expert matrices multiply them group by group (:func:`grouped_mlp`: a
+  pallas kernel named ``moe_experts`` where the serving kernels run, and
+  ``jax.lax.ragged_dot`` elsewhere, which differentiates); the rows come
+  back weighted and are added to their tokens;
+- shapes are static. The rows multiplied at once are :func:`expert_rows`:
+  all ``tokens x k`` pairs where every expert is held (one pass, so the
+  layer differentiates), and twice the expected pairs where a share is
+  held. Pairs beyond that are not dropped: a ``while_loop`` multiplies
+  further chunks of the same shape until every held pair is done, so skew
+  costs time and never a token. Pairs on absent experts sort last and are
+  never multiplied: what those experts would add is left out, and the
+  weights stay normalised over all k chosen (the model-configs guide,
+  section 4). On one chip the layer runs without its exchange.
 
-Capacity keeps shapes static (XLA requirement): each expert accepts at most
-`capacity` tokens per group; overflow tokens fall through with a zero
-expert contribution (their residual path still carries them).
+Per-expert weights carry the logical axis ("expert", ...) and shard over
+the mesh "expert" axis. The Switch load-balancing auxiliary loss is
+computed from the router's scores as before.
 """
 
 from __future__ import annotations
@@ -33,64 +43,228 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..ops.layers import swiglu
+from .configs import MOE_LOAD_COLLECTION as LOAD_COLLECTION
 from .configs import DecoderConfig
 
 
-def compute_capacity(group_size: int, num_experts: int, top_k: int, factor: float) -> int:
-    """Static per-expert queue length within one routing group."""
-    return max(1, int(group_size * top_k * factor / num_experts))
+def router_scores(logits: jax.Array, scoring: str) -> jax.Array:
+    """float32 scores [tokens, outputs] from the router's logits."""
+    logits = logits.astype(jnp.float32)
+    return jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
 
 
-def top_k_routing(
-    router_probs: jax.Array,  # [groups, group_size, experts] fp32
-    top_k: int,
-    capacity: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Build (dispatch [g,n,e,c], combine [g,n,e,c], aux_loss).
+def top_k_routing(scores: jax.Array, top_k: int,
+                  selection_bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
+    """(experts [tokens, k] int32, weights [tokens, k] float32): the top k
+    of ``scores + selection_bias``; the weights are the chosen scores
+    themselves, normalised over the k chosen."""
+    choose = scores if selection_bias is None else scores + selection_bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(choose, top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx.astype(jnp.int32), chosen / jnp.maximum(jnp.sum(chosen, -1, keepdims=True), 1e-9)
 
-    Queue positions are assigned in token order per (group, expert) — first
-    come, first served; slots beyond `capacity` are dropped. The aux loss is
-    the Switch load-balancing term E * sum_e f_e * P_e (==1 at perfect
-    balance), averaged over groups.
-    """
-    g, n, num_experts = router_probs.shape
-    gate_vals, gate_idx = jax.lax.top_k(router_probs, top_k)  # [g, n, k]
-    gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
-    # slot -> expert one-hot, token-major then slot-major so queue positions
-    # are deterministic; int32 cumsum keeps positions exact at any size
-    slot_onehot = jax.nn.one_hot(gate_idx, num_experts, dtype=jnp.int32)  # [g, n, k, e]
-    flat = slot_onehot.reshape(g, n * top_k, num_experts)
-    queue_pos = jnp.cumsum(flat, axis=1) - flat  # position within expert queue
-    pos = jnp.sum(queue_pos * flat, axis=-1).reshape(g, n, top_k)  # [g, n, k]
-    keep = (pos < capacity).astype(jnp.float32)
+def load_balance_loss(scores: jax.Array, experts: jax.Array) -> jax.Array:
+    """Switch auxiliary loss ``E * sum_e f_e * P_e`` over one group of
+    tokens: f the share of tokens whose first choice is e, P the mean
+    score of e. 1 at perfect balance."""
+    n = scores.shape[-1]
+    top1 = jax.nn.one_hot(experts[..., 0], n, dtype=jnp.float32)
+    return n * jnp.sum(jnp.mean(top1, axis=-2) * jnp.mean(scores, axis=-2), axis=-1)
 
-    expert_onehot = slot_onehot.astype(jnp.float32)
-    pos_onehot = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)  # [g, n, k, c]
-    # dispatch[g,n,e,c] = sum_k expert_onehot[g,n,k,e] * pos_onehot[g,n,k,c] * keep
-    dispatch = jnp.einsum("gnke,gnkc,gnk->gnec", expert_onehot, pos_onehot, keep)
-    combine = jnp.einsum("gnke,gnkc,gnk,gnk->gnec", expert_onehot, pos_onehot, keep, gate_vals)
 
-    # Switch aux loss on top-1 assignment, averaged over groups
-    top1 = jax.nn.one_hot(gate_idx[..., 0], num_experts, dtype=jnp.float32)  # [g, n, e]
-    fraction_routed = jnp.mean(top1, axis=1)  # [g, e]
-    mean_prob = jnp.mean(router_probs, axis=1)  # [g, e]
-    aux_loss = num_experts * jnp.mean(jnp.sum(fraction_routed * mean_prob, axis=-1))
-    return dispatch, combine, aux_loss
+def expert_rows(tokens: int, top_k: int, held: int, outputs: int) -> int:
+    """Rows the grouped product multiplies at once. Every pair where every
+    expert is held; else twice the pairs expected on the held share (a
+    multiple of 8, at least 16), which a balanced router fills half and
+    skew overflows into further chunks of the same shape, never into a
+    drop."""
+    pairs = tokens * top_k
+    if held >= outputs:
+        return pairs
+    want = max(16, -(-2 * pairs * held // outputs))
+    return min(pairs, -(-want // 8) * 8)
+
+
+def sort_pairs(experts: jax.Array, first: int, count: int,
+               token_mask: Optional[jax.Array] = None):
+    """Sort the (token, choice) pairs by held expert. Returns ``order``
+    [pairs] (pair indices, held pairs first and by expert, absent last),
+    ``sizes`` [count] (pairs on each held expert) and their sum. A pair of
+    a masked token counts as absent."""
+    local = experts.reshape(-1) - first
+    held = (local >= 0) & (local < count)
+    if token_mask is not None:
+        held = held & jnp.repeat(token_mask.reshape(-1), experts.shape[-1])
+    key = jnp.where(held, local, count)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(jax.nn.one_hot(key, count + 1, dtype=jnp.int32), axis=0)[:count]
+    return order, sizes, jnp.sum(sizes)
+
+
+# -- the grouped product ------------------------------------------------------
+
+_EXPERT_TILE = 256            # columns of the expert width a kernel step holds
+_EXPERT_VMEM = 64 * 1024 * 1024
+
+
+def _experts_kernel(live_ref, gmap_ref, start_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                    o_ref, acc):
+    """Grid (experts, tiles of the expert width). Step (g, t) multiplies
+    every row by tile t of expert ``gmap[g]`` and keeps the rows that are
+    that expert's. Experts without a row come last in ``gmap`` as repeats
+    of the last live one, so their weights are neither fetched nor
+    multiplied."""
+    from jax.experimental import pallas as pl
+
+    g, t, nt = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+
+    @pl.when((g == 0) & (t == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(g < live_ref[0])
+    def _multiply():
+        @pl.when(t == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        x = x_ref[...]
+        gate = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        acc[...] += jnp.dot(hidden, wd_ref[0], preferred_element_type=jnp.float32)
+
+        @pl.when(t == nt - 1)
+        def _():
+            e = gmap_ref[g]
+            row = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+            mine = (row >= start_ref[e]) & (row < start_ref[e + 1])
+            o_ref[...] += jnp.where(mine, acc[...], 0.0)
+
+
+def _experts_kernel_call(xs, wg, wu, wd, sizes, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, d = xs.shape
+    count, _, m = wg.shape
+    tile = next(c for c in (_EXPERT_TILE, 128, m) if m % c == 0)
+    live = sizes > 0
+    n_live = jnp.sum(live.astype(jnp.int32))
+    # live experts first, in order; the rest repeat the last live one
+    ranked = jnp.argsort(jnp.where(live, 0, 1), stable=True).astype(jnp.int32)
+    last = ranked[jnp.maximum(n_live - 1, 0)]
+    gmap = jnp.where(jnp.arange(count) < n_live, ranked, last)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
+
+    def w_in(g, t, lv, gm, st):
+        return (gm[g], 0, jnp.where(g < lv[0], t, m // tile - 1))
+
+    def w_out(g, t, lv, gm, st):
+        return (gm[g], jnp.where(g < lv[0], t, m // tile - 1), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(count, m // tile),
+        in_specs=[
+            pl.BlockSpec((rows, d), lambda g, t, *_: (0, 0)),
+            pl.BlockSpec((1, d, tile), w_in),
+            pl.BlockSpec((1, d, tile), w_in),
+            pl.BlockSpec((1, tile, d), w_out),
+        ],
+        out_specs=pl.BlockSpec((rows, d), lambda g, t, *_: (0, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
+    )
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_EXPERT_VMEM)}
+    return pl.pallas_call(
+        _experts_kernel, grid_spec=grid_spec, name="moe_experts", interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32), **params,
+    )(n_live.reshape(1), gmap, starts, xs, wg, wu, wd)
+
+
+def grouped_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
+                sizes: jax.Array, impl: str = "xla") -> jax.Array:
+    """``(silu(x Wg_e) * (x Wu_e)) Wd_e`` for rows ``xs`` [rows, d] sorted
+    by expert, ``sizes`` [experts] rows each; rows past their sum come out
+    as zeros. float32 [rows, d]. ``impl``: "xla" (``jax.lax.ragged_dot``),
+    "pallas" or "interpret" (the ``moe_experts`` kernel)."""
+    if impl != "xla":
+        return _experts_kernel_call(xs, wg, wu, wd, sizes, impl == "interpret")
+    sizes = sizes.astype(jnp.int32)
+    gate = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=jnp.float32)
+    up = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=jnp.float32)
+    hidden = swiglu(gate, up).astype(xs.dtype)
+    return jax.lax.ragged_dot(hidden, wd, sizes, preferred_element_type=jnp.float32)
+
+
+def experts_impl(cfg, decode: bool) -> str:
+    """Where the serving kernels run, so does the ``moe_experts`` kernel:
+    compiled on a TPU, interpreted where ``decode_kernel`` says so. A
+    program that is differentiated (training) takes ``ragged_dot``."""
+    if not decode:
+        return "xla"
+    mode = getattr(cfg, "decode_kernel", None)
+    if mode == "interpret":
+        return "interpret"
+    if mode == "dense" or jax.default_backend() != "tpu":
+        return "xla"
+    return "pallas"
+
+
+def routed_experts(x, experts, weights, wg, wu, wd, *, first: int = 0,
+                   outputs: Optional[int] = None, token_mask=None, impl: str = "xla"):
+    """The held experts' part of the layer's result for tokens ``x``
+    [tokens, d], and the pairs on each held expert [count]."""
+    tokens, d = x.shape
+    k, count = experts.shape[-1], wg.shape[0]
+    order, sizes, n_held = sort_pairs(experts, first, count, token_mask)
+    rows = expert_rows(tokens, k, count, outputs or count)
+    pair_token = order // k
+    pair_weight = weights.reshape(-1)[order]
+
+    def chunk(c, y):
+        lo = c * rows
+        idx = lo + jnp.arange(rows)
+        tok = jnp.take(pair_token, idx, mode="clip")
+        ends = jnp.cumsum(sizes)
+        here = jnp.clip(ends, lo, lo + rows) - jnp.clip(ends - sizes, lo, lo + rows)
+        out = grouped_mlp(jnp.take(x, tok, axis=0), wg, wu, wd, here, impl)
+        w = jnp.where(idx < n_held, jnp.take(pair_weight, idx, mode="clip"), 0.0)
+        return y.at[tok].add(out * w[:, None])
+
+    y0 = jnp.zeros((tokens, d), jnp.float32)
+    if rows >= tokens * k:
+        y = chunk(0, y0)  # one pass holds every pair: no loop, so it differentiates
+    else:
+        _, y = jax.lax.while_loop(
+            lambda cy: cy[0] * rows < n_held, lambda cy: (cy[0] + 1, chunk(cy[0], cy[1])),
+            (jnp.int32(0), y0))
+    return y, sizes
 
 
 class MoeMLP(nn.Module):
-    """Drop-in replacement for DecoderMLP returning (y, aux_loss)."""
+    """Drop-in replacement for DecoderMLP returning (y, aux_loss).
+    ``token_mask`` [b, s] bool marks the tokens that are real (a serving
+    step's live slots, a packed prefill's rows); the others are routed
+    nowhere. ``router_input``: what the router reads where it is not ``x``
+    (the same activations before they were rounded to the layer's dtype).
+    Where the ``moe_load`` collection is mutable, the pairs on each held
+    expert are written to it."""
 
     config: DecoderConfig
     mesh: Optional[Mesh] = None
+    decode: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x: jax.Array, token_mask=None, router_input=None) -> Tuple[jax.Array, jax.Array]:
         from .decoder import _constrain, _dense_init
 
         cfg = self.config
         E, k = cfg.moe_num_experts, cfg.moe_top_k
+        R = cfg.moe_router_outputs or E
+        first = cfg.moe_experts_held[0] if cfg.moe_experts_held else 0
         b, s, d = x.shape
         m = cfg.mlp_dim
         dt = cfg.dtype
@@ -98,8 +272,14 @@ class MoeMLP(nn.Module):
         router_w = self.param(
             "router",
             nn.with_logical_partitioning(_dense_init(), ("embed", "router_experts")),
-            (d, E),
+            (d, R),
         )
+        bias = None
+        if cfg.moe_selection_bias:
+            bias = self.param(
+                "selection_bias",
+                nn.with_logical_partitioning(nn.initializers.zeros, ("router_experts",)),
+                (R,), jnp.float32)
         wg = self.param(
             "w_gate",
             nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
@@ -116,22 +296,22 @@ class MoeMLP(nn.Module):
             (E, m, d),
         )
 
-        # one routing group per batch row: dispatch stays [b, s, E, c] with
-        # c = O(s), independent of the global batch size
-        logits = jnp.einsum("gnd,de->gne", x.astype(jnp.float32), router_w.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        capacity = compute_capacity(s, E, k, cfg.moe_capacity_factor)
-        dispatch, combine, aux_loss = top_k_routing(probs, k, capacity)
-
-        # token -> expert-queue scatter; GSPMD lowers this to the all-to-all
-        # when x is batch-sharded and the experts axis is mesh-sharded
-        expert_in = jnp.einsum("gnec,gnd->gecd", dispatch.astype(dt), x)
-        expert_in = _constrain(expert_in, ("batch", "expert", "expert_capacity", "embed"), self.mesh)
-        gate = jnp.einsum("gecd,edm->gecm", expert_in, wg.astype(dt))
-        up = jnp.einsum("gecd,edm->gecm", expert_in, wu.astype(dt))
-        hidden = _constrain(swiglu(gate, up), ("batch", "expert", "expert_capacity", "mlp"), self.mesh)
-        expert_out = jnp.einsum("gecm,emd->gecd", hidden, wd.astype(dt))
-        expert_out = _constrain(expert_out, ("batch", "expert", "expert_capacity", "embed"), self.mesh)
-        # expert-queue -> token gather (the return all-to-all)
-        y = jnp.einsum("gnec,gecd->gnd", combine.astype(dt), expert_out)
+        flat = x.reshape(b * s, d)
+        with jax.named_scope("moe_router"):
+            routed = flat if router_input is None else router_input.reshape(b * s, d)
+            logits = jnp.matmul(routed.astype(jnp.float32), router_w.astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
+            scores = router_scores(logits, cfg.moe_scoring)
+            experts, weights = top_k_routing(scores, k, bias)
+        # the auxiliary loss is a mean over the batch rows' own balance, as
+        # the grouped routing before it had it
+        aux_loss = jnp.mean(load_balance_loss(
+            scores.reshape(b, s, R), experts.reshape(b, s, k)))
+        y, sizes = routed_experts(
+            flat.astype(dt), experts, weights, wg.astype(dt), wu.astype(dt), wd.astype(dt),
+            first=first, outputs=R, token_mask=token_mask,
+            impl=experts_impl(cfg, self.decode))
+        if self.is_mutable_collection(LOAD_COLLECTION):
+            self.variable(LOAD_COLLECTION, "pairs", lambda: jnp.zeros((E,), jnp.int32)).value = sizes
+        y = y.astype(dt).reshape(b, s, d)
         return _constrain(y, ("batch", "seq", "embed"), self.mesh), aux_loss

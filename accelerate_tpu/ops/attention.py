@@ -87,37 +87,52 @@ def mha_reference(
     causal: bool = False,
     sm_scale: Optional[float] = None,
     bias: Optional[jax.Array] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    value_scale: float = 1.0,
 ) -> jax.Array:
-    """Plain-XLA attention. q: [B, H, Sq, D]; k/v: [B, KVH, Skv, D].
-    ``bias`` is additive, broadcastable to [B, H, Sq, Skv] (use large
-    negatives for padding masks)."""
+    """Plain-XLA attention. q: [B, H, Sq, D]; k: [B, KVH, Skv, D]; v:
+    [B, KVH, Skv, Dv] (the value width may differ from the key width; the
+    output is [B, H, Sq, Dv]). ``bias`` is additive, broadcastable to
+    [B, H, Sq, Skv] (use large negatives for padding masks).
+
+    ``window`` (with ``causal``): a query at position i sees key j iff
+    ``j <= i`` and ``i - j < window`` -- the window counts the query's own
+    position. ``sink`` [H] float: a learned scalar per query head that
+    joins the softmax's denominator and carries no value. ``value_scale``
+    multiplies the values (by linearity, the output)."""
     orig_dtype = q.dtype
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     b, h, sq, d = q.shape
-    kvh = k.shape[1]
-    if kvh != h:
-        group = h // kvh
-        q = q.reshape(b, kvh, group, sq, d)
-        s = jnp.einsum("bkgqd,bkcd->bkgqc", q, k, preferred_element_type=jnp.float32)
-    else:
-        s = jnp.einsum("bhqd,bhcd->bhqc", q, k, preferred_element_type=jnp.float32)
+    kvh, skv, dv = k.shape[1], k.shape[2], v.shape[-1]
+    group = h // kvh
+    qg = q.reshape(b, kvh, group, sq, d)
+    s = jnp.einsum("bkgqd,bkcd->bkgqc", qg, k, preferred_element_type=jnp.float32)
     s = s * sm_scale
     if bias is not None:
-        bias32 = jnp.broadcast_to(bias.astype(jnp.float32), (b, h, sq, k.shape[2]))
-        if kvh != h:
-            bias32 = bias32.reshape(b, kvh, group, sq, k.shape[2])
-        s = s + bias32
+        bias32 = jnp.broadcast_to(bias.astype(jnp.float32), (b, h, sq, skv))
+        s = s + bias32.reshape(b, kvh, group, sq, skv)
     if causal:
-        skv = k.shape[2]
-        mask = jnp.tril(jnp.ones((sq, skv), dtype=bool), k=skv - sq)
+        qpos = jnp.arange(sq)[:, None] + (skv - sq)
+        kpos = jnp.arange(skv)[None, :]
+        mask = kpos <= qpos
+        if window is not None:
+            mask = mask & (qpos - kpos < window)
         s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    if kvh != h:
-        out = jnp.einsum("bkgqc,bkcd->bkgqd", p.astype(v.dtype), v)
-        out = out.reshape(b, h, sq, d)
+    elif window is not None:
+        raise ValueError("window needs causal=True (or a bias that encodes it)")
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, kvh, group, 1, 1), (b, kvh, group, sq, 1))
+        p = jax.nn.softmax(jnp.concatenate([s, col], axis=-1), axis=-1)[..., :skv]
     else:
-        out = jnp.einsum("bhqc,bhcd->bhqd", p.astype(v.dtype), v)
-    return out.astype(orig_dtype)
+        p = jax.nn.softmax(s, axis=-1)
+    if value_scale != 1.0:
+        out = jnp.einsum("bkgqc,bkcd->bkgqd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32) * value_scale
+    else:
+        out = jnp.einsum("bkgqc,bkcd->bkgqd", p.astype(v.dtype), v)
+    return out.reshape(b, h, sq, dv).astype(orig_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -684,8 +699,22 @@ def _warn_decode_fallback(reason: str):
     )
 
 
+def paged_key_lanes(d: int) -> int:
+    """Lanes a key of width ``d`` takes in a paged arena's pages. The paged
+    decode kernel copies whole pages out of the arena in HBM, and Mosaic
+    takes such a slice only where the last dimension is a 128-multiple
+    (``_decode_kernel_gate``). A width over 128 that is no 128-multiple
+    (192) is therefore stored zero-padded to the next one (256): the
+    queries are padded alike, so the scores are the same numbers, and the
+    page costs a third more key bytes. A 64-wide head keeps its 64 lanes
+    and the masked-dense read, as before (padding it would double its
+    bytes)."""
+    return d if d <= 128 or d % 128 == 0 else -(-d // 128) * 128
+
+
 def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
-                        quant_bits: int = 0, paged: bool = False):
+                        quant_bits: int = 0, paged: bool = False,
+                        dv: Optional[int] = None):
     """(use_kernel, interpret) for one dispatch. Falls back silently for
     by-design exclusions (``dense`` mode, prefill-size Sq) and with a
     warn-once for environment/shape gates. ``quant_bits`` extends the
@@ -709,7 +738,11 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
     128-multiples of unquantized pages only. A 64-wide head, an int4
     payload (head_dim / 2 wide) and the ``[.., page_size, 1]`` scale
     pages of any quantized arena resolve to the dense path there
-    (tests/test_tpu_compile.py holds both halves by name)."""
+    (tests/test_tpu_compile.py holds both halves by name). ``d`` is the
+    width of the key pages as stored and ``dv`` that of the value pages
+    (absent: the same): a 192-wide key is refused as it is and taken
+    padded to 256 lanes (:func:`paged_key_lanes`), the layout a model
+    with such keys gives its pages."""
     if mode == "dense":
         return False, False
     if sq > _DECODE_KERNEL_MAX_SQ:
@@ -738,11 +771,13 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
             "resolves to the gathered dequant + masked-dense read"
         )
         return False, False
-    if paged and (d % 128 != 0 or quant_bits):
+    dv = d if dv is None else dv
+    if paged and (d % 128 != 0 or dv % 128 != 0 or quant_bits):
         _warn_decode_fallback(
             f"shape gate: the paged kernel copies whole pages out of the "
             f"arena in HBM, and Mosaic refuses a slice of an HBM array "
             f"whose last dimension is not a 128-multiple: head_dim {d}"
+            + (f" (values {dv})" if dv != d else "")
             + (f", int{quant_bits} KV (its scale pages end in a dimension "
                "of 1)" if quant_bits else "")
             + "; this dispatch resolves to the gathered masked-dense read"
@@ -769,7 +804,8 @@ def decode_kernel_active(config, sq: int = 1) -> bool:
         getattr(config, "kv_cache_dtype", "bf16"), 0
     )
     use, _ = _decode_kernel_gate(
-        mode, sq, head_dim, int(page_size), quant_bits, paged=True)
+        mode, sq, paged_key_lanes(head_dim), int(page_size), quant_bits, paged=True,
+        dv=int(getattr(config, "v_head_dim", None) or head_dim))
     return use
 
 
@@ -926,18 +962,33 @@ def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
 
 
 def _paged_decode_block_pages(kvh: int, ps: int, pd: int, dtype,
-                              quant_bits: int, table_len: int) -> int:
+                              quant_bits: int, table_len: int,
+                              pdv: Optional[int] = None,
+                              window_pages: Optional[int] = None) -> int:
     """Pages a block of the paged decode walk holds: the largest power of
     two whose double-buffered K and V pages (all kv heads of a page, plus
     their fp32 scale pages when quantized) fit the VMEM budget, at most
     ``_PAGED_DECODE_MAX_BLOCK_PAGES`` and no more than the page table is
-    long."""
+    long. ``pdv`` is the value pages' width where it differs from the
+    keys'. ``window_pages``: the most pages a window layer's walk spans;
+    one block then holds them all where VMEM allows."""
     page = kvh * _vmem_tile_bytes(ps, pd, dtype)
+    page_v = page if pdv is None else kvh * _vmem_tile_bytes(ps, pdv, dtype)
     if quant_bits:
         page += kvh * _vmem_tile_bytes(ps, 1, jnp.float32)
-    fit = _PAGED_DECODE_VMEM_BUDGET // (4 * page)
+        page_v += kvh * _vmem_tile_bytes(ps, 1, jnp.float32)
+    fit = _PAGED_DECODE_VMEM_BUDGET // (2 * (page + page_v))
     cap = max(1, min(fit, _PAGED_DECODE_MAX_BLOCK_PAGES, table_len))
-    return 1 << (cap.bit_length() - 1)
+    n = 1 << (cap.bit_length() - 1)
+    if window_pages is not None:
+        n = min(n, 1 << (max(1, window_pages) - 1).bit_length())
+    return n
+
+
+def window_span_pages(window: int, ps: int, sq: int = 1) -> int:
+    """The most pages that hold the ``window + sq - 1`` positions a window
+    layer's ``sq`` query rows see between them."""
+    return (window + sq - 3) // ps + 2 if window + sq > 2 else 1
 
 
 def paged_decode_block_pages(config, table_len: int) -> int:
@@ -945,16 +996,22 @@ def paged_decode_block_pages(config, table_len: int) -> int:
     with this config and a page table ``table_len`` entries long: what the
     serving engine counts ``walked_blocks`` in."""
     bits = {"int8": 8, "int4": 4}.get(getattr(config, "kv_cache_dtype", "bf16"), 0)
-    width = config.head_dim // 2 if bits == 4 else config.head_dim
+    width = config.head_dim // 2 if bits == 4 else paged_key_lanes(config.head_dim)
+    dv = getattr(config, "v_head_dim", None)
+    window = getattr(config, "attn_window", None)
     return _paged_decode_block_pages(
         config.num_kv_heads, int(config.kv_page_size), width,
         jnp.int8 if bits else config.dtype, bits, table_len,
+        pdv=None if dv in (None, config.head_dim) else dv,
+        window_pages=None if window is None else window_span_pages(
+            window, int(config.kv_page_size)),
     )
 
 
 def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
                          sm_scale, sq, group, block_pages, quant_bits,
-                         out_dtype):
+                         out_dtype, window=None, has_sink=False,
+                         value_scale=1.0):
     """One slot a grid step; inside, a loop over blocks of ``block_pages``
     consecutive table entries. Every live page of a block comes from the
     arena (left in HBM) by one asynchronous copy that brings all kv heads
@@ -968,7 +1025,18 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
     softmax and accumulator, probabilities cast to the value dtype before
     PV, validity ``kv position <= row position`` (bounded by the slot's
     live length, beyond which no page was copied), quantized pages
-    dequantized in-register by ``utils.quantization.dequantize_kv``."""
+    dequantized in-register by ``utils.quantization.dequantize_kv``.
+
+    ``window`` (a window layer): a row at position p sees kv positions
+    ``p - window < c <= p`` only, and the walk starts at the page that
+    holds the first position any of the slot's rows sees, so it visits at
+    most ``window_span_pages`` pages whatever the context; the pages
+    before it may have been given back. ``has_sink``: a further operand
+    [KVH, G, 128] holds each row's learned scalar, which starts the running
+    maximum with a sum of one and no value. ``value_scale`` multiplies the
+    output. The key pages may be wider than the value pages."""
+    if has_sink:
+        sink_ref, refs = refs[0], refs[1:]
     if quant_bits:
         (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
          kbuf, vbuf, ksbuf, vsbuf, sems, state, acc, m_scr, l_scr) = refs
@@ -980,11 +1048,23 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
     g = group * sq
     bk = block_pages * ps  # kv positions a block spans
     live = len_ref[b]
-    n_pages = (live + ps - 1) // ps
+
+    def first_page(slot):
+        """Table entry a slot's walk starts at: 0, or the page that holds
+        the first position its earliest row sees through the window."""
+        if window is None:
+            return 0
+        return jnp.maximum(len_ref[slot] - sq - window + 1, 0) // ps
+
+    def walk_pages(slot):
+        return (len_ref[slot] + ps - 1) // ps - first_page(slot)
+
+    p0 = first_page(b)
+    n_pages = walk_pages(b)
     n_blocks = (n_pages + block_pages - 1) // block_pages
 
     def page_copies(slot, blk, half, j):
-        page = table_ref[slot, blk * block_pages + j]
+        page = table_ref[slot, first_page(slot) + blk * block_pages + j]
         pairs = [(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)]
         if quant_bits:
             pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
@@ -994,8 +1074,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
         ]
 
     def for_each_live_page(slot, blk, half, act):
-        pages = (len_ref[slot] + ps - 1) // ps
-        count = jnp.minimum(block_pages, pages - blk * block_pages)
+        count = jnp.minimum(block_pages, walk_pages(slot) - blk * block_pages)
 
         def one(j, _):
             for copy in page_copies(slot, blk, half, j):
@@ -1035,8 +1114,12 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
         def _():
             start(b, 0, first_half)
 
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if has_sink:
+            m_scr[...] = sink_ref[...]
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
         def block(ib, half):
@@ -1062,11 +1145,14 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
                 state[1] = (nxt < nslots).astype(jnp.int32)
 
             wait(b, ib, half)
-            kvpos = ib * bk + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1)
+            kvpos = (p0 * ps + ib * bk
+                     + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1))
             # a row sees kv position c iff c <= its own position, and never
             # past the live length: beyond it no page was copied
             rowpos = _fold_row_positions(pos_ref, b, sq, (g, bk), bound=live - 1)
             valid = kvpos <= rowpos
+            if window is not None:
+                valid = valid & (rowpos - kvpos < window)
 
             def load(buf, sbuf, h_):
                 x = buf[half, :, h_]  # [block_pages, ps, pd]
@@ -1108,51 +1194,69 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
             return other
 
         state[0] = jax.lax.fori_loop(0, n_blocks, block, first_half)
-        # kv position 0 is valid for every row, so the sum is never zero
-        o_ref[0] = (acc[...] / l_scr[...][:, :, :1]).astype(o_ref.dtype)
+        # a row's own position is valid for it, so the sum is never zero
+        out = acc[...] / l_scr[...][:, :, :1]
+        if value_scale != 1.0:
+            out = out * value_scale
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
                               sm_scale, interpret, k_scale=None,
-                              v_scale=None, quant_bits=0):
+                              v_scale=None, quant_bits=0, window=None,
+                              sink=None, value_scale=1.0):
     b, h, sq, d = q.shape
     _, kvh, ps, pd = k_pages.shape  # pd: payload width (d, or d/2 packed int4)
+    pdv = v_pages.shape[-1]
+    dv = 2 * pdv if quant_bits == 4 else pdv  # the output's width
     group = h // kvh
     g = group * sq
     n = _paged_decode_block_pages(
-        kvh, ps, pd, k_pages.dtype, quant_bits, page_table.shape[1])
+        kvh, ps, pd, k_pages.dtype, quant_bits, page_table.shape[1],
+        pdv=None if pdv == pd else pdv,
+        window_pages=None if window is None else window_span_pages(window, ps, sq))
     q_r = _fold_q_heads(q, kvh)
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, sq=sq, group=group,
         block_pages=n, quant_bits=quant_bits, out_dtype=q.dtype,
+        window=window, has_sink=sink is not None, value_scale=value_scale,
     )
     slot_spec = pl.BlockSpec((1, kvh, g, d), lambda b_, ln, po, tb: (b_, 0, 0, 0))
+    out_spec = pl.BlockSpec((1, kvh, g, dv), lambda b_, ln, po, tb: (b_, 0, 0, 0))
     arena = pl.BlockSpec(memory_space=pl.ANY)
-    operands = [q_r, k_pages, v_pages]
-    buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype)] * 2
+    in_specs, operands = [slot_spec], [q_r]
+    if sink is not None:
+        # row r of a kv head's fold is query head r // sq of its group
+        rows = jnp.repeat(sink.astype(jnp.float32).reshape(kvh, group), sq, axis=1)
+        operands.append(jnp.broadcast_to(rows[:, :, None], (kvh, g, 128)))
+        in_specs.append(pl.BlockSpec((kvh, g, 128), lambda b_, ln, po, tb: (0, 0, 0)))
+    operands += [k_pages, v_pages]
+    buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype),
+               pltpu.VMEM((2, n, kvh, ps, pdv), v_pages.dtype)]
     if quant_bits:
         # per-(page, kv-head, token) fp32 scales ride the same walk
         operands += [k_scale, v_scale]
         buffers += [pltpu.VMEM((2, n, kvh, ps, 1), jnp.float32)] * 2
+    in_specs += [arena] * (4 if quant_bits else 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
-        in_specs=[slot_spec] + [arena] * (len(operands) - 1),
-        out_specs=slot_spec,
+        in_specs=in_specs,
+        out_specs=out_spec,
         scratch_shapes=buffers + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
-            _vmem((kvh, g, d)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
+            _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
         ],
     )
     # the slots run in order: each starts the next one's first copies
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
         **_grid_params(interpret, ("arbitrary",)),
     )(lengths.astype(jnp.int32), pos, page_table.astype(jnp.int32), *operands)
-    return out.reshape(b, h, sq, d)
+    return out.reshape(b, h, sq, dv)
 
 
 def _dense_decode_kernel_call(q, k, v, pos, sm_scale, bk, interpret,
@@ -1212,6 +1316,9 @@ def decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     kv_quant_bits: int = 0,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    value_scale: float = 1.0,
 ) -> jax.Array:
     """Masked KV-cache decode attention with per-row validity.
 
@@ -1240,11 +1347,19 @@ def decode_attention(
     kernel path dequantizes IN-REGISTER after the quantized HBM read; the
     masked-dense path runs the reference ``dequantize_kv`` first and stays
     the exactness oracle.
+
+    ``window`` / ``sink`` / ``value_scale`` and a value width other than
+    the key width are :func:`mha_reference`'s; the dense-arena kernel has
+    none of them, so a layer that states one reads masked-dense here (the
+    paged kernel, :func:`paged_decode_attention`, has them all).
     """
     mode = resolve_decode_kernel(impl)
     sq, d = q.shape[2], q.shape[3]
     if kv_quant_bits and (k_scale is None or v_scale is None):
         raise ValueError("kv_quant_bits needs k_scale and v_scale")
+    if (window is not None or sink is not None or value_scale != 1.0
+            or v.shape[-1] != k.shape[-1]):
+        mode = "dense"
     if mode != "dense":
         bk = _pick_decode_block(k.shape[2], block_kv, mode == "interpret")
         if block_kv and bk and bk != int(block_kv):
@@ -1269,14 +1384,14 @@ def decode_attention(
         k = dequantize_kv(k, k_scale, kv_quant_bits, q.dtype)
         v = dequantize_kv(v, v_scale, kv_quant_bits, q.dtype)
     kv_pos = jnp.arange(k.shape[2])
-    if q_positions.ndim == 1:  # [Sq] shared positions
-        bias = jnp.where(kv_pos[None, :] <= q_positions[:, None], 0.0, NEG_INF)
-        bias = bias[None, None]  # [1, 1, Sq, L]
-    else:  # [B, Sq] per-slot positions
-        bias = jnp.where(
-            kv_pos[None, None, :] <= q_positions[:, :, None], 0.0, NEG_INF
-        )[:, None]  # [B, 1, Sq, L]
-    return mha_reference(q, k, v, causal=False, sm_scale=sm_scale, bias=bias)
+    seen = kv_pos <= q_positions[..., None]  # [Sq, L] or [B, Sq, L]
+    if window is not None:
+        seen = seen & (q_positions[..., None] - kv_pos < window)
+    bias = jnp.where(seen, 0.0, NEG_INF)
+    # [Sq] shared positions -> [1, 1, Sq, L]; [B, Sq] per-slot -> [B, 1, Sq, L]
+    bias = bias[None, None] if q_positions.ndim == 1 else bias[:, None]
+    return mha_reference(q, k, v, causal=False, sm_scale=sm_scale, bias=bias,
+                         sink=sink, value_scale=value_scale)
 
 
 def gather_kv_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
@@ -1309,6 +1424,9 @@ def paged_decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     kv_quant_bits: int = 0,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    value_scale: float = 1.0,
 ) -> jax.Array:
     """Decode attention reading K/V through a per-slot page table.
 
@@ -1340,14 +1458,23 @@ def paged_decode_attention(
     live-token bandwidth win compounds with the 2-4x byte shrink. The
     gather fallback dequantizes with the reference ``dequantize_kv`` —
     identical quantized inputs produce the oracle's exact values.
+
+    The value pages may be narrower than the key pages ([.., Dv] against
+    [.., D]; the output is [B, H, Sq, Dv]). ``window``: a row at position
+    p sees positions ``p - window < c <= p``, and the kernel's walk starts
+    at the page that holds the first of them, so the table's entries
+    before it are never read (the engine gives those pages back).
+    ``sink`` [H] and ``value_scale`` are :func:`mha_reference`'s.
     """
     mode = resolve_decode_kernel(impl)
     if kv_quant_bits and (k_scale is None or v_scale is None):
         raise ValueError("kv_quant_bits needs k_scale and v_scale")
+    extras = {"window": window, "sink": sink, "value_scale": value_scale}
     if mode != "dense":
         sq, d = q.shape[2], q.shape[3]
         use, interpret = _decode_kernel_gate(
-            mode, sq, d, k_pages.shape[2], kv_quant_bits, paged=True
+            mode, sq, d, k_pages.shape[2], kv_quant_bits, paged=True,
+            dv=v_pages.shape[-1] * (2 if kv_quant_bits == 4 else 1),
         )
         if use:
             scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
@@ -1357,6 +1484,7 @@ def paged_decode_attention(
             return _paged_decode_kernel_call(
                 q, k_pages, v_pages, page_table, pos, kv_lengths, scale, interpret,
                 k_scale=k_scale, v_scale=v_scale, quant_bits=kv_quant_bits,
+                **extras,
             )
     k_full = gather_kv_pages(k_pages, page_table)
     v_full = gather_kv_pages(v_pages, page_table)
@@ -1366,10 +1494,11 @@ def paged_decode_attention(
             impl="dense",
             k_scale=gather_kv_pages(k_scale, page_table),
             v_scale=gather_kv_pages(v_scale, page_table),
-            kv_quant_bits=kv_quant_bits,
+            kv_quant_bits=kv_quant_bits, **extras,
         )
     return decode_attention(
-        q, k_full, v_full, q_positions=q_positions, sm_scale=sm_scale, impl="dense"
+        q, k_full, v_full, q_positions=q_positions, sm_scale=sm_scale,
+        impl="dense", **extras,
     )
 
 
@@ -1530,12 +1659,29 @@ def _quantize_block(x, bits):
     return kv_payload(qf, bits), scale, qf * scale
 
 
+def _prefill_window_kernel_entry(bslot_ref, bhist_ref, tbl_ref, blo_ref, *refs,
+                                 has_sink, **kw):
+    """A window layer's entry: a fourth prefetched scalar a token block,
+    the table entry its arena walk starts at, and (``has_sink``) the rows'
+    learned scalars after the positions."""
+    q_ref, k_ref, v_ref, kn_ref, vn_ref, qpos_ref, kvpos_ref = refs[:7]
+    rest = refs[7:]
+    sink_ref = None
+    if has_sink:
+        sink_ref, rest = rest[0], rest[1:]
+    o_ref, acc, m_scr, l_scr = rest
+    _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
+                         kn_ref, vn_ref, qpos_ref, kvpos_ref, o_ref,
+                         acc, m_scr, l_scr, blo_ref=blo_ref, sink_ref=sink_ref, **kw)
+
+
 def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
                          kn_ref, vn_ref, qpos_ref, kvpos_ref, o_ref,
                          acc, m_scr, l_scr, *, sm_scale, ps, bt, group,
                          npb, ntb, quant_bits=0, out_dtype=None,
                          ks_ref=None, vs_ref=None, kq_ref=None, kso_ref=None,
-                         vq_ref=None, vso_ref=None):
+                         vq_ref=None, vso_ref=None, window=None,
+                         value_scale=1.0, blo_ref=None, sink_ref=None):
     """One (token-block i, kv-head h, kv-step j) cell of the ragged
     prefill grid. j < ``npb`` walks the q block's slot's live arena pages
     (the prefix already in the cache — dequantized in-register when the
@@ -1551,13 +1697,21 @@ def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        if sink_ref is not None:
+            # the learned scalar joins the denominator and carries no value
+            m_scr[...] = jnp.broadcast_to(sink_ref[0], m_scr.shape)
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
     slot = bslot_ref[i]
     hist = bhist_ref[i]
     n_hist_blocks = (hist + ps - 1) // ps
+    # a window layer's arena walk starts at the first page its block's
+    # earliest row sees (the pages before it may have been given back)
+    lo = 0 if blo_ref is None else blo_ref[i]
     # per-row (token, head-group) query positions, expanded per folded
     # row by the caller: a (bt, group) -> (bt*group, 1) reshape in here
     # is a shape cast Mosaic cannot lay out
@@ -1603,7 +1757,13 @@ def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
 
     q = q_ref[0, 0]  # [bt*group, D]
 
-    @pl.when((slot >= 0) & (j < n_hist_blocks))
+    arena_live = (slot >= 0) & (lo + j < n_hist_blocks)
+    if blo_ref is not None:
+        # the window's walk is npb steps long, not the table's length: a
+        # later step belongs to the fresh phase even where pages remain
+        arena_live = arena_live & (j < npb)
+
+    @pl.when(arena_live)
     def _arena_phase():
         k = k_ref[0, 0]
         v = v_ref[0, 0]
@@ -1615,12 +1775,14 @@ def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale
-        kvp = j * ps + jax.lax.broadcasted_iota(
+        kvp = (lo + j) * ps + jax.lax.broadcasted_iota(
             jnp.int32, (bt * group, ps), 1
         )
         # kvp < hist: only the slot's live prefix (stale arena rows past
         # the frontier never score); kvp <= rowpos masks pad rows
         valid = (kvp < hist) & (kvp <= rowpos)
+        if window is not None:
+            valid = valid & (rowpos - kvp < window)
         s = jnp.where(valid, s, NEG_INF)
         _accumulate(s, valid, v)
 
@@ -1639,6 +1801,8 @@ def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
             preferred_element_type=jnp.float32,
         ) * sm_scale
         valid = (kvq >= 0) & (kvq <= rowpos)
+        if window is not None:
+            valid = valid & (rowpos - kvq < window)
         s = jnp.where(valid, s, NEG_INF)
         _accumulate(s, valid, v_fresh)
 
@@ -1646,7 +1810,10 @@ def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
     def _out():
         l = l_scr[...][:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc[...] / safe_l).astype(o_ref.dtype)
+        out = acc[...] / safe_l
+        if value_scale != 1.0:
+            out = out * value_scale
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def _prefill_quant_kernel_entry(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref,
@@ -1663,19 +1830,29 @@ def _prefill_quant_kernel_entry(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref,
 def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
                                 row_slot, row_pos, slot_hist, sm_scale, bt,
                                 interpret, k_scale=None, v_scale=None,
-                                quant_bits=0):
+                                quant_bits=0, window=None, sink=None,
+                                value_scale=1.0):
     _, h, cap, d = q.shape
     _, kvh, ps, pd = k_pages.shape
+    dv, pdv = v_new.shape[-1], v_pages.shape[-1]
     group = h // kvh
     ntb = cap // bt
     g = bt * group
+    windowed = window is not None or sink is not None
+    if windowed and quant_bits:
+        raise NotImplementedError(
+            "the ragged prefill kernel has no window or sink over quantized pages")
+    # arena steps of the grid: every table entry, or (a window layer) the
+    # pages that can hold the window - 1 positions before a block's first row
     npb = page_table.shape[1]
+    if window is not None:
+        npb = min(npb, window_span_pages(window - 1, ps))
     # fold: per kv head, one [bt*group, D] block per token block, rows
     # ordered (token, group member) — same convention as _fold_q_heads
     q_r = (q[0].reshape(kvh, group, cap, d)
            .transpose(0, 2, 1, 3).reshape(kvh, ntb, g, d))
     kn_r = k_new[0].reshape(kvh, ntb, bt, d)
-    vn_r = v_new[0].reshape(kvh, ntb, bt, d)
+    vn_r = v_new[0].reshape(kvh, ntb, bt, dv)
     blk_slot = row_slot.reshape(ntb, bt)[:, 0].astype(jnp.int32)
     blk_hist = jnp.where(
         blk_slot >= 0, slot_hist[jnp.maximum(blk_slot, 0)], 0
@@ -1683,25 +1860,35 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
     pos_in = row_pos.reshape(ntb, 1, bt).astype(jnp.int32)
     # row r of a folded q block is token r // group
     pos_rows = jnp.repeat(row_pos.astype(jnp.int32), group).reshape(ntb, g, 1)
+    prefetch = [blk_slot, blk_hist, page_table.astype(jnp.int32)]
+    if windowed:
+        first = row_pos.reshape(ntb, bt)[:, 0].astype(jnp.int32)
+        lo = (jnp.maximum(first - window + 1, 0) // ps if window is not None
+              else jnp.zeros_like(first))
+        prefetch.append(lo.astype(jnp.int32))
 
-    entry = _prefill_quant_kernel_entry if quant_bits else _prefill_kernel_body
+    if windowed:
+        entry = functools.partial(_prefill_window_kernel_entry, has_sink=sink is not None)
+    else:
+        entry = _prefill_quant_kernel_entry if quant_bits else _prefill_kernel_body
     kernel = functools.partial(
         entry, sm_scale=sm_scale, ps=ps, bt=bt, group=group, npb=npb,
         ntb=ntb, quant_bits=quant_bits, out_dtype=q.dtype,
+        **({"window": window, "value_scale": value_scale}
+           if windowed or value_scale != 1.0 else {}),
     )
 
     def _page_spec(width):
         # arena phase: walk the q block's slot's live prefix pages; dead
         # steps (past ceil(hist/ps), or the whole fresh phase) re-address
         # the last live page so their fetch is elided
-        return pl.BlockSpec(
-            (1, 1, ps, width),
-            lambda i, h_, j, bs, bh, tb: (
-                tb[jnp.maximum(bs[i], 0),
-                   jnp.clip(j, 0, jnp.maximum((bh[i] + ps - 1) // ps - 1, 0))],
-                h_, 0, 0,
-            ),
-        )
+        def index(i, h_, j, bs, bh, tb, *lo_):
+            entry_ = j + lo_[0][i] if lo_ else j
+            return (tb[jnp.maximum(bs[i], 0),
+                       jnp.clip(entry_, 0, jnp.maximum((bh[i] + ps - 1) // ps - 1, 0))],
+                    h_, 0, 0)
+
+        return pl.BlockSpec((1, 1, ps, width), index)
 
     def _fresh_spec(width):
         # fresh phase: packed kv block j - npb (clamped to 0 during the
@@ -1709,13 +1896,13 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         # target, so it must always point at a real block)
         return pl.BlockSpec(
             (1, 1, bt, width),
-            lambda i, h_, j, bs, bh, tb: (h_, jnp.clip(j - npb, 0, ntb - 1), 0, 0),
+            lambda i, h_, j, *_: (h_, jnp.clip(j - npb, 0, ntb - 1), 0, 0),
         )
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda i, h_, j, bs, bh, tb: (h_, i, 0, 0)),
+        pl.BlockSpec((1, 1, g, d), lambda i, h_, j, *_: (h_, i, 0, 0)),
         _page_spec(pd),
-        _page_spec(pd),
+        _page_spec(pdv),
     ]
     operands = [q_r, k_pages, v_pages]
     if quant_bits:
@@ -1723,29 +1910,34 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         operands += [k_scale, v_scale]
     in_specs += [
         _fresh_spec(d),
-        _fresh_spec(d),
-        pl.BlockSpec((1, g, 1), lambda i, h_, j, bs, bh, tb: (i, 0, 0)),
+        _fresh_spec(dv),
+        pl.BlockSpec((1, g, 1), lambda i, h_, j, *_: (i, 0, 0)),
         pl.BlockSpec((1, 1, bt),
-                     lambda i, h_, j, bs, bh, tb: (jnp.clip(j - npb, 0, ntb - 1), 0, 0)),
+                     lambda i, h_, j, *_: (jnp.clip(j - npb, 0, ntb - 1), 0, 0)),
     ]
     operands += [kn_r, vn_r, pos_rows, pos_in]
+    if sink is not None:
+        # row r of a folded q block is group member r % group of its kv head
+        rows = jnp.tile(sink.astype(jnp.float32).reshape(kvh, 1, group), (1, bt, 1))
+        operands.append(rows.reshape(kvh, g, 1))
+        in_specs.append(pl.BlockSpec((1, g, 1), lambda i, h_, j, *_: (h_, 0, 0)))
 
     out_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda i, h_, j, bs, bh, tb: (h_, i, 0, 0)),
+        pl.BlockSpec((1, 1, g, dv), lambda i, h_, j, *_: (h_, i, 0, 0)),
     ]
-    out_shape = [jax.ShapeDtypeStruct((kvh, ntb, g, d), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((kvh, ntb, g, dv), q.dtype)]
     if quant_bits:
         for width, dt in ((pd, jnp.int8), (1, jnp.float32),
-                          (pd, jnp.int8), (1, jnp.float32)):
+                          (pdv, jnp.int8), (1, jnp.float32)):
             out_specs.append(_fresh_spec(width))
             out_shape.append(jax.ShapeDtypeStruct((kvh, ntb, bt, width), dt))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(ntb, kvh, npb + ntb),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[_vmem((g, d)), _vmem((g, 128)), _vmem((g, 128))],
+        scratch_shapes=[_vmem((g, dv)), _vmem((g, 128)), _vmem((g, 128))],
     )
     outs = pl.pallas_call(
         kernel,
@@ -1755,14 +1947,14 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         # token blocks revisit the quantize-on-write output windows, so
         # the grid's outer dim must stay sequential ("arbitrary")
         **_grid_params(interpret, ("arbitrary", "parallel", "arbitrary")),
-    )(blk_slot, blk_hist, page_table.astype(jnp.int32), *operands)
+    )(*prefetch, *operands)
     o = outs[0]  # out_shape is a list, so pallas returns a list
-    out = (o.reshape(kvh, ntb, bt, group, d)
-           .transpose(0, 3, 1, 2, 4).reshape(1, h, cap, d))
+    out = (o.reshape(kvh, ntb, bt, group, dv)
+           .transpose(0, 3, 1, 2, 4).reshape(1, h, cap, dv))
     if quant_bits:
         k_pay = jnp.swapaxes(outs[1].reshape(kvh, cap, pd), 0, 1)
         k_scl = jnp.swapaxes(outs[2].reshape(kvh, cap, 1), 0, 1)
-        v_pay = jnp.swapaxes(outs[3].reshape(kvh, cap, pd), 0, 1)
+        v_pay = jnp.swapaxes(outs[3].reshape(kvh, cap, pdv), 0, 1)
         v_scl = jnp.swapaxes(outs[4].reshape(kvh, cap, 1), 0, 1)
     else:
         k_pay = jnp.swapaxes(k_new[0], 0, 1)
@@ -1773,7 +1965,8 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
 
 def _ragged_prefill_reference(q, k_new, v_new, k_pages, v_pages, page_table,
                               row_slot, row_pos, slot_hist, scale,
-                              k_scale=None, v_scale=None, quant_bits=0):
+                              k_scale=None, v_scale=None, quant_bits=0,
+                              window=None, sink=None, value_scale=1.0):
     """Chunked-dense-oracle math for a packed ragged dispatch: per-row
     gathered arena context + packed fresh kv, masked exactly as the
     kernel masks, through the reference op sequence (``quantize_kv`` /
@@ -1814,6 +2007,8 @@ def _ragged_prefill_reference(q, k_new, v_new, k_pages, v_pages, page_table,
     hist_r = jnp.where(row_slot >= 0, slot_hist[sl], 0)
     valid_ctx = ((lpos[None, :] < hist_r[:, None])
                  & (lpos[None, :] <= row_pos[:, None]))
+    if window is not None:
+        valid_ctx = valid_ctx & (row_pos[:, None] - lpos[None, :] < window)
     s_ctx = jnp.where(valid_ctx[None, None], s_ctx, NEG_INF)
     kf = jnp.swapaxes(k_fresh, 0, 1)  # [KVH, CAP, D]
     vf = jnp.swapaxes(v_fresh, 0, 1)
@@ -1824,16 +2019,24 @@ def _ragged_prefill_reference(q, k_new, v_new, k_pages, v_pages, page_table,
                  & (row_slot[:, None] >= 0)
                  & (row_pos[None, :] <= row_pos[:, None])
                  & (row_pos[None, :] >= 0))
+    if window is not None:
+        valid_new = valid_new & (row_pos[:, None] - row_pos[None, :] < window)
     s_new = jnp.where(valid_new[None, None], s_new, NEG_INF)
     s = jnp.concatenate([s_ctx, s_new], axis=-1)
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(kvh, group, 1, 1), s.shape[:-1] + (1,))
+        s = jnp.concatenate([s, col], axis=-1)
     p = jax.nn.softmax(s, axis=-1)
     out = (jnp.einsum("kgrl,rkld->kgrd", p[..., :length].astype(v_row.dtype), v_row)
-           + jnp.einsum("kgrc,kcd->kgrd", p[..., length:].astype(vf.dtype), vf))
+           + jnp.einsum("kgrc,kcd->kgrd", p[..., length:length + cap].astype(vf.dtype), vf))
+    if value_scale != 1.0:
+        out = out * value_scale
     # pad rows are fully masked: softmax degenerates to uniform — force
     # the kernel's exact 0 output (safe_l semantics) instead
     row_ok = (row_slot >= 0) & (row_pos >= 0)
     out = jnp.where(row_ok[None, None, :, None], out, 0.0)
-    out = out.reshape(h, cap, d)[None].astype(q.dtype)
+    out = out.reshape(h, cap, v_new.shape[-1])[None].astype(q.dtype)
     return out, k_pay, k_scl, v_pay, v_scl
 
 
@@ -1854,6 +2057,9 @@ def ragged_prefill_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     kv_quant_bits: int = 0,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    value_scale: float = 1.0,
 ):
     """Packed ragged prefill attention over the paged KV arena, with
     quantize-on-write fused.
@@ -1877,7 +2083,16 @@ def ragged_prefill_attention(
     :func:`resolve_prefill_kernel` (``impl`` / ``ATT_PREFILL_KERNEL``,
     default "ragged" with a warn-once dense fallback off-TPU,
     "interpret" for CPU tests); the chunked-dense reference stays the
-    bit-exactness oracle."""
+    bit-exactness oracle.
+
+    The values (``v_new``, ``v_pages``) may be narrower than the keys; the
+    output has their width. ``window``: a row at position p sees positions
+    ``p - window < c <= p`` of its slot, in the arena and among the packed
+    rows; the kernel's arena walk then starts at the first page the token
+    block's earliest row sees and is ``window_span_pages`` long instead
+    of the table's length, so the pages behind the window are neither
+    read nor stepped over. ``sink`` [H] and ``value_scale`` are
+    :func:`mha_reference`'s."""
     mode = resolve_prefill_kernel(impl)
     b, h, cap, d = q.shape
     if b != 1:
@@ -1903,11 +2118,13 @@ def ragged_prefill_attention(
                 q, k_new, v_new, k_pages, v_pages, page_table, row_slot,
                 row_pos, slot_hist, scale, bt, interpret,
                 k_scale=k_scale, v_scale=v_scale, quant_bits=kv_quant_bits,
+                window=window, sink=sink, value_scale=value_scale,
             )
     return _ragged_prefill_reference(
         q, k_new, v_new, k_pages, v_pages, page_table, row_slot, row_pos,
         slot_hist, scale, k_scale=k_scale, v_scale=v_scale,
-        quant_bits=kv_quant_bits,
+        quant_bits=kv_quant_bits, window=window, sink=sink,
+        value_scale=value_scale,
     )
 
 

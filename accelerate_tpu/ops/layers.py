@@ -45,7 +45,13 @@ def apply_rotary_embedding(
     x: jax.Array, sin: jax.Array, cos: jax.Array
 ) -> jax.Array:
     """Rotate pairs (split-half convention). x: [B, H, S, D]; sin/cos
-    [S, D/2] or [B, S, D/2] (broadcast over heads)."""
+    [S, R/2] or [B, S, R/2] (broadcast over heads). Tables narrower than
+    the head (R < D) rotate its first R dimensions, ``rotate_half`` over
+    those, and pass the rest through."""
+    rot = 2 * sin.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rotary_embedding(x[..., :rot], sin, cos), x[..., rot:]], axis=-1)
     dtype = x.dtype
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)

@@ -22,17 +22,22 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# K/V cache leaves are [B, KVH, L, D] (+ an optional leading layer axis
-# from nn.scan); anything of lower rank is a cache_index bookkeeping leaf
-_KV_NDIM = 4
+from .pages import SLOT_LEAF_NAMES, leaf_name
 
-
-def _is_kv(leaf) -> bool:
-    return getattr(leaf, "ndim", 0) >= _KV_NDIM
+# A slot leaf is found by its name (pages.SLOT_LEAF_NAMES: keys, values,
+# their scales, a seq2seq decoder's cross keys and values), never by its
+# rank: [B, KVH, L, D] with an optional leading layer axis from nn.scan.
+# Everything else is a cache_index bookkeeping leaf.
 
 
 def _slot_axis(leaf) -> int:
-    return leaf.ndim - _KV_NDIM
+    return leaf.ndim - 4
+
+
+def _map_slots(fn, arena, *rest, other=lambda leaf, *_: leaf):
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf, *r: fn(leaf, *r) if leaf_name(path) in SLOT_LEAF_NAMES
+        else other(leaf, *r), arena, *rest)
 
 
 def init_arena(definition, params, num_slots: int, placer):
@@ -58,8 +63,9 @@ def init_arena(definition, params, num_slots: int, placer):
 
 
 def arena_num_slots(arena) -> int:
-    for leaf in jax.tree_util.tree_leaves(arena):
-        if _is_kv(leaf):
+    flat, _ = jax.tree_util.tree_flatten_with_path(arena)
+    for path, leaf in flat:
+        if leaf_name(path) in SLOT_LEAF_NAMES:
             return int(leaf.shape[_slot_axis(leaf)])
     raise ValueError("arena holds no K/V leaves")
 
@@ -74,25 +80,16 @@ def slot_view(arena, slot, start):
     path (the one chunked prefill rides) continues this slot exactly where
     its previous chunk stopped. Traced-friendly: ``slot``/``start`` may be
     tracers, keeping the caller's jit free of per-slot recompiles."""
-
-    def take(leaf):
-        if _is_kv(leaf):
-            return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=_slot_axis(leaf))
-        return jnp.full(leaf.shape, start, leaf.dtype)
-
-    return jax.tree_util.tree_map(take, arena)
+    return _map_slots(
+        lambda leaf: jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=_slot_axis(leaf)),
+        arena, other=lambda leaf: jnp.full(leaf.shape, start, leaf.dtype))
 
 
 def write_slot(arena, slot_tree, slot):
     """Write a batch-1 slot tree's K/V back into the arena. Index leaves
     keep the arena's value — per-slot progress lives in the engine's
     ``lengths`` vector, not in the collection."""
-
-    def put(a, s):
-        if _is_kv(a):
-            return jax.lax.dynamic_update_slice_in_dim(
-                a, s.astype(a.dtype), slot, axis=_slot_axis(a)
-            )
-        return a
-
-    return jax.tree_util.tree_map(put, arena, slot_tree)
+    return _map_slots(
+        lambda a, s: jax.lax.dynamic_update_slice_in_dim(
+            a, s.astype(a.dtype), slot, axis=_slot_axis(a)),
+        arena, slot_tree)

@@ -45,6 +45,7 @@ import numpy as np
 from ..generation import _sample, _sized_definition, depipeline
 from ..telemetry.spans import emit as _emit_span
 from ..telemetry.spans import span as _span
+from ..models.decoder import MOE_LOAD
 from ..ops.attention import (
     _PREFILL_TOKEN_BLOCK,
     decode_kernel_active,
@@ -55,8 +56,7 @@ from ..ops.attention import (
 from .arena import arena_nbytes, init_arena, slot_view, write_slot
 from .pages import (
     NGramDrafter,
-    PageAllocator,
-    PagedTables,
+    CacheKind,
     PrefixCache,
     dense_slot_view,
     fork_page,
@@ -64,6 +64,7 @@ from .pages import (
     init_paged_arena,
     install_page,
     kv_cache_bits,
+    kv_token_bytes,
     scatter_slot_view,
     set_table_entry,
     set_table_row,
@@ -237,6 +238,7 @@ class ServingEngine:
         kv_cache_dtype: Optional[str] = None,
         replica: Optional[str] = None,
         kv_tiers=None,
+        kind_pages: Optional[dict] = None,
     ):
         from ..utils.compile_cache import (
             compile_event_counters,
@@ -303,6 +305,39 @@ class ServingEngine:
                 "speculative decoding (spec_draft_len > 0) requires the "
                 "paged arena; pass page_size=..."
             )
+        # a model that states layer kinds, a window, a sink or experts runs
+        # on the paged arena's normal path only; what cannot yet be right
+        # for it refuses here, by the feature's name, and never runs
+        mcfg = definition.config
+        self._by_kind = bool(
+            getattr(mcfg, "layer_kinds", ()) or getattr(mcfg, "attn_window", None)
+            or getattr(mcfg, "attn_sink", False) or getattr(mcfg, "moe_num_experts", 0) > 1)
+        if self._by_kind:
+            refused = {
+                "the flat slot arena (pass page_size)": not self.page_size,
+                "prefix_cache (page sharing across a window kind)": bool(prefix_cache),
+                "kv_tiers": kv_tiers is not None,
+                "preemption by page-out and restore (scheduler.config.preemption)": (
+                    scheduler is not None
+                    and getattr(getattr(scheduler, "config", scheduler), "preemption", False)),
+                "speculative verify (spec_draft_len)": bool(self.spec_k),
+                "fused decode bursts (steps_per_call)": self.steps_per_call > 1,
+                "quantized pages (kv_cache_dtype)": kvq != "bf16",
+            }
+            for feature, asked in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"ServingEngine: {feature} is not supported for a model with "
+                        "layer kinds, a window, a sink or experts; it is refused "
+                        "rather than run and be wrong (ROADMAP.md, Reach)")
+        elif kind_pages:
+            raise ValueError("kind_pages names pools of cache kinds; this model has one kind")
+        # the programs of a model with experts return, with the tokens, the
+        # pairs on each held expert of each expert layer
+        moe_runs = [c for c in mcfg.run_configs() if c.moe_num_experts > 1] \
+            if hasattr(mcfg, "run_configs") else []
+        self._expert_layers = sum(c.num_layers for c in moe_runs)
+        self._pairs_per_token = sum(c.num_layers * c.moe_top_k for c in moe_runs)
         if self.page_size:
             if self.max_cache_len % self.page_size:
                 raise ValueError(
@@ -325,15 +360,14 @@ class ServingEngine:
                 getattr(definition.config, "prefill_kernel_block", None)
                 or prefill_token_block(self.prefill_chunks)
             )
-            self._paged_def = definition.clone(config=dataclasses.replace(
-                definition.config,
-                kv_page_size=self.page_size, kv_num_pages=self.num_pages,
-                prefill_kernel_block=self._ragged_bt,
-            ))
-            self._allocator = PageAllocator(self.num_pages, reserved=1)
-            self._tables_host = PagedTables(
-                self.num_slots, self.pages_per_slot, parking=0
-            )
+            self._paged_def = definition.clone(config=self._paged_config(
+                definition.config, kind_pages or {}))
+            # which state each layer kind keeps and how it is paged
+            # (pages.CacheKind): a pool, a table a slot, the window's rule.
+            # One kind: the allocator, tables and counts of before.
+            self._kinds = self._cache_kinds(self._paged_def.config)
+            self._allocator = self._kinds[0].allocator
+            self._tables_host = self._kinds[0].tables
             # hierarchical KV tiering (serving/tiers.py): demote-on-evict
             # host/disk/peer store under the prefix cache. A TierConfig
             # builds the store here (wired to the usage byte-seconds hook
@@ -374,19 +408,24 @@ class ServingEngine:
             self._arena = init_paged_arena(
                 self._paged_def, params, self.num_slots, self.pages_per_slot,
                 self._placer,
+                kinds=[k.name for k in self._kinds] if len(self._kinds) > 1 else None,
             )
             # whether the decode step rides the paged pallas kernel (the
-            # serving/decode_kernel_active gauge)
+            # serving/decode_kernel_active gauge): in every layer kind
             pcfg = self._paged_def.config
-            self._kernel_costed = decode_kernel_active(pcfg)
-            self._walk_block_pages = paged_decode_block_pages(pcfg, self.pages_per_slot)
+            run_cfgs = pcfg.run_configs()
+            self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
+            self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
             # packed ragged prefill (ops/attention.ragged_prefill_attention):
             # when the flash prefill kernel (or its interpreter) engages,
             # the admission planner packs every pending tail into ONE
             # ragged dispatch per scheduler iteration — token-block
             # padding only — instead of per-slot bucketed chunks. The
-            # chunked path stays compiled as the fallback/oracle.
-            self._ragged_prefill = prefill_kernel_active(pcfg)
+            # chunked path stays compiled as the fallback/oracle. A model
+            # by kind always packs: where the kernel does not engage, the
+            # packed dispatch runs its dense reference.
+            self._prefill_kernel_costed = all(prefill_kernel_active(c) for c in run_cfgs)
+            self._ragged_prefill = self._prefill_kernel_costed or self._by_kind
             rb = self._ragged_bt
             # fixed grid capacities compiled at warmup (the zero-recompile
             # invariant): each chunk bucket rounded up to the token block,
@@ -395,9 +434,10 @@ class ServingEngine:
             self._ragged_caps = tuple(sorted(
                 {-(-int(c) // rb) * rb for c in self.prefill_chunks}
             ))
-            self._page_tables = jnp.zeros(
-                (self.num_slots, self.pages_per_slot), jnp.int32
-            )
+            for kind in self._kinds:
+                kind.device_tables = jnp.zeros(
+                    (self.num_slots, self.pages_per_slot), jnp.int32
+                )
             table_donate = (0,) if self._donate else ()
             self._set_row = jax.jit(set_table_row, donate_argnums=table_donate)
             self._set_entry = jax.jit(set_table_entry, donate_argnums=table_donate)
@@ -420,6 +460,7 @@ class ServingEngine:
             )
         else:
             self._paged_def = None
+            self._kinds = []
             self._prefix = None
             self._tiers = None
             self._drafter = None
@@ -511,6 +552,7 @@ class ServingEngine:
         # metrics
         self.iterations = 0  # scheduler iterations (calls of step() that had work)
         self.pages_allocated = 0  # pages handed out by _alloc_page, lifetime
+        self.pages_released = 0   # pages given back behind a window, lifetime
         self.step_count = 0
         self.requests_completed = 0
         self.requests_shed = 0
@@ -535,6 +577,74 @@ class ServingEngine:
         if telemetry is not None:
             telemetry.attach_serving(self)
 
+    # -- the cache by layer kind -------------------------------------------
+
+    @property
+    def _page_tables(self):
+        """The first kind's device page tables (the only kind's, for a
+        model of one kind)."""
+        return self._kinds[0].device_tables
+
+    @_page_tables.setter
+    def _page_tables(self, value):
+        self._kinds[0].device_tables = value
+
+    def _tables_arg(self):
+        """What the programs take as ``page_table``: the one table, or a
+        table a cache kind by its name."""
+        if len(self._kinds) == 1:
+            return self._kinds[0].device_tables
+        return {k.name: k.device_tables for k in self._kinds}
+
+    def _paged_config(self, cfg, kind_pages: dict):
+        """The model's config with the page geometry: ``num_pages`` is the
+        pool of the full kind (or the only kind), ``kind_pages`` gives the
+        other kinds' pools by cache-kind name (a window kind's default is
+        its slots' windows plus one prefill pack each, and spare)."""
+        paged = dict(kv_page_size=self.page_size, kv_num_pages=self.num_pages,
+                     prefill_kernel_block=self._ragged_bt)
+        kinds = []
+        names = set()
+        for name, over in cfg.layer_kinds:
+            kcfg = dataclasses.replace(cfg, **over, layer_kinds=(), layer_pattern=())
+            names.add(kcfg.cache_kind)
+            if kcfg.attn_window is not None:
+                span = -(-(kcfg.attn_window + self.prefill_chunks[-1]) // self.page_size) + 1
+                over = dict(over, kv_num_pages=int(kind_pages.get(
+                    kcfg.cache_kind, 1 + self.num_slots * span)))
+            kinds.append((name, over))
+        unknown = set(kind_pages) - names
+        if unknown:
+            raise ValueError(
+                f"kind_pages names {sorted(unknown)}; the model's cache kinds are {sorted(names)}")
+        if not cfg.layer_kinds and cfg.attn_window is not None and kind_pages:
+            paged["kv_num_pages"] = int(kind_pages.get(cfg.cache_kind, self.num_pages))
+        return dataclasses.replace(cfg, **paged, layer_kinds=tuple(kinds))
+
+    def _cache_kinds(self, pcfg) -> list:
+        """One :class:`~.pages.CacheKind` a distinct kind of state among the
+        model's runs of layers, in the order the layers first state it."""
+        from ..ops.attention import paged_key_lanes
+
+        kinds = {}
+        itemsize = jnp.dtype(pcfg.dtype).itemsize
+        for c in pcfg.run_configs():
+            if self.kv_cache_dtype == "bf16":
+                token_bytes = c.num_kv_heads * (paged_key_lanes(c.head_dim) + c.value_dim) * itemsize
+            else:
+                token_bytes = kv_token_bytes(c.num_kv_heads, c.head_dim, self.kv_cache_dtype)
+            kind = kinds.get(c.cache_kind)
+            if kind is None:
+                kinds[c.cache_kind] = CacheKind(
+                    c.cache_kind, c.attn_window, c.kv_num_pages, self.num_slots,
+                    self.pages_per_slot, self.page_size, c.num_layers, token_bytes)
+            else:
+                if (kind.num_pages, kind.token_bytes) != (c.kv_num_pages, token_bytes):
+                    raise ValueError(
+                        f"layers of cache kind {c.cache_kind!r} disagree on their pages")
+                kind.layers += c.num_layers
+        return list(kinds.values())
+
     # -- compiled programs -------------------------------------------------
 
     def _build_step_core(self):
@@ -544,6 +654,7 @@ class ServingEngine:
         definition = self._paged_def if paged else self.definition
 
         last_pos = self.max_cache_len - 1
+        mutable = ["cache"] + ([MOE_LOAD] if self._expert_layers else [])
 
         def step(params, arena, tokens, lengths, active, rngs, page_tables=None):
             """One batched decode step -> (arena, tokens, lengths, rngs).
@@ -569,7 +680,7 @@ class ServingEngine:
                 use_cache=True,
                 decode=True,
                 cache_positions=write_pos,
-                mutable=["cache"],
+                mutable=mutable,
                 **kwargs,
             )
             logits = out["logits"][:, -1]  # [N, V]
@@ -586,7 +697,7 @@ class ServingEngine:
             nxt = jnp.where(active, nxt, tokens)
             new_rngs = jnp.where(active[:, None], split[:, 0], rngs)
             new_lengths = jnp.where(active, lengths + 1, lengths)
-            return mutated["cache"], nxt, new_lengths, new_rngs
+            return (mutated["cache"], nxt, new_lengths, new_rngs) + _expert_load(mutated)
 
         return step
 
@@ -732,6 +843,7 @@ class ServingEngine:
             return fn
         definition, placer = self._paged_def, self._placer
         temperature, top_k = self.temperature, self.top_k
+        mutable = ["cache"] + ([MOE_LOAD] if self._expert_layers else [])
 
         def ragged_prefill(params, arena, ids, row_slot, row_pos, slot_hist,
                            page_tables, last_rows, rngs):
@@ -755,13 +867,13 @@ class ServingEngine:
                 page_table=page_tables,
                 ragged_slots=row_slot,
                 slot_hist=slot_hist,
-                mutable=["cache"],
+                mutable=mutable,
             )
             rows = jnp.take(out["logits"][0], last_rows, axis=0)  # [S, V]
             firsts = jax.vmap(
                 lambda key, row: _sample(row[None], key, temperature, top_k)[0]
             )(rngs, rows)
-            return mutated["cache"], firsts
+            return (mutated["cache"], firsts) + _expert_load(mutated)
 
         fn = jax.jit(ragged_prefill,
                      donate_argnums=(1,) if self._donate else ())
@@ -813,7 +925,8 @@ class ServingEngine:
         costs = getattr(self.telemetry, "costs", None)
         paged = self.page_size is not None
         pk = {"page_tables": self._page_tables} if paged else {}
-        for bucket in self.prefill_chunks:
+        # (a model by kind admits through the packed dispatch only)
+        for bucket in () if self._by_kind else self.prefill_chunks:
             warm_chunk = jnp.zeros((1, bucket), jnp.int32)
             self._note_forensics(f"prefill_{bucket}", {"chunk_ids": warm_chunk})
             self._arena, _ = self._prefill_fn(bucket)(
@@ -839,17 +952,18 @@ class ServingEngine:
                 self._page_tables, 0, jnp.asarray(self._tables_host.rows[0])
             )
             self._page_tables = self._set_entry(self._page_tables, 0, 0, 0)
-            self._arena = self._fork(self._arena, 0, 0)
-            # the KV-handoff install program: write a zeros page into the
-            # parking page (whose content is unreachable by construction),
-            # so a post-steady import of handed-off pages never compiles
-            self._arena = self._install_page(
-                self._arena, self._page_slice_tree(), 0
-            )
-            # ... and its mirror, the demote-on-evict page gather (reads
-            # the parking page; nothing observable), so a post-steady
-            # eviction can demote into the host tier with zero recompiles
-            jax.device_get(self._gather_page(self._arena, 0))
+            if not self._by_kind:  # nothing forks, imports or demotes a page there
+                self._arena = self._fork(self._arena, 0, 0)
+                # the KV-handoff install program: write a zeros page into the
+                # parking page (whose content is unreachable by construction),
+                # so a post-steady import of handed-off pages never compiles
+                self._arena = self._install_page(
+                    self._arena, self._page_slice_tree(), 0
+                )
+                # ... and its mirror, the demote-on-evict page gather (reads
+                # the parking page; nothing observable), so a post-steady
+                # eviction can demote into the host tier with zero recompiles
+                jax.device_get(self._gather_page(self._arena, 0))
             if self._ragged_prefill:
                 # the packed ragged-prefill programs, one per fixed grid
                 # capacity. All-pad warm args are safe: both kernel kv
@@ -865,9 +979,9 @@ class ServingEngine:
                     self._note_forensics(
                         f"ragged_prefill_{rcap}", {"ids": warm_ids}
                     )
-                    self._arena, _ = self._ragged_prefill_fn(rcap)(
+                    self._arena, *_ = self._ragged_prefill_fn(rcap)(
                         self.params, self._arena, warm_ids, warm_neg,
-                        warm_neg, warm_hist, self._page_tables, warm_last,
+                        warm_neg, warm_hist, self._tables_arg(), warm_last,
                         warm_rngs,
                     )
                     if costs is not None:
@@ -877,7 +991,7 @@ class ServingEngine:
                                 self._ragged_prefill_fn(rcap).lower(
                                     self.params, self._arena, warm_ids,
                                     warm_neg, warm_neg, warm_hist,
-                                    self._page_tables, warm_last,
+                                    self._tables_arg(), warm_last,
                                     warm_rngs,
                                 ))
                         except Exception:
@@ -890,8 +1004,8 @@ class ServingEngine:
             {"tokens": self._tokens, "lengths": self._lengths,
              "active": self._active, "rngs": self._rngs},
         )
-        step_extra = (self._page_tables,) if paged else ()
-        self._arena, self._tokens, self._lengths, self._rngs = self._decode_step(
+        step_extra = (self._tables_arg(),) if paged else ()
+        self._arena, self._tokens, self._lengths, self._rngs, *_ = self._decode_step(
             self.params, self._arena, self._tokens, self._lengths, self._active,
             self._rngs, *step_extra,
         )
@@ -1199,6 +1313,14 @@ class ServingEngine:
             if self.page_size:
                 args["pages_in_use"] = self._allocator.in_use
                 args["pages_free"] = self._allocator.free_count
+                for kind in self._kinds[1:]:
+                    args[f"pages_in_use.{kind.name}"] = kind.allocator.in_use
+                if self._by_kind:
+                    # what the cache holds against what it holds it for
+                    args["kv_bytes_in_use"] = sum(
+                        k.allocator.in_use * k.page_bytes for k in self._kinds)
+                    args["live_tokens"] = sum(
+                        r.prompt.size + len(r.tokens) for r in self._slot_req.values())
             args["emitted"] = self.generated_tokens - emitted0
         return progressed
 
@@ -1644,18 +1766,21 @@ class ServingEngine:
 
     # -- paged-arena bookkeeping -------------------------------------------
 
-    def _alloc_page(self) -> int:
-        """One fresh page, evicting LRU prefix-cache entries under
-        pressure. Exhaustion with nothing left to evict raises
-        :class:`PagePressure`, which the admission/decode paths translate
-        into a scheduling decision (preempt a victim, shed the request)
-        — never an exception out of ``step()``."""
-        page = self._allocator.alloc()
-        while page is None and self._prefix is not None and self._prefix.evict_lru():
-            page = self._allocator.alloc()
+    def _alloc_page(self, kind=None) -> int:
+        """One fresh page of ``kind``'s pool (the first kind's by default),
+        evicting LRU prefix-cache entries under pressure. Exhaustion with
+        nothing left to evict raises :class:`PagePressure`, which the
+        admission/decode paths translate into a scheduling decision
+        (preempt a victim, shed the request) — never an exception out of
+        ``step()``."""
+        kind = kind or self._kinds[0]
+        page = kind.allocator.alloc()
+        while (page is None and kind is self._kinds[0]
+               and self._prefix is not None and self._prefix.evict_lru()):
+            page = kind.allocator.alloc()
         if page is None:
             raise PagePressure(
-                f"paged KV arena exhausted ({self.num_pages} pages, "
+                f"paged KV arena exhausted ({kind.num_pages} {kind.name} pages, "
                 f"{len(self._slot_req)} live slots): raise num_pages or "
                 "lower num_slots/max_new_tokens for this overcommit ratio"
             )
@@ -1672,17 +1797,36 @@ class ServingEngine:
         ps = self.page_size
         usage = self._usage()
         p_hi = hi_pos // ps
-        while th.alloc_count[slot] <= p_hi:
-            idx = th.alloc_count[slot]
-            page = self._alloc_page()
-            th.rows[slot][idx] = page
-            th.alloc_count[slot] = idx + 1
-            self._page_tables = self._set_entry(self._page_tables, slot, idx, page)
-            req.pages_allocated += 1
-            if usage is not None:
-                # growth: one more page held; a CoW fork below is held-
-                # count-neutral (fresh page replaces the shared claim)
-                usage.note_pages(req.tenant, 1)
+        for kind in self._kinds:
+            kt = kind.tables
+            if kt.alloc_count[slot] <= p_hi and kt.alloc_count[slot] == kt.released[slot]:
+                # nothing live in this slot's table (a fresh slot of a window
+                # kind whose write starts past its first pages): skip what
+                # lies behind the window of the first position written
+                kt.alloc_count[slot] = kt.released[slot] = max(
+                    kt.alloc_count[slot], kind.first_live_entry(lo_pos))
+            grown = []
+            try:
+                while kt.alloc_count[slot] <= p_hi:
+                    idx = kt.alloc_count[slot]
+                    page = self._alloc_page(kind)
+                    kt.rows[slot][idx] = page
+                    kt.alloc_count[slot] = idx + 1
+                    grown.append((idx, page))
+                    req.pages_allocated += 1
+                    if usage is not None:
+                        # growth: one more page held; a CoW fork below is held-
+                        # count-neutral (fresh page replaces the shared claim)
+                        usage.note_pages(req.tenant, 1)
+            finally:
+                # one table program a kind: the entry for a decode step's one
+                # page, the whole row for a prefill pack's many (its stale
+                # entries behind a window become parking entries on the way)
+                if len(grown) == 1:
+                    kind.device_tables = self._set_entry(kind.device_tables, slot, *grown[0])
+                elif grown:
+                    kind.device_tables = self._set_row(
+                        kind.device_tables, slot, jnp.asarray(kt.rows[slot]))
         for idx in range(lo_pos // ps, p_hi + 1):
             page = int(th.rows[slot][idx])
             if not self._allocator.shared(page):
@@ -1778,18 +1922,35 @@ class ServingEngine:
         by the prefix cache or another slot survive) and point its device
         table row back at the parking page, so a later all-inactive fused
         step can never write into a page that was reallocated."""
-        th = self._tables_host
-        pages = th.slot_pages(slot)
-        for page in pages:
-            self._allocator.release(page)
-        if tenant is not None and pages:
+        held = 0
+        for kind in self._kinds:
+            th = kind.tables
+            pages = th.slot_pages(slot)
+            for page in pages:
+                kind.allocator.release(page)
+            held += len(pages)
+            th.reset_slot(slot)
+            kind.device_tables = self._set_row(
+                kind.device_tables, slot, jnp.asarray(th.rows[slot])
+            )
+        if tenant is not None and held:
             usage = self._usage()
             if usage is not None:
-                usage.note_pages(tenant, -len(pages))
-        th.reset_slot(slot)
-        self._page_tables = self._set_row(
-            self._page_tables, slot, jnp.asarray(th.rows[slot])
-        )
+                usage.note_pages(tenant, -held)
+
+    def _release_behind_window(self, req: Request, slot: int, next_pos: int) -> int:
+        """Give back the pages of every window kind that lie wholly behind
+        the window of the slot's next query at ``next_pos`` (after a prefill
+        dispatch, and each round in ``serving/decode_grow``). Returns the
+        pages released."""
+        n = sum(kind.release_behind(slot, next_pos)
+                for kind in self._kinds if kind.window is not None)
+        if n:
+            self.pages_released += n
+            usage = self._usage()
+            if usage is not None:
+                usage.note_pages(req.tenant, -n)
+        return n
 
     # -- hierarchical KV tiering (HBM -> host -> disk -> peers) -------------
 
@@ -1966,13 +2127,13 @@ class ServingEngine:
         flatten order), the slice is that payload's ``page_index``-th
         page; without, zeros (the warmup compile). Non-K/V leaves become
         fresh zeros so nothing aliases the donated arena."""
-        from .pages import _is_kv, _page_axis
+        from .pages import _page_axis, is_paged_leaf
 
-        flat, treedef = jax.tree_util.tree_flatten(self._arena)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(self._arena)
         it = iter(arrays) if arrays is not None else None
         leaves = []
-        for leaf in flat:
-            if _is_kv(leaf):
+        for path, leaf in flat:
+            if is_paged_leaf(path):
                 axis = _page_axis(leaf)
                 if it is None:
                     shape = list(leaf.shape)
@@ -1989,14 +2150,20 @@ class ServingEngine:
     def _kv_leaf_specs(self) -> list:
         """(path, leaf) for every K/V leaf, arena flatten order — the
         handoff wire format's leaf identity (payloads AND scale arenas:
-        same rank by design, so they always travel together)."""
-        from .pages import _is_kv
+        found by name, so they always travel together)."""
+        from .pages import is_paged_leaf
 
         flat, _ = jax.tree_util.tree_flatten_with_path(self._arena)
         return [
             (jax.tree_util.keystr(path), leaf)
-            for path, leaf in flat if _is_kv(leaf)
+            for path, leaf in flat if is_paged_leaf(path)
         ]
+
+    def _refuse_handoff(self):
+        if self._by_kind:
+            raise NotImplementedError(
+                "ServingEngine: KV handoff (export_prefix_kv / import_prefix_kv) is not "
+                "supported for a model with layer kinds, a window, a sink or experts")
 
     def export_prefix_kv(self, tokens) -> Optional[dict]:
         """Export the longest cached prefix of ``tokens`` as a KV handoff:
@@ -2007,6 +2174,7 @@ class ServingEngine:
         replica calls this for a finished prompt; a router calls it to
         migrate a session's KV off a draining replica. The probe uses
         ``PrefixCache.peek`` — exports never skew the hit gauges."""
+        self._refuse_handoff()
         if not self.page_size or self._prefix is None:
             raise ValueError(
                 "KV handoff needs the paged arena with the prefix cache "
@@ -2123,6 +2291,7 @@ class ServingEngine:
         install — a handoff is an optimization, never worth shedding live
         work for). Raises ValueError on an incompatible wire format
         (page size, KV dtype, or leaf layout mismatch)."""
+        self._refuse_handoff()
         if not self.page_size or self._prefix is None:
             raise ValueError(
                 "KV handoff needs the paged arena with the prefix cache "
@@ -2497,12 +2666,15 @@ class ServingEngine:
         pack that completed into its slot."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
                    requests=len(packs)) as sp:
-            self._arena, firsts = self._ragged_prefill_fn(rcap)(
+            self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
-                self._page_tables, last_rows, rngs,
+                self._tables_arg(), last_rows, rngs,
             )
         with _span("serving/prefill_fetch") as sp_f:
-            firsts_h = np.asarray(jax.device_get(firsts))
+            firsts_h, *load = jax.device_get((firsts, *load))  # one fetch
+            firsts_h = np.asarray(firsts_h)
+        if load:
+            _load_args(sp, np.asarray(load[0]), fresh * self._pairs_per_token)
         t0, wall = sp.t0, sp_f.t1 - sp.t0
         with _span("serving/prefill_commit") as sp_c:
             costs = (getattr(self.telemetry, "costs", None)
@@ -2517,6 +2689,8 @@ class ServingEngine:
             first_tokens = 0
             for preq, psl, s0, s1, prng, drng, pseq, primary in packs:
                 self._note_prefill_chunk(preq, psl, s0, s1 - s0, t0, wall, tr)
+                # a window kind's pages behind the next row's window go back
+                self._release_behind_window(preq, psl, s1)
                 if usage is not None:
                     usage.note_prefill(preq.tenant, s1 - s0)
                     # the shared dispatch wall is billed proportionally to
@@ -2581,22 +2755,23 @@ class ServingEngine:
         decode step writes the PREVIOUS token before sampling the next)."""
         return req.prompt.size + len(req.tokens) - 1
 
-    def _walked_tokens(self, pos: int) -> int:
-        """Tokens the paged decode kernel walks for a slot whose last write
-        of this round lands at ``pos``: whole pages up to that one."""
-        ps = self.page_size
-        return (pos // ps + 1) * ps
-
     def _note_walk(self, sp, walked: list) -> None:
         """What the decode kernel is handed this round, on the
         ``serving/decode_grow`` span: ``walked`` holds each grown slot's
-        page-rounded tokens. A block is ``_walk_block_pages`` table
-        entries; a slot that is free or mid-admission has no live tokens
-        and is skipped whole."""
+        last write position, and ``walked_tokens`` the page-rounded tokens
+        one layer of the first cache kind walks for them (a model of
+        several kinds adds ``walked_tokens.<kind>`` for the others: a
+        window kind walks its window's pages only). A block is
+        ``_walk_block_pages`` table entries; a slot that is free or
+        mid-admission has no live tokens and is skipped whole."""
         block = self._walk_block_pages * self.page_size
-        sp.args["walked_tokens"] = sum(walked)
-        sp.args["walked_blocks"] = sum(-(-w // block) for w in walked)
+        first, *others = self._kinds
+        tokens = [first.walked_tokens(p) for p in walked]
+        sp.args["walked_tokens"] = sum(tokens)
+        sp.args["walked_blocks"] = sum(-(-w // block) for w in tokens)
         sp.args["skipped_slots"] = self.num_slots - len(self._slot_req)
+        for kind in others:
+            sp.args[f"walked_tokens.{kind.name}"] = sum(kind.walked_tokens(p) for p in walked)
 
     def _spec_verify_once(self) -> bool:
         """One speculative round: host drafter proposes K tokens per slot,
@@ -2625,7 +2800,7 @@ class ServingEngine:
                 drafts[slot] = self._drafter.propose(ctx, k)
                 pos = self._next_write_pos(req)
                 if self._grow_or_resolve(req, slot, pos, pos + k):
-                    walked.append(self._walked_tokens(pos + k))
+                    walked.append(pos + k)
             sp.args["pages_allocated"] = self.pages_allocated - pages0
             self._note_walk(sp, walked)
             if not self._slot_req:
@@ -2706,19 +2881,23 @@ class ServingEngine:
         k = self._burst_len()
         if self.page_size:
             with _span("serving/decode_grow") as sp:
-                pages0 = self.pages_allocated
-                # page-rounded tokens the decode kernel walks this round,
-                # counted as each slot is grown (a slot preempted later in
-                # this same loop, for another's pages, stays counted)
+                pages0, released0 = self.pages_allocated, self.pages_released
+                # each grown slot's last write position of this round: the
+                # decode kernel walks its pages up to that one (a slot
+                # preempted later in this same loop, for another's pages,
+                # stays counted)
                 walked = []
                 for slot, req in list(self._slot_req.items()):
                     if slot not in self._slot_req:
                         continue  # shed/preempted while relieving another slot
                     pos = self._next_write_pos(req)
+                    self._release_behind_window(req, slot, pos)
                     if self._grow_or_resolve(req, slot, pos, pos + k - 1):
-                        walked.append(self._walked_tokens(pos + k - 1))
+                        walked.append(pos + k - 1)
                 sp.args["pages_allocated"] = self.pages_allocated - pages0
                 self._note_walk(sp, walked)
+                if self._by_kind:
+                    sp.args["pages_released"] = self.pages_released - released0
             if not self._slot_req:
                 return True  # every live slot was shed under page pressure
         if self._faults is not None:
@@ -2728,7 +2907,8 @@ class ServingEngine:
             {"tokens": self._tokens, "lengths": self._lengths,
              "active": self._active, "rngs": self._rngs},
         )
-        step_extra = (self._page_tables,) if self.page_size else ()
+        step_extra = (self._tables_arg(),) if self.page_size else ()
+        load = ()
         with _span("serving/decode_dispatch", slots=len(self._slot_req)) as sp_d:
             if k > 1:
                 self._arena, self._tokens, self._lengths, self._rngs, toks = (
@@ -2738,15 +2918,18 @@ class ServingEngine:
                     )
                 )
             else:
-                self._arena, self._tokens, self._lengths, self._rngs = self._decode_step(
+                self._arena, self._tokens, self._lengths, self._rngs, *load = self._decode_step(
                     self.params, self._arena, self._tokens, self._lengths, self._active,
                     self._rngs, *step_extra,
                 )
                 toks = self._tokens
         with _span("serving/token_fetch") as sp_f:
-            host = np.asarray(jax.device_get(toks))  # forces the step or burst
+            host, *load = jax.device_get((toks, *load))  # forces the step or burst; one fetch
+            host = np.asarray(host)
             if k == 1:
                 host = host[None]  # [1, N]
+        if load:
+            _load_args(sp_d, np.asarray(load[0]), len(self._slot_req) * self._pairs_per_token)
         t0, wall = sp_d.t0, sp_f.t1 - sp_d.t0
         with _span("serving/emit") as sp_e:
             done0 = self.requests_completed
@@ -2939,7 +3122,12 @@ class ServingEngine:
             out["serving/page_size"] = self.page_size
             out["serving/page_forks"] = self.page_forks
             out["serving/decode_kernel_active"] = bool(self._kernel_costed)
-            out["serving/prefill_kernel_active"] = bool(self._ragged_prefill)
+            out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
+            for kind in self._kinds[1:]:
+                out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use
+                out[f"serving/pages_total.{kind.name}"] = kind.num_pages
+            if self._by_kind:
+                out["serving/pages_released"] = self.pages_released
             out["serving/prefill_packed_tokens"] = int(
                 self.prefill_packed_tokens
             )
@@ -3038,6 +3226,28 @@ class ServingEngine:
             dispatched.definition, params,
             param_placer=dispatched.param_placer(), **kwargs,
         )
+
+
+def _expert_load(mutated) -> tuple:
+    """``(pairs [expert layers, held experts],)`` from what the expert
+    layers wrote to their load collection, in layer order; ``()`` for a
+    model without experts, whose programs return what they always did."""
+    load = mutated.get(MOE_LOAD)
+    if not load:
+        return ()
+    leaves = jax.tree_util.tree_leaves(load)
+    return (jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in leaves], axis=0),)
+
+
+def _load_args(sp, load, pairs_all: int) -> None:
+    """Expert load on a dispatch span: ``expert_pairs`` (pairs on held
+    experts), ``expert_pairs_all`` (tokens x k over the expert layers),
+    ``expert_load_max`` (most pairs on one expert of one layer) and
+    ``experts_idle`` (held experts of a layer that got no token)."""
+    sp.args["expert_pairs"] = int(load.sum())
+    sp.args["expert_pairs_all"] = int(pairs_all)
+    sp.args["expert_load_max"] = int(load.max())
+    sp.args["experts_idle"] = int((load == 0).sum())
 
 
 def _admit_state_fn(tokens, lengths, rngs, slot, first, length, rng):

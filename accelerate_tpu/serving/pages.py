@@ -536,44 +536,140 @@ class PagedTables:
         self.parking = int(parking)
         self.rows = np.full((num_slots, pages_per_slot), parking, np.int32)
         self.alloc_count = [0] * num_slots
+        # entries before ``released`` were given back behind a window: a
+        # slot's pages are rows[released:alloc_count] (0 for a full kind)
+        self.released = [0] * num_slots
 
     def reset_slot(self, slot: int):
         self.rows[slot] = self.parking
         self.alloc_count[slot] = 0
+        self.released[slot] = 0
 
     def slot_pages(self, slot: int) -> list:
-        return [int(p) for p in self.rows[slot, : self.alloc_count[slot]]]
+        return [int(p) for p in self.rows[slot, self.released[slot]: self.alloc_count[slot]]]
+
+
+class CacheKind:
+    """One kind of state a model's attention layers keep in the paged
+    arena, and how it is paged: the layers of one kind share a pool of
+    physical pages (``allocator``), a page table a slot (``tables`` on the
+    host, ``device_tables`` its device twin) and, for a window kind, the
+    rule by which pages fall behind the window. A model of one kind has
+    one, named "full", with the pages, tables and counts of before.
+
+    ``window``: None for full attention, whose pages live as long as the
+    slot; for a window layer the positions a query sees, its own included.
+    ``layers`` and ``token_bytes`` (keys and values of one layer, at the
+    widths the pages store) say what a page of this kind costs."""
+
+    def __init__(self, name: str, window: Optional[int], num_pages: int,
+                 num_slots: int, pages_per_slot: int, page_size: int,
+                 layers: int, token_bytes: int):
+        self.name, self.window = name, window
+        self.num_pages, self.page_size = int(num_pages), int(page_size)
+        self.layers, self.token_bytes = int(layers), int(token_bytes)
+        self.allocator = PageAllocator(self.num_pages, reserved=1)
+        self.tables = PagedTables(num_slots, pages_per_slot, parking=0)
+        self.device_tables = None  # the engine puts the device twin here
+
+    @property
+    def page_bytes(self) -> int:
+        """Arena bytes one page of this kind takes over all its layers."""
+        return self.layers * self.page_size * self.token_bytes
+
+    def first_live_entry(self, next_pos: int) -> int:
+        """The first table entry a slot still needs when its next query
+        sits at ``next_pos``: every page before it lies wholly behind
+        ``next_pos - window + 1``, the earliest position any later query
+        sees. 0 for a full kind."""
+        if self.window is None:
+            return 0
+        return max(0, next_pos - self.window + 1) // self.page_size
+
+    def release_behind(self, slot: int, next_pos: int) -> int:
+        """Give back the slot's pages that lie wholly behind the window of
+        a query at ``next_pos`` (host bookkeeping only: no kernel reads a
+        table entry before the window's first page, so the device table
+        keeps its stale entries). Returns the pages released."""
+        th = self.tables
+        first = min(self.first_live_entry(next_pos), th.alloc_count[slot])
+        n = 0
+        for idx in range(th.released[slot], first):
+            self.allocator.release(int(th.rows[slot][idx]))
+            th.rows[slot][idx] = th.parking
+            n += 1
+        th.released[slot] = max(th.released[slot], first)
+        return n
+
+    def walked_tokens(self, pos: int) -> int:
+        """Tokens the paged decode kernel walks in one layer of this kind
+        for a slot whose last write lands at ``pos``: whole pages from the
+        window's first (or the slot's first) through that one."""
+        ps = self.page_size
+        return (pos // ps + 1 - self.first_live_entry(pos)) * ps
 
 
 # ---------------------------------------------------------------------------
 # device helpers (lazy jax: the bookkeeping above must import accelerator-free)
 # ---------------------------------------------------------------------------
 
-# paged K/V leaves are [num_pages, KVH, page_size, D] (+ layer axis). A
-# quantized arena's scale leaves are [num_pages, KVH, page_size, 1] — same
-# rank BY DESIGN, so every generic tree op below (gather views, scatters,
-# CoW forks) moves a page's payload and its scales together with no
-# special-casing, and nothing can fork or share one without the other.
-_KV_NDIM = 4
+# A cache leaf is found by its name, never by its rank: paged K/V leaves
+# are ``cached_key`` / ``cached_value`` [num_pages, KVH, page_size, D] (+ a
+# leading layer axis under a scanned stack; the two widths may differ), a
+# quantized arena's ``*_scale`` leaves [num_pages, KVH, page_size, 1] move
+# with their payloads through every generic tree op below (gather views,
+# scatters, CoW forks), and everything else (``cache_index``, an encoder's
+# memory) is not paged. The flat slot arena (arena.py) slices the same
+# leaves, and a seq2seq decoder's cross keys and values, along the slot axis.
+PAGED_LEAF_NAMES = frozenset(
+    ("cached_key", "cached_value", "cached_key_scale", "cached_value_scale"))
+SLOT_LEAF_NAMES = PAGED_LEAF_NAMES | {"cross_key", "cross_value"}
 
 
-def _is_kv(leaf) -> bool:
-    return getattr(leaf, "ndim", 0) >= _KV_NDIM
+def leaf_name(path) -> Optional[str]:
+    """The last key of a tree path (a dict key or an attribute name)."""
+    last = path[-1] if path else None
+    return getattr(last, "key", getattr(last, "name", None))
+
+
+def is_paged_leaf(path) -> bool:
+    return leaf_name(path) in PAGED_LEAF_NAMES
 
 
 def _page_axis(leaf) -> int:
-    return leaf.ndim - _KV_NDIM
+    """A paged leaf's page axis: the fourth from the end."""
+    return leaf.ndim - 4
+
+
+def map_paged(fn, arena, *rest, other=lambda leaf, *_: leaf):
+    """``fn(leaf, *rest_leaves)`` on the paged leaves, ``other`` on the rest."""
+    import jax
+
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf, *r: fn(leaf, *r) if is_paged_leaf(path) else other(leaf, *r),
+        arena, *rest)
+
+
+def paged_leaves(arena) -> list:
+    """The paged leaves in the arena's flatten order."""
+    import jax
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(arena)
+    return [leaf for path, leaf in flat if is_paged_leaf(path)]
 
 
 def init_paged_arena(definition, params, num_slots: int, pages_per_slot: int,
-                     placer):
+                     placer, kinds=None):
     """All-zeros paged cache arena shaped by ``jax.eval_shape`` over the
     paged decode apply — the paged twin of ``arena.init_arena`` (no compile,
-    no device compute, correct for any cache layout the family uses)."""
+    no device compute, correct for any cache layout the family uses).
+    ``kinds``: the cache kinds' names where the model states several (it
+    then takes a page table a kind)."""
     import jax
     import jax.numpy as jnp
 
     def shape_fn(p):
+        table = jnp.zeros((num_slots, pages_per_slot), jnp.int32)
         _, mutated = definition.apply(
             {"params": placer(p)},
             jnp.zeros((num_slots, 1), jnp.int32),
@@ -581,7 +677,7 @@ def init_paged_arena(definition, params, num_slots: int, pages_per_slot: int,
             use_cache=True,
             decode=True,
             cache_positions=jnp.zeros((num_slots,), jnp.int32),
-            page_table=jnp.zeros((num_slots, pages_per_slot), jnp.int32),
+            page_table={k: table for k in kinds} if kinds else table,
             mutable=["cache"],
         )
         return mutated["cache"]
@@ -596,19 +692,16 @@ def dense_slot_view(arena, page_row, start):
     scalar-``cache_index`` prefill path (and its chunk-exactness contract)
     is reused verbatim on the paged arena. ``cache_index`` leaves become
     ``start``, like ``arena.slot_view``. Traced-friendly."""
-    import jax
     import jax.numpy as jnp
 
     def take(leaf):
-        if not _is_kv(leaf):
-            return jnp.full(leaf.shape, start, leaf.dtype)
         axis = _page_axis(leaf)
         g = jnp.take(leaf, page_row, axis=axis)       # [..., P, KVH, ps, D]
         g = jnp.moveaxis(g, axis, axis + 1)           # [..., KVH, P, ps, D]
         shape = g.shape[: axis + 1] + (g.shape[axis + 1] * g.shape[axis + 2], g.shape[-1])
         return jnp.expand_dims(g.reshape(shape), axis)  # [..., 1, KVH, P*ps, D]
 
-    return jax.tree_util.tree_map(take, arena)
+    return map_paged(take, arena, other=lambda leaf: jnp.full(leaf.shape, start, leaf.dtype))
 
 
 def scatter_slot_view(arena, view_tree, page_row):
@@ -618,12 +711,9 @@ def scatter_slot_view(arena, view_tree, page_row):
     chunk only mutates positions inside the slot's allocated span — so the
     scatter's unspecified duplicate order cannot matter. Index leaves keep
     the arena's value, mirroring ``arena.write_slot``."""
-    import jax
     import jax.numpy as jnp
 
     def put(leaf, view):
-        if not _is_kv(leaf):
-            return leaf
         axis = _page_axis(leaf)
         ps = leaf.shape[-2]
         v = jnp.squeeze(view.astype(leaf.dtype), axis=axis)  # [..., KVH, P*ps, D]
@@ -631,7 +721,7 @@ def scatter_slot_view(arena, view_tree, page_row):
         v = jnp.moveaxis(v.reshape(shape), axis + 1, axis)   # [..., P, KVH, ps, D]
         return leaf.at[(slice(None),) * axis + (page_row,)].set(v)
 
-    return jax.tree_util.tree_map(put, arena, view_tree)
+    return map_paged(put, arena, view_tree)
 
 
 def fork_page(arena, src, dst):
@@ -641,13 +731,11 @@ def fork_page(arena, src, dst):
     import jax
 
     def copy(leaf):
-        if not _is_kv(leaf):
-            return leaf
         axis = _page_axis(leaf)
         page = jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=axis)
         return jax.lax.dynamic_update_slice_in_dim(leaf, page, dst, axis=axis)
 
-    return jax.tree_util.tree_map(copy, arena)
+    return map_paged(copy, arena)
 
 
 def gather_pages(arena, page_ids):
@@ -655,21 +743,15 @@ def gather_pages(arena, page_ids):
     the order given — the KV-handoff export read. Returns a list of numpy
     arrays (one per K/V leaf, arena flatten order) whose page axis holds
     ``len(page_ids)`` entries; quantized arenas ship the int8/int4 payload
-    leaves and their fp32 scale leaves alike (same rank — see the module
-    note above ``_KV_NDIM``), so a handoff can never separate a payload
-    from its scales. One small gather dispatch per leaf (the full arena is
-    never device_get)."""
+    leaves and their fp32 scale leaves alike, so a handoff can never
+    separate a payload from its scales. One small gather dispatch per leaf
+    (the full arena is never device_get)."""
     import jax
     import jax.numpy as jnp
 
     ids = jnp.asarray(list(page_ids), jnp.int32)
-    out = []
-    for leaf in jax.tree_util.tree_leaves(arena):
-        if not _is_kv(leaf):
-            continue
-        g = jnp.take(leaf, ids, axis=_page_axis(leaf))
-        out.append(np.asarray(jax.device_get(g)))
-    return out
+    return [np.asarray(jax.device_get(jnp.take(leaf, ids, axis=_page_axis(leaf))))
+            for leaf in paged_leaves(arena)]
 
 
 def gather_page(arena, src):
@@ -681,14 +763,8 @@ def gather_page(arena, src):
     its per-call id *list*, would compile per distinct page count)."""
     import jax
 
-    out = []
-    for leaf in jax.tree_util.tree_leaves(arena):
-        if not _is_kv(leaf):
-            continue
-        out.append(
-            jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=_page_axis(leaf))
-        )
-    return out
+    return [jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=_page_axis(leaf))
+            for leaf in paged_leaves(arena)]
 
 
 def install_page(arena, page_tree, dst):
@@ -700,13 +776,11 @@ def install_page(arena, page_tree, dst):
     import jax
 
     def put(leaf, page):
-        if not _is_kv(leaf):
-            return leaf
         return jax.lax.dynamic_update_slice_in_dim(
             leaf, page.astype(leaf.dtype), dst, axis=_page_axis(leaf)
         )
 
-    return jax.tree_util.tree_map(put, arena, page_tree)
+    return map_paged(put, arena, page_tree)
 
 
 def set_table_row(tables, slot, row):

@@ -40,7 +40,7 @@ import time
 from collections import deque
 from typing import Optional
 
-RING_SPANS = 8192
+RING_SPANS = 16384
 
 _RECORDER: Optional["SpanRecorder"] = None
 _tls = threading.local()

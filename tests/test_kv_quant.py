@@ -213,7 +213,7 @@ class TestKvQuantHostHelpers:
         """kv_token_bytes (the planning number) equals the bytes the real
         arena allocates per token slot — drift here would skew every
         capacity decision the router makes."""
-        from accelerate_tpu.serving.pages import _is_kv, kv_token_bytes
+        from accelerate_tpu.serving.pages import kv_token_bytes, paged_leaves
 
         model, cfg, params, prompts = served_model
         for kvq in ("bf16", "int8", "int4"):
@@ -224,8 +224,7 @@ class TestKvQuantHostHelpers:
                 num_layers=cfg.num_layers,
             )
             kv_bytes = sum(  # cache_index bookkeeping scalars excluded
-                int(l.nbytes) for l in jax.tree_util.tree_leaves(engine._arena)
-                if _is_kv(l)
+                int(l.nbytes) for l in paged_leaves(engine._arena)
             )
             actual = kv_bytes / (engine.num_pages * engine.page_size)
             assert predicted == actual, (kvq, predicted, actual)

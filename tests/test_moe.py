@@ -7,45 +7,91 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.models import DecoderConfig, DecoderLM, MoeMLP
-from accelerate_tpu.models.moe import compute_capacity, top_k_routing
+from accelerate_tpu.models.moe import (
+    expert_rows,
+    grouped_mlp,
+    load_balance_loss,
+    routed_experts,
+    router_scores,
+    sort_pairs,
+    top_k_routing,
+)
 from accelerate_tpu.parallel.mesh import build_mesh
 
 
-class TestRouting:
-    def test_dispatch_combines_to_gates(self):
-        """With ample capacity every top-k slot lands in a queue and combine
-        weights sum to 1 per token."""
-        probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0), (2, 8, 4)), -1)
-        dispatch, combine, aux = top_k_routing(probs, top_k=2, capacity=8)
-        np.testing.assert_allclose(np.asarray(combine.sum((2, 3))), np.ones((2, 8)), rtol=1e-5)
-        # dispatch is 0/1 and each (group, expert) queue slot holds <= 1 token
-        d = np.asarray(dispatch)
-        assert set(np.unique(d)).issubset({0.0, 1.0})
-        assert (d.sum(axis=1) <= 1.0 + 1e-6).all()
+def _experts(num, d=16, m=32, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(k[0], (num, d, m)) * d ** -0.5, jax.random.normal(k[1], (num, d, m)) * d ** -0.5,
+            jax.random.normal(k[2], (num, m, d)) * m ** -0.5)
 
-    def test_capacity_drops_overflow(self):
-        """All tokens route to one expert: only `capacity` slots survive,
-        first come first served, independently per group."""
-        probs = jnp.tile(jnp.asarray([[[0.97, 0.01, 0.01, 0.01]]]), (2, 8, 1))
-        dispatch, combine, _ = top_k_routing(probs, top_k=1, capacity=3)
-        assert float(dispatch.sum()) == 6.0  # 3 per group
-        kept = np.asarray(combine.sum((2, 3)))
-        assert (kept[:, :3] > 0).all() and (kept[:, 3:] == 0).all()
+
+def _dense_share(x, experts, weights, wg, wu, wd, first=0):
+    """Every pair by hand: the held experts' part of the layer's result."""
+    y = np.zeros(x.shape, np.float32)
+    for t in range(x.shape[0]):
+        for j in range(experts.shape[1]):
+            e = int(experts[t, j]) - first
+            if 0 <= e < wg.shape[0]:
+                h = jax.nn.silu(x[t] @ wg[e]) * (x[t] @ wu[e])
+                y[t] += float(weights[t, j]) * np.asarray(h @ wd[e])
+    return y
+
+
+class TestRouting:
+    """The routing of before (a capacity a group and dropped overflow) is
+    gone; each of its five tests is rewritten here as a case of the routing
+    by sorted pairs, in the same order, and the new options follow."""
+
+    def test_weights_sum_to_one_and_every_pair_is_routed(self):
+        """(was: dispatch combines to gates) the chosen scores normalise to
+        1 a token, and the sorted pairs are every (token, choice) once."""
+        scores = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0), (16, 4)), -1)
+        experts, weights = top_k_routing(scores, top_k=2)
+        np.testing.assert_allclose(np.asarray(weights.sum(-1)), np.ones(16), rtol=1e-5)
+        order, sizes, n_held = sort_pairs(experts, 0, 4)
+        assert sorted(np.asarray(order).tolist()) == list(range(32)) and int(n_held) == 32
+        assert int(sizes.sum()) == 32
+        # sorted by expert: the keys along the order never fall
+        keys = np.asarray(experts).reshape(-1)[np.asarray(order)]
+        assert (np.diff(keys) >= 0).all()
+
+    def test_skew_overflows_into_further_chunks_and_drops_nothing(self):
+        """(was: capacity drops overflow) every token on the same held
+        experts: 4 x the rows one pass multiplies, and every pair's product
+        is in the result all the same."""
+        tokens, k, held, outputs = 40, 4, 8, 32
+        x = jax.random.normal(jax.random.PRNGKey(1), (tokens, 16))
+        wg, wu, wd = _experts(held)
+        experts = jnp.tile(jnp.arange(k, dtype=jnp.int32)[None], (tokens, 1))
+        weights = jnp.full((tokens, k), 1.0 / k)
+        assert expert_rows(tokens, k, held, outputs) * 2 == tokens * k  # two chunks
+        y, sizes = jax.jit(lambda *a: routed_experts(*a, first=0, outputs=outputs))(x, experts, weights, wg, wu, wd)
+        assert np.asarray(sizes).tolist() == [tokens] * k + [0] * (held - k)
+        np.testing.assert_allclose(np.asarray(y), _dense_share(x, experts, weights, wg, wu, wd), atol=2e-5)
 
     def test_aux_loss_minimized_at_balance(self):
-        balanced = jnp.full((1, 32, 4), 0.25)
-        _, _, aux_b = top_k_routing(balanced, 1, 32)
-        skewed = jnp.tile(jnp.asarray([[[0.97, 0.01, 0.01, 0.01]]]), (1, 32, 1))
-        _, _, aux_s = top_k_routing(skewed, 1, 32)
+        balanced = jnp.full((32, 4), 0.25)
+        first = jnp.arange(32)[:, None] % 4  # first choices spread evenly
+        aux_b = load_balance_loss(balanced, first)
+        skewed = jnp.tile(jnp.asarray([[0.97, 0.01, 0.01, 0.01]]), (32, 1))
+        aux_s = load_balance_loss(skewed, top_k_routing(skewed, 1)[0])
         assert float(aux_b) == pytest.approx(1.0, rel=1e-5)
         assert float(aux_s) > float(aux_b)
 
-    def test_capacity_formula(self):
-        assert compute_capacity(128, 8, 2, 1.0) == 32
-        assert compute_capacity(4, 8, 1, 1.0) == 1  # floor of 1
+    def test_rows_formula(self):
+        """(was: capacity formula) all pairs where every expert is held;
+        twice the expected pairs on a held share, in sublane tiles, at
+        least 16, never more than there are pairs."""
+        assert expert_rows(128, 2, 8, 8) == 256
+        assert expert_rows(64, 8, 16, 256) == 64      # the serving cell's decode step
+        assert expert_rows(256, 8, 16, 256) == 256    # ... and its prefill pack
+        assert expert_rows(4, 2, 2, 64) == 8          # never more than the pairs
+        assert expert_rows(100, 8, 1, 256) == 16      # floor
 
-    def test_dispatch_memory_linear_in_batch(self):
-        """Grouped routing: capacity depends on seq, not the global batch."""
+    def test_shapes_linear_in_tokens(self):
+        """(was: dispatch memory linear in batch) the layer's output shape
+        follows the tokens, and the rows multiplied at once grow with them
+        in proportion, not with their square."""
         cfg4 = DecoderConfig.tiny(moe_num_experts=4, moe_top_k=2)
         moe = MoeMLP(cfg4, None)
         x_small = jnp.zeros((2, 16, cfg4.embed_dim), cfg4.dtype)
@@ -57,6 +103,58 @@ class TestRouting:
         shapes_small = jax.eval_shape(lambda p, x: moe.apply({"params": p}, x), raw, x_small)
         shapes_big = jax.eval_shape(lambda p, x: moe.apply({"params": p}, x), raw, x_big)
         assert shapes_small[0].shape[1:] == shapes_big[0].shape[1:]
+        assert expert_rows(8 * 16, 2, 4, 4) == 4 * expert_rows(2 * 16, 2, 4, 4)
+
+    def test_selection_bias_moves_the_choice_and_not_the_weights(self):
+        scores = jnp.asarray([[0.9, 0.8, 0.1, 0.05]])
+        experts, weights = top_k_routing(scores, 2)
+        assert sorted(np.asarray(experts[0]).tolist()) == [0, 1]
+        biased, w_b = top_k_routing(scores, 2, selection_bias=jnp.asarray([0.0, -1.0, 1.0, 0.0]))
+        assert sorted(np.asarray(biased[0]).tolist()) == [0, 2]
+        # the weights are the scores themselves (0.9, 0.1), normalised over the chosen
+        np.testing.assert_allclose(sorted(np.asarray(w_b[0]).tolist()), [0.1, 0.9], rtol=1e-6)
+
+    def test_sigmoid_scores_each_output_alone(self):
+        logits = jnp.asarray([[2.0, -1.0, 0.5]])
+        np.testing.assert_allclose(np.asarray(router_scores(logits, "sigmoid")),
+                                   1 / (1 + np.exp(-np.asarray(logits))), rtol=1e-6)
+        np.testing.assert_allclose(float(router_scores(logits, "softmax").sum()), 1.0, rtol=1e-6)
+
+    @pytest.mark.parametrize("impl", ["xla", "interpret"])
+    def test_the_shares_add_up_to_the_whole_layer(self, impl):
+        """Four shares of 8 experts over 32 router outputs: each computes
+        its own experts' part with the weights normalised over all k
+        chosen, and the parts add up to what one holder of all 32 gives."""
+        tokens, k, outputs, d = 24, 4, 32, 16
+        x = jax.random.normal(jax.random.PRNGKey(2), (tokens, d))
+        wg, wu, wd = _experts(outputs, d)
+        scores = router_scores(jax.random.normal(jax.random.PRNGKey(3), (tokens, outputs)), "sigmoid")
+        experts, weights = top_k_routing(scores, k)
+        whole, _ = routed_experts(x, experts, weights, wg, wu, wd, impl=impl)
+        parts = sum(routed_experts(x, experts, weights, wg[f:f + 8], wu[f:f + 8], wd[f:f + 8],
+                                   first=f, outputs=outputs, impl=impl)[0] for f in range(0, outputs, 8))
+        np.testing.assert_allclose(np.asarray(parts), np.asarray(whole), atol=3e-5)
+        np.testing.assert_allclose(np.asarray(whole), _dense_share(x, experts, weights, wg, wu, wd), atol=3e-5)
+
+    def test_masked_tokens_are_routed_nowhere(self):
+        x = jax.random.normal(jax.random.PRNGKey(4), (8, 16))
+        wg, wu, wd = _experts(4)
+        experts, weights = top_k_routing(router_scores(x[:, :4], "softmax"), 2)
+        mask = jnp.arange(8) < 5
+        y, sizes = routed_experts(x, experts, weights, wg, wu, wd, token_mask=mask)
+        assert int(sizes.sum()) == 10 and not np.asarray(y[5:]).any()
+
+    def test_kernel_skips_experts_without_a_row(self):
+        """The grouped product by the kernel (interpreted) and by
+        ragged_dot agree where half the experts got nothing, and rows past
+        the groups come out as zeros."""
+        xs = jax.random.normal(jax.random.PRNGKey(5), (16, 16))
+        wg, wu, wd = _experts(6)
+        sizes = jnp.asarray([3, 0, 5, 0, 0, 4], jnp.int32)
+        a = grouped_mlp(xs, wg, wu, wd, sizes, "xla")
+        b = grouped_mlp(xs, wg, wu, wd, sizes, "interpret")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+        assert not np.asarray(b[12:]).any()
 
 
 class TestMoeParity:
@@ -65,7 +163,7 @@ class TestMoeParity:
         == dense MLP output (gates sum to 1)."""
         from accelerate_tpu.models.decoder import DecoderMLP
 
-        cfg = DecoderConfig.tiny(moe_num_experts=4, moe_top_k=4, moe_capacity_factor=4.0)
+        cfg = DecoderConfig.tiny(moe_num_experts=4, moe_top_k=4)
         dense_cfg = DecoderConfig.tiny()
         moe = MoeMLP(cfg, None)
         dense = DecoderMLP(dense_cfg, None)
@@ -84,7 +182,7 @@ class TestMoeParity:
         assert np.isfinite(float(aux))
 
 
-_MOE_KW = dict(num_layers=4, moe_num_experts=4, moe_capacity_factor=2.0)
+_MOE_KW = dict(num_layers=4, moe_num_experts=4)
 
 
 def _moe_pipeline_fixtures():

@@ -74,17 +74,19 @@ def _kv_operands(chip, shape, d, bits):
 
 
 def _paged_decode(chip, *, h=32, kvh=32, d=128, ps=16, sq=1, bits=0,
-                  slots=SLOTS, pages=PAGES, table=None):
+                  slots=SLOTS, pages=PAGES, table=None, dv=None, window=None, sink=False):
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     kp, ks = _kv_operands(chip, (pages, kvh, ps), d, bits)
+    vp, _ = _kv_operands(chip, (pages, kvh, ps), dv or d, bits)
 
-    def fn(q, kp, vp, table, pos, lengths, ks, vs):
+    def fn(q, kp, vp, table, pos, lengths, ks, vs, sink):
         return A._paged_decode_kernel_call(
-            q, kp, vp, table, pos, lengths, SM_SCALE, False, k_scale=ks, v_scale=vs, quant_bits=bits)
+            q, kp, vp, table, pos, lengths, SM_SCALE, False, k_scale=ks, v_scale=vs, quant_bits=bits,
+            window=window, sink=sink, value_scale=0.707 if window else 1.0)
 
-    return fn, (S((slots, h, sq, d), jnp.bfloat16), kp, kp,
+    return fn, (S((slots, h, sq, d), jnp.bfloat16), kp, vp,
                 S((slots, table or 2048 // ps), jnp.int32), S((slots, sq), jnp.int32),
-                S((slots,), jnp.int32), ks, ks)
+                S((slots,), jnp.int32), ks, ks, S((h,), jnp.float32) if sink else None)
 
 
 def _dense_decode(chip, *, bits=0):
@@ -98,19 +100,31 @@ def _dense_decode(chip, *, bits=0):
     return fn, (S((SLOTS, 32, 1, 128), jnp.bfloat16), k, k, S((SLOTS, 1), jnp.int32), ks, ks)
 
 
-def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8):
+def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8, dv=None, window=None,
+                    sink=False, table=None):
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
     kp, ks = _kv_operands(chip, (PAGES, kvh, ps), d, bits)
-    new = S((1, kvh, cap, d), jnp.bfloat16)
+    vp, _ = _kv_operands(chip, (PAGES, kvh, ps), dv or d, bits)
     rows = S((cap,), jnp.int32)
 
-    def fn(q, kn, vn, kp, vp, table, row_slot, row_pos, hist, ks, vs):
+    def fn(q, kn, vn, kp, vp, table, row_slot, row_pos, hist, ks, vs, sink):
         return A._ragged_prefill_kernel_call(
             q, kn, vn, kp, vp, table, row_slot, row_pos, hist, SM_SCALE, bt, False,
-            k_scale=ks, v_scale=vs, quant_bits=bits)
+            k_scale=ks, v_scale=vs, quant_bits=bits, window=window, sink=sink,
+            value_scale=0.707 if window else 1.0)
 
-    return fn, (S((1, h, cap, d), jnp.bfloat16), new, new, kp, kp,
-                S((SLOTS, 2048 // ps), jnp.int32), rows, rows, S((SLOTS,), jnp.int32), ks, ks)
+    return fn, (S((1, h, cap, d), jnp.bfloat16), S((1, kvh, cap, d), jnp.bfloat16),
+                S((1, kvh, cap, dv or d), jnp.bfloat16), kp, vp,
+                S((SLOTS, table or 2048 // ps), jnp.int32), rows, rows, S((SLOTS,), jnp.int32), ks, ks,
+                S((h,), jnp.float32) if sink else None)
+
+
+def _moe_experts(chip, *, rows, held=16, d=4096, m=2048):
+    from accelerate_tpu.models import moe
+
+    S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    fn = lambda x, wg, wu, wd, sizes: moe._experts_kernel_call(x, wg, wu, wd, sizes, False)
+    return fn, (S((rows, d)), S((held, d, m)), S((held, d, m)), S((held, m, d)), S((held,), jnp.int32))
 
 
 CASES = {
@@ -140,6 +154,19 @@ CASES = {
     "ragged_prefill_mha_d64": (_ragged_prefill, dict(h=12, kvh=12, d=64)),
     "ragged_prefill_gqa_d64_int8": (_ragged_prefill, dict(h=12, kvh=4, d=64, bits=8)),
     "ragged_prefill_gqa_int4_page128": (_ragged_prefill, dict(kvh=8, ps=128, bits=4)),
+    # layer kinds (benchmarks/configs/mimo-v2-flash-serve-7l-ep16.json): 64 query heads,
+    # keys 192 wide stored padded to 256 lanes, values 128; a full kind of 4 kv heads,
+    # a window kind of 8 with a window of 128 and a sink; the held experts' kernel
+    "paged_decode_keys256_values128_full_kind": (
+        _paged_decode, dict(h=64, kvh=4, d=256, dv=128, slots=64, pages=16384, table=512)),
+    "paged_decode_keys256_values128_window_kind": (
+        _paged_decode, dict(h=64, kvh=8, d=256, dv=128, slots=64, pages=1024, table=512, window=128, sink=True)),
+    "ragged_prefill_keys256_values128_full_kind": (
+        _ragged_prefill, dict(h=64, kvh=4, d=256, dv=128, bt=64, table=512)),
+    "ragged_prefill_keys256_values128_window_kind": (
+        _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, table=512, window=128, sink=True)),
+    "moe_experts_decode_rows": (_moe_experts, dict(rows=64)),
+    "moe_experts_prefill_rows": (_moe_experts, dict(rows=256)),
     # dense-arena decode (single-stream generate(), the flat slot arena)
     "dense_decode_bf16": (_dense_decode, dict(bits=0)),
     "dense_decode_int8": (_dense_decode, dict(bits=8)),
@@ -161,6 +188,10 @@ REFUSED = {
     },
     "paged_decode_int4_gqa_32q8kv": dict(kvh=8, bits=4),
     "paged_decode_int4_page128": dict(ps=128, bits=4),
+    # keys 192 wide as they are: refused like 64; stored padded to 256 lanes
+    # (paged_key_lanes) they are the compiled cases above
+    "paged_decode_bf16_keys192_values128_full_kind": dict(h=64, kvh=4, d=192, dv=128),
+    "paged_decode_bf16_keys192_values128_window_kind": dict(h=64, kvh=8, d=192, dv=128, window=128, sink=True),
 }
 
 
@@ -184,7 +215,8 @@ def test_paged_decode_the_compiler_refuses_is_gated_off(chip, case, monkeypatch)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(A, "_decode_fallback_warned", set())
     gate = A._decode_kernel_gate(
-        "paged", kw.get("sq", 1), kw.get("d", 128), kw.get("ps", 16), kw.get("bits", 0), paged=True)
+        "paged", kw.get("sq", 1), kw.get("d", 128), kw.get("ps", 16), kw.get("bits", 0), paged=True,
+        dv=kw.get("dv"))
     assert gate == (False, False)
 
 
@@ -199,6 +231,7 @@ KERNEL_NAMES = {
     "flash_gqa_32q8kv_fwd_bwd": {"jvp_flash_attn_fwd_", "jvp_flash_attn_dq_", "jvp_flash_attn_dkv_"},
     "ragged_prefill_gqa_32q8kv_bf16": {"ragged_prefill_attn"},
     "paged_decode_bf16_d128_sq1": {"attn"},
+    "moe_experts_decode_rows": {"moe_experts"},
 }
 
 
@@ -265,3 +298,14 @@ def test_gates_admit_only_what_compiles(monkeypatch):
             # the page-table kernel: CASES has what it admits, REFUSED the rest
             paged = A._decode_kernel_gate("paged", 1, d, 16, bits, paged=True)
             assert paged == (d == 128 and not bits, False)
+    # keys 192 wide: refused as they are, admitted in the layout a model
+    # gives its pages (256 lanes, values 128), and the prefill kernel takes both
+    assert A._decode_kernel_gate("paged", 1, 192, 16, 0, paged=True, dv=128) == (False, False)
+    assert A._decode_kernel_gate("paged", 1, A.paged_key_lanes(192), 16, 0, paged=True, dv=128) == (True, False)
+    assert A._prefill_kernel_gate("ragged", 192, 16, 64) == A._prefill_kernel_gate("ragged", 256, 16, 64) == (True, False)
+    from accelerate_tpu.models import DecoderConfig
+
+    kind = DecoderConfig(num_heads=64, num_kv_heads=8, head_dim=192, v_head_dim=128, embed_dim=4096,
+                         kv_page_size=16, kv_num_pages=1024, attn_window=128, attn_sink=True)
+    assert A.decode_kernel_active(kind) and A.prefill_kernel_active(kind)
+    assert not A.decode_kernel_active(DecoderConfig(num_heads=12, head_dim=64, kv_page_size=16, kv_num_pages=64))
