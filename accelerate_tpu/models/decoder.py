@@ -172,7 +172,9 @@ class DecoderAttention(nn.Module):
     is each slot's count of live tokens, the bound of that kernel's walk
     (absent: the last row position + 1); the serving engine passes 0 for
     an inactive slot, whose parked write position would otherwise read as
-    a request at the end of the cache.
+    a request at the end of the cache. ``cache_layer`` (the scanned stack's
+    layer counter, :func:`arena_in_place`): the cache leaves are then the
+    layers' stacks and the kernel writes this layer's new rows itself.
 
     ``config.kv_cache_dtype`` ("int8"/"int4") makes the cache STORAGE
     quantized on both layouts: writes quantize the fresh K/V rows (one
@@ -211,7 +213,7 @@ class DecoderAttention(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, deterministic: bool = True, kv_mask=None,
                  cache_positions=None, page_table=None, ragged_slots=None,
-                 slot_hist=None, kv_lengths=None):
+                 slot_hist=None, kv_lengths=None, cache_layer=None):
         cfg = self.config
         e, h, kv, d = cfg.embed_dim, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         # a layer kind's own value width, window, sink and value scale
@@ -434,7 +436,19 @@ class DecoderAttention(nn.Module):
                 # reuses this module without the decode_kernel fields.
                 dk_impl = getattr(cfg, "decode_kernel", None)
                 dk_blk = getattr(cfg, "decode_kernel_block", None)
-                if paged:
+                if paged and cache_layer is not None:
+                    # the arena in place (arena_in_place): the cache leaves
+                    # are the layers' stacks, carried through the scan, and
+                    # the kernel writes this layer's new rows itself
+                    from ..ops.attention import paged_decode_attention
+
+                    out, cached_k.value, cached_v.value = paged_decode_attention(
+                        q, cached_k.value, cached_v.value,
+                        page_table=page_table, q_positions=pos2d,
+                        kv_lengths=kv_lengths, impl=dk_impl,
+                        layer=cache_layer, k_new=k, v_new=v, **extras,
+                    )
+                elif paged:
                     from ..ops.attention import paged_decode_attention
 
                     ps = cfg.kv_page_size
@@ -545,6 +559,30 @@ class DecoderAttention(nn.Module):
         return _constrain(out, ("batch", "seq", "embed"), self.mesh)
 
 
+def arena_in_place(config, sq: int = 1) -> bool:
+    """Does a paged decode step of ``sq`` new tokens a slot update the
+    arena in place on a model with this config? Then the scanned stack
+    carries the "cache" collection whole, ``[L, num_pages, KVH, page, D]`` a
+    leaf, and the paged decode kernel takes the stack and a layer index and
+    writes each slot's new row itself (``ops/attention.
+    paged_decode_attention``): no layer's pages are sliced out of the
+    stack, re-laid out for a scatter or put back. It takes what that
+    kernel's write takes: the scanned stack, the kernel engaged
+    (``decode_kernel_active``: on the chip or interpreted, 128-multiple
+    page widths), one new token a slot and unquantized pages. Everything
+    else (the packed prefill, speculative verify, the dense fallback, a
+    quantized cache) splits the collection along the layers as before.
+    The serving engine's ``arena_in_place`` gauge reads this."""
+    from ..ops.attention import decode_kernel_active
+
+    return (
+        sq == 1
+        and bool(getattr(config, "scan_layers", False))
+        and getattr(config, "kv_cache_dtype", "bf16") not in ("int8", "int4")
+        and decode_kernel_active(config, sq)
+    )
+
+
 class DecoderMLP(nn.Module):
     config: DecoderConfig
     mesh: Optional[Mesh] = None
@@ -577,7 +615,7 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, deterministic: bool = True, cache_positions=None,
                  page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None,
-                 token_mask=None):
+                 token_mask=None, cache_layer=None):
         cfg = self.config
         ln1 = self.param("ln_attn", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
         ln2 = self.param("ln_mlp", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
@@ -587,7 +625,7 @@ class DecoderBlock(nn.Module):
         y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
             y, sin, cos, deterministic, cache_positions=cache_positions,
             page_table=page_table, ragged_slots=ragged_slots,
-            slot_hist=slot_hist, kv_lengths=kv_lengths,
+            slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer,
         )
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
@@ -625,13 +663,18 @@ class _ScanBlock(nn.Module):
     def __call__(self, carry, _):
         # cpos/ptab/rslots/shist/klens/tmask ride the carry like sin/cos
         # (broadcast inputs every layer reads unchanged); None when the
-        # slot-arena / ragged-prefill paths are off
-        x, aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask = carry
+        # slot-arena / ragged-prefill paths are off. ``layer`` counts the
+        # blocks where the "cache" collection is carried whole
+        # (arena_in_place), else None
+        x, aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask, layer = carry
         x, block_aux = DecoderBlock(self.config, self.mesh, self.use_cache, self.decode, name="block")(
             x, sin, cos, self.deterministic, cache_positions=cpos, page_table=ptab,
             ragged_slots=rslots, slot_hist=shist, kv_lengths=klens, token_mask=tmask,
+            cache_layer=layer,
         )
-        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask), None
+        if layer is not None:
+            layer = layer + 1
+        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask, layer), None
 
 
 class StageStack(nn.Module):
@@ -656,7 +699,7 @@ class StageStack(nn.Module):
         )
         (x, aux, *_), _ = Stack(
             cfg, self.mesh, deterministic=deterministic, name="layers"
-        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None, None), None)
+        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None, None, None), None)
         if cfg.moe_num_experts > 1:
             # per-(stage, microbatch) router load-balance sum over this
             # stage's layers; the schedule accumulates and renormalizes
@@ -809,18 +852,30 @@ class DecoderLM(nn.Module):
                 ptab = page_table
                 if isinstance(page_table, dict):
                     ptab = page_table[run_cfg.cache_kind]
+                # a paged decode step the kernel serves carries the stacked
+                # arena through the scan whole and the kernel updates it in
+                # place; every other call splits it by layer, as ever
+                # (not the call that shapes the arena: a carry has to exist)
+                name = f"layers_{i}" if cfg.layer_kinds else "layers"
+                in_place = (use_cache and decode and page_table is not None
+                            and ragged_slots is None and arena_in_place(run_cfg, s)
+                            and name in self.variables.get("cache", {}))
+                split = {"params": 0, "cache": 0, "fp8_stats": 0, MOE_LOAD: 0}
+                if in_place:
+                    del split["cache"]
                 ScanStack = nn.scan(
                     scan_body,
-                    variable_axes={"params": 0, "cache": 0, "fp8_stats": 0, MOE_LOAD: 0},
+                    variable_axes=split,
+                    variable_carry="cache" if in_place else False,
                     split_rngs={"params": True, "dropout": True},
                     length=run_cfg.num_layers,
                     metadata_params={nn.PARTITION_NAME: "layer"},
                 )
                 (x, run_aux, *_), _ = ScanStack(
-                    run_cfg, self.mesh, use_cache, decode, deterministic,
-                    name=f"layers_{i}" if cfg.layer_kinds else "layers",
+                    run_cfg, self.mesh, use_cache, decode, deterministic, name=name,
                 )((x, jnp.float32(0.0), sin, cos, cache_positions, ptab,
-                   ragged_slots, slot_hist, kv_lengths, token_mask), None)
+                   ragged_slots, slot_hist, kv_lengths, token_mask,
+                   jnp.int32(0) if in_place else None), None)
                 moe_aux = moe_aux + run_aux
         else:
             block_cls = _maybe_streaming(DecoderBlock, cfg)

@@ -28,7 +28,10 @@ Capabilities:
   table, or in fixed blocks over a dense arena — so decode HBM traffic
   scales with live tokens, not arena capacity. Masked-dense stays the
   fallback + bit-exactness reference (`ATT_DECODE_KERNEL=paged|dense`,
-  "interpret" for CPU tests)
+  "interpret" for CPU tests). The paged kernel takes the layers' stacked
+  arena and a layer index and, in a decode step, writes each slot's new
+  row into its page itself, the stack aliased to its output: the step's
+  cache write is no XLA scatter
 - quantized KV arenas (`kv_quant_bits=8|4` + per-token scale operands):
   both decode kernels read int8/packed-int4 payloads from HBM and
   dequantize in-register before the flash inner product, so the byte
@@ -1008,10 +1011,10 @@ def paged_decode_block_pages(config, table_len: int) -> int:
     )
 
 
-def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
+def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
                          sm_scale, sq, group, block_pages, quant_bits,
                          out_dtype, window=None, has_sink=False,
-                         value_scale=1.0):
+                         value_scale=1.0, write=False):
     """One slot a grid step; inside, a loop over blocks of ``block_pages``
     consecutive table entries. Every live page of a block comes from the
     arena (left in HBM) by one asynchronous copy that brings all kv heads
@@ -1034,17 +1037,36 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
     before it may have been given back. ``has_sink``: a further operand
     [KVH, G, 128] holds each row's learned scalar, which starts the running
     maximum with a sum of one and no value. ``value_scale`` multiplies the
-    output. The key pages may be wider than the value pages."""
+    output. The key pages may be wider than the value pages.
+
+    The arena is the layers' stack ``[L, num_pages, KVH, page, D]`` and
+    ``layer_ref[0]`` the layer this call reads: a page is ``at[layer,
+    page]``. ``write`` (one query row a slot, unquantized pages): two
+    further operands hold each slot's new key and value row, and the stack
+    is this call's output as well as its input (aliased). Once the block
+    with the page of the slot's position is in VMEM, the row goes into it
+    at ``position % page`` and the whole page goes back to the arena while
+    the block is attended: the step needs no scatter, so the stack is never
+    sliced, re-laid out or copied. A slot of live length 0 writes nothing;
+    a slot that writes has a live length past its position."""
     if has_sink:
         sink_ref, refs = refs[0], refs[1:]
-    if quant_bits:
+    if write:
+        knew_ref, vnew_ref, refs = refs[0], refs[1], refs[2:]
+        # the stack as this call's output: the same buffer on the chip,
+        # and the one that holds the rows written so far when interpreted
+        (_, _, o_ref, k_hbm, v_hbm,
+         kbuf, vbuf, sems, state, acc, m_scr, l_scr, wsems) = refs
+        ks_hbm = vs_hbm = ksbuf = vsbuf = None
+    elif quant_bits:
         (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
          kbuf, vbuf, ksbuf, vsbuf, sems, state, acc, m_scr, l_scr) = refs
     else:
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, state, acc, m_scr, l_scr = refs
         ks_hbm = vs_hbm = ksbuf = vsbuf = None
     b, nslots = pl.program_id(0), pl.num_programs(0)
-    kvh, ps = k_hbm.shape[1], k_hbm.shape[2]
+    layer = layer_ref[0]
+    kvh, ps = k_hbm.shape[2], k_hbm.shape[3]
     g = group * sq
     bk = block_pages * ps  # kv positions a block spans
     live = len_ref[b]
@@ -1069,7 +1091,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
         if quant_bits:
             pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
         return [
-            pltpu.make_async_copy(src.at[page], dst.at[half, j], sems.at[half, which])
+            pltpu.make_async_copy(src.at[layer, page], dst.at[half, j], sems.at[half, which])
             for src, dst, which in pairs
         ]
 
@@ -1082,6 +1104,19 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
             return _
 
         jax.lax.fori_loop(0, count, one, None)
+
+    def new_row_page(blk, half):
+        """Whether block ``blk`` holds the page of the slot's position,
+        where in the block, and that page's copies back to the arena (all
+        kv heads of the page, keys and values)."""
+        entry = pos_ref[b, 0] // ps
+        j = entry - p0 - blk * block_pages
+        page = table_ref[b, entry]
+        back = [
+            pltpu.make_async_copy(buf.at[half, j], dst.at[layer, page], wsems.at[which])
+            for buf, dst, which in ((kbuf, k_hbm, 0), (vbuf, v_hbm, 1))
+        ]
+        return (j >= 0) & (j < block_pages), j, back
 
     def start(slot, blk, half):
         for_each_live_page(slot, blk, half, lambda copy: copy.start())
@@ -1145,6 +1180,22 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
                 state[1] = (nxt < nslots).astype(jnp.int32)
 
             wait(b, ib, half)
+            if write:
+                here, j_new, back = new_row_page(ib, half)
+
+                @pl.when(here)
+                def _():
+                    # the new rows into their page (a select over the page:
+                    # no store at a row that is not a tile's first), and
+                    # the page on its way back while the block is attended
+                    off = pos_ref[b, 0] % ps
+                    for buf, new_ref in ((kbuf, knew_ref), (vbuf, vnew_ref)):
+                        page = buf[half, j_new]  # [KVH, page, D]
+                        row = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+                        new = jnp.broadcast_to(new_ref[0], page.shape)
+                        buf[half, j_new] = jnp.where(row == off, new, page)
+                    for copy in back:
+                        copy.start()
             kvpos = (p0 * ps + ib * bk
                      + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1))
             # a row sees kv position c iff c <= its own position, and never
@@ -1191,6 +1242,12 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
                 heads(0, None)
             else:
                 jax.lax.fori_loop(0, kvh // together, heads, None)
+            if write:
+                # the page is back before its half of the buffer is filled again
+                @pl.when(here)
+                def _():
+                    for copy in back:
+                        copy.wait()
             return other
 
         state[0] = jax.lax.fori_loop(0, n_blocks, block, first_half)
@@ -1204,13 +1261,24 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, q_ref, *refs,
 def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
                               sm_scale, interpret, k_scale=None,
                               v_scale=None, quant_bits=0, window=None,
-                              sink=None, value_scale=1.0):
+                              sink=None, value_scale=1.0, layer=0,
+                              k_new=None, v_new=None):
+    """``k_pages`` / ``v_pages`` (and the scale pages) are the layers' stack
+    ``[L, num_pages, KVH, page, D]`` and ``layer`` the one to read. With
+    ``k_new`` / ``v_new`` ``[B, KVH, 1, D]`` the call also writes each
+    slot's new row (the kernel's ``write``) and returns ``(out, k_pages,
+    v_pages)``, the stacks updated in place."""
     b, h, sq, d = q.shape
-    _, kvh, ps, pd = k_pages.shape  # pd: payload width (d, or d/2 packed int4)
+    _, _, kvh, ps, pd = k_pages.shape  # pd: payload width (d, or d/2 packed int4)
     pdv = v_pages.shape[-1]
     dv = 2 * pdv if quant_bits == 4 else pdv  # the output's width
     group = h // kvh
     g = group * sq
+    write = k_new is not None
+    if write and (sq != 1 or quant_bits):
+        raise ValueError(
+            "the paged decode kernel writes one unquantized row a slot; "
+            f"got {sq} query rows, int{quant_bits} pages")
     n = _paged_decode_block_pages(
         kvh, ps, pd, k_pages.dtype, quant_bits, page_table.shape[1],
         pdv=None if pdv == pd else pdv,
@@ -1220,16 +1288,25 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         _paged_decode_kernel, sm_scale=sm_scale, sq=sq, group=group,
         block_pages=n, quant_bits=quant_bits, out_dtype=q.dtype,
         window=window, has_sink=sink is not None, value_scale=value_scale,
+        write=write,
     )
-    slot_spec = pl.BlockSpec((1, kvh, g, d), lambda b_, ln, po, tb: (b_, 0, 0, 0))
-    out_spec = pl.BlockSpec((1, kvh, g, dv), lambda b_, ln, po, tb: (b_, 0, 0, 0))
+
+    def per_slot(*block):
+        return pl.BlockSpec((1,) + block, lambda b_, ln, po, tb, ly: (b_, 0, 0, 0))
+
     arena = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs, operands = [slot_spec], [q_r]
+    in_specs, operands = [per_slot(kvh, g, d)], [q_r]
     if sink is not None:
         # row r of a kv head's fold is query head r // sq of its group
         rows = jnp.repeat(sink.astype(jnp.float32).reshape(kvh, group), sq, axis=1)
         operands.append(jnp.broadcast_to(rows[:, :, None], (kvh, g, 128)))
-        in_specs.append(pl.BlockSpec((kvh, g, 128), lambda b_, ln, po, tb: (0, 0, 0)))
+        in_specs.append(pl.BlockSpec((kvh, g, 128), lambda b_, ln, po, tb, ly: (0, 0, 0)))
+    if write:
+        operands += [k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype)]
+        in_specs += [per_slot(kvh, 1, pd), per_slot(kvh, 1, pdv)]
+    scalars = (lengths.astype(jnp.int32), pos, page_table.astype(jnp.int32),
+               jnp.asarray(layer, jnp.int32).reshape(1))
+    first_arena = len(scalars) + len(operands)
     operands += [k_pages, v_pages]
     buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype),
                pltpu.VMEM((2, n, kvh, ps, pdv), v_pages.dtype)]
@@ -1238,24 +1315,38 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         operands += [k_scale, v_scale]
         buffers += [pltpu.VMEM((2, n, kvh, ps, 1), jnp.float32)] * 2
     in_specs += [arena] * (4 if quant_bits else 2)
+    out_specs = per_slot(kvh, g, dv)
+    out_shape = jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype)
+    scratch = buffers + [
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((2,), jnp.int32),
+        _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
+    ]
+    aliases = {}
+    if write:
+        out_specs = [out_specs, arena, arena]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (k_pages, v_pages)]
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+        aliases = {first_arena: 1, first_arena + 1: 2}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=in_specs,
-        out_specs=out_spec,
-        scratch_shapes=buffers + [
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((2,), jnp.int32),
-            _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
-        ],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
     # the slots run in order: each starts the next one's first copies
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         **_grid_params(interpret, ("arbitrary",)),
-    )(lengths.astype(jnp.int32), pos, page_table.astype(jnp.int32), *operands)
+    )(*scalars, *operands)
+    if write:
+        out, k_pages, v_pages = out
+        return out.reshape(b, h, sq, dv), k_pages, v_pages
     return out.reshape(b, h, sq, dv)
 
 
@@ -1427,7 +1518,10 @@ def paged_decode_attention(
     window: Optional[int] = None,
     sink: Optional[jax.Array] = None,
     value_scale: float = 1.0,
-) -> jax.Array:
+    layer: Optional[jax.Array] = None,
+    k_new: Optional[jax.Array] = None,
+    v_new: Optional[jax.Array] = None,
+):
     """Decode attention reading K/V through a per-slot page table.
 
     q: [B, H, Sq, D]; k_pages/v_pages: [num_pages, KVH, page_size, D];
@@ -1465,6 +1559,14 @@ def paged_decode_attention(
     at the page that holds the first of them, so the table's entries
     before it are never read (the engine gives those pages back).
     ``sink`` [H] and ``value_scale`` are :func:`mha_reference`'s.
+
+    ``layer`` (with ``k_new`` / ``v_new`` [B, KVH, 1, D], the step's new
+    rows, not yet in the pages): the pages are the layers' stack
+    ``[L, num_pages, KVH, page_size, D]`` and the kernel puts each live
+    slot's row at its position itself; the result is ``(out, k_pages,
+    v_pages)``, the stacks updated in place (see ``_paged_decode_kernel``).
+    That is the kernel's alone: a caller asks :func:`decode_kernel_active`
+    first and keeps its own scatter where the dense path is taken.
     """
     mode = resolve_decode_kernel(impl)
     if kv_quant_bits and (k_scale is None or v_scale is None):
@@ -1473,7 +1575,7 @@ def paged_decode_attention(
     if mode != "dense":
         sq, d = q.shape[2], q.shape[3]
         use, interpret = _decode_kernel_gate(
-            mode, sq, d, k_pages.shape[2], kv_quant_bits, paged=True,
+            mode, sq, d, k_pages.shape[-2], kv_quant_bits, paged=True,
             dv=v_pages.shape[-1] * (2 if kv_quant_bits == 4 else 1),
         )
         if use:
@@ -1481,11 +1583,20 @@ def paged_decode_attention(
             pos = _positions_2d(q_positions, q.shape[0])
             if kv_lengths is None:
                 kv_lengths = jnp.max(pos, axis=1) + 1
+            pages = (k_pages, v_pages, k_scale, v_scale)
+            if layer is None:  # one layer's pages: a stack of one
+                pages, layer = [None if x is None else x[None] for x in pages], 0
+            k_pages, v_pages, k_scale, v_scale = pages
             return _paged_decode_kernel_call(
                 q, k_pages, v_pages, page_table, pos, kv_lengths, scale, interpret,
                 k_scale=k_scale, v_scale=v_scale, quant_bits=kv_quant_bits,
-                **extras,
+                layer=layer, k_new=k_new, v_new=v_new, **extras,
             )
+    if layer is not None:
+        raise ValueError(
+            "paged_decode_attention over the layers' stack is the kernel's "
+            "path; this dispatch resolves to the dense read "
+            "(decode_kernel_active says so beforehand)")
     k_full = gather_kv_pages(k_pages, page_table)
     v_full = gather_kv_pages(v_pages, page_table)
     if kv_quant_bits:

@@ -13,7 +13,9 @@ engine never recompiles:
 - **fused batched decode step** — ONE jitted fn
   ``(params, arena, last_tokens, lengths, active, rngs)`` with the arena
   (and the per-slot state vectors) **donated**, so the multi-hundred-MB
-  cache updates in place instead of doubling HBM per step.
+  cache updates in place instead of doubling HBM per step. The paged
+  arena also stays whole inside the step where the decode kernel serves
+  it (``models/decoder.arena_in_place``; the ``arena_in_place`` gauge).
 - **chunked prefill admission** — new prompts prefill in fixed-size
   bucketed chunks, one chunk per scheduler iteration, *interleaved*
   between decode steps: a 10k-token prompt never stalls in-flight decodes
@@ -45,7 +47,7 @@ import numpy as np
 from ..generation import _sample, _sized_definition, depipeline
 from ..telemetry.spans import emit as _emit_span
 from ..telemetry.spans import span as _span
-from ..models.decoder import MOE_LOAD
+from ..models.decoder import MOE_LOAD, arena_in_place
 from ..ops.attention import (
     _PREFILL_TOKEN_BLOCK,
     decode_kernel_active,
@@ -415,6 +417,10 @@ class ServingEngine:
             pcfg = self._paged_def.config
             run_cfgs = pcfg.run_configs()
             self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
+            # ... and whether that step updates the arena in place, the
+            # stacked leaves carried through the layer scan (the
+            # arena_in_place gauge and count of serving/decode_dispatch)
+            self._arena_in_place = all(arena_in_place(c) for c in run_cfgs)
             self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
             # packed ragged prefill (ops/attention.ragged_prefill_attention):
             # when the flash prefill kernel (or its interpreter) engages,
@@ -466,6 +472,7 @@ class ServingEngine:
             self._drafter = None
             self._verify_step = None
             self._kernel_costed = False
+            self._arena_in_place = False
             self._ragged_prefill = False
             self._ragged_bt = _PREFILL_TOKEN_BLOCK
             self._ragged_caps = ()
@@ -2812,7 +2819,8 @@ class ServingEngine:
                  "lengths": self._lengths, "active": self._active,
                  "rngs": self._rngs},
             )
-        with _span("serving/decode_dispatch", slots=len(self._slot_req)) as sp_d:
+        with _span("serving/decode_dispatch", slots=len(self._slot_req),
+                   arena_in_place=0) as sp_d:  # several rows a slot: the scatter
             (self._arena, self._tokens, self._lengths, self._rngs, cand, m) = (
                 self._verify_step(
                     self.params, self._arena, self._tokens, drafts_dev,
@@ -2909,7 +2917,8 @@ class ServingEngine:
         )
         step_extra = (self._tables_arg(),) if self.page_size else ()
         load = ()
-        with _span("serving/decode_dispatch", slots=len(self._slot_req)) as sp_d:
+        with _span("serving/decode_dispatch", slots=len(self._slot_req),
+                   arena_in_place=int(self._arena_in_place)) as sp_d:
             if k > 1:
                 self._arena, self._tokens, self._lengths, self._rngs, toks = (
                     self._decode_burst(k)(
@@ -3122,6 +3131,7 @@ class ServingEngine:
             out["serving/page_size"] = self.page_size
             out["serving/page_forks"] = self.page_forks
             out["serving/decode_kernel_active"] = bool(self._kernel_costed)
+            out["serving/arena_in_place"] = int(self._arena_in_place)
             out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
             for kind in self._kinds[1:]:
                 out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use

@@ -244,10 +244,47 @@ class TestPagedWalk:
 
         rng = np.random.RandomState(12)
         q, kp, vp, table, pos, lens, _ = _edge_setup(rng, h=4, kvh=2, sq=1, kv="bf16", live=3)
-        out = A._paged_decode_kernel_call(
-            q, kp, vp, table, pos, lens, 0.25,
+        out = A._paged_decode_kernel_call(  # the layers' stack, here of one layer
+            q, kp[None], vp[None], table, pos, lens, 0.25,
             pltpu.InterpretParams(uninitialized_memory="nan"))
         assert np.isfinite(np.asarray(out, np.float32)).all()
+
+
+    def test_the_kernel_writes_the_new_rows_into_the_stack(self):
+        """The decode step's own form (``k_new``/``v_new``): the stacked
+        pages and a layer index in, each live slot's row put at its position
+        by the kernel, the stack out; against the scatter followed by the
+        read-only kernel. Under the TPU interpreter too, which models the
+        asynchronous copies (a page on its way back while the block is
+        attended) and reports none of them racing."""
+        import accelerate_tpu.ops.attention as A
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+        from jax.experimental.pallas import tpu as pltpu
+
+        rng = np.random.RandomState(3)
+        layers, pages, kvh, ps, d, b, h = 2, 40, 2, 8, 128, 4, 4
+        kp, vp = (_rand(rng, (layers, pages, kvh, ps, d)) for _ in range(2))
+        q = _rand(rng, (b, h, 1, d))
+        k_new, v_new = (_rand(rng, (b, kvh, 1, d)) for _ in range(2))
+        table = jnp.asarray(1 + np.arange(b * 9).reshape(b, 9) % (pages - 1), jnp.int32)
+        # a walk of two blocks whose last row ends a page, a short one, a
+        # slot with no live tokens (writes nothing), a row that opens a page
+        pos = jnp.asarray([[71], [5], [71], [16]], jnp.int32)
+        lens = jnp.asarray([72, 6, 0, 17], jnp.int32)
+        rows = jnp.arange(b)[:, None]
+        page, off = table[rows, pos // ps], pos % ps
+        live = (lens > 0)[:, None, None, None]
+        put = lambda stack, new: stack.at[1, page, :, off].set(
+            jnp.where(live, jnp.swapaxes(new, 1, 2), stack[1, page, :, off]))
+        k_ref, v_ref = put(kp, k_new), put(vp, v_new)
+        ref = A._paged_decode_kernel_call(q, k_ref, v_ref, table, pos, lens, 0.25, True, layer=1)
+        for interpret in (True, pltpu.InterpretParams(uninitialized_memory="nan", detect_races=True)):
+            out, k_out, v_out = A._paged_decode_kernel_call(
+                q, kp, vp, table, pos, lens, 0.25, interpret, layer=1, k_new=k_new, v_new=v_new)
+            np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k_ref))
+            np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v_ref))
+            np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(ref, np.float32))
+        assert tpu_interpreter.races.races_found is False
 
 
 class TestDenseArenaKernel:

@@ -1,0 +1,244 @@
+"""The paged decode step that updates the arena in place
+(``models/decoder.arena_in_place``): the stacked cache leaves ride the layer
+scan as a carry and the paged decode kernel, given the stack and a layer
+index, writes each slot's new row into its page itself. Held against the
+threading every other call keeps (the collection split along the layers, an
+XLA scatter a layer, the kernel read-only), on the same weights and tables,
+kernels interpreted on the CPU: the same tokens and the same arena, bit for
+bit, except the parking page, which only the scatter writes (an inactive
+slot's parked row; the kernel writes nothing for a slot of live length 0).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import accelerate_tpu.models.decoder as decoder
+from accelerate_tpu.models import DecoderConfig, DecoderLM
+from accelerate_tpu.parallel.sharding import unbox_params
+from accelerate_tpu.serving import ServingEngine
+from accelerate_tpu.telemetry import spans
+
+PS = 8  # page size; 96 positions a slot are 12 table entries
+PARKING = 0
+
+
+@pytest.fixture(autouse=True)
+def optimized_xla():
+    """The suite compiles with most XLA optimizations off; whole engines with
+    interpreted kernels are then far slower (tests/benchmark/conftest)."""
+    before = jax.config.values["jax_disable_most_optimizations"]
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", before)
+
+
+def _model(shape: str, kernel="interpret"):
+    """``mistral``: one kind, grouped queries. ``by_kind``: a full and a
+    window kind, keys 192 wide (stored padded to 256 lanes), values 128,
+    partial rotary, a sink and a value scale on the window kind."""
+    common = dict(vocab_size=128, embed_dim=64, mlp_dim=128, max_seq_len=96, dtype=jnp.bfloat16,
+                  scan_layers=True, remat=False, decode_kernel=kernel)
+    if shape == "mistral":
+        cfg = DecoderConfig(num_layers=3, num_heads=4, num_kv_heads=2, head_dim=16, **common)
+    else:
+        cfg = DecoderConfig(
+            num_layers=5, num_heads=4, head_dim=192, v_head_dim=128, rope_dim=64, attn_value_scale=0.707,
+            layer_kinds=(("full", dict(num_kv_heads=1)),
+                         ("window", dict(num_kv_heads=2, attn_window=16, attn_sink=True, rope_theta=1e4))),
+            layer_pattern=(0, 1, 1, 0, 1), **common)
+    model = DecoderLM(cfg)
+    params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
+    return model, params
+
+
+@pytest.fixture(scope="module", params=["mistral", "by_kind"])
+def served(request):
+    return (request.param,) + _model(request.param)
+
+
+def _engine(shape, model, params, **kw):
+    args = dict(num_slots=3, max_cache_len=96, page_size=PS, prefill_chunks=(8, 16), prefix_cache=False)
+    if shape == "by_kind":
+        args.update(num_pages=1 + 3 * 12, kind_pages={"window16": 1 + 3 * 5})
+    args.update(kw)
+    return ServingEngine(model, params, **args)
+
+
+def _split_by_layer(monkeypatch):
+    """The threading of before, at the same kernel: what the decoder does
+    whenever ``arena_in_place`` says no."""
+    monkeypatch.setattr(decoder, "arena_in_place", lambda *a, **k: False)
+
+
+def _serve(eng, prompts, new=40):
+    outs = eng.generate_batched(prompts, max_new_tokens=new)
+    return [np.asarray(o) for o in outs], jax.tree_util.tree_map(np.asarray, eng._arena)
+
+
+def _assert_same_arena(got, want):
+    flat_g, _ = jax.tree_util.tree_flatten_with_path(got)
+    flat_w = jax.tree_util.tree_leaves(want)
+    assert len(flat_g) == len(flat_w) and any(g.ndim == 5 for _, g in flat_g)
+    for (path, g), w in zip(flat_g, flat_w):
+        assert g.shape == w.shape and g.dtype == w.dtype, jax.tree_util.keystr(path)
+        if g.ndim == 5:  # [L, pages, KVH, page, D]: every page but the parking page
+            g, w = g[:, PARKING + 1:], w[:, PARKING + 1:]
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
+
+
+def _mark() -> int:
+    """The newest span's id (the ring's last is an outer span, opened, so
+    numbered, before the spans inside it)."""
+    return max((s[0] for s in spans.snapshot()), default=0)
+
+
+def _decode_spans(mark: int) -> list:
+    """``args`` of the decode dispatches closed since ``_mark()``."""
+    return [args for i, _, name, _, _, args in spans.snapshot()
+            if i > mark and name == "serving/decode_dispatch"]
+
+
+PROMPTS = [np.arange(3, 3 + n) % 120 + 3 for n in (5, 17, 8, 30, 11)]  # five requests through three slots
+
+
+def test_forty_steps_give_the_same_tokens_and_the_same_arena(served, monkeypatch):
+    """Engines side by side, five requests through three slots (so slots are
+    used again, sit inactive and sit mid-admission while others decode):
+    the same tokens and every page but the parking page equal, and the
+    in-place engine says so in its gauge and on every decode span."""
+    shape, model, params = served
+    eng = _engine(shape, model, params)
+    assert eng.metrics()["serving/arena_in_place"] == 1 and eng.metrics()["serving/decode_kernel_active"]
+    mark = _mark()
+    got, arena = _serve(eng, PROMPTS)
+    mine = _decode_spans(mark)
+    assert len(mine) >= 40 and all(a["arena_in_place"] == 1 for a in mine)
+    _split_by_layer(monkeypatch)
+    want, arena_before = _serve(_engine(shape, model, params), PROMPTS)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    _assert_same_arena(arena, arena_before)
+
+
+def test_fused_bursts_carry_the_arena_too():
+    """``steps_per_call`` > 1 scans the same step: the same tokens and arena
+    as single steps, both in place (a model by kind is refused bursts)."""
+    shape, (model, params) = "mistral", _model("mistral")
+    single, arena_single = _serve(_engine(shape, model, params), PROMPTS[:3], new=16)
+    eng = _engine(shape, model, params, steps_per_call=4)
+    mark = _mark()
+    burst, arena_burst = _serve(eng, PROMPTS[:3], new=16)
+    assert all(a["arena_in_place"] == 1 for a in _decode_spans(mark))
+    for g, w in zip(burst, single):
+        np.testing.assert_array_equal(g, w)
+    _assert_same_arena(arena_burst, arena_single)
+
+
+@pytest.mark.parametrize("why", ["dense_kernel_mode", "quantized_pages", "layers_not_scanned",
+                                 "speculative_verify"])
+def test_the_fallback_says_so(why):
+    """What the in-place step does not take keeps the split threading, and
+    the span reads 0 (and the gauge, which is the plain decode step's): the
+    dense read, a quantized cache (its scale pages are the scatter's), a
+    model whose layers are not scanned, several rows a slot."""
+    model, params = _model("mistral", kernel="dense" if why == "dense_kernel_mode" else "interpret")
+    kw = {"quantized_pages": dict(kv_cache_dtype="int8"), "speculative_verify": dict(spec_draft_len=2)}.get(why, {})
+    if why == "layers_not_scanned":
+        model = model.clone(config=dataclasses.replace(model.config, scan_layers=False))
+        params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
+    eng = _engine("mistral", model, params, **kw)
+    assert eng.metrics()["serving/arena_in_place"] == int(why == "speculative_verify")
+    mark = _mark()
+    eng.generate_batched(PROMPTS[:2], max_new_tokens=4)
+    mine = _decode_spans(mark)
+    assert mine and all(a["arena_in_place"] == 0 for a in mine)
+
+
+# -- one step, tables made by hand -------------------------------------------
+
+
+def _random_arena(model, params, slots, table):
+    """An arena of noise (what a page holds is then visible byte for byte)."""
+    from accelerate_tpu.serving.pages import init_paged_arena
+
+    kinds = sorted({c.cache_kind for c in model.config.run_configs()}) if model.config.layer_kinds else None
+    arena = init_paged_arena(model, params, slots, table.shape[1], lambda p: p, kinds=kinds)
+    leaves, tree = jax.tree_util.tree_flatten(arena)
+    rng = np.random.default_rng(0)
+    noise = [jnp.asarray(rng.standard_normal(x.shape), x.dtype) if x.ndim == 5 else x for x in leaves]
+    return jax.tree_util.tree_unflatten(tree, noise), kinds
+
+
+def _one_step(model, params, arena, tokens, positions, lengths, table, kinds):
+    tables = {k: table for k in kinds} if kinds else table
+
+    @jax.jit
+    def step(params, arena):
+        out, mutated = model.apply(
+            {"params": params, "cache": arena}, tokens[:, None], positions=positions[:, None],
+            use_cache=True, decode=True, cache_positions=positions, page_table=tables,
+            kv_lengths=lengths, mutable=["cache"])
+        return out["logits"][:, -1], mutated["cache"]
+
+    logits, arena = step(params, arena)
+    return np.asarray(logits, np.float32), jax.tree_util.tree_map(np.asarray, arena)
+
+
+def _paged_model(shape):
+    model, params = _model(shape)
+    pages = dict(kv_page_size=PS, kv_num_pages=24)
+    cfg = model.config
+    if cfg.layer_kinds:
+        cfg = dataclasses.replace(cfg, layer_kinds=tuple((n, dict(o, **pages)) for n, o in cfg.layer_kinds))
+    return model.clone(config=dataclasses.replace(cfg, max_cache_len=96, **pages)), params
+
+
+# slot -> (table row, position written, live length); page 0 is the parking page
+CASES = {
+    # position 16 is row 0 of the slot's third page, which held nothing of it yet
+    "a_write_opens_a_new_page": {0: ([1, 2, 3], 16, 17), 1: ([4, 5, 6], 9, 10)},
+    # the last row of a page, and a slot's first token of all
+    "a_write_fills_a_page_and_a_first_token": {0: ([1, 2, 3], 15, 16), 1: ([4, 5, 6], 0, 1)},
+    # slot 1 is inactive (parked at the last position, a freed row of parking
+    # entries); slot 2 is mid-admission: it owns pages a prefill is filling
+    # and is parked likewise: neither may lose a byte
+    "an_inactive_slot_and_a_slot_mid_admission": {
+        0: ([1, 2, 3], 12, 13), 1: ([0, 0, 0], 95, 0), 2: ([7, 8, 9], 95, 0)},
+    # page 10 is a prefix both slots read; each writes a page of its own
+    "a_prefix_page_shared_by_two_slots": {0: ([10, 2, 3], 11, 12), 1: ([10, 5, 6], 20, 21)},
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("shape", ["mistral", "by_kind"])
+def test_one_step_writes_the_new_row_and_nothing_else(shape, case, monkeypatch):
+    model, params = _paged_model(shape)
+    slots = CASES[case]
+    n = len(slots)
+    table = np.zeros((n, 12), np.int32)
+    for s, (row, _, _) in slots.items():
+        table[s, :len(row)] = row
+    positions = jnp.asarray([slots[s][1] for s in range(n)], jnp.int32)
+    lengths = jnp.asarray([slots[s][2] for s in range(n)], jnp.int32)
+    tokens = jnp.arange(5, 5 + n, dtype=jnp.int32)
+    arena0, kinds = _random_arena(model, params, n, table)
+    before = jax.tree_util.tree_map(np.asarray, arena0)
+    args = (model, params, arena0, tokens, positions, lengths, jnp.asarray(table), kinds)
+    logits, arena = _one_step(*args)
+    _split_by_layer(monkeypatch)
+    logits_split, arena_split = _one_step(*args)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_array_equal(logits[live], logits_split[live])
+    _assert_same_arena(arena, arena_split)
+    # in place, a page changes only where a live slot wrote its row
+    written = {(int(table[s, p // PS]), p % PS) for s, (_, p, ln) in slots.items() if ln}
+    changed = set()
+    for b, a in zip(jax.tree_util.tree_leaves(before), jax.tree_util.tree_leaves(arena)):
+        if b.ndim == 5:
+            diff = (b != a).any(axis=(0, 2, 4))  # [pages, rows]
+            changed |= {(int(p), int(r)) for p, r in zip(*np.nonzero(diff))}
+    assert changed == written

@@ -73,20 +73,25 @@ def _kv_operands(chip, shape, d, bits):
     return payload, (S((*shape, 1), jnp.float32) if bits else None)
 
 
-def _paged_decode(chip, *, h=32, kvh=32, d=128, ps=16, sq=1, bits=0,
-                  slots=SLOTS, pages=PAGES, table=None, dv=None, window=None, sink=False):
+def _paged_decode(chip, *, h=32, kvh=32, d=128, ps=16, sq=1, bits=0, slots=SLOTS, pages=PAGES,
+                  table=None, dv=None, window=None, sink=False, layers=2, write=False):
+    """The kernel over the layers' stack and a layer index; ``write``: it also
+    puts each slot's new row into its page and the stack is its output."""
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    kp, ks = _kv_operands(chip, (pages, kvh, ps), d, bits)
-    vp, _ = _kv_operands(chip, (pages, kvh, ps), dv or d, bits)
+    kp, ks = _kv_operands(chip, (layers, pages, kvh, ps), d, bits)
+    vp, _ = _kv_operands(chip, (layers, pages, kvh, ps), dv or d, bits)
+    new = lambda width: S((slots, kvh, 1, width), jnp.bfloat16) if write else None
 
-    def fn(q, kp, vp, table, pos, lengths, ks, vs, sink):
+    def fn(q, kp, vp, table, pos, lengths, layer, ks, vs, sink, k_new, v_new):
         return A._paged_decode_kernel_call(
             q, kp, vp, table, pos, lengths, SM_SCALE, False, k_scale=ks, v_scale=vs, quant_bits=bits,
-            window=window, sink=sink, value_scale=0.707 if window else 1.0)
+            window=window, sink=sink, value_scale=0.707 if window else 1.0, layer=layer,
+            k_new=k_new, v_new=v_new)
 
     return fn, (S((slots, h, sq, d), jnp.bfloat16), kp, vp,
                 S((slots, table or 2048 // ps), jnp.int32), S((slots, sq), jnp.int32),
-                S((slots,), jnp.int32), ks, ks, S((h,), jnp.float32) if sink else None)
+                S((slots,), jnp.int32), S((), jnp.int32), ks, ks, S((h,), jnp.float32) if sink else None,
+                new(d), new(dv or d))
 
 
 def _dense_decode(chip, *, bits=0):
@@ -141,6 +146,10 @@ CASES = {
     "paged_decode_bf16_gqa_32q8kv_sq5": (_paged_decode, dict(kvh=8, sq=5)),
     "paged_decode_bf16_page8": (_paged_decode, dict(kvh=8, ps=8)),
     "paged_decode_serving_cell": (_paged_decode, dict(kvh=8, slots=32, pages=3584, table=256)),
+    # the decode step's own form: the kernel writes the new rows, the stack aliased to its output
+    "paged_decode_serving_cell_in_place": (
+        _paged_decode, dict(kvh=8, slots=32, pages=3584, table=256, layers=16, write=True)),
+    "paged_decode_bf16_page8_in_place": (_paged_decode, dict(kvh=8, ps=8, write=True)),
     # ragged prefill with quantize-on-write: MHA and GQA x KV storage
     **{
         f"ragged_prefill_{name}_{kv}": (_ragged_prefill, dict(kvh=kvh, bits=bits))
@@ -161,6 +170,11 @@ CASES = {
         _paged_decode, dict(h=64, kvh=4, d=256, dv=128, slots=64, pages=16384, table=512)),
     "paged_decode_keys256_values128_window_kind": (
         _paged_decode, dict(h=64, kvh=8, d=256, dv=128, slots=64, pages=1024, table=512, window=128, sink=True)),
+    "paged_decode_keys256_values128_full_kind_in_place": (
+        _paged_decode, dict(h=64, kvh=4, d=256, dv=128, slots=64, pages=16384, table=512, write=True)),
+    "paged_decode_keys256_values128_window_kind_in_place": (
+        _paged_decode, dict(h=64, kvh=8, d=256, dv=128, slots=64, pages=1024, table=512, window=128, sink=True,
+                            layers=4, write=True)),
     "ragged_prefill_keys256_values128_full_kind": (
         _ragged_prefill, dict(h=64, kvh=4, d=256, dv=128, bt=64, table=512)),
     "ragged_prefill_keys256_values128_window_kind": (
@@ -231,20 +245,97 @@ KERNEL_NAMES = {
     "flash_gqa_32q8kv_fwd_bwd": {"jvp_flash_attn_fwd_", "jvp_flash_attn_dq_", "jvp_flash_attn_dkv_"},
     "ragged_prefill_gqa_32q8kv_bf16": {"ragged_prefill_attn"},
     "paged_decode_bf16_d128_sq1": {"attn"},
+    "paged_decode_serving_cell_in_place": {"attn"},
     "moe_experts_decode_rows": {"moe_experts"},
 }
 
 
-@pytest.mark.parametrize("case", sorted(KERNEL_NAMES))
-def test_kernels_carry_their_names_into_the_hlo(chip, case):
+def _kernel_names(compiled_text: str) -> set:
     import re
 
+    return {re.sub(r"(\.\d+)+$", "", m.group(1))
+            for m in re.finditer(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", compiled_text)}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_NAMES))
+def test_kernels_carry_their_names_into_the_hlo(chip, case):
     build, kw = CASES[case]
     fn, args = build(chip, **kw)
     text = jax.jit(jax.named_scope("attn")(fn)).lower(*args).compile().as_text()
-    names = {re.sub(r"(\.\d+)+$", "", m.group(1))
-             for m in re.finditer(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
-    assert names == KERNEL_NAMES[case]
+    assert _kernel_names(text) == KERNEL_NAMES[case]
+
+
+def _small_model(by_kind: bool):
+    """Widths the paged kernel takes on the chip (128-multiple pages), small
+    enough to build on the CPU: one kind, or a full and a window kind with
+    keys 192 wide (stored padded to 256 lanes), values 128 and a sink."""
+    from accelerate_tpu.models import DecoderConfig, DecoderLM
+
+    common = dict(vocab_size=512, embed_dim=256, num_heads=8, mlp_dim=512, max_seq_len=512, dtype=jnp.bfloat16,
+                  scan_layers=True, remat=False)
+    if not by_kind:
+        return DecoderLM(DecoderConfig(num_layers=3, num_kv_heads=2, head_dim=128, **common))
+    return DecoderLM(DecoderConfig(
+        num_layers=5, head_dim=192, v_head_dim=128, rope_dim=64, attn_value_scale=0.707,
+        layer_kinds=(("full", dict(num_kv_heads=2)),
+                     ("window", dict(num_kv_heads=4, attn_window=32, attn_sink=True, rope_theta=1e4))),
+        layer_pattern=(0, 1, 1, 0, 1), **common))
+
+
+@pytest.mark.parametrize("threading", ["in_place", "split_by_layer"])
+@pytest.mark.parametrize("by_kind", [False, True], ids=["one_kind", "by_kind"])
+def test_the_decode_step_holds_one_arena(chip, monkeypatch, by_kind, threading):
+    """The whole decode step of a small paged engine, compiled for the chip:
+    with the arena carried through the layer scan and written by the kernel,
+    no operation of the program has the stacked arena's or a layer's pages'
+    shape as its result but the kernel itself (no slice out of the stack, no
+    copy to the scatter's layout and back, no update-slice, no scatter), and
+    its temporaries are less than one layer's pages. ``split_by_layer`` is
+    the control, the threading every other call keeps: the same check finds
+    them all there."""
+    import re
+
+    import accelerate_tpu.models.decoder as decoder
+    from accelerate_tpu.parallel.sharding import unbox_params
+    from accelerate_tpu.serving import ServingEngine
+
+    model = _small_model(by_kind)
+    params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
+    params = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if threading == "split_by_layer":
+        monkeypatch.setattr(decoder, "arena_in_place", lambda *a, **k: False)
+    eng = ServingEngine(model, params, num_slots=8, max_cache_len=512, page_size=16, num_pages=1025,
+                        prefix_cache=False, **({"kind_pages": {"window32": 513}} if by_kind else {}))
+    assert eng.metrics()["serving/decode_kernel_active"]
+    S = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    step = jax.jit(eng._step_core, donate_argnums=(1, 2, 3, 5))
+    # the suite compiles with most XLA optimizations off; this is about what they leave
+    unoptimized = jax.config.values["jax_disable_most_optimizations"]
+    jax.config.update("jax_disable_most_optimizations", False)
+    try:
+        compiled = step.lower(*S((eng.params, eng._arena, eng._tokens, eng._lengths, eng._active, eng._rngs,
+                                  eng._tables_arg()))).compile()
+    finally:
+        jax.config.update("jax_disable_most_optimizations", unoptimized)
+    text = compiled.as_text()
+    paged = [x for x in jax.tree_util.tree_leaves(eng._arena) if x.ndim == 5]
+    shapes = {",".join(map(str, shp)) for x in paged for shp in (x.shape, x.shape[1:])}
+    moved = re.compile(r"= \w+\[(%s)\]\S* (copy|copy-start|dynamic-slice|dynamic-update-slice|scatter)\("
+                       % "|".join(shapes))
+    found = sorted({m.group(2) for m in moved.finditer(text)})
+    one_layer = min(x.nbytes // x.shape[0] for x in paged)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert "attn" in _kernel_names(text)  # what decode_attn_roofline_pct finds the kernel by
+    if threading == "in_place":
+        assert eng.metrics()["serving/arena_in_place"] == 1
+        assert not found and temp < one_layer, (found, temp, one_layer)
+    else:
+        assert eng.metrics()["serving/arena_in_place"] == 1  # the engine's own view is not patched
+        # (its temporaries say nothing at this size: the compiler keeps them in VMEM, 128 MiB on a v5e;
+        # at the serving cell's size they are 4.16 GiB against 0.3 MiB: PERF.md, PR 29)
+        assert {"copy", "dynamic-update-slice"} <= set(found), found
 
 
 @pytest.mark.parametrize("outer_manual", [(), ("fsdp", "tensor"), ("fsdp",)],
