@@ -99,8 +99,7 @@ def register(subparsers):
     replica.add_argument("--prefill-chunks", default="16,64",
                          help="comma-separated prefill bucket sizes")
     replica.add_argument("--page-size", type=int, default=16,
-                         help="0 = flat slot arena (no paging, no prefix "
-                              "cache, no KV handoff)")
+                         help="tokens a KV page; must divide the cache length")
     replica.add_argument("--kv-cache-dtype", default=None,
                          choices=["bf16", "int8", "int4"])
     replica.add_argument("--kv-host-entries", type=int, default=0,
@@ -223,12 +222,11 @@ def build_replica_engine(args):
     chunks = tuple(
         int(c) for c in str(args.prefill_chunks).split(",") if c.strip()
     )
-    page_size = int(args.page_size) or None
     kv_tiers = None
     host_entries = int(getattr(args, "kv_host_entries", 0) or 0)
     disk_entries = int(getattr(args, "kv_disk_entries", 0) or 0)
     peers = _parse_replica_flags(getattr(args, "kv_peers", []) or [])
-    if page_size and (host_entries or disk_entries or peers):
+    if host_entries or disk_entries or peers:
         from ..serving.tiers import TierConfig
 
         kv_tiers = TierConfig(
@@ -242,7 +240,7 @@ def build_replica_engine(args):
         num_slots=int(args.num_slots),
         max_cache_len=args.max_cache_len,
         prefill_chunks=chunks,
-        page_size=page_size,
+        page_size=int(args.page_size),
         temperature=float(args.temperature),
         top_k=args.top_k,
         steps_per_call=int(args.steps_per_call),

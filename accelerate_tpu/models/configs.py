@@ -79,7 +79,7 @@ class DecoderConfig:
     # [B, KVH, max_cache_len, D] arena — the slot's KV footprint tracks its
     # actual length, and pages can be shared copy-on-write across slots
     # (prefix cache). Only the slot-arena decode path supports paging;
-    # prefill runs against dense per-slot gather views the engine builds.
+    # prefill is the packed ragged dispatch over the same pages.
     kv_page_size: Optional[int] = None   # tokens per page, power of two
     kv_num_pages: Optional[int] = None   # physical pages in the arena
     # KV-cache storage precision (utils/quantization.quantize_kv /
@@ -90,14 +90,13 @@ class DecoderConfig:
     # nothing ever re-quantizes and preempt/resume/prefix-hit round-trips
     # are drift-free). Reads dequantize in-register inside the pallas
     # decode kernels (HBM decode traffic shrinks 2-4x) or as the fused
-    # astype*scale of the masked-dense reference. Applies to both the
-    # dense slot arena and the paged arena.
+    # astype*scale of the masked-dense reference. Applies to both
+    # generate()'s dense cache and the paged arena.
     kv_cache_dtype: str = "bf16"
     # decode-attention implementation for the KV-cache decode paths
-    # (ops/attention dispatch). None -> the ATT_DECODE_KERNEL env knob
-    # (default "paged": the length-aware pallas decode kernel on TPU —
-    # HBM read ∝ live tokens — with a warn-once masked-dense fallback
-    # elsewhere); "dense" forces the masked-dense reference path;
+    # (ops/attention dispatch). None -> "paged": the length-aware pallas
+    # decode kernel on TPU — HBM read ∝ live tokens — with a warn-once
+    # masked-dense fallback elsewhere; "dense" forces the masked-dense reference path;
     # "interpret" runs the same kernel through the pallas interpreter
     # (the CPU test/CI mode). ``decode_kernel_block`` tunes the
     # dense-arena kernel's kv block size (must divide the cache length;
@@ -106,11 +105,10 @@ class DecoderConfig:
     decode_kernel_block: Optional[int] = None
     # prefill-attention implementation for the packed ragged prefill over
     # the paged arena (ops/attention.ragged_prefill_attention). None ->
-    # the ATT_PREFILL_KERNEL env knob (default "ragged": the flash
-    # online-softmax pallas kernel on TPU — one dispatch packs every
-    # pending admission tail, prefix pages already in the arena are
-    # skipped at the block level — with a warn-once dense fallback
-    # elsewhere); "dense" forces the reference path (the bit-exactness
+    # "ragged": the flash online-softmax pallas kernel on TPU — one
+    # dispatch packs every pending admission tail, prefix pages already
+    # in the arena are skipped at the block level — with a warn-once
+    # dense fallback elsewhere; "dense" forces the reference path (the bit-exactness
     # oracle); "interpret" runs the same kernel through the pallas
     # interpreter (the CPU test/CI mode). ``prefill_kernel_block`` tunes
     # the token-block granule rows are packed to (default 8).

@@ -148,7 +148,8 @@ class DecoderAttention(nn.Module):
     cache is [B, KVH, max_cache_len, D] — static shapes, so the whole decode
     loop compiles once.
 
-    ``cache_positions`` ([B] or [B, S] int32, decode-only) switches the
+    ``cache_positions`` ([B] or [B, S] int32, decode-only, a paged cache
+    only: ``config.kv_page_size`` with a ``page_table``) switches the
     cache to slot-arena semantics (``serving/``): each batch row is an
     independent request whose new K/V lands at its OWN offset(s) and whose
     attention sees only its own prefix — admission/eviction become pure
@@ -165,8 +166,8 @@ class DecoderAttention(nn.Module):
     read (``ops/attention.paged_decode_attention``) walks only the slot's
     LIVE pages via the pallas decode kernel on TPU — HBM traffic per step
     is live tokens, not the arena reservation — falling back to the
-    gather + masked-dense reference elsewhere (``config.decode_kernel`` /
-    ``ATT_DECODE_KERNEL``). Sharing one physical page across slots'
+    gather + masked-dense reference elsewhere (``config.decode_kernel``).
+    Sharing one physical page across slots'
     tables is copy-on-write prefix sharing; the serving engine forks
     pages before divergent writes. ``kv_lengths`` ([B] int32, optional)
     is each slot's count of live tokens, the bound of that kernel's walk
@@ -191,12 +192,11 @@ class DecoderAttention(nn.Module):
     every pending admission's tail — row r is token ``cache_positions[0,
     r]`` of slot ``ragged_slots[r]`` (-1 = token-block padding) — and the
     flash prefill kernel (``ops/attention.ragged_prefill_attention``,
-    ``config.prefill_kernel`` / ``ATT_PREFILL_KERNEL``) attends each row
+    ``config.prefill_kernel``) attends each row
     against its slot's live arena prefix plus the packed fresh rows,
     with quantize-on-write fused so the page-table scatter lands the
-    kernel's payload+scales directly. One dispatch replaces the per-slot
-    bucketed chunk programs; padding waste drops from bucket-size to
-    token-block granularity.
+    kernel's payload+scales directly. One dispatch carries every pending
+    tail; padding is token-block granularity.
 
     ``causal=False`` (+ optional ``kv_mask``) is the bidirectional form the
     seq2seq encoder reuses (models/seq2seq.py) — same projections, RoPE and
@@ -314,9 +314,14 @@ class DecoderAttention(nn.Module):
                 raise NotImplementedError(
                     "a paged KV cache (config.kv_page_size) supports only "
                     "slot-arena decode (decode=True with cache_positions "
-                    "and page_table); prefill runs either as the packed "
-                    "ragged dispatch (ragged_slots/slot_hist) or against "
-                    "dense per-slot gather views built by serving/pages.py"
+                    "and page_table); prefill runs as the packed "
+                    "ragged dispatch (ragged_slots/slot_hist)"
+                )
+            if cache_positions is not None and not paged:
+                raise NotImplementedError(
+                    "cache_positions (slot-arena decode) requires the paged "
+                    "KV arena (config.kv_page_size with a page_table); a "
+                    "dense cache decodes one stream at its scalar cache_index"
                 )
             if not self.decode:
                 # prefill: cache starts at 0, so plain causal attention over
@@ -324,7 +329,7 @@ class DecoderAttention(nn.Module):
                 # Quantized: store payload+scale and attend over the
                 # DEQUANTIZED values — the stored cache is the source of
                 # truth, so whole-prompt prefill stays token-identical to
-                # the chunked prefill path (which reads the cache back).
+                # the engine's packed prefill (which reads the cache back).
                 if kvq_bits:
                     from ..utils.quantization import dequantize_kv, quantize_kv
 
@@ -405,10 +410,10 @@ class DecoderAttention(nn.Module):
                 # slot-arena decode (serving/): every batch row writes its
                 # new K/V at its own per-slot offset(s) and attends only
                 # its own prefix. Stale entries past a slot's frontier
-                # (previous occupant, bucketed-prefill padding, rolled-back
-                # speculative drafts) are always overwritten at the write
-                # position BEFORE being attended, so neither slot reuse nor
-                # speculative rollback needs any cache clearing.
+                # (previous occupant, rolled-back speculative drafts) are
+                # always overwritten at the write position BEFORE being
+                # attended, so neither slot reuse nor speculative rollback
+                # needs any cache clearing.
                 pos2d = (
                     cache_positions[:, None]
                     if cache_positions.ndim == 1 else cache_positions
@@ -434,23 +439,20 @@ class DecoderAttention(nn.Module):
                 # length-aware kernel on TPU / under "interpret", the
                 # masked-dense reference otherwise. getattr: Seq2SeqConfig
                 # reuses this module without the decode_kernel fields.
+                from ..ops.attention import paged_decode_attention
+
                 dk_impl = getattr(cfg, "decode_kernel", None)
-                dk_blk = getattr(cfg, "decode_kernel_block", None)
-                if paged and cache_layer is not None:
+                if cache_layer is not None:
                     # the arena in place (arena_in_place): the cache leaves
                     # are the layers' stacks, carried through the scan, and
                     # the kernel writes this layer's new rows itself
-                    from ..ops.attention import paged_decode_attention
-
                     out, cached_k.value, cached_v.value = paged_decode_attention(
                         q, cached_k.value, cached_v.value,
                         page_table=page_table, q_positions=pos2d,
                         kv_lengths=kv_lengths, impl=dk_impl,
                         layer=cache_layer, k_new=k, v_new=v, **extras,
                     )
-                elif paged:
-                    from ..ops.attention import paged_decode_attention
-
+                else:
                     ps = cfg.kv_page_size
                     page = page_table[rows[:, None], pos2d // ps]  # [B, S]
                     off = pos2d % ps
@@ -470,25 +472,6 @@ class DecoderAttention(nn.Module):
                         q, k_pages, v_pages,
                         page_table=page_table, q_positions=pos2d,
                         kv_lengths=kv_lengths, impl=dk_impl, **scale_kw, **extras,
-                    )
-                else:
-                    from ..ops.attention import decode_attention
-
-                    k_full = cached_k.value.at[rows[:, None], :, pos2d].set(kv_new)
-                    v_full = cached_v.value.at[rows[:, None], :, pos2d].set(vv_new)
-                    cached_k.value = k_full
-                    cached_v.value = v_full
-                    scale_kw = {}
-                    if kvq_bits:
-                        k_sc = cached_ks.value.at[rows[:, None], :, pos2d].set(ks_new)
-                        v_sc = cached_vs.value.at[rows[:, None], :, pos2d].set(vs_new)
-                        cached_ks.value = k_sc
-                        cached_vs.value = v_sc
-                        scale_kw = {"k_scale": k_sc, "v_scale": v_sc,
-                                    "kv_quant_bits": kvq_bits}
-                    out = decode_attention(
-                        q, k_full, v_full, q_positions=pos2d,
-                        impl=dk_impl, block_kv=dk_blk, **scale_kw, **extras,
                     )
             else:
                 scale_kw = {}
@@ -511,17 +494,13 @@ class DecoderAttention(nn.Module):
                 from ..ops.attention import decode_attention
 
                 # query i sits at global position cur+i; valid kv = [0, cur+i].
-                # s == 1 is the single-stream decode loop — same kernel
+                # The single-stream decode loop (s == 1): the same kernel
                 # dispatch as the slot-arena path, so generation.generate
-                # reads live tokens, not the whole right-sized arena, per
-                # step. s > 1 on this branch is ALWAYS a prefill chunk
-                # (serving's bucketed admission against a slot view):
-                # force the masked-dense reference there regardless of the
-                # bucket size, so chunked prefill stays bit-identical to
-                # the full-prefill path token-exactness is proven against.
+                # reads live tokens, not the whole right-sized cache, per
+                # step.
                 out = decode_attention(
                     q, k_full, v_full, q_positions=cur + jnp.arange(s),
-                    impl=getattr(cfg, "decode_kernel", None) if s == 1 else "dense",
+                    impl=getattr(cfg, "decode_kernel", None),
                     block_kv=getattr(cfg, "decode_kernel_block", None),
                     **scale_kw, **extras,
                 )

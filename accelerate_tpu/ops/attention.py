@@ -27,8 +27,8 @@ Capabilities:
   blocks — straight from the physical page arena through the slot's page
   table, or in fixed blocks over a dense arena — so decode HBM traffic
   scales with live tokens, not arena capacity. Masked-dense stays the
-  fallback + bit-exactness reference (`ATT_DECODE_KERNEL=paged|dense`,
-  "interpret" for CPU tests). The paged kernel takes the layers' stacked
+  fallback + bit-exactness reference (`DecoderConfig.decode_kernel`
+  `paged|dense`, "interpret" for CPU tests). The paged kernel takes the layers' stacked
   arena and a layer index and, in a decode step, writes each slot's new
   row into its page itself, the stack aliased to its output: the step's
   cache write is no XLA scatter
@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -647,7 +646,7 @@ def flash_attention_bwd(
 # blocks over a (slots × kv-heads × kv-blocks) grid, blocks past a slot's
 # frontier clamped in the BlockSpec index map (the pipeline elides the
 # re-fetch) and skipped by ``pl.when`` — the same win for the
-# single-stream decode loop and the flat slot arena. GQA folds the query
+# single-stream decode loop. GQA folds the query
 # head group (× the Sq query rows: the multi-query form spec_verify and
 # fused bursts use) into one [group*Sq, D] block per kv head, so K/V are
 # never expanded.
@@ -664,14 +663,13 @@ _decode_fallback_warned: set = set()
 
 def resolve_decode_kernel(impl: Optional[str] = None) -> str:
     """Resolve the decode-attention implementation choice: the explicit
-    ``impl`` (``DecoderConfig.decode_kernel``) wins, else the
-    ``ATT_DECODE_KERNEL`` env knob, else ``"paged"`` (the kernel, with a
-    warn-once dense fallback off-TPU). ``"interpret"`` runs the same kernel
-    through the pallas interpreter — the CPU test/CI mode."""
-    mode = impl or os.environ.get("ATT_DECODE_KERNEL", "paged")
+    ``impl`` (``DecoderConfig.decode_kernel``), else ``"paged"`` (the
+    kernel, with a warn-once dense fallback off-TPU). ``"interpret"`` runs
+    the same kernel through the pallas interpreter — the CPU test/CI mode."""
+    mode = impl or "paged"
     if mode not in _DECODE_KERNEL_MODES:
         raise ValueError(
-            f"ATT_DECODE_KERNEL/decode_kernel must be one of "
+            f"decode_kernel must be one of "
             f"{_DECODE_KERNEL_MODES}, got {mode!r}"
         )
     return mode
@@ -695,8 +693,8 @@ def _warn_decode_fallback(reason: str):
         reason,
         "paged decode-attention kernel unavailable (%s); falling back to "
         "the masked-dense read — decode HBM traffic will scale with the "
-        "arena reservation, not live tokens. Set ATT_DECODE_KERNEL=dense "
-        "(or DecoderConfig.decode_kernel='dense') to silence, or "
+        "arena reservation, not live tokens. Set "
+        "DecoderConfig.decode_kernel='dense' to silence, or "
         "'interpret' to run the kernel through the pallas interpreter.",
         reason,
     )
@@ -1427,8 +1425,7 @@ def decode_attention(
     Dispatch: at decode widths (Sq <= 16) the length-aware pallas kernel
     reads only the live kv blocks (HBM traffic ∝ live tokens, not L) on
     TPU — or through the interpreter under ``impl='interpret'`` — per
-    :func:`resolve_decode_kernel` (``impl`` / ``ATT_DECODE_KERNEL``,
-    default "paged" with a warn-once dense fallback off-TPU). Prefill-size
+    :func:`resolve_decode_kernel` (``impl``, default "paged" with a warn-once dense fallback off-TPU). Prefill-size
     chunks and the ``dense`` mode run the masked-dense XLA path, which
     stays the bit-exactness reference. ``block_kv`` tunes the kernel's kv
     block (must divide L; default: largest of 512..16 that does).
@@ -1538,8 +1535,8 @@ def paged_decode_attention(
     each slot's live pages DIRECTLY from the physical arena — the HBM read
     per step is the slot's live tokens (page-rounded), not its whole
     ``P * page_size`` reservation, which is the decode-bandwidth lever at
-    high occupancy with mixed lengths. Otherwise (``impl='dense'`` /
-    ``ATT_DECODE_KERNEL=dense`` / no TPU backend — warn-once) the
+    high occupancy with mixed lengths. Otherwise (``impl='dense'`` / no
+    TPU backend — warn-once) the
     gather maps each slot's pages back into position order and the read is
     exactly :func:`decode_attention`'s masked-dense path: the CPU-sim
     fallback and the bit-exactness reference the kernel is asserted
@@ -1667,15 +1664,14 @@ def prefill_token_block(capacities) -> int:
 
 def resolve_prefill_kernel(impl: Optional[str] = None) -> str:
     """Resolve the prefill-attention implementation choice: the explicit
-    ``impl`` (``DecoderConfig.prefill_kernel``) wins, else the
-    ``ATT_PREFILL_KERNEL`` env knob, else ``"ragged"`` (the packed pallas
-    kernel, with a warn-once chunked-dense fallback off-TPU).
+    ``impl`` (``DecoderConfig.prefill_kernel``), else ``"ragged"`` (the
+    packed pallas kernel, with a warn-once dense fallback off-TPU).
     ``"interpret"`` runs the same kernel through the pallas interpreter —
     the CPU test/CI mode, so tier-1 asserts the identical kernel."""
-    mode = impl or os.environ.get("ATT_PREFILL_KERNEL", "ragged")
+    mode = impl or "ragged"
     if mode not in _PREFILL_KERNEL_MODES:
         raise ValueError(
-            f"ATT_PREFILL_KERNEL/prefill_kernel must be one of "
+            f"prefill_kernel must be one of "
             f"{_PREFILL_KERNEL_MODES}, got {mode!r}"
         )
     return mode
@@ -1683,16 +1679,15 @@ def resolve_prefill_kernel(impl: Optional[str] = None) -> str:
 
 def _warn_prefill_fallback(reason: str):
     """Warn-once per distinct reason: the ragged prefill kernel was
-    requested (or defaulted) but this process resolves to the chunked
-    dense prefill path — admissions pay bucket padding and the per-chunk
-    gather/scatter round-trip."""
+    requested (or defaulted) but this process runs the packed dispatch on
+    its dense reference, which gathers each slot's whole cache."""
     _warn_once(
         "prefill:" + reason,
-        "ragged prefill kernel unavailable (%s); admissions resolve to "
-        "the chunked dense prefill path — TTFT pays bucket padding and a "
-        "gather/scatter round-trip per chunk. Set ATT_PREFILL_KERNEL="
-        "dense (or DecoderConfig.prefill_kernel='dense') to silence, or "
-        "'interpret' to run the kernel through the pallas interpreter.",
+        "ragged prefill kernel unavailable (%s); the packed prefill "
+        "dispatch runs its dense reference, which gathers each slot's "
+        "whole cache reservation. Set DecoderConfig.prefill_kernel="
+        "'dense' to silence, or 'interpret' to run the kernel through "
+        "the pallas interpreter.",
         reason,
     )
 
@@ -2191,10 +2186,9 @@ def ragged_prefill_attention(
     v_scale)`` — payloads token-major [CAP, KVH, pd] ready for one arena
     scatter (scales None unquantized; payloads then pass through k_new/
     v_new). Dispatch mirrors the decode kernel's:
-    :func:`resolve_prefill_kernel` (``impl`` / ``ATT_PREFILL_KERNEL``,
-    default "ragged" with a warn-once dense fallback off-TPU,
-    "interpret" for CPU tests); the chunked-dense reference stays the
-    bit-exactness oracle.
+    :func:`resolve_prefill_kernel` (``impl``, default "ragged" with a
+    warn-once dense fallback off-TPU, "interpret" for CPU tests); the
+    dense reference stays the bit-exactness oracle.
 
     The values (``v_new``, ``v_pages``) may be narrower than the keys; the
     output has their width. ``window``: a row at position p sees positions

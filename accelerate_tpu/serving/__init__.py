@@ -1,6 +1,6 @@
-"""Continuous-batching serving: slot-arena KV cache (flat or paged with a
-copy-on-write prefix cache), chunked prefill admission, donated in-place
-batched decode, and speculative decoding (docs/serving.md).
+"""Continuous-batching serving: a paged KV cache with a copy-on-write
+prefix cache, packed prefill admission, donated in-place batched decode,
+and speculative decoding (docs/serving.md).
 
 PEP 562 lazy re-exports: ``serving.pages`` is host-side bookkeeping
 (free lists, refcounts, prefix hashing, the n-gram drafter) that a
@@ -9,9 +9,7 @@ importing it must not drag the jax-heavy engine in (tests/test_imports).
 """
 
 _EXPORTS = {
-    "arena_nbytes": "arena",
-    "arena_num_slots": "arena",
-    "init_arena": "arena",
+    "arena_nbytes": "pages",
     "Request": "engine",
     "ServingEngine": "engine",
     "generate_batched": "engine",

@@ -17,8 +17,8 @@ Two complementary measurements (docs/serving.md "Quantized KV cache"):
   the cascade, so this isolates the per-step numeric cost of quantized
   storage — the number that should stay stable as generations get longer.
 
-The harness is what the bench's ``kv_quant_token_match_rate`` row and the
-tier-1 drift tests (tests/test_kv_quant.py) run; point it at a real model
+The harness is what the tier-1 drift tests (tests/test_kv_quant.py) run;
+point it at a real model
 via ``kv_quant_drift(definition, params, prompts, ...)`` when generation
 quality looks degraded after enabling a quantized arena
 (docs/troubleshooting.md has the recipe).
@@ -73,7 +73,6 @@ def kv_quant_drift(
     temperature: float = 0.0,
     top_k: Optional[int] = None,
     seeds=None,
-    page_size: Optional[int] = None,
     num_slots: Optional[int] = None,
     max_cache_len: Optional[int] = None,
     prefill_chunks=None,
@@ -97,15 +96,10 @@ def kv_quant_drift(
                                per-chip multiplier at equal budget),
         }
 
-    ``page_size`` selects the paged arena (what production serves);
-    omitted, the flat slot arena is measured — drift is storage-precision
-    math either way, and the tests assert flat == paged token-exactly.
-
     The result also carries a ``"baseline"`` dict (the bf16 streams +
     arena bytes). Pass it back via ``baseline=`` on a second call with
     the SAME prompts/seeds/engine shape to compare another
-    ``kv_cache_dtype`` without rebuilding and re-running the bf16 engine
-    — the bench compares int8 and int4 against one baseline this way.
+    ``kv_cache_dtype`` without rebuilding and re-running the bf16 engine.
     """
     from .engine import ServingEngine
     from .pages import kv_cache_bits
@@ -122,8 +116,6 @@ def kv_quant_drift(
         prefill_chunks=tuple(sorted(set(chunks))),
         temperature=temperature, top_k=top_k, **engine_kwargs,
     )
-    if page_size:
-        kw["page_size"] = page_size
 
     def run(kvq):
         engine = ServingEngine(definition, params, kv_cache_dtype=kvq, **kw)

@@ -3,29 +3,31 @@
 ``generation.generate()`` is one prompt -> one prefill -> one private
 decode loop; a server with N concurrent users would run N of those
 serially and waste (N-1)/N of every decode step's HBM bandwidth. This
-module decodes **many requests per device step** against one slot-arena
-KV cache and admits/evicts requests with no shape change, so a live
-engine never recompiles:
+module decodes **many requests per device step** against one paged KV
+cache and admits/evicts requests with no shape change, so a live engine
+never recompiles:
 
-- **slot-based batched KV cache** (``arena.py``) — the model's "cache"
-  collection at batch = num_slots, plus a per-slot ``lengths`` vector.
-  Admission writes a slot, eviction is host bookkeeping.
+- **paged slot KV cache** (``pages.py``) — the model's "cache" collection
+  as fixed-size physical pages, a page table a slot, and a per-slot
+  ``lengths`` vector. Admission maps and writes pages, eviction is host
+  bookkeeping.
 - **fused batched decode step** — ONE jitted fn
-  ``(params, arena, last_tokens, lengths, active, rngs)`` with the arena
-  (and the per-slot state vectors) **donated**, so the multi-hundred-MB
-  cache updates in place instead of doubling HBM per step. The paged
-  arena also stays whole inside the step where the decode kernel serves
-  it (``models/decoder.arena_in_place``; the ``arena_in_place`` gauge).
-- **chunked prefill admission** — new prompts prefill in fixed-size
-  bucketed chunks, one chunk per scheduler iteration, *interleaved*
-  between decode steps: a 10k-token prompt never stalls in-flight decodes
-  for more than one chunk's worth of compute.
+  ``(params, arena, last_tokens, lengths, active, rngs, page_tables)``
+  with the arena (and the per-slot state vectors) **donated**, so the
+  multi-hundred-MB cache updates in place instead of doubling HBM per
+  step. The arena also stays whole inside the step where the decode
+  kernel serves it (``models/decoder.arena_in_place``; the
+  ``arena_in_place`` gauge).
+- **packed prefill admission** — the pending prompts' tails are packed
+  into one ragged dispatch of a fixed grid capacity per scheduler
+  iteration, *interleaved* between decode steps: a 10k-token prompt never
+  stalls in-flight decodes for more than one grid's worth of compute.
 - **host-side scheduler** (``ServingEngine``) — request queue, slot
   allocator, per-request token-stream callbacks, serving metrics through
   the runtime telemetry pipeline.
 
 Token-exactness: batched decode reuses the exact sampling helpers and the
-exact masked-attention path (``ops/attention.decode_attention``) the
+exact masked-attention reference (``ops/attention.decode_attention``) the
 single-stream loop uses, with per-request RNG chains split identically —
 so ``generate_batched()`` output is token-for-token equal to sequential
 ``generate()`` calls with the same per-request seeds (tests/test_serving).
@@ -49,25 +51,22 @@ from ..telemetry.spans import emit as _emit_span
 from ..telemetry.spans import span as _span
 from ..models.decoder import MOE_LOAD, arena_in_place
 from ..ops.attention import (
-    _PREFILL_TOKEN_BLOCK,
     decode_kernel_active,
     paged_decode_block_pages,
     prefill_kernel_active,
     prefill_token_block,
 )
-from .arena import arena_nbytes, init_arena, slot_view, write_slot
 from .pages import (
     NGramDrafter,
     CacheKind,
     PrefixCache,
-    dense_slot_view,
+    arena_nbytes,
     fork_page,
     gather_page,
     init_paged_arena,
     install_page,
     kv_cache_bits,
     kv_token_bytes,
-    scatter_slot_view,
     set_table_entry,
     set_table_row,
 )
@@ -150,10 +149,10 @@ class Request:
     kv_restore_tier: Optional[str] = None
     kv_restore_ms: float = 0.0
     kv_restore_pages: int = 0
-    # which prefill path admitted this request: "ragged" (the packed
-    # flash prefill kernel / its interpreter) or "dense" (bucketed
-    # chunks) — the waterfall's prefill stage annotates kernel-vs-dense
-    # from this field on the request record
+    # what attended this request's packed prefill dispatches: "ragged"
+    # (the flash prefill kernel / its interpreter) or "dense" (the
+    # kernel's dense reference) — the waterfall's prefill stage annotates
+    # kernel-vs-dense from this field on the request record
     prefill_kernel: Optional[str] = None
 
     def result(self) -> np.ndarray:
@@ -179,12 +178,14 @@ class ServingEngine:
     the prompt, ``max_new_tokens``, the RNG seed, and the streaming
     callback.
 
-    ``page_size`` switches the KV storage to the **paged arena**
-    (``pages.py``): fixed-size pages + per-slot page tables instead of a
-    dense ``num_slots x max_cache_len`` block, with ``num_pages`` physical
-    pages (default: capacity-equivalent to the flat arena plus the parking
-    page; set it lower to overcommit — more slots per HBM byte when real
-    lengths are below ``max_cache_len``). With ``prefix_cache`` on,
+    The KV storage is the **paged arena** (``pages.py``): pages of
+    ``page_size`` tokens (it must divide ``max_cache_len``) + per-slot
+    page tables, with ``num_pages`` physical pages (default: every slot
+    can reach ``max_cache_len``, plus the parking page; set it lower to
+    overcommit — more slots per HBM byte when real lengths are below
+    ``max_cache_len``). ``prefill_chunks`` gives the packed prefill
+    dispatch its grid capacities (each rounded up to the token block) and
+    the admit plan its unit. With ``prefix_cache`` on,
     admissions whose prompt prefix is cached map the shared pages
     (copy-on-write) and prefill only the tail. ``spec_draft_len=K`` adds
     speculative decoding: the host-side ``drafter`` (default
@@ -206,12 +207,11 @@ class ServingEngine:
     zero-recompile invariant are unchanged — quantization is a cache-leaf
     dtype, not a program shape.
 
-    The decode step and every prefill-chunk bucket compile exactly once;
+    The decode step and every packed-prefill grid capacity compile exactly once;
     after ``mark_steady()`` the ``admission_recompiles`` property must
     stay 0 no matter what traffic arrives — admissions, prefix hits, page
     forks and speculative verify steps are all pure data changes — the
-    recompile invariant the bench (`serving_admission_recompiles`) and
-    tests assert.
+    recompile invariant the tests assert.
     """
 
     def __init__(
@@ -229,7 +229,7 @@ class ServingEngine:
         param_placer=None,
         donate: Optional[bool] = None,
         telemetry=None,
-        page_size: Optional[int] = None,
+        page_size: int = 16,
         num_pages: Optional[int] = None,
         prefix_cache: bool = True,
         prefix_max_entries: Optional[int] = None,
@@ -259,8 +259,8 @@ class ServingEngine:
             )
         # KV-cache storage precision: the engine knob wins, else whatever
         # the config already carries. Cloning the definition here (before
-        # cache sizing) makes every program this engine compiles — prefill
-        # buckets against slot views, the fused decode step, spec verify —
+        # cache sizing) makes every program this engine compiles — the
+        # packed prefill, the fused decode step, spec verify —
         # create/consume the quantized payload + scale cache leaves.
         kvq = kv_cache_dtype or getattr(cfg, "kv_cache_dtype", "bf16") or "bf16"
         kv_cache_bits(kvq)  # validate early (raises on typos)
@@ -300,13 +300,13 @@ class ServingEngine:
         )
 
         # -- paged arena / prefix cache / speculative decoding -------------
-        self.page_size = int(page_size) if page_size else None
-        self.spec_k = max(0, int(spec_draft_len))
-        if self.spec_k and not self.page_size:
+        if not page_size:
             raise ValueError(
-                "speculative decoding (spec_draft_len > 0) requires the "
-                "paged arena; pass page_size=..."
+                "ServingEngine: the flat slot arena is gone; the KV cache is "
+                f"paged and page_size must be a positive integer, got {page_size!r}"
             )
+        self.page_size = int(page_size)
+        self.spec_k = max(0, int(spec_draft_len))
         # a model that states layer kinds, a window, a sink or experts runs
         # on the paged arena's normal path only; what cannot yet be right
         # for it refuses here, by the feature's name, and never runs
@@ -316,7 +316,6 @@ class ServingEngine:
             or getattr(mcfg, "attn_sink", False) or getattr(mcfg, "moe_num_experts", 0) > 1)
         if self._by_kind:
             refused = {
-                "the flat slot arena (pass page_size)": not self.page_size,
                 "prefix_cache (page sharing across a window kind)": bool(prefix_cache),
                 "kv_tiers": kv_tiers is not None,
                 "preemption by page-out and restore (scheduler.config.preemption)": (
@@ -340,143 +339,126 @@ class ServingEngine:
             if hasattr(mcfg, "run_configs") else []
         self._expert_layers = sum(c.num_layers for c in moe_runs)
         self._pairs_per_token = sum(c.num_layers * c.moe_top_k for c in moe_runs)
-        if self.page_size:
-            if self.max_cache_len % self.page_size:
-                raise ValueError(
-                    f"page_size ({self.page_size}) must divide max_cache_len "
-                    f"({self.max_cache_len})"
-                )
-            self.pages_per_slot = self.max_cache_len // self.page_size
-            # default: capacity-equivalent to the flat arena (+ the parking
-            # page). Overcommit by passing a smaller num_pages.
-            self.num_pages = (
-                int(num_pages) if num_pages
-                else 1 + self.num_slots * self.pages_per_slot
+        if self.max_cache_len % self.page_size:
+            raise ValueError(
+                f"page_size ({self.page_size}) must divide max_cache_len "
+                f"({self.max_cache_len})"
             )
-            if self.num_pages < 2:
-                raise ValueError(f"num_pages ({self.num_pages}) must be >= 2")
-            # the packed prefill dispatch's token block follows from the
-            # capacities compiled for it, unless the config names one;
-            # the model hands the same block to the kernel
-            self._ragged_bt = int(
-                getattr(definition.config, "prefill_kernel_block", None)
-                or prefill_token_block(self.prefill_chunks)
-            )
-            self._paged_def = definition.clone(config=self._paged_config(
-                definition.config, kind_pages or {}))
-            # which state each layer kind keeps and how it is paged
-            # (pages.CacheKind): a pool, a table a slot, the window's rule.
-            # One kind: the allocator, tables and counts of before.
-            self._kinds = self._cache_kinds(self._paged_def.config)
-            self._allocator = self._kinds[0].allocator
-            self._tables_host = self._kinds[0].tables
-            # hierarchical KV tiering (serving/tiers.py): demote-on-evict
-            # host/disk/peer store under the prefix cache. A TierConfig
-            # builds the store here (wired to the usage byte-seconds hook
-            # and this replica's identity); a prebuilt TieredStore is
-            # taken as-is; None = tiering off (evictions drop, as before)
-            if isinstance(kv_tiers, TierConfig):
-                self._tiers = TieredStore(
-                    kv_tiers, page_size=self.page_size,
-                    kv_cache_dtype=self.kv_cache_dtype,
-                    replica=replica, on_bytes=self._note_tier_bytes,
-                )
-            else:
-                self._tiers = kv_tiers
-                if self._tiers is not None and self._tiers.on_bytes is None:
-                    self._tiers.on_bytes = self._note_tier_bytes
-            tier_entries = (
-                self._tiers.config.entry_capacity() if self._tiers else 0
-            )
-            prefix_entries = (
-                int(prefix_max_entries) if prefix_max_entries else 512
-            )
-            self._prefix = (
-                PrefixCache(
-                    self._allocator, self.page_size,
-                    max_entries=prefix_entries,
-                    # tier-aware ghost shadows: headroom beyond the new
-                    # TOTAL (HBM+host+disk) capacity
-                    ghost_base_entries=(
-                        prefix_entries + tier_entries if tier_entries else None
-                    ),
-                    on_evict=(
-                        self._demote_entry if self._tiers is not None else None
-                    ),
-                )
-                if prefix_cache else None
-            )
-            self._drafter = drafter or (NGramDrafter() if self.spec_k else None)
-            self._arena = init_paged_arena(
-                self._paged_def, params, self.num_slots, self.pages_per_slot,
-                self._placer,
-                kinds=[k.name for k in self._kinds] if len(self._kinds) > 1 else None,
-            )
-            # whether the decode step rides the paged pallas kernel (the
-            # serving/decode_kernel_active gauge): in every layer kind
-            pcfg = self._paged_def.config
-            run_cfgs = pcfg.run_configs()
-            self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
-            # ... and whether that step updates the arena in place, the
-            # stacked leaves carried through the layer scan (the
-            # arena_in_place gauge and count of serving/decode_dispatch)
-            self._arena_in_place = all(arena_in_place(c) for c in run_cfgs)
-            self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
-            # packed ragged prefill (ops/attention.ragged_prefill_attention):
-            # when the flash prefill kernel (or its interpreter) engages,
-            # the admission planner packs every pending tail into ONE
-            # ragged dispatch per scheduler iteration — token-block
-            # padding only — instead of per-slot bucketed chunks. The
-            # chunked path stays compiled as the fallback/oracle. A model
-            # by kind always packs: where the kernel does not engage, the
-            # packed dispatch runs its dense reference.
-            self._prefill_kernel_costed = all(prefill_kernel_active(c) for c in run_cfgs)
-            self._ragged_prefill = self._prefill_kernel_costed or self._by_kind
-            rb = self._ragged_bt
-            # fixed grid capacities compiled at warmup (the zero-recompile
-            # invariant): each chunk bucket rounded up to the token block,
-            # deduped. The packer picks the smallest capacity that fits
-            # the round's packed tails.
-            self._ragged_caps = tuple(sorted(
-                {-(-int(c) // rb) * rb for c in self.prefill_chunks}
-            ))
-            for kind in self._kinds:
-                kind.device_tables = jnp.zeros(
-                    (self.num_slots, self.pages_per_slot), jnp.int32
-                )
-            table_donate = (0,) if self._donate else ()
-            self._set_row = jax.jit(set_table_row, donate_argnums=table_donate)
-            self._set_entry = jax.jit(set_table_entry, donate_argnums=table_donate)
-            self._fork = jax.jit(
-                fork_page, donate_argnums=(0,) if self._donate else ()
-            )
-            # KV-handoff import write (one page per dispatch, traced dst)
-            self._install_page = jax.jit(
-                install_page, donate_argnums=(0,) if self._donate else ()
-            )
-            # demote-on-evict read: install_page's mirror, traced src —
-            # one compiled program gathers any page, so post-steady
-            # demotions never recompile (gather_pages' per-call id list
-            # would compile per distinct page count)
-            self._gather_page = jax.jit(gather_page)
-            self._verify_step = (
-                jax.jit(self._build_verify_core(),
-                        donate_argnums=(1, 2, 4, 6) if self._donate else ())
-                if self.spec_k else None
+        self.pages_per_slot = self.max_cache_len // self.page_size
+        # default: every slot can reach max_cache_len (+ the parking
+        # page). Overcommit by passing a smaller num_pages.
+        self.num_pages = (
+            int(num_pages) if num_pages
+            else 1 + self.num_slots * self.pages_per_slot
+        )
+        if self.num_pages < 2:
+            raise ValueError(f"num_pages ({self.num_pages}) must be >= 2")
+        # the packed prefill dispatch's token block follows from the
+        # capacities compiled for it, unless the config names one;
+        # the model hands the same block to the kernel
+        self._ragged_bt = int(
+            getattr(definition.config, "prefill_kernel_block", None)
+            or prefill_token_block(self.prefill_chunks)
+        )
+        self._paged_def = definition.clone(config=self._paged_config(
+            definition.config, kind_pages or {}))
+        # which state each layer kind keeps and how it is paged
+        # (pages.CacheKind): a pool, a table a slot, the window's rule.
+        # One kind: the allocator, tables and counts of before.
+        self._kinds = self._cache_kinds(self._paged_def.config)
+        self._allocator = self._kinds[0].allocator
+        self._tables_host = self._kinds[0].tables
+        # hierarchical KV tiering (serving/tiers.py): demote-on-evict
+        # host/disk/peer store under the prefix cache. A TierConfig
+        # builds the store here (wired to the usage byte-seconds hook
+        # and this replica's identity); a prebuilt TieredStore is
+        # taken as-is; None = tiering off (evictions drop, as before)
+        if isinstance(kv_tiers, TierConfig):
+            self._tiers = TieredStore(
+                kv_tiers, page_size=self.page_size,
+                kv_cache_dtype=self.kv_cache_dtype,
+                replica=replica, on_bytes=self._note_tier_bytes,
             )
         else:
-            self._paged_def = None
-            self._kinds = []
-            self._prefix = None
-            self._tiers = None
-            self._drafter = None
-            self._verify_step = None
-            self._kernel_costed = False
-            self._arena_in_place = False
-            self._ragged_prefill = False
-            self._ragged_bt = _PREFILL_TOKEN_BLOCK
-            self._ragged_caps = ()
-            self._arena = init_arena(definition, params, self.num_slots, self._placer)
+            self._tiers = kv_tiers
+            if self._tiers is not None and self._tiers.on_bytes is None:
+                self._tiers.on_bytes = self._note_tier_bytes
+        tier_entries = (
+            self._tiers.config.entry_capacity() if self._tiers else 0
+        )
+        prefix_entries = (
+            int(prefix_max_entries) if prefix_max_entries else 512
+        )
+        self._prefix = (
+            PrefixCache(
+                self._allocator, self.page_size,
+                max_entries=prefix_entries,
+                # tier-aware ghost shadows: headroom beyond the new
+                # TOTAL (HBM+host+disk) capacity
+                ghost_base_entries=(
+                    prefix_entries + tier_entries if tier_entries else None
+                ),
+                on_evict=(
+                    self._demote_entry if self._tiers is not None else None
+                ),
+            )
+            if prefix_cache else None
+        )
+        self._drafter = drafter or (NGramDrafter() if self.spec_k else None)
+        self._arena = init_paged_arena(
+            self._paged_def, params, self.num_slots, self.pages_per_slot,
+            self._placer,
+            kinds=[k.name for k in self._kinds] if len(self._kinds) > 1 else None,
+        )
+        # whether the decode step rides the paged pallas kernel (the
+        # serving/decode_kernel_active gauge): in every layer kind
+        pcfg = self._paged_def.config
+        run_cfgs = pcfg.run_configs()
+        self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
+        # ... and whether that step updates the arena in place, the
+        # stacked leaves carried through the layer scan (the
+        # arena_in_place gauge and count of serving/decode_dispatch)
+        self._arena_in_place = all(arena_in_place(c) for c in run_cfgs)
+        self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
+        # packed ragged prefill (ops/attention.ragged_prefill_attention):
+        # the admission planner packs every pending tail into ONE ragged
+        # dispatch per scheduler iteration, token-block padding only.
+        # Where the flash prefill kernel (or its interpreter) does not
+        # engage, the packed dispatch runs its dense reference (the
+        # serving/prefill_kernel_active gauge says which).
+        self._prefill_kernel_costed = all(prefill_kernel_active(c) for c in run_cfgs)
+        # fixed grid capacities compiled at warmup (the zero-recompile
+        # invariant): each chunk bucket rounded up to the token block,
+        # deduped. The packer picks the smallest capacity that fits
+        # the round's packed tails.
+        rb = self._ragged_bt
+        self._ragged_caps = tuple(sorted(
+            {-(-int(c) // rb) * rb for c in self.prefill_chunks}
+        ))
+        for kind in self._kinds:
+            kind.device_tables = jnp.zeros(
+                (self.num_slots, self.pages_per_slot), jnp.int32
+            )
+        table_donate = (0,) if self._donate else ()
+        self._set_row = jax.jit(set_table_row, donate_argnums=table_donate)
+        self._set_entry = jax.jit(set_table_entry, donate_argnums=table_donate)
+        self._fork = jax.jit(
+            fork_page, donate_argnums=(0,) if self._donate else ()
+        )
+        # KV-handoff import write (one page per dispatch, traced dst)
+        self._install_page = jax.jit(
+            install_page, donate_argnums=(0,) if self._donate else ()
+        )
+        # demote-on-evict read: install_page's mirror, traced src —
+        # one compiled program gathers any page, so post-steady
+        # demotions never recompile (a gather by a per-call id list
+        # would compile per distinct page count)
+        self._gather_page = jax.jit(gather_page)
+        self._verify_step = (
+            jax.jit(self._build_verify_core(),
+                    donate_argnums=(1, 2, 4, 6) if self._donate else ())
+            if self.spec_k else None
+        )
         self.page_forks = 0
         self.kv_pages_exported = 0
         self.kv_pages_imported = 0
@@ -494,13 +476,11 @@ class ServingEngine:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.prefill_chunks_skipped = 0
-        # prefill padding-waste accounting (both paths dispatch FIXED row
-        # counts — chunk buckets or ragged grid capacities — so waste =
-        # 1 - live/dispatched is directly comparable between them):
-        # the prefill_pad_waste_frac gauge and the TTFT bench read these
-        self.prefill_packed_tokens = 0      # live tokens via ragged packs
-        self._prefill_tokens_dispatched = 0  # live tokens, either path
-        self._prefill_rows_dispatched = 0    # grid/bucket rows, either path
+        # prefill padding-waste accounting (a dispatch has the FIXED row
+        # count of its grid capacity, so waste = 1 - live/dispatched):
+        # the prefill_pad_waste_frac gauge reads these
+        self.prefill_packed_tokens = 0      # live tokens of the packs
+        self._prefill_rows_dispatched = 0   # grid rows of the packs
         self.arena_bytes = arena_nbytes(self._arena)
         self._tokens = jnp.zeros((self.num_slots,), jnp.int32)
         self._lengths = jnp.zeros((self.num_slots,), jnp.int32)
@@ -552,7 +532,6 @@ class ServingEngine:
         donate = (1, 2, 3, 5) if self._donate else ()
         self._decode_step = jax.jit(self._step_core, donate_argnums=donate)
         self._decode_bursts: dict = {}
-        self._prefill_fns: dict = {}
         self._ragged_fns: dict = {}
         self._admit_state = jax.jit(_admit_state_fn)
 
@@ -657,29 +636,24 @@ class ServingEngine:
     def _build_step_core(self):
         placer = self._placer
         temperature, top_k = self.temperature, self.top_k
-        paged = self.page_size is not None
-        definition = self._paged_def if paged else self.definition
+        definition = self._paged_def
 
         last_pos = self.max_cache_len - 1
         mutable = ["cache"] + ([MOE_LOAD] if self._expert_layers else [])
 
-        def step(params, arena, tokens, lengths, active, rngs, page_tables=None):
+        def step(params, arena, tokens, lengths, active, rngs, page_tables):
             """One batched decode step -> (arena, tokens, lengths, rngs).
             Jitted directly as the single step and scanned by the bursts."""
             # inactive slots still flow through the fused step (fixed batch)
             # but must NOT write at ``lengths``: a slot mid-admission has
-            # prefill chunks landing in the arena while decode steps run
+            # prefill dispatches landing in the arena while decode steps run
             # interleaved, and a stray write there corrupts its prefix.
             # Park them on the LAST cache position instead — any request
             # that legitimately reaches it writes its own K/V there before
-            # attending, so the garbage is unreachable. (Paged: a freed
-            # slot's table row is reset to the parking page, so a parked
-            # write can never land in another request's page.)
+            # attending, so the garbage is unreachable. (A freed slot's
+            # table row is reset to the parking page, so a parked write
+            # can never land in another request's page.)
             write_pos = jnp.where(active, lengths, last_pos)
-            # the paged decode kernel walks a slot's live pages only: an
-            # inactive slot, parked at the end of the cache, has none
-            kwargs = {"page_table": page_tables,
-                      "kv_lengths": jnp.where(active, lengths + 1, 0)} if paged else {}
             out, mutated = definition.apply(
                 {"params": placer(params), "cache": arena},
                 tokens[:, None],
@@ -687,8 +661,11 @@ class ServingEngine:
                 use_cache=True,
                 decode=True,
                 cache_positions=write_pos,
+                page_table=page_tables,
+                # the paged decode kernel walks a slot's live pages only: an
+                # inactive slot, parked at the end of the cache, has none
+                kv_lengths=jnp.where(active, lengths + 1, 0),
                 mutable=mutable,
-                **kwargs,
             )
             logits = out["logits"][:, -1]  # [N, V]
             split = jax.vmap(jax.random.split)(rngs)  # [N, 2, 2]
@@ -781,7 +758,7 @@ class ServingEngine:
             return fn
         core = self._step_core
 
-        def burst(params, arena, tokens, lengths, active, rngs, page_tables=None):
+        def burst(params, arena, tokens, lengths, active, rngs, page_tables):
             def body(carry, _):
                 arena, tokens, lengths, rngs = carry
                 arena, tokens, lengths, rngs = core(
@@ -796,52 +773,6 @@ class ServingEngine:
 
         fn = jax.jit(burst, donate_argnums=(1, 2, 3, 5) if self._donate else ())
         self._decode_bursts[k] = fn
-        return fn
-
-    def _prefill_fn(self, bucket: int):
-        fn = self._prefill_fns.get(bucket)
-        if fn is not None:
-            return fn
-        definition, placer = self.definition, self._placer
-        temperature, top_k = self.temperature, self.top_k
-        paged = self.page_size is not None
-
-        def prefill(params, arena, chunk_ids, slot, start, last_idx, rng,
-                    page_tables=None):
-            # per-slot chunked prefill rides the scalar-cache_index decode
-            # path: queries at global positions start..start+C-1 attend the
-            # slot's full prefix — exact continuation across chunks. On the
-            # paged arena the slot view is GATHERED from its pages into
-            # dense position order first and scattered back after, so the
-            # model-side chunk program (and its exactness contract) is the
-            # same one the flat arena runs.
-            if paged:
-                row = jax.lax.dynamic_index_in_dim(
-                    page_tables, slot, 0, keepdims=False
-                )
-                view = dense_slot_view(arena, row, start)
-            else:
-                view = slot_view(arena, slot, start)
-            out, mutated = definition.apply(
-                {"params": placer(params), "cache": view},
-                chunk_ids,  # [1, C]
-                positions=start + jnp.arange(bucket),
-                use_cache=True,
-                decode=True,
-                mutable=["cache"],
-            )
-            if paged:
-                arena = scatter_slot_view(arena, mutated["cache"], row)
-            else:
-                arena = write_slot(arena, mutated["cache"], slot)
-            # first-token sample from the last VALID row (padding rows of a
-            # bucketed final chunk produce garbage logits we never read)
-            row_l = jax.lax.dynamic_index_in_dim(out["logits"][0], last_idx, 0, keepdims=False)
-            first = _sample(row_l[None], rng, temperature, top_k)[0]
-            return arena, first
-
-        fn = jax.jit(prefill, donate_argnums=(1,) if self._donate else ())
-        self._prefill_fns[bucket] = fn
         return fn
 
     def _ragged_prefill_fn(self, cap: int):
@@ -887,9 +818,20 @@ class ServingEngine:
         self._ragged_fns[cap] = fn
         return fn
 
+    def _ragged_warm_args(self, rcap: int) -> tuple:
+        """An all-pad pack of ``rcap`` rows for the packed prefill program.
+        Safe to run on the idle arena: both kernel kv phases see zero live
+        rows, quantize-on-write lands on the parking page (unreachable by
+        construction), and the sampled firsts are discarded host-side."""
+        ids = jnp.zeros((1, rcap), jnp.int32)
+        pad = jnp.full((rcap,), -1, jnp.int32)
+        per_slot = jnp.zeros((self.num_slots,), jnp.int32)
+        return (self.params, self._arena, ids, pad, pad, per_slot, self._tables_arg(),
+                per_slot, jnp.zeros((self.num_slots, 2), jnp.uint32))
+
     def warmup(self):
         """Compile every program this engine can ever dispatch — each
-        prefill bucket, the admission scatter, the single decode step and
+        packed-prefill grid capacity, the admission scatter, the single decode step and
         the ``steps_per_call`` burst, plus the host-side eager RNG ops —
         by running them once against the (idle) arena. After
         ``warmup(); mark_steady()``, ``admission_recompiles`` staying 0 is
@@ -930,79 +872,39 @@ class ServingEngine:
                          "temperature": self.temperature, "top_k": self.top_k},
             )
         costs = getattr(self.telemetry, "costs", None)
-        paged = self.page_size is not None
-        pk = {"page_tables": self._page_tables} if paged else {}
-        # (a model by kind admits through the packed dispatch only)
-        for bucket in () if self._by_kind else self.prefill_chunks:
-            warm_chunk = jnp.zeros((1, bucket), jnp.int32)
-            self._note_forensics(f"prefill_{bucket}", {"chunk_ids": warm_chunk})
-            self._arena, _ = self._prefill_fn(bucket)(
-                self.params, self._arena, warm_chunk,
-                0, 0, bucket - 1, rng, **pk,
+        # the page-table maintenance programs: row install (admission),
+        # entry scatter (growth), page fork (copy-on-write). All traced-
+        # index data ops — one compile each, any slot/page thereafter.
+        # Warmup runs them as no-ops against the idle state (row 0 is
+        # already parking; forking the parking page onto itself).
+        self._page_tables = self._set_row(
+            self._page_tables, 0, jnp.asarray(self._tables_host.rows[0])
+        )
+        self._page_tables = self._set_entry(self._page_tables, 0, 0, 0)
+        if not self._by_kind:  # nothing forks, imports or demotes a page there
+            self._arena = self._fork(self._arena, 0, 0)
+            # the KV-handoff install program: write a zeros page into the
+            # parking page (whose content is unreachable by construction),
+            # so a post-steady import of handed-off pages never compiles
+            self._arena = self._install_page(
+                self._arena, self._page_slice_tree(), 0
             )
+            # ... and its mirror, the demote-on-evict page gather (reads
+            # the parking page; nothing observable), so a post-steady
+            # eviction can demote into the host tier with zero recompiles
+            jax.device_get(self._gather_page(self._arena, 0))
+        # the packed ragged-prefill programs, one per fixed grid capacity
+        for rcap in self._ragged_caps:
+            warm = self._ragged_warm_args(rcap)
+            self._note_forensics(f"ragged_prefill_{rcap}", {"ids": warm[2]})
+            self._arena, *_ = self._ragged_prefill_fn(rcap)(*warm)
             if costs is not None:
-                # roofline row per bucket; one re-trace, and the compiled
-                # memory analysis only when the persistent cache serves it
                 try:
-                    costs.capture_lowered(f"prefill_{bucket}", self._prefill_fn(bucket).lower(
-                        self.params, self._arena, warm_chunk, 0, 0, bucket - 1, rng, **pk,
-                    ))
+                    costs.capture_lowered(
+                        f"ragged_prefill_{rcap}",
+                        self._ragged_prefill_fn(rcap).lower(*self._ragged_warm_args(rcap)))
                 except Exception:
                     pass
-        if paged:
-            # the page-table maintenance programs: row install (admission),
-            # entry scatter (growth), page fork (copy-on-write). All traced-
-            # index data ops — one compile each, any slot/page thereafter.
-            # Warmup runs them as no-ops against the idle state (row 0 is
-            # already parking; forking the parking page onto itself).
-            self._page_tables = self._set_row(
-                self._page_tables, 0, jnp.asarray(self._tables_host.rows[0])
-            )
-            self._page_tables = self._set_entry(self._page_tables, 0, 0, 0)
-            if not self._by_kind:  # nothing forks, imports or demotes a page there
-                self._arena = self._fork(self._arena, 0, 0)
-                # the KV-handoff install program: write a zeros page into the
-                # parking page (whose content is unreachable by construction),
-                # so a post-steady import of handed-off pages never compiles
-                self._arena = self._install_page(
-                    self._arena, self._page_slice_tree(), 0
-                )
-                # ... and its mirror, the demote-on-evict page gather (reads
-                # the parking page; nothing observable), so a post-steady
-                # eviction can demote into the host tier with zero recompiles
-                jax.device_get(self._gather_page(self._arena, 0))
-            if self._ragged_prefill:
-                # the packed ragged-prefill programs, one per fixed grid
-                # capacity. All-pad warm args are safe: both kernel kv
-                # phases see zero live rows, quantize-on-write lands on
-                # the parking page (unreachable by construction), and
-                # the sampled firsts are discarded host-side.
-                warm_hist = jnp.zeros((self.num_slots,), jnp.int32)
-                warm_last = jnp.zeros((self.num_slots,), jnp.int32)
-                warm_rngs = jnp.zeros((self.num_slots, 2), jnp.uint32)
-                for rcap in self._ragged_caps:
-                    warm_ids = jnp.zeros((1, rcap), jnp.int32)
-                    warm_neg = jnp.full((rcap,), -1, jnp.int32)
-                    self._note_forensics(
-                        f"ragged_prefill_{rcap}", {"ids": warm_ids}
-                    )
-                    self._arena, *_ = self._ragged_prefill_fn(rcap)(
-                        self.params, self._arena, warm_ids, warm_neg,
-                        warm_neg, warm_hist, self._tables_arg(), warm_last,
-                        warm_rngs,
-                    )
-                    if costs is not None:
-                        try:
-                            costs.capture_lowered(
-                                f"ragged_prefill_{rcap}",
-                                self._ragged_prefill_fn(rcap).lower(
-                                    self.params, self._arena, warm_ids,
-                                    warm_neg, warm_neg, warm_hist,
-                                    self._tables_arg(), warm_last,
-                                    warm_rngs,
-                                ))
-                        except Exception:
-                            pass
         self._tokens, self._lengths, self._rngs = self._admit_state(
             self._tokens, self._lengths, self._rngs, 0, 0, 0, rng
         )
@@ -1011,16 +913,15 @@ class ServingEngine:
             {"tokens": self._tokens, "lengths": self._lengths,
              "active": self._active, "rngs": self._rngs},
         )
-        step_extra = (self._tables_arg(),) if paged else ()
         self._arena, self._tokens, self._lengths, self._rngs, *_ = self._decode_step(
             self.params, self._arena, self._tokens, self._lengths, self._active,
-            self._rngs, *step_extra,
+            self._rngs, self._tables_arg(),
         )
         if self.steps_per_call > 1:
             self._arena, self._tokens, self._lengths, self._rngs, _ = (
                 self._decode_burst(self.steps_per_call)(
                     self.params, self._arena, self._tokens, self._lengths,
-                    self._active, self._rngs, *step_extra,
+                    self._active, self._rngs, self._tables_arg(),
                 )
             )
         if self._verify_step is not None:
@@ -1062,7 +963,7 @@ class ServingEngine:
     def audit_entrypoints(self) -> list:
         """Entry-point specs for the static program auditor
         (``accelerate_tpu.analysis.program_audit``): every program
-        ``warmup()`` compiles — prefill buckets, the decode step and the
+        ``warmup()`` compiles — the packed prefill grids, the decode step and the
         ``steps_per_call`` burst, spec verify, the page-table maintenance
         programs — with the example args warmup itself would pass and the
         *effective* donation sets. Trace-only consumers: building the
@@ -1071,23 +972,11 @@ class ServingEngine:
         warmup populates anyway). ``donate_expected`` mirrors
         ``self._donate`` so the CPU sim's deliberate no-donation policy
         is not reported as a donation miss."""
-        rng = jax.random.PRNGKey(0)
-        paged = self.page_size is not None
         dtype = np.dtype(self.definition.config.dtype).name
-        pk = {"page_tables": self._page_tables} if paged else {}
         donate_on = self._donate
         specs = []
-        for bucket in self.prefill_chunks:
-            warm_chunk = jnp.zeros((1, bucket), jnp.int32)
-            specs.append(dict(
-                name=f"prefill_{bucket}", fn=self._prefill_fn(bucket),
-                args=(self.params, self._arena, warm_chunk, 0, 0, bucket - 1, rng),
-                kwargs=dict(pk), donate=(1,) if donate_on else (),
-                donate_expected=donate_on, compute_dtype=dtype,
-            ))
-        step_extra = (self._page_tables,) if paged else ()
         step_args = (self.params, self._arena, self._tokens, self._lengths,
-                     self._active, self._rngs) + step_extra
+                     self._active, self._rngs, self._page_tables)
         step_donate = (1, 2, 3, 5) if donate_on else ()
         # NB: no shape_probe on the engine's own programs, deliberately.
         # The weak-shape check compares shape-derived scalar literals
@@ -1118,40 +1007,31 @@ class ServingEngine:
                 donate=(1, 2, 4, 6) if donate_on else (),
                 donate_expected=donate_on, compute_dtype=dtype,
             ))
-        if paged:
-            table_donate = (0,) if donate_on else ()
+        table_donate = (0,) if donate_on else ()
+        specs.append(dict(
+            name="table_set_row", fn=self._set_row,
+            args=(self._page_tables, 0,
+                  jnp.asarray(self._tables_host.rows[0])),
+            donate=table_donate, donate_expected=donate_on,
+        ))
+        specs.append(dict(
+            name="table_set_entry", fn=self._set_entry,
+            args=(self._page_tables, 0, 0, 0),
+            donate=table_donate, donate_expected=donate_on,
+        ))
+        specs.append(dict(
+            name="page_fork", fn=self._fork, args=(self._arena, 0, 0),
+            donate=(0,) if donate_on else (), donate_expected=donate_on,
+            compute_dtype=dtype,
+        ))
+        for rcap in self._ragged_caps:
             specs.append(dict(
-                name="table_set_row", fn=self._set_row,
-                args=(self._page_tables, 0,
-                      jnp.asarray(self._tables_host.rows[0])),
-                donate=table_donate, donate_expected=donate_on,
+                name=f"ragged_prefill_{rcap}",
+                fn=self._ragged_prefill_fn(rcap),
+                args=self._ragged_warm_args(rcap),
+                donate=(1,) if donate_on else (),
+                donate_expected=donate_on, compute_dtype=dtype,
             ))
-            specs.append(dict(
-                name="table_set_entry", fn=self._set_entry,
-                args=(self._page_tables, 0, 0, 0),
-                donate=table_donate, donate_expected=donate_on,
-            ))
-            specs.append(dict(
-                name="page_fork", fn=self._fork, args=(self._arena, 0, 0),
-                donate=(0,) if donate_on else (), donate_expected=donate_on,
-                compute_dtype=dtype,
-            ))
-            if self._ragged_prefill:
-                warm_hist = jnp.zeros((self.num_slots,), jnp.int32)
-                warm_last = jnp.zeros((self.num_slots,), jnp.int32)
-                warm_rngs = jnp.zeros((self.num_slots, 2), jnp.uint32)
-                for rcap in self._ragged_caps:
-                    warm_ids = jnp.zeros((1, rcap), jnp.int32)
-                    warm_neg = jnp.full((rcap,), -1, jnp.int32)
-                    specs.append(dict(
-                        name=f"ragged_prefill_{rcap}",
-                        fn=self._ragged_prefill_fn(rcap),
-                        args=(self.params, self._arena, warm_ids, warm_neg,
-                              warm_neg, warm_hist, self._page_tables,
-                              warm_last, warm_rngs),
-                        donate=(1,) if donate_on else (),
-                        donate_expected=donate_on, compute_dtype=dtype,
-                    ))
         return specs
 
     # -- request API -------------------------------------------------------
@@ -1317,17 +1197,16 @@ class ServingEngine:
             args["iteration"] = self.iterations
             args["queued"] = self._queued_depth()
             args["live"] = len(self._slot_req)
-            if self.page_size:
-                args["pages_in_use"] = self._allocator.in_use
-                args["pages_free"] = self._allocator.free_count
-                for kind in self._kinds[1:]:
-                    args[f"pages_in_use.{kind.name}"] = kind.allocator.in_use
-                if self._by_kind:
-                    # what the cache holds against what it holds it for
-                    args["kv_bytes_in_use"] = sum(
-                        k.allocator.in_use * k.page_bytes for k in self._kinds)
-                    args["live_tokens"] = sum(
-                        r.prompt.size + len(r.tokens) for r in self._slot_req.values())
+            args["pages_in_use"] = self._allocator.in_use
+            args["pages_free"] = self._allocator.free_count
+            for kind in self._kinds[1:]:
+                args[f"pages_in_use.{kind.name}"] = kind.allocator.in_use
+            if self._by_kind:
+                # what the cache holds against what it holds it for
+                args["kv_bytes_in_use"] = sum(
+                    k.allocator.in_use * k.page_bytes for k in self._kinds)
+                args["live_tokens"] = sum(
+                    r.prompt.size + len(r.tokens) for r in self._slot_req.values())
             args["emitted"] = self.generated_tokens - emitted0
         return progressed
 
@@ -1485,8 +1364,7 @@ class ServingEngine:
         slot = req.slot
         self._slot_req.pop(slot, None)
         self._active[slot] = False
-        if self.page_size:
-            self._release_slot_pages(slot, tenant=req.tenant)
+        self._release_slot_pages(slot, tenant=req.tenant)
         self._free.append(slot)
         req.slot = None
 
@@ -1575,8 +1453,7 @@ class ServingEngine:
                 self._allocator.release(p)
             self._restore = None
             self.kv_restores_aborted += 1
-        if self.page_size:
-            self._release_slot_pages(slot, tenant=req.tenant)
+        self._release_slot_pages(slot, tenant=req.tenant)
         self._free.append(slot)
         req.slot = None
         if outcome == "shed":
@@ -1588,8 +1465,6 @@ class ServingEngine:
     # -- pressure: shedding and preemption ----------------------------------
 
     def _page_free_frac(self) -> float:
-        if not self.page_size:
-            return 1.0
         usable = self.num_pages - self._allocator.reserved
         return self._allocator.free_count / max(1, usable)
 
@@ -1598,7 +1473,7 @@ class ServingEngine:
         drops below the configured watermark, drop the newest
         lowest-priority queued request each step (queued work that could
         not be admitted anyway) with a telemetry event."""
-        if not self.page_size or self._sched.total_queued == 0:
+        if self._sched.total_queued == 0:
             return False
         # prefix-cache-held pages are reclaimable, not pressure: evict LRU
         # entries first and only shed if the arena is still below the
@@ -1664,18 +1539,17 @@ class ServingEngine:
         rng_row = np.asarray(jax.device_get(self._rngs))[slot].copy()
         self._slot_req.pop(slot, None)
         self._active[slot] = False
-        if self.page_size:
-            if self._prefix is not None and req.tokens:
-                replay = np.concatenate(
-                    [req.prompt, np.asarray(req.tokens[:-1], np.int32)]
-                )
-                # page out THROUGH the prefix cache: the entries hold the
-                # refs, so re-admission maps them back as cache hits (and
-                # LRU eviction can still reclaim them under real pressure)
-                self._prefix.insert(
-                    replay, self._tables_host.rows[slot], tenant=req.tenant
-                )
-            self._release_slot_pages(slot, tenant=req.tenant)
+        if self._prefix is not None and req.tokens:
+            replay = np.concatenate(
+                [req.prompt, np.asarray(req.tokens[:-1], np.int32)]
+            )
+            # page out THROUGH the prefix cache: the entries hold the
+            # refs, so re-admission maps them back as cache hits (and
+            # LRU eviction can still reclaim them under real pressure)
+            self._prefix.insert(
+                replay, self._tables_host.rows[slot], tenant=req.tenant
+            )
+        self._release_slot_pages(slot, tenant=req.tenant)
         self._free.append(slot)
         req.slot = None
         req.preemptions += 1
@@ -1754,9 +1628,10 @@ class ServingEngine:
 
     def _plan_chunks(self, prompt_len: int):
         """(start, bucket) list covering [0, prompt_len) from the fixed
-        bucket set — largest bucket that fits, smallest (padded) for the
-        tail. A bounded bucket set means a bounded compile set: admission
-        at ANY prompt length reuses these programs."""
+        ``prefill_chunks`` — largest bucket that fits, smallest (padded)
+        for the tail. The admit plan's unit: prefix-hit policy and the
+        capacity guard count in these buckets (the packed dispatch itself
+        takes rows up to its grid capacity, whatever the buckets)."""
         plan, start = [], 0
         while start < prompt_len:
             rem = prompt_len - start
@@ -2120,7 +1995,7 @@ class ServingEngine:
                 })
         return {
             "version": 1, "replica": self.replica,
-            "page_size": self.page_size or 0,
+            "page_size": self.page_size,
             "kv_cache_dtype": self.kv_cache_dtype,
             "prefixes": prefixes,
         }
@@ -2182,11 +2057,8 @@ class ServingEngine:
         migrate a session's KV off a draining replica. The probe uses
         ``PrefixCache.peek`` — exports never skew the hit gauges."""
         self._refuse_handoff()
-        if not self.page_size or self._prefix is None:
-            raise ValueError(
-                "KV handoff needs the paged arena with the prefix cache "
-                "(page_size=..., prefix_cache=True)"
-            )
+        if self._prefix is None:
+            raise ValueError("KV handoff needs the prefix cache (prefix_cache=True)")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if tokens.size < 1:
             return None
@@ -2198,8 +2070,8 @@ class ServingEngine:
         n_pages = -(-hit_len // self.page_size)
         ids = [int(p) for p in entry.pages[:n_pages]]
         # per-page through the warmup-compiled gather (same as demotion):
-        # gather_pages' per-call id list would compile per distinct page
-        # count, and a donor serving peer pulls exports in steady state
+        # a per-call id list would compile per distinct page count, and
+        # a donor serving peer pulls exports in steady state
         from .pages import _page_axis as _pa
 
         per_page = [
@@ -2299,11 +2171,8 @@ class ServingEngine:
         work for). Raises ValueError on an incompatible wire format
         (page size, KV dtype, or leaf layout mismatch)."""
         self._refuse_handoff()
-        if not self.page_size or self._prefix is None:
-            raise ValueError(
-                "KV handoff needs the paged arena with the prefix cache "
-                "(page_size=..., prefix_cache=True)"
-            )
+        if self._prefix is None:
+            raise ValueError("KV handoff needs the prefix cache (prefix_cache=True)")
         tokens, token_len, n_pages, arrays = self._handoff_arrays(handoff)
         have, _ = self._prefix.peek(tokens)
         if have >= token_len:
@@ -2395,9 +2264,7 @@ class ServingEngine:
             work = self._plan_dispatch(tr)
         if isinstance(work, bool):
             return work  # nothing to admit, or progress without a dispatch
-        if self._ragged_prefill:
-            return self._ragged_dispatch(tr, *work)
-        return self._dense_dispatch(tr, *work)
+        return self._ragged_dispatch(tr, *work)
 
     def _admission_writable(self, req: Request, slot: int, lo: int, hi: int) -> bool:
         """Pages for the admission's next write range. Same ladder as
@@ -2448,18 +2315,15 @@ class ServingEngine:
             else:
                 seq = req.prompt
                 prefill_rng, decode_rng = jax.random.split(req.rng)
-            if self.page_size:
-                # tier probe BEFORE the admit plan: a host/disk/peer hit
-                # longer than HBM's best sets up a staged restore (plan
-                # None until the pages land); otherwise plan immediately
-                restore = self._plan_restore(req, seq)
-                if restore is not None:
-                    self._restore = restore
-                    plan = None
-                else:
-                    plan = self._paged_admit_plan(req, slot, seq)
+            # tier probe BEFORE the admit plan: a host/disk/peer hit
+            # longer than HBM's best sets up a staged restore (plan
+            # None until the pages land); otherwise plan immediately
+            restore = self._plan_restore(req, seq)
+            if restore is not None:
+                self._restore = restore
+                plan = None
             else:
-                plan = self._plan_chunks(seq.size)
+                plan = self._paged_admit_plan(req, slot, seq)
             self._admitting = [req, slot, plan, 0, prefill_rng, decode_rng, seq]
             if req._resume is not None:
                 if tr is not None:
@@ -2472,117 +2336,26 @@ class ServingEngine:
             # so the decode step right after overlaps the installs
             self._advance_restore(req, slot, seq)
             return True
-        if self._ragged_prefill:
-            # flash prefill kernel engaged: one packed ragged dispatch
-            # replaces this iteration's bucket chunk (and may co-admit
-            # further queued tails into the same grid)
-            return self._ragged_pack(tr)
-        start, bucket = plan[idx]
-        chunk = np.zeros((1, bucket), np.int32)
-        seg = seq[start:start + bucket]
-        chunk[0, : seg.size] = seg
-        last_idx = min(seq.size, start + bucket) - 1 - start
-        chunk_dev = jnp.asarray(chunk)
-        self._note_forensics(f"prefill_{bucket}", {"chunk_ids": chunk_dev})
-        if self._faults is not None:
-            self._faults.before_prefill(self)
-        if self.page_size and not self._admission_writable(
-            req, slot, start, start + bucket - 1
-        ):
-            return True
-        return start, bucket, int(seg.size), last_idx, chunk_dev
-
-    def _dense_dispatch(self, tr, start: int, bucket: int, live: int,
-                        last_idx: int, chunk_dev) -> bool:
-        """One bucketed chunk of the admission singleton (the fallback and
-        bit-exactness oracle of the ragged path)."""
-        req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
-        pk = {"page_tables": self._page_tables} if self.page_size else {}
-        with _span("serving/prefill_dispatch", rows=bucket, tokens=live,
-                   requests=1) as sp:
-            self._arena, first = self._prefill_fn(bucket)(
-                self.params, self._arena, chunk_dev, slot, start, last_idx,
-                prefill_rng, **pk,
-            )
-        final = idx + 1 == len(plan)
-        resume = req._resume is not None
-        if final and not resume:
-            with _span("serving/prefill_fetch"):
-                first_tok = int(jax.device_get(first))
-        with _span("serving/prefill_commit") as sp_c:
-            wall = sp.t1 - sp.t0
-            self._note_prefill_chunk(req, slot, start, bucket, sp.t0, wall, tr)
-            if self.telemetry is not None and getattr(self.telemetry, "costs", None) is not None:
-                self.telemetry.costs.note_wall(f"prefill_{bucket}", wall)
-            usage = self._usage()
-            if usage is not None:
-                # actual tokens this chunk prefilled (padding excluded) plus
-                # the dispatch wall, billed to the admitting tenant
-                usage.note_prefill(req.tenant, live)
-                usage.note_compute(req.tenant, wall * 1e3)
-            # pad-waste accounting, comparable with the ragged path: the
-            # bucket is the dispatched row count, the segment is what's live
-            self._prefill_rows_dispatched += bucket
-            self._prefill_tokens_dispatched += live
-            sp_c.args["first_tokens"] = 0
-            if not final:
-                self._admitting[3] = idx + 1
-                return True
-            # final chunk done -> the slot goes live with its first token
-            self._admitting = None
-            if self.page_size and not resume:
-                self._insert_prefix(req, slot)
-            if resume:
-                # the replayed slot continues where it was paged out: last
-                # emitted token, restored chain, no new emission
-                first_tok = int(req.tokens[-1])
-                length = int(seq.size)
-                req._resume = None
-                self.resumptions += 1
-            else:
-                length = int(req.prompt.size)
-            self._tokens, self._lengths, self._rngs = self._admit_state(
-                self._tokens, self._lengths, self._rngs, slot, first_tok, length,
-                decode_rng,
-            )
-            req.slot = slot
-            req.prefill_kernel = "dense"
-            self._slot_req[slot] = req
-            self._active[slot] = True
-            if resume:
-                # the paged-out + requeued + replay wait is scheduling latency
-                # (the record's preemptions field owns it), not an inter-token
-                # gap: clearing the reference clock makes the first post-resume
-                # token gap-less, so one preemption cannot fake an ITL-p99
-                # breach and trip the AIMD controller into cutting the budget
-                req._last_token_t = 0.0
-                return True
-            now = time.perf_counter()
-            self._note_first_token(req, now, tr)
-            # _last_token_t stays 0.0 until _emit sets it: the first token has
-            # no preceding token, so it must not record a spurious 0.0 ITL gap
-            self._emit(req, first_tok, now)
-            sp_c.args["first_tokens"] = 1
-        return True
+        # one packed ragged dispatch (it may co-admit further queued
+        # tails into the same grid)
+        return self._ragged_pack(tr)
 
     def _ragged_pack(self, tr):
         """The host side of one packed ragged-prefill dispatch: the primary
         admission's next tail segment plus — when capacity remains — the
         WHOLE tails of further queued requests, packed token-block-aligned
-        into the smallest compiled grid capacity that fits. Replaces the
-        per-slot bucket chunks of the dense path (which stays compiled as
-        the fallback and bit-exactness oracle); preserves the interleave
-        discipline (one dispatch per scheduler iteration) and the
-        zero-recompile invariant (grid capacities fixed at warmup).
+        into the smallest compiled grid capacity that fits. Keeps the
+        interleave discipline (one dispatch per scheduler iteration) and
+        the zero-recompile invariant (grid capacities fixed at warmup).
         Returns ``_ragged_dispatch``'s arguments, or True where the
         admission was shed for pages."""
         req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
         bt = self._ragged_bt
         cap_max = self._ragged_caps[-1]
-        # ``idx`` is repurposed by this path as the next global position
-        # to prefill (0 = nothing dispatched yet -> start past the
-        # prefix hit the admit plan recorded; a first dispatch always
-        # advances past position 0, so the sentinel is unambiguous)
+        # ``idx`` is the next global position to prefill (0 = nothing
+        # dispatched yet -> start past the prefix hit the admit plan
+        # recorded; a first dispatch always advances past position 0,
+        # so the sentinel is unambiguous)
         cur = plan[0][0] if idx == 0 else idx
         n = min(seq.size - cur, cap_max)
         if self._faults is not None:
@@ -2690,7 +2463,6 @@ class ServingEngine:
                 costs.note_wall(f"ragged_prefill_{rcap}", wall)
             usage = self._usage()
             self.prefill_packed_tokens += fresh
-            self._prefill_tokens_dispatched += fresh
             self._prefill_rows_dispatched += rcap
             now = time.perf_counter()
             first_tokens = 0
@@ -2732,7 +2504,7 @@ class ServingEngine:
                     length, drng,
                 )
                 preq.slot = psl
-                preq.prefill_kernel = "ragged"
+                preq.prefill_kernel = "ragged" if self._prefill_kernel_costed else "dense"
                 self._slot_req[psl] = preq
                 self._active[psl] = True
                 if resume:
@@ -2887,27 +2659,26 @@ class ServingEngine:
         if self.spec_k:
             return self._spec_verify_once()
         k = self._burst_len()
-        if self.page_size:
-            with _span("serving/decode_grow") as sp:
-                pages0, released0 = self.pages_allocated, self.pages_released
-                # each grown slot's last write position of this round: the
-                # decode kernel walks its pages up to that one (a slot
-                # preempted later in this same loop, for another's pages,
-                # stays counted)
-                walked = []
-                for slot, req in list(self._slot_req.items()):
-                    if slot not in self._slot_req:
-                        continue  # shed/preempted while relieving another slot
-                    pos = self._next_write_pos(req)
-                    self._release_behind_window(req, slot, pos)
-                    if self._grow_or_resolve(req, slot, pos, pos + k - 1):
-                        walked.append(pos + k - 1)
-                sp.args["pages_allocated"] = self.pages_allocated - pages0
-                self._note_walk(sp, walked)
-                if self._by_kind:
-                    sp.args["pages_released"] = self.pages_released - released0
-            if not self._slot_req:
-                return True  # every live slot was shed under page pressure
+        with _span("serving/decode_grow") as sp:
+            pages0, released0 = self.pages_allocated, self.pages_released
+            # each grown slot's last write position of this round: the
+            # decode kernel walks its pages up to that one (a slot
+            # preempted later in this same loop, for another's pages,
+            # stays counted)
+            walked = []
+            for slot, req in list(self._slot_req.items()):
+                if slot not in self._slot_req:
+                    continue  # shed/preempted while relieving another slot
+                pos = self._next_write_pos(req)
+                self._release_behind_window(req, slot, pos)
+                if self._grow_or_resolve(req, slot, pos, pos + k - 1):
+                    walked.append(pos + k - 1)
+            sp.args["pages_allocated"] = self.pages_allocated - pages0
+            self._note_walk(sp, walked)
+            if self._by_kind:
+                sp.args["pages_released"] = self.pages_released - released0
+        if not self._slot_req:
+            return True  # every live slot was shed under page pressure
         if self._faults is not None:
             self._faults.before_decode(self)
         self._note_forensics(
@@ -2915,7 +2686,6 @@ class ServingEngine:
             {"tokens": self._tokens, "lengths": self._lengths,
              "active": self._active, "rngs": self._rngs},
         )
-        step_extra = (self._tables_arg(),) if self.page_size else ()
         load = ()
         with _span("serving/decode_dispatch", slots=len(self._slot_req),
                    arena_in_place=int(self._arena_in_place)) as sp_d:
@@ -2923,13 +2693,13 @@ class ServingEngine:
                 self._arena, self._tokens, self._lengths, self._rngs, toks = (
                     self._decode_burst(k)(
                         self.params, self._arena, self._tokens, self._lengths,
-                        self._active, self._rngs, *step_extra,
+                        self._active, self._rngs, self._tables_arg(),
                     )
                 )
             else:
                 self._arena, self._tokens, self._lengths, self._rngs, *load = self._decode_step(
                     self.params, self._arena, self._tokens, self._lengths, self._active,
-                    self._rngs, *step_extra,
+                    self._rngs, self._tables_arg(),
                 )
                 toks = self._tokens
         with _span("serving/token_fetch") as sp_f:
@@ -3023,7 +2793,7 @@ class ServingEngine:
     def mark_steady(self):
         """Snapshot the compile counters: every compile AFTER this call
         counts as an admission recompile (the invariant says there are
-        none). Call once the engine has seen each prefill bucket + the
+        none). Call once the engine has seen each prefill grid + the
         decode step — e.g. after a warmup wave."""
         self._steady_mark = self._counters()
 
@@ -3046,10 +2816,9 @@ class ServingEngine:
         if self._exe_mem is not None or cached_only:
             return self._exe_mem or {}
         try:
-            step_extra = (self._page_tables,) if self.page_size else ()
             compiled = self._decode_step.lower(
                 self.params, self._arena, self._tokens, self._lengths,
-                self._active, self._rngs, *step_extra,
+                self._active, self._rngs, self._page_tables,
             ).compile()
             costs = getattr(self.telemetry, "costs", None)
             if costs is not None:
@@ -3125,59 +2894,54 @@ class ServingEngine:
             p99, _ = self._recent_itl_p99_ms()
             if p99 is not None:
                 out["serving/itl_recent_p99_ms"] = round(p99, 3)
-        if self.page_size:
-            out["serving/pages_in_use"] = self._allocator.in_use
-            out["serving/pages_total"] = self.num_pages
-            out["serving/page_size"] = self.page_size
-            out["serving/page_forks"] = self.page_forks
-            out["serving/decode_kernel_active"] = bool(self._kernel_costed)
-            out["serving/arena_in_place"] = int(self._arena_in_place)
-            out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
-            for kind in self._kinds[1:]:
-                out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use
-                out[f"serving/pages_total.{kind.name}"] = kind.num_pages
-            if self._by_kind:
-                out["serving/pages_released"] = self.pages_released
-            out["serving/prefill_packed_tokens"] = int(
-                self.prefill_packed_tokens
-            )
-            if self.kv_pages_exported or self.kv_pages_imported:
-                out["serving/kv_pages_exported"] = self.kv_pages_exported
-                out["serving/kv_pages_imported"] = self.kv_pages_imported
-            if self._prefix is not None:
-                out["serving/prefix_hit_ratio"] = self._prefix.hit_ratio
-                out["serving/prefix_hit_tokens"] = self._prefix.hit_tokens
-                out["serving/prefix_entries"] = len(self._prefix.entries)
-                out["serving/prefill_chunks_skipped"] = self.prefill_chunks_skipped
-                if self._prefix.ghost is not None:
-                    # ghost-cache economics: the hit ratio the prefix
-                    # cache WOULD have at 2x/4x/10x entry capacity, plus
-                    # reuse-after-evict distances — the evidence base for
-                    # a host/disk KV tier (ROADMAP item 2)
-                    out.update(self._prefix.ghost.gauges())
-            if self._tiers is not None:
-                out.update(self._tiers.gauges())
-                lookups = self._prefix.lookups if self._prefix else 0
-                for tier, hits in self.kv_tier_hits.items():
-                    out[f"serving/kv_tier_hits_{tier}"] = hits
-                    out[f"serving/kv_tier_hit_ratio_{tier}"] = (
-                        hits / lookups if lookups else 0.0
-                    )
-                out["serving/kv_restores"] = self.kv_restores
-                out["serving/kv_restores_aborted"] = self.kv_restores_aborted
-                out["serving/kv_restore_batches"] = self.kv_restore_batches
-                out["serving/kv_restore_overlap_frac"] = (
-                    self.kv_restore_batches_overlapped / self.kv_restore_batches
-                    if self.kv_restore_batches else 0.0
+        out["serving/pages_in_use"] = self._allocator.in_use
+        out["serving/pages_total"] = self.num_pages
+        out["serving/page_size"] = self.page_size
+        out["serving/page_forks"] = self.page_forks
+        out["serving/decode_kernel_active"] = bool(self._kernel_costed)
+        out["serving/arena_in_place"] = int(self._arena_in_place)
+        out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
+        for kind in self._kinds[1:]:
+            out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use
+            out[f"serving/pages_total.{kind.name}"] = kind.num_pages
+        if self._by_kind:
+            out["serving/pages_released"] = self.pages_released
+        out["serving/prefill_packed_tokens"] = int(
+            self.prefill_packed_tokens
+        )
+        if self.kv_pages_exported or self.kv_pages_imported:
+            out["serving/kv_pages_exported"] = self.kv_pages_exported
+            out["serving/kv_pages_imported"] = self.kv_pages_imported
+        if self._prefix is not None:
+            out["serving/prefix_hit_ratio"] = self._prefix.hit_ratio
+            out["serving/prefix_hit_tokens"] = self._prefix.hit_tokens
+            out["serving/prefix_entries"] = len(self._prefix.entries)
+            out["serving/prefill_chunks_skipped"] = self.prefill_chunks_skipped
+            if self._prefix.ghost is not None:
+                # ghost-cache economics: the hit ratio the prefix
+                # cache WOULD have at 2x/4x/10x entry capacity, plus
+                # reuse-after-evict distances — the evidence base for
+                # a host/disk KV tier (ROADMAP item 2)
+                out.update(self._prefix.ghost.gauges())
+        if self._tiers is not None:
+            out.update(self._tiers.gauges())
+            lookups = self._prefix.lookups if self._prefix else 0
+            for tier, hits in self.kv_tier_hits.items():
+                out[f"serving/kv_tier_hits_{tier}"] = hits
+                out[f"serving/kv_tier_hit_ratio_{tier}"] = (
+                    hits / lookups if lookups else 0.0
                 )
+            out["serving/kv_restores"] = self.kv_restores
+            out["serving/kv_restores_aborted"] = self.kv_restores_aborted
+            out["serving/kv_restore_batches"] = self.kv_restore_batches
+            out["serving/kv_restore_overlap_frac"] = (
+                self.kv_restore_batches_overlapped / self.kv_restore_batches
+                if self.kv_restore_batches else 0.0
+            )
         if self._prefill_rows_dispatched:
-            # fraction of dispatched prefill rows that were padding —
-            # both paths dispatch fixed row counts (chunk buckets or
-            # ragged grid capacities), so the gauge compares them
-            # directly; the ragged packer's win is this number falling
+            # fraction of dispatched prefill rows that were padding
             out["serving/prefill_pad_waste_frac"] = (
-                1.0 - self._prefill_tokens_dispatched
-                / self._prefill_rows_dispatched
+                1.0 - self.prefill_packed_tokens / self._prefill_rows_dispatched
             )
         if self.spec_k:
             out["serving/spec_proposed"] = self.spec_proposed
@@ -3191,19 +2955,18 @@ class ServingEngine:
         # the placement-signal contract (telemetry/fleet.py, documented in
         # docs/telemetry.md "Fleet view"): one comparable scalar a router
         # ranks replicas by, plus the raw components it folds — exported
-        # by EVERY engine, flat or paged, scheduler or not
+        # by EVERY engine, scheduler or not
         from ..telemetry.fleet import load_score
 
         out["serving/num_slots"] = self.num_slots
         out["serving/free_slots"] = self.num_slots - len(self._slot_req)
-        if self.page_size:
-            out["serving/free_pages"] = self._allocator.free_count
+        out["serving/free_pages"] = self._allocator.free_count
         out["serving/load_score"] = load_score(
             queue_depth=out["serving/queue_depth"],
             num_slots=self.num_slots,
             slot_occupancy=out["serving/slot_occupancy"],
-            free_pages=out.get("serving/free_pages"),
-            pages_total=self.num_pages if self.page_size else None,
+            free_pages=out["serving/free_pages"],
+            pages_total=self.num_pages,
             itl_recent_p99_ms=out.get("serving/itl_recent_p99_ms"),
             itl_slo_ms=(
                 self._sched.config.itl_slo_ms if self._sched is not None else None

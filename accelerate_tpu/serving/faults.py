@@ -287,12 +287,9 @@ class FaultInjector:
                 s[2] = True
                 self.log.append((step, "storm", s[0]))
                 s[1](engine)
-        alloc = getattr(engine, "_allocator", None)
         for sq in self._squeezes:
+            alloc = engine._allocator
             if sq["held"] is None and sq["release_at"] is None and step >= sq["at_step"]:
-                if alloc is None:
-                    sq["release_at"] = step  # flat arena: nothing to squeeze
-                    continue
                 held = []
                 for _ in range(sq["pages"]):
                     page = alloc.alloc()
@@ -319,9 +316,8 @@ class FaultInjector:
 
     def release_all(self, engine):
         """Return any still-held squeeze pages (test teardown)."""
-        alloc = getattr(engine, "_allocator", None)
         for sq in self._squeezes:
-            if sq["held"] is not None and alloc is not None:
+            if sq["held"] is not None:
                 for page in sq["held"]:
-                    alloc.release(page)
+                    engine._allocator.release(page)
                 sq["held"] = None

@@ -1,10 +1,10 @@
 """Paged KV arena: fixed-size pages, refcounted free list, copy-on-write
 prefix cache, and the n-gram drafter for speculative decoding.
 
-The flat slot arena (``arena.py``) reserves ``max_cache_len`` of KV per
-slot no matter how long the request actually is, and every request pays a
-full prefill even when thousands share a templated system prompt. This
-module replaces the storage layer with **pages**:
+A dense ``num_slots x max_cache_len`` block would reserve ``max_cache_len``
+of KV per slot no matter how long the request actually is, and every
+request would pay a full prefill even when thousands share a templated
+system prompt. The serving engine's storage layer is **pages**:
 
 - K/V leaves become ``[num_pages, KVH, page_size, D]`` physical pages (a
   leading layer axis under ``scan_layers``); a per-slot **page table**
@@ -14,7 +14,7 @@ module replaces the storage layer with **pages**:
   inactive slots' fused-step writes land there.
 - the **free list + refcounts** live host-side (:class:`PageAllocator`);
   admission/growth/eviction are pure data changes (table-entry scatters),
-  so the zero-recompile discipline of the flat arena carries over.
+  so a live engine never recompiles.
 - the **prefix cache** (:class:`PrefixCache`) keys page-aligned prompt
   prefixes by token hash. A request whose prompt prefix is cached maps the
   shared pages into its table (refcount++) and prefills only the tail —
@@ -30,8 +30,8 @@ module replaces the storage layer with **pages**:
 Everything above the device helpers is plain-python/numpy bookkeeping and
 imports **without jax or flax** (locked by tests/test_imports.py): a
 router/scheduler tier can reason about page budgets on machines with no
-accelerator stack. The device helpers (arena init, dense gather views,
-page forks) import jax lazily at call time.
+accelerator stack. The device helpers (arena init, page forks, page
+gathers and installs) import jax lazily at call time.
 """
 
 from __future__ import annotations
@@ -617,13 +617,11 @@ class CacheKind:
 # are ``cached_key`` / ``cached_value`` [num_pages, KVH, page_size, D] (+ a
 # leading layer axis under a scanned stack; the two widths may differ), a
 # quantized arena's ``*_scale`` leaves [num_pages, KVH, page_size, 1] move
-# with their payloads through every generic tree op below (gather views,
-# scatters, CoW forks), and everything else (``cache_index``, an encoder's
-# memory) is not paged. The flat slot arena (arena.py) slices the same
-# leaves, and a seq2seq decoder's cross keys and values, along the slot axis.
+# with their payloads through every generic tree op below (page gathers,
+# installs, CoW forks), and everything else (``cache_index``, an encoder's
+# memory) is not paged.
 PAGED_LEAF_NAMES = frozenset(
     ("cached_key", "cached_value", "cached_key_scale", "cached_value_scale"))
-SLOT_LEAF_NAMES = PAGED_LEAF_NAMES | {"cross_key", "cross_value"}
 
 
 def leaf_name(path) -> Optional[str]:
@@ -661,8 +659,8 @@ def paged_leaves(arena) -> list:
 def init_paged_arena(definition, params, num_slots: int, pages_per_slot: int,
                      placer, kinds=None):
     """All-zeros paged cache arena shaped by ``jax.eval_shape`` over the
-    paged decode apply — the paged twin of ``arena.init_arena`` (no compile,
-    no device compute, correct for any cache layout the family uses).
+    paged decode apply (no compile, no device compute, correct for any
+    cache layout the family uses).
     ``kinds``: the cache kinds' names where the model states several (it
     then takes a page table a kind)."""
     import jax
@@ -686,42 +684,10 @@ def init_paged_arena(definition, params, num_slots: int, pages_per_slot: int,
     return jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
-def dense_slot_view(arena, page_row, start):
-    """Batch-1 DENSE cache tree for one slot, gathered from its pages in
-    position order — what chunked prefill runs against, so the per-slot
-    scalar-``cache_index`` prefill path (and its chunk-exactness contract)
-    is reused verbatim on the paged arena. ``cache_index`` leaves become
-    ``start``, like ``arena.slot_view``. Traced-friendly."""
-    import jax.numpy as jnp
+def arena_nbytes(arena) -> int:
+    import jax
 
-    def take(leaf):
-        axis = _page_axis(leaf)
-        g = jnp.take(leaf, page_row, axis=axis)       # [..., P, KVH, ps, D]
-        g = jnp.moveaxis(g, axis, axis + 1)           # [..., KVH, P, ps, D]
-        shape = g.shape[: axis + 1] + (g.shape[axis + 1] * g.shape[axis + 2], g.shape[-1])
-        return jnp.expand_dims(g.reshape(shape), axis)  # [..., 1, KVH, P*ps, D]
-
-    return map_paged(take, arena, other=lambda leaf: jnp.full(leaf.shape, start, leaf.dtype))
-
-
-def scatter_slot_view(arena, view_tree, page_row):
-    """Write a mutated dense slot view back into the pages it was gathered
-    from (the inverse of :func:`dense_slot_view`). Duplicate ``page_row``
-    entries (parking padding) receive byte-identical writes — a prefill
-    chunk only mutates positions inside the slot's allocated span — so the
-    scatter's unspecified duplicate order cannot matter. Index leaves keep
-    the arena's value, mirroring ``arena.write_slot``."""
-    import jax.numpy as jnp
-
-    def put(leaf, view):
-        axis = _page_axis(leaf)
-        ps = leaf.shape[-2]
-        v = jnp.squeeze(view.astype(leaf.dtype), axis=axis)  # [..., KVH, P*ps, D]
-        shape = v.shape[: axis + 1] + (v.shape[axis + 1] // ps, ps, v.shape[-1])
-        v = jnp.moveaxis(v.reshape(shape), axis + 1, axis)   # [..., P, KVH, ps, D]
-        return leaf.at[(slice(None),) * axis + (page_row,)].set(v)
-
-    return map_paged(put, arena, view_tree)
+    return sum(int(l.nbytes) for l in jax.tree_util.tree_leaves(arena))
 
 
 def fork_page(arena, src, dst):
@@ -738,29 +704,13 @@ def fork_page(arena, src, dst):
     return map_paged(copy, arena)
 
 
-def gather_pages(arena, page_ids):
-    """Host copies of physical pages ``page_ids`` from every K/V leaf, in
-    the order given — the KV-handoff export read. Returns a list of numpy
-    arrays (one per K/V leaf, arena flatten order) whose page axis holds
-    ``len(page_ids)`` entries; quantized arenas ship the int8/int4 payload
-    leaves and their fp32 scale leaves alike, so a handoff can never
-    separate a payload from its scales. One small gather dispatch per leaf
-    (the full arena is never device_get)."""
-    import jax
-    import jax.numpy as jnp
-
-    ids = jnp.asarray(list(page_ids), jnp.int32)
-    return [np.asarray(jax.device_get(jnp.take(leaf, ids, axis=_page_axis(leaf))))
-            for leaf in paged_leaves(arena)]
-
-
 def gather_page(arena, src):
     """Size-1 page slice of every K/V leaf at page ``src``, arena
     flatten order — the demote-on-evict read, and the exact mirror of
     :func:`install_page`'s write. Traced ``src``: one compiled program
     gathers any page, so a warmed engine demotes evicted prefixes into
-    the host tier with zero recompiles (``gather_pages`` above, with
-    its per-call id *list*, would compile per distinct page count)."""
+    the host tier with zero recompiles (a gather by a per-call id *list*
+    would compile per distinct page count)."""
     import jax
 
     return [jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis=_page_axis(leaf))

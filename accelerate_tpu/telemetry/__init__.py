@@ -18,7 +18,7 @@ runtime together and the engine feeds it automatically:
 - **metrics pipeline** — every optimizer step (eager or fused
   ``build_train_step``) records wall time, tokens, data-loader wait, and
   XLA compile activity into a rolling window; ``rollup()`` adds MFU
-  (flops accounting shared with bench.py via ``telemetry.metrics``),
+  (the flops accounting of ``telemetry.metrics``),
   grad-norm/loss, fp16 loss-scale, fp8 amax health, device memory and the
   PowerSGD wire-bytes estimate. Flushes ride the existing
   ``GeneralTracker`` plumbing, so JSONL/TensorBoard/W&B get system

@@ -1,10 +1,8 @@
 """Per-step metrics accounting: rolling windows, flops/MFU math, device
 memory and fp8 amax health probes.
 
-This module owns the flops accounting that ``bench.py`` previously kept to
-itself (peak-flops table + the decoder FLOPs/token formula), so a live
-training run reports the same MFU the benchmark would compute offline —
-one definition, two consumers.
+This module owns the flops accounting (peak-flops table + the decoder
+FLOPs/token formula) by which a live training run reports its MFU.
 
 Everything here is host-side arithmetic; the only device interaction is
 ``device_memory_stats()`` (a stats query, not a computation) and
@@ -47,7 +45,7 @@ def peak_flops(device) -> Optional[float]:
 def decoder_flops_per_token(num_params: int, num_layers: int, seq_len: int,
                             embed_dim: int) -> float:
     """Training FLOPs per token for a causal decoder: 6N weight FLOPs +
-    causal attention 6*L*S*E (the bench.py headline formula)."""
+    causal attention 6*L*S*E."""
     return 6 * num_params + 6 * num_layers * seq_len * embed_dim
 
 

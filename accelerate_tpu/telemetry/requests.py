@@ -219,7 +219,7 @@ class RequestTracer:
             rec["shed_reason"] = shed_reason
         rec["finish_unix_s"] = round(time.time(), 6)
         # paged-arena / speculative attribution (engine-owned counters on
-        # the request; 0s on a flat-arena engine): how much of this
+        # the request): how much of this
         # request's TTFT the prefix cache saved, what it cost in pages,
         # and how its draft tokens fared — what `accelerate-tpu trace`
         # aggregates into per-burst hit/accept rates

@@ -182,8 +182,7 @@ class UsageAccountant:
             self._integrate(t, now)
             t.pages_held += int(delta)
             if t.pages_held < 0:
-                # release without a matched retain (flat arena, double
-                # release): clamp — page_seconds must stay non-negative
+                # release without a matched retain (double release): clamp — page_seconds must stay non-negative
                 t.pages_held = 0
 
     def note_tier_bytes(self, tenant: str, tier: str, delta: int,

@@ -4,7 +4,7 @@ over the telemetry span layer.
 Two consumers, two shapes:
 
 - ``collect_phases()`` arms a process-global collector that accumulates
-  wall time per named phase — bench.py's TTFT worker uses it to publish
+  wall time per named phase, to publish
   WHERE dispatch time goes (checkpoint read / host quantize / transfer
   submit / compile / first forward) instead of a single opaque total.
 - when a telemetry span recorder is armed (``telemetry.spans.arm`` or a

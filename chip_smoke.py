@@ -417,7 +417,7 @@ def serve_prompts(S: Sizes, vocab: int, seed: int) -> list:
     return prompts
 
 
-def run_engine(S: Sizes, cfg, params, prompts, on_chip: bool, label: str):
+def run_engine(S: Sizes, cfg, params, prompts, on_chip: bool, label: str, **engine_kw):
     """warmup -> submit -> run on one engine. Returns the generated tokens."""
     import jax
 
@@ -428,7 +428,7 @@ def run_engine(S: Sizes, cfg, params, prompts, on_chip: bool, label: str):
     t0 = time.perf_counter()
     engine = ServingEngine(
         DecoderLM(cfg), params, page_size=S.page_size, num_slots=S.num_slots,
-        max_cache_len=S.max_cache_len,
+        max_cache_len=S.max_cache_len, **engine_kw,
     )
     engine.warmup()
     warm = time.perf_counter() - t0
@@ -487,7 +487,10 @@ def serve_phase(S: Sizes, seed: int, on_chip: bool) -> None:
     prompts = serve_prompts(S, cfg.vocab_size, seed)
     kernel = run_engine(S, cfg, params, prompts, on_chip, "kernel")
     dense_cfg = dataclasses.replace(cfg, decode_kernel="dense", prefill_kernel="dense")
-    dense = run_engine(S, dense_cfg, params, prompts, on_chip, "dense")
+    # the packed dispatch's dense reference gathers the cache of every row's
+    # slot: at these widths a grid of 256 rows holds 8.5 GiB of temporaries
+    # (ahead-of-time compile for v5e:2x2, PR 30), one of 64 rows 4.4 GiB
+    dense = run_engine(S, dense_cfg, params, prompts, on_chip, "dense", prefill_chunks=(64,))
     same = sum(a == b for k, d in zip(kernel, dense) for a, b in zip(k, d))
     total = sum(len(k) for k in kernel)
     firsts = [k[0] == d[0] for k, d in zip(kernel, dense)]

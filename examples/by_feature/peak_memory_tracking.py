@@ -123,7 +123,7 @@ def training_function(config, args):
 
     # New Code #
     # Compiled-program estimate: exact buffer accounting from XLA, available
-    # on every backend (the number `bench.py` uses for the pipeline rows)
+    # on every backend
     engine = model._engine
     try:
         from accelerate_tpu.utils.serialization import flatten_pytree

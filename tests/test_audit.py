@@ -376,11 +376,11 @@ class TestEngineWarmupSetZeroFalsePositives:
         names = {pa.EntrypointSpec.normalize(s).name
                  for s in eng.audit_entrypoints()}
         # the full warmup program set is enumerated
-        assert {"prefill_4", "prefill_8", "decode_step", "decode_burst2",
+        assert {"ragged_prefill_8", "decode_step", "decode_burst2",
                 "spec_verify", "table_set_row", "table_set_entry",
                 "page_fork"} <= names
 
-    def test_flat_engine_clean(self, audited_model):
+    def test_default_engine_clean(self, audited_model):
         eng = self._engine(audited_model)
         fs = pa.audit_engine(eng)
         assert fs == [], [f.to_dict() for f in fs]

@@ -11,7 +11,7 @@ CPU (the compiled TPU path shares every line but the `interpret` flag):
   ([Sq]) and per-slot ([B, Sq]) positions at any valid kv block size;
 - the parking page (page 0) is never *observable*: arbitrary garbage in
   parked/unallocated pages cannot perturb any slot's output;
-- dispatch: `ATT_DECODE_KERNEL`/`decode_kernel` resolution, the warn-once
+- dispatch: `decode_kernel` resolution, the warn-once
   dense fallback off-TPU, and the by-design dense routing of
   prefill-size multi-query calls.
 """
@@ -305,7 +305,7 @@ class TestDenseArenaKernel:
                                        err_msg=f"position {p}")
 
     def test_per_slot_positions_and_block_sweep(self):
-        """[B, Sq] per-slot positions (flat slot-arena serving) at several
+        """[B, Sq] per-slot positions at several
         kv block sizes — block choice changes the walk, not the math."""
         rng = np.random.RandomState(6)
         b, h, kvh, d, L = 3, 4, 2, 16, 32
@@ -323,14 +323,12 @@ class TestDenseArenaKernel:
 
 
 class TestDecodeKernelDispatch:
-    def test_resolution_order_and_validation(self, monkeypatch):
-        monkeypatch.delenv("ATT_DECODE_KERNEL", raising=False)
-        assert resolve_decode_kernel() == "paged"
+    def test_resolution_order_and_validation(self):
+        # the config's value, else the default
+        assert resolve_decode_kernel() == resolve_decode_kernel(None) == "paged"
         assert resolve_decode_kernel("dense") == "dense"
-        monkeypatch.setenv("ATT_DECODE_KERNEL", "dense")
-        assert resolve_decode_kernel() == "dense"
-        assert resolve_decode_kernel("interpret") == "interpret"  # arg wins
-        with pytest.raises(ValueError):
+        assert resolve_decode_kernel("interpret") == "interpret"
+        with pytest.raises(ValueError, match="decode_kernel must be one of"):
             resolve_decode_kernel("flash")
 
     def test_warn_once_dense_fallback_off_tpu(self, caplog):

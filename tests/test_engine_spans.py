@@ -2,8 +2,9 @@
 "Serving iteration spans"): one ``serving/step`` an iteration with its phases
 as children, in order, and the request-level stamps and spans beside them.
 
-One engine run a path (ragged prefill, dense chunks, flat arena, fused
-burst, speculative verify), read back from the process-wide span ring.
+One engine run a path (the packed prefill on its kernel and on its dense
+reference, quantized pages, fused burst, speculative verify), read back
+from the process-wide span ring.
 """
 
 import dataclasses
@@ -29,8 +30,9 @@ NEW_TOKENS = 5
 
 PATHS = {
     "ragged": dict(page_size=8, kernels=True),
-    "dense": dict(page_size=8),
-    "flat": dict(),
+    "dense": dict(page_size=8),  # the packed dispatch on its reference
+    # quantized pages: the kernels with the split-threading arm of the decode step
+    "int8": dict(page_size=8, kernels=True, kv_cache_dtype="int8"),
     "burst": dict(page_size=8, kernels=True, steps_per_call=2),
     "verify": dict(page_size=8, kernels=True, spec_draft_len=2),
 }
@@ -109,12 +111,11 @@ def test_every_step_has_its_phases_in_order_and_they_cover_it(runs, path):
         if "serving/prefill_dispatch" in names:
             dispatched += 1
             assert "serving/prefill_commit" in names
-            # the packed path fetches its first tokens after every dispatch;
-            # a dense chunk only after the prompt's last one
-            assert ("serving/prefill_fetch" in names) or path not in ("ragged", "burst", "verify")
+            # the packed dispatch fetches its first tokens every time
+            assert "serving/prefill_fetch" in names
         if "serving/decode_dispatch" in names:
             assert names[-3:] == ["serving/decode_dispatch", "serving/token_fetch", "serving/emit"]
-            assert ("serving/decode_grow" in names) == (path != "flat")
+            assert "serving/decode_grow" in names
         # children lie inside the step, one after another, and cover it
         for a, b in zip(kids, kids[1:]):
             assert a[4] <= b[3]
@@ -138,11 +139,9 @@ def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
     run = runs(path)
     eng = run.engine
     dispatches = run.named("serving/prefill_dispatch")
-    assert sum(s[5]["tokens"] for s in dispatches) == eng._prefill_tokens_dispatched
     assert sum(s[5]["rows"] for s in dispatches) == eng._prefill_rows_dispatched
     assert all(0 < s[5]["tokens"] <= s[5]["rows"] and s[5]["requests"] >= 1 for s in dispatches)
-    if path in ("ragged", "burst", "verify"):
-        assert sum(s[5]["tokens"] for s in dispatches) == eng.prefill_packed_tokens
+    assert sum(s[5]["tokens"] for s in dispatches) == eng.prefill_packed_tokens
     if path == "ragged":
         assert any(s[5]["requests"] > 1 for s in dispatches)  # the long prompt's tail and a short prompt share a grid
     assert sum(s[5]["emitted"] for s in run.named("serving/step")) == eng.generated_tokens
@@ -153,11 +152,10 @@ def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
         1 for r in run.requests if len(r.tokens) == 1) == eng.requests_completed == len(PROMPT_LENS)
     last = run.named("serving/step")[-1][5]
     assert last["queued"] == 0 and last["live"] == 0
-    if path != "flat":
-        grows = run.named("serving/decode_grow")
-        assert sum(s[5]["pages_allocated"] for s in grows) <= eng.pages_allocated
-        assert all(s[5]["walked_tokens"] % 8 == 0 and s[5]["walked_tokens"] > 0 for s in grows)
-        assert last["pages_in_use"] + last["pages_free"] > 0
+    grows = run.named("serving/decode_grow")
+    assert sum(s[5]["pages_allocated"] for s in grows) <= eng.pages_allocated
+    assert all(s[5]["walked_tokens"] % 8 == 0 and s[5]["walked_tokens"] > 0 for s in grows)
+    assert last["pages_in_use"] + last["pages_free"] > 0
     reaps = run.named("serving/reap")
     assert all(s[5] == {"reaped": 0, "shed": 0, "preempted": 0} for s in reaps)
 
@@ -198,7 +196,7 @@ def test_warmup_is_one_span_with_its_compile_counts(runs):
 
 
 def test_an_idle_poll_records_nothing(runs):
-    engine = runs("flat").engine
+    engine = runs("dense").engine
     spans_mod.emit("mark", 0.0, 0.0)
     mark, before = spans_mod.snapshot()[-1][0], engine.iterations
     assert engine.step() is False and engine.step() is False

@@ -30,6 +30,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from accelerate_tpu.generation import generate
 from accelerate_tpu.models import DecoderConfig, DecoderLM
 from accelerate_tpu.ops.attention import (
     decode_attention,
@@ -232,18 +233,18 @@ class TestKvQuantHostHelpers:
 
 
 class TestKvQuantServing:
-    def test_flat_and_paged_int8_token_exact_twins(self, served_model):
+    def test_paged_int8_token_exact_with_generate(self, served_model):
+        """The paged int8 engine against ``generate()`` on an int8 config:
+        both quantize a row once, at its write, and read the stored cache."""
         model, cfg, params, prompts = served_model
         paged = _engine(model, params, kv_cache_dtype="int8")
-        flat = ServingEngine(model, params, num_slots=2, max_cache_len=64,
-                             prefill_chunks=(4, 8), kv_cache_dtype="int8")
-        flat.telemetry = None
-        out_p = paged.generate_batched(prompts, max_new_tokens=6)
-        out_f = flat.generate_batched(prompts, max_new_tokens=6)
-        for a, b in zip(out_p, out_f):
-            np.testing.assert_array_equal(a, b)
+        single = model.clone(config=dataclasses.replace(cfg, kv_cache_dtype="int8"))
+        outs = paged.generate_batched(prompts, max_new_tokens=6)
+        for i, (p, out) in enumerate(zip(prompts, outs)):
+            ref = generate(single, params, p[None], max_new_tokens=6,
+                           rng=jax.random.PRNGKey(i))[0]
+            np.testing.assert_array_equal(out, np.asarray(ref))
         assert paged.metrics()["serving/kv_cache_bits"] == 8
-        assert flat.metrics()["serving/kv_cache_bits"] == 8
 
     def test_arena_shrinks_with_bits(self, served_model):
         model, cfg, params, prompts = served_model
@@ -376,8 +377,6 @@ class TestKvQuantServing:
     def test_single_stream_generate_quantized(self, served_model):
         """generate() on a kv_cache_dtype config runs the quantized dense
         arena (prefill + scalar-index decode) end to end."""
-        from accelerate_tpu.generation import generate
-
         model, cfg, params, prompts = served_model
         qcfg = dataclasses.replace(cfg, kv_cache_dtype="int8", max_cache_len=32)
         out = generate(DecoderLM(qcfg), params, prompts[0][None],

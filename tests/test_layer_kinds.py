@@ -252,7 +252,6 @@ REFUSALS = {
     "speculative verify": dict(spec_draft_len=2),
     "fused decode bursts": dict(steps_per_call=4),
     "quantized pages": dict(kv_cache_dtype="int8"),
-    "the flat slot arena": dict(page_size=None, kind_pages=None),
 }
 
 
@@ -264,6 +263,18 @@ def test_what_cannot_be_right_for_layer_kinds_refuses_by_name(feature):
     params = jax.eval_shape(ARCH.to_program_tree(c), params)
     with pytest.raises(NotImplementedError, match=feature):
         _engine(DecoderLM(cfg), params, **REFUSALS[feature])
+
+
+@pytest.mark.parametrize("page_size", [0, None])
+def test_the_flat_slot_arena_is_gone(page_size):
+    """A falsy page_size asked for the flat arena; it is refused by name,
+    for a model by kind as for any other."""
+    c = tiny(7)
+    cfg = ARCH.decoder_config(c, max_seq_len=256, remat=False)
+    params = jax.eval_shape(lambda: weights.make(REF, c, weights.seed_key(1), jnp.float32))
+    params = jax.eval_shape(ARCH.to_program_tree(c), params)
+    with pytest.raises(ValueError, match="flat slot arena is gone"):
+        _engine(DecoderLM(cfg), params, page_size=page_size, kind_pages=None)
 
 
 def test_kv_handoff_refuses_by_name():

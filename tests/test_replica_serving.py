@@ -277,11 +277,11 @@ class TestKvHandoff:
         bad = dict(handoff, leaves=handoff["leaves"][:-1])
         with pytest.raises(ValueError, match="leaves"):
             b.import_prefix_kv(bad)
-        # flat-arena engines have no pages to hand off
-        flat = ServingEngine(model, params, num_slots=1, max_cache_len=CACHE,
-                             prefill_chunks=CHUNKS)
-        with pytest.raises(ValueError, match="paged arena"):
-            flat.export_prefix_kv(prompts[0])
+        # an engine without the prefix cache has nothing to hand off
+        bare = ServingEngine(model, params, num_slots=1, max_cache_len=CACHE,
+                             prefill_chunks=CHUNKS, prefix_cache=False)
+        with pytest.raises(ValueError, match="prefix cache"):
+            bare.export_prefix_kv(prompts[0])
 
     def test_quantized_handoff_ships_scales_verbatim(self, served_model):
         """int8 arena: the scale leaves ride the same wire and the

@@ -113,16 +113,17 @@ class TestPreemptResumeExactness:
             high.result(),
             _ref(model, params, prompts[3], 3, 5, temperature=1.0, top_k=8))
 
-    def test_flat_arena_preempt_resume_token_exact(self, served_model):
-        """Without pages the resume re-prefills prompt+generated in full —
-        slower, still exact (the evict-and-replay preemption mode)."""
+    def test_no_prefix_cache_preempt_resume_token_exact(self, served_model):
+        """Without the prefix cache the resume re-prefills prompt+generated
+        in full — slower, still exact (the evict-and-replay preemption mode)."""
         model, cfg, params, prompts = served_model
-        engine = _engine(model, params, num_slots=1, page_size=None)
+        engine = _engine(model, params, num_slots=1, prefix_cache=False)
         low = engine.submit(prompts[1], max_new_tokens=8, seed=3, priority=0)
         high = _preempt_once(engine, low, dict(
             prompt=prompts[0], max_new_tokens=3, seed=2, priority=5))
         engine.run()
         assert low.preemptions == 1 and low.outcome == "finished"
+        assert low.prefix_hit == 0  # nothing was published: a whole replay
         np.testing.assert_array_equal(
             low.result(), _ref(model, params, prompts[1], 8, 3))
         np.testing.assert_array_equal(

@@ -3,7 +3,8 @@
 The contracts of record:
 - batched decode is TOKEN-EXACT vs. sequential single-request generate()
   for the same per-request seeds (greedy and sampled);
-- chunked prefill == whole prefill (same tokens, any bucket mix);
+- a prompt admitted over several packed dispatches == one dispatch
+  (same tokens, any grid capacity);
 - slot admission/eviction reuses slots with no cache clearing and no
   cross-request contamination;
 - a warmed engine triggers ZERO compiles across staggered admissions at
@@ -98,21 +99,21 @@ class TestBatchedParity:
         for out, ref in zip(outs, refs):
             np.testing.assert_array_equal(out, ref)
 
-    def test_chunked_prefill_matches_whole_prefill(self, served_model):
-        """Any bucket mix (including a padded tail chunk) yields the same
-        tokens as covering the prompt in one bucket."""
+    def test_prompt_over_several_packed_dispatches_matches_generate(self, served_model):
+        """A prompt longer than the largest grid capacity, admitted over
+        several packed dispatches (the last one padded), yields the same
+        tokens as one dispatch that holds it whole, and as generate()."""
         model, cfg, params, prompts = served_model
-        p = prompts[2]  # len 12: (4,) -> 3 exact chunks; (8,) -> 8 + padded 8
-        whole = ServingEngine(
-            model, params, num_slots=1, max_cache_len=64, prefill_chunks=(16,)
-        ).generate_batched([p], max_new_tokens=5)[0]
-        # (4,): three exact chunks; (8,): one exact + one PADDED tail chunk
-        for chunks in [(4,), (8,)]:
+        p = prompts[2]  # len 12: a grid of 16 rows holds it, one of 8 takes 8 + 4
+        ref = _refs(model, params, prompts, 5)[2]
+        for chunks, dispatches in [((16,), 1), ((4,), 2), ((8,), 2)]:
             engine = ServingEngine(
                 model, params, num_slots=1, max_cache_len=64, prefill_chunks=chunks
             )
-            out = engine.generate_batched([p], max_new_tokens=5)[0]
-            np.testing.assert_array_equal(out, whole)
+            req = engine.submit(p, max_new_tokens=5, seed=2)
+            engine.run()
+            assert req.prefill_dispatches == dispatches, chunks
+            np.testing.assert_array_equal(req.result(), ref)
 
     def test_from_dispatched_offloaded(self, served_model):
         """Serving over a DispatchedModel: the in-graph placement transform
@@ -443,7 +444,9 @@ class TestPlacementSignalContract:
         m = engine.metrics()
         assert m["serving/num_slots"] == 2
         assert m["serving/free_slots"] == 2
-        assert m["serving/load_score"] == 0.0  # idle engine: nothing queued
+        # idle engine: nothing queued, no page out but the parking page
+        assert m["serving/free_pages"] == engine.num_pages - 1
+        assert m["serving/load_score"] == round(1 / engine.num_pages, 6)
 
     def test_score_moves_monotonically_under_perturbation(self, served_model):
         from accelerate_tpu.telemetry.fleet import DRAINING_PENALTY

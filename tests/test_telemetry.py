@@ -95,7 +95,8 @@ class TestMetricsWindow:
 
 class TestFlopsAccounting:
     def test_decoder_formula_matches_bench(self):
-        # the one formula bench.py's headline also uses
+        # 6 x parameters + causal attention, the form the benchmark's
+        # mfu_pct also takes (benchmarks/arch/<model_type>.py)
         assert decoder_flops_per_token(100, 4, 8, 16) == 6 * 100 + 6 * 4 * 8 * 16
 
     def test_flops_fn_from_model_config(self):
