@@ -48,10 +48,15 @@ class Context:
         self.seed, self.seconds = args.seed, float(args.seconds)
         self.trace, self.rehearsal = bool(args.trace), args.cpu_rehearsal
         self.control = args.control
+        # a rehearsal's window may be counted in iterations: what it compares is then the same on every machine
+        self.iterations = getattr(args, "iterations", None)
         settings = json.loads(json.dumps(cell["config_values"]))
         traffic = dict(cell["traffic_values"])
         if self.rehearsal:  # tiny widths and a short trace, from the same files
-            for group, over in settings.pop("rehearsal").items():
+            rehearsal = settings.pop("rehearsal")
+            # limits of its own stand alone: a rehearsal may hold another percentile than the cell
+            settings["limits"] = rehearsal.pop("limits", settings["limits"])
+            for group, over in rehearsal.items():
                 if isinstance(settings.get(group), dict):
                     settings[group].update(over)
                 else:
@@ -105,10 +110,16 @@ def parse_args(argv=None):
     ap.add_argument("--control", choices=("bfloat16", "fp8"), default=None,
                     help="also put the reference at this lower precision in the program's place "
                          "and print what the comparison says of it (set-up of a limit; not a run)")
+    ap.add_argument("--iterations", type=int, default=None,
+                    help="with --cpu-rehearsal: close the window after this many iterations of the serving loop, "
+                         "not after --seconds, so that a test compares the same requests at every pace")
     ap.add_argument("--trace-seconds", type=float, default=None, help="override the mix's traced seconds")
     ap.add_argument("--dump", default=None, help="write the run's counters and per-iteration record here (JSON)")
     ap.add_argument("--keep-trace", default=None, help="copy the .xplane.pb and its description here")
-    return ap.parse_args(argv)
+    args = ap.parse_args(argv)
+    if args.iterations and not args.cpu_rehearsal:
+        ap.error("--iterations counts a rehearsal's window; a run on the chip measures for --seconds")
+    return args
 
 
 def check_devices(cell: dict, rehearsal: bool):

@@ -154,19 +154,33 @@ def test_a_cut_under_the_floors_fails_the_public_values(name, case):
 @pytest.mark.parametrize("seed", [1, 2, 3])
 @pytest.mark.parametrize("name", CELLS)
 def test_control_serving_by_kind_in_fp8_fails_the_limit(name, seed, optimized_xla):
-    """The cell's rehearsal with its control: the program is correct by the
-    rehearsal's limit with room to spare, and the reference computed in fp8
-    in the program's place is not."""
+    """The cell's rehearsal with its control: the program holds every limit of
+    the rehearsal with room (the maximum by 1.5 times, a percentile by 2), and
+    the reference computed in fp8 in the program's place fails a percentile's
+    by 1.5 times (the file's readings leave 2.4 times, CPU). The maximum is
+    not asked to fail the control: one token whose last expert flipped reads
+    as far as the control's worst. Nothing here moves with the machine's
+    pace: the window is 120 iterations of the loop, not seconds, and the
+    sample is drawn from the first requests submitted in it
+    (``closed_loop.pick_sample``), so every machine compares the same tokens
+    of the same requests; that all of them are the window's is asserted."""
     import run as R
 
-    args = types.SimpleNamespace(seed=seed, seconds=3.0, trace=0, cpu_rehearsal=True, control="fp8")
+    args = types.SimpleNamespace(seed=seed, seconds=1.0, iterations=120, trace=0, cpu_rehearsal=True, control="fp8")
     ctx = R.Context(cells.find(name), args)
     out = M.load_driver("closed_loop").run(ctx)
     assert out["correct"] is True and out["failed"] == 0
-    # the rehearsal's limit lies between its two readings: sound runs read 0.0-0.031 over 13 seeds (which tokens a
-    # short window serves depends on the machine), the control 0.087-0.207 over 10
-    assert out["check"]["control_gap"] > 1.3 * ctx.limits["served_logit_gap"]
-    assert ctx.limits["served_logit_gap"] >= 1.3 * out["check"]["served_logit_gap"]
+    assert out["iterations"]["warm_in"] + 120 == len(out["iterations"]["live"])
+    held = [c for c in out["check"]["cases"] if c["held"]]
+    assert len(held) == ctx.traffic["check_requests"] < len(out["check"]["cases"])
+    assert all(c["submit_iter"] >= out["iterations"]["warm_in"] for c in out["check"]["cases"])
+    numbers, control = out["check"]["numbers"], out["check"]["control"]
+    percentiles = [k for k in numbers if k != "served_logit_gap"]
+    assert percentiles and set(numbers) == set(control) == set(ctx.limits)
+    value, limit = numbers["served_logit_gap"]
+    assert limit >= 1.5 * value
+    assert all(limit >= 2 * value for value, limit in (numbers[k] for k in percentiles))
+    assert any(value >= 1.5 * limit for value, limit in (control[k] for k in percentiles)), control
 
 
 @pytest.mark.parametrize("name", CELLS)
