@@ -843,15 +843,21 @@ def _fold_row_positions(pos_ref, b, sq, shape, bound=None):
     return rowpos
 
 
-def _online_softmax_step(s, v, m_scr, l_scr, acc):
+def _online_softmax_step(s, v, m_scr, l_scr, acc, valid=None):
     """Fold one block of masked fp32 scores ``s`` [G, kv] and its values
     ``v`` [kv, D] into the running max, sum and accumulator (fp32; the
-    probabilities are cast to the value dtype before PV)."""
+    probabilities are cast to the value dtype before PV). ``valid`` (the
+    mask ``s`` was made with) where a row may see nothing at all: such a
+    row keeps its maximum at NEG_INF, where exp(s - m) is 1, not 0, so its
+    masked entries are zeroed by name and its sum stays 0 (a row that sees
+    something already underflows to 0 at the exp)."""
     m_prev = m_scr[...][:, :1]
     l_prev = l_scr[...][:, :1]
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_next)
     p = jnp.exp(s - m_next)
+    if valid is not None:
+        p = jnp.where(valid, p, 0.0)
     l_scr[...] = jnp.broadcast_to(
         l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape
     )
@@ -965,21 +971,26 @@ def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
 def _paged_decode_block_pages(kvh: int, ps: int, pd: int, dtype,
                               quant_bits: int, table_len: int,
                               pdv: Optional[int] = None,
-                              window_pages: Optional[int] = None) -> int:
+                              window_pages: Optional[int] = None,
+                              budget: Optional[int] = None,
+                              most: Optional[int] = None,
+                              page_extra: int = 0) -> int:
     """Pages a block of the paged decode walk holds: the largest power of
     two whose double-buffered K and V pages (all kv heads of a page, plus
     their fp32 scale pages when quantized) fit the VMEM budget, at most
     ``_PAGED_DECODE_MAX_BLOCK_PAGES`` and no more than the page table is
     long. ``pdv`` is the value pages' width where it differs from the
     keys'. ``window_pages``: the most pages a window layer's walk spans;
-    one block then holds them all where VMEM allows."""
+    one block then holds them all where VMEM allows. ``budget``, ``most``
+    and ``page_extra`` (bytes a page costs beside its buffers) are the
+    ragged prefill kernel's, which walks the same way."""
     page = kvh * _vmem_tile_bytes(ps, pd, dtype)
     page_v = page if pdv is None else kvh * _vmem_tile_bytes(ps, pdv, dtype)
     if quant_bits:
         page += kvh * _vmem_tile_bytes(ps, 1, jnp.float32)
         page_v += kvh * _vmem_tile_bytes(ps, 1, jnp.float32)
-    fit = _PAGED_DECODE_VMEM_BUDGET // (2 * (page + page_v))
-    cap = max(1, min(fit, _PAGED_DECODE_MAX_BLOCK_PAGES, table_len))
+    fit = (budget or _PAGED_DECODE_VMEM_BUDGET) // (2 * (page + page_v) + page_extra)
+    cap = max(1, min(fit, most or _PAGED_DECODE_MAX_BLOCK_PAGES, table_len))
     n = 1 << (cap.bit_length() - 1)
     if window_pages is not None:
         n = min(n, 1 << (max(1, window_pages) - 1).bit_length())
@@ -1613,24 +1624,24 @@ def paged_decode_attention(
 # ---------------------------------------------------------------------------
 # pallas ragged prefill kernel over the paged arena (ROADMAP item 3)
 #
-# The chunked dense prefill path pads every admission tail to a bucket,
-# gathers the slot's whole arena reservation into a dense view, attends,
-# and scatters the view back — per chunk. This kernel is the prefill
-# counterpart of the decode kernel above: ONE dispatch packs the fresh
-# tails of every pending admission into a fixed token capacity (rows are
-# (token, query-head-group) pairs; padding is only up to the token-block
-# granule, not a bucket), a scalar-prefetched per-block (slot, history)
-# map drives the page-table walk, and the kv sweep per token block is
+# The prefill counterpart of the paged decode kernel above: ONE dispatch
+# packs the fresh tails of every pending admission into a fixed token
+# capacity (rows are (token, query-head-group) pairs; padding is only up
+# to the token-block granule), and the kv sweep of a token block is
 #
-#   [arena pages 0 .. ceil(hist/page)) → packed fresh blocks 0 .. i]
+#   [arena pages first .. ceil(hist/page)) -> packed fresh blocks first .. i]
 #
-# with flash online softmax across both phases. Prefix-aware skipping is
-# structural: positions already served by a prefix-cache / tier hit are
-# never re-attended as QUERIES (only the fresh tail packs rows), and the
-# kv walk visits exactly the slot's live prefix pages — blocks past
-# ``ceil(hist/page)`` and fresh blocks of other slots (or causally-later
-# blocks of the same slot) are clamped in the index map and skipped by
-# ``pl.when``, so an elided block costs neither DMA nor compute.
+# with flash online softmax across both phases. The layer's pages stay in
+# HBM; a grid step is one token block, and inside it the block's slot's
+# live table entries are walked in blocks of many pages, each page (all its
+# kv heads) brought by one asynchronous copy into one half of a double
+# buffer while the other half is attended, as the decode kernel does. So
+# the work is the live pages: a padding block (slot -1) or a slot with no
+# history copies nothing, a window layer starts at the first page its
+# first row sees, and the fresh phase visits only the packed blocks of the
+# block's own slot at or before it. Prefix-aware skipping is structural:
+# positions already served by a prefix-cache / tier hit are never
+# re-attended as QUERIES (only the fresh tail packs rows).
 # Quantize-on-write is fused: the kernel quantizes each fresh K/V block
 # in-register (the exact ``utils.quantization.quantize_kv`` op
 # sequence), emits payload+scale outputs for the caller's single arena
@@ -1643,13 +1654,23 @@ _PREFILL_KERNEL_MODES = ("ragged", "dense", "interpret")
 # default q token block: one sublane tile; the packer pads each tail to
 # this granule (vs a whole prefill bucket on the chunked path)
 _PREFILL_TOKEN_BLOCK = 8
-# the tallest block a serving engine packs with. The kernel's grid is
-# (capacity / block) x kv heads x (table entries + capacity / block) steps
-# of some 0.2-0.5 us each, nearly all of them dead, so its time falls
-# almost in proportion to the block: on the chip, at the serving cells'
-# shape (PERF.md section 6, PR 25), 8 -> 16 -> 32 -> 64 rows cut the chat
-# cell's time to first token 4,455 -> 2,265 -> 1,740 -> 1,416 ms.
+# the tallest block a serving engine packs with: a grid step is one token
+# block, and a taller one shares each page it walks among more rows. From
+# the chip sweep of PR 25 at the serving cells' shape (8 -> 16 -> 32 -> 64
+# rows cut the chat cell's time to first token 4,455 -> 1,416 ms under the
+# grid form that stood then); not swept again under the walk.
 _PREFILL_TOKEN_BLOCK_MAX = 64
+# VMEM the prefill kernel may spend on a block of its walk: the K/V page
+# buffers (two halves each, scale pages included) and the two fp32 score
+# tiles a head's rows take over the block's positions. From the chip sweep
+# of PR 35 at the serving cells' shapes (PERF.md section 6): blocks of 32
+# and 64 pages ran alike, 16 up to 1.6 times slower over a long history.
+# The kv heads of a step are a loop: as straight-line code they ran 10-15%
+# faster over a long history (1% of a pack) and compiled 3-5 times longer.
+_PREFILL_VMEM_BUDGET = 8 * 1024 * 1024
+_PREFILL_MAX_BLOCK_PAGES = 32
+# position of a kv row that no query row may see (every real one is less)
+_UNSEEN = 2 ** 30
 
 
 def prefill_token_block(capacities) -> int:
@@ -1693,12 +1714,17 @@ def _warn_prefill_fallback(reason: str):
 
 
 def _prefill_kernel_gate(mode: str, d: int, ps: int, bt: int,
-                         quant_bits: int = 0):
-    """(use_kernel, interpret) for one ragged prefill dispatch. Shape
-    rules mirror the decode gate: head_dim a 64-multiple compiled (64
-    lane-pads as a narrow tile), page size and token block 8-multiples
-    (sublane tiles), int4 payload width ``d // 2`` itself a 64-multiple
-    (head_dim a 128-multiple)."""
+                         quant_bits: int = 0, dv: Optional[int] = None):
+    """(use_kernel, interpret) for one ragged prefill dispatch. Compiled,
+    the kernel takes key and value widths (``d``, ``dv``; absent: the
+    same) that are 64-multiples, page size and token block 8-multiples
+    (sublane tiles), and an int4 payload (``d // 2`` wide) that is itself
+    a 64-multiple: every KV storage and width the grid form of before
+    PR 35 took (tests/test_tpu_compile.py compiles each by name). It
+    copies whole pages out of the arena in HBM as the paged decode kernel
+    does, which Mosaic takes in whole lanes only, so a page narrower than
+    a 128-multiple and the scale pages reach it as lane-dense views made
+    before the call (``_ragged_prefill_kernel_call``)."""
     if mode == "dense":
         return False, False
     if ps <= 0 or bt <= 0:
@@ -1709,19 +1735,22 @@ def _prefill_kernel_gate(mode: str, d: int, ps: int, bt: int,
     if jax.default_backend() != "tpu":
         _warn_prefill_fallback(f"no TPU backend ({jax.default_backend()} process)")
         return False, False
-    if d % 64 != 0 or ps % 8 != 0 or bt % 8 != 0:
+    dv = d if dv is None else dv
+    if d % 64 != 0 or dv % 64 != 0 or ps % 8 != 0 or bt % 8 != 0:
         _warn_prefill_fallback(
-            f"shape gate: head_dim {d} must be a 64-multiple and the page "
-            f"size {ps} / token block {bt} 8-multiples for the compiled "
-            "kernel; admissions resolve to the chunked dense prefill path"
+            f"shape gate: head_dim {d}"
+            + (f" (values {dv})" if dv != d else "")
+            + f" must be a 64-multiple and the page size {ps} / token "
+            f"block {bt} 8-multiples for the compiled kernel; the packed "
+            "dispatch runs its dense reference"
         )
         return False, False
-    if quant_bits == 4 and (d // 2) % 64 != 0:
+    if quant_bits == 4 and ((d // 2) % 64 != 0 or (dv // 2) % 64 != 0):
         _warn_prefill_fallback(
             f"shape gate: int4 KV packs the payload to head_dim/2 = "
             f"{d // 2}, which must itself be a 64-multiple for the "
-            "compiled kernel (head_dim a 128-multiple); admissions "
-            "resolve to the chunked dense prefill path"
+            "compiled kernel (head_dim a 128-multiple); the packed "
+            "dispatch runs its dense reference"
         )
         return False, False
     return True, False
@@ -1729,10 +1758,9 @@ def _prefill_kernel_gate(mode: str, d: int, ps: int, bt: int,
 
 def prefill_kernel_active(config) -> bool:
     """Would a packed ragged prefill dispatch on a model with this config
-    run the pallas kernel in this process? The serving engine's admission
-    planner keys its SHAPE of work off this (packed ragged dispatch vs
-    per-slot bucket chunks) — it must mirror
-    :func:`ragged_prefill_attention`'s gate exactly."""
+    run the pallas kernel in this process? The serving engine's
+    ``serving/prefill_kernel_active`` gauge and per-request record read it
+    — it must mirror :func:`ragged_prefill_attention`'s gate exactly."""
     page_size = getattr(config, "kv_page_size", None)
     if not page_size:
         return False
@@ -1744,9 +1772,10 @@ def prefill_kernel_active(config) -> bool:
     quant_bits = {"int8": 8, "int4": 4}.get(
         getattr(config, "kv_cache_dtype", "bf16"), 0
     )
+    head_dim = int(getattr(config, "head_dim", 0) or 0)
     use, _ = _prefill_kernel_gate(
-        mode, int(getattr(config, "head_dim", 0) or 0), int(page_size), bt,
-        quant_bits,
+        mode, paged_key_lanes(head_dim), int(page_size), bt, quant_bits,
+        dv=int(getattr(config, "v_head_dim", None) or head_dim),
     )
     return use
 
@@ -1765,172 +1794,266 @@ def _quantize_block(x, bits):
     return kv_payload(qf, bits), scale, qf * scale
 
 
-def _prefill_window_kernel_entry(bslot_ref, bhist_ref, tbl_ref, blo_ref, *refs,
-                                 has_sink, **kw):
-    """A window layer's entry: a fourth prefetched scalar a token block,
-    the table entry its arena walk starts at, and (``has_sink``) the rows'
-    learned scalars after the positions."""
-    q_ref, k_ref, v_ref, kn_ref, vn_ref, qpos_ref, kvpos_ref = refs[:7]
-    rest = refs[7:]
-    sink_ref = None
+def _prefill_block_pages(kvh: int, ps: int, pd: int, dtype, quant_bits: int,
+                         table_len: int, rows: int,
+                         pdv: Optional[int] = None,
+                         window_pages: Optional[int] = None) -> int:
+    """Pages a block of the ragged prefill walk holds: what
+    :func:`_paged_decode_block_pages` reckons from the pages' shapes, with
+    the prefill kernel's budget and the fp32 scores of the token block's
+    ``rows`` folded rows (two tiles a page) counted beside the buffers. A
+    quantized page's scales are one lane-dense fp32 row here
+    (``_ragged_prefill_kernel_call``): K's and V's, in both halves, and
+    the ``ps`` rows one is broadcast over while a head picks its column."""
+    extra = 2 * rows * ps * 4
+    if quant_bits:
+        lanes = -(-kvh * ps // 128) * 128
+        extra += 2 * (2 * _vmem_tile_bytes(1, lanes, jnp.float32) + ps * lanes * 4)
+    return _paged_decode_block_pages(
+        kvh, ps, pd, dtype, 0, table_len, pdv=pdv,
+        window_pages=window_pages, budget=_PREFILL_VMEM_BUDGET,
+        most=_PREFILL_MAX_BLOCK_PAGES, page_extra=extra)
+
+
+def prefill_walk_pages(hist: int, first_pos: int, ps: int,
+                       window: Optional[int] = None) -> int:
+    """Live pages the arena walk of one token block visits: the table
+    entries from the block's first (0, or the page that holds the first
+    position a row at ``first_pos``, the block's first row, sees through
+    the window) up to ``ceil(hist / ps)``. The kernel's own count, on the
+    host: what the serving engine sums into ``pages_walked``."""
+    lo = 0 if window is None else max(first_pos - window + 1, 0) // ps
+    return max(-(-hist // ps) - lo, 0)
+
+
+def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
+                           q_ref, *refs, sm_scale, bt, block_pages,
+                           key_lanes, value_lanes, quant_bits=0,
+                           out_dtype=None, window=None, has_sink=False,
+                           value_scale=1.0):
+    """One token block a grid step: ``bt`` packed rows of one slot, folded
+    with their query-head group into ``[KVH, bt*group, D]``.
+
+    Arena phase: a loop over blocks of ``block_pages`` consecutive table
+    entries of the block's slot, from entry ``blo_ref[i]`` (0, or a window
+    layer's first page: the pages before it may have been given back and
+    are never read) up to ``ceil(hist / page)``. Every live page of a
+    block comes from the arena (left in HBM) by one asynchronous copy that
+    brings all its kv heads, into one half of a double buffer, while the
+    other half is attended head by head. A padding block (slot -1, history
+    0 in ``bhist_ref``) and a slot with no history copy nothing.
+
+    Fresh phase: the packed blocks ``bfirst_ref[i] .. i`` (the first block
+    of the slot's rows through this one; a window layer leaves out those
+    wholly behind its first row's window), one at a time from the packed
+    K/V, which sits in VMEM whole. Fresh K/V is quantized in-register
+    (quantize-on-write): the payload and scale of block ``i`` are this
+    step's outputs, and the tail attends the dequantized form, keeping
+    bit-compatibility with the dense oracle that reads the cache back.
+
+    The mathematics is the dense reference's: fp32 scores, fp32 online
+    softmax and accumulator, probabilities cast to the value dtype before
+    PV; a row at position p sees arena positions ``c < hist, c <= p`` and
+    fresh rows of its slot at positions ``0 <= c <= p`` (a window layer:
+    ``p - c < window`` besides); a row that sees nothing (a pad row) is
+    exactly zero. ``has_sink``: an operand [KVH, bt*group, 1] holds each
+    row's learned scalar, which starts the running maximum with a sum of
+    one and no value. ``value_scale`` multiplies the output. The key pages
+    may be wider than the value pages; quantized pages walk their scale
+    pages with them (a page's scales as one lane-dense row, (kv head,
+    token) the lanes) and are dequantized in-register by
+    ``utils.quantization.dequantize_kv``. Pages come in whole lanes:
+    ``key_lanes`` / ``value_lanes`` are the widths stored, which a
+    zero-padded page (a 64-wide head, an int4 payload) is read at."""
     if has_sink:
-        sink_ref, rest = rest[0], rest[1:]
-    o_ref, acc, m_scr, l_scr = rest
-    _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
-                         kn_ref, vn_ref, qpos_ref, kvpos_ref, o_ref,
-                         acc, m_scr, l_scr, blo_ref=blo_ref, sink_ref=sink_ref, **kw)
-
-
-def _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
-                         kn_ref, vn_ref, qpos_ref, kvpos_ref, o_ref,
-                         acc, m_scr, l_scr, *, sm_scale, ps, bt, group,
-                         npb, ntb, quant_bits=0, out_dtype=None,
-                         ks_ref=None, vs_ref=None, kq_ref=None, kso_ref=None,
-                         vq_ref=None, vso_ref=None, window=None,
-                         value_scale=1.0, blo_ref=None, sink_ref=None):
-    """One (token-block i, kv-head h, kv-step j) cell of the ragged
-    prefill grid. j < ``npb`` walks the q block's slot's live arena pages
-    (the prefix already in the cache — dequantized in-register when the
-    arena is quantized); j >= ``npb`` walks the packed FRESH kv blocks,
-    attending only blocks of the same slot at causally-visible packed
-    positions. Fresh K/V is quantized in-register (quantize-on-write) —
-    payload+scale outputs are written every cell their output window
-    points at (identical values each visit, so revisits are benign) and
-    the tail attends the dequantized form, keeping bit-compatibility
-    with the chunked dense oracle that reads the cache back."""
-    i, j = pl.program_id(0), pl.program_id(2)
-    nj = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        if sink_ref is not None:
-            # the learned scalar joins the denominator and carries no value
-            m_scr[...] = jnp.broadcast_to(sink_ref[0], m_scr.shape)
-            l_scr[...] = jnp.ones_like(l_scr)
-        else:
-            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-        acc[...] = jnp.zeros_like(acc)
-
+        sink_ref, refs = refs[0], refs[1:]
+    kn_ref, vn_ref, qpos_ref, kvpos_ref, k_hbm, v_hbm = refs[:6]
+    if quant_bits:
+        (ks_hbm, vs_hbm, o_ref, kq_ref, kso_ref, vq_ref, vso_ref,
+         kbuf, vbuf, ksbuf, vsbuf, sems, acc, m_scr, l_scr) = refs[6:]
+    else:
+        o_ref, kbuf, vbuf, sems, acc, m_scr, l_scr = refs[6:]
+        ks_hbm = vs_hbm = ksbuf = vsbuf = None
+    i = pl.program_id(0)
+    kvh, ps = k_hbm.shape[1], k_hbm.shape[2]
+    bk = block_pages * ps  # kv positions a block of the walk spans
     slot = bslot_ref[i]
+    row = jnp.maximum(slot, 0)
     hist = bhist_ref[i]
-    n_hist_blocks = (hist + ps - 1) // ps
-    # a window layer's arena walk starts at the first page its block's
-    # earliest row sees (the pages before it may have been given back)
-    lo = 0 if blo_ref is None else blo_ref[i]
+    lo = blo_ref[i]
+    n_pages = jnp.maximum((hist + ps - 1) // ps - lo, 0)
+    n_blocks = (n_pages + block_pages - 1) // block_pages
     # per-row (token, head-group) query positions, expanded per folded
     # row by the caller: a (bt, group) -> (bt*group, 1) reshape in here
     # is a shape cast Mosaic cannot lay out
     rowpos = qpos_ref[0]  # [bt*group, 1]
 
-    # fresh K/V of the block this cell's fresh window points at (clamped
-    # to block 0 during the arena phase): quantize-on-write runs every
-    # cell so every visited output window holds the correct payload
-    kn = kn_ref[0, 0]
-    vn = vn_ref[0, 0]
-    if quant_bits:
-        kp, ksv, kdq = _quantize_block(kn, quant_bits)
-        vp, vsv, vdq = _quantize_block(vn, quant_bits)
-        kq_ref[0, 0] = kp
-        kso_ref[0, 0] = ksv
-        vq_ref[0, 0] = vp
-        vso_ref[0, 0] = vsv
-        k_fresh = kdq.astype(out_dtype)
-        v_fresh = vdq.astype(out_dtype)
-    else:
-        k_fresh, v_fresh = kn, vn
-
-    def _accumulate(s, valid, v):
-        m_prev = m_scr[...][:, :1]
-        l_prev = l_scr[...][:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        # a FULLY-masked row (a pad row, or a tail row in a skipped-slot
-        # block) keeps m_next = NEG_INF, where exp(s - m_next) is 1, not
-        # 0 — zero masked entries explicitly so its l stays 0 and the
-        # safe_l output is exactly 0 (partially-masked rows already
-        # underflow to 0 at the exp)
-        p = jnp.where(valid, jnp.exp(s - m_next), 0.0)
-        l_scr[...] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape
-        )
-        acc[...] = acc[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
-
-    q = q_ref[0, 0]  # [bt*group, D]
-
-    arena_live = (slot >= 0) & (lo + j < n_hist_blocks)
-    if blo_ref is not None:
-        # the window's walk is npb steps long, not the table's length: a
-        # later step belongs to the fresh phase even where pages remain
-        arena_live = arena_live & (j < npb)
-
-    @pl.when(arena_live)
-    def _arena_phase():
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
+    def page_copies(blk, half, j):
+        page = tbl_ref[row, lo + blk * block_pages + j]
+        pairs = [(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)]
         if quant_bits:
+            pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
+        return [
+            pltpu.make_async_copy(src.at[page], dst.at[half, j], sems.at[half, which])
+            for src, dst, which in pairs
+        ]
+
+    def for_each_live_page(blk, half, act):
+        count = jnp.minimum(block_pages, n_pages - blk * block_pages)
+
+        def one(j, _):
+            for copy in page_copies(blk, half, j):
+                act(copy)
+            return _
+
+        jax.lax.fori_loop(0, count, one, None)
+
+    def start(blk, half):
+        for_each_live_page(blk, half, lambda copy: copy.start())
+
+    def wait(blk, half):
+        for_each_live_page(blk, half, lambda copy: copy.wait())
+
+    @pl.when(i == 0)
+    def _first():
+        # the buffers start as zeros: pages past a slot's frontier are
+        # never copied, and what they leave in a block's tail is masked to
+        # probability zero, which only holds against finite values
+        for buf in (kbuf, vbuf, ksbuf, vsbuf):
+            if buf is not None:
+                buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        start(0, 0)
+
+    if has_sink:
+        # the learned scalar joins the denominator and carries no value
+        m_scr[...] = jnp.broadcast_to(sink_ref[...], m_scr.shape)
+        l_scr[...] = jnp.ones_like(l_scr)
+    else:
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+    acc[...] = jnp.zeros_like(acc)
+
+    def attend(h_, k, v, kvpos):
+        """Fold kv rows ``k`` / ``v`` at positions ``kvpos`` [1, rows]
+        (``_UNSEEN`` where no row may see them) into head ``h_``."""
+        s = jax.lax.dot_general(
+            q_ref[0, h_], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale
+        valid = kvpos <= rowpos  # never true of a pad row (position -1)
+        if window is not None:
+            valid = valid & (rowpos - kvpos < window)
+        s = jnp.where(valid, s, NEG_INF)
+        _online_softmax_step(s, v, m_scr.at[h_], l_scr.at[h_], acc.at[h_], valid=valid)
+
+    def each_head(fn):
+        def one(h_, _):
+            fn(h_)
+            return _
+
+        jax.lax.fori_loop(0, kvh, one, None)
+
+    def block(ib, half):
+        @pl.when(ib + 1 < n_blocks)
+        def _():
+            start(ib + 1, 1 - half)
+
+        wait(ib, half)
+        kvp = ((lo + ib * block_pages) * ps
+               + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
+        # only the slot's live prefix: stale arena rows past the frontier,
+        # and the block's tail no page was copied into, never score
+        kvp = jnp.where(kvp < hist, kvp, _UNSEEN)
+
+        def scale_column(sbuf, h_):
+            # a page's scales are one lane-dense row, (kv head, token) the
+            # lanes: each kv row picks its own lane, exactly (the other
+            # lanes add zeros), which stands them up as the column
+            # dequantize_kv takes
+            rows = sbuf[half]  # [block_pages, 1, lanes]
+            lanes = rows.shape[-1]
+            rows = jnp.broadcast_to(rows, (block_pages, ps, lanes)).reshape(bk, lanes)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (bk, lanes), 1)
+            token = jax.lax.broadcasted_iota(jnp.int32, (bk, lanes), 0) % ps
+            return jnp.sum(jnp.where(lane == h_ * ps + token, rows, 0.0),
+                           axis=1, keepdims=True)
+
+        def load(buf, sbuf, h_, lanes):
+            x = buf[half, :, h_]  # [block_pages, ps, whole lanes]
+            if quant_bits:
+                # widen before the pages merge into rows: a page is a
+                # whole number of 32-bit sublane tiles, not of 8-bit ones
+                x = x.astype(jnp.int32)
+            x = x.reshape(bk, x.shape[-1])
+            if lanes != x.shape[-1]:
+                x = x[:, :lanes]  # a zero-padded page, at the width stored
+            if not quant_bits:
+                return x
             from ..utils.quantization import dequantize_kv
 
-            k = dequantize_kv(k, ks_ref[0, 0], quant_bits, out_dtype)
-            v = dequantize_kv(v, vs_ref[0, 0], quant_bits, out_dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        kvp = (lo + j) * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (bt * group, ps), 1
-        )
-        # kvp < hist: only the slot's live prefix (stale arena rows past
-        # the frontier never score); kvp <= rowpos masks pad rows
-        valid = (kvp < hist) & (kvp <= rowpos)
-        if window is not None:
-            valid = valid & (rowpos - kvp < window)
-        s = jnp.where(valid, s, NEG_INF)
-        _accumulate(s, valid, v)
+            return dequantize_kv(x, scale_column(sbuf, h_), quant_bits, out_dtype)
 
-    jf = j - npb
-    kslot = bslot_ref[jnp.clip(jf, 0, ntb - 1)]
+        each_head(lambda h_: attend(
+            h_, load(kbuf, ksbuf, h_, key_lanes),
+            load(vbuf, vsbuf, h_, value_lanes), kvp))
+        return 1 - half
 
-    @pl.when((slot >= 0) & (j >= npb) & (kslot == slot) & (jf <= i))
-    def _fresh_phase():
-        # packed tails are position-ordered per slot, so blocks of the
-        # same slot after this q block (jf > i) are entirely above the
-        # causal frontier — skipped at block level; the per-element mask
-        # below would zero them anyway
-        kvq = kvpos_ref[0, 0].reshape(1, bt)  # [1, bt] fresh positions
-        s = jax.lax.dot_general(
-            q, k_fresh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
-        valid = (kvq >= 0) & (kvq <= rowpos)
-        if window is not None:
-            valid = valid & (rowpos - kvq < window)
-        s = jnp.where(valid, s, NEG_INF)
-        _accumulate(s, valid, v_fresh)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
 
-    @pl.when(j == nj - 1)
-    def _out():
-        l = l_scr[...][:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = acc[...] / safe_l
+    def fresh_kv(h_, jf):
+        kn, vn = kn_ref[h_, jf], vn_ref[h_, jf]  # [bt, D], [bt, Dv]
+        if not quant_bits:
+            return kn, vn, None
+        kp, ksv, kdq = _quantize_block(kn, quant_bits)
+        vp, vsv, vdq = _quantize_block(vn, quant_bits)
+        return kdq.astype(out_dtype), vdq.astype(out_dtype), (kp, ksv, vp, vsv)
+
+    if quant_bits:
+        def write(h_):
+            kp, ksv, vp, vsv = fresh_kv(h_, i)[2]
+            kq_ref[h_, 0] = kp
+            kso_ref[h_, 0] = ksv
+            vq_ref[h_, 0] = vp
+            vso_ref[h_, 0] = vsv
+
+        each_head(write)
+
+    # packed tails are position-ordered per slot, so blocks of the same
+    # slot after this one are entirely above the causal frontier, and
+    # those of other slots are never visited
+    jf0 = bfirst_ref[i]
+    if window is not None:
+        # block jf's last position is (i - jf) * bt - bt + 1 behind this
+        # block's first row, which sees window - 1 positions behind itself
+        jf0 = jnp.maximum(jf0, i - (window + bt - 2) // bt)
+
+    def fresh(jf, carry):
+        kvq = kvpos_ref[jf]  # [1, bt] fresh positions, -1 on a pad row
+        kvq = jnp.where(kvq >= 0, kvq, _UNSEEN)
+        each_head(lambda h_: attend(h_, *fresh_kv(h_, jf)[:2], kvq))
+        return carry
+
+    jax.lax.fori_loop(jf0, jnp.where(slot >= 0, i + 1, jf0), fresh, None)
+
+    def out(h_):
+        l = l_scr[h_][:, :1]
+        o = acc[h_] / jnp.where(l == 0.0, 1.0, l)
         if value_scale != 1.0:
-            out = out * value_scale
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+            o = o * value_scale
+        o_ref[0, h_] = o.astype(o_ref.dtype)
+
+    each_head(out)
 
 
-def _prefill_quant_kernel_entry(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref,
-                                v_ref, ks_ref, vs_ref, kn_ref, vn_ref,
-                                qpos_ref, kvpos_ref, o_ref, kq_ref, kso_ref,
-                                vq_ref, vso_ref, acc, m_scr, l_scr, **kw):
-    _prefill_kernel_body(bslot_ref, bhist_ref, tbl_ref, q_ref, k_ref, v_ref,
-                         kn_ref, vn_ref, qpos_ref, kvpos_ref, o_ref,
-                         acc, m_scr, l_scr, ks_ref=ks_ref, vs_ref=vs_ref,
-                         kq_ref=kq_ref, kso_ref=kso_ref, vq_ref=vq_ref,
-                         vso_ref=vso_ref, **kw)
+def _whole_lanes(x):
+    """``x`` with its last dimension zero-padded to a 128-multiple: Mosaic
+    copies a page out of HBM only in whole lanes
+    (``_decode_kernel_gate``). A copy of the array where it pads."""
+    pad = -x.shape[-1] % 128
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
 def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
@@ -1939,128 +2062,115 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
                                 quant_bits=0, window=None, sink=None,
                                 value_scale=1.0):
     _, h, cap, d = q.shape
-    _, kvh, ps, pd = k_pages.shape
-    dv, pdv = v_new.shape[-1], v_pages.shape[-1]
+    _, kvh, ps, key_lanes = k_pages.shape
+    dv, value_lanes = v_new.shape[-1], v_pages.shape[-1]
+    # the kernel copies whole pages out of HBM, which Mosaic takes in whole
+    # lanes only: a narrower arena (a 64-wide head, an int4 payload) goes
+    # in padded, by a copy of the layer's pages, and the kernel reads the
+    # lanes that are stored. Pages a model stores whole (128; 192-wide
+    # keys at 256, ``paged_key_lanes``) go in as they are.
+    k_pages, v_pages = _whole_lanes(k_pages), _whole_lanes(v_pages)
+    pd, pdv = k_pages.shape[-1], v_pages.shape[-1]
     group = h // kvh
     ntb = cap // bt
     g = bt * group
-    windowed = window is not None or sink is not None
-    if windowed and quant_bits:
-        raise NotImplementedError(
-            "the ragged prefill kernel has no window or sink over quantized pages")
-    # arena steps of the grid: every table entry, or (a window layer) the
-    # pages that can hold the window - 1 positions before a block's first row
-    npb = page_table.shape[1]
-    if window is not None:
-        npb = min(npb, window_span_pages(window - 1, ps))
-    # fold: per kv head, one [bt*group, D] block per token block, rows
+    n = _prefill_block_pages(
+        kvh, ps, pd, k_pages.dtype, quant_bits, page_table.shape[1], g,
+        pdv=None if pdv == pd else pdv,
+        # the window - 1 positions before a block's first row
+        window_pages=None if window is None else window_span_pages(window - 1, ps))
+    # fold: per token block, one [bt*group, D] block a kv head, rows
     # ordered (token, group member) — same convention as _fold_q_heads
-    q_r = (q[0].reshape(kvh, group, cap, d)
-           .transpose(0, 2, 1, 3).reshape(kvh, ntb, g, d))
+    q_r = (q[0].reshape(kvh, group, ntb, bt, d)
+           .transpose(2, 0, 3, 1, 4).reshape(ntb, kvh, g, d))
     kn_r = k_new[0].reshape(kvh, ntb, bt, d)
     vn_r = v_new[0].reshape(kvh, ntb, bt, dv)
     blk_slot = row_slot.reshape(ntb, bt)[:, 0].astype(jnp.int32)
     blk_hist = jnp.where(
         blk_slot >= 0, slot_hist[jnp.maximum(blk_slot, 0)], 0
     ).astype(jnp.int32)
+    first_pos = row_pos.reshape(ntb, bt)[:, 0].astype(jnp.int32)
+    # table entry a block's arena walk starts at: a window layer's is the
+    # page of the first position its first row sees
+    blk_lo = (jnp.maximum(first_pos - window + 1, 0) // ps if window is not None
+              else jnp.zeros_like(first_pos))
+    # first packed block of each block's slot (a slot's rows are contiguous)
+    idx = jnp.arange(ntb, dtype=jnp.int32)
+    starts = jnp.concatenate([jnp.ones((1,), bool), blk_slot[1:] != blk_slot[:-1]])
+    blk_first = jax.lax.cummax(jnp.where(starts, idx, 0))
     pos_in = row_pos.reshape(ntb, 1, bt).astype(jnp.int32)
     # row r of a folded q block is token r // group
     pos_rows = jnp.repeat(row_pos.astype(jnp.int32), group).reshape(ntb, g, 1)
-    prefetch = [blk_slot, blk_hist, page_table.astype(jnp.int32)]
-    if windowed:
-        first = row_pos.reshape(ntb, bt)[:, 0].astype(jnp.int32)
-        lo = (jnp.maximum(first - window + 1, 0) // ps if window is not None
-              else jnp.zeros_like(first))
-        prefetch.append(lo.astype(jnp.int32))
+    prefetch = [blk_slot, blk_hist, page_table.astype(jnp.int32), blk_lo, blk_first]
 
-    if windowed:
-        entry = functools.partial(_prefill_window_kernel_entry, has_sink=sink is not None)
-    else:
-        entry = _prefill_quant_kernel_entry if quant_bits else _prefill_kernel_body
     kernel = functools.partial(
-        entry, sm_scale=sm_scale, ps=ps, bt=bt, group=group, npb=npb,
-        ntb=ntb, quant_bits=quant_bits, out_dtype=q.dtype,
-        **({"window": window, "value_scale": value_scale}
-           if windowed or value_scale != 1.0 else {}),
+        _ragged_prefill_kernel, sm_scale=sm_scale, bt=bt, block_pages=n,
+        key_lanes=key_lanes, value_lanes=value_lanes, quant_bits=quant_bits,
+        out_dtype=q.dtype, window=window, has_sink=sink is not None, value_scale=value_scale,
     )
 
-    def _page_spec(width):
-        # arena phase: walk the q block's slot's live prefix pages; dead
-        # steps (past ceil(hist/ps), or the whole fresh phase) re-address
-        # the last live page so their fetch is elided
-        def index(i, h_, j, bs, bh, tb, *lo_):
-            entry_ = j + lo_[0][i] if lo_ else j
-            return (tb[jnp.maximum(bs[i], 0),
-                       jnp.clip(entry_, 0, jnp.maximum((bh[i] + ps - 1) // ps - 1, 0))],
-                    h_, 0, 0)
+    def per_block(*block):
+        return pl.BlockSpec((1,) + block, lambda i, *_: (i,) + (0,) * len(block))
 
-        return pl.BlockSpec((1, 1, ps, width), index)
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda i, *_: (0,) * x.ndim)
 
-    def _fresh_spec(width):
-        # fresh phase: packed kv block j - npb (clamped to 0 during the
-        # arena phase — its window doubles as the quantize-on-write
-        # target, so it must always point at a real block)
-        return pl.BlockSpec(
-            (1, 1, bt, width),
-            lambda i, h_, j, *_: (h_, jnp.clip(j - npb, 0, ntb - 1), 0, 0),
-        )
+    def fresh_out(width):
+        # block i of the packed rows, every kv head: this step's payloads
+        return pl.BlockSpec((kvh, 1, bt, width), lambda i, *_: (0, i, 0, 0))
 
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda i, h_, j, *_: (h_, i, 0, 0)),
-        _page_spec(pd),
-        _page_spec(pdv),
-    ]
-    operands = [q_r, k_pages, v_pages]
-    if quant_bits:
-        in_specs += [_page_spec(1), _page_spec(1)]
-        operands += [k_scale, v_scale]
-    in_specs += [
-        _fresh_spec(d),
-        _fresh_spec(dv),
-        pl.BlockSpec((1, g, 1), lambda i, h_, j, *_: (i, 0, 0)),
-        pl.BlockSpec((1, 1, bt),
-                     lambda i, h_, j, *_: (jnp.clip(j - npb, 0, ntb - 1), 0, 0)),
-    ]
-    operands += [kn_r, vn_r, pos_rows, pos_in]
+    arena = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs, operands = [per_block(kvh, g, d)], [q_r]
     if sink is not None:
         # row r of a folded q block is group member r % group of its kv head
         rows = jnp.tile(sink.astype(jnp.float32).reshape(kvh, 1, group), (1, bt, 1))
         operands.append(rows.reshape(kvh, g, 1))
-        in_specs.append(pl.BlockSpec((1, g, 1), lambda i, h_, j, *_: (h_, 0, 0)))
-
-    out_specs = [
-        pl.BlockSpec((1, 1, g, dv), lambda i, h_, j, *_: (h_, i, 0, 0)),
-    ]
-    out_shape = [jax.ShapeDtypeStruct((kvh, ntb, g, dv), q.dtype)]
+        in_specs.append(whole(operands[-1]))
+    operands += [kn_r, vn_r, pos_rows, pos_in, k_pages, v_pages]
+    in_specs += [whole(kn_r), whole(vn_r), per_block(g, 1), whole(pos_in), arena, arena]
+    buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype),
+               pltpu.VMEM((2, n, kvh, ps, pdv), v_pages.dtype)]
+    out_specs = [per_block(kvh, g, dv)]
+    out_shape = [jax.ShapeDtypeStruct((ntb, kvh, g, dv), q.dtype)]
     if quant_bits:
-        for width, dt in ((pd, jnp.int8), (1, jnp.float32),
-                          (pdv, jnp.int8), (1, jnp.float32)):
-            out_specs.append(_fresh_spec(width))
+        # per-(page, kv-head, token) fp32 scales ride the same walk, a
+        # page's as one lane-dense row (a view made here: the scale pages
+        # end in a dimension of 1 as stored, which is no whole lane)
+        scales = [_whole_lanes(x.reshape(x.shape[0], 1, kvh * ps))
+                  for x in (k_scale, v_scale)]
+        operands += scales
+        in_specs += [arena, arena]
+        buffers += [pltpu.VMEM((2, n) + scales[0].shape[1:], jnp.float32)] * 2
+        for width, dt in ((key_lanes, jnp.int8), (1, jnp.float32),
+                          (value_lanes, jnp.int8), (1, jnp.float32)):
+            out_specs.append(fresh_out(width))
             out_shape.append(jax.ShapeDtypeStruct((kvh, ntb, bt, width), dt))
-
+    scratch = buffers + [
+        pltpu.SemaphoreType.DMA((2, 2)),
+        _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(ntb, kvh, npb + ntb),
+        grid=(ntb,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[_vmem((g, dv)), _vmem((g, 128)), _vmem((g, 128))],
+        scratch_shapes=scratch,
     )
     outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         name="ragged_prefill_attn",
-        # token blocks revisit the quantize-on-write output windows, so
-        # the grid's outer dim must stay sequential ("arbitrary")
-        **_grid_params(interpret, ("arbitrary", "parallel", "arbitrary")),
+        # the first step zeroes the page buffers for those after it
+        **_grid_params(interpret, ("arbitrary",)),
     )(*prefetch, *operands)
     o = outs[0]  # out_shape is a list, so pallas returns a list
-    out = (o.reshape(kvh, ntb, bt, group, dv)
-           .transpose(0, 3, 1, 2, 4).reshape(1, h, cap, dv))
+    out = (o.reshape(ntb, kvh, bt, group, dv)
+           .transpose(1, 3, 0, 2, 4).reshape(1, h, cap, dv))
     if quant_bits:
-        k_pay = jnp.swapaxes(outs[1].reshape(kvh, cap, pd), 0, 1)
+        k_pay = jnp.swapaxes(outs[1].reshape(kvh, cap, key_lanes), 0, 1)
         k_scl = jnp.swapaxes(outs[2].reshape(kvh, cap, 1), 0, 1)
-        v_pay = jnp.swapaxes(outs[3].reshape(kvh, cap, pdv), 0, 1)
+        v_pay = jnp.swapaxes(outs[3].reshape(kvh, cap, value_lanes), 0, 1)
         v_scl = jnp.swapaxes(outs[4].reshape(kvh, cap, 1), 0, 1)
     else:
         k_pay = jnp.swapaxes(k_new[0], 0, 1)
@@ -2175,11 +2285,15 @@ def ragged_prefill_attention(
     capacity. ``row_slot``/``row_pos`` [CAP] int32 map each packed row to
     its (slot, absolute position); -1 marks padding (only up to the
     token-block granule). Rows of one slot must be contiguous,
-    position-ordered, and token-block aligned — the packer's contract.
+    position-ordered, and token-block aligned — the packer's contract,
+    which the kernel leans on: a token block attends the packed blocks from
+    the first of its slot's run (where the slot id last changed) through
+    itself, so rows of one slot in two runs would not see each other.
     ``slot_hist`` [S] int32 is each slot's live prefix length (tokens
     already in the arena: a prefix-cache/tier hit plus earlier packed
     dispatches of a long tail); the kernel walks exactly
-    ``ceil(hist/page_size)`` arena pages per token block and never
+    ``ceil(hist/page_size)`` arena pages per token block, in blocks of
+    many pages copied out of HBM (``_ragged_prefill_kernel``), and never
     re-attends served positions as queries — the prefix-aware skip.
 
     Returns ``(out [1, H, CAP, D], k_payload, k_scale, v_payload,
@@ -2194,10 +2308,9 @@ def ragged_prefill_attention(
     output has their width. ``window``: a row at position p sees positions
     ``p - window < c <= p`` of its slot, in the arena and among the packed
     rows; the kernel's arena walk then starts at the first page the token
-    block's earliest row sees and is ``window_span_pages`` long instead
-    of the table's length, so the pages behind the window are neither
-    read nor stepped over. ``sink`` [H] and ``value_scale`` are
-    :func:`mha_reference`'s."""
+    block's earliest row sees and spans at most ``window_span_pages``, so
+    the pages behind the window (which may have been given back) are never
+    read. ``sink`` [H] and ``value_scale`` are :func:`mha_reference`'s."""
     mode = resolve_prefill_kernel(impl)
     b, h, cap, d = q.shape
     if b != 1:
@@ -2216,7 +2329,9 @@ def ragged_prefill_attention(
     slot_hist = jnp.asarray(slot_hist, jnp.int32)
     if mode != "dense":
         use, interpret = _prefill_kernel_gate(
-            mode, d, k_pages.shape[2], bt, kv_quant_bits
+            mode, k_pages.shape[-1] * (2 if kv_quant_bits == 4 else 1),
+            k_pages.shape[2], bt, kv_quant_bits,
+            dv=v_pages.shape[-1] * (2 if kv_quant_bits == 4 else 1),
         )
         if use:
             return _ragged_prefill_kernel_call(

@@ -55,6 +55,7 @@ from ..ops.attention import (
     paged_decode_block_pages,
     prefill_kernel_active,
     prefill_token_block,
+    prefill_walk_pages,
 )
 from .pages import (
     NGramDrafter,
@@ -2445,7 +2446,7 @@ class ServingEngine:
         """Dispatch one packed grid, fetch its first tokens, and put every
         pack that completed into its slot."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
-                   requests=len(packs)) as sp:
+                   requests=len(packs), **self._pages_walked(packs)) as sp:
             self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
                 self._tables_arg(), last_rows, rngs,
@@ -2515,6 +2516,27 @@ class ServingEngine:
                 first_tokens += 1
             sp_c.args["first_tokens"] = first_tokens
         return True
+
+    def _pages_walked(self, packs: list) -> dict:
+        """What the ragged prefill kernel is handed in this pack, for the
+        ``serving/prefill_dispatch`` span: ``pages_walked`` is the sum over
+        the pack's token blocks of the live pages the arena walk of one
+        layer of the first cache kind visits (``prefill_walk_pages``: the
+        kernel's own count, from each pack's history and first positions);
+        a model of several kinds adds ``pages_walked.<kind>`` for the
+        others (a window kind walks its window's pages only). 0 where the
+        kernel does not engage: the dense reference walks nothing."""
+        bt, ps = self._ragged_bt, self.page_size
+
+        def pages(kind):
+            if not self._prefill_kernel_costed:
+                return 0
+            return sum(prefill_walk_pages(s0, pos, ps, kind.window)
+                       for _, _, s0, s1, *_ in packs for pos in range(s0, s1, bt))
+
+        first, *others = self._kinds
+        return {"pages_walked": pages(first),
+                **{f"pages_walked.{kind.name}": pages(kind) for kind in others}}
 
     def _burst_len(self) -> int:
         """steps_per_call when a fused burst cannot delay an admission or
@@ -2901,6 +2923,9 @@ class ServingEngine:
         out["serving/decode_kernel_active"] = bool(self._kernel_costed)
         out["serving/arena_in_place"] = int(self._arena_in_place)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
+        # ... and that kernel walks a slot's live pages in blocks out of HBM
+        # (the one form of it there is: the same bit under the mechanism's name)
+        out["serving/prefill_page_walk"] = int(self._prefill_kernel_costed)
         for kind in self._kinds[1:]:
             out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use
             out[f"serving/pages_total.{kind.name}"] = kind.num_pages

@@ -1,12 +1,15 @@
 """Nestable span tracing: one process-wide ring in memory, always on, and an
 optional Chrome-trace-compatible JSONL stream per host.
 
-Every closed span lands in a bounded ring (``RING_SPANS`` entries) as
+Every closed span lands in a bounded ring (``RING_SPANS`` entries, 65,536:
+a whole measured run of the serving engine, see the constant) as
 ``(id, parent_id, name, t0, t1, args)`` on ``time.perf_counter``;
 ``parent_id`` is the span that enclosed it on the same thread, and
 request-level spans carry ``request_id`` in ``args`` so the spans of one
 request share an identifier. ``snapshot()`` returns the ring's content,
-``dropped()`` how many spans the ring has forgotten since the process began,
+``dropped()`` how many spans the ring has forgotten since the process began
+(a reader that needs a whole run checks it: past 0 the run's first spans may
+be gone),
 ``last_spans()`` the newest few as the watchdog and the flight recorder
 print them. There is no switch: the serving engine's iteration spans
 (``serving/step`` and its children, docs/telemetry.md) are recorded whether
@@ -40,7 +43,20 @@ import time
 from collections import deque
 from typing import Optional
 
-RING_SPANS = 16384
+# The ring holds a whole run of the serving engine, so that a reader of
+# one (benchmarks/program_spans.py) never finds its beginning overwritten.
+# An iteration closes about 10 spans on the serving cells' mixes (7 when it
+# only decodes: serving/step and its six phases; when it admits, three more
+# around the prefill dispatch, a serving/prefill_chunk a packed request,
+# and serving/queue_wait and serving/first_token a request;
+# tests/test_engine_spans.py counts them), so 65,536 hold 6,500
+# iterations: the 800 warm-in iterations of the longest mix and a 51 s
+# window at 8.9 ms an iteration, less than the 9.2 ms a decode step takes
+# to read a 7B model's 16 layers of weights once. 16,384 wrapped inside a
+# run as soon as an iteration fell from 66 to 55 ms (PERF.md, PR 33). No
+# knob: a deque's append costs the same at any length, and 65,536 tuples
+# with their args are tens of MB of host memory at the most.
+RING_SPANS = 65536
 
 _RECORDER: Optional["SpanRecorder"] = None
 _tls = threading.local()
@@ -77,7 +93,7 @@ def last_spans(n: int = 16) -> list:
     """The most recently closed spans (newest last) as ``{"name",
     "end_unix_s", "dur_s"}`` — what a stall report prints."""
     with _ring_lock:
-        recent = list(_ring)[-n:] if n > 0 else []
+        recent = list(itertools.islice(reversed(_ring), max(n, 0)))[::-1]
     unix_minus_perf = time.time() - time.perf_counter()
     return [{"name": name, "end_unix_s": t1 + unix_minus_perf, "dur_s": t1 - t0}
             for _, _, name, t0, t1, _ in recent]

@@ -236,7 +236,7 @@ def check_ragged_prefill(S: Sizes, rng, *, kvh, bits):
     import numpy as np
 
     from accelerate_tpu.ops.attention import _PREFILL_TOKEN_BLOCK as bt
-    from accelerate_tpu.ops.attention import ragged_prefill_attention
+    from accelerate_tpu.ops.attention import _prefill_kernel_gate, ragged_prefill_attention, resolve_prefill_kernel
     from accelerate_tpu.utils.quantization import unpack_int4_kv
 
     cfg = llama_cfg(S, 1)
@@ -294,7 +294,8 @@ def check_ragged_prefill(S: Sizes, rng, *, kvh, bits):
             byte_mismatch = max(byte_mismatch, float((diff > 0).mean()))
         if byte_mismatch > 1e-3:
             raise AssertionError(f"quantize-on-write payload mismatch share {byte_mismatch:.2e}")
-    return float(np.max(np.abs(out - out_ref))), byte_mismatch
+    kernel, _ = _prefill_kernel_gate(resolve_prefill_kernel(S.kernel_mode), d, ps, bt, bits)
+    return float(np.max(np.abs(out - out_ref))), byte_mismatch, kernel
 
 
 def kernels_phase(S: Sizes, seed: int, on_chip: bool) -> None:
@@ -310,9 +311,10 @@ def kernels_phase(S: Sizes, seed: int, on_chip: bool) -> None:
             for sq, (err, kernel) in check_paged_decode(S, rng, kvh=kvh, bits=bits).items():
                 say(f"  paged decode   {heads}q/{kvh}kv {kv} Sq={sq}: max|kernel-ref|={err:.4f}"
                     + ("" if kernel else " (gated to the dense path: no kernel ran)"))
-            err, mism = check_ragged_prefill(S, rng, kvh=kvh, bits=bits)
+            err, mism, kernel = check_ragged_prefill(S, rng, kvh=kvh, bits=bits)
             say(f"  ragged prefill {heads}q/{kvh}kv {kv}: max|kernel-ref|={err:.4f}"
-                + (f", payload mismatch share={mism:.1e}" if bits else ""))
+                + (f", payload mismatch share={mism:.1e}" if bits else "")
+                + ("" if kernel else " (gated to the dense path: no kernel ran)"))
 
 
 # ---------------------------------------------------------------------------

@@ -47,27 +47,41 @@ def model_and_params():
     return model, cfg, params
 
 
+def _mark():
+    spans_mod.emit("mark", 0.0, 0.0)
+    return spans_mod.snapshot()[-1][0]
+
+
+def _spans_since(mark):
+    ring = spans_mod.snapshot()
+    assert ring[0][0] <= mark  # the mark is still there: the ring did not wrap since
+    return [s for s in ring if s[0] > mark]
+
+
+def _engine(model, cfg, params, *, kernels=False, **kw):
+    """The tests' engine; ``kernels``: both serving kernels, interpreted."""
+    if kernels:
+        cfg = dataclasses.replace(cfg, decode_kernel="interpret", decode_kernel_block=8,
+                                  prefill_kernel="interpret")
+        model = model.clone(config=cfg)
+    args = dict(num_slots=2, max_cache_len=64, page_size=8, prefill_chunks=(8, 16))
+    args.update(kw)
+    return ServingEngine(model, params, **args)
+
+
 class Run:
     """One engine driven to the end, with what the ring holds of it."""
 
-    def __init__(self, model, cfg, params, *, kernels=False, telemetry=None, **kw):
-        if kernels:
-            cfg = dataclasses.replace(cfg, decode_kernel="interpret", decode_kernel_block=8,
-                                      prefill_kernel="interpret")
-            model = model.clone(config=cfg)
-        spans_mod.emit("mark", 0.0, 0.0)
-        mark = spans_mod.snapshot()[-1][0]
-        self.engine = ServingEngine(model, params, num_slots=2, max_cache_len=64,
-                                    prefill_chunks=(8, 16), telemetry=telemetry, **kw)
+    def __init__(self, model, cfg, params, **kw):
+        mark = _mark()
+        self.engine = _engine(model, cfg, params, **kw)
         self.engine.warmup()
         rng = np.random.RandomState(0)
         self.requests = [self.engine.submit(rng.randint(3, cfg.vocab_size, (n,)),
                                             max_new_tokens=NEW_TOKENS, seed=i)
                          for i, n in enumerate(PROMPT_LENS)]
         self.engine.run()
-        ring = spans_mod.snapshot()
-        assert ring[0][0] <= mark  # the mark is still there: the ring did not wrap inside this run
-        self.spans = [s for s in ring if s[0] > mark]
+        self.spans = _spans_since(mark)
 
     def named(self, name):
         return [s for s in self.spans if s[2] == name]
@@ -197,10 +211,9 @@ def test_warmup_is_one_span_with_its_compile_counts(runs):
 
 def test_an_idle_poll_records_nothing(runs):
     engine = runs("dense").engine
-    spans_mod.emit("mark", 0.0, 0.0)
-    mark, before = spans_mod.snapshot()[-1][0], engine.iterations
+    mark, before = _mark(), engine.iterations
     assert engine.step() is False and engine.step() is False
-    assert [s for s in spans_mod.snapshot() if s[0] > mark] == []
+    assert _spans_since(mark) == []
     assert engine.iterations == before
 
 
@@ -234,3 +247,92 @@ def test_request_spans_land_once_in_ring_and_file(model_and_params, tmp_path):
     assert sorted(len(r["prefill_chunks"]) for r in records) == sorted(
         r.prefill_dispatches for r in run.requests)
     assert all("queue_wait_ms" in r for r in records)
+
+
+def test_pages_walked_is_the_count_the_page_table_gives(model_and_params):
+    """``pages_walked`` on ``serving/prefill_dispatch`` is the sum over the
+    pack's token blocks of the live pages the kernel's walk visits: after
+    each iteration, for every request the dispatch carried, the slot's table
+    entries before the rows' first position (all live: a full kind gives
+    nothing back), once a block of 8 rows. The known histories: a 40-token
+    prompt takes three dispatches of 16 rows at histories 0, 16 and 32, the
+    short prompt that finds a free slot packs beside its tail at history 0, and the prompt asked
+    again finds its pages in the prefix cache."""
+    model, cfg, params = model_and_params
+    eng = _engine(model, cfg, params, kernels=True)
+    assert eng.metrics()["serving/prefill_page_walk"] == 1
+    eng.warmup()
+    rng = np.random.RandomState(1)
+    long_prompt = rng.randint(3, cfg.vocab_size, (40,))
+    prompts = [long_prompt] + [rng.randint(3, cfg.vocab_size, (n,)) for n in (5, 12)] + [long_prompt]
+    reqs = [eng.submit(p, max_new_tokens=3, seed=i) for i, p in enumerate(prompts)]
+    bt, ps, tables = eng._ragged_bt, eng.page_size, eng._kinds[0].tables
+    seen, total = [], 0
+    while True:
+        mark = _mark()
+        if not eng.step():
+            break
+        new = _spans_since(mark)
+        dispatches = [s for s in new if s[2] == "serving/prefill_dispatch"]
+        chunks = [s[5] for s in new if s[2] == "serving/prefill_chunk"]
+        assert len(dispatches) <= 1 and bool(chunks) == bool(dispatches)
+        if not dispatches:
+            continue
+        want = 0
+        for chunk in chunks:
+            before = tables.rows[chunk["slot"]][:-(-chunk["start"] // ps)]
+            assert tables.parking not in before
+            want += -(-chunk["bucket"] // bt) * len(before)
+        assert dispatches[0][5]["pages_walked"] == want
+        seen.append((sorted(c["start"] for c in chunks), want))
+        total += want
+    assert all(r.outcome == "finished" for r in reqs)
+    # 16 rows are two blocks over 2 pages, then over 4; 8 rows one block over 4
+    assert seen[:3] == [([0], 0), ([16], 4), ([0, 32], 4)]
+    # the prompt again: everything but its last page is cached, one block walks those pages
+    assert reqs[3].prefix_hit == 32 and seen[-1] == ([32], 4) and total == 12
+    # on its dense reference the engine walks nothing, and says so
+    dense = _engine(model, cfg, params)
+    assert dense.metrics()["serving/prefill_page_walk"] == 0
+    mark = _mark()
+    dense.generate_batched([long_prompt], max_new_tokens=2)
+    assert {s[5]["pages_walked"] for s in _spans_since(mark) if s[2] == "serving/prefill_dispatch"} == {0}
+
+
+def test_the_ring_holds_a_whole_run_of_iterations(model_and_params):
+    """What ``RING_SPANS`` is sized by (telemetry/spans.py): the spans an
+    iteration closes. An engine whose every iteration admits (a queue of
+    prompts of three dispatches each, so most dispatches are mid-prompt, as
+    in the serving cells) closes ``serving/step``, six phases, three more
+    around the prefill dispatch and a ``serving/prefill_chunk`` a packed
+    request, plus ``serving/queue_wait`` and ``serving/first_token`` a
+    request; an iteration that only decodes closes 7. The ring holds 5,000
+    iterations that all admit, and 6,000 of the mix the longest serving cell
+    runs (MiMo: 43 of 79 traced iterations admit; PERF.md section 6), which
+    is that cell's 800 warm-in iterations and a 51 s window at under 10 ms
+    an iteration. A span added to the iteration shows here before a
+    benchmark run loses its ring-read metrics to a wrapped ring."""
+    model, cfg, params = model_and_params
+    eng = _engine(model, cfg, params, kernels=True)
+    eng.warmup()
+    rng = np.random.RandomState(2)
+    for i in range(6):
+        eng.submit(rng.randint(3, cfg.vocab_size, (40,)), max_new_tokens=4, seed=i)
+    admitting, decoding = [], []
+    while True:
+        mark = _mark()
+        if not eng.step():
+            break
+        new = _spans_since(mark)
+        assert new[-1][2] == "serving/step"  # the iteration's own span closes last
+        names = [s[2] for s in new]
+        (admitting if "serving/prefill_dispatch" in names else decoding).append(len(new))
+        if "serving/prefill_dispatch" not in names:
+            assert len(new) == 7, names
+    assert len(admitting) >= 18 and decoding
+    # step + 9 phases + a chunk, and two request spans in one dispatch of three
+    assert 11 <= max(admitting) <= 14
+    per_admitting = sum(admitting) / len(admitting)
+    assert 11 <= per_admitting <= 12.5
+    assert spans_mod.RING_SPANS >= 5000 * per_admitting
+    assert spans_mod.RING_SPANS >= 6000 * (43 / 79 * per_admitting + 36 / 79 * 7)

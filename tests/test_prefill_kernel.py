@@ -12,6 +12,9 @@ Op-level contracts of record, run through the pallas interpreter on CPU
   payload + scales (int8 and int4) in the same pass as attention;
 - pad rows are never observable: they output exactly zero and garbage in
   foreign slots' pages cannot perturb a pack;
+- the walk over a slot's live pages in blocks out of the arena: a history
+  of 0, one that ends inside a page, inside a block and at its edge, one of
+  several blocks, one that fills the table, two slots a pack, padding blocks;
 - dispatch: `prefill_kernel` resolution, the
   warn-once dense fallback off-TPU, `prefill_kernel_active` mirroring
   the gate, config validation.
@@ -42,14 +45,17 @@ ATOL = 2e-5  # fp32 interpreter vs XLA softmax: reassociation-level noise
 
 
 def _packed_case(rng, packs, *, h=4, kvh=2, d=16, ps=8, bt=8,
-                 quant_bits=0):
+                 quant_bits=0, cap=None, table_len=None):
     """Build one packed grid from ``packs`` = [(hist, tail), ...]: rows
     of one slot contiguous and position-ordered, each pack padded up to a
     token-block boundary (pads keep the slot id, pos = -1), per-slot
     page tables position-ordered over disjoint live pages (page 0
-    parked), ``slot_hist[s]`` = live prefix tokens already in the arena."""
+    parked), ``slot_hist[s]`` = live prefix tokens already in the arena.
+    ``cap`` past the packs' rows leaves whole padding blocks (slot -1);
+    ``table_len`` cuts the tables to that many entries (the kernel reads a
+    slot's history pages only)."""
     S = max(1, len(packs))
-    cap = max(bt, sum(-(-t // bt) * bt for _, t in packs))
+    cap = cap or max(bt, sum(-(-t // bt) * bt for _, t in packs))
     row_slot = np.full((cap,), -1, np.int32)
     row_pos = np.full((cap,), -1, np.int32)
     slot_hist = np.zeros((S,), np.int32)
@@ -83,6 +89,7 @@ def _packed_case(rng, packs, *, h=4, kvh=2, d=16, ps=8, bt=8,
     q = rng.standard_normal((1, h, cap, d)).astype(np.float32)
     k_new = rng.standard_normal((1, kvh, cap, d)).astype(np.float32)
     v_new = rng.standard_normal((1, kvh, cap, d)).astype(np.float32)
+    table = table[:, :table_len]
     kw = dict(page_table=jnp.asarray(table), row_slot=jnp.asarray(row_slot),
               row_pos=jnp.asarray(row_pos), slot_hist=jnp.asarray(slot_hist),
               token_block=bt, kv_quant_bits=quant_bits)
@@ -183,17 +190,87 @@ class TestRaggedPackingEdges:
                                       np.asarray(out_garbage[0]))
 
 
+# the walk over a slot's live pages, in blocks of two pages of 8 (the
+# blocks' edges at the sizes a test can hold): (packs, _packed_case's options)
+WALKS = {
+    "history_0": ([(0, 20)], {}),
+    "history_ends_inside_a_page": ([(13, 9)], {}),
+    "history_of_three_blocks": ([(45, 10)], {}),
+    "history_whose_last_block_is_half_full": ([(40, 8)], {}),
+    "history_fills_the_table": ([(48, 8)], dict(table_len=6)),
+    "two_slots_history_ends_inside_a_block_and_at_its_edge": ([(24, 8), (32, 16)], {}),
+    "two_slots_change_after_a_padded_token_block": ([(20, 5), (16, 11)], {}),
+    "second_slot_has_no_history": ([(40, 16), (0, 24)], {}),
+    "padding_blocks_after_the_packs": ([(17, 8)], dict(cap=32)),
+    "padding_blocks_only": ([], dict(cap=24)),
+    "one_kv_head_for_all_query_heads": ([(27, 12), (8, 8)], dict(kvh=1)),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_walk_in_blocks_of_pages_is_the_reference(walk, monkeypatch):
+    """Each edge of the arena walk and of the fresh phase's own-slot visit
+    against the dense reference; pad rows and padding blocks exactly zero."""
+    from accelerate_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_PREFILL_MAX_BLOCK_PAGES", 2)
+    packs, options = WALKS[walk]
+    args, kw, valid = _packed_case(np.random.RandomState(11), packs, **options)
+    _assert_kernel_matches_dense(args, kw, valid, walk)
+
+
+def test_walk_visits_the_live_pages_only(monkeypatch):
+    """Garbage in every page the walk has no business with (other slots'
+    pages, the parking page, a slot's own pages past its history: those of
+    the rows being written) cannot move a pack, at several blocks a slot."""
+    from accelerate_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_PREFILL_MAX_BLOCK_PAGES", 2)
+    args, kw, valid = _packed_case(np.random.RandomState(12), [(40, 12), (21, 8), (0, 8)])
+    clean = ragged_prefill_attention(*args, impl="interpret", **kw)
+    q, k_new, v_new, kp, vp = args
+    table, hist = np.asarray(kw["page_table"]), np.asarray(kw["slot_hist"])
+    live = {int(pg) for s in range(3) for pg in table[s, :-(-int(hist[s]) // 8)]}
+    assert len(live) == 5 + 3
+    for pg in set(range(kp.shape[0])) - live:
+        kp, vp = kp.at[pg].set(1e6), vp.at[pg].set(-1e6)
+    garbage = ragged_prefill_attention(q, k_new, v_new, kp, vp, impl="interpret", **kw)
+    np.testing.assert_array_equal(np.asarray(clean[0]), np.asarray(garbage[0]))
+
+
+def test_walk_counts_its_pages_on_the_host():
+    """``prefill_walk_pages``, what the serving engine sums into
+    ``pages_walked``: the table entries from a block's first to the end of
+    the slot's history."""
+    from accelerate_tpu.ops.attention import prefill_walk_pages, window_span_pages
+
+    assert [prefill_walk_pages(h, h, 16) for h in (0, 1, 16, 17, 3584)] == [0, 1, 1, 2, 224]
+    # a later block of the same pack walks the same history
+    assert prefill_walk_pages(768, 768 + 64, 16) == 48
+    # a window layer: from the page of the first position the block's first row sees ...
+    assert prefill_walk_pages(1000, 1000, 16, window=128) == 63 - 54 == window_span_pages(127, 16)
+    assert prefill_walk_pages(1000, 1064, 16, window=128) == 63 - 58
+    # ... which may lie past the history: the rows see fresh rows only
+    assert prefill_walk_pages(1000, 1128, 16, window=128) == 1  # position 1,001 shares its page with 992-999
+    assert prefill_walk_pages(1000, 1136, 16, window=128) == 0 == prefill_walk_pages(0, 0, 16, window=128)
+    assert prefill_walk_pages(100, 100, 16, window=128) == 7
+
+
 class TestQuantizeOnWrite:
+    @pytest.mark.parametrize("packs", [[(10, 11), (0, 9)], [(45, 10), (24, 8)]],
+                             ids=["one_block_a_slot", "several_blocks_a_slot"])
     @pytest.mark.parametrize("bits", [8, 4])
-    def test_payload_matches_quantize_kv(self, bits):
+    def test_payload_matches_quantize_kv(self, bits, packs, monkeypatch):
         """Fused quantize-on-write (one pass with attention) emits the
         EXACT reference `quantize_kv` payload and scales, and interpret
-        == dense bitwise on both."""
+        == dense bitwise on both; the quantized pages walk their scale
+        pages with them, in blocks of two pages here."""
+        from accelerate_tpu.ops import attention as A
         from accelerate_tpu.utils.quantization import quantize_kv
 
+        monkeypatch.setattr(A, "_PREFILL_MAX_BLOCK_PAGES", 2)
         rng = np.random.RandomState(7)
-        args, kw, valid = _packed_case(rng, [(10, 11), (0, 9)],
-                                       quant_bits=bits)
+        args, kw, valid = _packed_case(rng, packs, quant_bits=bits)
         out_k, out_d = _assert_kernel_matches_dense(args, kw, valid)
         _, kp_k, ks_k, vp_k, vs_k = out_k
         _, kp_d, ks_d, vp_d, vs_d = out_d
@@ -210,6 +287,50 @@ class TestQuantizeOnWrite:
                                           np.asarray(ref_p))
             np.testing.assert_allclose(np.asarray(got_s), np.asarray(ref_s),
                                        atol=1e-7)
+
+    @pytest.mark.parametrize("shape", [
+        dict(h=4, kvh=2, d=64, ps=8, quant_bits=0),     # a 64-wide head: pages padded to 128 lanes
+        dict(h=4, kvh=2, d=192, ps=8, quant_bits=0),    # 192-wide keys as stored: padded to 256
+        dict(h=4, kvh=2, d=128, ps=8, quant_bits=4),    # an int4 payload of 64 lanes
+        dict(h=4, kvh=2, d=64, ps=8, quant_bits=8),     # an int8 payload of 64 lanes
+        dict(h=6, kvh=3, d=128, ps=8, quant_bits=8),    # 24 scales a page: one padded lane row
+        dict(h=4, kvh=4, d=128, ps=64, quant_bits=8),   # 256 scales a page: two whole lane rows
+        dict(h=12, kvh=12, d=16, ps=16, quant_bits=8),  # 192 scales a page: two lane rows, the second half full
+    ], ids=lambda kw: "h{h}_kvh{kvh}_d{d}_ps{ps}_bits{quant_bits}".format(**kw))
+    def test_narrow_and_scale_pages_go_in_as_whole_lanes(self, shape, monkeypatch):
+        """The compiled kernel copies whole pages out of HBM in whole lanes:
+        a page narrower than a 128-multiple goes in zero-padded and is read
+        at its stored width, and a page's scales go in as one lane-dense
+        row, (kv head, token) the lanes, out of which each kv row picks its
+        own. The same numbers as the reference at each shape of the views."""
+        from accelerate_tpu.ops import attention as A
+
+        monkeypatch.setattr(A, "_PREFILL_MAX_BLOCK_PAGES", 2)
+        rng = np.random.RandomState(11)
+        ps = shape["ps"]
+        args, kw, valid = _packed_case(rng, [(2 * ps + 3, 9), (0, 8), (ps, 5)], **shape)
+        out_k = ragged_prefill_attention(*args, impl="interpret", **kw)
+        out_d = ragged_prefill_attention(*args, impl="dense", **kw)
+        # outputs of magnitude 60 over up to 140 positions: the grid form of
+        # before PR 35 lay as far from the reference's order of sums here
+        # (0.0012); a wrong lane or scale is off by whole units
+        np.testing.assert_allclose(np.asarray(out_k[0])[0, :, valid], np.asarray(out_d[0])[0, :, valid], atol=2e-3)
+        np.testing.assert_array_equal(np.asarray(out_k[0])[0, :, ~valid], 0.0)
+        if shape["quant_bits"]:
+            for pay, scl in ((1, 2), (3, 4)):
+                np.testing.assert_array_equal(np.asarray(out_k[pay]), np.asarray(out_d[pay]))
+                np.testing.assert_allclose(np.asarray(out_k[scl]), np.asarray(out_d[scl]), atol=1e-7)
+
+    def test_whole_lanes_pads_the_last_dimension_only(self):
+        from accelerate_tpu.ops.attention import _whole_lanes
+
+        x = jnp.arange(2 * 3 * 64, dtype=jnp.int8).reshape(2, 3, 64)
+        padded = _whole_lanes(x)
+        assert padded.shape == (2, 3, 128) and padded.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(padded[..., :64]), np.asarray(x))
+        assert not np.asarray(padded[..., 64:]).any()
+        whole = jnp.zeros((2, 3, 256), jnp.bfloat16)
+        assert _whole_lanes(whole) is whole
 
     def test_unquantized_returns_no_scales(self):
         rng = np.random.RandomState(8)
@@ -245,6 +366,32 @@ class TestPrefillDispatch:
         np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(ref[0]))
         np.testing.assert_array_equal(np.asarray(again[0]),
                                       np.asarray(ref[0]))
+
+    def test_compiled_gate_takes_every_storage_the_grid_form_took(self, monkeypatch):
+        """On the chip the kernel takes 64-multiple widths, bf16 / int8 /
+        int4 pages (an int4 payload itself a 64-multiple), 8-multiple pages
+        and token blocks, where the paged decode kernel takes whole-lane
+        unquantized pages only: narrow and scale pages reach the walk as
+        lane-dense views. The config-level answer says the same."""
+        from accelerate_tpu.models import DecoderConfig
+        from accelerate_tpu.ops import attention as A
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(A, "_decode_fallback_warned", set())
+        for d, dv, bits, want in [(128, None, 0, True), (256, 128, 0, True), (64, None, 0, True), (192, 128, 0, True),
+                                  (128, None, 8, True), (128, None, 4, True), (128, 64, 0, True), (64, None, 8, True),
+                                  (64, None, 4, False), (128, 64, 4, False), (96, None, 0, False), (128, 32, 0, False)]:
+            assert A._prefill_kernel_gate("ragged", d, 16, 64, bits, dv=dv) == (want, False), (d, dv, bits)
+        assert A._prefill_kernel_gate("ragged", 128, 12, 64) == (False, False)  # page no sublane multiple
+        assert A._prefill_kernel_gate("ragged", 128, 16, 12) == (False, False)  # nor the token block
+        paged = dict(kv_page_size=16, kv_num_pages=64)
+        assert prefill_kernel_active(DecoderConfig(num_heads=32, num_kv_heads=8, head_dim=128, **paged))
+        assert prefill_kernel_active(DecoderConfig(num_heads=64, num_kv_heads=4, head_dim=192, v_head_dim=128,
+                                                   embed_dim=4096, **paged))
+        assert prefill_kernel_active(DecoderConfig(num_heads=12, head_dim=64, **paged))
+        assert prefill_kernel_active(DecoderConfig(num_heads=32, head_dim=128, kv_cache_dtype="int8", **paged))
+        assert prefill_kernel_active(DecoderConfig(num_heads=32, head_dim=128, kv_cache_dtype="int4", **paged))
+        assert not prefill_kernel_active(DecoderConfig(num_heads=12, head_dim=64, kv_cache_dtype="int4", **paged))
 
     def test_prefill_kernel_active_mirrors_gate(self):
         from accelerate_tpu.models import DecoderConfig

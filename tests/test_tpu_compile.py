@@ -4,6 +4,8 @@ runs. Interpret mode hid two refused kernel families for three PRs (GQA
 ragged prefill, every int4 variant); these compiles are what would have
 caught them, at about two seconds each. A compile that passes is not a chip
 run: it says the compiler accepts the kernel, nothing about results or time.
+What the compiler refuses is held by name too, with the gate that keeps it off
+the kernel (``REFUSED``).
 """
 
 import os
@@ -106,10 +108,10 @@ def _dense_decode(chip, *, bits=0):
 
 
 def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8, dv=None, window=None,
-                    sink=False, table=None):
+                    sink=False, table=None, slots=SLOTS, pages=PAGES):
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    kp, ks = _kv_operands(chip, (PAGES, kvh, ps), d, bits)
-    vp, _ = _kv_operands(chip, (PAGES, kvh, ps), dv or d, bits)
+    kp, ks = _kv_operands(chip, (pages, kvh, ps), d, bits)
+    vp, _ = _kv_operands(chip, (pages, kvh, ps), dv or d, bits)
     rows = S((cap,), jnp.int32)
 
     def fn(q, kn, vn, kp, vp, table, row_slot, row_pos, hist, ks, vs, sink):
@@ -120,7 +122,7 @@ def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8, 
 
     return fn, (S((1, h, cap, d), jnp.bfloat16), S((1, kvh, cap, d), jnp.bfloat16),
                 S((1, kvh, cap, dv or d), jnp.bfloat16), kp, vp,
-                S((SLOTS, table or 2048 // ps), jnp.int32), rows, rows, S((SLOTS,), jnp.int32), ks, ks,
+                S((slots, table or 2048 // ps), jnp.int32), rows, rows, S((slots,), jnp.int32), ks, ks,
                 S((h,), jnp.float32) if sink else None)
 
 
@@ -150,7 +152,9 @@ CASES = {
     "paged_decode_serving_cell_in_place": (
         _paged_decode, dict(kvh=8, slots=32, pages=3584, table=256, layers=16, write=True)),
     "paged_decode_bf16_page8_in_place": (_paged_decode, dict(kvh=8, ps=8, write=True)),
-    # ragged prefill with quantize-on-write: MHA and GQA x KV storage
+    # ragged prefill, the walk over a slot's live pages out of HBM, with
+    # quantize-on-write: MHA and GQA x KV storage (scale pages, a 64-wide head
+    # and an int4 payload go in as lane-dense views: _ragged_prefill_kernel_call)
     **{
         f"ragged_prefill_{name}_{kv}": (_ragged_prefill, dict(kvh=kvh, bits=bits))
         for name, kvh in (("mha", 32), ("gqa_32q8kv", 8))
@@ -163,6 +167,17 @@ CASES = {
     "ragged_prefill_mha_d64": (_ragged_prefill, dict(h=12, kvh=12, d=64)),
     "ragged_prefill_gqa_d64_int8": (_ragged_prefill, dict(h=12, kvh=4, d=64, bits=8)),
     "ragged_prefill_gqa_int4_page128": (_ragged_prefill, dict(kvh=8, ps=128, bits=4)),
+    # the Mistral serving cells' own shape (benchmarks/configs/mistral-7b-v0.3-serve-16l.json),
+    # at both capacities the engine compiles
+    "ragged_prefill_serving_cell": (
+        _ragged_prefill, dict(kvh=8, bt=64, slots=32, pages=3584, table=256)),
+    "ragged_prefill_serving_cell_64_rows": (
+        _ragged_prefill, dict(kvh=8, bt=64, cap=64, slots=32, pages=3584, table=256)),
+    # ... and with --kv-cache-dtype int8 / int4 (no cell yet: ROADMAP R9)
+    "ragged_prefill_serving_cell_int8": (
+        _ragged_prefill, dict(kvh=8, bt=64, slots=32, pages=3584, table=256, bits=8)),
+    "ragged_prefill_serving_cell_int4": (
+        _ragged_prefill, dict(kvh=8, bt=64, slots=32, pages=3584, table=256, bits=4)),
     # layer kinds (benchmarks/configs/mimo-v2-flash-serve-7l-ep16.json): 64 query heads,
     # keys 192 wide stored padded to 256 lanes, values 128; a full kind of 4 kv heads,
     # a window kind of 8 with a window of 128 and a sink; the held experts' kernel
@@ -179,6 +194,18 @@ CASES = {
         _ragged_prefill, dict(h=64, kvh=4, d=256, dv=128, bt=64, table=512)),
     "ragged_prefill_keys256_values128_window_kind": (
         _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, table=512, window=128, sink=True)),
+    # keys 192 wide as they are (a model stores them at 256 for the decode kernel's sake)
+    "ragged_prefill_keys192_values128_window_kind": (
+        _ragged_prefill, dict(h=64, kvh=8, d=192, dv=128, bt=64, table=512, window=128, sink=True)),
+    # ... and at the MiMo cell's slots and pools
+    "ragged_prefill_mimo_cell_full_kind": (
+        _ragged_prefill, dict(h=64, kvh=4, d=256, dv=128, bt=64, slots=64, pages=16384, table=512)),
+    "ragged_prefill_mimo_cell_window_kind": (
+        _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, slots=64, pages=1024, table=512, window=128,
+                              sink=True)),
+    "ragged_prefill_mimo_cell_window_kind_64_rows": (
+        _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, cap=64, slots=64, pages=1024, table=512,
+                              window=128, sink=True)),
     "moe_experts_decode_rows": (_moe_experts, dict(rows=64)),
     "moe_experts_prefill_rows": (_moe_experts, dict(rows=256)),
     # dense-arena decode (single-stream generate(), the flat slot arena)
@@ -244,6 +271,8 @@ KERNEL_NAMES = {
     "flash_32k_context_fwd": {"flash_attn_fwd"},
     "flash_gqa_32q8kv_fwd_bwd": {"jvp_flash_attn_fwd_", "jvp_flash_attn_dq_", "jvp_flash_attn_dkv_"},
     "ragged_prefill_gqa_32q8kv_bf16": {"ragged_prefill_attn"},
+    "ragged_prefill_serving_cell": {"ragged_prefill_attn"},
+    "ragged_prefill_mimo_cell_window_kind": {"ragged_prefill_attn"},
     "paged_decode_bf16_d128_sq1": {"attn"},
     "paged_decode_serving_cell_in_place": {"attn"},
     "moe_experts_decode_rows": {"moe_experts"},
@@ -386,17 +415,24 @@ def test_gates_admit_only_what_compiles(monkeypatch):
             admitted = not (bits == 4 and d == 64)
             assert A._decode_kernel_gate("paged", 1, d, 16, bits) == (admitted, False)
             assert A._prefill_kernel_gate("ragged", d, 16, 8, bits) == (admitted, False)
-            # the page-table kernel: CASES has what it admits, REFUSED the rest
+            # the page-table decode kernel: CASES has what it admits, REFUSED the rest
             paged = A._decode_kernel_gate("paged", 1, d, 16, bits, paged=True)
             assert paged == (d == 128 and not bits, False)
-    # keys 192 wide: refused as they are, admitted in the layout a model
-    # gives its pages (256 lanes, values 128), and the prefill kernel takes both
+    # keys 192 wide: the decode kernel refuses them as they are and takes
+    # them in the layout a model gives its pages (256 lanes, values 128);
+    # the prefill kernel takes both
     assert A._decode_kernel_gate("paged", 1, 192, 16, 0, paged=True, dv=128) == (False, False)
     assert A._decode_kernel_gate("paged", 1, A.paged_key_lanes(192), 16, 0, paged=True, dv=128) == (True, False)
-    assert A._prefill_kernel_gate("ragged", 192, 16, 64) == A._prefill_kernel_gate("ragged", 256, 16, 64) == (True, False)
+    assert A._prefill_kernel_gate("ragged", 192, 16, 64, dv=128) == (True, False)
+    assert A._prefill_kernel_gate("ragged", A.paged_key_lanes(192), 16, 64, dv=128) == (True, False)
     from accelerate_tpu.models import DecoderConfig
 
     kind = DecoderConfig(num_heads=64, num_kv_heads=8, head_dim=192, v_head_dim=128, embed_dim=4096,
                          kv_page_size=16, kv_num_pages=1024, attn_window=128, attn_sink=True)
     assert A.decode_kernel_active(kind) and A.prefill_kernel_active(kind)
-    assert not A.decode_kernel_active(DecoderConfig(num_heads=12, head_dim=64, kv_page_size=16, kv_num_pages=64))
+    narrow = DecoderConfig(num_heads=12, head_dim=64, kv_page_size=16, kv_num_pages=64)
+    assert not A.decode_kernel_active(narrow) and A.prefill_kernel_active(narrow)
+    for kv in ("int8", "int4"):
+        quantized = DecoderConfig(num_heads=32, num_kv_heads=8, head_dim=128, kv_page_size=16, kv_num_pages=64,
+                                  kv_cache_dtype=kv)
+        assert not A.decode_kernel_active(quantized) and A.prefill_kernel_active(quantized)

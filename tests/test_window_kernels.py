@@ -106,11 +106,15 @@ def _pack(rng, hists, tails, bt, cap):
     return row_slot, row_pos, np.asarray(hists, np.int32)
 
 
+@pytest.mark.parametrize("block_pages", [None, 1], ids=["one_block", "blocks_of_one_page"])
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_ragged_prefill_kernel_is_the_reference(case):
+def test_ragged_prefill_kernel_is_the_reference(case, block_pages, monkeypatch):
     """Three slots in one pack: one from position 0, one far behind its
     window (its released pages hold NaN), one short; the kernel against
-    ``mha_reference`` over each slot's arena prefix and fresh rows."""
+    ``mha_reference`` over each slot's arena prefix and fresh rows, with the
+    walk in one block of pages and in several."""
+    if block_pages is not None:
+        monkeypatch.setattr(A, "_PREFILL_MAX_BLOCK_PAGES", block_pages)
     kw = dict(CASES[case])
     rng = np.random.default_rng(7)
     bt, cap = 8, 64
@@ -152,7 +156,19 @@ def test_ragged_prefill_kernel_is_the_reference(case):
 
 
 def test_prefill_window_walk_is_as_long_as_the_window():
-    """The grid's arena steps are the pages that can hold the window - 1
-    positions before a block's first row, not the table's length."""
+    """The arena walk of a window layer spans the pages that can hold the
+    window - 1 positions before a block's first row, not the table's
+    length, and one block of the walk holds them (the MiMo cell's window
+    kind: 16 pages for 9, at 512 folded rows)."""
     assert A.window_span_pages(128 - 1, 16) == 9
     assert A.window_span_pages(W - 1, PS) == 4
+    assert A._prefill_block_pages(8, 16, 256, jnp.bfloat16, 0, 512, 512, pdv=128, window_pages=9) == 16
+    # a full kind's block is what the budget gives, whatever the table's length
+    assert A._prefill_block_pages(4, 16, 256, jnp.bfloat16, 0, 512, 1024, pdv=128) == 32
+    assert A._prefill_block_pages(8, 16, 128, jnp.bfloat16, 0, 256, 256) == 32
+    assert A._prefill_block_pages(8, 16, 128, jnp.bfloat16, 0, 4, 256) == 4
+    # int8 / int4 pages of that shape (payloads in whole lanes, a page's scales one
+    # lane-dense row) hold as many; 32 kv heads a page hold a quarter of them
+    for bits in (8, 4):
+        assert A._prefill_block_pages(8, 16, 128, jnp.int8, bits, 256, 256) == 32
+    assert A._prefill_block_pages(32, 16, 128, jnp.int8, 8, 128, 64) == 8
