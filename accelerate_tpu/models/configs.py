@@ -150,12 +150,12 @@ class DecoderConfig:
     # a model with one kind, and one kind's layers where ``layer_kinds``
     # states several. ``v_head_dim``: the values' width (None: head_dim).
     # ``rope_dim``: the leading dimensions of a head that are rotated
-    # (None: all). ``attn_window``: a query sees its own position and the
+    # (None: all; 0: none). ``attn_window``: a query sees its own position and the
     # window - 1 before it. ``attn_sink``: a learned scalar per query head
     # in the softmax's denominator. ``attn_value_scale`` multiplies the
     # values.
     v_head_dim: Optional[int] = None
-    rope_dim: Optional[int] = None
+    rope_dim: Optional[int] = None  # 0: no rotation (the model carries the order elsewhere)
     attn_window: Optional[int] = None
     attn_sink: bool = False
     attn_value_scale: float = 1.0
@@ -176,6 +176,25 @@ class DecoderConfig:
     # published order. Empty: one kind, the stack every caller has today.
     layer_kinds: tuple = ()
     layer_pattern: tuple = ()
+    # ``mixer``: what a layer mixes the sequence with, "attention" or "ssm",
+    # a selective state-space block (Mamba-1; models/ssm.py): an input
+    # projection to ``ssm_expand x embed_dim`` channels and a gate, a causal
+    # depthwise convolution of ``ssm_conv_width`` taps (``ssm_conv_bias``), a
+    # step, B and C of ``ssm_dt_rank`` (None: embed_dim / 16, rounded up) and
+    # ``ssm_state_dim`` each, each through an RMSNorm of its own where
+    # ``ssm_inner_norms``, and the recurrence over a float32 state of
+    # ``ssm_state_dim`` a channel. In the serving cache it keeps a state and
+    # the convolution's last inputs a slot, not pages (cache kind "state").
+    # ``ssm_kernel``: None (the ``ssm_scan`` kernel on a TPU, its
+    # ``jax.numpy`` reference elsewhere), "scan", "reference" or "interpret".
+    mixer: str = "attention"
+    ssm_state_dim: int = 16
+    ssm_conv_width: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: Optional[int] = None
+    ssm_inner_norms: bool = True
+    ssm_conv_bias: bool = True
+    ssm_kernel: Optional[str] = None
 
     def __post_init__(self):
         if self.num_kv_heads is None:
@@ -271,10 +290,21 @@ class DecoderConfig:
                     f"moe_experts_held={self.moe_experts_held!r} must be (first, "
                     f"moe_num_experts={self.moe_num_experts}) within the router's "
                     f"{outputs} outputs")
-        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.head_dim:
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
             raise ValueError(
                 f"rope_dim must be even and at most head_dim {self.head_dim}, "
                 f"got {self.rope_dim}")
+        if self.mixer not in ("attention", "ssm"):
+            raise ValueError(f"mixer must be 'attention' or 'ssm', got {self.mixer!r}")
+        if self.ssm_kernel not in (None, "scan", "reference", "interpret"):
+            raise ValueError(
+                "ssm_kernel must be None, 'scan', 'reference' or 'interpret', "
+                f"got {self.ssm_kernel!r}")
+        if self.mixer == "ssm" and min(
+                self.ssm_state_dim, self.ssm_conv_width - 1, self.ssm_expand, self.ssm_rank) < 1:
+            raise ValueError(
+                "a state-space mixer needs ssm_state_dim, ssm_expand and ssm_dt_rank >= 1 "
+                "and ssm_conv_width >= 2")
         if self.attn_window is not None and self.attn_window < 1:
             raise ValueError(f"attn_window must be >= 1, got {self.attn_window}")
         self.layer_kinds = tuple((str(n), dict(o)) for n, o in self.layer_kinds)
@@ -337,19 +367,38 @@ class DecoderConfig:
 
     @property
     def rotary_dim(self) -> int:
-        return self.rope_dim or self.head_dim
+        return self.head_dim if self.rope_dim is None else self.rope_dim
+
+    @property
+    def ssm_inner_dim(self) -> int:
+        return self.ssm_expand * self.embed_dim
+
+    @property
+    def ssm_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.embed_dim // 16)
 
     @property
     def cache_kind(self) -> str:
-        """Name of the kind of state this (one-kind) config's attention
-        layers keep, for the serving cache: layers of one name share a
-        page pool and a page table."""
+        """Name of the kind of state this (one-kind) config's layers keep,
+        for the serving cache: attention layers of one name share a page
+        pool and a page table; "state" is a state-space mixer's, which is
+        of a fixed size a slot and not paged."""
+        if self.mixer == "ssm":
+            return "state"
         return "full" if self.attn_window is None else f"window{self.attn_window}"
 
     def _layer_params(self, active: bool = False) -> int:
         e, h, kv = self.embed_dim, self.num_heads, self.num_kv_heads
-        attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
-            + h * self.value_dim * e + (h if self.attn_sink else 0)
+        if self.mixer == "ssm":
+            # in and out projections, the convolution, the step's, B's and
+            # C's projection with their norms, the step's expansion and
+            # bias, A and the skip
+            d, n, r = self.ssm_inner_dim, self.ssm_state_dim, self.ssm_rank
+            attn = e * 2 * d + d * e + self.ssm_conv_width * d + (d if self.ssm_conv_bias else 0) \
+                + d * (r + 2 * n) + (r + 2 * n if self.ssm_inner_norms else 0) + r * d + d + d * n + d
+        else:
+            attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
+                + h * self.value_dim * e + (h if self.attn_sink else 0)
         if self.moe_num_experts > 1:
             # per-expert gate/up/down + the router (and its selection bias)
             outputs = self.moe_router_outputs or self.moe_num_experts

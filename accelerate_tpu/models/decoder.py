@@ -39,6 +39,14 @@ def _constrain(x, names, mesh: Optional[Mesh], rules=DEFAULT_AXIS_RULES):
     return constrain_activation(x, names, mesh, rules)
 
 
+def _rotary_tables(positions, cfg, dtype):
+    """(sin, cos) of a config's rotated width, or (None, None) where its
+    layers do not rotate (a state-space mixer; attention with ``rope_dim`` 0)."""
+    if getattr(cfg, "mixer", "attention") == "ssm" or not cfg.rotary_dim:
+        return None, None
+    return rotary_embedding_tables(positions, cfg.rotary_dim, theta=cfg.rope_theta, dtype=dtype)
+
+
 def _dense_init(scale: float = 1.0):
     return nn.initializers.variance_scaling(scale, "fan_in", "normal")
 
@@ -252,8 +260,9 @@ class DecoderAttention(nn.Module):
             v = jnp.einsum("bse,ehd->bhsd", x, wv.astype(dt))
         q = _constrain(q, ("batch", "heads", "seq", "head_dim"), self.mesh)
         k = _constrain(k, ("batch", "kv_heads", "seq", "head_dim"), self.mesh)
-        q = apply_rotary_embedding(q, sin, cos)
-        k = apply_rotary_embedding(k, sin, cos)
+        if sin is not None:  # None: a kind without rotation (rope_dim 0)
+            q = apply_rotary_embedding(q, sin, cos)
+            k = apply_rotary_embedding(k, sin, cos)
 
         if self.use_cache:
             # getattr: Seq2SeqConfig reuses this module and has no paging
@@ -601,11 +610,18 @@ class DecoderBlock(nn.Module):
         # the stream may be carried wider than the activations
         # (config.residual_dtype); the layers' inputs are cfg.dtype either way
         y = rms_norm(x, ln1, cfg.norm_eps).astype(cfg.dtype)
-        y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
-            y, sin, cos, deterministic, cache_positions=cache_positions,
-            page_table=page_table, ragged_slots=ragged_slots,
-            slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer,
-        )
+        if getattr(cfg, "mixer", "attention") == "ssm":
+            from .ssm import SelectiveSSM
+
+            y = SelectiveSSM(cfg, self.mesh, self.use_cache, self.decode, name="ssm")(
+                y, cache_positions=cache_positions, ragged_slots=ragged_slots,
+                slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer)
+        else:
+            y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
+                y, sin, cos, deterministic, cache_positions=cache_positions,
+                page_table=page_table, ragged_slots=ragged_slots,
+                slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer,
+            )
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
         x = x + y.astype(x.dtype)
@@ -752,7 +768,7 @@ class DecoderLM(nn.Module):
 
         if positions is None:
             positions = jnp.arange(s)
-        sin, cos = rotary_embedding_tables(positions, cfg.rotary_dim, theta=cfg.rope_theta, dtype=cfg.dtype)
+        sin, cos = _rotary_tables(positions, cfg, cfg.dtype)
         # the tokens that are real, for the experts' routing and load: a
         # packed prefill's rows (pads carry position -1), a decode step's
         # live slots (an idle slot's live length is 0)
@@ -826,8 +842,7 @@ class DecoderLM(nn.Module):
             # the cache is paged, its kind's page table
             for i, run_cfg in enumerate(cfg.run_configs()):
                 if cfg.layer_kinds:
-                    sin, cos = rotary_embedding_tables(
-                        positions, run_cfg.rotary_dim, theta=run_cfg.rope_theta, dtype=cfg.dtype)
+                    sin, cos = _rotary_tables(positions, run_cfg, cfg.dtype)
                 ptab = page_table
                 if isinstance(page_table, dict):
                     ptab = page_table[run_cfg.cache_kind]
@@ -835,9 +850,13 @@ class DecoderLM(nn.Module):
                 # arena through the scan whole and the kernel updates it in
                 # place; every other call splits it by layer, as ever
                 # (not the call that shapes the arena: a carry has to exist)
+                # A state-space run's states are carried whole in both serving
+                # programs, the packed prefill too: a pack advances a few
+                # slots of a state that is of all of them.
                 name = f"layers_{i}" if cfg.layer_kinds else "layers"
                 in_place = (use_cache and decode and page_table is not None
-                            and ragged_slots is None and arena_in_place(run_cfg, s)
+                            and (run_cfg.mixer == "ssm"
+                                 or (ragged_slots is None and arena_in_place(run_cfg, s)))
                             and name in self.variables.get("cache", {}))
                 split = {"params": 0, "cache": 0, "fp8_stats": 0, MOE_LOAD: 0}
                 if in_place:

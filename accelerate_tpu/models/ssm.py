@@ -1,0 +1,192 @@
+"""The selective state-space mixer (Mamba-1, with the inner norms of the
+Jamba family) that a layer kind may state in attention's place
+(``DecoderConfig.mixer == "ssm"``).
+
+    [u, z] = h W_in                               (E -> 2 x D, D = ssm_expand x E)
+    u'     = silu(conv(u) + b_conv)               causal, depthwise, ssm_conv_width taps
+    [d, B, C] = u' W_x                            (D -> R + N + N)
+    d, B, C each through an RMSNorm of its own    (ssm_inner_norms)
+    dt     = softplus(d W_dt + b_dt)              (R -> D)
+    S_t    = exp(dt_t (x) A) * S_{t-1} + (dt_t * u'_t) (x) B_t,   A = -exp(A_log)
+    y_t    = S_t^T C_t + D_skip * u'_t
+    out    = (y * silu(z)) W_out                  (D -> E)
+
+The recurrence is float32 (``ops/ssm.py``); the projections take the
+config's dtype like every other matrix multiplication of the model.
+
+Three call forms, one mathematics:
+
+- **a whole sequence** a batch row, from a zero state or, with ``use_cache``
+  in a decode call, from the state the cache holds (the plain forward pass
+  and ``generate()``): the ``jax.numpy`` recurrence, differentiable.
+- **a packed ragged prefill** (``ragged_slots``): row r of the one batch row
+  is position ``cache_positions[0, r]`` of slot ``ragged_slots[r]``, in token
+  blocks of ``prefill_kernel_block`` rows, each of one slot. A slot whose
+  first packed position is 0 starts from a zero state inside the program (a
+  request's first chunk; the reset is no dispatch); a later chunk resumes
+  from the slot's state. Padding rows (position -1) advance nothing.
+- **a decode step** (``cache_positions`` alone): one row a slot;
+  ``kv_lengths`` says which slots are live (0: the slot's state and
+  convolution inputs stay as they are).
+
+What a slot keeps, in the "cache" collection beside the paged leaves:
+``ssm_state`` [slots, N, D] float32 (the wide dimension on lanes) and
+``conv_state`` [slots, ssm_conv_width - 1, D], the convolution's last raw
+inputs (``serving/pages.STATE_LEAF_NAMES`` finds them by these names). Where the layer scan carries the collection whole (``cache_layer``),
+both are the layers' stacks with a leading layer axis and are updated in
+place: the kernel takes the stack and the layer, and the convolution's
+inputs are one dynamic slice in and one out.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from ..ops.layers import rms_norm
+from ..ops.ssm import ssm_scan
+from .configs import DecoderConfig
+
+def _inverse_softplus_log_uniform(lo: float = 1e-3, hi: float = 1e-1):
+    """The step's bias as the family initialises it: the inverse softplus of
+    a step drawn log-uniform in [lo, hi]."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """A = -(1..N) a channel: ``a_log`` [N, D] = log(n + 1)."""
+    del key
+    n, d = shape
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None], (n, d)).astype(dtype)
+
+
+class SelectiveSSM(nn.Module):
+    config: DecoderConfig
+    mesh: Optional[Mesh] = None
+    use_cache: bool = False
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, cache_positions=None, ragged_slots=None, slot_hist=None,
+                 kv_lengths=None, cache_layer=None):
+        cfg = self.config
+        e, d, n, r, k = cfg.embed_dim, cfg.ssm_inner_dim, cfg.ssm_state_dim, cfg.ssm_rank, cfg.ssm_conv_width
+        dt_, f32 = cfg.dtype, jnp.float32
+        b, s = x.shape[0], x.shape[1]
+        dense = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        part = nn.with_logical_partitioning
+        w_in = self.param("w_in", part(dense, ("embed", "mlp")), (e, 2 * d))
+        conv_w = self.param("conv_w", part(dense, (None, "mlp")), (k, d))
+        conv_b = self.param("conv_b", part(nn.initializers.zeros, ("mlp",)), (d,)) if cfg.ssm_conv_bias else None
+        w_x = self.param("w_x", part(dense, ("mlp", None)), (d, r + 2 * n))
+        w_dt = self.param("w_dt", part(dense, (None, "mlp")), (r, d))
+        b_dt = self.param("b_dt", part(_inverse_softplus_log_uniform(), ("mlp",)), (d,), f32)
+        a_log = self.param("a_log", part(_a_log_init, (None, "mlp")), (n, d), f32)
+        d_skip = self.param("d_skip", part(nn.initializers.ones, ("mlp",)), (d,), f32)
+        w_out = self.param("w_out", part(dense, ("mlp", "embed")), (d, e))
+        norms = None
+        if cfg.ssm_inner_norms:
+            norms = [self.param(name, part(nn.initializers.ones, ("norm",)), (width,))
+                     for name, width in (("norm_dt", r), ("norm_b", n), ("norm_c", n))]
+
+        uz = x @ w_in.astype(dt_)
+        u, z = uz[..., :d], uz[..., d:]
+
+        serving = self.use_cache and self.decode and cache_positions is not None
+        if serving and ragged_slots is None and s != 1:
+            raise NotImplementedError(
+                "a state-space layer decodes one token a slot: several (speculative "
+                "verify) would need the state rolled back on a rejected draft")
+        state = conv = None
+        if self.use_cache:
+            state = self.variable("cache", "ssm_state", jnp.zeros, (b, n, d), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros, (b, k - 1, d), dt_)
+        stacked = cache_layer is not None
+        read = lambda var: var.value[cache_layer] if stacked else var.value
+
+        # -- the blocks the recurrence walks, and the inputs before each --
+        if serving and ragged_slots is not None:
+            # a pack: token blocks of one slot each; a block's first rows take
+            # the block before it where that continues its slot's segment, else
+            # the slot's kept inputs (none before position 0)
+            bt = cfg.prefill_kernel_block
+            if not bt or s % bt or b != 1:
+                raise ValueError(
+                    f"a packed prefill is one batch row of whole token blocks "
+                    f"(prefill_kernel_block={bt}), got {x.shape[:2]}")
+            nb = s // bt
+            row_pos = jnp.reshape(cache_positions, (nb, bt))
+            first = row_pos[:, 0]
+            rows = jnp.sum(row_pos >= 0, axis=1).astype(jnp.int32)
+            slot = jnp.where(rows > 0, ragged_slots[::bt], -1)
+            at = jnp.maximum(slot, 0)
+            fresh = (first == 0).astype(jnp.int32)
+            blocks = u.reshape(nb, bt, d)
+            kept = read(conv)[at]                                           # [nb, k-1, d]
+            back = first[:, None] - jnp.arange(k - 1, 0, -1)[None, :]       # the positions before the block
+            kept = jnp.where((back >= 0)[..., None], kept, 0)
+            before = jnp.concatenate([jnp.zeros((1, k - 1, d), dt_), blocks[:-1, bt - (k - 1):]], axis=0)
+            continues = (first > slot_hist[at]) & (slot >= 0)
+            halo = jnp.where(continues[:, None, None], before, kept)
+        else:
+            # a sequence a batch row (or a decode step's one row a slot)
+            nb, bt = b, s
+            slot = jnp.arange(b, dtype=jnp.int32)
+            live = jnp.ones((b,), bool) if not serving or kv_lengths is None else kv_lengths > 0
+            rows = jnp.where(live, s, 0).astype(jnp.int32)
+            resume = self.use_cache and self.decode
+            fresh = jnp.full((b,), 0 if resume else 1, jnp.int32)
+            blocks = u
+            halo = read(conv) if resume else jnp.zeros((b, k - 1, d), dt_)
+        ext = jnp.concatenate([halo.astype(dt_), blocks], axis=1)            # [nb, k-1+bt, d]
+        acc = sum(ext[:, j:j + bt].astype(f32) * conv_w[j].astype(f32) for j in range(k))
+        if conv_b is not None:
+            acc = acc + conv_b.astype(f32)
+        u_c = jax.nn.silu(acc)                                               # [nb, bt, d] float32
+
+        # what feeds the recurrence stays float32 between the multiplications
+        # (their inputs are cfg.dtype, their sums float32): the step, B and C
+        # enter every token's update, and a rounding there is one per token
+        mm = lambda v, w: jnp.matmul(v.astype(dt_), w.astype(dt_), preferred_element_type=f32)
+        xp = mm(u_c, w_x)
+        dlt, b_t, c_t = xp[..., :r], xp[..., r:r + n], xp[..., r + n:]
+        if norms is not None:
+            dlt, b_t, c_t = (rms_norm(v, w, cfg.norm_eps) for v, w in zip((dlt, b_t, c_t), norms))
+        step = jax.nn.softplus(mm(dlt, w_dt) + b_dt)
+        a = -jnp.exp(a_log.astype(f32))
+
+        # -- the recurrence, and what the slot keeps --
+        if state is None:
+            stack, layer = jnp.zeros((1, nb, n, d), f32), 0
+        elif stacked:
+            stack, layer = state.value, cache_layer
+        else:
+            stack, layer = state.value[None], 0
+        y, stack = ssm_scan(
+            u_c, step, b_t, c_t, a, d_skip, stack, block_slot=slot, block_rows=rows,
+            block_fresh=fresh, layer=layer,
+            # off the serving programs the jax.numpy form: it is the one that differentiates
+            impl=cfg.ssm_kernel if serving else "reference")
+        if state is not None:
+            state.value = stack if stacked else stack[0]
+            # the last k-1 raw inputs of each block that ends its slot's rows here
+            tail = jax.vmap(lambda e_, n_: jax.lax.dynamic_slice_in_dim(e_, n_, k - 1, axis=0))(ext, rows)
+            if serving and ragged_slots is not None:
+                # (the next block is another slot's or has no rows); the others
+                # write past the last slot and are dropped
+                ends = (slot >= 0) & (jnp.concatenate([slot[1:], jnp.full((1,), -1, slot.dtype)]) != slot)
+                where = jnp.where(ends, slot, read(conv).shape[0])
+                kept = read(conv).at[where].set(tail.astype(dt_), mode="drop")
+            else:
+                kept = jnp.where((rows > 0)[:, None, None], tail.astype(dt_), read(conv))
+            conv.value = conv.value.at[cache_layer].set(kept) if stacked else kept
+
+        out = (y * jax.nn.silu(z.reshape(nb, bt, d).astype(f32))).astype(dt_) @ w_out.astype(dt_)
+        return out.reshape(b, s, e)

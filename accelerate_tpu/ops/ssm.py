@@ -1,0 +1,235 @@
+"""The selective state-space recurrence (Mamba-1) for the serving programs.
+
+One token of one sequence advances a state ``S`` in R^(N x D) (``N`` =
+``d_state``, ``D`` = ``d_inner``; laid out ``[N, D]`` so that the wide
+dimension lies on lanes: Mosaic copies whole lanes only):
+
+    S_t = exp(dt_t (x) A) * S_{t-1} + (dt_t * u_t) (x) B_t
+    y_t = S_t^T C_t + D_skip * u_t
+
+with ``u_t``, ``dt_t`` in R^D (the convolved input and the step, after its
+softplus), ``B_t``, ``C_t`` in R^N, ``A`` in R^(N x D) negative. Everything
+is float32: the recurrence compounds its rounding over a request's length.
+
+Both serving programs hand the recurrence over as **blocks**: ``u``, ``dt``
+``[blocks, rows, D]`` and ``b``, ``c`` ``[blocks, rows, N]``, each block the
+rows of one slot in order, with three descriptors a block:
+
+- ``block_slot`` the slot whose state the block advances (-1: no slot, a
+  pack's trailing padding);
+- ``block_rows`` how many of its rows are real (the rest advance nothing);
+- ``block_fresh`` 1 where the slot's state is zeroed before the first row (a
+  request's first chunk: the reset costs no dispatch of its own).
+
+A packed prefill is the pack's token blocks (consecutive blocks of one slot
+continue each other); a decode step is one block of one row a slot, with 0
+rows for a slot that is not live. The states of all layers of a scanned
+stack are one array ``[layers, slots, N, D]`` and the call names its layer,
+so that the stack rides the layer scan whole and is updated in place
+(``input_output_aliases``): no layer's state is sliced out or put back.
+
+:func:`selective_scan_reference` is the ``jax.numpy`` form (differentiable;
+what runs off the chip and in the plain forward pass), and
+:func:`ssm_scan` the dispatch onto the one pallas kernel,
+``pallas_call(name="ssm_scan")``: a grid step is one block; the slot's state
+comes out of HBM into VMEM through a block spec indexed by the block's
+slot, so the next block's state is on its way while this one's rows are
+walked, and goes back when the slot changes.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from .attention import pl, pltpu
+
+_KERNEL_MODES = ("scan", "reference", "interpret")
+_LANE_CHUNK = 512            # lanes of the state walked at a time: 8 vregs a value at N = 16
+_VMEM_LIMIT = 48 * 2 ** 20   # a 64-row block at D = 5120 holds about 12 MB, double-buffered
+
+
+def resolve_ssm_kernel(impl: Optional[str] = None) -> str:
+    """``impl`` (``DecoderConfig.ssm_kernel``), else the kernel on a TPU and
+    the ``jax.numpy`` reference elsewhere. "interpret" runs the same kernel
+    through the pallas interpreter, for the tests on the CPU."""
+    mode = impl or ("scan" if jax.default_backend() == "tpu" else "reference")
+    if mode not in _KERNEL_MODES:
+        raise ValueError(f"ssm_kernel must be one of {_KERNEL_MODES}, got {mode!r}")
+    return mode
+
+
+def ssm_kernel_active(config) -> bool:
+    """Would the serving programs of a model with this config run the
+    ``ssm_scan`` kernel in this process (the ``serving/ssm_kernel_active``
+    gauge)? It mirrors :func:`ssm_scan`'s dispatch."""
+    return resolve_ssm_kernel(getattr(config, "ssm_kernel", None)) != "reference"
+
+
+def selective_scan_reference(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows,
+                             block_fresh, layer=0):
+    """The recurrence in ``jax.numpy``: blocks in order, rows in order.
+    ``u``, ``dt`` [blocks, rows, D]; ``b``, ``c`` [blocks, rows, N]; ``a``
+    [N, D]; ``d_skip`` [D]; ``state`` [layers, slots, N, D] float32. Returns
+    ``(y [blocks, rows, D] float32, state)``."""
+    f32 = jnp.float32
+    a, d_skip = a.astype(f32), d_skip.astype(f32)
+    rows = u.shape[1]
+    stack = state[layer]
+
+    def block(stack, xs):
+        u_b, dt_b, b_b, c_b, slot, n, fresh = xs
+        at = jnp.maximum(slot, 0)
+        before = stack[at]
+
+        def row(s, r):
+            u_t, dt_t, b_t, c_t, i = r
+            dt_t = jnp.where(i < n, dt_t, 0.0)  # dt = 0: the state stays as it is
+            s = jnp.exp(dt_t[None, :] * a) * s + (dt_t * u_t)[None, :] * b_t[:, None]
+            return s, jnp.sum(s * c_t[:, None], axis=0) + d_skip * u_t
+
+        s, y = jax.lax.scan(row, jnp.where(fresh > 0, 0.0, before),
+                            (u_b.astype(f32), dt_b.astype(f32), b_b.astype(f32), c_b.astype(f32),
+                             jnp.arange(rows)))
+        # a block of no slot or no rows advances nothing (a fresh one still zeroes)
+        keep = (slot < 0) | ((n <= 0) & (fresh <= 0))
+        return stack.at[at].set(jnp.where(keep, before, s)), y
+
+    stack, y = jax.lax.scan(block, stack, (u, dt, b, c, block_slot, block_rows, block_fresh))
+    return y, state.at[layer].set(stack)
+
+
+def _ssm_scan_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
+                     u_ref, dt_ref, b_ref, c_ref, a_ref, dskip_ref, s_in_ref,
+                     y_ref, s_out_ref, *, group: int, chunk: int):
+    del layer_ref, slot_ref  # the block specs read them
+    f32 = jnp.float32
+    j = pl.program_id(0)
+    rows, width = u_ref.shape[1], u_ref.shape[2]
+    n = rows_ref[j]
+
+    # the slot's state stays in the output block while consecutive blocks
+    # continue one slot; the first of them takes it from HBM, or from zero
+    @pl.when(cont_ref[j] == 0)
+    def _():
+        s_out_ref[...] = s_in_ref[...]
+
+    @pl.when(fresh_ref[j] == 1)
+    def _():
+        s_out_ref[...] = jnp.zeros(s_out_ref.shape, f32)
+
+    @pl.when(n < rows)
+    def _():  # rows no group reaches are padding: their y is never read, but is no garbage either
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    def walk(base):
+        for c0 in range(0, width, chunk):
+            lanes = slice(c0, c0 + chunk)
+            a, d_skip = a_ref[:, lanes], dskip_ref[:, lanes]
+            u_g = u_ref[0, pl.ds(base, group), lanes].astype(f32)
+            dt_g = dt_ref[0, pl.ds(base, group), lanes].astype(f32)
+            s = s_out_ref[0, 0, :, lanes]
+            ys = []
+            for i in range(group):
+                dt_i = jnp.where(base + i < n, dt_g[i:i + 1], 0.0)  # a padding row advances nothing
+                u_i = u_g[i:i + 1]
+                s = jnp.exp(dt_i * a) * s + (dt_i * u_i) * b_ref[0, base + i]
+                ys.append(jnp.sum(s * c_ref[0, base + i], axis=0, keepdims=True) + d_skip * u_i)
+            s_out_ref[0, 0, :, lanes] = s
+            y_ref[0, pl.ds(base, group), lanes] = (
+                jnp.concatenate(ys, axis=0) if group > 1 else ys[0]).astype(y_ref.dtype)
+
+    if rows == group:  # one group (a decode step's one row): no loop, and no dynamic row index
+        pl.when(n > 0)(lambda: walk(0))
+    else:
+        def body(g, carry):
+            walk(pl.multiple_of(g * group, group))
+            return carry
+
+        jax.lax.fori_loop(0, (n + group - 1) // group, body, 0)
+
+
+def _block_descriptors(block_slot, block_rows, block_fresh):
+    """What the kernel's grid reads a block: the slot whose state its block
+    specs fetch (a block of no slot stays on the one before it, so nothing
+    moves), its rows, its reset, and whether it continues the block before
+    it (the state is then already in VMEM)."""
+    i32 = jnp.int32
+    slot = block_slot.astype(i32)
+    nb = slot.shape[0]
+    last = jax.lax.cummax(jnp.where(slot >= 0, jnp.arange(nb, dtype=i32), -1))
+    at = jnp.where(last >= 0, slot[jnp.maximum(last, 0)], 0)
+    rows = jnp.where(slot >= 0, block_rows.astype(i32), 0)
+    fresh = jnp.where(slot >= 0, block_fresh.astype(i32), 0)
+    cont = jnp.concatenate([jnp.zeros((1,), i32), (at[1:] == at[:-1]).astype(i32)])
+    return at, rows, fresh, cont
+
+
+def _ssm_scan_call(u, dt, b, c, a, d_skip, state, block_slot, block_rows, block_fresh, layer,
+                   interpret: bool):
+    f32 = jnp.float32
+    nb, rows, width = u.shape
+    n = a.shape[0]
+    group = 8 if rows % 8 == 0 else 1
+    if group == 1 and rows != 1:
+        raise ValueError(f"ssm_scan walks blocks of 1 row or of a multiple of 8, got {rows}")
+    chunk = _LANE_CHUNK if width % _LANE_CHUNK == 0 else width
+    at, n_rows, fresh, cont = _block_descriptors(block_slot, block_rows, block_fresh)
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1), at, n_rows, fresh, cont)
+
+    def a_block(j, *_):
+        return (j, 0, 0)
+
+    def a_row(j, *_):
+        return (j, 0, 0, 0)
+
+    def whole(j, *_):
+        return (0, 0)
+
+    def of_slot(j, ly, sl, *_):
+        return (ly[0], sl[j], 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, rows, width), a_block),          # u
+        pl.BlockSpec((1, rows, width), a_block),          # dt
+        pl.BlockSpec((1, rows, n, 1), a_row),             # b: a column a row, to broadcast along lanes
+        pl.BlockSpec((1, rows, n, 1), a_row),             # c
+        pl.BlockSpec((n, width), whole),                  # a
+        pl.BlockSpec((1, width), whole),                  # d_skip
+        pl.BlockSpec((1, 1, n, width), of_slot),          # the layers' states, this block's slot
+    ]
+    out_specs = [pl.BlockSpec((1, rows, width), a_block), pl.BlockSpec((1, 1, n, width), of_slot)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars), grid=(nb,), in_specs=in_specs, out_specs=out_specs)
+    # blocks in order: consecutive blocks of a slot hand its state on
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT)}
+    operands = (u, dt, b.astype(f32)[..., None], c.astype(f32)[..., None], a.astype(f32),
+                d_skip.astype(f32).reshape(1, width), state)
+    y, state = pl.pallas_call(
+        functools.partial(_ssm_scan_kernel, group=group, chunk=chunk),
+        grid_spec=grid_spec, name="ssm_scan", interpret=interpret,
+        out_shape=[jax.ShapeDtypeStruct((nb, rows, width), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={len(scalars) + len(operands) - 1: 1},
+        **params,
+    )(*scalars, *operands)
+    return y, state
+
+
+def ssm_scan(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows, block_fresh, layer=0,
+             impl: Optional[str] = None):
+    """The recurrence over blocks (the module's docstring): returns ``(y
+    [blocks, rows, D] float32, state)``, the stack of states with this
+    ``layer``'s slots advanced. ``impl``: :func:`resolve_ssm_kernel`."""
+    mode = resolve_ssm_kernel(impl)
+    if state.dtype != jnp.float32:
+        raise ValueError(f"the recurrent state is float32, got {state.dtype}")
+    if mode == "reference":
+        return selective_scan_reference(u, dt, b, c, a, d_skip, state, block_slot=block_slot,
+                                        block_rows=block_rows, block_fresh=block_fresh, layer=layer)
+    return _ssm_scan_call(u, dt, b, c, a, d_skip, state, block_slot, block_rows, block_fresh, layer,
+                          mode == "interpret")

@@ -57,11 +57,13 @@ from ..ops.attention import (
     prefill_token_block,
     prefill_walk_pages,
 )
+from ..ops.ssm import ssm_kernel_active
 from .pages import (
     NGramDrafter,
     CacheKind,
     PrefixCache,
     arena_nbytes,
+    state_nbytes,
     fork_page,
     gather_page,
     init_paged_arena,
@@ -308,16 +310,24 @@ class ServingEngine:
             )
         self.page_size = int(page_size)
         self.spec_k = max(0, int(spec_draft_len))
-        # a model that states layer kinds, a window, a sink or experts runs
-        # on the paged arena's normal path only; what cannot yet be right
-        # for it refuses here, by the feature's name, and never runs
+        # a model that states layer kinds, a window, a sink, experts or a
+        # recurrent state runs on the paged arena's normal path only; what
+        # cannot yet be right for it refuses here, by the feature's name,
+        # and never runs
         mcfg = definition.config
+        run_cfgs = mcfg.run_configs() if hasattr(mcfg, "run_configs") else [mcfg]
+        # a state-space mixer's state a slot (pages.CacheKind "state"): a
+        # cached prefix would need its snapshot at the page boundary, a
+        # page-out its copy, a rejected draft its rollback
+        has_state = any(getattr(c, "mixer", "attention") == "ssm" for c in run_cfgs)
         self._by_kind = bool(
             getattr(mcfg, "layer_kinds", ()) or getattr(mcfg, "attn_window", None)
-            or getattr(mcfg, "attn_sink", False) or getattr(mcfg, "moe_num_experts", 0) > 1)
+            or getattr(mcfg, "attn_sink", False) or getattr(mcfg, "moe_num_experts", 0) > 1
+            or has_state)
         if self._by_kind:
             refused = {
-                "prefix_cache (page sharing across a window kind)": bool(prefix_cache),
+                "prefix_cache (page sharing across a window kind, or without a state's "
+                "snapshot at the page boundary)": bool(prefix_cache),
                 "kv_tiers": kv_tiers is not None,
                 "preemption by page-out and restore (scheduler.config.preemption)": (
                     scheduler is not None
@@ -330,14 +340,13 @@ class ServingEngine:
                 if asked:
                     raise NotImplementedError(
                         f"ServingEngine: {feature} is not supported for a model with "
-                        "layer kinds, a window, a sink or experts; it is refused "
-                        "rather than run and be wrong (ROADMAP.md, Reach)")
+                        "layer kinds, a window, a sink, experts or a recurrent state; "
+                        "it is refused rather than run and be wrong (ROADMAP.md, Reach)")
         elif kind_pages:
             raise ValueError("kind_pages names pools of cache kinds; this model has one kind")
         # the programs of a model with experts return, with the tokens, the
         # pairs on each held expert of each expert layer
-        moe_runs = [c for c in mcfg.run_configs() if c.moe_num_experts > 1] \
-            if hasattr(mcfg, "run_configs") else []
+        moe_runs = [c for c in run_cfgs if getattr(c, "moe_num_experts", 0) > 1]
         self._expert_layers = sum(c.num_layers for c in moe_runs)
         self._pairs_per_token = sum(c.num_layers * c.moe_top_k for c in moe_runs)
         if self.max_cache_len % self.page_size:
@@ -366,7 +375,7 @@ class ServingEngine:
         # which state each layer kind keeps and how it is paged
         # (pages.CacheKind): a pool, a table a slot, the window's rule.
         # One kind: the allocator, tables and counts of before.
-        self._kinds = self._cache_kinds(self._paged_def.config)
+        self._kinds, self._state_kind = self._cache_kinds(self._paged_def.config)
         self._allocator = self._kinds[0].allocator
         self._tables_host = self._kinds[0].tables
         # hierarchical KV tiering (serving/tiers.py): demote-on-evict
@@ -414,7 +423,14 @@ class ServingEngine:
         # whether the decode step rides the paged pallas kernel (the
         # serving/decode_kernel_active gauge): in every layer kind
         pcfg = self._paged_def.config
-        run_cfgs = pcfg.run_configs()
+        all_runs = pcfg.run_configs()
+        ssm_runs = [c for c in all_runs if c.mixer == "ssm"]
+        run_cfgs = [c for c in all_runs if c.mixer != "ssm"]  # the attention kinds
+        # a state-space kind: whether its recurrence runs the ssm_scan kernel,
+        # and whether both programs carry the layers' states whole and update
+        # them in place (the ssm_kernel_active and state_in_place gauges)
+        self._ssm_kernel_costed = bool(ssm_runs) and all(ssm_kernel_active(c) for c in ssm_runs)
+        self._state_in_place = bool(ssm_runs) and all(c.scan_layers for c in ssm_runs)
         self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
         # ... and whether that step updates the arena in place, the
         # stacked leaves carried through the layer scan (the
@@ -483,6 +499,7 @@ class ServingEngine:
         self.prefill_packed_tokens = 0      # live tokens of the packs
         self._prefill_rows_dispatched = 0   # grid rows of the packs
         self.arena_bytes = arena_nbytes(self._arena)
+        self.state_bytes = state_nbytes(self._arena)  # of arena_bytes: a slot's state, all slots
         self._tokens = jnp.zeros((self.num_slots,), jnp.int32)
         self._lengths = jnp.zeros((self.num_slots,), jnp.int32)
         self._rngs = jnp.zeros((self.num_slots, 2), jnp.uint32)
@@ -594,7 +611,8 @@ class ServingEngine:
         names = set()
         for name, over in cfg.layer_kinds:
             kcfg = dataclasses.replace(cfg, **over, layer_kinds=(), layer_pattern=())
-            names.add(kcfg.cache_kind)
+            if kcfg.mixer != "ssm":  # a state a slot has no pool to size
+                names.add(kcfg.cache_kind)
             if kcfg.attn_window is not None:
                 span = -(-(kcfg.attn_window + self.prefill_chunks[-1]) // self.page_size) + 1
                 over = dict(over, kv_num_pages=int(kind_pages.get(
@@ -608,14 +626,22 @@ class ServingEngine:
             paged["kv_num_pages"] = int(kind_pages.get(cfg.cache_kind, self.num_pages))
         return dataclasses.replace(cfg, **paged, layer_kinds=tuple(kinds))
 
-    def _cache_kinds(self, pcfg) -> list:
-        """One :class:`~.pages.CacheKind` a distinct kind of state among the
-        model's runs of layers, in the order the layers first state it."""
+    def _cache_kinds(self, pcfg) -> tuple:
+        """``(paged kinds, state kind or None)``: one :class:`~.pages.CacheKind`
+        a distinct kind of paged state among the model's runs of attention
+        layers, in the order the layers first state it, and the one that is
+        a state a slot where the model has state-space layers."""
         from ..ops.attention import paged_key_lanes
 
         kinds = {}
         itemsize = jnp.dtype(pcfg.dtype).itemsize
+        state_layers = slot_bytes = 0
         for c in pcfg.run_configs():
+            if c.mixer == "ssm":
+                state_layers += c.num_layers
+                slot_bytes += c.num_layers * c.ssm_inner_dim * (
+                    c.ssm_state_dim * 4 + (c.ssm_conv_width - 1) * itemsize)
+                continue
             if self.kv_cache_dtype == "bf16":
                 token_bytes = c.num_kv_heads * (paged_key_lanes(c.head_dim) + c.value_dim) * itemsize
             else:
@@ -630,7 +656,15 @@ class ServingEngine:
                     raise ValueError(
                         f"layers of cache kind {c.cache_kind!r} disagree on their pages")
                 kind.layers += c.num_layers
-        return list(kinds.values())
+        if not kinds:
+            raise NotImplementedError(
+                "ServingEngine: a model with no attention layer is not supported: a slot's "
+                "length, admission and growth are kept by the page tables of an attention kind")
+        state = None
+        if state_layers:
+            state = CacheKind("state", None, 0, self.num_slots, 0, self.page_size,
+                              state_layers, 0, slot_bytes=slot_bytes)
+        return list(kinds.values()), state
 
     # -- compiled programs -------------------------------------------------
 
@@ -1208,6 +1242,10 @@ class ServingEngine:
                     k.allocator.in_use * k.page_bytes for k in self._kinds)
                 args["live_tokens"] = sum(
                     r.prompt.size + len(r.tokens) for r in self._slot_req.values())
+            if self._state_kind is not None:
+                # a slot's state is held from its admission on, whatever its length
+                args["state_bytes_in_use"] = self._state_kind.slot_bytes * (
+                    self.num_slots - len(self._free))
             args["emitted"] = self.generated_tokens - emitted0
         return progressed
 
@@ -2046,7 +2084,8 @@ class ServingEngine:
         if self._by_kind:
             raise NotImplementedError(
                 "ServingEngine: KV handoff (export_prefix_kv / import_prefix_kv) is not "
-                "supported for a model with layer kinds, a window, a sink or experts")
+                "supported for a model with layer kinds, a window, a sink, experts or a "
+                "recurrent state")
 
     def export_prefix_kv(self, tokens) -> Optional[dict]:
         """Export the longest cached prefix of ``tokens`` as a KV handoff:
@@ -2446,7 +2485,8 @@ class ServingEngine:
         """Dispatch one packed grid, fetch its first tokens, and put every
         pack that completed into its slot."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
-                   requests=len(packs), **self._pages_walked(packs)) as sp:
+                   requests=len(packs), **self._pages_walked(packs),
+                   **self._state_advanced(packs, fresh)) as sp:
             self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
                 self._tables_arg(), last_rows, rngs,
@@ -2537,6 +2577,20 @@ class ServingEngine:
         first, *others = self._kinds
         return {"pages_walked": pages(first),
                 **{f"pages_walked.{kind.name}": pages(kind) for kind in others}}
+
+    def _state_advanced(self, packs: list, rows: int) -> dict:
+        """What a pack hands the state-space layers, for the
+        ``serving/prefill_dispatch`` span: ``ssm_rows`` the pack's live rows,
+        on which their recurrence advances (the grid's padding rows advance
+        nothing, and the blocks the kernel copies for them are not counted),
+        ``ssm_slots`` the slots whose state it advances and
+        ``ssm_fresh_slots`` those of them it zeroes first, inside the program
+        (a request's first chunk starts at position 0). Nothing where the
+        model keeps no such state."""
+        if self._state_kind is None:
+            return {}
+        return {"ssm_rows": rows, "ssm_slots": len(packs),
+                "ssm_fresh_slots": sum(1 for _, _, s0, *_ in packs if s0 == 0)}
 
     def _burst_len(self) -> int:
         """steps_per_call when a fused burst cannot delay an admission or
@@ -2710,7 +2764,10 @@ class ServingEngine:
         )
         load = ()
         with _span("serving/decode_dispatch", slots=len(self._slot_req),
-                   arena_in_place=int(self._arena_in_place)) as sp_d:
+                   arena_in_place=int(self._arena_in_place),
+                   # the live slots' states advance one token each (an idle
+                   # slot's state is copied in and out unchanged: not counted)
+                   **({"ssm_slots": len(self._slot_req)} if self._state_kind else {})) as sp_d:
             if k > 1:
                 self._arena, self._tokens, self._lengths, self._rngs, toks = (
                     self._decode_burst(k)(
@@ -2926,6 +2983,14 @@ class ServingEngine:
         # ... and that kernel walks a slot's live pages in blocks out of HBM
         # (the one form of it there is: the same bit under the mechanism's name)
         out["serving/prefill_page_walk"] = int(self._prefill_kernel_costed)
+        if self._state_kind is not None:
+            # the state a slot keeps beside its pages (of arena_bytes), whether
+            # its recurrence runs the ssm_scan kernel, and whether the programs
+            # hold one copy of it
+            out["serving/state_bytes"] = self.state_bytes
+            out["serving/state_bytes_per_slot"] = self._state_kind.slot_bytes
+            out["serving/ssm_kernel_active"] = int(self._ssm_kernel_costed)
+            out["serving/state_in_place"] = int(self._state_in_place)
         for kind in self._kinds[1:]:
             out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use
             out[f"serving/pages_total.{kind.name}"] = kind.num_pages

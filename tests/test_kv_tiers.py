@@ -235,7 +235,7 @@ def _store_entry(key_tokens, n_pages=2, ps=PS, dtype=np.float32):
 
     return TierEntry(
         key=_digest(tokens), token_len=int(tokens.size), tokens=tokens,
-        n_pages=n_pages, arrays=arrays, paths=["k0"],
+        n_pages=n_pages, arrays=arrays, paths=["['layers']['attn']['cached_key']"],
         nbytes=entry_nbytes(arrays, tokens),
     )
 

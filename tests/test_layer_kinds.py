@@ -255,8 +255,29 @@ REFUSALS = {
 }
 
 
+def _state_model(attention_behind: bool = False):
+    """A model of one kind whose mixer is a state-space block: what it keeps
+    a slot is a recurrent state (cache kind "state"), and no pages at all.
+    ``attention_behind``: an attention layer behind the state-space one, as
+    the engine needs one to keep a slot's length by."""
+    ssm = dict(mixer="ssm", ssm_state_dim=4, ssm_dt_rank=4)
+    kinds = dict(layer_kinds=(("state_space", ssm), ("attention", {})), layer_pattern=(0, 1)) if attention_behind else ssm
+    model = DecoderLM(DecoderConfig.tiny(num_layers=2, **kinds))
+    return model, jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+
+@pytest.mark.parametrize("kind", ["layer_kinds", "state"])
 @pytest.mark.parametrize("feature", sorted(REFUSALS))
-def test_what_cannot_be_right_for_layer_kinds_refuses_by_name(feature):
+def test_what_cannot_be_right_for_layer_kinds_refuses_by_name(feature, kind):
+    """... and for a recurrent state a slot: a cached prefix would need the
+    state's snapshot at its page boundary, a page-out its copy, a rejected
+    draft its rollback."""
+    if kind == "state":
+        model, params = _state_model()
+        assert model.config.cache_kind == "state" and not model.config.layer_kinds
+        with pytest.raises(NotImplementedError, match=feature):
+            _engine(model, params, **dict(REFUSALS[feature], kind_pages=None))
+        return
     c = tiny(7)
     cfg = ARCH.decoder_config(c, max_seq_len=256, remat=False)
     params = jax.eval_shape(lambda: weights.make(REF, c, weights.seed_key(1), jnp.float32))
@@ -277,14 +298,35 @@ def test_the_flat_slot_arena_is_gone(page_size):
         _engine(DecoderLM(cfg), params, page_size=page_size, kind_pages=None)
 
 
-def test_kv_handoff_refuses_by_name():
-    c = tiny(7)
-    model, params = program(c, jnp.float32)
-    eng = _engine(model, params)
+@pytest.mark.parametrize("kind", ["layer_kinds", "state"])
+def test_kv_handoff_refuses_by_name(kind):
+    if kind == "state":
+        from accelerate_tpu.parallel.sharding import unbox_params
+
+        model, _ = _state_model(attention_behind=True)
+        params, _ = unbox_params(model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+        eng = _engine(model, params, kind_pages=None)
+        assert [k.name for k in eng._kinds] == ["full"] and eng._kinds[0].layers == 1
+        assert eng._state_kind.slot_bytes == 1 * 128 * (4 * 4 + 3 * 4)
+        req = eng.submit(np.arange(20) % 256, max_new_tokens=4)
+        eng.run()
+        assert req.outcome == "finished" and len(req.tokens) == 4
+    else:
+        model, params = program(tiny(7), jnp.float32)
+        eng = _engine(model, params)
     with pytest.raises(NotImplementedError, match="KV handoff"):
         eng.export_prefix_kv(np.arange(16))
     with pytest.raises(NotImplementedError, match="KV handoff"):
         eng.import_prefix_kv({})
+
+
+def test_a_model_with_no_attention_layer_refuses_by_name():
+    """A slot's length, admission and growth are kept by an attention kind's
+    page tables; a model of state-space layers only is refused, not served
+    over pages of no bytes."""
+    model, params = _state_model()
+    with pytest.raises(NotImplementedError, match="no attention layer"):
+        _engine(model, params, kind_pages=None)
 
 
 def test_parameters_held_and_active_a_token():

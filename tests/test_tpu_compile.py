@@ -134,6 +134,21 @@ def _moe_experts(chip, *, rows, held=16, d=4096, m=2048):
     return fn, (S((rows, d)), S((held, d, m)), S((held, d, m)), S((held, m, d)), S((held,), jnp.int32))
 
 
+def _ssm_scan(chip, *, blocks, rows, width=5120, n=16, layers=13, slots=128):
+    """The selective-scan kernel over the layers' stack of states and a layer
+    index, the stack its output: ``blocks`` of ``rows`` rows (a pack's token
+    blocks, or a decode step's one row a slot)."""
+    from accelerate_tpu.ops import ssm
+
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    fn = lambda u, dt, b, c, a, d, state, slot, n_rows, fresh, layer: ssm._ssm_scan_call(
+        u, dt, b, c, a, d, state, slot, n_rows, fresh, layer, False)
+    per_block = S((blocks,), jnp.int32)
+    return fn, (S((blocks, rows, width), jnp.bfloat16), S((blocks, rows, width)), S((blocks, rows, n)),
+                S((blocks, rows, n)), S((n, width)), S((width,)), S((layers, slots, n, width)),
+                per_block, per_block, per_block, S((), jnp.int32))
+
+
 CASES = {
     # flash attention, forward + backward (what the train step holds)
     "flash_mha_d128_fwd_bwd": (_flash, dict(b=1, h=32, kvh=32, s=2048, d=128)),
@@ -208,6 +223,17 @@ CASES = {
                               window=128, sink=True)),
     "moe_experts_decode_rows": (_moe_experts, dict(rows=64)),
     "moe_experts_prefill_rows": (_moe_experts, dict(rows=256)),
+    # a state-space mixer at published widths (5120 channels x 16, 128 slots): a decode step's one row a slot, and
+    # a pack's token blocks; and the attention layers beside it: one kv head, a query group of 20, 128-wide pages
+    "ssm_scan_decode_step_128_slots": (_ssm_scan, dict(blocks=128, rows=1)),
+    "ssm_scan_pack_256_rows": (_ssm_scan, dict(blocks=4, rows=64)),
+    "ssm_scan_pack_64_rows": (_ssm_scan, dict(blocks=1, rows=64)),
+    "paged_decode_one_kv_head_group20_in_place": (
+        _paged_decode, dict(h=20, kvh=1, slots=128, pages=16384, table=512, write=True)),
+    "ragged_prefill_one_kv_head_group20": (
+        _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=256, slots=128, pages=16384, table=512)),
+    "ragged_prefill_one_kv_head_group20_64_rows": (
+        _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=64, slots=128, pages=16384, table=512)),
     # dense-arena decode (single-stream generate(), the flat slot arena)
     "dense_decode_bf16": (_dense_decode, dict(bits=0)),
     "dense_decode_int8": (_dense_decode, dict(bits=8)),
@@ -276,6 +302,8 @@ KERNEL_NAMES = {
     "paged_decode_bf16_d128_sq1": {"attn"},
     "paged_decode_serving_cell_in_place": {"attn"},
     "moe_experts_decode_rows": {"moe_experts"},
+    "ssm_scan_decode_step_128_slots": {"ssm_scan"},
+    "ssm_scan_pack_256_rows": {"ssm_scan"},
 }
 
 
@@ -365,6 +393,53 @@ def test_the_decode_step_holds_one_arena(chip, monkeypatch, by_kind, threading):
         # (its temporaries say nothing at this size: the compiler keeps them in VMEM, 128 MiB on a v5e;
         # at the serving cell's size they are 4.16 GiB against 0.3 MiB: PERF.md, PR 29)
         assert {"copy", "dynamic-update-slice"} <= set(found), found
+
+
+@pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
+def test_both_serving_programs_hold_one_copy_of_the_state(chip, monkeypatch, program):
+    """A small model with state-space layers beside an attention layer without
+    rotation (one kv head, a query group of 4), both serving programs
+    compiled for the chip: the ``ssm_scan`` kernel is in each, and no
+    operation of the program has the stacked states' or one layer's states'
+    shape as its result but the kernel: no slice out of the stack, no copy,
+    no update-slice. The convolution's kept inputs, a 16th of the state, move
+    through XLA and are not held to that."""
+    import re
+
+    from accelerate_tpu.models import DecoderConfig, DecoderLM
+    from accelerate_tpu.parallel.sharding import unbox_params
+    from accelerate_tpu.serving import ServingEngine
+
+    model = DecoderLM(DecoderConfig(
+        vocab_size=512, embed_dim=256, num_heads=4, num_kv_heads=1, head_dim=128, rope_dim=0, mlp_dim=512,
+        max_seq_len=512, dtype=jnp.bfloat16, residual_dtype=jnp.float32, scan_layers=True, remat=False,
+        num_layers=5, layer_kinds=(("state_space", dict(mixer="ssm", ssm_state_dim=16, ssm_dt_rank=16)),
+                                   ("attention", dict(mixer="attention"))), layer_pattern=(0, 0, 1, 0, 0)))
+    params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = ServingEngine(model, params, num_slots=8, max_cache_len=512, page_size=16, num_pages=257, prefix_cache=False)
+    m = eng.metrics()
+    assert m["serving/ssm_kernel_active"] == 1 and m["serving/state_in_place"] == 1 and m["serving/decode_kernel_active"]
+    S = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    if program == "decode_step":
+        fn = jax.jit(eng._step_core, donate_argnums=(1, 2, 3, 5))
+        args = (eng.params, eng._arena, eng._tokens, eng._lengths, eng._active, eng._rngs, eng._tables_arg())
+    else:
+        fn, args = eng._ragged_prefill_fn(256), eng._ragged_warm_args(256)
+    unoptimized = jax.config.values["jax_disable_most_optimizations"]
+    jax.config.update("jax_disable_most_optimizations", False)
+    try:
+        text = fn.lower(*S(args)).compile().as_text()
+    finally:
+        jax.config.update("jax_disable_most_optimizations", unoptimized)
+    assert "ssm_scan" in _kernel_names(text)
+    states = [x for p, x in jax.tree_util.tree_flatten_with_path(eng._arena)[0] if p[-1].key == "ssm_state"]
+    assert len(states) == 2 and all(x.shape == (2, 8, 16, 512) for x in states)
+    shapes = {",".join(map(str, shp)) for x in states for shp in (x.shape, x.shape[1:])}
+    moved = re.compile(r"= \w+\[(%s)\]\S* (copy|copy-start|dynamic-slice|dynamic-update-slice|scatter)\("
+                       % "|".join(shapes))
+    assert not sorted({m.group(2) for m in moved.finditer(text)})
 
 
 @pytest.mark.parametrize("outer_manual", [(), ("fsdp", "tensor"), ("fsdp",)],
