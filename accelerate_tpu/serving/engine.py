@@ -40,7 +40,7 @@ import os
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -137,6 +137,11 @@ class Request:
     shed_reason: Optional[str] = None
     preemptions: int = 0
     _last_token_t: float = 0.0
+    # tokens whose production is enqueued on the device (the pack's first
+    # and one a decode step since): the engine's positions, budget and
+    # ``_active`` follow from this count, ``len(tokens)`` lags it by what
+    # is in flight
+    _dispatched: int = 0
     _cancel: bool = False
     _resume: Optional[dict] = None       # preempted: saved RNG row for re-admission
     # paged-arena attribution (request records carry these so
@@ -547,11 +552,24 @@ class ServingEngine:
         self._id_lock = threading.Lock()
 
         self._step_core = self._build_step_core()
-        donate = (1, 2, 3, 5) if self._donate else ()
-        self._decode_step = jax.jit(self._step_core, donate_argnums=donate)
+        self._decode_step = jax.jit(self._step_core, donate_argnums=self._step_donate())
         self._decode_bursts: dict = {}
         self._ragged_fns: dict = {}
+        # admission on the device: a request's two keys are staged into
+        # these rows when it takes its slot (the pack program samples with
+        # the first, the slot's decode chain starts from the second), and
+        # one program a pack puts its slots live from the pack's own firsts
+        self._prefill_keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
+        self._decode_keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
+        self._stage_keys = jax.jit(_stage_keys_fn)
         self._admit_state = jax.jit(_admit_state_fn)
+        # one dispatch in flight: the decode step whose tokens the host has
+        # not read yet (read after the next one is enqueued, or by
+        # _settle()), and this iteration's packs, whose first tokens are
+        # read behind the decode dispatch
+        self._flight: Optional[_StepFlight] = None
+        self._flight_packs: list = []
+        self._last_result_t = 0.0  # when the host last saw a result complete
 
         # metrics
         self.iterations = 0  # scheduler iterations (calls of step() that had work)
@@ -564,6 +582,7 @@ class ServingEngine:
         self.preemptions = 0
         self.resumptions = 0
         self.generated_tokens = 0
+        self.rows_discarded = 0  # decode rows dispatched for a request whose eos was still in flight
         self._step_samples: deque = deque(maxlen=512)  # (wall_s, tokens, steps)
         self._itl: deque = deque(maxlen=2048)  # inter-token gaps, seconds
         self._itl_emitted = 0   # lifetime gap count; the controller only
@@ -667,6 +686,13 @@ class ServingEngine:
         return list(kinds.values()), state
 
     # -- compiled programs -------------------------------------------------
+
+    def _step_donate(self) -> tuple:
+        """What the decode step and its bursts donate: the arena, the
+        lengths and the key chains. Not the tokens: a step's tokens are
+        read by the host after the next step, which takes them as its
+        input, is enqueued."""
+        return (1, 3, 5) if self._donate else ()
 
     def _build_step_core(self):
         placer = self._placer
@@ -806,7 +832,7 @@ class ServingEngine:
             )
             return arena, tokens, lengths, rngs, toks
 
-        fn = jax.jit(burst, donate_argnums=(1, 2, 3, 5) if self._donate else ())
+        fn = jax.jit(burst, donate_argnums=self._step_donate())
         self._decode_bursts[k] = fn
         return fn
 
@@ -864,11 +890,18 @@ class ServingEngine:
         return (self.params, self._arena, ids, pad, pad, per_slot, self._tables_arg(),
                 per_slot, jnp.zeros((self.num_slots, 2), jnp.uint32))
 
+    def _admit_warm_args(self) -> tuple:
+        """``_admit_state`` with no slot going live: nothing changes."""
+        meta = np.zeros((self.num_slots, 5), np.int32)
+        return (self._tokens, self._lengths, self._rngs, self._tokens,
+                self._decode_keys, jnp.asarray(meta))
+
     def warmup(self):
         """Compile every program this engine can ever dispatch — each
-        packed-prefill grid capacity, the admission scatter, the single decode step and
-        the ``steps_per_call`` burst, plus the host-side eager RNG ops —
-        by running them once against the (idle) arena. After
+        packed-prefill grid capacity, the two admission programs (a request's
+        keys staged on the device, a pack's slots put live), the single decode
+        step and the ``steps_per_call`` burst — by running them once against
+        the (idle) arena. After
         ``warmup(); mark_steady()``, ``admission_recompiles`` staying 0 is
         deterministic, not a function of what traffic happened to arrive.
         All-inactive decode steps park their writes (see the step body), so
@@ -889,11 +922,6 @@ class ServingEngine:
     def _warmup(self):
         if self._slot_req or self._queued_depth() or self._admitting is not None:
             raise RuntimeError("warmup() needs an idle engine")
-        rng = jax.random.PRNGKey(0)
-        # the eager per-admission ops, UNPACKED like _advance_admission does:
-        # iterating the split result compiles the index programs too, and
-        # they must not count against the post-steady recompile invariant
-        _, _ = jax.random.split(rng)
         if self.telemetry is not None:
             from ..telemetry import forensics
 
@@ -901,7 +929,7 @@ class ServingEngine:
             # steady-state signatures, so any later diagnosed recompile
             # names what the admission path changed
             forensics.register(
-                "decode_step", donate=(1, 2, 3, 5) if self._donate else (),
+                "decode_step", donate=self._step_donate(),
                 statics={"num_slots": self.num_slots,
                          "max_cache_len": self.max_cache_len,
                          "temperature": self.temperature, "top_k": self.top_k},
@@ -913,7 +941,7 @@ class ServingEngine:
         # Warmup runs them as no-ops against the idle state (row 0 is
         # already parking; forking the parking page onto itself).
         self._page_tables = self._set_row(
-            self._page_tables, 0, jnp.asarray(self._tables_host.rows[0])
+            self._page_tables, 0, _row_upload(self._tables_host.rows[0])
         )
         self._page_tables = self._set_entry(self._page_tables, 0, 0, 0)
         if not self._by_kind:  # nothing forks, imports or demotes a page there
@@ -940,9 +968,12 @@ class ServingEngine:
                         self._ragged_prefill_fn(rcap).lower(*self._ragged_warm_args(rcap)))
                 except Exception:
                     pass
+        # the admission programs: a request's keys staged into slot 0's rows
+        # (submit()'s own key program with them), and a pack of no slot put live
+        self._prefill_keys, self._decode_keys = self._stage_keys(
+            self._prefill_keys, self._decode_keys, 0, jax.random.PRNGKey(0))
         self._tokens, self._lengths, self._rngs = self._admit_state(
-            self._tokens, self._lengths, self._rngs, 0, 0, 0, rng
-        )
+            *self._admit_warm_args())
         self._note_forensics(
             "decode_step",
             {"tokens": self._tokens, "lengths": self._lengths,
@@ -1012,7 +1043,7 @@ class ServingEngine:
         specs = []
         step_args = (self.params, self._arena, self._tokens, self._lengths,
                      self._active, self._rngs, self._page_tables)
-        step_donate = (1, 2, 3, 5) if donate_on else ()
+        step_donate = self._step_donate()
         # NB: no shape_probe on the engine's own programs, deliberately.
         # The weak-shape check compares shape-derived scalar literals
         # between two traces, and the batched per-slot RNG chains bake
@@ -1046,7 +1077,7 @@ class ServingEngine:
         specs.append(dict(
             name="table_set_row", fn=self._set_row,
             args=(self._page_tables, 0,
-                  jnp.asarray(self._tables_host.rows[0])),
+                  _row_upload(self._tables_host.rows[0])),
             donate=table_donate, donate_expected=donate_on,
         ))
         specs.append(dict(
@@ -1067,6 +1098,17 @@ class ServingEngine:
                 donate=(1,) if donate_on else (),
                 donate_expected=donate_on, compute_dtype=dtype,
             ))
+        # the admission programs (a few words a slot: nothing worth donating)
+        specs.append(dict(
+            name="stage_keys", fn=self._stage_keys,
+            args=(self._prefill_keys, self._decode_keys, 0,
+                  jnp.zeros((2,), jnp.uint32)),
+            donate=(), donate_expected=False,
+        ))
+        specs.append(dict(
+            name="admit_state", fn=self._admit_state,
+            args=self._admit_warm_args(), donate=(), donate_expected=False,
+        ))
         return specs
 
     # -- request API -------------------------------------------------------
@@ -1210,15 +1252,21 @@ class ServingEngine:
         return self._sched.total_queued if self._sched is not None else len(self._queue)
 
     def _pending(self) -> bool:
+        # a result in flight counts: after a late eos the last dispatch may
+        # carry no live request, and run() still ends with nothing unread
         return bool(
             self._queued_depth() or self._admitting is not None or self._slot_req
+            or self._flight is not None
         )
 
     def step(self) -> bool:
         """One scheduler iteration: reap cancels/timeouts, apply pressure
         decisions (shed, preempt), advance prefill admission within the
-        ITL-budget, then run one batched decode step over every active
-        slot. Returns whether any work happened (False = fully idle)."""
+        ITL-budget, enqueue one batched decode step over every active slot,
+        and only then read what the device has finished: the previous
+        step's tokens and this iteration's first tokens. One dispatch is in
+        flight while the host works; nothing else in here waits for the
+        device. Returns whether any work happened (False = fully idle)."""
         if self._faults is None and not self._pending():
             # an idle poll (serve() between requests) does nothing below and
             # records no span: a thousand of them a second would wash the
@@ -1240,8 +1288,9 @@ class ServingEngine:
                 # what the cache holds against what it holds it for
                 args["kv_bytes_in_use"] = sum(
                     k.allocator.in_use * k.page_bytes for k in self._kinds)
+                # counted as dispatched, like the pages held for them
                 args["live_tokens"] = sum(
-                    r.prompt.size + len(r.tokens) for r in self._slot_req.values())
+                    r.prompt.size + r._dispatched for r in self._slot_req.values())
             if self._state_kind is not None:
                 # a slot's state is held from its admission on, whatever its length
                 args["state_bytes_in_use"] = self._state_kind.slot_bytes * (
@@ -1255,6 +1304,9 @@ class ServingEngine:
         with _span("serving/reap") as sp:
             gone0 = (self.requests_cancelled, self.requests_shed, self.preemptions)
             if self._faults is not None:
+                # a fault script keys on step_count and on what the requests
+                # hold: it sees the engine with nothing unread
+                self._settle()
                 self._faults.on_step(self)
             if self._draining and self._queued_depth():
                 # request_drain() only sets the flag (it may fire from a
@@ -1288,6 +1340,9 @@ class ServingEngine:
         else:
             progressed = self._advance_admission() or progressed
         progressed = self._decode_once() or progressed
+        # the packs' first tokens, behind the decode dispatch: the step runs
+        # while the host stamps and commits them
+        progressed = self._read_packs(int(self._flight is not None)) or progressed
         if (
             self._controller is not None
             and self._itl_emitted != self._itl_observed
@@ -1334,6 +1389,7 @@ class ServingEngine:
                     if should_stop is None and not self._pending():
                         return
                     time.sleep(idle_sleep_s)
+            self._settle()  # stopped from outside: deliver what was computed
         except Exception:
             self._flight_dump("serving_exception")
             raise
@@ -1377,6 +1433,7 @@ class ServingEngine:
         )
         while self._pending():
             if deadline is not None and time.perf_counter() > deadline:
+                self._settle()  # the stragglers keep what was computed for them
                 now = time.perf_counter()
                 if self._admitting is not None:
                     self._abort_admission(now, "cancelled", "drain_timeout")
@@ -1563,6 +1620,11 @@ class ServingEngine:
         victim = self._sched.pick_victim(self._slot_req.items(), best)
         if victim is None:
             return False
+        if self._settle():
+            # the tokens just read may have ended a request and freed a
+            # slot: decide again, with nothing unread
+            self._maybe_preempt()
+            return True
         self._preempt(*victim)
         return True
 
@@ -1572,7 +1634,10 @@ class ServingEngine:
         prefix cache, release the slot, and requeue it at the front of
         its class. Re-admission replays prompt+generated via the prefix
         cache (mostly hits) and restores the saved chain — token-exact
-        vs. an uninterrupted run, asserted in tests."""
+        vs. an uninterrupted run, asserted in tests. The callers settle
+        first: the saved chain stands behind every dispatched step, so
+        ``req.tokens`` must hold every dispatched token too."""
+        assert req._dispatched == len(req.tokens), "preempt with a token in flight"
         # whole-array device_get then host index: jnp fancy-indexing one
         # row would compile a gather, breaking the zero-recompile invariant
         rng_row = np.asarray(jax.device_get(self._rngs))[slot].copy()
@@ -1613,6 +1678,7 @@ class ServingEngine:
         sheds ``req`` instead of wedging."""
         if self._sched is None:
             return False
+        self._settle()
         victim = self._sched.pick_victim(
             ((s, r) for s, r in self._slot_req.items() if s != exclude_slot),
             int(req.priority),
@@ -1747,7 +1813,7 @@ class ServingEngine:
                     kind.device_tables = self._set_entry(kind.device_tables, slot, *grown[0])
                 elif grown:
                     kind.device_tables = self._set_row(
-                        kind.device_tables, slot, jnp.asarray(kt.rows[slot]))
+                        kind.device_tables, slot, _row_upload(kt.rows[slot]))
         for idx in range(lo_pos // ps, p_hi + 1):
             page = int(th.rows[slot][idx])
             if not self._allocator.shared(page):
@@ -1819,7 +1885,7 @@ class ServingEngine:
                 self._plan_chunks(seq.size - hit_len)
             )
         self._page_tables = self._set_row(
-            self._page_tables, slot, jnp.asarray(th.rows[slot])
+            self._page_tables, slot, _row_upload(th.rows[slot])
         )
         tail_plan = self._plan_chunks(seq.size - hit_len)
         return [(hit_len + start, bucket) for start, bucket in tail_plan]
@@ -1852,7 +1918,7 @@ class ServingEngine:
             held += len(pages)
             th.reset_slot(slot)
             kind.device_tables = self._set_row(
-                kind.device_tables, slot, jnp.asarray(th.rows[slot])
+                kind.device_tables, slot, _row_upload(th.rows[slot])
             )
         if tenant is not None and held:
             usage = self._usage()
@@ -2297,8 +2363,9 @@ class ServingEngine:
             tr.on_first_token(req, ttft)
 
     def _advance_admission(self) -> bool:
-        """One admission dispatch: plan and pack on the host, dispatch the
-        prefill program, fetch the first tokens, commit them."""
+        """One admission dispatch: plan and pack on the host, enqueue the
+        prefill program and put the slots it completes live on the device.
+        Its first tokens stay in flight until ``_read_packs``."""
         tr = self._tracer()
         with _span("serving/admit_plan"):
             work = self._plan_dispatch(tr)
@@ -2306,30 +2373,42 @@ class ServingEngine:
             return work  # nothing to admit, or progress without a dispatch
         return self._ragged_dispatch(tr, *work)
 
-    def _admission_writable(self, req: Request, slot: int, lo: int, hi: int) -> bool:
-        """Pages for the admission's next write range. Same ladder as
-        live-slot growth (_grow_or_resolve): LRU eviction already failed
-        inside _ensure_writable, so try paging out a strictly
-        lower-priority victim before giving up — shedding the admission
-        first would drop the highest-priority work under pressure. Only
-        when no victim qualifies is the admission shed (never a raise out
-        of step()); False then."""
+    def _retry_writable(self, req: Request, slot: int, lo: int, hi: int) -> bool:
         try:
             self._ensure_writable(req, slot, lo, hi)
             return True
         except PagePressure:
-            if self._relieve_pressure(req, slot):
-                try:
-                    self._ensure_writable(req, slot, lo, hi)
-                    return True
-                except PagePressure:
-                    pass
+            return False
+
+    def _admission_writable(self, req: Request, slot: int, lo: int, hi: int) -> bool:
+        """Pages for the admission's next write range. Same ladder as
+        live-slot growth (_grow_or_resolve): LRU eviction already failed
+        inside _ensure_writable, so read what is in flight (its tokens may
+        end requests and free their pages), then try paging out a strictly
+        lower-priority victim before giving up — shedding the admission
+        first would drop the highest-priority work under pressure. Only
+        when no victim qualifies is the admission shed (never a raise out
+        of step()); False then."""
+        if self._retry_writable(req, slot, lo, hi):
+            return True
+        if self._settle() and self._retry_writable(req, slot, lo, hi):
+            return True
+        if self._relieve_pressure(req, slot) and self._retry_writable(req, slot, lo, hi):
+            return True
         self._abort_admission(time.perf_counter(), "shed", SHED_PAGE_EXHAUSTED)
         flight = getattr(self.telemetry, "flight", None)
         if flight is not None:
             flight.note("request_shed", request_id=req.id,
                         reason=SHED_PAGE_EXHAUSTED)
         return False
+
+    def _stage_request_keys(self, req: Request, slot: int):
+        """``req``'s prefill and decode keys into its slot's rows, on the
+        device (``jax.random.split`` inside one small program: the bits of
+        the single-stream loop, and no host read of a key, which would wait
+        for the step in flight)."""
+        self._prefill_keys, self._decode_keys = self._stage_keys(
+            self._prefill_keys, self._decode_keys, slot, req.rng)
 
     def _plan_dispatch(self, tr):
         """Everything of an admission step that comes before the prefill
@@ -2346,15 +2425,12 @@ class ServingEngine:
             if req._resume is not None:
                 # preemption resume: replay prompt+generated (mostly
                 # prefix-cache hits — the page-out published those pages),
-                # discard the trailing sample, restore the saved RNG chain.
-                # req.rng is reused as the (ignored) prefill sample key: a
-                # concrete array, so no fresh eager op can recompile.
+                # discard the trailing sample (whatever key the slot's row
+                # holds draws it), restore the saved RNG chain.
                 seq = self._replay_seq(req)
-                prefill_rng = req.rng
-                decode_rng = jnp.asarray(req._resume["rng"])
             else:
                 seq = req.prompt
-                prefill_rng, decode_rng = jax.random.split(req.rng)
+                self._stage_request_keys(req, slot)
             # tier probe BEFORE the admit plan: a host/disk/peer hit
             # longer than HBM's best sets up a staged restore (plan
             # None until the pages land); otherwise plan immediately
@@ -2364,13 +2440,13 @@ class ServingEngine:
                 plan = None
             else:
                 plan = self._paged_admit_plan(req, slot, seq)
-            self._admitting = [req, slot, plan, 0, prefill_rng, decode_rng, seq]
+            self._admitting = [req, slot, plan, 0, seq]
             if req._resume is not None:
                 if tr is not None:
                     tr.on_resume(req, slot)
             else:
                 self._note_admission(req, slot, tr)
-        req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
+        req, slot, plan, idx, seq = self._admitting
         if plan is None:
             # restore in flight: one page batch per scheduler iteration,
             # so the decode step right after overlaps the installs
@@ -2389,7 +2465,7 @@ class ServingEngine:
         the zero-recompile invariant (grid capacities fixed at warmup).
         Returns ``_ragged_dispatch``'s arguments, or True where the
         admission was shed for pages."""
-        req, slot, plan, idx, prefill_rng, decode_rng, seq = self._admitting
+        req, slot, plan, idx, seq = self._admitting
         bt = self._ragged_bt
         cap_max = self._ragged_caps[-1]
         # ``idx`` is the next global position to prefill (0 = nothing
@@ -2402,13 +2478,12 @@ class ServingEngine:
             self._faults.before_prefill(self)
         if not self._admission_writable(req, slot, cur, cur + n - 1):
             return True
-        # packs: [request, slot, s0, s1, prefill_rng, decode_rng, seq,
-        # primary]. The primary may be mid-tail (longer than the largest
-        # grid); co-admitted tails are always whole, so every co-admit
-        # completes in-dispatch and the admission singleton invariant
-        # (_reap/_abort only ever see self._admitting[0]) holds.
-        packs = [[req, slot, cur, cur + n, prefill_rng, decode_rng, seq,
-                  True]]
+        # packs: [request, slot, s0, s1, seq, primary]. The primary may be
+        # mid-tail (longer than the largest grid); co-admitted tails are
+        # always whole, so every co-admit completes in-dispatch and the
+        # admission singleton invariant (_reap/_abort only ever see
+        # self._admitting[0]) holds.
+        packs = [[req, slot, cur, cur + n, seq, True]]
         used = -(-n // bt) * bt
         # co-admission: pull further queued requests into the same grid.
         # FIFO only (a scheduler's WFQ/priority pick must stay one-at-a-
@@ -2430,7 +2505,6 @@ class ServingEngine:
                     break
                 self._queue.popleft()
                 slot2 = self._free.pop()
-                p_rng, d_rng = jax.random.split(nxt.rng)
                 plan2 = self._paged_admit_plan(nxt, slot2, nxt.prompt)
                 hit2 = plan2[0][0]
                 n2 = int(nxt.prompt.size) - hit2
@@ -2447,9 +2521,9 @@ class ServingEngine:
                         nxt.prefix_hit = 0
                     self._queue.appendleft(nxt)
                     break
+                self._stage_request_keys(nxt, slot2)
                 self._note_admission(nxt, slot2, tr)
-                packs.append([nxt, slot2, hit2, hit2 + n2, p_rng, d_rng,
-                              nxt.prompt, False])
+                packs.append([nxt, slot2, hit2, hit2 + n2, nxt.prompt, False])
                 used += -(-n2 // bt) * bt
         rcap = next(c for c in self._ragged_caps if c >= used)
         ids = np.zeros((1, rcap), np.int32)
@@ -2457,10 +2531,9 @@ class ServingEngine:
         row_pos = np.full((rcap,), -1, np.int32)
         hist = np.zeros((self.num_slots,), np.int32)
         last_rows = np.zeros((self.num_slots,), np.int32)
-        rngs = np.zeros((self.num_slots, 2), np.uint32)
         fresh = 0
         r = 0
-        for preq, psl, s0, s1, prng, _, pseq, _ in packs:
+        for preq, psl, s0, s1, pseq, _ in packs:
             nseg = s1 - s0
             nb = -(-nseg // bt)
             ids[0, r:r + nseg] = pseq[s0:s1]
@@ -2471,91 +2544,124 @@ class ServingEngine:
             row_pos[r:r + nseg] = np.arange(s0, s1)
             hist[psl] = s0
             last_rows[psl] = r + nseg - 1
-            rngs[psl] = np.asarray(jax.device_get(prng), np.uint32)
             r += nb * bt
             fresh += nseg
         ids_dev = jnp.asarray(ids)
         self._note_forensics(f"ragged_prefill_{rcap}", {"ids": ids_dev})
         return (packs, rcap, fresh, ids_dev, jnp.asarray(row_slot),
-                jnp.asarray(row_pos), jnp.asarray(hist), jnp.asarray(last_rows),
-                jnp.asarray(rngs))
+                jnp.asarray(row_pos), jnp.asarray(hist), jnp.asarray(last_rows))
 
     def _ragged_dispatch(self, tr, packs: list, rcap: int, fresh: int, ids_dev,
-                         row_slot, row_pos, hist, last_rows, rngs) -> bool:
-        """Dispatch one packed grid, fetch its first tokens, and put every
-        pack that completed into its slot."""
+                         row_slot, row_pos, hist, last_rows) -> bool:
+        """Enqueue one packed grid and put every pack it completes into its
+        slot, on the device and in the host's books: from here on the slot
+        is live, and the decode step of this same iteration carries it. The
+        first tokens themselves stay on the device (``_admit_state`` takes
+        them from the pack program's result) until ``_read_packs`` fetches,
+        stamps and emits them, behind that decode dispatch."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
                    requests=len(packs), **self._pages_walked(packs),
                    **self._state_advanced(packs, fresh)) as sp:
             self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
-                self._tables_arg(), last_rows, rngs,
+                self._tables_arg(), last_rows, self._prefill_keys,
             )
-        with _span("serving/prefill_fetch") as sp_f:
-            firsts_h, *load = jax.device_get((firsts, *load))  # one fetch
-            firsts_h = np.asarray(firsts_h)
-        if load:
-            _load_args(sp, np.asarray(load[0]), fresh * self._pairs_per_token)
-        t0, wall = sp.t0, sp_f.t1 - sp.t0
-        with _span("serving/prefill_commit") as sp_c:
-            costs = (getattr(self.telemetry, "costs", None)
-                     if self.telemetry is not None else None)
-            if costs is not None:
-                costs.note_wall(f"ragged_prefill_{rcap}", wall)
-            usage = self._usage()
             self.prefill_packed_tokens += fresh
             self._prefill_rows_dispatched += rcap
-            now = time.perf_counter()
-            first_tokens = 0
-            for preq, psl, s0, s1, prng, drng, pseq, primary in packs:
-                self._note_prefill_chunk(preq, psl, s0, s1 - s0, t0, wall, tr)
-                # a window kind's pages behind the next row's window go back
+            # (goes live, the token a resume continues from or -1 for the
+            # pack's own first, length, a resume's saved chain in two words)
+            meta = np.zeros((self.num_slots, 5), np.int32)
+            meta[:, 1] = -1
+            rows = []  # what _read_packs notes and emits for each pack
+            for preq, psl, s0, s1, pseq, primary in packs:
+                # a window kind's pages behind the next row's window go
+                # back while the pack that reads them is still in flight:
+                # whoever writes such a page next is a later dispatch on
+                # the same stream, so the pack has read it by then
                 self._release_behind_window(preq, psl, s1)
-                if usage is not None:
-                    usage.note_prefill(preq.tenant, s1 - s0)
-                    # the shared dispatch wall is billed proportionally to
-                    # each tenant's live tokens in the pack
-                    usage.note_compute(
-                        preq.tenant, wall * 1e3 * (s1 - s0) / max(fresh, 1)
-                    )
                 if primary and s1 < pseq.size:
                     # mid-tail: the primary stays the admission singleton
                     # and resumes at position s1 next scheduler iteration
                     # (a mid-tail primary fills the whole grid, so it never
                     # coexists with co-admits)
                     self._admitting[3] = s1
+                    rows.append((preq, psl, s0, s1, False))
                     continue
                 if primary:
                     self._admitting = None
-                resume = preq._resume is not None
-                if not resume:
-                    self._insert_prefix(preq, psl)
-                if resume:
+                if preq._resume is not None:
                     # the replayed slot continues where it was paged out:
                     # last emitted token, restored chain, no new emission
-                    first_tok = int(preq.tokens[-1])
-                    length = int(pseq.size)
+                    meta[psl, :3] = 1, preq.tokens[-1], pseq.size
+                    meta[psl, 3:] = np.asarray(preq._resume["rng"], np.uint32).view(np.int32)
+                    preq._dispatched = len(preq.tokens)
                     preq._resume = None
+                    preq._last_token_t = 0.0
                     self.resumptions += 1
+                    rows.append((preq, psl, s0, s1, False))
                 else:
-                    first_tok = int(firsts_h[psl])
-                    length = int(preq.prompt.size)
-                self._tokens, self._lengths, self._rngs = self._admit_state(
-                    self._tokens, self._lengths, self._rngs, psl, first_tok,
-                    length, drng,
-                )
+                    self._insert_prefix(preq, psl)
+                    meta[psl, 0], meta[psl, 2] = 1, preq.prompt.size
+                    preq._dispatched = 1
+                    rows.append((preq, psl, s0, s1, True))
                 preq.slot = psl
                 preq.prefill_kernel = "ragged" if self._prefill_kernel_costed else "dense"
                 self._slot_req[psl] = preq
-                self._active[psl] = True
-                if resume:
-                    preq._last_token_t = 0.0
+                self._active[psl] = preq._dispatched < preq.max_new_tokens
+            if meta[:, 0].any():
+                self._tokens, self._lengths, self._rngs = self._admit_state(
+                    self._tokens, self._lengths, self._rngs, firsts,
+                    self._decode_keys, jnp.asarray(meta))
+        self._flight_packs.append(_PackFlight(firsts, tuple(load), rows, rcap, fresh, sp))
+        return True
+
+    def _read_packs(self, in_flight: int) -> bool:
+        """Fetch the first tokens of this iteration's packs, oldest first,
+        and stamp and emit them. ``in_flight``: 1 where a decode step was
+        enqueued behind them (the chip runs it while the host commits), 0
+        where they are read with nothing behind."""
+        if not self._flight_packs:
+            return False
+        flights, self._flight_packs = self._flight_packs, []
+        for flight in flights:
+            self._read_pack(flight, in_flight)
+        return True
+
+    def _read_pack(self, flight: "_PackFlight", in_flight: int):
+        sp, tr, rcap, fresh = flight.span, self._tracer(), flight.rcap, flight.fresh
+        with _span("serving/prefill_fetch", in_flight=in_flight) as sp_f:
+            firsts_h, *load = jax.device_get((flight.firsts, *flight.load))  # one fetch
+            firsts_h = np.asarray(firsts_h)
+        if load:
+            _load_args(sp, np.asarray(load[0]), fresh * self._pairs_per_token)
+        # a request waited from the dispatch on; the device worked on the
+        # pack from when it had finished what lay before it
+        t0, wall = sp.t0, sp_f.t1 - sp.t0
+        device_wall = sp_f.t1 - max(sp.t0, self._last_result_t)
+        self._last_result_t = sp_f.t1
+        with _span("serving/prefill_commit") as sp_c:
+            costs = (getattr(self.telemetry, "costs", None)
+                     if self.telemetry is not None else None)
+            if costs is not None:
+                costs.note_wall(f"ragged_prefill_{rcap}", device_wall)
+            usage = self._usage()
+            now = time.perf_counter()
+            first_tokens = 0
+            for preq, psl, s0, s1, first in flight.rows:
+                self._note_prefill_chunk(preq, psl, s0, s1 - s0, t0, wall, tr)
+                if usage is not None:
+                    usage.note_prefill(preq.tenant, s1 - s0)
+                    # the shared dispatch wall is billed proportionally to
+                    # each tenant's live tokens in the pack
+                    usage.note_compute(
+                        preq.tenant, device_wall * 1e3 * (s1 - s0) / max(fresh, 1)
+                    )
+                if not first or preq.done:
                     continue
                 self._note_first_token(preq, now, tr)
-                self._emit(preq, first_tok, now)
+                self._emit(preq, int(firsts_h[psl]), now)
                 first_tokens += 1
             sp_c.args["first_tokens"] = first_tokens
-        return True
 
     def _pages_walked(self, packs: list) -> dict:
         """What the ragged prefill kernel is handed in this pack, for the
@@ -2600,15 +2706,18 @@ class ServingEngine:
         if k <= 1 or self._admitting is not None or (self._queued_depth() and self._free):
             return 1
         remaining = min(
-            req.max_new_tokens - len(req.tokens) for req in self._slot_req.values()
+            req.max_new_tokens - req._dispatched
+            for slot, req in self._slot_req.items() if self._active[slot]
         )
         return k if remaining >= k else 1
 
     def _next_write_pos(self, req: Request) -> int:
-        """The slot's next cache write position: the latest emitted token's
-        K/V has not been written yet (prefill samples the first token, each
-        decode step writes the PREVIOUS token before sampling the next)."""
-        return req.prompt.size + len(req.tokens) - 1
+        """The slot's next cache write position: the latest dispatched
+        token's K/V has not been written yet (prefill samples the first
+        token, each decode step writes the PREVIOUS token before sampling
+        the next). Counted in tokens dispatched, which the host knows
+        without reading any."""
+        return req.prompt.size + req._dispatched - 1
 
     def _note_walk(self, sp, walked: list) -> None:
         """What the decode kernel is handed this round, on the
@@ -2617,14 +2726,15 @@ class ServingEngine:
         one layer of the first cache kind walks for them (a model of
         several kinds adds ``walked_tokens.<kind>`` for the others: a
         window kind walks its window's pages only). A block is
-        ``_walk_block_pages`` table entries; a slot that is free or
-        mid-admission has no live tokens and is skipped whole."""
+        ``_walk_block_pages`` table entries; a slot that is free,
+        mid-admission or waiting for its last token to be read has no live
+        tokens and is skipped whole."""
         block = self._walk_block_pages * self.page_size
         first, *others = self._kinds
         tokens = [first.walked_tokens(p) for p in walked]
         sp.args["walked_tokens"] = sum(tokens)
         sp.args["walked_blocks"] = sum(-(-w // block) for w in tokens)
-        sp.args["skipped_slots"] = self.num_slots - len(self._slot_req)
+        sp.args["skipped_slots"] = self.num_slots - int(self._active.sum())
         for kind in others:
             sp.args[f"walked_tokens.{kind.name}"] = sum(kind.walked_tokens(p) for p in walked)
 
@@ -2675,14 +2785,15 @@ class ServingEngine:
                     self._lengths, self._active, self._rngs, self._page_tables,
                 )
             )
-        with _span("serving/token_fetch") as sp_f:
+        with _span("serving/token_fetch", in_flight=0) as sp_f:
             cand_h = np.asarray(jax.device_get(cand))  # [N, K+1]; forces the step
             m_h = np.asarray(jax.device_get(m))
         t0, wall = sp_d.t0, sp_f.t1 - sp_d.t0
-        with _span("serving/emit") as sp_e:
+        self._last_result_t = sp_f.t1
+        with _span("serving/emit", discarded=0) as sp_e:
             done0 = self.requests_completed
             self.step_count += 1
-            self._usage_note_step(wall)
+            self._usage_note_step(wall, list(self._slot_req.items()))
             emitted = 0
             for slot, req in list(self._slot_req.items()):
                 accepted = int(m_h[slot])
@@ -2698,6 +2809,7 @@ class ServingEngine:
                     emitted += 1
                     if req.done:
                         break  # budget/eos hit mid-run: drop the rest
+                req._dispatched = len(req.tokens)  # read as soon as dispatched
             self._step_samples.append((wall, emitted, 1))
             if self.telemetry is not None:
                 self.telemetry.on_step(self, wall, tokens=emitted, steps=1)
@@ -2710,15 +2822,21 @@ class ServingEngine:
 
     def _grow_or_resolve(self, req: Request, slot: int, lo: int, hi: int) -> bool:
         """Grow a live slot's pages for the next write range, resolving
-        page pressure by preempting a strictly-lower-priority victim (its
-        pages move here) or, when none qualifies, shedding ``req`` itself
-        — the one request outgrowing capacity pays, the loop never
-        raises. True when the slot is still live and writable."""
+        page pressure by reading what is in flight (its tokens may end
+        requests, this one too, and free their pages), then by preempting
+        a strictly-lower-priority victim (its pages move here) or, when
+        none qualifies, shedding ``req`` itself — the one request
+        outgrowing capacity pays, the loop never raises. True when the
+        slot is still live and writable."""
         while True:
             try:
                 self._ensure_writable(req, slot, lo, hi)
                 return True
             except PagePressure:
+                if self._settle():
+                    if req.done:
+                        return False
+                    continue
                 if self._relieve_pressure(req, slot):
                     continue
                 req.shed_reason = SHED_PAGE_EXHAUSTED
@@ -2730,10 +2848,17 @@ class ServingEngine:
                 return False
 
     def _decode_once(self) -> bool:
-        if not self._slot_req:
-            return False
+        """Enqueue the next decode step over the active slots, then read the
+        tokens of the step before it: the device runs one while the host
+        emits the other. Where there is nothing to enqueue, what is in
+        flight is read with nothing behind it."""
         if self.spec_k:
-            return self._spec_verify_once()
+            # page growth follows the fetched acceptance counts, and the
+            # drafter reads the tokens: nothing stays in flight (depth 0)
+            self._settle()
+            return self._spec_verify_once() if self._slot_req else False
+        if not self._active.any():
+            return self._settle_step()
         k = self._burst_len()
         with _span("serving/decode_grow") as sp:
             pages0, released0 = self.pages_allocated, self.pages_released
@@ -2743,8 +2868,10 @@ class ServingEngine:
             # stays counted)
             walked = []
             for slot, req in list(self._slot_req.items()):
-                if slot not in self._slot_req:
-                    continue  # shed/preempted while relieving another slot
+                if self._slot_req.get(slot) is not req or not self._active[slot]:
+                    # shed/preempted while relieving another slot, or its
+                    # whole budget is dispatched: it waits for its last token
+                    continue
                 pos = self._next_write_pos(req)
                 self._release_behind_window(req, slot, pos)
                 if self._grow_or_resolve(req, slot, pos, pos + k - 1):
@@ -2753,46 +2880,94 @@ class ServingEngine:
             self._note_walk(sp, walked)
             if self._by_kind:
                 sp.args["pages_released"] = self.pages_released - released0
-        if not self._slot_req:
-            return True  # every live slot was shed under page pressure
+            roster = [(slot, req) for slot, req in self._slot_req.items() if self._active[slot]]
+        if not roster:
+            # every live slot was shed under page pressure, or ended by
+            # the tokens that pressure made the engine read
+            self._settle_step()
+            return True
         if self._faults is not None:
             self._faults.before_decode(self)
-        self._note_forensics(
-            "decode_step" if k == 1 else f"decode_burst{k}",
-            {"tokens": self._tokens, "lengths": self._lengths,
-             "active": self._active, "rngs": self._rngs},
-        )
         load = ()
-        with _span("serving/decode_dispatch", slots=len(self._slot_req),
+        with _span("serving/decode_dispatch", slots=len(roster),
                    arena_in_place=int(self._arena_in_place),
                    # the live slots' states advance one token each (an idle
                    # slot's state is copied in and out unchanged: not counted)
-                   **({"ssm_slots": len(self._slot_req)} if self._state_kind else {})) as sp_d:
+                   **({"ssm_slots": len(roster)} if self._state_kind else {})) as sp_d:
+            self._note_forensics(
+                "decode_step" if k == 1 else f"decode_burst{k}",
+                {"tokens": self._tokens, "lengths": self._lengths,
+                 "active": self._active, "rngs": self._rngs},
+            )
+            # the mask the program reads is its own copy: the engine changes
+            # its own below, before the step has run
+            active = self._active.copy()
             if k > 1:
                 self._arena, self._tokens, self._lengths, self._rngs, toks = (
                     self._decode_burst(k)(
                         self.params, self._arena, self._tokens, self._lengths,
-                        self._active, self._rngs, self._tables_arg(),
+                        active, self._rngs, self._tables_arg(),
                     )
                 )
             else:
                 self._arena, self._tokens, self._lengths, self._rngs, *load = self._decode_step(
-                    self.params, self._arena, self._tokens, self._lengths, self._active,
+                    self.params, self._arena, self._tokens, self._lengths, active,
                     self._rngs, self._tables_arg(),
                 )
                 toks = self._tokens
-        with _span("serving/token_fetch") as sp_f:
-            host, *load = jax.device_get((toks, *load))  # forces the step or burst; one fetch
+            for slot, req in roster:
+                req._dispatched += k
+                if req._dispatched >= req.max_new_tokens:
+                    # its budget is on the device: it rides no further step (no
+                    # wasted row), and keeps slot and pages until the host has
+                    # read and emitted its last token
+                    self._active[slot] = False
+        before, self._flight = self._flight, _StepFlight(toks, tuple(load), k, roster, sp_d)
+        if before is not None:
+            self._read_step(before, in_flight=1)
+        return True
+
+    def _settle_step(self) -> bool:
+        """Read the decode step in flight, if any, with nothing behind it."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return False
+        self._read_step(flight, in_flight=0)
+        return True
+
+    def _settle(self) -> bool:
+        """Read everything in flight now, oldest first: the serial order,
+        for whoever must know the tokens before acting (preemption, which
+        saves a slot's chain; page pressure; ``drain()``; a fault script;
+        speculative verify). Returns whether anything was read."""
+        step = self._settle_step()
+        return self._read_packs(0) or step
+
+    def _read_step(self, flight: "_StepFlight", in_flight: int):
+        """Fetch a decode step's (or burst's) tokens and emit them to the
+        requests that rode it. ``in_flight``: the decode dispatches enqueued
+        behind it when the host began to wait (1 overlapped, 0 settled)."""
+        k, roster, sp_d = flight.k, flight.roster, flight.span
+        with _span("serving/token_fetch", in_flight=in_flight) as sp_f:
+            host, *load = jax.device_get((flight.toks, *flight.load))  # forces the step or burst; one fetch
             host = np.asarray(host)
             if k == 1:
                 host = host[None]  # [1, N]
         if load:
-            _load_args(sp_d, np.asarray(load[0]), len(self._slot_req) * self._pairs_per_token)
-        t0, wall = sp_d.t0, sp_f.t1 - sp_d.t0
+            # the step's own span, one iteration after it closed
+            _load_args(sp_d, np.asarray(load[0]), len(roster) * self._pairs_per_token)
+        # the device took the step up when it had finished what lay before it
+        t0 = max(sp_d.t0, self._last_result_t)
+        wall = sp_f.t1 - t0
+        self._last_result_t = sp_f.t1
         with _span("serving/emit") as sp_e:
             done0 = self.requests_completed
             self.step_count += k
-            self._usage_note_step(wall)
+            self._usage_note_step(wall, roster)
+            # rows computed for nothing: the request's eos was still in flight
+            # when this step was enqueued (the write landed in its own page)
+            discarded = k * sum(1 for _, req in roster
+                                if req.done and req.finish_reason == "eos")
             emitted = 0
             for i in range(k):
                 # a fused burst delivers k tokens in one host RTT; amortize the
@@ -2801,9 +2976,15 @@ class ServingEngine:
                 # (the gaps feeding both the engine deque and the serving/itl
                 # SLO histogram — and through it the p99 profiler trigger)
                 ts = t0 + wall * (i + 1) / k
-                for slot, req in list(self._slot_req.items()):
+                for slot, req in roster:
+                    if req.done:
+                        # ended since the dispatch: by that late eos, by an
+                        # eos earlier in this burst, or cancelled, timed out
+                        # or shed with this token in flight (it is dropped)
+                        continue
                     self._emit(req, int(host[i, slot]), ts)
                     emitted += 1
+            self.rows_discarded += discarded
             # count DELIVERED tokens, not n_active*k: an eos finish mid-burst
             # drops its slot's remaining burst tokens, and tokens/s must not
             # claim them
@@ -2818,19 +2999,17 @@ class ServingEngine:
                     # of splitting into an uncaptured decode_burst<k> row
                     costs.note_wall("decode_step", wall, calls=k)
             sp_e.args["emitted"] = emitted
+            sp_e.args["discarded"] = discarded
             sp_e.args["finished"] = self.requests_completed - done0
-        return True
 
-    def _usage_note_step(self, wall_s: float):
+    def _usage_note_step(self, wall_s: float, roster):
         """Attribute one batched decode/verify dispatch's wall across the
-        live slots' tenants, evenly — called BEFORE emission (finished
-        requests leave ``_slot_req`` during ``_emit``, but they rode this
-        dispatch)."""
+        tenants of the requests that rode it, evenly."""
         usage = self._usage()
-        if usage is None or not self._slot_req:
+        if usage is None or not roster:
             return
-        share = wall_s * 1e3 / len(self._slot_req)
-        for req in self._slot_req.values():
+        share = wall_s * 1e3 / len(roster)
+        for _, req in roster:
             usage.note_compute(req.tenant, share)
 
     def _emit(self, req: Request, token: int, now: float):
@@ -2977,6 +3156,10 @@ class ServingEngine:
         out["serving/pages_total"] = self.num_pages
         out["serving/page_size"] = self.page_size
         out["serving/page_forks"] = self.page_forks
+        # dispatches enqueued whose results the host has not read: 1 between
+        # the iterations of an engine that overlaps, 0 idle or settled
+        out["serving/dispatch_depth"] = int(self._flight is not None) + len(self._flight_packs)
+        out["serving/rows_discarded"] = self.rows_discarded
         out["serving/decode_kernel_active"] = bool(self._kernel_costed)
         out["serving/arena_in_place"] = int(self._arena_in_place)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
@@ -3091,6 +3274,14 @@ class ServingEngine:
         )
 
 
+def _row_upload(row):
+    """A slot's host page-table row for a table program, as its own copy: an
+    upload may alias the host's buffer or read it later than the call (the
+    CPU backend does the first for an aligned array), and the engine writes
+    the row again while earlier dispatches are still in flight."""
+    return jnp.asarray(row.copy())
+
+
 def _expert_load(mutated) -> tuple:
     """``(pairs [expert layers, held experts],)`` from what the expert
     layers wrote to their load collection, in layer order; ``()`` for a
@@ -3113,13 +3304,51 @@ def _load_args(sp, load, pairs_all: int) -> None:
     sp.args["experts_idle"] = int((load == 0).sum())
 
 
-def _admit_state_fn(tokens, lengths, rngs, slot, first, length, rng):
-    """Scatter one slot's go-live state (traced ``slot``: one compile total,
-    not one per slot index)."""
+class _StepFlight(NamedTuple):
+    """A decode step (or burst) enqueued and not read: its tokens and
+    expert load on the device, the requests that rode it by slot, and its
+    ``serving/decode_dispatch`` span."""
+
+    toks: jax.Array
+    load: tuple
+    k: int
+    roster: list
+    span: object
+
+
+class _PackFlight(NamedTuple):
+    """A pack enqueued and not read: its first tokens and expert load on
+    the device, ``(request, slot, s0, s1, emits a first token)`` for each
+    request it carried, and its ``serving/prefill_dispatch`` span."""
+
+    firsts: jax.Array
+    load: tuple
+    rows: list
+    rcap: int
+    fresh: int
+    span: object
+
+
+def _stage_keys_fn(prefill_keys, decode_keys, slot, rng):
+    """One request's two keys into its slot's rows (traced ``slot``: one
+    compile): ``jax.random.split`` as the single-stream loop splits."""
+    prefill_rng, decode_rng = jax.random.split(rng)
+    return prefill_keys.at[slot].set(prefill_rng), decode_keys.at[slot].set(decode_rng)
+
+
+def _admit_state_fn(tokens, lengths, rngs, firsts, decode_keys, meta):
+    """One pack's slots go live, without a token crossing the host. ``meta``
+    is ``[slots, 5]`` int32 from the host: whether the slot goes live, the
+    token a resumed request continues from (-1: a fresh one takes the pack
+    program's ``firsts``), its length, and a resume's saved key chain as two
+    words (a fresh request's chain starts from its staged decode key)."""
+    live, last, length = meta[:, 0] > 0, meta[:, 1], meta[:, 2]
+    resumed = last >= 0
+    saved = jax.lax.bitcast_convert_type(meta[:, 3:], jnp.uint32)
     return (
-        tokens.at[slot].set(first),
-        lengths.at[slot].set(length),
-        rngs.at[slot].set(rng),
+        jnp.where(live, jnp.where(resumed, last, firsts), tokens),
+        jnp.where(live, length, lengths),
+        jnp.where(live[:, None], jnp.where(resumed[:, None], saved, decode_keys), rngs),
     )
 
 

@@ -177,7 +177,13 @@ class _GhostShadow:
     semantics exactly (longest-first probe, recency on committed hits and
     insert-touch, evict min ``last_used`` past capacity), so its hit count
     equals a brute-force ``PrefixCache(max_entries=N*base)`` replaying the
-    same trace — the oracle tests/test_loadgen.py asserts against."""
+    same trace — the oracle tests/test_loadgen.py asserts against.
+
+    Every touch takes a new, larger tick and moves the entry to the end of
+    the dict, so the dict's first key is the one of least ``last_used``:
+    an eviction costs the same at any capacity. (A scan for the minimum was
+    three quarters of an admission's host time with the cache full: 100,000
+    calls a 512-token prompt over the 2x/4x/10x shadows; PERF.md, PR 37.)"""
 
     __slots__ = ("max_entries", "entries", "_clock", "hits")
 
@@ -199,23 +205,28 @@ class _GhostShadow:
                              reverse=True):
             if length > n:
                 continue
-            e = self.entries.get(dig(length))
+            key = dig(length)
+            e = self.entries.get(key)
             if e is not None and e[0] == length:
                 self.hits += 1
-                e[1] = self._tick()
+                self._touch(key, e)
                 return length
         return 0
+
+    def _touch(self, key, e):
+        e[1] = self._tick()
+        del self.entries[key]
+        self.entries[key] = e  # the newest tick goes last
 
     def insert(self, keyed_lengths):
         for length, key in keyed_lengths:
             e = self.entries.get(key)
             if e is not None:
-                e[1] = self._tick()
+                self._touch(key, e)
                 continue
             self.entries[key] = [length, self._tick()]
         while len(self.entries) > self.max_entries:
-            victim = min(self.entries, key=lambda k: self.entries[k][1])
-            del self.entries[victim]
+            del self.entries[next(iter(self.entries))]  # least last_used: see the class
 
 
 class GhostCache:

@@ -49,13 +49,17 @@ from typing import Optional
 # only decodes: serving/step and its six phases; when it admits, three more
 # around the prefill dispatch, a serving/prefill_chunk a packed request,
 # and serving/queue_wait and serving/first_token a request;
-# tests/test_engine_spans.py counts them), so 65,536 hold 6,500
-# iterations: the 800 warm-in iterations of the longest mix and a 51 s
-# window at 8.9 ms an iteration, less than the 9.2 ms a decode step takes
-# to read a 7B model's 16 layers of weights once. 16,384 wrapped inside a
-# run as soon as an iteration fell from 66 to 55 ms (PERF.md, PR 33). No
-# knob: a deque's append costs the same at any length, and 65,536 tuples
-# with their args are tens of MB of host memory at the most.
+# tests/test_engine_spans.py counts them; one dispatch in flight, PR 37,
+# changed their order and not their number), so 65,536 hold 6,500
+# iterations. The longest run is the state-space cell's: 1,100 warm-in
+# iterations and a 51 s window at the 26 ms a decode-only iteration takes
+# with the host's work hidden, 3,100 in all; the ring would hold the 800
+# warm-in iterations of MiMo's mix and 51 s at 8.9 ms an iteration, less
+# than the 9.2 ms a decode step takes to read a 7B model's 16 layers of
+# weights once. 16,384 wrapped inside a run as soon as an iteration fell
+# from 66 to 55 ms (PERF.md, PR 33). No knob: a deque's append costs the
+# same at any length, and 65,536 tuples with their args are tens of MB of
+# host memory at the most.
 RING_SPANS = 65536
 
 _RECORDER: Optional["SpanRecorder"] = None
