@@ -195,6 +195,26 @@ class DecoderConfig:
     ssm_inner_norms: bool = True
     ssm_conv_bias: bool = True
     ssm_kernel: Optional[str] = None
+    # -- EVA attention (ops/eva.py; "Efficient Attention via Control
+    # Variates", arXiv:2302.04542, causal and chunked). ``eva_window``: a
+    # query sees the positions of its own window of this many exactly, and
+    # every window before it as one pooled key and value a chunk of
+    # ``eva_chunk`` positions, pooled under two learned vectors a kv head
+    # (``eva_mu``, ``eva_phi``), all under one softmax. In the serving cache
+    # its pages fall away when a window closes and the table gains the
+    # window's pages of summaries (cache kind "closing<window>"); a page is a
+    # chunk there. None: plain attention.
+    eva_window: Optional[int] = None
+    eva_chunk: Optional[int] = None
+    # ``norm_unit_offset``: every RMS norm scales by ``1 + weight`` (weights
+    # stored around 0). ``num_pred_heads``: the untied head has
+    # ``vocab_size x num_pred_heads`` rows, block j the prediction of token
+    # t + 1 + j; the logits returned are block 0's (the product is taken over
+    # all rows). ``fp32_logits``: the head's product leaves the unit in
+    # float32 and is not rounded to ``dtype`` on the way.
+    norm_unit_offset: bool = False
+    num_pred_heads: int = 1
+    fp32_logits: bool = False
 
     def __post_init__(self):
         if self.num_kv_heads is None:
@@ -307,6 +327,24 @@ class DecoderConfig:
                 "and ssm_conv_width >= 2")
         if self.attn_window is not None and self.attn_window < 1:
             raise ValueError(f"attn_window must be >= 1, got {self.attn_window}")
+        if (self.eva_window is None) != (self.eva_chunk is None):
+            raise ValueError("eva_window and eva_chunk must be set together")
+        if self.eva_window is not None:
+            w, c = self.eva_window, self.eva_chunk
+            if c < 1 or w % (c * c):
+                raise ValueError(
+                    f"eva_window ({w}) must be a multiple of eva_chunk squared ({c}): a page "
+                    "is a chunk, and a window's summaries fill whole pages")
+            if self.attn_window is not None or self.attn_sink or self.mixer != "attention":
+                raise ValueError("EVA attention takes no sliding window and no sink")
+            if self.kv_page_size is not None and self.kv_page_size != c:
+                raise ValueError(
+                    f"EVA attention pools a filled page into one entry: kv_page_size "
+                    f"({self.kv_page_size}) must equal eva_chunk ({c})")
+            if self.kv_cache_dtype != "bf16":
+                raise NotImplementedError("EVA attention pools unquantized pages only")
+        if self.num_pred_heads < 1 or (self.num_pred_heads > 1 and self.tie_embeddings):
+            raise ValueError("num_pred_heads > 1 needs an untied head (tie_embeddings=False)")
         self.layer_kinds = tuple((str(n), dict(o)) for n, o in self.layer_kinds)
         self.layer_pattern = tuple(int(i) for i in self.layer_pattern)
         if bool(self.layer_kinds) != bool(self.layer_pattern):
@@ -385,6 +423,8 @@ class DecoderConfig:
         of a fixed size a slot and not paged."""
         if self.mixer == "ssm":
             return "state"
+        if self.eva_window is not None:
+            return f"closing{self.eva_window}"
         return "full" if self.attn_window is None else f"window{self.attn_window}"
 
     def _layer_params(self, active: bool = False) -> int:
@@ -398,7 +438,8 @@ class DecoderConfig:
                 + d * (r + 2 * n) + (r + 2 * n if self.ssm_inner_norms else 0) + r * d + d + d * n + d
         else:
             attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
-                + h * self.value_dim * e + (h if self.attn_sink else 0)
+                + h * self.value_dim * e + (h if self.attn_sink else 0) \
+                + (2 * kv * self.head_dim if self.eva_window is not None else 0)
         if self.moe_num_experts > 1:
             # per-expert gate/up/down + the router (and its selection bias)
             outputs = self.moe_router_outputs or self.moe_num_experts
@@ -411,7 +452,7 @@ class DecoderConfig:
 
     def _count_params(self, active: bool) -> int:
         layers = sum(c.num_layers * c._layer_params(active) for c in self.run_configs())
-        head = 0 if self.tie_embeddings else self.embed_dim * self.vocab_size
+        head = 0 if self.tie_embeddings else self.embed_dim * self.vocab_size * self.num_pred_heads
         return layers + self.vocab_size * self.embed_dim + head + self.embed_dim  # + final norm
 
     @property

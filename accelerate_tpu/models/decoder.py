@@ -47,6 +47,15 @@ def _rotary_tables(positions, cfg, dtype):
     return rotary_embedding_tables(positions, cfg.rotary_dim, theta=cfg.rope_theta, dtype=dtype)
 
 
+def _norm(x, weight, cfg):
+    """The config's RMS norm (``norm_unit_offset``: scale ``1 + weight``)."""
+    return rms_norm(x, weight, cfg.norm_eps, unit_offset=cfg.norm_unit_offset)
+
+
+def _norm_init(cfg):
+    return nn.initializers.zeros if cfg.norm_unit_offset else nn.initializers.ones
+
+
 def _dense_init(scale: float = 1.0):
     return nn.initializers.variance_scaling(scale, "fan_in", "normal")
 
@@ -83,9 +92,11 @@ def _head_ce_loss(x, ln_f, embedding, lm_head, labels, cfg, mesh, weight=None):
     tokens. ``weight`` rescales the mean (the 1f1b schedule passes each
     microbatch's valid-token share so the sum over microbatches equals the
     GLOBAL token mean even with uneven -100 padding)."""
-    x = rms_norm(x, ln_f, cfg.norm_eps)
+    x = _norm(x, ln_f, cfg)
     x = _constrain(x, ("batch", "seq", "embed"), mesh)
     vocab_kernel = _tied_vocab_kernel(embedding, lm_head, cfg)
+    if cfg.num_pred_heads > 1:  # trained on the next token: block 0
+        vocab_kernel = vocab_kernel[:, :cfg.vocab_size]
     b, s = x.shape[0], x.shape[1]
     hidden = x[:, :-1].reshape(b * (s - 1), cfg.embed_dim)
     targets = labels[:, 1:].reshape(b * (s - 1))
@@ -245,6 +256,21 @@ class DecoderAttention(nn.Module):
         if window is not None or sink is not None or value_scale != 1.0:
             extras = {"window": window, "sink": sink, "value_scale": value_scale}
         plain = not extras and dv == d
+        # EVA attention (ops/eva.py): the cache's positions and lengths are
+        # counted in entries, [summaries of closed windows][open window]
+        eva = getattr(cfg, "eva_window", None) is not None
+        if eva:
+            from ..ops.eva import entry_index
+
+            ew, ec = cfg.eva_window, cfg.eva_chunk
+            mu = self.param(
+                "eva_mu", nn.with_logical_partitioning(nn.initializers.normal(1.0), ("kv_heads", "head_dim")),
+                (kv, d), jnp.float32)
+            phi = self.param(
+                "eva_phi", nn.with_logical_partitioning(nn.initializers.normal(1.0), ("kv_heads", "head_dim")),
+                (kv, d), jnp.float32)
+            entries = lambda pos: entry_index(pos, ew, ec)
+            plain = False
 
         dt = cfg.dtype
         if getattr(cfg, "use_fp8", False):
@@ -332,6 +358,11 @@ class DecoderAttention(nn.Module):
                     "KV arena (config.kv_page_size with a page_table); a "
                     "dense cache decodes one stream at its scalar cache_index"
                 )
+            if eva and (not paged or (self.decode and cache_positions is not None
+                                      and ragged_slots is None and s != 1)):
+                raise NotImplementedError(
+                    "EVA attention keeps its cache in pages (config.kv_page_size): one new "
+                    "token a slot in a decode step, or the packed ragged prefill")
             if not self.decode:
                 # prefill: cache starts at 0, so plain causal attention over
                 # the freshly computed K/V stays on the flash-kernel path.
@@ -387,6 +418,11 @@ class DecoderAttention(nn.Module):
                     cache_positions[0]
                     if cache_positions.ndim == 2 else cache_positions
                 )
+                true_pos = row_pos
+                if eva:
+                    # a slot's rows of one pack lie in one window (the
+                    # engine's plan), so their entries are consecutive too
+                    row_pos, slot_hist = entries(row_pos), entries(slot_hist)
                 scale_kw = {}
                 if kvq_bits:
                     scale_kw = {"k_scale": cached_ks.value,
@@ -415,6 +451,18 @@ class DecoderAttention(nn.Module):
                 if kvq_bits:
                     cached_ks.value = cached_ks.value.at[page, :, off].set(k_scl)
                     cached_vs.value = cached_vs.value.at[page, :, off].set(v_scl)
+                if eva:
+                    # the pages this pack has filled, each pooled into its
+                    # entry of the open window's summaries: a gather, the
+                    # pooling and a second scatter of XLA's on the layer's
+                    # pages (the aliased kernel here costs a copy of them,
+                    # which the scatters do not: PERF.md, PR 38)
+                    from ..ops.eva import eva_pool_reference, pool_plan
+
+                    plan = pool_plan(true_pos, valid, ragged_slots, page_table,
+                                     window=ew, chunk=ec, size=max(1, s // ec))
+                    cached_k.value, cached_v.value = eva_pool_reference(
+                        cached_k.value, cached_v.value, mu, phi, *plan, d ** -0.5)
             elif cache_positions is not None:
                 # slot-arena decode (serving/): every batch row writes its
                 # new K/V at its own per-slot offset(s) and attends only
@@ -432,6 +480,10 @@ class DecoderAttention(nn.Module):
                         f"cache_positions covers {pos2d.shape[1]} positions "
                         f"per slot but {s} tokens were fed"
                     )
+                if eva:
+                    true_pos, pos2d = pos2d[:, 0], entries(pos2d)
+                    if kv_lengths is not None:
+                        kv_lengths = jnp.where(kv_lengths > 0, entries(kv_lengths - 1) + 1, 0)
                 rows = jnp.arange(b)
                 kv_new = jnp.swapaxes(k, 1, 2)  # [B, S, KVH, D]
                 vv_new = jnp.swapaxes(v, 1, 2)
@@ -482,6 +534,21 @@ class DecoderAttention(nn.Module):
                         page_table=page_table, q_positions=pos2d,
                         kv_lengths=kv_lengths, impl=dk_impl, **scale_kw, **extras,
                     )
+                if eva:
+                    # a slot whose new row filled its page: the page pooled
+                    # into its entry of the open window's summaries (an idle
+                    # slot, parked at the cache's last position, pools nothing)
+                    from ..ops.eva import eva_pool_pages, eva_pool_reference, pool_plan
+
+                    live = jnp.ones((b,), bool) if kv_lengths is None else kv_lengths > 0
+                    plan = pool_plan(true_pos, live, rows, page_table, window=ew, chunk=ec, size=b)
+                    if cache_layer is not None:  # the arena in place: the kernel, as the row's write
+                        cached_k.value, cached_v.value = eva_pool_pages(
+                            cached_k.value, cached_v.value, mu, phi, *plan, sm_scale=d ** -0.5,
+                            layer=cache_layer, interpret=dk_impl == "interpret")
+                    else:
+                        cached_k.value, cached_v.value = eva_pool_reference(
+                            cached_k.value, cached_v.value, mu, phi, *plan, d ** -0.5)
             else:
                 scale_kw = {}
                 if kvq_bits:
@@ -513,6 +580,13 @@ class DecoderAttention(nn.Module):
                     block_kv=getattr(cfg, "decode_kernel_block", None),
                     **scale_kw, **extras,
                 )
+        elif eva:
+            # a forward pass over a whole sequence, no cache (ops/eva.py)
+            if not self.causal or kv_mask is not None:
+                raise NotImplementedError("EVA attention is causal and takes no key mask")
+            from ..ops.eva import eva_attention
+
+            out = eva_attention(q, k, v, mu, phi, window=ew, chunk=ec)
         elif not plain:
             # a layer kind the flash kernel has no form of: the plain
             # reference (forward passes of such a model; it is not trained)
@@ -605,11 +679,11 @@ class DecoderBlock(nn.Module):
                  page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None,
                  token_mask=None, cache_layer=None):
         cfg = self.config
-        ln1 = self.param("ln_attn", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
-        ln2 = self.param("ln_mlp", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
+        ln1 = self.param("ln_attn", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
+        ln2 = self.param("ln_mlp", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
         # the stream may be carried wider than the activations
         # (config.residual_dtype); the layers' inputs are cfg.dtype either way
-        y = rms_norm(x, ln1, cfg.norm_eps).astype(cfg.dtype)
+        y = _norm(x, ln1, cfg).astype(cfg.dtype)
         if getattr(cfg, "mixer", "attention") == "ssm":
             from .ssm import SelectiveSSM
 
@@ -625,7 +699,7 @@ class DecoderBlock(nn.Module):
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
         x = x + y.astype(x.dtype)
-        y_stream = rms_norm(x, ln2, cfg.norm_eps)  # in the stream's dtype
+        y_stream = _norm(x, ln2, cfg)  # in the stream's dtype
         y = y_stream.astype(cfg.dtype)
         if cfg.moe_num_experts > 1:
             from .moe import MoeMLP
@@ -887,13 +961,13 @@ class DecoderLM(nn.Module):
                 )
                 moe_aux = moe_aux + block_aux
 
-        ln_f = self.param("ln_final", nn.with_logical_partitioning(nn.initializers.ones, ("norm",)), (cfg.embed_dim,))
+        ln_f = self.param("ln_final", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
         lm_head = None
         if not cfg.tie_embeddings:
             lm_head = self.param(
                 "lm_head",
                 nn.with_logical_partitioning(_dense_init(), ("embed", "vocab")),
-                (cfg.embed_dim, cfg.vocab_size),
+                (cfg.embed_dim, cfg.vocab_size * cfg.num_pred_heads),
             )
 
         if labels is not None:
@@ -902,9 +976,17 @@ class DecoderLM(nn.Module):
                 aux = cfg.moe_aux_loss_weight * moe_aux / cfg.num_layers
                 return {"loss": loss + aux, "lm_loss": loss, "aux_loss": aux}
             return {"loss": loss}
-        x = rms_norm(x, ln_f, cfg.norm_eps).astype(cfg.dtype)
+        x = _norm(x, ln_f, cfg).astype(cfg.dtype)
         vocab_kernel = _tied_vocab_kernel(embedding, lm_head, cfg)
-        out = {"logits": _constrain((x @ vocab_kernel).astype(jnp.float32), ("batch", "seq", "vocab"), self.mesh)}
+        if cfg.fp32_logits:
+            # the product leaves the unit in float32, not rounded to cfg.dtype
+            logits = jnp.matmul(x, vocab_kernel, preferred_element_type=jnp.float32)
+        else:
+            logits = (x @ vocab_kernel).astype(jnp.float32)
+        if cfg.num_pred_heads > 1:
+            # every block of rows is multiplied; the next token's is block 0
+            logits = logits[..., :cfg.vocab_size]
+        out = {"logits": _constrain(logits, ("batch", "seq", "vocab"), self.mesh)}
         if cfg.moe_num_experts > 1:
             out["aux_loss"] = cfg.moe_aux_loss_weight * moe_aux / cfg.num_layers
         return out

@@ -13,13 +13,18 @@ import jax
 import jax.numpy as jnp
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
-    """RMSNorm with fp32 internal math, output in x.dtype."""
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
+             unit_offset: bool = False) -> jax.Array:
+    """RMSNorm with fp32 internal math, output in x.dtype. ``unit_offset``:
+    the scale is ``1 + weight`` (weights stored around 0)."""
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     y = x32 * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(jnp.float32)).astype(dtype)
+    scale = weight.astype(jnp.float32)
+    if unit_offset:
+        scale = 1.0 + scale
+    return (y * scale).astype(dtype)
 
 
 def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:

@@ -325,14 +325,19 @@ class ServingEngine:
         # cached prefix would need its snapshot at the page boundary, a
         # page-out its copy, a rejected draft its rollback
         has_state = any(getattr(c, "mixer", "attention") == "ssm" for c in run_cfgs)
+        # a closing window with pooled summaries (EVA attention): a shared
+        # prefix would have to end at a window's close, a page-out needs the
+        # open window's summaries, a rejected draft may have pooled a page
+        closing = [c for c in run_cfgs if getattr(c, "eva_window", None) is not None]
+        self._closing = tuple(sorted({c.eva_window for c in closing}))  # the windows that close
         self._by_kind = bool(
             getattr(mcfg, "layer_kinds", ()) or getattr(mcfg, "attn_window", None)
             or getattr(mcfg, "attn_sink", False) or getattr(mcfg, "moe_num_experts", 0) > 1
-            or has_state)
+            or has_state or closing)
         if self._by_kind:
             refused = {
-                "prefix_cache (page sharing across a window kind, or without a state's "
-                "snapshot at the page boundary)": bool(prefix_cache),
+                "prefix_cache (page sharing across a window kind, past a closing window, "
+                "or without a state's snapshot at the page boundary)": bool(prefix_cache),
                 "kv_tiers": kv_tiers is not None,
                 "preemption by page-out and restore (scheduler.config.preemption)": (
                     scheduler is not None
@@ -345,8 +350,9 @@ class ServingEngine:
                 if asked:
                     raise NotImplementedError(
                         f"ServingEngine: {feature} is not supported for a model with "
-                        "layer kinds, a window, a sink, experts or a recurrent state; "
-                        "it is refused rather than run and be wrong (ROADMAP.md, Reach)")
+                        "layer kinds, a window, a closing window, a sink, experts or a "
+                        "recurrent state; it is refused rather than run and be wrong "
+                        "(ROADMAP.md, Reach)")
         elif kind_pages:
             raise ValueError("kind_pages names pools of cache kinds; this model has one kind")
         # the programs of a model with experts return, with the tokens, the
@@ -375,6 +381,14 @@ class ServingEngine:
             getattr(definition.config, "prefill_kernel_block", None)
             or prefill_token_block(self.prefill_chunks)
         )
+        for c in closing:
+            # a slot's rows of one pack end at a window's close, in whole
+            # token blocks of whole chunks (the pages the pack fills)
+            if c.eva_chunk != self.page_size or self._ragged_bt % c.eva_chunk or c.eva_window % self._ragged_bt:
+                raise ValueError(
+                    f"ServingEngine: a closing window of {c.eva_window} in chunks of {c.eva_chunk} "
+                    f"needs page_size {c.eva_chunk} and a prefill token block ({self._ragged_bt}) "
+                    "that is a multiple of the chunk and divides the window")
         self._paged_def = definition.clone(config=self._paged_config(
             definition.config, kind_pages or {}))
         # which state each layer kind keeps and how it is paged
@@ -574,7 +588,9 @@ class ServingEngine:
         # metrics
         self.iterations = 0  # scheduler iterations (calls of step() that had work)
         self.pages_allocated = 0  # pages handed out by _alloc_page, lifetime
-        self.pages_released = 0   # pages given back behind a window, lifetime
+        self.pages_released = 0   # pages given back behind a window or at a close, lifetime
+        self.windows_closed = 0   # windows of a closing kind closed, lifetime
+        self.pages_pooled = 0     # pages a dispatch filled and pooled into a summary, lifetime
         self.step_count = 0
         self.requests_completed = 0
         self.requests_shed = 0
@@ -667,9 +683,11 @@ class ServingEngine:
                 token_bytes = kv_token_bytes(c.num_kv_heads, c.head_dim, self.kv_cache_dtype)
             kind = kinds.get(c.cache_kind)
             if kind is None:
+                closes = None if c.eva_window is None else (c.eva_window, c.eva_chunk)
                 kinds[c.cache_kind] = CacheKind(
                     c.cache_kind, c.attn_window, c.kv_num_pages, self.num_slots,
-                    self.pages_per_slot, self.page_size, c.num_layers, token_bytes)
+                    self.pages_per_slot, self.page_size, c.num_layers, token_bytes,
+                    closes=closes)
             else:
                 if (kind.num_pages, kind.token_bytes) != (c.kv_num_pages, token_bytes):
                     raise ValueError(
@@ -1274,6 +1292,7 @@ class ServingEngine:
             return False
         with _span("serving/step") as sp:
             emitted0 = self.generated_tokens
+            closed0, pooled0 = self.windows_closed, self.pages_pooled
             progressed = self._step_phases()
             self.iterations += 1
             args = sp.args
@@ -1291,6 +1310,15 @@ class ServingEngine:
                 # counted as dispatched, like the pages held for them
                 args["live_tokens"] = sum(
                     r.prompt.size + r._dispatched for r in self._slot_req.values())
+            if self._closing:
+                # entries the live slots hold for those positions (a closed
+                # window stands as one entry a chunk), and this iteration's
+                # closes and pooled pages, packs and decode step together
+                kind = self._kinds[0]
+                args["entries_held"] = sum(
+                    kind.entries(r.prompt.size + r._dispatched) for r in self._slot_req.values())
+                args["windows_closed"] = self.windows_closed - closed0
+                args["pages_pooled"] = self.pages_pooled - pooled0
             if self._state_kind is not None:
                 # a slot's state is held from its admission on, whatever its length
                 args["state_bytes_in_use"] = self._state_kind.slot_bytes * (
@@ -1783,9 +1811,11 @@ class ServingEngine:
         th = self._tables_host
         ps = self.page_size
         usage = self._usage()
-        p_hi = hi_pos // ps
         for kind in self._kinds:
             kt = kind.tables
+            # the table entry of the last position written: its page, but for
+            # a closing kind, whose table is in entry order
+            p_hi = kind.entries(hi_pos) // ps
             if kt.alloc_count[slot] <= p_hi and kt.alloc_count[slot] == kt.released[slot]:
                 # nothing live in this slot's table (a fresh slot of a window
                 # kind whose write starts past its first pages): skip what
@@ -1794,6 +1824,26 @@ class ServingEngine:
                     kt.alloc_count[slot], kind.first_live_entry(lo_pos))
             grown = []
             try:
+                if kt.aside and not kt.aside_held[slot]:
+                    # a closing kind: the pages the open window's summaries
+                    # are pooled into, in the row's last columns (all of
+                    # them or, under page pressure, none)
+                    aside = []
+                    try:
+                        for _ in range(kt.aside):
+                            aside.append(self._alloc_page(kind))
+                    except PagePressure:
+                        for page in aside:
+                            kind.allocator.release(page)
+                        self.pages_allocated -= len(aside)
+                        raise
+                    first = kt.pages_per_slot - kt.aside
+                    kt.rows[slot][first:] = aside
+                    kt.aside_held[slot] = True
+                    grown += [(first + i, page) for i, page in enumerate(aside)]
+                    req.pages_allocated += kt.aside
+                    if usage is not None:
+                        usage.note_pages(req.tenant, kt.aside)
                 while kt.alloc_count[slot] <= p_hi:
                     idx = kt.alloc_count[slot]
                     page = self._alloc_page(kind)
@@ -1814,7 +1864,8 @@ class ServingEngine:
                 elif grown:
                     kind.device_tables = self._set_row(
                         kind.device_tables, slot, _row_upload(kt.rows[slot]))
-        for idx in range(lo_pos // ps, p_hi + 1):
+        first = self._kinds[0]
+        for idx in range(first.entries(lo_pos) // ps, first.entries(hi_pos) // ps + 1):
             page = int(th.rows[slot][idx])
             if not self._allocator.shared(page):
                 continue
@@ -1927,11 +1978,25 @@ class ServingEngine:
 
     def _release_behind_window(self, req: Request, slot: int, next_pos: int) -> int:
         """Give back the pages of every window kind that lie wholly behind
-        the window of the slot's next query at ``next_pos`` (after a prefill
+        the window of the slot's next query at ``next_pos``, and close the
+        windows of a closing kind that lie wholly before it (after a prefill
         dispatch, and each round in ``serving/decode_grow``). Returns the
         pages released."""
         n = sum(kind.release_behind(slot, next_pos)
                 for kind in self._kinds if kind.window is not None)
+        for kind in self._kinds:
+            if kind.closes is None:
+                continue
+            # a closing kind: the table is rewritten at a close, on the host
+            # and (one row program) on the device; the pages given back are
+            # read by nothing enqueued after this
+            closed0 = kind.tables.closed[slot]
+            given = kind.close_windows(slot, next_pos)
+            if given:
+                n += given
+                self.windows_closed += kind.tables.closed[slot] - closed0
+                kind.device_tables = self._set_row(
+                    kind.device_tables, slot, _row_upload(kind.tables.rows[slot]))
         if n:
             self.pages_released += n
             usage = self._usage()
@@ -2474,6 +2539,10 @@ class ServingEngine:
         # so the sentinel is unambiguous)
         cur = plan[0][0] if idx == 0 else idx
         n = min(seq.size - cur, cap_max)
+        # a closing kind: a slot's rows of one pack end at its window's close
+        # (the summaries the rows behind the close read are pooled by this pack)
+        for window in self._closing:
+            n = min(n, window - cur % window)
         if self._faults is not None:
             self._faults.before_prefill(self)
         if not self._admission_writable(req, slot, cur, cur + n - 1):
@@ -2503,6 +2572,8 @@ class ServingEngine:
                     break
                 if used + -(-int(nxt.prompt.size) // bt) * bt > cap_max:
                     break
+                if any(int(nxt.prompt.size) > window for window in self._closing):
+                    break  # a whole tail that crosses a close admits alone, window by window
                 self._queue.popleft()
                 slot2 = self._free.pop()
                 plan2 = self._paged_admit_plan(nxt, slot2, nxt.prompt)
@@ -2561,7 +2632,7 @@ class ServingEngine:
         stamps and emits them, behind that decode dispatch."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
                    requests=len(packs), **self._pages_walked(packs),
-                   **self._state_advanced(packs, fresh)) as sp:
+                   **self._state_advanced(packs, fresh), **self._pages_pooled(packs)) as sp:
             self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
                 self._tables_arg(), last_rows, self._prefill_keys,
@@ -2677,12 +2748,24 @@ class ServingEngine:
         def pages(kind):
             if not self._prefill_kernel_costed:
                 return 0
-            return sum(prefill_walk_pages(s0, pos, ps, kind.window)
+            return sum(prefill_walk_pages(kind.entries(s0), kind.entries(pos), ps, kind.window)
                        for _, _, s0, s1, *_ in packs for pos in range(s0, s1, bt))
 
         first, *others = self._kinds
         return {"pages_walked": pages(first),
                 **{f"pages_walked.{kind.name}": pages(kind) for kind in others}}
+
+    def _pages_pooled(self, packs: list) -> dict:
+        """``pages_pooled`` for the ``serving/prefill_dispatch`` span: the
+        pages of a closing kind this pack fills, each pooled into one entry
+        of its window's summaries inside the program (one layer's count).
+        Nothing where the model has no such kind."""
+        if not self._closing:
+            return {}
+        ps = self.page_size
+        n = sum(s1 // ps - s0 // ps for _, _, s0, s1, *_ in packs)
+        self.pages_pooled += n
+        return {"pages_pooled": n}
 
     def _state_advanced(self, packs: list, rows: int) -> dict:
         """What a pack hands the state-space layers, for the
@@ -2880,6 +2963,11 @@ class ServingEngine:
             self._note_walk(sp, walked)
             if self._by_kind:
                 sp.args["pages_released"] = self.pages_released - released0
+            if self._closing:
+                # the slots whose write fills a page: the step pools it
+                pooled = sum(1 for p in walked if (p + 1) % self.page_size == 0)
+                self.pages_pooled += pooled
+                sp.args["pages_pooled"] = pooled
             roster = [(slot, req) for slot, req in self._slot_req.items() if self._active[slot]]
         if not roster:
             # every live slot was shed under page pressure, or ended by
@@ -3179,6 +3267,9 @@ class ServingEngine:
             out[f"serving/pages_total.{kind.name}"] = kind.num_pages
         if self._by_kind:
             out["serving/pages_released"] = self.pages_released
+        if self._closing:
+            out["serving/windows_closed"] = self.windows_closed
+            out["serving/pages_pooled"] = self.pages_pooled
         out["serving/prefill_packed_tokens"] = int(
             self.prefill_packed_tokens
         )

@@ -542,7 +542,7 @@ class PagedTables:
     allocated-entry count. Entries beyond ``alloc_count`` are parking-page
     padding (gathered but masked, never written by an active slot)."""
 
-    def __init__(self, num_slots: int, pages_per_slot: int, parking: int = 0):
+    def __init__(self, num_slots: int, pages_per_slot: int, parking: int = 0, aside: int = 0):
         self.num_slots = int(num_slots)
         self.pages_per_slot = int(pages_per_slot)
         self.parking = int(parking)
@@ -551,14 +551,25 @@ class PagedTables:
         # entries before ``released`` were given back behind a window: a
         # slot's pages are rows[released:alloc_count] (0 for a full kind)
         self.released = [0] * num_slots
+        # a closing kind (CacheKind.closes): the row's last ``aside`` columns
+        # hold the pages the open window's summaries are pooled into, once
+        # the slot has them (``aside_held``); ``closed`` counts its windows
+        self.aside = int(aside)
+        self.aside_held = [False] * num_slots
+        self.closed = [0] * num_slots
 
     def reset_slot(self, slot: int):
         self.rows[slot] = self.parking
         self.alloc_count[slot] = 0
         self.released[slot] = 0
+        self.aside_held[slot] = False
+        self.closed[slot] = 0
 
     def slot_pages(self, slot: int) -> list:
-        return [int(p) for p in self.rows[slot, self.released[slot]: self.alloc_count[slot]]]
+        pages = self.rows[slot, self.released[slot]: self.alloc_count[slot]]
+        if self.aside_held[slot]:
+            pages = np.concatenate([pages, self.rows[slot, self.pages_per_slot - self.aside:]])
+        return [int(p) for p in pages]
 
 
 class CacheKind:
@@ -577,19 +588,45 @@ class CacheKind:
     A recurrent state ("state": a state-space mixer's, models/ssm.py) is
     **of a fixed size a slot and not paged**: ``num_pages`` 0, no pool, no
     table, ``token_bytes`` 0 and ``slot_bytes`` (all its layers' leaves of
-    one slot), whatever the context's length."""
+    one slot), whatever the context's length.
+
+    ``closes`` ``(window, chunk)``: a **closing window with pooled
+    summaries** (EVA attention, ops/eva.py; a page is a chunk). The table is
+    in *entry order*, ``[summaries of closed windows][tokens of the open
+    window]``: a context of ``L`` positions holds :meth:`entries` ``(L //
+    window) * (window // chunk) + L % window`` entries, not ``L``. While a
+    window is open the device pools each filled page into one entry of the
+    ``window / chunk / page`` pages the slot holds aside (the table's last
+    columns); when it closes, :meth:`close_windows` puts those pages where
+    the window's first token pages stood, gives its ``window / page`` token
+    pages back to the pool and takes fresh pages aside. Everything that
+    reckons a slot's pages from its length asks :meth:`entries`."""
 
     def __init__(self, name: str, window: Optional[int], num_pages: int,
                  num_slots: int, pages_per_slot: int, page_size: int,
-                 layers: int, token_bytes: int, slot_bytes: int = 0):
+                 layers: int, token_bytes: int, slot_bytes: int = 0,
+                 closes: Optional[tuple] = None):
         self.name, self.window = name, window
         self.num_pages, self.page_size = int(num_pages), int(page_size)
         self.layers, self.token_bytes = int(layers), int(token_bytes)
         self.num_slots, self.slot_bytes = int(num_slots), int(slot_bytes)
+        self.closes = None if closes is None else (int(closes[0]), int(closes[1]))
         self.allocator = self.tables = None
+        aside = 0
+        if self.closes is not None:
+            w, c = self.closes
+            aside = w // c // self.page_size
+            # the pages of the most entries any context of the slot holds, and those aside
+            most = max(self.entries(p) for p in range(pages_per_slot * self.page_size))
+            need = most // self.page_size + 1 + aside
+            if c != self.page_size or need > pages_per_slot:
+                raise ValueError(
+                    f"cache kind {name!r}: a page ({self.page_size}) must be a chunk ({c}), and "
+                    f"a slot's table ({pages_per_slot} pages) must hold its most entries and "
+                    f"the {aside} pages aside ({need}): max_cache_len at least two windows")
         if self.paged:
             self.allocator = PageAllocator(self.num_pages, reserved=1)
-            self.tables = PagedTables(num_slots, pages_per_slot, parking=0)
+            self.tables = PagedTables(num_slots, pages_per_slot, parking=0, aside=aside)
         self.device_tables = None  # the engine puts the device twin here
 
     @property
@@ -600,6 +637,43 @@ class CacheKind:
     def page_bytes(self) -> int:
         """Arena bytes one page of this kind takes over all its layers."""
         return self.layers * self.page_size * self.token_bytes
+
+    def entries(self, length: int) -> int:
+        """Entries a slot holds at a context of ``length`` positions, which
+        is also the entry position ``length`` takes: ``length`` itself but
+        for a closing kind, whose closed windows stand as one entry a chunk."""
+        if self.closes is None:
+            return length
+        w, c = self.closes
+        return (length // w) * (w // c) + length % w
+
+    def close_windows(self, slot: int, next_pos: int) -> int:
+        """Close the windows that lie wholly before ``next_pos``, the slot's
+        next write (host bookkeeping; the caller uploads the row when this
+        returns pages): the window's token pages go back to the pool, the
+        pages of its summaries take their first columns, and the pages the
+        next window's summaries are pooled into come out of those just given
+        back. Returns the pages the slot holds fewer. A slot closes one
+        window at a time: no dispatch's rows straddle a close."""
+        if self.closes is None:
+            return 0
+        th, (w, _) = self.tables, self.closes
+        released = 0
+        while th.closed[slot] < next_pos // w:
+            n = th.closed[slot]
+            assert th.aside_held[slot] and next_pos // w == n + 1, "one window closes at a time"
+            first, tokens, aside = n * th.aside, w // self.page_size, th.aside
+            row = th.rows[slot]
+            assert th.alloc_count[slot] == first + tokens, "the window's pages are all written"
+            for idx in range(first, first + tokens):
+                self.allocator.release(int(row[idx]))
+            row[first:first + aside] = row[th.pages_per_slot - aside:]
+            row[first + aside:first + tokens] = th.parking
+            row[th.pages_per_slot - aside:] = [self.allocator.alloc() for _ in range(aside)]
+            th.alloc_count[slot] = first + aside
+            th.closed[slot] = n + 1
+            released += tokens - aside
+        return released
 
     def first_live_entry(self, next_pos: int) -> int:
         """The first table entry a slot still needs when its next query
@@ -630,7 +704,7 @@ class CacheKind:
         for a slot whose last write lands at ``pos``: whole pages from the
         window's first (or the slot's first) through that one."""
         ps = self.page_size
-        return (pos // ps + 1 - self.first_live_entry(pos)) * ps
+        return (self.entries(pos) // ps + 1 - self.first_live_entry(pos)) * ps
 
 
 # ---------------------------------------------------------------------------

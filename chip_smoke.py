@@ -368,6 +368,55 @@ def ssm_phase(S: Sizes, seed: int, on_chip: bool) -> None:
         say(line)
 
 
+def eva_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    """The ``eva_pool`` kernel against ``eva_pool_reference`` at the published
+    widths of the closing-window cell (32 kv heads of 128, pages of 16, a
+    stack of two layers of which the second is pooled; tiny and interpreted
+    in the rehearsal): a decode step in which some slots fill a page (two
+    fill rows of one destination page, the others name the parking page) and
+    one in which all 16 do. What no step names has to come back bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.eva import eva_pool_pages, eva_pool_reference
+
+    kvh, d, ps, pages = (32, 128, 16, 512) if on_chip else (4, 16, 4, 40)
+    mode = S.kernel_mode  # None on the chip: the compiled kernel
+    key = jax.random.split(jax.random.key(seed + 38), 4)
+    kp, vp = (jax.random.normal(k, (2, pages, kvh, ps, d)).astype(jnp.bfloat16) for k in key[:2])
+    mu, phi = (jax.random.normal(k, (kvh, d)) for k in key[2:])
+    rng = np.random.RandomState(seed)
+    for name, n in (("some slots fill a page", 16), ("every slot fills a page", 16)):
+        src = rng.permutation(np.arange(1, pages // 2))[:n]
+        if name.startswith("some"):
+            src[rng.rand(n) < 0.5] = 0  # slots that fill no page
+        dst = np.where(src > 0, pages // 2 + np.arange(n) // 2, 0)  # two steps a destination page
+        off = np.where(src > 0, rng.randint(0, ps // 2, n) * 2 + np.arange(n) % 2, 0)
+        args = tuple(jnp.asarray(x, jnp.int32) for x in (src, dst, off))
+        run = jax.jit(lambda k, v: eva_pool_pages(
+            k, v, mu, phi, *args, sm_scale=d ** -0.5, layer=1, interpret=mode == "interpret"))
+        got_k, got_v = jax.block_until_ready(run(kp, vp))
+        want_k, want_v = eva_pool_reference(kp[1], vp[1], mu, phi, *args, d ** -0.5)
+        err = max(float(jnp.max(jnp.abs(g[1, 1:].astype(jnp.float32) - w[1:].astype(jnp.float32))))
+                  for g, w in ((got_k, want_k), (got_v, want_v)))
+        assert err <= BF16_ATOL, err
+        assert np.array_equal(np.asarray(got_k[0]), np.asarray(kp[0]))  # the other layer
+        named = set(int(p) for p in dst if p)
+        kept = [p for p in range(1, pages) if p not in named]
+        assert np.array_equal(np.asarray(got_k[1, kept]), np.asarray(kp[1, kept]))
+        assert np.array_equal(np.asarray(got_v[1, kept]), np.asarray(vp[1, kept]))
+        line = (f"  eva_pool {name} ({int((src > 0).sum())} of {n} steps pool, {kvh} kv heads x {d}, pages of {ps}, "
+                f"{mode or 'compiled (Mosaic)'}): max|kernel-ref|={err:.4f}")
+        if on_chip:
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = run(kp, vp)
+            jax.block_until_ready(out)
+            line += f"; {1e6 * (time.perf_counter() - t0) / 20:.0f} us a call, host clock, dispatch and the stack's copy included"
+        say(line)
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -746,6 +795,7 @@ def main() -> int:
         [("four chips: fsdp2 x tp2 trainer vs one device", four_chip_phase)]
         if args.chips == 4 else
         [("kernels vs references", kernels_phase), ("state-space scan vs reference", ssm_phase),
+         ("closing-window pooling vs reference", eva_phase),
          ("train", accelerator_train), ("serve", serve_phase)]
     )
     for name, fn in phases:

@@ -147,6 +147,19 @@ def _ssm_scan(chip, *, blocks, rows, width=5120, n=16, layers=13, slots=128):
     return fn, (S((blocks, rows, width), jnp.bfloat16), S((blocks, rows, width)), S((blocks, rows, n)),
                 S((blocks, rows, n)), S((n, width)), S((width,)), S((layers, slots, n, width)),
                 per_block, per_block, per_block, S((), jnp.int32))
+def _eva_pool(chip, *, steps, kvh=32, d=128, ps=16, layers=8, pages=1792):
+    """The pooling kernel of a closing window (ops/eva.py) at published widths:
+    ``steps`` pages pooled in one layer of the layers' stack (a decode step's
+    carried arena), the stack aliased to its output."""
+    from accelerate_tpu.ops import eva
+
+    S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    arena = (layers, pages, kvh, ps, d)
+    fn = lambda k, v, mu, phi, src, dst, off, layer: eva._eva_pool_kernel_call(
+        k, v, mu, phi, src, dst, off, layer, SM_SCALE, False)
+    per_step = S((steps,), jnp.int32)
+    return fn, (S(arena), S(arena), S((kvh, d), jnp.float32), S((kvh, d), jnp.float32),
+                per_step, per_step, per_step, S((), jnp.int32))
 
 
 CASES = {
@@ -234,6 +247,14 @@ CASES = {
         _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=256, slots=128, pages=16384, table=512)),
     "ragged_prefill_one_kv_head_group20_64_rows": (
         _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=64, slots=128, pages=16384, table=512)),
+    # a closing window with pooled summaries at published widths (benchmarks/configs/evabyte-6.5b-serve-8l.json):
+    # 32 kv heads and a query group of one over entry lists, 16 slots, a table of 1280; the pooling of a decode
+    # step's filled pages in the carried stack (a pack pools by XLA's gather and scatter)
+    "paged_decode_32_kv_heads_closing_cell_in_place": (
+        _paged_decode, dict(slots=16, pages=1792, table=1280, layers=8, write=True)),
+    "ragged_prefill_32_kv_heads_closing_cell": (
+        _ragged_prefill, dict(bt=64, slots=16, pages=1792, table=1280)),
+    "eva_pool_decode_step_16_slots": (_eva_pool, dict(steps=16)),
     # dense-arena decode (single-stream generate(), the flat slot arena)
     "dense_decode_bf16": (_dense_decode, dict(bits=0)),
     "dense_decode_int8": (_dense_decode, dict(bits=8)),
@@ -304,6 +325,7 @@ KERNEL_NAMES = {
     "moe_experts_decode_rows": {"moe_experts"},
     "ssm_scan_decode_step_128_slots": {"ssm_scan"},
     "ssm_scan_pack_256_rows": {"ssm_scan"},
+    "eva_pool_decode_step_16_slots": {"eva_pool"},
 }
 
 
