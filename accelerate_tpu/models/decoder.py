@@ -16,6 +16,7 @@ targets (reference examples/nlp_example.py, benchmarks/big_model_inference).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import flax.linen as nn
@@ -194,7 +195,8 @@ class DecoderAttention(nn.Module):
     an inactive slot, whose parked write position would otherwise read as
     a request at the end of the cache. ``cache_layer`` (the scanned stack's
     layer counter, :func:`arena_in_place`): the cache leaves are then the
-    layers' stacks and the kernel writes this layer's new rows itself.
+    layers' stacks and the call's kernel writes this layer's new rows
+    itself, a decode step's row a slot or a pack's rows.
 
     ``config.kv_cache_dtype`` ("int8"/"int4") makes the cache STORAGE
     quantized on both layouts: writes quantize the fresh K/V rows (one
@@ -212,10 +214,11 @@ class DecoderAttention(nn.Module):
     r]`` of slot ``ragged_slots[r]`` (-1 = token-block padding) — and the
     flash prefill kernel (``ops/attention.ragged_prefill_attention``,
     ``config.prefill_kernel``) attends each row
-    against its slot's live arena prefix plus the packed fresh rows,
-    with quantize-on-write fused so the page-table scatter lands the
-    kernel's payload+scales directly. One dispatch carries every pending
-    tail; padding is token-block granularity.
+    against its slot's live arena prefix plus the packed fresh rows. On
+    the carried stack (``cache_layer``) the kernel writes the rows into
+    the slots' pages itself; else quantize-on-write is fused so the
+    page-table scatter lands the kernel's payload+scales directly. One
+    dispatch carries every pending tail; padding is token-block granularity.
 
     ``causal=False`` (+ optional ``kv_mask``) is the bidirectional form the
     seq2seq encoder reuses (models/seq2seq.py) — same projections, RoPE and
@@ -398,10 +401,12 @@ class DecoderAttention(nn.Module):
                 # kernel (ops/attention.ragged_prefill_attention) attends
                 # each row against its slot's live arena prefix
                 # (slot_hist, prefix-aware block skipping) plus the packed
-                # fresh rows at <= its own position, and quantize-on-write
-                # is fused: the kernel emits payload+scales which the
-                # scatter below lands through the page table in the same
-                # program — no separate quantize pass, no bucket padding.
+                # fresh rows at <= its own position. The rows reach the
+                # pages inside the kernel where the stack is carried
+                # (cache_layer); else quantize-on-write is fused: the kernel
+                # emits payload+scales which the scatter below lands
+                # through the page table in the same program — no separate
+                # quantize pass, no bucket padding.
                 if not paged:
                     raise NotImplementedError(
                         "ragged_slots (packed ragged prefill) requires the "
@@ -423,46 +428,57 @@ class DecoderAttention(nn.Module):
                     # a slot's rows of one pack lie in one window (the
                     # engine's plan), so their entries are consecutive too
                     row_pos, slot_hist = entries(row_pos), entries(slot_hist)
-                scale_kw = {}
-                if kvq_bits:
-                    scale_kw = {"k_scale": cached_ks.value,
-                                "v_scale": cached_vs.value,
-                                "kv_quant_bits": kvq_bits}
-                out, k_pay, k_scl, v_pay, v_scl = ragged_prefill_attention(
-                    q, k, v, cached_k.value, cached_v.value,
-                    page_table=page_table, row_slot=ragged_slots,
-                    row_pos=row_pos, slot_hist=slot_hist,
-                    impl=getattr(cfg, "prefill_kernel", None),
-                    token_block=getattr(cfg, "prefill_kernel_block", None),
-                    **scale_kw, **extras,
-                )
-                # fused scatter through the page table. Pad rows (-1) route
-                # to physical page 0 — the arena's reserved parking page —
-                # so the scatter stays a fixed-shape data move with no
-                # masking branch; parking content is never attended.
                 ps = cfg.kv_page_size
                 valid = (ragged_slots >= 0) & (row_pos >= 0)
-                srow = jnp.maximum(ragged_slots, 0)
-                spos = jnp.maximum(row_pos, 0)
-                page = jnp.where(valid, page_table[srow, spos // ps], 0)
-                off = spos % ps
-                cached_k.value = cached_k.value.at[page, :, off].set(k_pay)
-                cached_v.value = cached_v.value.at[page, :, off].set(v_pay)
-                if kvq_bits:
-                    cached_ks.value = cached_ks.value.at[page, :, off].set(k_scl)
-                    cached_vs.value = cached_vs.value.at[page, :, off].set(v_scl)
+                pk_impl = getattr(cfg, "prefill_kernel", None)
+                attend = functools.partial(
+                    ragged_prefill_attention, q, k, v, cached_k.value, cached_v.value,
+                    page_table=page_table, row_slot=ragged_slots,
+                    row_pos=row_pos, slot_hist=slot_hist, impl=pk_impl,
+                    token_block=getattr(cfg, "prefill_kernel_block", None), **extras)
+                if cache_layer is not None:
+                    # the arena in place (arena_in_place): the cache leaves
+                    # are the layers' stacks, carried through the scan, and
+                    # the kernel writes the pack's rows into this layer's
+                    # pages itself, through the table
+                    out, cached_k.value, cached_v.value = attend(layer=cache_layer)
+                else:
+                    scale_kw = {}
+                    if kvq_bits:
+                        scale_kw = {"k_scale": cached_ks.value,
+                                    "v_scale": cached_vs.value,
+                                    "kv_quant_bits": kvq_bits}
+                    out, k_pay, k_scl, v_pay, v_scl = attend(**scale_kw)
+                    # fused scatter through the page table. Pad rows (-1) route
+                    # to physical page 0 — the arena's reserved parking page —
+                    # so the scatter stays a fixed-shape data move with no
+                    # masking branch; parking content is never attended.
+                    srow = jnp.maximum(ragged_slots, 0)
+                    spos = jnp.maximum(row_pos, 0)
+                    page = jnp.where(valid, page_table[srow, spos // ps], 0)
+                    off = spos % ps
+                    cached_k.value = cached_k.value.at[page, :, off].set(k_pay)
+                    cached_v.value = cached_v.value.at[page, :, off].set(v_pay)
+                    if kvq_bits:
+                        cached_ks.value = cached_ks.value.at[page, :, off].set(k_scl)
+                        cached_vs.value = cached_vs.value.at[page, :, off].set(v_scl)
                 if eva:
                     # the pages this pack has filled, each pooled into its
-                    # entry of the open window's summaries: a gather, the
-                    # pooling and a second scatter of XLA's on the layer's
-                    # pages (the aliased kernel here costs a copy of them,
-                    # which the scatters do not: PERF.md, PR 38)
-                    from ..ops.eva import eva_pool_reference, pool_plan
+                    # entry of the open window's summaries, after the rows'
+                    # write: by the kernel on the carried stack, as a decode
+                    # step pools, else a gather, the pooling and a second
+                    # scatter of XLA's on the layer's pages
+                    from ..ops.eva import eva_pool_pages, eva_pool_reference, pool_plan
 
                     plan = pool_plan(true_pos, valid, ragged_slots, page_table,
                                      window=ew, chunk=ec, size=max(1, s // ec))
-                    cached_k.value, cached_v.value = eva_pool_reference(
-                        cached_k.value, cached_v.value, mu, phi, *plan, d ** -0.5)
+                    if cache_layer is not None:
+                        cached_k.value, cached_v.value = eva_pool_pages(
+                            cached_k.value, cached_v.value, mu, phi, *plan, sm_scale=d ** -0.5,
+                            layer=cache_layer, interpret=pk_impl == "interpret")
+                    else:
+                        cached_k.value, cached_v.value = eva_pool_reference(
+                            cached_k.value, cached_v.value, mu, phi, *plan, d ** -0.5)
             elif cache_positions is not None:
                 # slot-arena decode (serving/): every batch row writes its
                 # new K/V at its own per-slot offset(s) and attends only
@@ -621,28 +637,29 @@ class DecoderAttention(nn.Module):
         return _constrain(out, ("batch", "seq", "embed"), self.mesh)
 
 
-def arena_in_place(config, sq: int = 1) -> bool:
-    """Does a paged decode step of ``sq`` new tokens a slot update the
-    arena in place on a model with this config? Then the scanned stack
-    carries the "cache" collection whole, ``[L, num_pages, KVH, page, D]`` a
-    leaf, and the paged decode kernel takes the stack and a layer index and
-    writes each slot's new row itself (``ops/attention.
-    paged_decode_attention``): no layer's pages are sliced out of the
-    stack, re-laid out for a scatter or put back. It takes what that
-    kernel's write takes: the scanned stack, the kernel engaged
-    (``decode_kernel_active``: on the chip or interpreted, 128-multiple
-    page widths), one new token a slot and unquantized pages. Everything
-    else (the packed prefill, speculative verify, the dense fallback, a
-    quantized cache) splits the collection along the layers as before.
-    The serving engine's ``arena_in_place`` gauge reads this."""
-    from ..ops.attention import decode_kernel_active
+def arena_in_place(config, sq: int = 1, packed: bool = False) -> bool:
+    """Does a paged serving program update the arena in place on a model
+    with this config: a decode step of ``sq`` new tokens a slot, or
+    (``packed``) the packed ragged prefill? Then the scanned stack carries
+    the "cache" collection whole, ``[L, num_pages, KVH, page, D]`` a leaf,
+    and the program's kernel takes the stack and a layer index and writes
+    the new rows itself (``ops/attention.paged_decode_attention``: each
+    slot's row; ``ragged_prefill_attention``: the pack's rows, page by page
+    through the table): no layer's pages are sliced out of the stack,
+    re-laid out for a scatter or put back. It takes what the kernel's write
+    takes: the scanned stack, unquantized pages, and the kernel engaged: a
+    decode step's (``decode_kernel_active``: on the chip or interpreted,
+    128-multiple page widths) with one new token a slot, a pack's
+    (``prefill_writes_pages``: likewise). Everything else (speculative
+    verify, the dense fallback, a quantized cache, pages of no whole lanes)
+    splits the collection along the layers as before. The serving engine's
+    ``arena_in_place`` gauges and span counters read this."""
+    from ..ops.attention import decode_kernel_active, prefill_writes_pages
 
-    return (
-        sq == 1
-        and bool(getattr(config, "scan_layers", False))
-        and getattr(config, "kv_cache_dtype", "bf16") not in ("int8", "int4")
-        and decode_kernel_active(config, sq)
-    )
+    if (not getattr(config, "scan_layers", False)
+            or getattr(config, "kv_cache_dtype", "bf16") in ("int8", "int4")):
+        return False
+    return prefill_writes_pages(config) if packed else sq == 1 and decode_kernel_active(config, sq)
 
 
 class DecoderMLP(nn.Module):
@@ -920,17 +937,17 @@ class DecoderLM(nn.Module):
                 ptab = page_table
                 if isinstance(page_table, dict):
                     ptab = page_table[run_cfg.cache_kind]
-                # a paged decode step the kernel serves carries the stacked
-                # arena through the scan whole and the kernel updates it in
-                # place; every other call splits it by layer, as ever
-                # (not the call that shapes the arena: a carry has to exist)
-                # A state-space run's states are carried whole in both serving
-                # programs, the packed prefill too: a pack advances a few
-                # slots of a state that is of all of them.
+                # a paged decode step or packed prefill the kernel serves
+                # carries the stacked arena through the scan whole and the
+                # kernel updates it in place; every other call splits it by
+                # layer, as ever (not the call that shapes the arena: a
+                # carry has to exist). A state-space run's states are
+                # carried whole in both serving programs: a pack advances a
+                # few slots of a state that is of all of them.
                 name = f"layers_{i}" if cfg.layer_kinds else "layers"
                 in_place = (use_cache and decode and page_table is not None
                             and (run_cfg.mixer == "ssm"
-                                 or (ragged_slots is None and arena_in_place(run_cfg, s)))
+                                 or arena_in_place(run_cfg, s, packed=ragged_slots is not None))
                             and name in self.variables.get("cache", {}))
                 split = {"params": 0, "cache": 0, "fp8_stats": 0, MOE_LOAD: 0}
                 if in_place:

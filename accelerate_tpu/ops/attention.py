@@ -1642,7 +1642,12 @@ def paged_decode_attention(
 # block's own slot at or before it. Prefix-aware skipping is structural:
 # positions already served by a prefix-cache / tier hit are never
 # re-attended as QUERIES (only the fresh tail packs rows).
-# Quantize-on-write is fused: the kernel quantizes each fresh K/V block
+# The write is the kernel's too where the arena is the layers' stack
+# carried through the layer scan (``models/decoder.arena_in_place``): a
+# token block's fresh rows go into the slot's pages through the table and
+# the pages back to the arena, the stack aliased to the output. Else (a
+# quantized cache, pages of no whole lanes) the caller scatters, and
+# quantize-on-write is fused: the kernel quantizes each fresh K/V block
 # in-register (the exact ``utils.quantization.quantize_kv`` op
 # sequence), emits payload+scale outputs for the caller's single arena
 # scatter, and attends the tail over the DEQUANTIZED values — the same
@@ -1780,6 +1785,25 @@ def prefill_kernel_active(config) -> bool:
     return use
 
 
+def prefill_writes_pages(config) -> bool:
+    """Would a packed ragged prefill dispatch on a model with this config
+    write the pack's rows into the arena's pages inside the kernel (given
+    the layers' stack and a layer index)? Where the kernel runs
+    (:func:`prefill_kernel_active`) over unquantized pages (a quantized
+    pack returns payloads and scales for the caller's scatter) whose widths
+    are whole lanes as stored (a narrower page reaches the compiled kernel
+    padded, by a copy). ``models/decoder.arena_in_place`` asks."""
+    if not prefill_kernel_active(config):
+        return False
+    if getattr(config, "kv_cache_dtype", "bf16") in ("int8", "int4"):
+        return False
+    if resolve_prefill_kernel(getattr(config, "prefill_kernel", None)) == "interpret":
+        return True
+    head_dim = int(config.head_dim)
+    return (paged_key_lanes(head_dim) % 128 == 0
+            and int(getattr(config, "v_head_dim", None) or head_dim) % 128 == 0)
+
+
 def _quantize_block(x, bits):
     """In-register quantize-on-write on one [rows, D] block through the
     SAME functions the jitted cache writes call
@@ -1827,10 +1851,11 @@ def prefill_walk_pages(hist: int, first_pos: int, ps: int,
 
 
 def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
+                           bpos_ref, blive_ref, layer_ref,
                            q_ref, *refs, sm_scale, bt, block_pages,
                            key_lanes, value_lanes, quant_bits=0,
                            out_dtype=None, window=None, has_sink=False,
-                           value_scale=1.0):
+                           value_scale=1.0, write=False):
     """One token block a grid step: ``bt`` packed rows of one slot, folded
     with their query-head group into ``[KVH, bt*group, D]``.
 
@@ -1864,18 +1889,43 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
     token) the lanes) and are dequantized in-register by
     ``utils.quantization.dequantize_kv``. Pages come in whole lanes:
     ``key_lanes`` / ``value_lanes`` are the widths stored, which a
-    zero-padded page (a 64-wide head, an int4 payload) is read at."""
+    zero-padded page (a 64-wide head, an int4 payload) is read at.
+
+    The arena is the layers' stack ``[L, num_pages, KVH, page, D]`` and
+    ``layer_ref[0]`` the layer this call reads: a page is ``at[layer,
+    page]``. ``write`` (unquantized pages): the stack is this call's output
+    as well as its input (aliased), and the step puts its block's fresh
+    keys and values, which it holds in VMEM, into the slot's pages itself:
+    the ``blive_ref[i]`` live rows from position ``bpos_ref[i]`` on lie in
+    at most ``(bt + page - 2) // page + 1`` consecutive table entries. A
+    page the rows do not cover whole (the block starts mid-page, or the tail
+    ends in it) is read first, while the arena is walked; after the walk the
+    rows go into their pages (moved to their place in the page by a
+    one-hot product, which is exact, and a select over the page) and the
+    pages go back to the arena while the fresh phase runs; the step ends
+    when they have arrived, so the next block of the slot, which may share
+    this block's last page, reads what was written. Only pages that hold a
+    live row are touched: a padding block and a tail's pad rows (position
+    -1) write nothing. The pack needs no scatter, so the stack is never
+    sliced, re-laid out or copied (``models/decoder.arena_in_place``)."""
     if has_sink:
         sink_ref, refs = refs[0], refs[1:]
     kn_ref, vn_ref, qpos_ref, kvpos_ref, k_hbm, v_hbm = refs[:6]
-    if quant_bits:
+    if write:
+        # the stack as this call's output: the same buffer on the chip,
+        # and the one that holds the rows written so far when interpreted
+        (o_ref, k_hbm, v_hbm, kbuf, vbuf, sems, acc, m_scr, l_scr,
+         wkbuf, wvbuf, wsems) = refs[6:]
+        ks_hbm = vs_hbm = ksbuf = vsbuf = None
+    elif quant_bits:
         (ks_hbm, vs_hbm, o_ref, kq_ref, kso_ref, vq_ref, vso_ref,
          kbuf, vbuf, ksbuf, vsbuf, sems, acc, m_scr, l_scr) = refs[6:]
     else:
         o_ref, kbuf, vbuf, sems, acc, m_scr, l_scr = refs[6:]
         ks_hbm = vs_hbm = ksbuf = vsbuf = None
     i = pl.program_id(0)
-    kvh, ps = k_hbm.shape[1], k_hbm.shape[2]
+    layer = layer_ref[0]
+    kvh, ps = k_hbm.shape[2], k_hbm.shape[3]
     bk = block_pages * ps  # kv positions a block of the walk spans
     slot = bslot_ref[i]
     row = jnp.maximum(slot, 0)
@@ -1894,7 +1944,7 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
         if quant_bits:
             pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
         return [
-            pltpu.make_async_copy(src.at[page], dst.at[half, j], sems.at[half, which])
+            pltpu.make_async_copy(src.at[layer, page], dst.at[half, j], sems.at[half, which])
             for src, dst, which in pairs
         ]
 
@@ -1926,6 +1976,34 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
     @pl.when(n_blocks > 0)
     def _():
         start(0, 0)
+
+    if write:
+        # this block's live rows as rows of its first touched page on:
+        # [w_lo, w_hi) of the w_pages * ps rows the touched pages hold
+        w_pages = wkbuf.shape[0]
+        entry0 = bpos_ref[i] // ps
+        w_lo = bpos_ref[i] - entry0 * ps
+        w_hi = w_lo + blive_ref[i]
+        n_written = jnp.where(blive_ref[i] > 0, (w_hi + ps - 1) // ps, 0)
+
+        def for_each_written_page(act, back):
+            """``act(copy)`` for the copies, keys and values, of every
+            touched page: ``back`` to the arena, or in from it, and then
+            of the pages alone that the rows do not cover whole."""
+            for j in range(w_pages):
+                touched = j < n_written
+                if not back:
+                    touched &= (w_lo > j * ps) | (w_hi < (j + 1) * ps)
+
+                @pl.when(touched)
+                def _():
+                    page = tbl_ref[row, entry0 + j]
+                    for hbm, buf, which in ((k_hbm, wkbuf, 0), (v_hbm, wvbuf, 1)):
+                        there, here = hbm.at[layer, page], buf.at[j]
+                        src, dst = (here, there) if back else (there, here)
+                        act(pltpu.make_async_copy(src, dst, wsems.at[j, which]))
+
+        for_each_written_page(lambda copy: copy.start(), back=False)
 
     if has_sink:
         # the learned scalar joins the denominator and carries no value
@@ -2003,6 +2081,31 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
 
     jax.lax.fori_loop(0, n_blocks, block, 0)
 
+    if write:
+        for_each_written_page(lambda copy: copy.wait(), back=False)
+        # row r of the touched pages is the block's row r - w_lo: a one-hot
+        # product puts it there exactly (one term a row, the rest zeros)
+        at = jax.lax.broadcasted_iota(jnp.int32, (w_pages * ps, bt), 0)
+        take = jax.lax.broadcasted_iota(jnp.int32, (w_pages * ps, bt), 1)
+        pick = at - w_lo == take
+        exact = jax.lax.Precision.HIGHEST if kn_ref.dtype == jnp.float32 else None
+
+        def put_rows(h_):
+            for new_ref, buf in ((kn_ref, wkbuf), (vn_ref, wvbuf)):
+                moved = jax.lax.dot_general(
+                    pick.astype(new_ref.dtype), new_ref[h_, i],
+                    (((1,), (0,)), ((), ())), precision=exact,
+                    preferred_element_type=jnp.float32).astype(buf.dtype)
+                for j in range(w_pages):
+                    page = buf[j, h_]  # [page, D]
+                    r = j * ps + jax.lax.broadcasted_iota(jnp.int32, page.shape, 0)
+                    buf[j, h_] = jnp.where(
+                        (r >= w_lo) & (r < w_hi), moved[j * ps:(j + 1) * ps], page)
+
+        each_head(put_rows)
+        # on their way back while the fresh phase runs
+        for_each_written_page(lambda copy: copy.start(), back=True)
+
     def fresh_kv(h_, jf):
         kn, vn = kn_ref[h_, jf], vn_ref[h_, jf]  # [bt, D], [bt, Dv]
         if not quant_bits:
@@ -2012,14 +2115,14 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
         return kdq.astype(out_dtype), vdq.astype(out_dtype), (kp, ksv, vp, vsv)
 
     if quant_bits:
-        def write(h_):
+        def payloads(h_):
             kp, ksv, vp, vsv = fresh_kv(h_, i)[2]
             kq_ref[h_, 0] = kp
             kso_ref[h_, 0] = ksv
             vq_ref[h_, 0] = vp
             vso_ref[h_, 0] = vsv
 
-        each_head(write)
+        each_head(payloads)
 
     # packed tails are position-ordered per slot, so blocks of the same
     # slot after this one are entirely above the causal frontier, and
@@ -2046,6 +2149,9 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
         o_ref[0, h_] = o.astype(o_ref.dtype)
 
     each_head(out)
+    if write:
+        # the pages are back before the slot's next block reads them
+        for_each_written_page(lambda copy: copy.wait(), back=True)
 
 
 def _whole_lanes(x):
@@ -2060,16 +2166,35 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
                                 row_slot, row_pos, slot_hist, sm_scale, bt,
                                 interpret, k_scale=None, v_scale=None,
                                 quant_bits=0, window=None, sink=None,
-                                value_scale=1.0):
+                                value_scale=1.0, layer=None):
+    """``layer`` absent: ``k_pages`` / ``v_pages`` (and the scale pages) are
+    one layer's pages ``[num_pages, KVH, page, D]`` and the result is
+    ``(out, k_payload, k_scale, v_payload, v_scale)`` for the caller's
+    scatter. With ``layer`` they are the layers' stack ``[L, num_pages, KVH,
+    page, D]``, the call also writes the pack's rows into the slots' pages
+    (the kernel's ``write``) and returns ``(out, k_pages, v_pages)``, the
+    stacks updated in place."""
     _, h, cap, d = q.shape
-    _, kvh, ps, key_lanes = k_pages.shape
+    write = layer is not None
+    if not write:  # one layer's pages: a stack of one
+        k_pages, v_pages, k_scale, v_scale = (
+            None if x is None else x[None] for x in (k_pages, v_pages, k_scale, v_scale))
+        layer = 0
+    _, _, kvh, ps, key_lanes = k_pages.shape
     dv, value_lanes = v_new.shape[-1], v_pages.shape[-1]
+    if write and (quant_bits or (not interpret and (key_lanes % 128 or value_lanes % 128))):
+        raise ValueError(
+            "the ragged prefill kernel writes unquantized pages of whole lanes; "
+            f"got int{quant_bits} pages {key_lanes} / {value_lanes} wide "
+            "(prefill_writes_pages says so beforehand)")
     # the kernel copies whole pages out of HBM, which Mosaic takes in whole
     # lanes only: a narrower arena (a 64-wide head, an int4 payload) goes
     # in padded, by a copy of the layer's pages, and the kernel reads the
     # lanes that are stored. Pages a model stores whole (128; 192-wide
-    # keys at 256, ``paged_key_lanes``) go in as they are.
-    k_pages, v_pages = _whole_lanes(k_pages), _whole_lanes(v_pages)
+    # keys at 256, ``paged_key_lanes``) go in as they are, and so does the
+    # stack the kernel writes (interpreted, any width is whole).
+    if not write:
+        k_pages, v_pages = _whole_lanes(k_pages), _whole_lanes(v_pages)
     pd, pdv = k_pages.shape[-1], v_pages.shape[-1]
     group = h // kvh
     ntb = cap // bt
@@ -2101,12 +2226,17 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
     pos_in = row_pos.reshape(ntb, 1, bt).astype(jnp.int32)
     # row r of a folded q block is token r // group
     pos_rows = jnp.repeat(row_pos.astype(jnp.int32), group).reshape(ntb, g, 1)
-    prefetch = [blk_slot, blk_hist, page_table.astype(jnp.int32), blk_lo, blk_first]
+    # what a block writes: its live rows (a tail's pads come last), from
+    # its first row's position on
+    blk_live = jnp.sum(row_pos.reshape(ntb, bt) >= 0, axis=1).astype(jnp.int32)
+    prefetch = [blk_slot, blk_hist, page_table.astype(jnp.int32), blk_lo, blk_first,
+                jnp.maximum(first_pos, 0), blk_live, jnp.asarray(layer, jnp.int32).reshape(1)]
 
     kernel = functools.partial(
         _ragged_prefill_kernel, sm_scale=sm_scale, bt=bt, block_pages=n,
         key_lanes=key_lanes, value_lanes=value_lanes, quant_bits=quant_bits,
         out_dtype=q.dtype, window=window, has_sink=sink is not None, value_scale=value_scale,
+        write=write,
     )
 
     def per_block(*block):
@@ -2126,7 +2256,9 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         rows = jnp.tile(sink.astype(jnp.float32).reshape(kvh, 1, group), (1, bt, 1))
         operands.append(rows.reshape(kvh, g, 1))
         in_specs.append(whole(operands[-1]))
-    operands += [kn_r, vn_r, pos_rows, pos_in, k_pages, v_pages]
+    operands += [kn_r, vn_r, pos_rows, pos_in]
+    first_arena = len(prefetch) + len(operands)
+    operands += [k_pages, v_pages]
     in_specs += [whole(kn_r), whole(vn_r), per_block(g, 1), whole(pos_in), arena, arena]
     buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype),
                pltpu.VMEM((2, n, kvh, ps, pdv), v_pages.dtype)]
@@ -2136,11 +2268,11 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         # per-(page, kv-head, token) fp32 scales ride the same walk, a
         # page's as one lane-dense row (a view made here: the scale pages
         # end in a dimension of 1 as stored, which is no whole lane)
-        scales = [_whole_lanes(x.reshape(x.shape[0], 1, kvh * ps))
+        scales = [_whole_lanes(x.reshape(x.shape[:2] + (1, kvh * ps)))
                   for x in (k_scale, v_scale)]
         operands += scales
         in_specs += [arena, arena]
-        buffers += [pltpu.VMEM((2, n) + scales[0].shape[1:], jnp.float32)] * 2
+        buffers += [pltpu.VMEM((2, n) + scales[0].shape[2:], jnp.float32)] * 2
         for width, dt in ((key_lanes, jnp.int8), (1, jnp.float32),
                           (value_lanes, jnp.int8), (1, jnp.float32)):
             out_specs.append(fresh_out(width))
@@ -2149,6 +2281,16 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         pltpu.SemaphoreType.DMA((2, 2)),
         _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
     ]
+    aliases = {}
+    if write:
+        # the pages a block's rows touch, staged: bt rows from anywhere in a page
+        w_pages = (bt + ps - 2) // ps + 1
+        out_specs += [arena, arena]
+        out_shape += [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (k_pages, v_pages)]
+        scratch += [pltpu.VMEM((w_pages, kvh, ps, pd), k_pages.dtype),
+                    pltpu.VMEM((w_pages, kvh, ps, pdv), v_pages.dtype),
+                    pltpu.SemaphoreType.DMA((w_pages, 2))]
+        aliases = {first_arena: 1, first_arena + 1: 2}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(ntb,),
@@ -2160,13 +2302,17 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        input_output_aliases=aliases,
         name="ragged_prefill_attn",
-        # the first step zeroes the page buffers for those after it
+        # the first step zeroes the page buffers for those after it, and a
+        # slot's blocks write its pages in order
         **_grid_params(interpret, ("arbitrary",)),
     )(*prefetch, *operands)
     o = outs[0]  # out_shape is a list, so pallas returns a list
     out = (o.reshape(ntb, kvh, bt, group, dv)
            .transpose(1, 3, 0, 2, 4).reshape(1, h, cap, dv))
+    if write:
+        return out, outs[1], outs[2]
     if quant_bits:
         k_pay = jnp.swapaxes(outs[1].reshape(kvh, cap, key_lanes), 0, 1)
         k_scl = jnp.swapaxes(outs[2].reshape(kvh, cap, 1), 0, 1)
@@ -2276,6 +2422,7 @@ def ragged_prefill_attention(
     window: Optional[int] = None,
     sink: Optional[jax.Array] = None,
     value_scale: float = 1.0,
+    layer: Optional[jax.Array] = None,
 ):
     """Packed ragged prefill attention over the paged KV arena, with
     quantize-on-write fused.
@@ -2310,7 +2457,15 @@ def ragged_prefill_attention(
     rows; the kernel's arena walk then starts at the first page the token
     block's earliest row sees and spans at most ``window_span_pages``, so
     the pages behind the window (which may have been given back) are never
-    read. ``sink`` [H] and ``value_scale`` are :func:`mha_reference`'s."""
+    read. ``sink`` [H] and ``value_scale`` are :func:`mha_reference`'s.
+
+    ``layer``: the pages are the layers' stack ``[L, num_pages, KVH,
+    page_size, D]`` and the kernel puts the pack's rows into the slots'
+    pages itself, through the table; the result is ``(out, k_pages,
+    v_pages)``, the stacks updated in place (see
+    ``_ragged_prefill_kernel``). That is the kernel's alone, for
+    unquantized pages of whole lanes: a caller asks
+    :func:`prefill_writes_pages` first and keeps its own scatter elsewhere."""
     mode = resolve_prefill_kernel(impl)
     b, h, cap, d = q.shape
     if b != 1:
@@ -2330,7 +2485,7 @@ def ragged_prefill_attention(
     if mode != "dense":
         use, interpret = _prefill_kernel_gate(
             mode, k_pages.shape[-1] * (2 if kv_quant_bits == 4 else 1),
-            k_pages.shape[2], bt, kv_quant_bits,
+            k_pages.shape[-2], bt, kv_quant_bits,
             dv=v_pages.shape[-1] * (2 if kv_quant_bits == 4 else 1),
         )
         if use:
@@ -2338,8 +2493,13 @@ def ragged_prefill_attention(
                 q, k_new, v_new, k_pages, v_pages, page_table, row_slot,
                 row_pos, slot_hist, scale, bt, interpret,
                 k_scale=k_scale, v_scale=v_scale, quant_bits=kv_quant_bits,
-                window=window, sink=sink, value_scale=value_scale,
+                window=window, sink=sink, value_scale=value_scale, layer=layer,
             )
+    if layer is not None:
+        raise ValueError(
+            "ragged_prefill_attention over the layers' stack is the kernel's "
+            "path; this dispatch resolves to the dense reference "
+            "(prefill_writes_pages says so beforehand)")
     return _ragged_prefill_reference(
         q, k_new, v_new, k_pages, v_pages, page_table, row_slot, row_pos,
         slot_hist, scale, k_scale=k_scale, v_scale=v_scale,

@@ -166,10 +166,10 @@ def _eva_pool_kernel_call(k_pages, v_pages, mu, phi, src, dst, off, layer, sm_sc
 
 def eva_pool_reference(k_pages, v_pages, mu, phi, src, dst, off, sm_scale):
     """The kernel's mathematics as a gather and a scatter of XLA's over one
-    layer's pages [num_pages, KVH, page, D]: a pack's pooling, the decode
-    step's where the arena is split by layer, and what the kernel is tested
-    against. A step with ``src`` 0 writes the parking page's pooling into
-    the parking page, which nothing reads."""
+    layer's pages [num_pages, KVH, page, D]: a program's pooling where the
+    arena is split by layer (``models/decoder.arena_in_place`` says no),
+    and what the kernel is tested against. A step with ``src`` 0 writes the
+    parking page's pooling into the parking page, which nothing reads."""
     kbar, vbar = pool_chunks(k_pages[src], v_pages[src], mu, phi, sm_scale)
     return (k_pages.at[dst, :, off].set(kbar.astype(k_pages.dtype)),
             v_pages.at[dst, :, off].set(vbar.astype(v_pages.dtype)))
@@ -178,12 +178,14 @@ def eva_pool_reference(k_pages, v_pages, mu, phi, src, dst, off, sm_scale):
 def eva_pool_pages(k_pages, v_pages, mu, phi, src, dst, off, *, sm_scale: float, layer,
                    interpret: bool = False):
     """The ``eva_pool`` kernel in place on the layers' stack [L, num_pages,
-    KVH, page, D] (a decode step's carried arena): in layer ``layer``, page
+    KVH, page, D] (the carried arena of a decode step or a pack, after the
+    attention kernel's own writes of the layer): in layer ``layer``, page
     ``src[i]`` (a filled chunk of an open window) is pooled into row
     ``off[i]`` of page ``dst[i]``, for every ``i`` with ``src[i] > 0``.
     Returns the stacks, aliased to the inputs. One layer's pages on their own
-    (a pack; the decode step where the arena is split by layer) take
-    :func:`eva_pool_reference`."""
+    (a program where the arena is split by layer) take
+    :func:`eva_pool_reference`: aliased to a slice of the split scan the
+    kernel costs a copy of the layer's pages (PERF.md, PR 38)."""
     return _eva_pool_kernel_call(k_pages, v_pages, mu, phi, src, dst, off, layer, sm_scale, interpret)
 
 

@@ -16,8 +16,9 @@ never recompiles:
   with the arena (and the per-slot state vectors) **donated**, so the
   multi-hundred-MB cache updates in place instead of doubling HBM per
   step. The arena also stays whole inside the step where the decode
-  kernel serves it (``models/decoder.arena_in_place``; the
-  ``arena_in_place`` gauge).
+  kernel serves it, and inside the packed prefill where its kernel writes
+  the pack's pages (``models/decoder.arena_in_place``; the
+  ``arena_in_place`` and ``prefill_arena_in_place`` gauges).
 - **packed prefill admission** — the pending prompts' tails are packed
   into one ragged dispatch of a fixed grid capacity per scheduler
   iteration, *interleaved* between decode steps: a 10k-token prompt never
@@ -455,6 +456,10 @@ class ServingEngine:
         # stacked leaves carried through the layer scan (the
         # arena_in_place gauge and count of serving/decode_dispatch)
         self._arena_in_place = all(arena_in_place(c) for c in run_cfgs)
+        # ... and whether the packed prefill does, its kernel writing the
+        # pack's pages (the prefill_arena_in_place gauge, arena_in_place
+        # of serving/prefill_dispatch)
+        self._prefill_in_place = all(arena_in_place(c, packed=True) for c in run_cfgs)
         self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
         # packed ragged prefill (ops/attention.ragged_prefill_attention):
         # the admission planner packs every pending tail into ONE ragged
@@ -2631,7 +2636,8 @@ class ServingEngine:
         them from the pack program's result) until ``_read_packs`` fetches,
         stamps and emits them, behind that decode dispatch."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
-                   requests=len(packs), **self._pages_walked(packs),
+                   requests=len(packs), arena_in_place=int(self._prefill_in_place),
+                   **self._pages_walked(packs),
                    **self._state_advanced(packs, fresh), **self._pages_pooled(packs)) as sp:
             self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
@@ -3250,6 +3256,7 @@ class ServingEngine:
         out["serving/rows_discarded"] = self.rows_discarded
         out["serving/decode_kernel_active"] = bool(self._kernel_costed)
         out["serving/arena_in_place"] = int(self._arena_in_place)
+        out["serving/prefill_arena_in_place"] = int(self._prefill_in_place)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
         # ... and that kernel walks a slot's live pages in blocks out of HBM
         # (the one form of it there is: the same bit under the mechanism's name)

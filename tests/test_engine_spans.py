@@ -188,6 +188,11 @@ def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
     assert sum(s[5]["tokens"] for s in dispatches) == eng.prefill_packed_tokens
     if path == "ragged":
         assert any(s[5]["requests"] > 1 for s in dispatches)  # the long prompt's tail and a short prompt share a grid
+    # the pack program carries the arena and its kernel writes the pack's pages: wherever that kernel runs over
+    # unquantized pages (the decode dispatch's own counter says 0 on every verify dispatch; the pack's does not)
+    in_place = int(path in ("ragged", "burst", "verify"))
+    assert {s[5]["arena_in_place"] for s in dispatches} == {in_place}
+    assert eng.metrics()["serving/prefill_arena_in_place"] == in_place
     assert sum(s[5]["emitted"] for s in run.named("serving/step")) == eng.generated_tokens
     firsts = sum(s[5]["first_tokens"] for s in run.named("serving/prefill_commit"))
     decoded = sum(s[5]["emitted"] for s in run.named("serving/emit"))
@@ -343,9 +348,14 @@ def test_the_ring_holds_a_whole_run_of_iterations(model_and_params):
     79 traced iterations admit; PERF.md section 6), and 6,000 of the
     state-space cell's (53% admit), which runs the most iterations at the
     pace of PR 37: 1,100 of warm-in and a 51 s window at 26 ms an
-    iteration, 3,100 in all, against 2,500 before. A span added to the
-    iteration shows here before a benchmark run loses its ring-read metrics
-    to a wrapped ring."""
+    iteration, 3,100 in all, against 2,500 before. At the pace of PR 39
+    (a pack iteration near 30 ms where it was 62-80) EvaByte's cell runs the
+    most: 1,000 of warm-in and a window at 17 ms an iteration, 3,631 in a
+    traced run of 40 s that held 31,111 spans (PERF.md section 6), 4,000 in
+    one of 51 s, 38% of them admitting. ``arena_in_place`` on the prefill
+    dispatch is an attribute and no span: the counts stand. A span added to
+    the iteration shows here before a benchmark run loses its ring-read
+    metrics to a wrapped ring."""
     model, cfg, params = model_and_params
     eng = _engine(model, cfg, params, kernels=True)
     eng.warmup()
@@ -371,3 +381,4 @@ def test_the_ring_holds_a_whole_run_of_iterations(model_and_params):
     assert spans_mod.RING_SPANS >= 5000 * per_admitting
     assert spans_mod.RING_SPANS >= 6000 * (43 / 79 * per_admitting + 36 / 79 * 7)
     assert spans_mod.RING_SPANS >= 6000 * (0.53 * per_admitting + 0.47 * 7)
+    assert spans_mod.RING_SPANS >= 1.5 * 4000 * (0.38 * per_admitting + 0.62 * 7)  # EvaByte since PR 39, half again

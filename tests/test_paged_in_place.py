@@ -1,12 +1,14 @@
-"""The paged decode step that updates the arena in place
+"""The paged serving programs that update the arena in place
 (``models/decoder.arena_in_place``): the stacked cache leaves ride the layer
-scan as a carry and the paged decode kernel, given the stack and a layer
-index, writes each slot's new row into its page itself. Held against the
-threading every other call keeps (the collection split along the layers, an
-XLA scatter a layer, the kernel read-only), on the same weights and tables,
-kernels interpreted on the CPU: the same tokens and the same arena, bit for
-bit, except the parking page, which only the scatter writes (an inactive
-slot's parked row; the kernel writes nothing for a slot of live length 0).
+scan as a carry and the program's kernel, given the stack and a layer index,
+writes the new rows into their pages itself: the paged decode kernel each
+slot's row, the ragged prefill kernel a pack's rows, page by page through
+the table. Held against the threading every other call keeps (the collection
+split along the layers, an XLA scatter a layer, the kernel read-only), on the
+same weights and tables, kernels interpreted on the CPU: the same tokens and
+the same arena, bit for bit, except the parking page, which only the scatter
+writes (an inactive slot's parked row, a pack's pad rows; the kernels write
+nothing for a slot of live length 0, a padding block or a pad row).
 """
 
 import dataclasses
@@ -36,14 +38,20 @@ def optimized_xla():
     jax.config.update("jax_disable_most_optimizations", before)
 
 
-def _model(shape: str, kernel="interpret"):
+def _model(shape: str, kernel="interpret", prefill=None):
     """``mistral``: one kind, grouped queries. ``by_kind``: a full and a
     window kind, keys 192 wide (stored padded to 256 lanes), values 128,
-    partial rotary, a sink and a value scale on the window kind."""
+    partial rotary, a sink and a value scale on the window kind. ``eva``: a
+    window of 32 that closes into summaries pooled a chunk (a page) of 4.
+    ``prefill``: the packed prefill's kernel (absent: off the chip, its
+    dense reference, so the pack splits the arena by layer)."""
     common = dict(vocab_size=128, embed_dim=64, mlp_dim=128, max_seq_len=96, dtype=jnp.bfloat16,
-                  scan_layers=True, remat=False, decode_kernel=kernel)
+                  scan_layers=True, remat=False, decode_kernel=kernel, prefill_kernel=prefill)
     if shape == "mistral":
         cfg = DecoderConfig(num_layers=3, num_heads=4, num_kv_heads=2, head_dim=16, **common)
+    elif shape == "eva":
+        cfg = DecoderConfig(num_layers=3, num_heads=2, num_kv_heads=2, head_dim=16, eva_window=32, eva_chunk=4,
+                            **dict(common, max_seq_len=160))
     else:
         cfg = DecoderConfig(
             num_layers=5, num_heads=4, head_dim=192, v_head_dim=128, rope_dim=64, attn_value_scale=0.707,
@@ -100,10 +108,9 @@ def _mark() -> int:
     return max((s[0] for s in spans.snapshot()), default=0)
 
 
-def _decode_spans(mark: int) -> list:
+def _decode_spans(mark: int, name="serving/decode_dispatch") -> list:
     """``args`` of the decode dispatches closed since ``_mark()``."""
-    return [args for i, _, name, _, _, args in spans.snapshot()
-            if i > mark and name == "serving/decode_dispatch"]
+    return [args for i, _, n, _, _, args in spans.snapshot() if i > mark and n == name]
 
 
 PROMPTS = [np.arange(3, 3 + n) % 120 + 3 for n in (5, 17, 8, 30, 11)]  # five requests through three slots
@@ -162,6 +169,10 @@ def test_the_fallback_says_so(why):
     eng.generate_batched(PROMPTS[:2], max_new_tokens=4)
     mine = _decode_spans(mark)
     assert mine and all(a["arena_in_place"] == 0 for a in mine)
+    # the pack runs its dense reference off the chip: split by layer, and says so
+    packs = _decode_spans(mark, "serving/prefill_dispatch")
+    assert packs and all(a["arena_in_place"] == 0 for a in packs)
+    assert eng.metrics()["serving/prefill_arena_in_place"] == 0
 
 
 # -- one step, tables made by hand -------------------------------------------
@@ -248,3 +259,182 @@ def test_one_step_writes_the_new_row_and_nothing_else(shape, case, monkeypatch):
             diff = (b != a).any(axis=(0, 2, 4))  # [pages, rows]
             changed |= {(int(p), int(r)) for p, r in zip(*np.nonzero(diff))}
     assert changed == written
+
+
+# -- the packed prefill in place ----------------------------------------------
+
+
+@pytest.fixture(scope="module", params=["mistral", "by_kind", "eva"])
+def packed(request):
+    """Both kernels interpreted: the pack program carries the arena too."""
+    return (request.param,) + _model(request.param, prefill="interpret")
+
+
+def _pack_engine(shape, model, params, **kw):
+    if shape == "eva":  # pages of a chunk; 160 positions are 5 windows
+        kw = dict(dict(page_size=4, max_cache_len=160, num_pages=1 + 3 * 24), **kw)
+    return _engine(shape, model, params, **kw)
+
+
+# five requests through three slots: tails of one block with pad rows, of several packs in a row (the largest
+# capacity is 16 rows), two admitted in one pack; for ``eva`` (a window of 32, chunks of 4): a pack that fills and
+# pools pages (17), one that ends at a close (32), one that crosses a close (45: window by window)
+PACK_PROMPTS = [np.arange(5, 5 + n) % 120 + 3 for n in (17, 32, 45, 7, 11)]
+
+
+def test_packs_then_forty_steps_give_the_same_tokens_and_the_same_arena(packed, monkeypatch):
+    """Engines side by side, both programs in place against both split by
+    layer: the same tokens over forty decode steps after the packs, every
+    page but the parking page equal, and the in-place engine says so in its
+    gauges and on every dispatch span of both programs."""
+    shape, model, params = packed
+    eng = _pack_engine(shape, model, params)
+    m = eng.metrics()
+    assert m["serving/prefill_kernel_active"] and m["serving/prefill_arena_in_place"] == 1
+    assert m["serving/arena_in_place"] == 1
+    mark = _mark()
+    got, arena = _serve(eng, PACK_PROMPTS)
+    packs = _decode_spans(mark, "serving/prefill_dispatch")
+    assert len(packs) >= 8 and all(a["arena_in_place"] == 1 for a in packs)
+    assert all(a["arena_in_place"] == 1 for a in _decode_spans(mark))
+    if shape == "eva":
+        assert sum(a["pages_pooled"] for a in packs) >= 4 + 8 + 11 + 1 + 2  # whole chunks of each prompt
+    _split_by_layer(monkeypatch)
+    want, arena_before = _serve(_pack_engine(shape, model, params), PACK_PROMPTS)
+    for g, w, prompt in zip(got, want, PACK_PROMPTS):
+        assert len(g) == len(prompt) + 40
+        np.testing.assert_array_equal(g, w)
+    _assert_same_arena(arena, arena_before)
+
+
+def test_a_quantized_cache_keeps_the_split_form_and_says_so():
+    """Quantized pages: the prefill kernel returns payloads and scales for
+    XLA's scatter, as before, under both programs' counters."""
+    model, params = _model("mistral", prefill="interpret")
+    eng = _engine("mistral", model, params, kv_cache_dtype="int8")
+    m = eng.metrics()
+    assert m["serving/prefill_kernel_active"] and m["serving/prefill_arena_in_place"] == 0
+    mark = _mark()
+    eng.generate_batched(PROMPTS[:2], max_new_tokens=4)
+    packs = _decode_spans(mark, "serving/prefill_dispatch")
+    assert packs and all(a["arena_in_place"] == 0 for a in packs)
+    assert all(a["arena_in_place"] == 0 for a in _decode_spans(mark))
+
+
+# -- one pack, tables made by hand --------------------------------------------
+
+BT, CAP = 8, 32  # token block and rows of the hand-made packs: four blocks
+
+
+def _one_pack(model, params, arena, shares, tables):
+    """One packed prefill call: ``shares`` is [(slot, history, ids)], each
+    slot's rows from position ``history`` on, padded to the token block.
+    Returns the live rows' logits and the arena."""
+    ids, row_slot, row_pos = np.zeros(CAP, np.int32), np.full(CAP, -1, np.int32), np.full(CAP, -1, np.int32)
+    n_slots = next(iter(tables.values())).shape[0] if isinstance(tables, dict) else tables.shape[0]
+    hist, r = np.zeros(n_slots, np.int32), 0
+    for slot, s0, new in shares:
+        n = len(new)
+        ids[r:r + n], row_pos[r:r + n] = new, np.arange(s0, s0 + n)
+        row_slot[r:r + -(-n // BT) * BT] = slot  # a tail's pad rows keep the slot, dead through position -1
+        hist[slot] = s0
+        r += -(-n // BT) * BT
+    assert r <= CAP
+
+    @jax.jit
+    def pack(params, arena):
+        out, mutated = model.apply(
+            {"params": params, "cache": arena}, jnp.asarray(ids)[None], positions=jnp.maximum(row_pos, 0)[None],
+            use_cache=True, decode=True, cache_positions=jnp.asarray(row_pos)[None], page_table=tables,
+            ragged_slots=jnp.asarray(row_slot), slot_hist=jnp.asarray(hist), mutable=["cache"])
+        return out["logits"][0], mutated["cache"]
+
+    logits, arena = pack(params, arena)
+    return np.asarray(logits, np.float32)[row_pos >= 0], arena
+
+
+def _pack_model(shape):
+    model, params = _model(shape, prefill="interpret")
+    pages = dict(kv_page_size=PS, kv_num_pages=24)
+    cfg = dataclasses.replace(model.config, prefill_kernel_block=BT)
+    if cfg.layer_kinds:
+        cfg = dataclasses.replace(cfg, layer_kinds=tuple((n, dict(o, **pages)) for n, o in cfg.layer_kinds))
+    return model.clone(config=dataclasses.replace(cfg, max_cache_len=96, **pages)), params
+
+
+_ids = lambda n, k=0: (np.arange(n) * 7 + k) % 120 + 3
+# packs in a row: [(slot, history, ids)] each; tables: slot -> row (page 0 is the parking page)
+PACK_CASES = {
+    # two blocks, the second of 5 live rows and 3 pads; pages 1 and 2 (rows 0-4)
+    "a_tail_from_position_0": dict(tables={0: [1, 2, 3]}, packs=[[(0, 0, _ids(13))]]),
+    # history 11 (page 10 is a shared prefix, page 2 holds positions 8-10 of it): the first block's rows sit
+    # mid-page in pages 2 and 3, the second block starts in page 3, where the first ended
+    "a_tail_from_a_mid_page_prefix_hit": dict(tables={0: [10, 2, 3, 4, 5]}, packs=[[(0, 11, _ids(14))]]),
+    # one block of 5 live rows: page 4's rows 5-7 stay, and so does page 5
+    "a_last_block_with_pad_rows": dict(tables={0: [0, 0, 0], 1: [4, 5, 6]}, packs=[[(1, 0, _ids(5))]]),
+    # slot 0 from position 0, slot 2 from a history of 20 (mid-page), then a padding block (slot -1)
+    "two_co_admitted_tails": dict(tables={0: [1, 2, 3], 1: [0, 0, 0], 2: [7, 8, 9, 11, 12]},
+                                  packs=[[(0, 0, _ids(9)), (2, 20, _ids(12, 5))]]),
+    # each pack reads what the one before it wrote
+    "a_tail_of_three_packs_in_a_row": dict(
+        tables={0: [1, 2, 3, 4, 5, 6]},
+        packs=[[(0, 0, _ids(16))], [(0, 16, _ids(16, 3))], [(0, 32, _ids(9, 6))]]),
+    # the window kind (window 16) gave back the pages behind position 40's window: their entries are parking
+    "a_window_kind_whose_early_pages_were_given_back": dict(
+        tables={0: [1, 2, 3, 4, 5, 6, 7]}, window_tables={0: [0, 0, 0, 12, 13, 14, 15]},
+        packs=[[(0, 40, _ids(10))]]),
+}
+
+
+# by_kind: wide keys (192 at 256 lanes) and a window kind; one kind has no window to give pages back behind
+@pytest.mark.parametrize("shape,case", [(shape, case) for shape in ("mistral", "by_kind") for case in sorted(PACK_CASES)
+                                        if shape == "by_kind" or "window_tables" not in PACK_CASES[case]])
+def test_one_pack_writes_its_rows_and_nothing_else(shape, case, monkeypatch):
+    """A pack in place leaves the same logits and, page by page over every
+    page a slot owns, the same arena as the split form, and touches no row
+    of any page but those its live rows lie in (the parking page among
+    them, which the split form's pad rows write)."""
+    spec = PACK_CASES[case]
+    model, params = _pack_model(shape)
+
+    def table(rows):
+        t = np.zeros((len(rows), 12), np.int32)
+        for s, row in rows.items():
+            t[s, :len(row)] = row
+        return jnp.asarray(t)
+
+    full = table(spec["tables"])
+    arena0, kinds = _random_arena(model, params, full.shape[0], np.asarray(full))
+    tables = full
+    if kinds:
+        tables = {k: table(spec["window_tables"]) if "window" in k and "window_tables" in spec else full
+                  for k in kinds}
+    before = jax.tree_util.tree_map(np.asarray, arena0)
+
+    def run():
+        arena, logits = arena0, []
+        for shares in spec["packs"]:
+            out, arena = _one_pack(model, params, arena, shares, tables)
+            logits.append(out)
+        return logits, jax.tree_util.tree_map(np.asarray, arena)
+
+    logits, arena = run()
+    _split_by_layer(monkeypatch)
+    logits_split, arena_split = run()
+    for got, want in zip(logits, logits_split):
+        np.testing.assert_array_equal(got, want)
+    _assert_same_arena(arena, arena_split)
+    # in place, a row changes only where a live row of a pack was written, in every kind's pages
+    flat = jax.tree_util.tree_flatten_with_path(arena)[0]
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(before)):
+        if a.ndim != 5:
+            continue
+        own = tables
+        if kinds:
+            run_i = int(path[0].key.split("_")[1])
+            own = tables[model.config.run_configs()[run_i].cache_kind]
+        own = np.asarray(own)
+        written = {(int(own[s, p // PS]), p % PS) for shares in spec["packs"] for s, s0, new in shares
+                   for p in range(s0, s0 + len(new))}
+        diff = (a != b).any(axis=(0, 2, 4))  # [pages, rows]
+        assert {(int(p), int(r)) for p, r in zip(*np.nonzero(diff))} == written, jax.tree_util.keystr(path)

@@ -256,6 +256,45 @@ def test_walk_counts_its_pages_on_the_host():
     assert prefill_walk_pages(100, 100, 16, window=128) == 7
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_kernel_writes_the_packs_rows_into_the_stack(dtype):
+    """The pack program's own form (``layer``): the stacked pages and a layer
+    index in, each token block's live rows put into the slot's pages by the
+    kernel, the stack out; against the read-only kernel followed by the
+    scatter of its payloads. A tail from a history that ends mid-page (its
+    blocks share pages with the history and with each other), one of a block
+    with pad rows, one that starts on a page, and a padding block. Under the
+    TPU interpreter too, which models the asynchronous copies (a page read
+    during the walk, pages on their way back while the fresh phase runs) and
+    reports none of them racing."""
+    import accelerate_tpu.ops.attention as A
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+    from jax.experimental.pallas import tpu as pltpu
+
+    rng = np.random.RandomState(7)
+    args, kw, valid = _packed_case(rng, [(11, 14), (0, 5), (16, 8)], d=128, cap=40)
+    q, k_new, v_new, kp, vp = (x.astype(dtype) for x in args)
+    noise = lambda x: jnp.asarray(rng.standard_normal((2,) + x.shape), dtype).at[1].set(x)
+    k_stack, v_stack = noise(kp), noise(vp)  # the pack's layer is the stack's second
+    table, row_slot, row_pos, hist = kw["page_table"], kw["row_slot"], kw["row_pos"], kw["slot_hist"]
+    call = lambda k_pages, v_pages, interpret, **k: A._ragged_prefill_kernel_call(
+        q, k_new, v_new, k_pages, v_pages, table, row_slot, row_pos, hist, 0.25, 8, interpret, **k)
+    ref, k_pay, _, v_pay, _ = call(kp, vp, True)
+    ps = kp.shape[2]
+    page = jnp.where(valid, table[jnp.maximum(row_slot, 0), jnp.maximum(row_pos, 0) // ps], 0)
+    off = jnp.maximum(row_pos, 0) % ps
+    put = lambda stack, pay: stack.at[1, page[valid], :, off[valid]].set(pay[valid])
+    k_ref, v_ref = put(k_stack, k_pay), put(v_stack, v_pay)
+    for interpret in (True, pltpu.InterpretParams(uninitialized_memory="nan", detect_races=True)):
+        out, k_out, v_out = call(k_stack, v_stack, interpret, layer=1)
+        np.testing.assert_array_equal(np.asarray(k_out, np.float32), np.asarray(k_ref, np.float32))
+        np.testing.assert_array_equal(np.asarray(v_out, np.float32), np.asarray(v_ref, np.float32))
+        np.testing.assert_array_equal(np.asarray(out, np.float32)[:, :, valid], np.asarray(ref, np.float32)[:, :, valid])
+    assert tpu_interpreter.races.races_found is False
+    with pytest.raises(ValueError, match="unquantized pages of whole lanes"):
+        call(k_stack[..., :64], v_stack[..., :64], False, layer=1)  # compiled: a 64-wide page is padded by a copy
+
+
 class TestQuantizeOnWrite:
     @pytest.mark.parametrize("packs", [[(10, 11), (0, 9)], [(45, 10), (24, 8)]],
                              ids=["one_block_a_slot", "several_blocks_a_slot"])

@@ -108,22 +108,26 @@ def _dense_decode(chip, *, bits=0):
 
 
 def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8, dv=None, window=None,
-                    sink=False, table=None, slots=SLOTS, pages=PAGES):
+                    sink=False, table=None, slots=SLOTS, pages=PAGES, layers=None):
+    """One layer's pages, the pack's payloads returned for a scatter; with
+    ``layers`` the layers' stack and a layer index: the kernel writes the
+    pack's rows into the slots' pages and the stack is its output."""
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    kp, ks = _kv_operands(chip, (pages, kvh, ps), d, bits)
-    vp, _ = _kv_operands(chip, (pages, kvh, ps), dv or d, bits)
+    arena = (pages, kvh, ps) if layers is None else (layers, pages, kvh, ps)
+    kp, ks = _kv_operands(chip, arena, d, bits)
+    vp, _ = _kv_operands(chip, arena, dv or d, bits)
     rows = S((cap,), jnp.int32)
 
-    def fn(q, kn, vn, kp, vp, table, row_slot, row_pos, hist, ks, vs, sink):
+    def fn(q, kn, vn, kp, vp, table, row_slot, row_pos, hist, ks, vs, sink, layer):
         return A._ragged_prefill_kernel_call(
             q, kn, vn, kp, vp, table, row_slot, row_pos, hist, SM_SCALE, bt, False,
             k_scale=ks, v_scale=vs, quant_bits=bits, window=window, sink=sink,
-            value_scale=0.707 if window else 1.0)
+            value_scale=0.707 if window else 1.0, layer=layer)
 
     return fn, (S((1, h, cap, d), jnp.bfloat16), S((1, kvh, cap, d), jnp.bfloat16),
                 S((1, kvh, cap, dv or d), jnp.bfloat16), kp, vp,
                 S((slots, table or 2048 // ps), jnp.int32), rows, rows, S((slots,), jnp.int32), ks, ks,
-                S((h,), jnp.float32) if sink else None)
+                S((h,), jnp.float32) if sink else None, None if layers is None else S((), jnp.int32))
 
 
 def _moe_experts(chip, *, rows, held=16, d=4096, m=2048):
@@ -201,6 +205,12 @@ CASES = {
         _ragged_prefill, dict(kvh=8, bt=64, slots=32, pages=3584, table=256)),
     "ragged_prefill_serving_cell_64_rows": (
         _ragged_prefill, dict(kvh=8, bt=64, cap=64, slots=32, pages=3584, table=256)),
+    # the pack program's own form: the kernel writes the pack's pages, the stack aliased to its output
+    "ragged_prefill_serving_cell_in_place": (
+        _ragged_prefill, dict(kvh=8, bt=64, slots=32, pages=3584, table=256, layers=16)),
+    "ragged_prefill_serving_cell_64_rows_in_place": (
+        _ragged_prefill, dict(kvh=8, bt=64, cap=64, slots=32, pages=3584, table=256, layers=16)),
+    "ragged_prefill_bf16_block8_page8_in_place": (_ragged_prefill, dict(kvh=8, ps=8, layers=2)),
     # ... and with --kv-cache-dtype int8 / int4 (no cell yet: ROADMAP R9)
     "ragged_prefill_serving_cell_int8": (
         _ragged_prefill, dict(kvh=8, bt=64, slots=32, pages=3584, table=256, bits=8)),
@@ -231,6 +241,11 @@ CASES = {
     "ragged_prefill_mimo_cell_window_kind": (
         _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, slots=64, pages=1024, table=512, window=128,
                               sink=True)),
+    "ragged_prefill_mimo_cell_full_kind_in_place": (
+        _ragged_prefill, dict(h=64, kvh=4, d=256, dv=128, bt=64, slots=64, pages=16384, table=512, layers=2)),
+    "ragged_prefill_mimo_cell_window_kind_in_place": (
+        _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, slots=64, pages=1024, table=512, window=128,
+                              sink=True, layers=5)),
     "ragged_prefill_mimo_cell_window_kind_64_rows": (
         _ragged_prefill, dict(h=64, kvh=8, d=256, dv=128, bt=64, cap=64, slots=64, pages=1024, table=512,
                               window=128, sink=True)),
@@ -247,6 +262,8 @@ CASES = {
         _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=256, slots=128, pages=16384, table=512)),
     "ragged_prefill_one_kv_head_group20_64_rows": (
         _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=64, slots=128, pages=16384, table=512)),
+    "ragged_prefill_one_kv_head_group20_in_place": (
+        _ragged_prefill, dict(h=20, kvh=1, bt=64, cap=256, slots=128, pages=16384, table=512, layers=1)),
     # a closing window with pooled summaries at published widths (benchmarks/configs/evabyte-6.5b-serve-8l.json):
     # 32 kv heads and a query group of one over entry lists, 16 slots, a table of 1280; the pooling of a decode
     # step's filled pages in the carried stack (a pack pools by XLA's gather and scatter)
@@ -254,6 +271,8 @@ CASES = {
         _paged_decode, dict(slots=16, pages=1792, table=1280, layers=8, write=True)),
     "ragged_prefill_32_kv_heads_closing_cell": (
         _ragged_prefill, dict(bt=64, slots=16, pages=1792, table=1280)),
+    "ragged_prefill_32_kv_heads_closing_cell_in_place": (
+        _ragged_prefill, dict(bt=64, slots=16, pages=2112, table=1280, layers=8)),
     "eva_pool_decode_step_16_slots": (_eva_pool, dict(steps=16)),
     # dense-arena decode (single-stream generate(), the flat slot arena)
     "dense_decode_bf16": (_dense_decode, dict(bits=0)),
@@ -361,41 +380,60 @@ def _small_model(by_kind: bool):
         layer_pattern=(0, 1, 1, 0, 1), **common))
 
 
+def _small_eva_model():
+    """A closing window with pooled summaries (ops/eva.py) at a page the chip
+    takes: chunks and pages of 16, a window of 256, heads of 128."""
+    from accelerate_tpu.models import DecoderConfig, DecoderLM
+
+    return DecoderLM(DecoderConfig(
+        vocab_size=512, embed_dim=256, num_heads=2, num_kv_heads=2, head_dim=128, mlp_dim=512, max_seq_len=1024,
+        dtype=jnp.bfloat16, scan_layers=True, remat=False, num_layers=3, eva_window=256, eva_chunk=16))
+
+
 @pytest.mark.parametrize("threading", ["in_place", "split_by_layer"])
-@pytest.mark.parametrize("by_kind", [False, True], ids=["one_kind", "by_kind"])
-def test_the_decode_step_holds_one_arena(chip, monkeypatch, by_kind, threading):
-    """The whole decode step of a small paged engine, compiled for the chip:
-    with the arena carried through the layer scan and written by the kernel,
-    no operation of the program has the stacked arena's or a layer's pages'
-    shape as its result but the kernel itself (no slice out of the stack, no
-    copy to the scatter's layout and back, no update-slice, no scatter), and
-    its temporaries are less than one layer's pages. ``split_by_layer`` is
-    the control, the threading every other call keeps: the same check finds
-    them all there."""
+@pytest.mark.parametrize("shape", ["one_kind", "by_kind", "eva"])
+@pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
+def test_the_decode_step_holds_one_arena(chip, monkeypatch, program, shape, threading):
+    """A whole serving program of a small paged engine, compiled for the
+    chip: the decode step, and the packed prefill at 256 rows. With the arena
+    carried through the layer scan and written by the program's kernel, no
+    operation of the program has the stacked arena's or a layer's pages'
+    shape as its result but the kernels themselves (no slice out of the
+    stack, no copy to the scatter's layout and back, no update-slice, no
+    scatter), and its temporaries are less than one layer's pages.
+    ``split_by_layer`` is the control, the threading every other call keeps:
+    the same check finds them all there. ``eva``: a closing window, whose
+    filled pages the ``eva_pool`` kernel pools in the same carried stack."""
     import re
 
     import accelerate_tpu.models.decoder as decoder
     from accelerate_tpu.parallel.sharding import unbox_params
     from accelerate_tpu.serving import ServingEngine
 
-    model = _small_model(by_kind)
+    model = _small_eva_model() if shape == "eva" else _small_model(shape == "by_kind")
     params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
     params = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if threading == "split_by_layer":
         monkeypatch.setattr(decoder, "arena_in_place", lambda *a, **k: False)
-    eng = ServingEngine(model, params, num_slots=8, max_cache_len=512, page_size=16, num_pages=1025,
-                        prefix_cache=False, **({"kind_pages": {"window32": 513}} if by_kind else {}))
-    assert eng.metrics()["serving/decode_kernel_active"]
+    sizes = {"one_kind": dict(max_cache_len=512, num_pages=1025),
+             "by_kind": dict(max_cache_len=512, num_pages=1025, kind_pages={"window32": 513}),
+             "eva": dict(max_cache_len=1024, num_pages=1025)}[shape]
+    eng = ServingEngine(model, params, num_slots=8, page_size=16, prefix_cache=False, **sizes)
+    assert eng.metrics()["serving/decode_kernel_active"] and eng.metrics()["serving/prefill_kernel_active"]
     S = lambda tree: jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
-    step = jax.jit(eng._step_core, donate_argnums=(1, 2, 3, 5))
+    if program == "decode_step":
+        fn = jax.jit(eng._step_core, donate_argnums=(1, 2, 3, 5))
+        args = (eng.params, eng._arena, eng._tokens, eng._lengths, eng._active, eng._rngs, eng._tables_arg())
+    else:
+        fn = jax.jit(eng._ragged_prefill_fn(256).__wrapped__, donate_argnums=(1,))
+        args = eng._ragged_warm_args(256)
     # the suite compiles with most XLA optimizations off; this is about what they leave
     unoptimized = jax.config.values["jax_disable_most_optimizations"]
     jax.config.update("jax_disable_most_optimizations", False)
     try:
-        compiled = step.lower(*S((eng.params, eng._arena, eng._tokens, eng._lengths, eng._active, eng._rngs,
-                                  eng._tables_arg()))).compile()
+        compiled = fn.lower(*S(args)).compile()
     finally:
         jax.config.update("jax_disable_most_optimizations", unoptimized)
     text = compiled.as_text()
@@ -406,12 +444,16 @@ def test_the_decode_step_holds_one_arena(chip, monkeypatch, by_kind, threading):
     found = sorted({m.group(2) for m in moved.finditer(text)})
     one_layer = min(x.nbytes // x.shape[0] for x in paged)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert "attn" in _kernel_names(text)  # what decode_attn_roofline_pct finds the kernel by
+    # what decode_attn_roofline_pct and prefill's readers find the kernels by
+    kernels = {"decode_step": {"attn"}, "packed_prefill": {"ragged_prefill_attn"}}[program]
+    if shape == "eva" and threading == "in_place":  # (split by layer, XLA's gather and scatter pool)
+        kernels = kernels | {"eva_pool"}
+    assert kernels <= _kernel_names(text)
+    gauge = {"decode_step": "serving/arena_in_place", "packed_prefill": "serving/prefill_arena_in_place"}[program]
+    assert eng.metrics()[gauge] == 1  # (the engine's own view is not patched)
     if threading == "in_place":
-        assert eng.metrics()["serving/arena_in_place"] == 1
         assert not found and temp < one_layer, (found, temp, one_layer)
     else:
-        assert eng.metrics()["serving/arena_in_place"] == 1  # the engine's own view is not patched
         # (its temporaries say nothing at this size: the compiler keeps them in VMEM, 128 MiB on a v5e;
         # at the serving cell's size they are 4.16 GiB against 0.3 MiB: PERF.md, PR 29)
         assert {"copy", "dynamic-update-slice"} <= set(found), found
