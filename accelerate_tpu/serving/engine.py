@@ -36,6 +36,7 @@ so ``generate_batched()`` output is token-for-token equal to sequential
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -49,6 +50,7 @@ import numpy as np
 
 from ..generation import _sample, _sized_definition, depipeline
 from ..telemetry.spans import emit as _emit_span
+from ..telemetry.spans import record_gc as _record_gc
 from ..telemetry.spans import span as _span
 from ..models.decoder import MOE_LOAD, arena_in_place
 from ..ops.attention import (
@@ -612,6 +614,7 @@ class ServingEngine:
         self._steady_mark = None
         self._exe_mem: Optional[dict] = None
         self._capacity_model = None  # lazy CapacityModel (metrics())
+        _record_gc()  # the collector's pauses as host/gc spans, once a process
 
         if telemetry is None:
             from ..telemetry import current_session
@@ -1894,28 +1897,9 @@ class ServingEngine:
         th = self._tables_host
         th.reset_slot(slot)
         cold_chunks = len(self._plan_chunks(seq.size))
-        hit_len = 0
-        entry = None
+        hit_len, entry = 0, None
         if self._prefix is not None:
-            hit_len, entry = self._prefix.lookup(seq, limit=seq.size - 1)
-            # the tail plan must still fit the slot (its padded cover can
-            # exceed the whole-prompt cover when the tail is tiny)
-            while hit_len and (
-                hit_len + self._plan_cover(seq.size - hit_len)
-                > self.max_cache_len
-            ):
-                hit_len = max(0, hit_len - self.page_size)
-            # a hit whose tail needs MORE prefill dispatches than the cold
-            # plan (e.g. cached 64 of a 256 prompt that cold-plans as one
-            # 256 chunk but tail-plans as three 64s) is a TTFT loss, not a
-            # win — decline it
-            if hit_len and (
-                len(self._plan_chunks(seq.size - hit_len)) > cold_chunks
-            ):
-                hit_len = 0
-            if hit_len == 0:
-                entry = None
-            self._prefix.record_hit(hit_len, entry)
+            hit_len, entry = self._lookup_prefix(req, seq, cold_chunks)
         usage = self._usage()
         if entry is not None:
             n_map = -(-hit_len // self.page_size)
@@ -1946,19 +1930,74 @@ class ServingEngine:
         tail_plan = self._plan_chunks(seq.size - hit_len)
         return [(hit_len + start, bucket) for start, bucket in tail_plan]
 
+    def _prefix_work(self) -> tuple:
+        """The prefix cache's work counters now, for a span to difference:
+        ``(probes, ghost_probes, hashed_tokens, evictions, evict_scanned)``,
+        the first and third with the ghost shadows' own digests among them.
+        Zeros in an engine without a prefix cache."""
+        cache = self._prefix
+        if cache is None:
+            return (0, 0, 0, 0, 0)
+        ghost_n, ghost_tokens = (
+            (cache.ghost.digests, cache.ghost.digested_tokens) if cache.ghost is not None else (0, 0))
+        return (cache.digests + ghost_n, ghost_n, cache.digested_tokens + ghost_tokens,
+                cache.evictions, cache.evict_scanned)
+
+    def _lookup_prefix(self, req: Request, seq: np.ndarray, cold_chunks: int):
+        """The longest cached prefix of ``seq`` the admission commits to,
+        as ``(hit_len, entry)``, under its own ``serving/prefix_lookup``
+        span (the counts of the work at the same boundary)."""
+        cache = self._prefix
+        with _span("serving/prefix_lookup", request_id=req.id,
+                   entries=len(cache.entries)) as sp:
+            work0 = self._prefix_work()
+            hit_len, entry = cache.lookup(seq, limit=seq.size - 1)
+            # the tail plan must still fit the slot (its padded cover can
+            # exceed the whole-prompt cover when the tail is tiny)
+            while hit_len and (
+                hit_len + self._plan_cover(seq.size - hit_len)
+                > self.max_cache_len
+            ):
+                hit_len = max(0, hit_len - self.page_size)
+            # a hit whose tail needs MORE prefill dispatches than the cold
+            # plan (e.g. cached 64 of a 256 prompt that cold-plans as one
+            # 256 chunk but tail-plans as three 64s) is a TTFT loss, not a
+            # win — decline it
+            if hit_len and (
+                len(self._plan_chunks(seq.size - hit_len)) > cold_chunks
+            ):
+                hit_len = 0
+            if hit_len == 0:
+                entry = None
+            cache.record_hit(hit_len, entry)
+            probes, ghost_probes, hashed, *_ = (
+                b - a for a, b in zip(work0, self._prefix_work()))
+            sp.args.update(probes=probes, ghost_probes=ghost_probes,
+                           hashed_tokens=hashed, hit_tokens=hit_len)
+        return hit_len, entry
+
     def _insert_prefix(self, req: Request, slot: int):
         """Admission finished: publish this prompt's pages to the prefix
-        cache (every page-aligned prefix + the full prompt). The request's
-        own boundary page becomes shared here — its first decode write
-        into that page forks it, leaving the cached copy pristine."""
-        if self._prefix is None:
+        cache (every page-aligned prefix + the full prompt), under a
+        ``serving/prefix_insert`` span. The request's own boundary page
+        becomes shared here — its first decode write into that page forks
+        it, leaving the cached copy pristine."""
+        cache = self._prefix
+        if cache is None:
             return
         n_pages = -(-req.prompt.size // self.page_size)
         if n_pages > self._tables_host.alloc_count[slot]:
             return  # cannot happen post-prefill; guard for safety
-        self._prefix.insert(
-            req.prompt, self._tables_host.rows[slot], tenant=req.tenant
-        )
+        with _span("serving/prefix_insert", request_id=req.id) as sp:
+            work0 = self._prefix_work()
+            cache.insert(
+                req.prompt, self._tables_host.rows[slot], tenant=req.tenant
+            )
+            probes, _, hashed, evictions, scanned = (
+                b - a for a, b in zip(work0, self._prefix_work()))
+            sp.args.update(probes=probes, hashed_tokens=hashed,
+                           evictions=evictions, evict_scanned=scanned,
+                           entries=len(cache.entries))
 
     def _release_slot_pages(self, slot: int, tenant: Optional[str] = None):
         """Eviction: drop the slot's page references (pages still retained
@@ -2443,6 +2482,23 @@ class ServingEngine:
             return work  # nothing to admit, or progress without a dispatch
         return self._ragged_dispatch(tr, *work)
 
+    @contextlib.contextmanager
+    def _page_grow_span(self, req: Request):
+        """``serving/page_grow`` around the page growth for one packed
+        request's rows (allocation, copy-on-write forks, the table programs),
+        with what it did: pages allocated, prefix entries evicted under
+        pressure and the entries ``evict_lru`` looked at to find them."""
+        with _span("serving/page_grow", request_id=req.id) as sp:
+            pages0 = self.pages_allocated
+            *_, evictions0, scanned0 = self._prefix_work()
+            try:
+                yield
+            finally:
+                *_, evictions, scanned = self._prefix_work()
+                sp.args.update(
+                    pages_allocated=self.pages_allocated - pages0,
+                    evictions=evictions - evictions0, evict_scanned=scanned - scanned0)
+
     def _retry_writable(self, req: Request, slot: int, lo: int, hi: int) -> bool:
         try:
             self._ensure_writable(req, slot, lo, hi)
@@ -2550,7 +2606,9 @@ class ServingEngine:
             n = min(n, window - cur % window)
         if self._faults is not None:
             self._faults.before_prefill(self)
-        if not self._admission_writable(req, slot, cur, cur + n - 1):
+        with self._page_grow_span(req):
+            writable = self._admission_writable(req, slot, cur, cur + n - 1)
+        if not writable:
             return True
         # packs: [request, slot, s0, s1, seq, primary]. The primary may be
         # mid-tail (longer than the largest grid); co-admitted tails are
@@ -2585,7 +2643,8 @@ class ServingEngine:
                 hit2 = plan2[0][0]
                 n2 = int(nxt.prompt.size) - hit2
                 try:
-                    self._ensure_writable(nxt, slot2, hit2, hit2 + n2 - 1)
+                    with self._page_grow_span(nxt):
+                        self._ensure_writable(nxt, slot2, hit2, hit2 + n2 - 1)
                 except PagePressure:
                     # back out this co-admission and requeue at the head:
                     # it re-admits alone next iteration, where the full
@@ -2602,30 +2661,30 @@ class ServingEngine:
                 packs.append([nxt, slot2, hit2, hit2 + n2, nxt.prompt, False])
                 used += -(-n2 // bt) * bt
         rcap = next(c for c in self._ragged_caps if c >= used)
-        ids = np.zeros((1, rcap), np.int32)
-        row_slot = np.full((rcap,), -1, np.int32)
-        row_pos = np.full((rcap,), -1, np.int32)
-        hist = np.zeros((self.num_slots,), np.int32)
-        last_rows = np.zeros((self.num_slots,), np.int32)
-        fresh = 0
-        r = 0
-        for preq, psl, s0, s1, pseq, _ in packs:
-            nseg = s1 - s0
-            nb = -(-nseg // bt)
-            ids[0, r:r + nseg] = pseq[s0:s1]
-            # pad rows of a pack's LAST block keep the slot id (the
-            # kernel reads the block's first row to name its slot; pads
-            # are dead through pos = -1, not slot = -1)
-            row_slot[r:r + nb * bt] = psl
-            row_pos[r:r + nseg] = np.arange(s0, s1)
-            hist[psl] = s0
-            last_rows[psl] = r + nseg - 1
-            r += nb * bt
-            fresh += nseg
-        ids_dev = jnp.asarray(ids)
-        self._note_forensics(f"ragged_prefill_{rcap}", {"ids": ids_dev})
-        return (packs, rcap, fresh, ids_dev, jnp.asarray(row_slot),
-                jnp.asarray(row_pos), jnp.asarray(hist), jnp.asarray(last_rows))
+        with _span("serving/pack_upload", rows=rcap):
+            ids = np.zeros((1, rcap), np.int32)
+            row_slot = np.full((rcap,), -1, np.int32)
+            row_pos = np.full((rcap,), -1, np.int32)
+            hist = np.zeros((self.num_slots,), np.int32)
+            last_rows = np.zeros((self.num_slots,), np.int32)
+            fresh = 0
+            r = 0
+            for preq, psl, s0, s1, pseq, _ in packs:
+                nseg = s1 - s0
+                nb = -(-nseg // bt)
+                ids[0, r:r + nseg] = pseq[s0:s1]
+                # pad rows of a pack's LAST block keep the slot id (the
+                # kernel reads the block's first row to name its slot; pads
+                # are dead through pos = -1, not slot = -1)
+                row_slot[r:r + nb * bt] = psl
+                row_pos[r:r + nseg] = np.arange(s0, s1)
+                hist[psl] = s0
+                last_rows[psl] = r + nseg - 1
+                r += nb * bt
+                fresh += nseg
+            ids_dev, *rest = map(jnp.asarray, (ids, row_slot, row_pos, hist, last_rows))
+            self._note_forensics(f"ragged_prefill_{rcap}", {"ids": ids_dev})
+        return (packs, rcap, fresh, ids_dev, *rest)
 
     def _ragged_dispatch(self, tr, packs: list, rcap: int, fresh: int, ids_dev,
                          row_slot, row_pos, hist, last_rows) -> bool:
@@ -3258,9 +3317,6 @@ class ServingEngine:
         out["serving/arena_in_place"] = int(self._arena_in_place)
         out["serving/prefill_arena_in_place"] = int(self._prefill_in_place)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
-        # ... and that kernel walks a slot's live pages in blocks out of HBM
-        # (the one form of it there is: the same bit under the mechanism's name)
-        out["serving/prefill_page_walk"] = int(self._prefill_kernel_costed)
         if self._state_kind is not None:
             # the state a slot keeps beside its pages (of arena_bytes), whether
             # its recurrence runs the ssm_scan kernel, and whether the programs
@@ -3286,7 +3342,6 @@ class ServingEngine:
         if self._prefix is not None:
             out["serving/prefix_hit_ratio"] = self._prefix.hit_ratio
             out["serving/prefix_hit_tokens"] = self._prefix.hit_tokens
-            out["serving/prefix_entries"] = len(self._prefix.entries)
             out["serving/prefill_chunks_skipped"] = self.prefill_chunks_skipped
             if self._prefix.ghost is not None:
                 # ghost-cache economics: the hit ratio the prefix

@@ -263,6 +263,11 @@ class GhostCache:
         }
         self.lookups = 0
         self.reuses = 0
+        # what the shadows themselves cost an admission: the digests
+        # observe_lookup computes and the tokens they read (lifetime;
+        # ghost_probes of the serving/prefix_lookup span)
+        self.digests = 0
+        self.digested_tokens = 0
         self._evicted: dict = {}  # key -> lookup count at eviction
         self._evicted_cap = max(self.multiples) * int(base_entries)
         self._distances: list = []
@@ -277,6 +282,8 @@ class GhostCache:
             d = memo.get(length)
             if d is None:
                 d = memo[length] = _digest(prompt[:length])
+                self.digests += 1
+                self.digested_tokens += length
             return d
 
         for shadow in self.shadows.values():
@@ -355,6 +362,15 @@ class PrefixCache:
         self.lookups = 0
         self.hits = 0
         self.hit_tokens = 0
+        # the work done, counted where it is done (lifetime; the engine's
+        # serving/prefix_lookup, prefix_insert and page_grow spans take the
+        # differences): digests computed by peek and insert and the tokens
+        # they read (the ghost shadows count their own), entries evicted,
+        # and entries evict_lru looked at to find them
+        self.digests = 0
+        self.digested_tokens = 0
+        self.evictions = 0
+        self.evict_scanned = 0
         # demote-on-evict hook: called with the victim PrefixEntry
         # BEFORE its page refs are released (the pages are still intact
         # on device, so the hook can gather them into a lower tier)
@@ -406,6 +422,8 @@ class PrefixCache:
         for length in self._candidate_lengths():
             if length > n:
                 continue
+            self.digests += 1
+            self.digested_tokens += length
             entry = self.entries.get(_digest(prompt[:length]))
             if entry is not None and entry.token_len == length:
                 return length, entry
@@ -435,6 +453,8 @@ class PrefixCache:
         if n % ps:
             lengths.append(n)  # partial-page tail: the COW-fork case
         keyed = [(length, _digest(prompt[:length])) for length in lengths]
+        self.digests += len(lengths)
+        self.digested_tokens += sum(lengths)
         created = 0
         for length, key in keyed:
             hit = self.entries.get(key)
@@ -465,6 +485,8 @@ class PrefixCache:
         lower tiers first — eviction demotes instead of dropping."""
         if not self.entries:
             return False
+        self.evictions += 1
+        self.evict_scanned += len(self.entries)
         key = min(self.entries, key=lambda k: self.entries[k].last_used)
         entry = self.entries.pop(key)
         if self.on_evict is not None:

@@ -36,6 +36,7 @@ viewers render them — so no name mangling is needed.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -45,21 +46,26 @@ from typing import Optional
 
 # The ring holds a whole run of the serving engine, so that a reader of
 # one (benchmarks/program_spans.py) never finds its beginning overwritten.
-# An iteration closes about 10 spans on the serving cells' mixes (7 when it
-# only decodes: serving/step and its six phases; when it admits, three more
-# around the prefill dispatch, a serving/prefill_chunk a packed request,
-# and serving/queue_wait and serving/first_token a request;
-# tests/test_engine_spans.py counts them; one dispatch in flight, PR 37,
-# changed their order and not their number), so 65,536 hold 6,500
-# iterations. The longest run is the state-space cell's: 1,100 warm-in
-# iterations and a 51 s window at the 26 ms a decode-only iteration takes
-# with the host's work hidden, 3,100 in all; the ring would hold the 800
-# warm-in iterations of MiMo's mix and 51 s at 8.9 ms an iteration, less
-# than the 9.2 ms a decode step takes to read a 7B model's 16 layers of
-# weights once. 16,384 wrapped inside a run as soon as an iteration fell
-# from 66 to 55 ms (PERF.md, PR 33). No knob: a deque's append costs the
-# same at any length, and 65,536 tuples with their args are tens of MB of
-# host memory at the most.
+# An iteration closes 7 spans when it only decodes (serving/step and its six
+# phases) and 13 to 15 when it admits: three more phases around the prefill
+# dispatch, a serving/pack_upload a pack, a serving/page_grow and a
+# serving/prefill_chunk a packed request, and serving/queue_wait,
+# serving/first_token, serving/prefix_lookup and serving/prefix_insert a
+# request (tests/test_engine_spans.py counts them). On the serving cells'
+# mixes that is 9.4 to 13.4 spans an iteration, and a traced run of 45 s
+# with its warm-in holds 18,997 (re-ask, 1,420 iterations) to 33,990
+# (EvaByte, 3,636 iterations at 12.5 ms; 32,727 in the state-space cell's
+# 2,891) of the 65,536 (PERF.md, PR 40): the longest stays under two thirds
+# of the ring, 43,690, which is the mark at which to raise the constant. The
+# mark is for a run of the benchmark's length, its ``run_seconds`` of 45 and a
+# cell's warm-in before them: a shorter iteration (ROADMAP S15) or a longer
+# run moves the count with it, and tests/test_engine_spans.py fails first.
+# 16,384 wrapped inside a run as soon as an iteration fell from 66 to 55 ms
+# (PERF.md, PR 33). No knob: a deque's append costs the same at any length,
+# and 65,536 tuples with their args are tens of MB of host memory at the most.
+# Not raised ahead of need either: a full ring is 130,000 objects that every
+# full collection of a long-running server walks, 5 ms more a collection at
+# 65,536 entries and 10 at 131,072 (a CPU's figure; PERF.md, PR 40).
 RING_SPANS = 65536
 
 _RECORDER: Optional["SpanRecorder"] = None
@@ -72,6 +78,8 @@ _closed = 0  # spans ever recorded; what the ring no longer holds was dropped
 
 def _record(span_id, parent_id, name, t0, t1, args, cat):
     global _closed
+    if _gc_pending:
+        _flush_gc()
     with _ring_lock:
         _ring.append((span_id, parent_id, name, t0, t1, args))
         _closed += 1
@@ -83,12 +91,16 @@ def _record(span_id, parent_id, name, t0, t1, args, cat):
 def snapshot() -> list:
     """The ring's content, oldest first: ``(id, parent_id, name, t0, t1,
     args)`` tuples on ``time.perf_counter`` (``args`` a dict or None)."""
+    if _gc_pending:
+        _flush_gc()
     with _ring_lock:
         return list(_ring)
 
 
 def dropped() -> int:
     """Spans the ring has forgotten (it wrapped) since the process began."""
+    if _gc_pending:
+        _flush_gc()
     with _ring_lock:
         return _closed - len(_ring)
 
@@ -96,6 +108,8 @@ def dropped() -> int:
 def last_spans(n: int = 16) -> list:
     """The most recently closed spans (newest last) as ``{"name",
     "end_unix_s", "dur_s"}`` — what a stall report prints."""
+    if _gc_pending:
+        _flush_gc()
     with _ring_lock:
         recent = list(itertools.islice(reversed(_ring), max(n, 0)))[::-1]
     unix_minus_perf = time.time() - time.perf_counter()
@@ -127,6 +141,59 @@ def emit(name: str, t0: float, dur_s: float, args: Optional[dict] = None,
     """Record a span that was timed elsewhere (a queue wait: the stamps lie
     iterations apart). Its parent is the span open on this thread now."""
     _record(next(_ids), _parent_id(), name, t0, t0 + dur_s, args, cat)
+
+
+# A collection of Python's garbage collector that took this long or longer
+# is a span of its own, ``host/gc``; the shorter ones (the young generation's,
+# tens of microseconds, some hundred a second under a serving engine) are not.
+GC_SPAN_S = 1e-3
+_gc_t0 = 0.0  # collections do not nest, and one thread collects at a time
+# The pauses the hook has timed and no later span has carried into the ring
+# yet, as ``(ring entry, thread)``. The hook takes no lock: the interpreter
+# starts a collection wherever it checks for one, also on a thread that holds
+# ``_ring_lock`` or a recorder's lock, right after the call inside the ``with``.
+_gc_pending: list = []
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    t1 = time.perf_counter()
+    if t1 - _gc_t0 >= GC_SPAN_S:
+        args = {"generation": info["generation"], "collected": info["collected"]}
+        _gc_pending.append(((next(_ids), _parent_id(), "host/gc", _gc_t0, t1, args), threading.get_ident()))
+
+
+def _flush_gc() -> None:
+    """Move the pauses the hook has left into the ring (and the stream), ahead
+    of whatever closes next: the next ``_record`` and every reader of the ring
+    call it, so a pause lies in the ring before the span that enclosed it."""
+    global _closed
+    while _gc_pending:
+        try:
+            entry, thread = _gc_pending.pop(0)
+        except IndexError:  # another thread took it
+            return
+        with _ring_lock:
+            _ring.append(entry)
+            _closed += 1
+        rec = _RECORDER
+        if rec is not None:
+            rec.write_span(entry[2], entry[3], entry[4] - entry[3], "host", entry[5], thread=thread)
+
+
+def record_gc() -> None:
+    """From now on every garbage collection of ``GC_SPAN_S`` or longer lands
+    in the ring as ``host/gc`` (``generation``, ``collected``), its parent
+    the span open on the collecting thread: a pause inside ``serving/emit``
+    lies under it, and ahead of it in the ring (the hook itself only notes
+    the pause; the next span to close carries it in). One ``gc.callbacks``
+    hook a process, however often this is called (the first ``ServingEngine``
+    calls it); between collections it costs nothing."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 class span:
@@ -179,8 +246,9 @@ class SpanRecorder:
         })
 
     def write_span(self, name: str, t0: float, dur_s: float, cat: str = "span",
-                   args: Optional[dict] = None):
-        """One closed span (``t0`` on the perf_counter clock) to the file."""
+                   args: Optional[dict] = None, thread: Optional[int] = None):
+        """One closed span (``t0`` on the perf_counter clock) to the file, on
+        the calling thread's row unless ``thread`` names another."""
         evt = {
             "name": name,
             "ph": "X",
@@ -188,7 +256,7 @@ class SpanRecorder:
             "ts": round(max(t0 - self._epoch, 0.0) * 1e6, 3),
             "dur": round(dur_s * 1e6, 3),
             "pid": self.process_index,
-            "tid": threading.get_ident() & 0xFFFFFFFF,
+            "tid": (thread or threading.get_ident()) & 0xFFFFFFFF,
         }
         if args:
             evt["args"] = args
