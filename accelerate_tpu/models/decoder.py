@@ -662,6 +662,42 @@ def arena_in_place(config, sq: int = 1, packed: bool = False) -> bool:
     return prefill_writes_pages(config) if packed else sq == 1 and decode_kernel_active(config, sq)
 
 
+def _run_name(config, i: int) -> str:
+    """The scanned stack of run ``i`` of ``config.run_configs()`` in the
+    "params" and "cache" collections."""
+    return f"layers_{i}" if config.layer_kinds else "layers"
+
+
+def expert_stacks(config, decode: bool, params) -> dict:
+    """For every scanned run of layers with experts, by its name in
+    ``params``: the run's stacks ``(w_gate, w_up, w_down)``, each
+    ``[L, E, ...]``, where the ``moe_experts`` kernel reads the experts out
+    of them (the stack and a layer index ride the layer scan, and no layer's
+    experts are sliced out before the call: 805 MB a layer in the MiMo
+    cell), else None: the layers read their own slices. It takes the kernel
+    engaged (``models/moe.experts_impl``: a serving program on the chip, or
+    interpreted), a stack that exists (not at ``init``) and is held on the
+    device, and leaves of the layers' dtype (a cast of the stack would be
+    the copy again). The serving engine's ``experts_from_stack`` gauge reads
+    this."""
+    from .moe import experts_impl
+
+    out = {}
+    for i, run_cfg in enumerate(config.run_configs()):
+        if run_cfg.moe_num_experts <= 1:
+            continue
+        name = _run_name(config, i)
+        out[name] = None
+        if (not config.scan_layers or config.stream_layer_weights or name not in params
+                or experts_impl(run_cfg, decode) == "xla"):
+            continue
+        moe = params[name]["block"]["moe_mlp"]
+        stack = tuple(nn.unbox(moe[leaf]) for leaf in ("w_gate", "w_up", "w_down"))
+        if all(w.dtype == run_cfg.dtype for w in stack):
+            out[name] = stack
+    return out
+
+
 class DecoderMLP(nn.Module):
     config: DecoderConfig
     mesh: Optional[Mesh] = None
@@ -694,7 +730,7 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, sin, cos, deterministic: bool = True, cache_positions=None,
                  page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None,
-                 token_mask=None, cache_layer=None):
+                 token_mask=None, cache_layer=None, expert_stack=None):
         cfg = self.config
         ln1 = self.param("ln_attn", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
         ln2 = self.param("ln_mlp", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
@@ -724,7 +760,7 @@ class DecoderBlock(nn.Module):
             # the router reads the normed stream as it is carried (float32
             # with residual_dtype): its choice is discrete
             y, aux = MoeMLP(cfg, self.mesh, self.decode, name="moe_mlp")(
-                y, token_mask, router_input=y_stream)
+                y, token_mask, router_input=y_stream, stack=expert_stack)
         else:
             y = DecoderMLP(cfg, self.mesh, name="mlp")(y)
             aux = jnp.float32(0.0)
@@ -751,16 +787,19 @@ class _ScanBlock(nn.Module):
         # (broadcast inputs every layer reads unchanged); None when the
         # slot-arena / ragged-prefill paths are off. ``layer`` counts the
         # blocks where the "cache" collection is carried whole
-        # (arena_in_place), else None
-        x, aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask, layer = carry
+        # (arena_in_place), else None. ``experts``: the run's stacked expert
+        # leaves and a count of the blocks likewise (expert_stacks), else None
+        x, aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask, layer, experts = carry
         x, block_aux = DecoderBlock(self.config, self.mesh, self.use_cache, self.decode, name="block")(
             x, sin, cos, self.deterministic, cache_positions=cpos, page_table=ptab,
             ragged_slots=rslots, slot_hist=shist, kv_lengths=klens, token_mask=tmask,
-            cache_layer=layer,
+            cache_layer=layer, expert_stack=experts,
         )
         if layer is not None:
             layer = layer + 1
-        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask, layer), None
+        if experts is not None:
+            experts = (*experts[:3], experts[3] + 1)
+        return (x, aux + block_aux, sin, cos, cpos, ptab, rslots, shist, klens, tmask, layer, experts), None
 
 
 class StageStack(nn.Module):
@@ -785,7 +824,7 @@ class StageStack(nn.Module):
         )
         (x, aux, *_), _ = Stack(
             cfg, self.mesh, deterministic=deterministic, name="layers"
-        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None, None, None), None)
+        )((x, jnp.float32(0.0), sin, cos, None, None, None, None, None, None, None, None), None)
         if cfg.moe_num_experts > 1:
             # per-(stage, microbatch) router load-balance sum over this
             # stage's layers; the schedule accumulates and renormalizes
@@ -930,7 +969,10 @@ class DecoderLM(nn.Module):
             # published order: a model of one kind is the one stack
             # "layers" of before; layer kinds give "layers_0", "layers_1",
             # ..., each with its kind's widths, rotary tables and, where
-            # the cache is paged, its kind's page table
+            # the cache is paged, its kind's page table. Where the
+            # moe_experts kernel runs, a run's stacked expert leaves ride
+            # its scan beside the tables and the kernel reads them there
+            stacks = expert_stacks(cfg, decode, self.variables.get("params", {}))
             for i, run_cfg in enumerate(cfg.run_configs()):
                 if cfg.layer_kinds:
                     sin, cos = _rotary_tables(positions, run_cfg, cfg.dtype)
@@ -944,7 +986,7 @@ class DecoderLM(nn.Module):
                 # carry has to exist). A state-space run's states are
                 # carried whole in both serving programs: a pack advances a
                 # few slots of a state that is of all of them.
-                name = f"layers_{i}" if cfg.layer_kinds else "layers"
+                name = _run_name(cfg, i)
                 in_place = (use_cache and decode and page_table is not None
                             and (run_cfg.mixer == "ssm"
                                  or arena_in_place(run_cfg, s, packed=ragged_slots is not None))
@@ -964,7 +1006,8 @@ class DecoderLM(nn.Module):
                     run_cfg, self.mesh, use_cache, decode, deterministic, name=name,
                 )((x, jnp.float32(0.0), sin, cos, cache_positions, ptab,
                    ragged_slots, slot_hist, kv_lengths, token_mask,
-                   jnp.int32(0) if in_place else None), None)
+                   jnp.int32(0) if in_place else None,
+                   (*stacks[name], jnp.int32(0)) if stacks.get(name) is not None else None), None)
                 moe_aux = moe_aux + run_aux
         else:
             block_cls = _maybe_streaming(DecoderBlock, cfg)

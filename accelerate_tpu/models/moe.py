@@ -108,13 +108,13 @@ _EXPERT_TILE = 256            # columns of the expert width a kernel step holds
 _EXPERT_VMEM = 64 * 1024 * 1024
 
 
-def _experts_kernel(live_ref, gmap_ref, start_ref, x_ref, wg_ref, wu_ref, wd_ref,
+def _experts_kernel(live_ref, gmap_ref, start_ref, layer_ref, x_ref, wg_ref, wu_ref, wd_ref,
                     o_ref, acc):
     """Grid (experts, tiles of the expert width). Step (g, t) multiplies
-    every row by tile t of expert ``gmap[g]`` and keeps the rows that are
-    that expert's. Experts without a row come last in ``gmap`` as repeats
-    of the last live one, so their weights are neither fetched nor
-    multiplied."""
+    every row by tile t of expert ``gmap[g]`` of layer ``layer[0]`` and
+    keeps the rows that are that expert's. Experts without a row come last
+    in ``gmap`` as repeats of the last live one, so their weights are
+    neither fetched nor multiplied."""
     from jax.experimental import pallas as pl
 
     g, t, nt = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
@@ -130,10 +130,10 @@ def _experts_kernel(live_ref, gmap_ref, start_ref, x_ref, wg_ref, wu_ref, wd_ref
             acc[...] = jnp.zeros_like(acc)
 
         x = x_ref[...]
-        gate = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        gate = jnp.dot(x, wg_ref[0, 0], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, wu_ref[0, 0], preferred_element_type=jnp.float32)
         hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-        acc[...] += jnp.dot(hidden, wd_ref[0], preferred_element_type=jnp.float32)
+        acc[...] += jnp.dot(hidden, wd_ref[0, 0], preferred_element_type=jnp.float32)
 
         @pl.when(t == nt - 1)
         def _():
@@ -143,12 +143,18 @@ def _experts_kernel(live_ref, gmap_ref, start_ref, x_ref, wg_ref, wu_ref, wd_ref
             o_ref[...] += jnp.where(mine, acc[...], 0.0)
 
 
-def _experts_kernel_call(xs, wg, wu, wd, sizes, interpret: bool):
+def _experts_kernel_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
+    """``wg``, ``wu`` [L, E, d, m] and ``wd`` [L, E, m, d] are the layers'
+    stacks as the layer scan holds them, ``layer`` which of them to
+    multiply by: the kernel fetches its tiles out of the stack, so no
+    layer's experts are sliced out (805 MB a layer in the MiMo cell) before
+    a call that reads a few of them. One layer's leaves are a stack of one
+    (``w[None]``, layer 0: a bitcast)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     rows, d = xs.shape
-    count, _, m = wg.shape
+    _, count, _, m = wg.shape
     tile = next(c for c in (_EXPERT_TILE, 128, m) if m % c == 0)
     live = sizes > 0
     n_live = jnp.sum(live.astype(jnp.int32))
@@ -158,20 +164,20 @@ def _experts_kernel_call(xs, wg, wu, wd, sizes, interpret: bool):
     gmap = jnp.where(jnp.arange(count) < n_live, ranked, last)
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
 
-    def w_in(g, t, lv, gm, st):
-        return (gm[g], 0, jnp.where(g < lv[0], t, m // tile - 1))
+    def w_in(g, t, lv, gm, st, ly):
+        return (ly[0], gm[g], 0, jnp.where(g < lv[0], t, m // tile - 1))
 
-    def w_out(g, t, lv, gm, st):
-        return (gm[g], jnp.where(g < lv[0], t, m // tile - 1), 0)
+    def w_out(g, t, lv, gm, st, ly):
+        return (ly[0], gm[g], jnp.where(g < lv[0], t, m // tile - 1), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(count, m // tile),
         in_specs=[
             pl.BlockSpec((rows, d), lambda g, t, *_: (0, 0)),
-            pl.BlockSpec((1, d, tile), w_in),
-            pl.BlockSpec((1, d, tile), w_in),
-            pl.BlockSpec((1, tile, d), w_out),
+            pl.BlockSpec((1, 1, d, tile), w_in),
+            pl.BlockSpec((1, 1, d, tile), w_in),
+            pl.BlockSpec((1, 1, tile, d), w_out),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda g, t, *_: (0, 0)),
         scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
@@ -181,17 +187,22 @@ def _experts_kernel_call(xs, wg, wu, wd, sizes, interpret: bool):
     return pl.pallas_call(
         _experts_kernel, grid_spec=grid_spec, name="moe_experts", interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32), **params,
-    )(n_live.reshape(1), gmap, starts, xs, wg, wu, wd)
+    )(n_live.reshape(1), gmap, starts, jnp.asarray(layer, jnp.int32).reshape(1), xs, wg, wu, wd)
 
 
 def grouped_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
-                sizes: jax.Array, impl: str = "xla") -> jax.Array:
+                sizes: jax.Array, impl: str = "xla", layer=None) -> jax.Array:
     """``(silu(x Wg_e) * (x Wu_e)) Wd_e`` for rows ``xs`` [rows, d] sorted
     by expert, ``sizes`` [experts] rows each; rows past their sum come out
     as zeros. float32 [rows, d]. ``impl``: "xla" (``jax.lax.ragged_dot``),
-    "pallas" or "interpret" (the ``moe_experts`` kernel)."""
+    "pallas" or "interpret" (the ``moe_experts`` kernel). With ``layer``
+    (the kernel only), the weights are the layers' stacks ([L, E, ...]) and
+    it says which layer's experts multiply: the kernel reads them where they
+    are."""
     if impl != "xla":
-        return _experts_kernel_call(xs, wg, wu, wd, sizes, impl == "interpret")
+        if layer is None:
+            wg, wu, wd, layer = wg[None], wu[None], wd[None], 0
+        return _experts_kernel_call(xs, wg, wu, wd, sizes, layer, impl == "interpret")
     sizes = sizes.astype(jnp.int32)
     gate = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=jnp.float32)
     up = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=jnp.float32)
@@ -214,11 +225,12 @@ def experts_impl(cfg, decode: bool) -> str:
 
 
 def routed_experts(x, experts, weights, wg, wu, wd, *, first: int = 0,
-                   outputs: Optional[int] = None, token_mask=None, impl: str = "xla"):
+                   outputs: Optional[int] = None, token_mask=None, impl: str = "xla", layer=None):
     """The held experts' part of the layer's result for tokens ``x``
-    [tokens, d], and the pairs on each held expert [count]."""
+    [tokens, d], and the pairs on each held expert [count]. ``layer``: as
+    :func:`grouped_mlp` takes it."""
     tokens, d = x.shape
-    k, count = experts.shape[-1], wg.shape[0]
+    k, count = experts.shape[-1], wg.shape[-3]
     order, sizes, n_held = sort_pairs(experts, first, count, token_mask)
     rows = expert_rows(tokens, k, count, outputs or count)
     pair_token = order // k
@@ -230,7 +242,7 @@ def routed_experts(x, experts, weights, wg, wu, wd, *, first: int = 0,
         tok = jnp.take(pair_token, idx, mode="clip")
         ends = jnp.cumsum(sizes)
         here = jnp.clip(ends, lo, lo + rows) - jnp.clip(ends - sizes, lo, lo + rows)
-        out = grouped_mlp(jnp.take(x, tok, axis=0), wg, wu, wd, here, impl)
+        out = grouped_mlp(jnp.take(x, tok, axis=0), wg, wu, wd, here, impl, layer)
         w = jnp.where(idx < n_held, jnp.take(pair_weight, idx, mode="clip"), 0.0)
         return y.at[tok].add(out * w[:, None])
 
@@ -251,14 +263,18 @@ class MoeMLP(nn.Module):
     nowhere. ``router_input``: what the router reads where it is not ``x``
     (the same activations before they were rounded to the layer's dtype).
     Where the ``moe_load`` collection is mutable, the pairs on each held
-    expert are written to it."""
+    expert are written to it. ``stack``: ``(w_gate, w_up, w_down, layer)``,
+    the scanned run's stacks of the three expert leaves and this layer's
+    index in them (``models/decoder.expert_stacks``); the layer then
+    leaves its own slices of them unread, and the kernel reads the stack."""
 
     config: DecoderConfig
     mesh: Optional[Mesh] = None
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array, token_mask=None, router_input=None) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x: jax.Array, token_mask=None, router_input=None,
+                 stack=None) -> Tuple[jax.Array, jax.Array]:
         from .decoder import _constrain, _dense_init
 
         cfg = self.config
@@ -280,21 +296,25 @@ class MoeMLP(nn.Module):
                 "selection_bias",
                 nn.with_logical_partitioning(nn.initializers.zeros, ("router_experts",)),
                 (R,), jnp.float32)
-        wg = self.param(
-            "w_gate",
-            nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
-            (E, d, m),
-        )
-        wu = self.param(
-            "w_up",
-            nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
-            (E, d, m),
-        )
-        wd = self.param(
-            "w_down",
-            nn.with_logical_partitioning(_dense_init(), ("expert", "mlp", "embed")),
-            (E, m, d),
-        )
+        if stack is not None:
+            wg, wu, wd, layer = stack
+        else:
+            layer = None
+            wg = self.param(
+                "w_gate",
+                nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
+                (E, d, m),
+            ).astype(dt)
+            wu = self.param(
+                "w_up",
+                nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
+                (E, d, m),
+            ).astype(dt)
+            wd = self.param(
+                "w_down",
+                nn.with_logical_partitioning(_dense_init(), ("expert", "mlp", "embed")),
+                (E, m, d),
+            ).astype(dt)
 
         flat = x.reshape(b * s, d)
         with jax.named_scope("moe_router"):
@@ -308,9 +328,8 @@ class MoeMLP(nn.Module):
         aux_loss = jnp.mean(load_balance_loss(
             scores.reshape(b, s, R), experts.reshape(b, s, k)))
         y, sizes = routed_experts(
-            flat.astype(dt), experts, weights, wg.astype(dt), wu.astype(dt), wd.astype(dt),
-            first=first, outputs=R, token_mask=token_mask,
-            impl=experts_impl(cfg, self.decode))
+            flat.astype(dt), experts, weights, wg, wu, wd, first=first, outputs=R, token_mask=token_mask,
+            impl=experts_impl(cfg, self.decode), layer=layer)
         if self.is_mutable_collection(LOAD_COLLECTION):
             self.variable(LOAD_COLLECTION, "pairs", lambda: jnp.zeros((E,), jnp.int32)).value = sizes
         y = y.astype(dt).reshape(b, s, d)

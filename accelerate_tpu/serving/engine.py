@@ -52,7 +52,7 @@ from ..generation import _sample, _sized_definition, depipeline
 from ..telemetry.spans import emit as _emit_span
 from ..telemetry.spans import record_gc as _record_gc
 from ..telemetry.spans import span as _span
-from ..models.decoder import MOE_LOAD, arena_in_place
+from ..models.decoder import MOE_LOAD, arena_in_place, expert_stacks
 from ..ops.attention import (
     decode_kernel_active,
     paged_decode_block_pages,
@@ -462,6 +462,10 @@ class ServingEngine:
         # pack's pages (the prefill_arena_in_place gauge, arena_in_place
         # of serving/prefill_dispatch)
         self._prefill_in_place = all(arena_in_place(c, packed=True) for c in run_cfgs)
+        # ... and whether both programs' moe_experts kernel reads the experts
+        # out of their scanned stacks (the experts_from_stack gauge)
+        stacks = expert_stacks(pcfg, True, params)
+        self._experts_from_stack = bool(stacks) and None not in stacks.values()
         self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
         # packed ragged prefill (ops/attention.ragged_prefill_attention):
         # the admission planner packs every pending tail into ONE ragged
@@ -3316,6 +3320,7 @@ class ServingEngine:
         out["serving/decode_kernel_active"] = bool(self._kernel_costed)
         out["serving/arena_in_place"] = int(self._arena_in_place)
         out["serving/prefill_arena_in_place"] = int(self._prefill_in_place)
+        out["serving/experts_from_stack"] = int(self._experts_from_stack)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
         if self._state_kind is not None:
             # the state a slot keeps beside its pages (of arena_bytes), whether

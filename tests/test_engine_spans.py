@@ -41,8 +41,10 @@ NEW_TOKENS = 5
 # re-ask, batch, MiMo, the state-space cell, EvaByte): the whole of a run of the benchmark's length, which is the
 # cell's warm-in (100 to 1,100 iterations) and BENCHMARK.json's ``run_seconds``, 45 s, at the iteration's pace of
 # PR 39-40 (12.5 to 30 ms). One builder's runs: a PR that shortens the iteration or lengthens the run takes the
-# count again from a chip run's ``len(snapshot())`` and raises ``RING_SPANS`` if the longest passes two thirds
-RUNS_ON_THE_CHIP = ((1913, 0.75), (1420, 0.94), (2448, 0.34), (2032, 0.70), (2891, 0.55), (3636, 0.31))
+# count again from a chip run's ``len(snapshot())`` and raises ``RING_SPANS`` if the longest passes two thirds.
+# MiMo's is PR 41's, whose step fell from 21.3 to 11.4 ms: 3,034 iterations, 2,359 with a pack, 36,187 spans
+# held (it was (2032, 0.70) and 24,558)
+RUNS_ON_THE_CHIP = ((1913, 0.75), (1420, 0.94), (2448, 0.34), (3034, 0.78), (2891, 0.55), (3636, 0.31))
 
 PATHS = {
     "ragged": dict(page_size=8, kernels=True),

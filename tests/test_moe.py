@@ -156,6 +156,23 @@ class TestRouting:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
         assert not np.asarray(b[12:]).any()
 
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    def test_kernel_reads_one_layers_experts_out_of_the_stack(self, layer):
+        """Three layers' leaves stacked as the layer scan holds them, and a
+        traced layer index: the kernel (interpreted) on the stack is the
+        kernel on that layer's slice bit for bit and ``ragged_dot`` on it
+        within rounding, experts without a row among them, rows past the
+        groups zeros."""
+        xs = jax.random.normal(jax.random.PRNGKey(5), (16, 16))
+        stacks = [jnp.stack(ws) for ws in zip(*(_experts(6, seed=seed) for seed in range(3)))]
+        mine = [w[layer] for w in stacks]
+        sizes = jnp.asarray([3, 0, 5, 0, 0, 4], jnp.int32)
+        want = grouped_mlp(xs, *mine, sizes, "xla")
+        got = jax.jit(lambda at: grouped_mlp(xs, *stacks, sizes, "interpret", at))(jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(grouped_mlp(xs, *mine, sizes, "interpret")))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+        assert np.asarray(got[:12]).any() and not np.asarray(got[12:]).any()
+
 
 class TestMoeParity:
     def test_identical_experts_match_dense_mlp(self):
@@ -336,3 +353,89 @@ class TestMoeDecoder:
                     np.asarray(leaf), np.asarray(gdf[path]),
                     rtol=5e-4, atol=2e-5, err_msg=path,
                 )
+
+
+# -- the experts read out of their scanned stack (models/decoder.expert_stacks) --
+
+def _served_model(shape: str, kernel="interpret", experts=4, params_dtype=jnp.bfloat16):
+    """``one_kind``: three layers with experts in one scanned stack.
+    ``by_kind``: a full kind with a dense MLP and a window kind with experts,
+    a run of two and one alone. Weights in bfloat16, the layers' dtype, as a
+    server holds them."""
+    common = dict(vocab_size=128, embed_dim=64, num_heads=4, head_dim=16, max_seq_len=96, dtype=jnp.bfloat16,
+                  scan_layers=True, remat=False, decode_kernel=kernel, prefill_kernel=kernel)
+    moe = dict(mlp_dim=128, moe_num_experts=experts, moe_top_k=2 if experts else 1)
+    if shape == "one_kind":
+        cfg = DecoderConfig(num_layers=3, num_kv_heads=2, **moe, **common)
+    else:
+        cfg = DecoderConfig(
+            num_layers=5, mlp_dim=256,
+            layer_kinds=(("full", dict(num_kv_heads=1)),
+                         ("window", dict(num_kv_heads=2, attn_window=16, attn_sink=True, **moe))),
+            layer_pattern=(0, 1, 1, 0, 1), **common)
+    model = DecoderLM(cfg)
+    from accelerate_tpu.parallel.sharding import unbox_params
+
+    params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
+    return model, jax.tree_util.tree_map(lambda x: x.astype(params_dtype), params)
+
+
+def _serving_engine(shape, model, params):
+    from accelerate_tpu.serving import ServingEngine
+
+    args = dict(num_slots=3, max_cache_len=96, page_size=8, prefill_chunks=(8, 16), prefix_cache=False)
+    if shape == "by_kind":
+        args.update(num_pages=1 + 3 * 12, kind_pages={"window16": 1 + 3 * 5})
+    return ServingEngine(model, params, **args)
+
+
+@pytest.fixture
+def optimized_xla():
+    """The suite compiles with most XLA optimizations off; whole engines with
+    interpreted kernels are then far slower (tests/benchmark/conftest)."""
+    before = jax.config.values["jax_disable_most_optimizations"]
+    jax.config.update("jax_disable_most_optimizations", False)
+    yield
+    jax.config.update("jax_disable_most_optimizations", before)
+
+
+@pytest.mark.parametrize("shape", ["one_kind", "by_kind"])
+def test_tokens_and_pages_are_the_same_from_the_stack_and_from_each_layers_slice(shape, monkeypatch, optimized_xla):
+    """Engines side by side, kernels interpreted, five requests through three
+    slots (packs of several sizes, slots used again, forty decode steps): the
+    ``moe_experts`` kernel given the run's stack and a layer index serves the
+    same tokens and leaves the same arena, bit for bit, as given each layer's
+    own slice, the threading of before."""
+    import accelerate_tpu.models.decoder as decoder
+
+    model, params = _served_model(shape)
+    prompts = [np.arange(3, 3 + n) % 120 + 3 for n in (5, 17, 8, 30, 11)]
+
+    def serve():
+        eng = _serving_engine(shape, model, params)
+        outs = eng.generate_batched(prompts, max_new_tokens=40)
+        return eng, [np.asarray(o) for o in outs], jax.tree_util.tree_map(np.asarray, eng._arena)
+
+    eng, got, arena = serve()
+    m = eng.metrics()
+    assert m["serving/experts_from_stack"] == 1 and m["serving/arena_in_place"] == m["serving/prefill_arena_in_place"] == 1
+    monkeypatch.setattr(decoder, "expert_stacks", lambda *a, **k: {})
+    _, want, arena_sliced = serve()
+    assert len({tuple(w) for w in want}) > 1  # (not one token over and over)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, arena, arena_sliced)
+
+
+@pytest.mark.parametrize("case,gauge", [("experts", 1), ("by_kind", 1), ("float32_leaves", 0), ("dense_kernel", 0),
+                                        ("no_experts", 0)])
+def test_the_gauge_says_whether_the_kernel_reads_the_stack(case, gauge):
+    """``serving/experts_from_stack``: 1 where both serving programs hand the
+    kernel the scanned stacks; 0 where the leaves are not of the layers'
+    dtype (a cast of the stack would be the copy again), where the experts
+    take ``ragged_dot`` (``decode_kernel="dense"``), and for a dense model."""
+    shape = "by_kind" if case == "by_kind" else "one_kind"
+    model, params = _served_model(
+        shape, kernel="dense" if case == "dense_kernel" else "interpret", experts=0 if case == "no_experts" else 4,
+        params_dtype=jnp.float32 if case == "float32_leaves" else jnp.bfloat16)
+    assert _serving_engine(shape, model, params).metrics()["serving/experts_from_stack"] == gauge

@@ -130,12 +130,14 @@ def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8, 
                 S((h,), jnp.float32) if sink else None, None if layers is None else S((), jnp.int32))
 
 
-def _moe_experts(chip, *, rows, held=16, d=4096, m=2048):
+def _moe_experts(chip, *, rows, layers=4, held=16, d=4096, m=2048):
+    """The MiMo cell's window run: the four layers' stacks and a layer index."""
     from accelerate_tpu.models import moe
 
     S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    fn = lambda x, wg, wu, wd, sizes: moe._experts_kernel_call(x, wg, wu, wd, sizes, False)
-    return fn, (S((rows, d)), S((held, d, m)), S((held, d, m)), S((held, m, d)), S((held,), jnp.int32))
+    fn = lambda x, wg, wu, wd, sizes, layer: moe._experts_kernel_call(x, wg, wu, wd, sizes, layer, False)
+    return fn, (S((rows, d)), S((layers, held, d, m)), S((layers, held, d, m)), S((layers, held, m, d)),
+                S((held,), jnp.int32), S((), jnp.int32))
 
 
 def _ssm_scan(chip, *, blocks, rows, width=5120, n=16, layers=13, slots=128):
@@ -363,10 +365,12 @@ def test_kernels_carry_their_names_into_the_hlo(chip, case):
     assert _kernel_names(text) == KERNEL_NAMES[case]
 
 
-def _small_model(by_kind: bool):
+def _small_model(by_kind: bool, experts: bool = False):
     """Widths the paged kernel takes on the chip (128-multiple pages), small
     enough to build on the CPU: one kind, or a full and a window kind with
-    keys 192 wide (stored padded to 256 lanes), values 128 and a sink."""
+    keys 192 wide (stored padded to 256 lanes), values 128 and a sink.
+    ``experts``: the window kind's layers (a run of two, and one alone) have
+    four experts 384 wide, two a token."""
     from accelerate_tpu.models import DecoderConfig, DecoderLM
 
     common = dict(vocab_size=512, embed_dim=256, num_heads=8, mlp_dim=512, max_seq_len=512, dtype=jnp.bfloat16,
@@ -376,7 +380,8 @@ def _small_model(by_kind: bool):
     return DecoderLM(DecoderConfig(
         num_layers=5, head_dim=192, v_head_dim=128, rope_dim=64, attn_value_scale=0.707,
         layer_kinds=(("full", dict(num_kv_heads=2)),
-                     ("window", dict(num_kv_heads=4, attn_window=32, attn_sink=True, rope_theta=1e4))),
+                     ("window", dict(num_kv_heads=4, attn_window=32, attn_sink=True, rope_theta=1e4,
+                                     **(dict(mlp_dim=384, moe_num_experts=4, moe_top_k=2) if experts else {})))),
         layer_pattern=(0, 1, 1, 0, 1), **common))
 
 
@@ -388,6 +393,26 @@ def _small_eva_model():
     return DecoderLM(DecoderConfig(
         vocab_size=512, embed_dim=256, num_heads=2, num_kv_heads=2, head_dim=128, mlp_dim=512, max_seq_len=1024,
         dtype=jnp.bfloat16, scan_layers=True, remat=False, num_layers=3, eva_window=256, eva_chunk=16))
+
+
+def _compile_serving_program(eng, chip, program: str):
+    """The engine's decode step or its packed prefill at 256 rows, compiled
+    for the described chip with XLA's optimizations on (the suite compiles
+    with most of them off; these tests are about what they leave)."""
+    S = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    if program == "decode_step":
+        fn = jax.jit(eng._step_core, donate_argnums=(1, 2, 3, 5))
+        args = (eng.params, eng._arena, eng._tokens, eng._lengths, eng._active, eng._rngs, eng._tables_arg())
+    else:
+        fn = jax.jit(eng._ragged_prefill_fn(256).__wrapped__, donate_argnums=(1,))
+        args = eng._ragged_warm_args(256)
+    unoptimized = jax.config.values["jax_disable_most_optimizations"]
+    jax.config.update("jax_disable_most_optimizations", False)
+    try:
+        return fn.lower(*S(args)).compile()
+    finally:
+        jax.config.update("jax_disable_most_optimizations", unoptimized)
 
 
 @pytest.mark.parametrize("threading", ["in_place", "split_by_layer"])
@@ -421,21 +446,7 @@ def test_the_decode_step_holds_one_arena(chip, monkeypatch, program, shape, thre
              "eva": dict(max_cache_len=1024, num_pages=1025)}[shape]
     eng = ServingEngine(model, params, num_slots=8, page_size=16, prefix_cache=False, **sizes)
     assert eng.metrics()["serving/decode_kernel_active"] and eng.metrics()["serving/prefill_kernel_active"]
-    S = lambda tree: jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
-    if program == "decode_step":
-        fn = jax.jit(eng._step_core, donate_argnums=(1, 2, 3, 5))
-        args = (eng.params, eng._arena, eng._tokens, eng._lengths, eng._active, eng._rngs, eng._tables_arg())
-    else:
-        fn = jax.jit(eng._ragged_prefill_fn(256).__wrapped__, donate_argnums=(1,))
-        args = eng._ragged_warm_args(256)
-    # the suite compiles with most XLA optimizations off; this is about what they leave
-    unoptimized = jax.config.values["jax_disable_most_optimizations"]
-    jax.config.update("jax_disable_most_optimizations", False)
-    try:
-        compiled = fn.lower(*S(args)).compile()
-    finally:
-        jax.config.update("jax_disable_most_optimizations", unoptimized)
+    compiled = _compile_serving_program(eng, chip, program)
     text = compiled.as_text()
     paged = [x for x in jax.tree_util.tree_leaves(eng._arena) if x.ndim == 5]
     shapes = {",".join(map(str, shp)) for x in paged for shp in (x.shape, x.shape[1:])}
@@ -457,6 +468,50 @@ def test_the_decode_step_holds_one_arena(chip, monkeypatch, program, shape, thre
         # (its temporaries say nothing at this size: the compiler keeps them in VMEM, 128 MiB on a v5e;
         # at the serving cell's size they are 4.16 GiB against 0.3 MiB: PERF.md, PR 29)
         assert {"copy", "dynamic-update-slice"} <= set(found), found
+
+
+@pytest.mark.parametrize("experts", ["from_stack", "sliced"])
+@pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
+def test_no_layers_experts_are_copied_out_of_their_stack(chip, monkeypatch, program, experts):
+    """Both serving programs of a small by-kind model whose window layers have
+    experts, a run of two among them, compiled for the chip: the
+    ``moe_experts`` kernel is in each, and with the run's stacked expert
+    leaves riding the layer scan (``models/decoder.expert_stacks``) no
+    operation of the program has an expert leaf's or its stack's shape as its
+    result: no slice out of the stack, no copy. ``sliced`` is the control,
+    the stack withheld: every layer reads its own slice (``w[None]`` to the
+    kernel, so it keeps a leading 1), and the same search finds the
+    ``dynamic-slice``. (At this size the compiler also prefetches whole
+    leaves into VMEM, ``copy-start`` and ``slice-start`` with a tuple for a
+    result, either way; nothing of the cell's 268 MB a leaf fits there, and
+    this search does not read them.)"""
+    import re
+
+    import accelerate_tpu.models.decoder as decoder
+    from accelerate_tpu.parallel.sharding import unbox_params
+    from accelerate_tpu.serving import ServingEngine
+
+    model = _small_model(True, experts=True)
+    params, _ = unbox_params(model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)["params"])
+    params = jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = ServingEngine(model, params, num_slots=8, page_size=16, prefix_cache=False, max_cache_len=512,
+                        num_pages=1025, kind_pages={"window32": 513})
+    assert eng.metrics()["serving/experts_from_stack"] == 1
+    if experts == "sliced":  # (after the engine took its own view)
+        monkeypatch.setattr(decoder, "expert_stacks", lambda *a, **k: {})
+    text = _compile_serving_program(eng, chip, program).as_text()
+    assert "moe_experts" in _kernel_names(text)
+    run = eng.params["layers_1"]["block"]["moe_mlp"]  # the run of two
+    leaves = [run[k] for k in ("w_gate", "w_up", "w_down")]
+    assert [x.shape for x in leaves] == [(2, 4, 256, 384), (2, 4, 256, 384), (2, 4, 384, 256)]
+    shapes = {",".join(map(str, shp)) for x in leaves for shp in (x.shape, x.shape[1:], (1,) + x.shape[1:])}
+    moved = re.compile(r"= \w+\[(%s)\]\S* (copy|copy-start|dynamic-slice)\(" % "|".join(shapes))
+    found = sorted({m.group(2) for m in moved.finditer(text)})
+    if experts == "from_stack":
+        assert not found, found
+    else:
+        assert "dynamic-slice" in found, found
 
 
 @pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
