@@ -146,6 +146,19 @@ class DecoderConfig:
     moe_selection_bias: bool = False
     moe_router_outputs: Optional[int] = None
     moe_experts_held: Optional[tuple] = None
+    # ``moe_n_group`` / ``moe_topk_group``: a group stage before the choice
+    # (group-limited routing): the router's outputs lie in ``moe_n_group``
+    # equal groups, a group scores the sum of its two best biased scores,
+    # and only experts of the ``moe_topk_group`` best groups can be chosen
+    # (1 group: no stage). ``moe_routed_scale`` multiplies the normalised
+    # weights. ``moe_shared_experts``: that many gated MLPs of the experts'
+    # width, as one of their summed width, which every token passes through
+    # and whose result is added to the routed one; it is computed once
+    # whatever share of the routed experts is held.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scale: float = 1.0
+    moe_shared_experts: int = 0
     # -- attention by layer kind. The five fields describe every layer of
     # a model with one kind, and one kind's layers where ``layer_kinds``
     # states several. ``v_head_dim``: the values' width (None: head_dim).
@@ -156,6 +169,25 @@ class DecoderConfig:
     # values.
     v_head_dim: Optional[int] = None
     rope_dim: Optional[int] = None  # 0: no rotation (the model carries the order elsewhere)
+    # -- latent attention (MLA; models/decoder.LatentAttention).
+    # ``kv_lora_rank``: keys and values are made from one latent of this
+    # width a token, normed, beside one rotated key of ``qk_rope_head_dim``
+    # that all heads share; a head's key is ``qk_nope_head_dim`` unrotated
+    # dimensions made from the latent and that rotated key (``head_dim`` is
+    # their sum), its value ``v_head_dim`` made from the latent.
+    # ``q_lora_rank``: the queries pass a bottleneck of this width with a
+    # norm inside (None: one projection). The serving cache keeps the latent
+    # and the rotated key, one entry a token a layer (cache kind "latent"),
+    # and attention over it runs absorbed: the keys' up-projection folded
+    # into the query, the values' applied after the softmax. ``rope_yarn``:
+    # ``(factor, original_max_position, beta_fast, beta_slow, mscale,
+    # mscale_all_dim)``, YaRN on the rotated dimensions (ops/layers.py); the
+    # softmax scale then carries ``yarn_mscale(factor, mscale_all_dim)^2``.
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    rope_yarn: Optional[tuple] = None
     attn_window: Optional[int] = None
     attn_sink: bool = False
     attn_value_scale: float = 1.0
@@ -327,6 +359,39 @@ class DecoderConfig:
                 "and ssm_conv_width >= 2")
         if self.attn_window is not None and self.attn_window < 1:
             raise ValueError(f"attn_window must be >= 1, got {self.attn_window}")
+        if self.kv_lora_rank is not None:
+            n, p = self.qk_nope_head_dim, self.qk_rope_head_dim
+            if not n or not p or p % 2 or n + p != self.head_dim or self.kv_lora_rank < 1:
+                raise ValueError(
+                    "latent attention needs kv_lora_rank >= 1 and head_dim = qk_nope_head_dim + "
+                    f"qk_rope_head_dim (even); got {self.kv_lora_rank}, {self.head_dim} = {n} + {p}")
+            if (self.attn_window is not None or self.attn_sink or self.attn_value_scale != 1.0
+                    or self.eva_window is not None or self.mixer != "attention"):
+                raise ValueError("latent attention takes no window, sink, value scale or closing window")
+            if self.kv_cache_dtype != "bf16":
+                raise NotImplementedError(
+                    "quantized pages (kv_cache_dtype) are not supported for latent attention: "
+                    "it keeps unquantized latents only")
+            if self.use_fp8:
+                raise NotImplementedError("latent attention has no fp8 recipe")
+        elif self.q_lora_rank is not None or self.rope_yarn is not None:
+            raise ValueError("q_lora_rank and rope_yarn are latent attention's (kv_lora_rank)")
+        if self.rope_yarn is not None:
+            self.rope_yarn = tuple(float(x) for x in self.rope_yarn)
+            if len(self.rope_yarn) != 6:
+                raise ValueError(
+                    "rope_yarn is (factor, original_max_position, beta_fast, beta_slow, mscale, mscale_all_dim)")
+        if self.moe_n_group < 1 or not 1 <= self.moe_topk_group <= self.moe_n_group:
+            raise ValueError(
+                f"moe_topk_group={self.moe_topk_group} must be in [1, moe_n_group={self.moe_n_group}]")
+        if self.moe_n_group > 1 and self.moe_num_experts > 1:
+            outputs = self.moe_router_outputs or self.moe_num_experts
+            if outputs % self.moe_n_group or outputs // self.moe_n_group < 2:
+                raise ValueError(
+                    f"the router's {outputs} outputs must lie in moe_n_group={self.moe_n_group} "
+                    "equal groups of at least two")
+            if self.moe_top_k > self.moe_topk_group * (outputs // self.moe_n_group):
+                raise ValueError("moe_top_k exceeds the experts of the moe_topk_group best groups")
         if (self.eva_window is None) != (self.eva_chunk is None):
             raise ValueError("eva_window and eva_chunk must be set together")
         if self.eva_window is not None:
@@ -408,6 +473,23 @@ class DecoderConfig:
         return self.head_dim if self.rope_dim is None else self.rope_dim
 
     @property
+    def latent_dim(self) -> int:
+        """What a latent layer's cache entry holds: the latent and the one
+        rotated key (512 + 64)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_sm_scale(self) -> float:
+        """The softmax scale: ``head_dim^-1/2``, times YaRN's
+        ``mscale(factor, mscale_all_dim)^2`` where the rotation is stretched."""
+        scale = self.head_dim ** -0.5
+        if self.rope_yarn is not None:
+            from ..ops.layers import yarn_mscale
+
+            scale *= yarn_mscale(self.rope_yarn[0], self.rope_yarn[5]) ** 2
+        return scale
+
+    @property
     def ssm_inner_dim(self) -> int:
         return self.ssm_expand * self.embed_dim
 
@@ -425,6 +507,8 @@ class DecoderConfig:
             return "state"
         if self.eva_window is not None:
             return f"closing{self.eva_window}"
+        if self.kv_lora_rank is not None:
+            return "latent"
         return "full" if self.attn_window is None else f"window{self.attn_window}"
 
     def _layer_params(self, active: bool = False) -> int:
@@ -436,6 +520,13 @@ class DecoderConfig:
             d, n, r = self.ssm_inner_dim, self.ssm_state_dim, self.ssm_rank
             attn = e * 2 * d + d * e + self.ssm_conv_width * d + (d if self.ssm_conv_bias else 0) \
                 + d * (r + 2 * n) + (r + 2 * n if self.ssm_inner_norms else 0) + r * d + d + d * n + d
+        elif self.kv_lora_rank is not None:
+            # the queries (through their bottleneck and its norm), the
+            # latent's down-projection, norm and up-projection, the output
+            r, rq, p = self.kv_lora_rank, self.q_lora_rank, self.qk_rope_head_dim
+            q = e * h * self.head_dim if rq is None else e * rq + rq + rq * h * self.head_dim
+            attn = q + e * (r + p) + r + r * h * (self.qk_nope_head_dim + self.value_dim) \
+                + h * self.value_dim * e
         else:
             attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
                 + h * self.value_dim * e + (h if self.attn_sink else 0) \
@@ -444,7 +535,7 @@ class DecoderConfig:
             # per-expert gate/up/down + the router (and its selection bias)
             outputs = self.moe_router_outputs or self.moe_num_experts
             experts = self.moe_top_k if active else self.moe_num_experts
-            mlp = experts * 3 * e * self.mlp_dim + e * outputs \
+            mlp = (experts + self.moe_shared_experts) * 3 * e * self.mlp_dim + e * outputs \
                 + (outputs if self.moe_selection_bias else 0)
         else:
             mlp = 3 * e * self.mlp_dim

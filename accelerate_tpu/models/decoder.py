@@ -25,7 +25,14 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
 from ..ops.attention import dot_product_attention, mha_reference
-from ..ops.layers import apply_rotary_embedding, rms_norm, rotary_embedding_tables, swiglu
+from ..ops.layers import (
+    apply_rotary_embedding,
+    rms_norm,
+    rotary_embedding_tables,
+    swiglu,
+    yarn_inv_freq,
+    yarn_mscale,
+)
 from ..ops.losses import fused_linear_cross_entropy
 from ..parallel.sharding import DEFAULT_AXIS_RULES, logical_to_spec
 from .configs import MOE_LOAD_COLLECTION as MOE_LOAD
@@ -45,6 +52,16 @@ def _rotary_tables(positions, cfg, dtype):
     layers do not rotate (a state-space mixer; attention with ``rope_dim`` 0)."""
     if getattr(cfg, "mixer", "attention") == "ssm" or not cfg.rotary_dim:
         return None, None
+    if getattr(cfg, "kv_lora_rank", None) is not None:
+        # latent attention rotates qk_rope_head_dim dimensions, under YaRN
+        # where the config stretches the context (ops/layers.yarn_inv_freq)
+        inv_freq, table_scale = None, 1.0
+        if cfg.rope_yarn is not None:
+            factor, original, fast, slow, mscale, mscale_all = cfg.rope_yarn
+            inv_freq = yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, factor, int(original), fast, slow)
+            table_scale = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
+        return rotary_embedding_tables(positions, cfg.qk_rope_head_dim, theta=cfg.rope_theta, dtype=dtype,
+                                       inv_freq=inv_freq, table_scale=table_scale)
     return rotary_embedding_tables(positions, cfg.rotary_dim, theta=cfg.rope_theta, dtype=dtype)
 
 
@@ -637,6 +654,139 @@ class DecoderAttention(nn.Module):
         return _constrain(out, ("batch", "seq", "embed"), self.mesh)
 
 
+class LatentAttention(nn.Module):
+    """Latent attention (MLA; ``config.kv_lora_rank``), causal, in its two
+    forms, which give the same numbers.
+
+    ``x`` is the block's normed input. The queries pass a bottleneck with a
+    norm inside (``q_lora_rank``): ``c_q = norm(x W_qa)``, ``[q_nope | q_pe]
+    = c_q W_qb`` a head, ``q_pe`` rotated. Keys and values come from one
+    latent a token: ``[c | k_pe] = x W_kva``, ``c`` normed, ``k_pe`` rotated
+    and **one for all heads**; a head's ``[k_nope | v] = c W_kvb``. The score
+    of query t and key m is ``s (q_nope . k_nope + q_pe . k_pe)`` with
+    ``s = config.attn_sm_scale`` (YaRN's ``mscale^2`` in it).
+
+    **Expanded** (no cache: a forward pass over a whole sequence): keys and
+    values of every head are made from the latents and attended as they are,
+    192 + 192 a head.
+
+    **Absorbed** (``use_cache``, the serving programs): the cache keeps ``[c
+    | k_pe]`` a token a layer, after the norm and the rotation, padded to
+    whole lanes (``ops/attention.cache_entry_widths``: 576 -> 640; leaf
+    ``cached_latent`` [num_pages, 1, page_size, lanes], the unit axis where
+    other kinds have their kv heads: one entry for all heads), and no key
+    or value of a head is ever made. ``W_kvb``'s key half is folded into the
+    query (``q' = q_nope W_kvb^K^T``, 512 wide), a score is one product of
+    ``[q' | q_pe]`` with the entry, the softmax weighs the latents
+    themselves (the entry's first 512 lanes), and ``W_kvb``'s value half is
+    applied to the result. A decode step is
+    ``ops/attention.paged_latent_attention``, a pack
+    ``ragged_latent_attention``; both write the new entries into the pages
+    themselves where the stack is carried (``cache_layer``,
+    :func:`arena_in_place`) and the caller scatters elsewhere. The cache is
+    paged only; one new token a slot in a decode step."""
+
+    config: DecoderConfig
+    mesh: Optional[Mesh] = None
+    use_cache: bool = False
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, sin, cos, cache_positions=None, page_table=None,
+                 ragged_slots=None, slot_hist=None, kv_lengths=None, cache_layer=None):
+        cfg = self.config
+        e, h, dt = cfg.embed_dim, cfg.num_heads, cfg.dtype
+        r, rq, dv = cfg.kv_lora_rank, cfg.q_lora_rank, cfg.value_dim
+        n, p = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        b, s = x.shape[0], x.shape[1]
+
+        def weight(name, axes, shape, init=_dense_init()):
+            return self.param(name, nn.with_logical_partitioning(init, axes), shape)
+
+        if rq is None:
+            q = jnp.einsum("bse,ehd->bhsd", x, weight("wq", ("embed", "heads", "head_dim"), (e, h, n + p)).astype(dt))
+        else:
+            wq_a = weight("wq_a", ("embed", None), (e, rq))
+            q_norm = weight("q_norm", ("norm",), (rq,), _norm_init(cfg))
+            wq_b = weight("wq_b", (None, "heads", "head_dim"), (rq, h, n + p))
+            q = jnp.einsum("bsr,rhd->bhsd", _norm(x @ wq_a.astype(dt), q_norm, cfg), wq_b.astype(dt))
+        wkv_a = weight("wkv_a", ("embed", None), (e, r + p))
+        kv_norm = weight("kv_norm", ("norm",), (r,), _norm_init(cfg))
+        wkv_b = weight("wkv_b", (None, "heads", "head_dim"), (r, h, n + dv)).astype(dt)
+        wo = weight("wo", ("heads", "head_dim", "embed"), (h, dv, e))
+        q = _constrain(q, ("batch", "heads", "seq", "head_dim"), self.mesh)
+        q_nope, q_pe = q[..., :n], apply_rotary_embedding(q[..., n:], sin, cos)
+        ckv = x @ wkv_a.astype(dt)
+        c = _norm(ckv[..., :r], kv_norm, cfg)                                  # [b, s, r]
+        k_pe = apply_rotary_embedding(ckv[..., r:][:, None], sin, cos)         # [b, 1, s, p]
+        scale = cfg.attn_sm_scale
+
+        if not self.use_cache:
+            # expanded: every head's keys and values from the latents
+            kv = jnp.einsum("bsr,rhd->bhsd", c, wkv_b)
+            k = jnp.concatenate([kv[..., :n], jnp.broadcast_to(k_pe, (b, h, s, p))], axis=-1)
+            out = mha_reference(jnp.concatenate([q_nope, q_pe], axis=-1), k, kv[..., n:],
+                                causal=True, sm_scale=scale)
+        else:
+            from ..ops.attention import (
+                cache_entry_widths,
+                paged_latent_attention,
+                ragged_latent_attention,
+            )
+
+            if cfg.kv_page_size is None or not self.decode or cache_positions is None or page_table is None:
+                raise NotImplementedError(
+                    "latent attention keeps its cache in pages (config.kv_page_size): one new token "
+                    "a slot in a decode step (cache_positions, page_table) or the packed ragged "
+                    "prefill (ragged_slots, slot_hist); there is no dense cache of latents")
+            _, lanes, _ = cache_entry_widths(cfg)
+            ps = cfg.kv_page_size
+            cached = self.variable("cache", "cached_latent", jnp.zeros,
+                                   (cfg.kv_num_pages, 1, ps, lanes), dt)
+            # absorbed: the keys' up-projection into the query, both in the
+            # entry's layout [latent | rotated key | zero lanes]
+            zeros = lambda *lead: jnp.zeros(lead + (lanes - r - p,), dt)
+            q_lat = jnp.concatenate(
+                [jnp.einsum("bhsn,rhn->bhsr", q_nope, wkv_b[..., :n]), q_pe, zeros(b, h, s)], axis=-1)
+            entry = jnp.concatenate([c[:, None], k_pe, zeros(b, 1, s)], axis=-1)   # [b, 1, s, lanes]
+            if ragged_slots is not None:
+                if b != 1:
+                    raise ValueError(f"packed ragged prefill packs all tails into one batch row; got batch {b}")
+                row_pos = cache_positions[0] if cache_positions.ndim == 2 else cache_positions
+                attend = functools.partial(
+                    ragged_latent_attention, q_lat, entry, cached.value, page_table=page_table,
+                    row_slot=ragged_slots, row_pos=row_pos, slot_hist=slot_hist, latent=r,
+                    sm_scale=scale, impl=cfg.prefill_kernel, token_block=cfg.prefill_kernel_block)
+                if cache_layer is not None:
+                    o_lat, cached.value = attend(layer=cache_layer)
+                else:
+                    o_lat, payload = attend()
+                    valid = (ragged_slots >= 0) & (row_pos >= 0)
+                    srow, spos = jnp.maximum(ragged_slots, 0), jnp.maximum(row_pos, 0)
+                    page = jnp.where(valid, page_table[srow, spos // ps], 0)  # pads to the parking page
+                    cached.value = cached.value.at[page, :, spos % ps].set(payload)
+            else:
+                pos2d = cache_positions[:, None] if cache_positions.ndim == 1 else cache_positions
+                if pos2d.shape[1] != s or s != 1:
+                    raise NotImplementedError(
+                        f"a latent decode step takes one new token a slot; got {s} tokens "
+                        f"for {pos2d.shape[1]} positions")
+                attend = functools.partial(
+                    paged_latent_attention, q_lat, page_table=page_table, q_positions=pos2d,
+                    latent=r, sm_scale=scale, kv_lengths=kv_lengths, impl=cfg.decode_kernel)
+                if cache_layer is not None:
+                    o_lat, cached.value = attend(cached.value, layer=cache_layer, new=entry)
+                else:
+                    page = page_table[jnp.arange(b)[:, None], pos2d // ps]
+                    cached.value = cached.value.at[page, :, pos2d % ps].set(jnp.swapaxes(entry, 1, 2))
+                    o_lat = attend(cached.value)
+            # the values' up-projection, after the softmax
+            out = jnp.einsum("bhsr,rhd->bhsd", o_lat, wkv_b[..., n:])
+        out = _constrain(out, ("batch", "heads", "seq", "head_dim"), self.mesh)
+        out = jnp.einsum("bhsd,hde->bse", out, wo.astype(dt))
+        return _constrain(out, ("batch", "seq", "embed"), self.mesh)
+
+
 def arena_in_place(config, sq: int = 1, packed: bool = False) -> bool:
     """Does a paged serving program update the arena in place on a model
     with this config: a decode step of ``sq`` new tokens a slot, or
@@ -743,6 +893,11 @@ class DecoderBlock(nn.Module):
             y = SelectiveSSM(cfg, self.mesh, self.use_cache, self.decode, name="ssm")(
                 y, cache_positions=cache_positions, ragged_slots=ragged_slots,
                 slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer)
+        elif getattr(cfg, "kv_lora_rank", None) is not None:
+            y = LatentAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
+                y, sin, cos, cache_positions=cache_positions, page_table=page_table,
+                ragged_slots=ragged_slots, slot_hist=slot_hist, kv_lengths=kv_lengths,
+                cache_layer=cache_layer)
         else:
             y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
                 y, sin, cos, deterministic, cache_positions=cache_positions,

@@ -10,7 +10,14 @@ routes by sorting:
   ``moe_router_outputs`` outputs; the scores are a softmax over them or a
   sigmoid of each (``moe_scoring``); the top ``moe_top_k`` are chosen by
   score plus an optional learned selection bias, and the chosen scores,
-  without the bias, are normalised to sum to one;
+  without the bias, are normalised to sum to one and multiplied by
+  ``moe_routed_scale``. With a group stage (``moe_n_group`` > 1) the
+  outputs lie in equal groups, a group scores the sum of its two best
+  biased scores, and the choice is among the experts of the
+  ``moe_topk_group`` best groups only;
+- ``moe_shared_experts`` gated MLPs of the experts' width (one of their
+  summed width) take every token, and their result is added to the routed
+  one: a program that holds a share of the routed experts computes it whole;
 - every (token, chosen expert) pair whose expert is held here
   (``moe_experts_held = (first, count)``; all of them by default) is sorted
   by expert, the tokens' rows are gathered in that order, and the three
@@ -23,7 +30,13 @@ routes by sorting:
   layer differentiates), and twice the expected pairs where a share is
   held. Pairs beyond that are not dropped: a ``while_loop`` multiplies
   further chunks of the same shape until every held pair is done, so skew
-  costs time and never a token. Pairs on absent experts sort last and are
+  costs time and never a token (the serving engine counts the chunks,
+  ``expert_chunks`` on its dispatch spans: one a layer where nothing
+  overflowed). A group stage makes the held share's load burstier (a token
+  whose best groups leave out the held experts' group sends them nothing,
+  another twice its share) at the same mean, which the factor of two holds
+  at a pack's hundreds of rows and the floor of 16 rows at a decode step's
+  few. Pairs on absent experts sort last and are
   never multiplied: what those experts would add is left out, and the
   weights stay normalised over all k chosen (the model-configs guide,
   section 4). On one chip the layer runs without its exchange.
@@ -54,14 +67,27 @@ def router_scores(logits: jax.Array, scoring: str) -> jax.Array:
 
 
 def top_k_routing(scores: jax.Array, top_k: int,
-                  selection_bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
+                  selection_bias: Optional[jax.Array] = None, *, n_group: int = 1,
+                  topk_group: int = 1, routed_scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """(experts [tokens, k] int32, weights [tokens, k] float32): the top k
     of ``scores + selection_bias``; the weights are the chosen scores
-    themselves, normalised over the k chosen."""
+    themselves, normalised over the k chosen and multiplied by
+    ``routed_scale``. ``n_group`` > 1, the group stage: the outputs lie in
+    ``n_group`` equal groups in order, a group scores the sum of its two
+    largest biased scores, and experts outside the ``topk_group`` best
+    groups cannot be chosen."""
     choose = scores if selection_bias is None else scores + selection_bias.astype(jnp.float32)
+    if n_group > 1:
+        tokens, outputs = choose.shape
+        grouped = choose.reshape(tokens, n_group, outputs // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, topk_group)
+        keep = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        choose = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(tokens, outputs)
     _, idx = jax.lax.top_k(choose, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx.astype(jnp.int32), chosen / jnp.maximum(jnp.sum(chosen, -1, keepdims=True), 1e-9)
+    weights = chosen / jnp.maximum(jnp.sum(chosen, -1, keepdims=True), 1e-9)
+    return idx.astype(jnp.int32), weights * routed_scale if routed_scale != 1.0 else weights
 
 
 def load_balance_loss(scores: jax.Array, experts: jax.Array) -> jax.Array:
@@ -78,12 +104,20 @@ def expert_rows(tokens: int, top_k: int, held: int, outputs: int) -> int:
     expert is held; else twice the pairs expected on the held share (a
     multiple of 8, at least 16), which a balanced router fills half and
     skew overflows into further chunks of the same shape, never into a
-    drop."""
+    drop: :func:`expert_chunks` says how many ran."""
     pairs = tokens * top_k
     if held >= outputs:
         return pairs
     want = max(16, -(-2 * pairs * held // outputs))
     return min(pairs, -(-want // 8) * 8)
+
+
+def expert_chunks(held_pairs: int, rows: int) -> int:
+    """Chunks of ``rows`` rows :func:`routed_experts` multiplies for a layer
+    whose held experts got ``held_pairs`` pairs: the loop's own count (one
+    pass where nothing overflows, none where no pair is held; a layer that
+    holds every expert has ``rows`` = all pairs and always makes its one)."""
+    return -(-int(held_pairs) // int(rows))
 
 
 def sort_pairs(experts: jax.Array, first: int, count: int,
@@ -322,7 +356,9 @@ class MoeMLP(nn.Module):
             logits = jnp.matmul(routed.astype(jnp.float32), router_w.astype(jnp.float32),
                                 precision=jax.lax.Precision.HIGHEST)
             scores = router_scores(logits, cfg.moe_scoring)
-            experts, weights = top_k_routing(scores, k, bias)
+            experts, weights = top_k_routing(
+                scores, k, bias, n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+                routed_scale=float(cfg.moe_routed_scale))
         # the auxiliary loss is a mean over the batch rows' own balance, as
         # the grouped routing before it had it
         aux_loss = jnp.mean(load_balance_loss(
@@ -332,5 +368,17 @@ class MoeMLP(nn.Module):
             impl=experts_impl(cfg, self.decode), layer=layer)
         if self.is_mutable_collection(LOAD_COLLECTION):
             self.variable(LOAD_COLLECTION, "pairs", lambda: jnp.zeros((E,), jnp.int32)).value = sizes
+        if cfg.moe_shared_experts:
+            # the shared expert: every token, once, beside whatever share of
+            # the routed experts is held
+            ms = m * cfg.moe_shared_experts
+            shared = [self.param(name, nn.with_logical_partitioning(_dense_init(), axes), shape).astype(dt)
+                      for name, axes, shape in (("shared_gate", ("embed", "mlp"), (d, ms)),
+                                                ("shared_up", ("embed", "mlp"), (d, ms)),
+                                                ("shared_down", ("mlp", "embed"), (ms, d)))]
+            with jax.named_scope("moe_shared"):
+                xs = flat.astype(dt)
+                hidden = swiglu(xs @ shared[0], xs @ shared[1])
+                y = y + jnp.matmul(hidden, shared[2], preferred_element_type=jnp.float32)
         y = y.astype(dt).reshape(b, s, d)
         return _constrain(y, ("batch", "seq", "embed"), self.mesh), aux_loss

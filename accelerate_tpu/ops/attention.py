@@ -358,10 +358,12 @@ def _pick_block(s: int, preferred: int) -> int:
 def _grid_params(
     interpret: bool,
     semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes: Optional[int] = None,
 ):
     kw = {"interpret": interpret}
     if not interpret:
-        kw["compiler_params"] = pltpu.CompilerParams(dimension_semantics=semantics)
+        extra = {} if vmem_limit_bytes is None else {"vmem_limit_bytes": int(vmem_limit_bytes)}
+        kw["compiler_params"] = pltpu.CompilerParams(dimension_semantics=semantics, **extra)
     return kw
 
 
@@ -700,6 +702,21 @@ def _warn_decode_fallback(reason: str):
     )
 
 
+def cache_entry_widths(config) -> tuple:
+    """``(kv heads, key lanes, value width)`` of what a model with this
+    (one-kind) config keeps a token in a paged cache, the keys at the width
+    their pages store (:func:`paged_key_lanes`). Latent attention
+    (``kv_lora_rank``) keeps one entry that every query head shares: the
+    latent and the rotated key side by side (576 -> 640 lanes), whose first
+    ``kv_lora_rank`` lanes are the value too, so there is no value page."""
+    rank = getattr(config, "kv_lora_rank", None)
+    if rank is not None:
+        return 1, paged_key_lanes(int(config.latent_dim)), int(rank)
+    head_dim = int(getattr(config, "head_dim", 0) or 0)
+    return (int(getattr(config, "num_kv_heads", 1)), paged_key_lanes(head_dim),
+            int(getattr(config, "v_head_dim", None) or head_dim))
+
+
 def paged_key_lanes(d: int) -> int:
     """Lanes a key of width ``d`` takes in a paged arena's pages. The paged
     decode kernel copies whole pages out of the arena in HBM, and Mosaic
@@ -800,13 +817,12 @@ def decode_kernel_active(config, sq: int = 1) -> bool:
     mode = resolve_decode_kernel(getattr(config, "decode_kernel", None))
     if mode == "dense":
         return False
-    head_dim = int(getattr(config, "head_dim", 0) or 0)
     quant_bits = {"int8": 8, "int4": 4}.get(
         getattr(config, "kv_cache_dtype", "bf16"), 0
     )
+    _, lanes, dv = cache_entry_widths(config)
     use, _ = _decode_kernel_gate(
-        mode, sq, paged_key_lanes(head_dim), int(page_size), quant_bits, paged=True,
-        dv=int(getattr(config, "v_head_dim", None) or head_dim))
+        mode, sq, lanes, int(page_size), quant_bits, paged=True, dv=dv)
     return use
 
 
@@ -1007,6 +1023,9 @@ def paged_decode_block_pages(config, table_len: int) -> int:
     """Pages a block of the paged decode kernel's walk holds for a model
     with this config and a page table ``table_len`` entries long: what the
     serving engine counts ``walked_blocks`` in."""
+    if getattr(config, "kv_lora_rank", None) is not None:
+        _, lanes, _ = cache_entry_widths(config)  # one entry a token, no value page
+        return _paged_decode_block_pages(1, int(config.kv_page_size), lanes, config.dtype, 0, table_len)
     bits = {"int8": 8, "int4": 4}.get(getattr(config, "kv_cache_dtype", "bf16"), 0)
     width = config.head_dim // 2 if bits == 4 else paged_key_lanes(config.head_dim)
     dv = getattr(config, "v_head_dim", None)
@@ -1023,7 +1042,7 @@ def paged_decode_block_pages(config, table_len: int) -> int:
 def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
                          sm_scale, sq, group, block_pages, quant_bits,
                          out_dtype, window=None, has_sink=False,
-                         value_scale=1.0, write=False):
+                         value_scale=1.0, write=False, latent=0):
     """One slot a grid step; inside, a loop over blocks of ``block_pages``
     consecutive table entries. Every live page of a block comes from the
     arena (left in HBM) by one asynchronous copy that brings all kv heads
@@ -1057,10 +1076,23 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
     at ``position % page`` and the whole page goes back to the arena while
     the block is attended: the step needs no scatter, so the stack is never
     sliced, re-laid out or copied. A slot of live length 0 writes nothing;
-    a slot that writes has a live length past its position."""
+    a slot that writes has a live length past its position.
+
+    ``latent`` (latent attention read absorbed, ``paged_latent_attention``):
+    there are no value pages, no value buffer and no new value row. A page
+    holds one entry a token that every query head shares (one kv head), and
+    its first ``latent`` lanes are the value too: a block is copied once
+    and attended as keys (all its lanes) and as values (those lanes)."""
     if has_sink:
         sink_ref, refs = refs[0], refs[1:]
-    if write:
+    vnew_ref = v_hbm = vbuf = None
+    if latent:
+        ks_hbm = vs_hbm = ksbuf = vsbuf = wsems = None
+        if write:
+            knew_ref, (_, o_ref, k_hbm, kbuf, sems, state, acc, m_scr, l_scr, wsems) = refs[0], refs[1:]
+        else:
+            k_hbm, o_ref, kbuf, sems, state, acc, m_scr, l_scr = refs
+    elif write:
         knew_ref, vnew_ref, refs = refs[0], refs[1], refs[2:]
         # the stack as this call's output: the same buffer on the chip,
         # and the one that holds the rows written so far when interpreted
@@ -1076,6 +1108,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
     b, nslots = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
     kvh, ps = k_hbm.shape[2], k_hbm.shape[3]
+    both = lambda pairs: list(pairs[:1] if latent else pairs)  # keys and values, or the one entry
     g = group * sq
     bk = block_pages * ps  # kv positions a block spans
     live = len_ref[b]
@@ -1096,7 +1129,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
 
     def page_copies(slot, blk, half, j):
         page = table_ref[slot, first_page(slot) + blk * block_pages + j]
-        pairs = [(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)]
+        pairs = both([(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)])
         if quant_bits:
             pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
         return [
@@ -1123,7 +1156,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
         page = table_ref[b, entry]
         back = [
             pltpu.make_async_copy(buf.at[half, j], dst.at[layer, page], wsems.at[which])
-            for buf, dst, which in ((kbuf, k_hbm, 0), (vbuf, v_hbm, 1))
+            for buf, dst, which in both(((kbuf, k_hbm, 0), (vbuf, v_hbm, 1)))
         ]
         return (j >= 0) & (j < block_pages), j, back
 
@@ -1198,7 +1231,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
                     # no store at a row that is not a tile's first), and
                     # the page on its way back while the block is attended
                     off = pos_ref[b, 0] % ps
-                    for buf, new_ref in ((kbuf, knew_ref), (vbuf, vnew_ref)):
+                    for buf, new_ref in both(((kbuf, knew_ref), (vbuf, vnew_ref))):
                         page = buf[half, j_new]  # [KVH, page, D]
                         row = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
                         new = jnp.broadcast_to(new_ref[0], page.shape)
@@ -1229,7 +1262,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
             def head(h_):
                 q = q_ref[0, h_]  # [G, D]: the kv head's query group x Sq rows
                 k = load(kbuf, ksbuf, h_)
-                v = load(vbuf, vsbuf, h_)
+                v = k[:, :latent] if latent else load(vbuf, vsbuf, h_)
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -1271,15 +1304,18 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
                               sm_scale, interpret, k_scale=None,
                               v_scale=None, quant_bits=0, window=None,
                               sink=None, value_scale=1.0, layer=0,
-                              k_new=None, v_new=None):
+                              k_new=None, v_new=None, latent=0):
     """``k_pages`` / ``v_pages`` (and the scale pages) are the layers' stack
     ``[L, num_pages, KVH, page, D]`` and ``layer`` the one to read. With
     ``k_new`` / ``v_new`` ``[B, KVH, 1, D]`` the call also writes each
     slot's new row (the kernel's ``write``) and returns ``(out, k_pages,
-    v_pages)``, the stacks updated in place."""
+    v_pages)``, the stacks updated in place. ``latent``: ``v_pages`` and
+    ``v_new`` are None, the values are the first ``latent`` lanes of the
+    one stack, the custom call is named ``mla_attn`` and a write returns
+    ``(out, k_pages)``."""
     b, h, sq, d = q.shape
     _, _, kvh, ps, pd = k_pages.shape  # pd: payload width (d, or d/2 packed int4)
-    pdv = v_pages.shape[-1]
+    pdv = latent or v_pages.shape[-1]
     dv = 2 * pdv if quant_bits == 4 else pdv  # the output's width
     group = h // kvh
     g = group * sq
@@ -1297,7 +1333,7 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         _paged_decode_kernel, sm_scale=sm_scale, sq=sq, group=group,
         block_pages=n, quant_bits=quant_bits, out_dtype=q.dtype,
         window=window, has_sink=sink is not None, value_scale=value_scale,
-        write=write,
+        write=write, latent=latent,
     )
 
     def per_slot(*block):
@@ -1310,20 +1346,21 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         rows = jnp.repeat(sink.astype(jnp.float32).reshape(kvh, group), sq, axis=1)
         operands.append(jnp.broadcast_to(rows[:, :, None], (kvh, g, 128)))
         in_specs.append(pl.BlockSpec((kvh, g, 128), lambda b_, ln, po, tb, ly: (0, 0, 0)))
+    arenas = [k_pages] if latent else [k_pages, v_pages]
     if write:
-        operands += [k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype)]
-        in_specs += [per_slot(kvh, 1, pd), per_slot(kvh, 1, pdv)]
+        news = [k_new] if latent else [k_new, v_new]
+        operands += [x.astype(a.dtype) for x, a in zip(news, arenas)]
+        in_specs += [per_slot(kvh, 1, a.shape[-1]) for a in arenas]
     scalars = (lengths.astype(jnp.int32), pos, page_table.astype(jnp.int32),
                jnp.asarray(layer, jnp.int32).reshape(1))
     first_arena = len(scalars) + len(operands)
-    operands += [k_pages, v_pages]
-    buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype),
-               pltpu.VMEM((2, n, kvh, ps, pdv), v_pages.dtype)]
+    operands += arenas
+    buffers = [pltpu.VMEM((2, n, kvh, ps, a.shape[-1]), a.dtype) for a in arenas]
     if quant_bits:
         # per-(page, kv-head, token) fp32 scales ride the same walk
         operands += [k_scale, v_scale]
         buffers += [pltpu.VMEM((2, n, kvh, ps, 1), jnp.float32)] * 2
-    in_specs += [arena] * (4 if quant_bits else 2)
+    in_specs += [arena] * (4 if quant_bits else len(arenas))
     out_specs = per_slot(kvh, g, dv)
     out_shape = jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype)
     scratch = buffers + [
@@ -1333,11 +1370,10 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
     ]
     aliases = {}
     if write:
-        out_specs = [out_specs, arena, arena]
-        out_shape = [out_shape] + [
-            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (k_pages, v_pages)]
+        out_specs = [out_specs] + [arena] * len(arenas)
+        out_shape = [out_shape] + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arenas]
         scratch.append(pltpu.SemaphoreType.DMA((2,)))
-        aliases = {first_arena: 1, first_arena + 1: 2}
+        aliases = {first_arena + i: 1 + i for i in range(len(arenas))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b,),
@@ -1351,11 +1387,13 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
+        # its own name in the device trace: its roofline is not attn's
+        **({"name": "mla_attn"} if latent else {}),
         **_grid_params(interpret, ("arbitrary",)),
     )(*scalars, *operands)
     if write:
-        out, k_pages, v_pages = out
-        return out.reshape(b, h, sq, dv), k_pages, v_pages
+        out, *arenas = out
+        return (out.reshape(b, h, sq, dv), *arenas)
     return out.reshape(b, h, sq, dv)
 
 
@@ -1621,6 +1659,117 @@ def paged_decode_attention(
     )
 
 
+def paged_latent_attention(
+    q: jax.Array,
+    pages: jax.Array,
+    *,
+    page_table: jax.Array,
+    q_positions: jax.Array,
+    latent: int,
+    sm_scale: float,
+    kv_lengths: Optional[jax.Array] = None,
+    impl: Optional[str] = None,
+    layer: Optional[jax.Array] = None,
+    new: Optional[jax.Array] = None,
+):
+    """Latent attention read **absorbed** through a per-slot page table: a
+    decode step of one model with ``kv_lora_rank``.
+
+    ``pages`` [num_pages, 1, page_size, W] hold one entry a token that all
+    query heads share, ``[latent | rotated key | zero lanes]`` (W the stored
+    width, :func:`cache_entry_widths`), and its first ``latent`` lanes are
+    the value as well: there is no second leaf and no second read. ``q``
+    [B, H, Sq, W] are the queries in that layout (each head's unrotated
+    part already multiplied by its key up-projection, its rotated part,
+    zeros), so a score is one product over W; the result [B, H, Sq, latent]
+    is the softmax-weighted latents, which the caller multiplies by each
+    head's value up-projection. ``sm_scale`` is stated: it is that of the
+    expanded head width (and YaRN's), not ``W^-1/2``.
+
+    The kernel is :func:`paged_decode_attention`'s in its ``latent`` mode
+    (one kv head, 64 query heads a page: 121 FLOP a byte at the published
+    widths), named ``mla_attn`` in the device trace; the walk, the mask,
+    ``kv_lengths`` and the dense fallback (a gather of each slot's pages
+    and :func:`decode_attention`'s masked-dense read) are its own.
+    ``layer`` with ``new`` [B, 1, 1, W]: the pages are the layers' stack and
+    the kernel writes each live slot's new entry itself; the result is
+    ``(out, pages)``."""
+    mode = resolve_decode_kernel(impl)
+    if mode != "dense":
+        use, interpret = _decode_kernel_gate(
+            mode, q.shape[2], pages.shape[-1], pages.shape[-2], 0, paged=True, dv=latent)
+        if use:
+            pos = _positions_2d(q_positions, q.shape[0])
+            if kv_lengths is None:
+                kv_lengths = jnp.max(pos, axis=1) + 1
+            if layer is None:  # one layer's pages: a stack of one
+                pages, layer = pages[None], 0
+            return _paged_decode_kernel_call(
+                q, pages, None, page_table, pos, kv_lengths, sm_scale, interpret,
+                layer=layer, k_new=new, latent=latent)
+    if layer is not None:
+        raise ValueError(
+            "paged_latent_attention over the layers' stack is the kernel's path; this "
+            "dispatch resolves to the dense read (decode_kernel_active says so beforehand)")
+    entries = gather_kv_pages(pages, page_table)
+    return decode_attention(q, entries, entries[..., :latent], q_positions=q_positions,
+                            sm_scale=sm_scale, impl="dense")
+
+
+def ragged_latent_attention(
+    q: jax.Array,
+    new: jax.Array,
+    pages: jax.Array,
+    *,
+    page_table: jax.Array,
+    row_slot: jax.Array,
+    row_pos: jax.Array,
+    slot_hist: jax.Array,
+    latent: int,
+    sm_scale: float,
+    impl: Optional[str] = None,
+    token_block: Optional[int] = None,
+    layer: Optional[jax.Array] = None,
+):
+    """Latent attention read **absorbed** for a packed ragged prefill:
+    :func:`ragged_prefill_attention`'s contract for the rows, the table and
+    the histories, over entries as :func:`paged_latent_attention` describes
+    them. ``q`` [1, H, CAP, W], ``new`` [1, 1, CAP, W] the pack's own
+    entries, ``pages`` [num_pages, 1, page_size, W]. A row attends its
+    slot's cached entries and the pack's rows before it, both as they are
+    stored: no cached latent is up-projected and no key or value of a head
+    is made. Returns ``(out [1, H, CAP, latent], entries [CAP, 1, W])`` for
+    the caller's scatter, or with ``layer`` (the pages the layers' stack)
+    ``(out, pages)``, the pack's entries written by the kernel. The kernel
+    is the ragged prefill kernel in its ``latent`` mode, named
+    ``mla_prefill_attn`` in the device trace; the dense reference is
+    ``_ragged_prefill_reference`` with the values cut from the entries."""
+    mode = resolve_prefill_kernel(impl)
+    b, h, cap, d = q.shape
+    if b != 1:
+        raise ValueError(f"packed ragged prefill takes batch 1, got {b}")
+    bt = int(token_block or _PREFILL_TOKEN_BLOCK)
+    if cap % bt:
+        raise ValueError(f"packed capacity {cap} must be a multiple of the token block {bt}")
+    row_slot = jnp.asarray(row_slot, jnp.int32)
+    row_pos = jnp.asarray(row_pos, jnp.int32)
+    slot_hist = jnp.asarray(slot_hist, jnp.int32)
+    if mode != "dense":
+        use, interpret = _prefill_kernel_gate(mode, pages.shape[-1], pages.shape[-2], bt, 0, dv=latent)
+        if use:
+            return _ragged_prefill_kernel_call(
+                q, new, None, pages, None, page_table, row_slot, row_pos, slot_hist,
+                sm_scale, bt, interpret, layer=layer, latent=latent)
+    if layer is not None:
+        raise ValueError(
+            "ragged_latent_attention over the layers' stack is the kernel's path; this "
+            "dispatch resolves to the dense reference (prefill_writes_pages says so beforehand)")
+    out, payload, *_ = _ragged_prefill_reference(
+        q, new, new[..., :latent], pages, pages[..., :latent], page_table, row_slot, row_pos,
+        slot_hist, sm_scale)
+    return out, payload
+
+
 # ---------------------------------------------------------------------------
 # pallas ragged prefill kernel over the paged arena (ROADMAP item 3)
 #
@@ -1676,6 +1825,11 @@ _PREFILL_VMEM_BUDGET = 8 * 1024 * 1024
 _PREFILL_MAX_BLOCK_PAGES = 32
 # position of a kv row that no query row may see (every real one is less)
 _UNSEEN = 2 ** 30
+# latent attention's packed kernel folds a token block's query heads into
+# groups of at most this many rows (tokens x heads of the group), each
+# attended against the one entry a token: 64 heads x 64 rows in one block
+# would be 4,096 rows, a 8 MB accumulator and 8 MB of scores a block of the walk
+_LATENT_GROUP_ROWS = 512
 
 
 def prefill_token_block(capacities) -> int:
@@ -1777,11 +1931,8 @@ def prefill_kernel_active(config) -> bool:
     quant_bits = {"int8": 8, "int4": 4}.get(
         getattr(config, "kv_cache_dtype", "bf16"), 0
     )
-    head_dim = int(getattr(config, "head_dim", 0) or 0)
-    use, _ = _prefill_kernel_gate(
-        mode, paged_key_lanes(head_dim), int(page_size), bt, quant_bits,
-        dv=int(getattr(config, "v_head_dim", None) or head_dim),
-    )
+    _, lanes, dv = cache_entry_widths(config)
+    use, _ = _prefill_kernel_gate(mode, lanes, int(page_size), bt, quant_bits, dv=dv)
     return use
 
 
@@ -1799,9 +1950,8 @@ def prefill_writes_pages(config) -> bool:
         return False
     if resolve_prefill_kernel(getattr(config, "prefill_kernel", None)) == "interpret":
         return True
-    head_dim = int(config.head_dim)
-    return (paged_key_lanes(head_dim) % 128 == 0
-            and int(getattr(config, "v_head_dim", None) or head_dim) % 128 == 0)
+    _, lanes, dv = cache_entry_widths(config)
+    return lanes % 128 == 0 and dv % 128 == 0
 
 
 def _quantize_block(x, bits):
@@ -1855,7 +2005,7 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
                            q_ref, *refs, sm_scale, bt, block_pages,
                            key_lanes, value_lanes, quant_bits=0,
                            out_dtype=None, window=None, has_sink=False,
-                           value_scale=1.0, write=False):
+                           value_scale=1.0, write=False, latent=0):
     """One token block a grid step: ``bt`` packed rows of one slot, folded
     with their query-head group into ``[KVH, bt*group, D]``.
 
@@ -1907,25 +2057,44 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
     this block's last page, reads what was written. Only pages that hold a
     live row are touched: a padding block and a tail's pad rows (position
     -1) write nothing. The pack needs no scatter, so the stack is never
-    sliced, re-laid out or copied (``models/decoder.arena_in_place``)."""
+    sliced, re-laid out or copied (``models/decoder.arena_in_place``).
+
+    ``latent`` (latent attention read absorbed,
+    ``ragged_latent_attention``): there are no value pages, buffers or
+    fresh values. A page holds one entry a token that every query head
+    shares, and its first ``latent`` lanes are the value too; the query
+    heads come folded in ``q_ref.shape[1]`` groups of heads, each attended
+    against that one entry as the kv heads are here, so that a group's
+    rows and accumulator stay of a size the vector memory holds."""
     if has_sink:
         sink_ref, refs = refs[0], refs[1:]
-    kn_ref, vn_ref, qpos_ref, kvpos_ref, k_hbm, v_hbm = refs[:6]
-    if write:
-        # the stack as this call's output: the same buffer on the chip,
-        # and the one that holds the rows written so far when interpreted
-        (o_ref, k_hbm, v_hbm, kbuf, vbuf, sems, acc, m_scr, l_scr,
-         wkbuf, wvbuf, wsems) = refs[6:]
-        ks_hbm = vs_hbm = ksbuf = vsbuf = None
-    elif quant_bits:
-        (ks_hbm, vs_hbm, o_ref, kq_ref, kso_ref, vq_ref, vso_ref,
-         kbuf, vbuf, ksbuf, vsbuf, sems, acc, m_scr, l_scr) = refs[6:]
+    vn_ref = v_hbm = vbuf = wvbuf = ks_hbm = vs_hbm = ksbuf = vsbuf = None
+    if latent:
+        kn_ref, qpos_ref, kvpos_ref, k_hbm = refs[:4]
+        if write:  # the stack as this call's output, as below
+            o_ref, k_hbm, kbuf, sems, acc, m_scr, l_scr, wkbuf, wsems = refs[4:]
+        else:
+            o_ref, kbuf, sems, acc, m_scr, l_scr = refs[4:]
     else:
-        o_ref, kbuf, vbuf, sems, acc, m_scr, l_scr = refs[6:]
-        ks_hbm = vs_hbm = ksbuf = vsbuf = None
+        kn_ref, vn_ref, qpos_ref, kvpos_ref, k_hbm, v_hbm = refs[:6]
+        if write:
+            # the stack as this call's output: the same buffer on the chip,
+            # and the one that holds the rows written so far when interpreted
+            (o_ref, k_hbm, v_hbm, kbuf, vbuf, sems, acc, m_scr, l_scr,
+             wkbuf, wvbuf, wsems) = refs[6:]
+        elif quant_bits:
+            (ks_hbm, vs_hbm, o_ref, kq_ref, kso_ref, vq_ref, vso_ref,
+             kbuf, vbuf, ksbuf, vsbuf, sems, acc, m_scr, l_scr) = refs[6:]
+        else:
+            o_ref, kbuf, vbuf, sems, acc, m_scr, l_scr = refs[6:]
     i = pl.program_id(0)
     layer = layer_ref[0]
     kvh, ps = k_hbm.shape[2], k_hbm.shape[3]
+    # the blocks of folded query rows a step attends one after another: a
+    # kv head's each, or (latent) groups of heads against the one entry
+    qh = q_ref.shape[1]
+    kv_of = (lambda h_: 0) if latent else (lambda h_: h_)
+    both = lambda pairs: list(pairs[:1] if latent else pairs)
     bk = block_pages * ps  # kv positions a block of the walk spans
     slot = bslot_ref[i]
     row = jnp.maximum(slot, 0)
@@ -1940,7 +2109,7 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
 
     def page_copies(blk, half, j):
         page = tbl_ref[row, lo + blk * block_pages + j]
-        pairs = [(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)]
+        pairs = both([(k_hbm, kbuf, 0), (v_hbm, vbuf, 1)])
         if quant_bits:
             pairs += [(ks_hbm, ksbuf, 0), (vs_hbm, vsbuf, 1)]
         return [
@@ -1998,7 +2167,7 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
                 @pl.when(touched)
                 def _():
                     page = tbl_ref[row, entry0 + j]
-                    for hbm, buf, which in ((k_hbm, wkbuf, 0), (v_hbm, wvbuf, 1)):
+                    for hbm, buf, which in both(((k_hbm, wkbuf, 0), (v_hbm, wvbuf, 1))):
                         there, here = hbm.at[layer, page], buf.at[j]
                         src, dst = (here, there) if back else (there, here)
                         act(pltpu.make_async_copy(src, dst, wsems.at[j, which]))
@@ -2027,12 +2196,12 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
         s = jnp.where(valid, s, NEG_INF)
         _online_softmax_step(s, v, m_scr.at[h_], l_scr.at[h_], acc.at[h_], valid=valid)
 
-    def each_head(fn):
+    def each_head(fn, count=None):
         def one(h_, _):
             fn(h_)
             return _
 
-        jax.lax.fori_loop(0, kvh, one, None)
+        jax.lax.fori_loop(0, qh if count is None else count, one, None)
 
     def block(ib, half):
         @pl.when(ib + 1 < n_blocks)
@@ -2074,9 +2243,11 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
 
             return dequantize_kv(x, scale_column(sbuf, h_), quant_bits, out_dtype)
 
-        each_head(lambda h_: attend(
-            h_, load(kbuf, ksbuf, h_, key_lanes),
-            load(vbuf, vsbuf, h_, value_lanes), kvp))
+        def one_head(h_):
+            k = load(kbuf, ksbuf, kv_of(h_), key_lanes)
+            attend(h_, k, k[:, :latent] if latent else load(vbuf, vsbuf, h_, value_lanes), kvp)
+
+        each_head(one_head)
         return 1 - half
 
     jax.lax.fori_loop(0, n_blocks, block, 0)
@@ -2091,7 +2262,7 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
         exact = jax.lax.Precision.HIGHEST if kn_ref.dtype == jnp.float32 else None
 
         def put_rows(h_):
-            for new_ref, buf in ((kn_ref, wkbuf), (vn_ref, wvbuf)):
+            for new_ref, buf in both(((kn_ref, wkbuf), (vn_ref, wvbuf))):
                 moved = jax.lax.dot_general(
                     pick.astype(new_ref.dtype), new_ref[h_, i],
                     (((1,), (0,)), ((), ())), precision=exact,
@@ -2102,11 +2273,14 @@ def _ragged_prefill_kernel(bslot_ref, bhist_ref, tbl_ref, blo_ref, bfirst_ref,
                     buf[j, h_] = jnp.where(
                         (r >= w_lo) & (r < w_hi), moved[j * ps:(j + 1) * ps], page)
 
-        each_head(put_rows)
+        each_head(put_rows, kvh)
         # on their way back while the fresh phase runs
         for_each_written_page(lambda copy: copy.start(), back=True)
 
     def fresh_kv(h_, jf):
+        if latent:
+            kn = kn_ref[0, jf]  # [bt, D]: the entry, its first lanes the value
+            return kn, kn[:, :latent], None
         kn, vn = kn_ref[h_, jf], vn_ref[h_, jf]  # [bt, D], [bt, Dv]
         if not quant_bits:
             return kn, vn, None
@@ -2166,8 +2340,13 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
                                 row_slot, row_pos, slot_hist, sm_scale, bt,
                                 interpret, k_scale=None, v_scale=None,
                                 quant_bits=0, window=None, sink=None,
-                                value_scale=1.0, layer=None):
-    """``layer`` absent: ``k_pages`` / ``v_pages`` (and the scale pages) are
+                                value_scale=1.0, layer=None, latent=0):
+    """``latent``: ``v_new`` and ``v_pages`` are None, the values are the
+    first ``latent`` lanes of the one arena and of ``k_new``, the query
+    heads are attended in groups (``_LATENT_GROUP_ROWS``), the custom call
+    is named ``mla_prefill_attn`` and a write returns ``(out, k_pages)``.
+
+    ``layer`` absent: ``k_pages`` / ``v_pages`` (and the scale pages) are
     one layer's pages ``[num_pages, KVH, page, D]`` and the result is
     ``(out, k_payload, k_scale, v_payload, v_scale)`` for the caller's
     scatter. With ``layer`` they are the layers' stack ``[L, num_pages, KVH,
@@ -2181,7 +2360,7 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
             None if x is None else x[None] for x in (k_pages, v_pages, k_scale, v_scale))
         layer = 0
     _, _, kvh, ps, key_lanes = k_pages.shape
-    dv, value_lanes = v_new.shape[-1], v_pages.shape[-1]
+    dv, value_lanes = (latent, latent) if latent else (v_new.shape[-1], v_pages.shape[-1])
     if write and (quant_bits or (not interpret and (key_lanes % 128 or value_lanes % 128))):
         raise ValueError(
             "the ragged prefill kernel writes unquantized pages of whole lanes; "
@@ -2194,9 +2373,17 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
     # keys at 256, ``paged_key_lanes``) go in as they are, and so does the
     # stack the kernel writes (interpreted, any width is whole).
     if not write:
-        k_pages, v_pages = _whole_lanes(k_pages), _whole_lanes(v_pages)
-    pd, pdv = k_pages.shape[-1], v_pages.shape[-1]
-    group = h // kvh
+        k_pages = _whole_lanes(k_pages)
+        v_pages = None if latent else _whole_lanes(v_pages)
+    pd = k_pages.shape[-1]
+    pdv = pd if latent else v_pages.shape[-1]
+    # the blocks the query heads are folded into: a kv head's group each, or
+    # (latent) groups of heads small enough that a block's rows, scores and
+    # accumulator stay in vector memory, all against the one entry a token
+    qh = kvh
+    if latent:
+        qh = next(n_ for n_ in range(1, h + 1) if h % n_ == 0 and bt * h // n_ <= _LATENT_GROUP_ROWS or n_ == h)
+    group = h // qh
     ntb = cap // bt
     g = bt * group
     n = _prefill_block_pages(
@@ -2206,10 +2393,10 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         window_pages=None if window is None else window_span_pages(window - 1, ps))
     # fold: per token block, one [bt*group, D] block a kv head, rows
     # ordered (token, group member) — same convention as _fold_q_heads
-    q_r = (q[0].reshape(kvh, group, ntb, bt, d)
-           .transpose(2, 0, 3, 1, 4).reshape(ntb, kvh, g, d))
-    kn_r = k_new[0].reshape(kvh, ntb, bt, d)
-    vn_r = v_new[0].reshape(kvh, ntb, bt, dv)
+    q_r = (q[0].reshape(qh, group, ntb, bt, d)
+           .transpose(2, 0, 3, 1, 4).reshape(ntb, qh, g, d))
+    kn_r = k_new[0].reshape(kvh, ntb, bt, k_new.shape[-1])
+    vn_r = None if latent else v_new[0].reshape(kvh, ntb, bt, dv)
     blk_slot = row_slot.reshape(ntb, bt)[:, 0].astype(jnp.int32)
     blk_hist = jnp.where(
         blk_slot >= 0, slot_hist[jnp.maximum(blk_slot, 0)], 0
@@ -2236,7 +2423,7 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         _ragged_prefill_kernel, sm_scale=sm_scale, bt=bt, block_pages=n,
         key_lanes=key_lanes, value_lanes=value_lanes, quant_bits=quant_bits,
         out_dtype=q.dtype, window=window, has_sink=sink is not None, value_scale=value_scale,
-        write=write,
+        write=write, latent=latent,
     )
 
     def per_block(*block):
@@ -2250,20 +2437,21 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         return pl.BlockSpec((kvh, 1, bt, width), lambda i, *_: (0, i, 0, 0))
 
     arena = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs, operands = [per_block(kvh, g, d)], [q_r]
+    in_specs, operands = [per_block(qh, g, d)], [q_r]
     if sink is not None:
         # row r of a folded q block is group member r % group of its kv head
         rows = jnp.tile(sink.astype(jnp.float32).reshape(kvh, 1, group), (1, bt, 1))
         operands.append(rows.reshape(kvh, g, 1))
         in_specs.append(whole(operands[-1]))
-    operands += [kn_r, vn_r, pos_rows, pos_in]
+    arenas = [k_pages] if latent else [k_pages, v_pages]
+    fresh_rows = [kn_r] if latent else [kn_r, vn_r]
+    operands += [*fresh_rows, pos_rows, pos_in]
     first_arena = len(prefetch) + len(operands)
-    operands += [k_pages, v_pages]
-    in_specs += [whole(kn_r), whole(vn_r), per_block(g, 1), whole(pos_in), arena, arena]
-    buffers = [pltpu.VMEM((2, n, kvh, ps, pd), k_pages.dtype),
-               pltpu.VMEM((2, n, kvh, ps, pdv), v_pages.dtype)]
-    out_specs = [per_block(kvh, g, dv)]
-    out_shape = [jax.ShapeDtypeStruct((ntb, kvh, g, dv), q.dtype)]
+    operands += arenas
+    in_specs += [*map(whole, fresh_rows), per_block(g, 1), whole(pos_in)] + [arena] * len(arenas)
+    buffers = [pltpu.VMEM((2, n, kvh, ps, a.shape[-1]), a.dtype) for a in arenas]
+    out_specs = [per_block(qh, g, dv)]
+    out_shape = [jax.ShapeDtypeStruct((ntb, qh, g, dv), q.dtype)]
     if quant_bits:
         # per-(page, kv-head, token) fp32 scales ride the same walk, a
         # page's as one lane-dense row (a view made here: the scale pages
@@ -2279,18 +2467,25 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
             out_shape.append(jax.ShapeDtypeStruct((kvh, ntb, bt, width), dt))
     scratch = buffers + [
         pltpu.SemaphoreType.DMA((2, 2)),
-        _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
+        _vmem((qh, g, dv)), _vmem((qh, g, 128)), _vmem((qh, g, 128)),
     ]
     aliases = {}
     if write:
         # the pages a block's rows touch, staged: bt rows from anywhere in a page
         w_pages = (bt + ps - 2) // ps + 1
-        out_specs += [arena, arena]
-        out_shape += [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (k_pages, v_pages)]
-        scratch += [pltpu.VMEM((w_pages, kvh, ps, pd), k_pages.dtype),
-                    pltpu.VMEM((w_pages, kvh, ps, pdv), v_pages.dtype),
-                    pltpu.SemaphoreType.DMA((w_pages, 2))]
-        aliases = {first_arena: 1, first_arena + 1: 2}
+        out_specs += [arena] * len(arenas)
+        out_shape += [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arenas]
+        scratch += [pltpu.VMEM((w_pages, kvh, ps, a.shape[-1]), a.dtype) for a in arenas]
+        scratch.append(pltpu.SemaphoreType.DMA((w_pages, 2)))
+        aliases = {first_arena + j: 1 + j for j in range(len(arenas))}
+    vmem_limit = None
+    if latent:
+        # a token block's queries and outputs (both double-buffered), its
+        # float32 accumulator, maximum and sum, and the scores of a group
+        # over a block of the walk, which pass the default scoped limit
+        need = (2 * qh * g * (d + dv) * q.dtype.itemsize + qh * g * (dv + 256) * 4
+                + 2 * n * ps * pd * k_pages.dtype.itemsize + 4 * g * n * ps * 4)
+        vmem_limit = min(max(2 * need, 32 * 2**20), 100 * 2**20)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(ntb,),
@@ -2303,16 +2498,19 @@ def _ragged_prefill_kernel_call(q, k_new, v_new, k_pages, v_pages, page_table,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        name="ragged_prefill_attn",
+        # the latent form under a name of its own in the device trace
+        name="mla_prefill_attn" if latent else "ragged_prefill_attn",
         # the first step zeroes the page buffers for those after it, and a
         # slot's blocks write its pages in order
-        **_grid_params(interpret, ("arbitrary",)),
+        **_grid_params(interpret, ("arbitrary",), vmem_limit_bytes=vmem_limit),
     )(*prefetch, *operands)
     o = outs[0]  # out_shape is a list, so pallas returns a list
-    out = (o.reshape(ntb, kvh, bt, group, dv)
+    out = (o.reshape(ntb, qh, bt, group, dv)
            .transpose(1, 3, 0, 2, 4).reshape(1, h, cap, dv))
     if write:
-        return out, outs[1], outs[2]
+        return (out, *outs[1:])
+    if latent:
+        return out, jnp.swapaxes(k_new[0], 0, 1)
     if quant_bits:
         k_pay = jnp.swapaxes(outs[1].reshape(kvh, cap, key_lanes), 0, 1)
         k_scl = jnp.swapaxes(outs[2].reshape(kvh, cap, 1), 0, 1)

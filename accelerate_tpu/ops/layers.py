@@ -7,10 +7,12 @@ accumulation for norms regardless of the bf16 activations around them.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
@@ -32,18 +34,55 @@ def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention scale for a context stretched ``factor`` times:
+    ``0.1 x mscale x ln(factor) + 1`` (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float, original_max_position: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's frequencies for ``head_dim`` rotated dimensions (float32
+    [head_dim / 2]). ``f_j = theta^(-2j / head_dim)``; the dimension that
+    turns ``b`` times over the original context is ``cd(b) = head_dim x
+    ln(original / (2 pi b)) / (2 ln theta)``; below ``low = floor(cd(beta_fast))``
+    a frequency stays as it is (it turns often enough to extrapolate), above
+    ``high = ceil(cd(beta_slow))`` it is divided by ``factor`` (interpolated),
+    and between them the two are blended linearly."""
+    half = head_dim // 2
+    f = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / head_dim)
+    cd = lambda b: head_dim * math.log(original_max_position / (2 * math.pi * b)) / (2 * math.log(theta))
+    low = max(math.floor(cd(beta_fast)), 0)
+    high = min(math.ceil(cd(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # no division by zero where the two bounds meet
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return ((f / factor) * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
 def rotary_embedding_tables(
     positions: jax.Array,
     head_dim: int,
     *,
     theta: float = 10000.0,
     dtype=jnp.float32,
+    inv_freq=None,
+    table_scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(sin, cos) tables for RoPE; positions [..., S] -> [..., S, head_dim/2]."""
+    """(sin, cos) tables for RoPE; positions [..., S] -> [..., S, head_dim/2].
+    ``inv_freq`` [head_dim / 2]: the frequencies where they are not
+    ``theta^(-2j / head_dim)`` (:func:`yarn_inv_freq`); ``table_scale``
+    multiplies both tables (YaRN's ``mscale / mscale_all_dim``)."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.sin(angles).astype(dtype), jnp.cos(angles).astype(dtype)
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if table_scale != 1.0:
+        sin, cos = sin * table_scale, cos * table_scale
+    return sin.astype(dtype), cos.astype(dtype)
 
 
 def apply_rotary_embedding(

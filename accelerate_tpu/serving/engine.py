@@ -53,6 +53,7 @@ from ..telemetry.spans import emit as _emit_span
 from ..telemetry.spans import record_gc as _record_gc
 from ..telemetry.spans import span as _span
 from ..models.decoder import MOE_LOAD, arena_in_place, expert_stacks
+from ..models.moe import expert_chunks, expert_rows
 from ..ops.attention import (
     decode_kernel_active,
     paged_decode_block_pages,
@@ -336,11 +337,12 @@ class ServingEngine:
         self._by_kind = bool(
             getattr(mcfg, "layer_kinds", ()) or getattr(mcfg, "attn_window", None)
             or getattr(mcfg, "attn_sink", False) or getattr(mcfg, "moe_num_experts", 0) > 1
-            or has_state or closing)
+            or has_state or closing or getattr(mcfg, "kv_lora_rank", None) is not None)
         if self._by_kind:
             refused = {
                 "prefix_cache (page sharing across a window kind, past a closing window, "
-                "or without a state's snapshot at the page boundary)": bool(prefix_cache),
+                "without a state's snapshot at the page boundary, or of latent pages, whose "
+                "sharing waits for a prefix index that does not scan: ROADMAP R5)": bool(prefix_cache),
                 "kv_tiers": kv_tiers is not None,
                 "preemption by page-out and restore (scheduler.config.preemption)": (
                     scheduler is not None
@@ -353,8 +355,8 @@ class ServingEngine:
                 if asked:
                     raise NotImplementedError(
                         f"ServingEngine: {feature} is not supported for a model with "
-                        "layer kinds, a window, a closing window, a sink, experts or a "
-                        "recurrent state; it is refused rather than run and be wrong "
+                        "layer kinds, a window, a closing window, a sink, experts, latent "
+                        "attention or a recurrent state; it is refused rather than run and be wrong "
                         "(ROADMAP.md, Reach)")
         elif kind_pages:
             raise ValueError("kind_pages names pools of cache kinds; this model has one kind")
@@ -362,6 +364,9 @@ class ServingEngine:
         # pairs on each held expert of each expert layer
         moe_runs = [c for c in run_cfgs if getattr(c, "moe_num_experts", 0) > 1]
         self._expert_layers = sum(c.num_layers for c in moe_runs)
+        # (top k, experts held, router outputs): what expert_rows takes
+        self._expert_widths = next(((c.moe_top_k, c.moe_num_experts, c.moe_router_outputs or c.moe_num_experts)
+                                    for c in moe_runs), None)
         self._pairs_per_token = sum(c.num_layers * c.moe_top_k for c in moe_runs)
         if self.max_cache_len % self.page_size:
             raise ValueError(
@@ -467,6 +472,13 @@ class ServingEngine:
         stacks = expert_stacks(pcfg, True, params)
         self._experts_from_stack = bool(stacks) and None not in stacks.values()
         self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
+        # a latent kind (latent attention, read absorbed by both programs):
+        # what the latent_* counts of the dispatch spans are reckoned for,
+        # and whether both its kernels engage (the mla_kernel_active gauge)
+        latent_runs = [c for c in run_cfgs if c.kv_lora_rank is not None]
+        self._latent_kind = next((k for k in self._kinds if k.name == "latent"), None)
+        self._mla_kernel_costed = bool(latent_runs) and all(
+            decode_kernel_active(c) and prefill_kernel_active(c) for c in latent_runs)
         # packed ragged prefill (ops/attention.ragged_prefill_attention):
         # the admission planner packs every pending tail into ONE ragged
         # dispatch per scheduler iteration, token-block padding only.
@@ -678,7 +690,7 @@ class ServingEngine:
         a distinct kind of paged state among the model's runs of attention
         layers, in the order the layers first state it, and the one that is
         a state a slot where the model has state-space layers."""
-        from ..ops.attention import paged_key_lanes
+        from ..ops.attention import cache_entry_widths, paged_key_lanes
 
         kinds = {}
         itemsize = jnp.dtype(pcfg.dtype).itemsize
@@ -689,7 +701,11 @@ class ServingEngine:
                 slot_bytes += c.num_layers * c.ssm_inner_dim * (
                     c.ssm_state_dim * 4 + (c.ssm_conv_width - 1) * itemsize)
                 continue
-            if self.kv_cache_dtype == "bf16":
+            if c.kv_lora_rank is not None:
+                # a latent kind: one entry a token for all heads, at the
+                # lanes its pages store (576 -> 640), and no value page
+                token_bytes = cache_entry_widths(c)[1] * itemsize
+            elif self.kv_cache_dtype == "bf16":
                 token_bytes = c.num_kv_heads * (paged_key_lanes(c.head_dim) + c.value_dim) * itemsize
             else:
                 token_bytes = kv_token_bytes(c.num_kv_heads, c.head_dim, self.kv_cache_dtype)
@@ -2700,7 +2716,7 @@ class ServingEngine:
         stamps and emits them, behind that decode dispatch."""
         with _span("serving/prefill_dispatch", rows=rcap, tokens=fresh,
                    requests=len(packs), arena_in_place=int(self._prefill_in_place),
-                   **self._pages_walked(packs),
+                   **self._pages_walked(packs), **self._latent_pairs(packs),
                    **self._state_advanced(packs, fresh), **self._pages_pooled(packs)) as sp:
             self._arena, firsts, *load = self._ragged_prefill_fn(rcap)(
                 self.params, self._arena, ids_dev, row_slot, row_pos, hist,
@@ -2773,7 +2789,8 @@ class ServingEngine:
             firsts_h, *load = jax.device_get((flight.firsts, *flight.load))  # one fetch
             firsts_h = np.asarray(firsts_h)
         if load:
-            _load_args(sp, np.asarray(load[0]), fresh * self._pairs_per_token)
+            _load_args(sp, np.asarray(load[0]), fresh * self._pairs_per_token,
+                       self._expert_rows(rcap))
         # a request waited from the dispatch on; the device worked on the
         # pack from when it had finished what lay before it
         t0, wall = sp.t0, sp_f.t1 - sp.t0
@@ -2803,6 +2820,12 @@ class ServingEngine:
                 first_tokens += 1
             sp_c.args["first_tokens"] = first_tokens
 
+    def _expert_rows(self, tokens: int) -> int:
+        """Rows a grouped product of the expert layers multiplies at once in
+        a program of ``tokens`` rows (``models/moe.expert_rows``; the expert
+        runs of one model share their routing's widths)."""
+        return expert_rows(tokens, *self._expert_widths)
+
     def _pages_walked(self, packs: list) -> dict:
         """What the ragged prefill kernel is handed in this pack, for the
         ``serving/prefill_dispatch`` span: ``pages_walked`` is the sum over
@@ -2823,6 +2846,20 @@ class ServingEngine:
         first, *others = self._kinds
         return {"pages_walked": pages(first),
                 **{f"pages_walked.{kind.name}": pages(kind) for kind in others}}
+
+    def _latent_pairs(self, packs: list) -> dict:
+        """What a pack asks of latent attention, for the
+        ``serving/prefill_dispatch`` span (one layer's counts):
+        ``latent_pairs`` the visible (row, entry) pairs, a row at position p
+        seeing p + 1 entries, cached and the pack's own; ``latent_entries``
+        the entries those rows see between them, each once; ``latent_expanded``
+        the cached entries the pack up-projected into keys and values, 0:
+        the pack program reads them absorbed, as they are stored. Nothing
+        where the model has no latent kind."""
+        if self._latent_kind is None:
+            return {}
+        return {"latent_pairs": sum((s1 * (s1 + 1) - s0 * (s0 + 1)) // 2 for _, _, s0, s1, *_ in packs),
+                "latent_entries": sum(s1 for _, _, _, s1, *_ in packs), "latent_expanded": 0}
 
     def _pages_pooled(self, packs: list) -> dict:
         """``pages_pooled`` for the ``serving/prefill_dispatch`` span: the
@@ -3048,6 +3085,9 @@ class ServingEngine:
         load = ()
         with _span("serving/decode_dispatch", slots=len(roster),
                    arena_in_place=int(self._arena_in_place),
+                   # the page-rounded entries one latent layer's kernel reads
+                   **({"latent_tokens": sum(self._latent_kind.walked_tokens(p) for p in walked)}
+                      if self._latent_kind else {}),
                    # the live slots' states advance one token each (an idle
                    # slot's state is copied in and out unchanged: not counted)
                    **({"ssm_slots": len(roster)} if self._state_kind else {})) as sp_d:
@@ -3112,7 +3152,8 @@ class ServingEngine:
                 host = host[None]  # [1, N]
         if load:
             # the step's own span, one iteration after it closed
-            _load_args(sp_d, np.asarray(load[0]), len(roster) * self._pairs_per_token)
+            _load_args(sp_d, np.asarray(load[0]), len(roster) * self._pairs_per_token,
+                       self._expert_rows(self.num_slots * k))
         # the device took the step up when it had finished what lay before it
         t0 = max(sp_d.t0, self._last_result_t)
         wall = sp_f.t1 - t0
@@ -3322,6 +3363,11 @@ class ServingEngine:
         out["serving/prefill_arena_in_place"] = int(self._prefill_in_place)
         out["serving/experts_from_stack"] = int(self._experts_from_stack)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)
+        if self._latent_kind is not None:
+            # what a token costs one latent layer's pages as stored, and
+            # whether both programs read them with the latent kernels
+            out["serving/latent_bytes_per_token"] = self._latent_kind.token_bytes
+            out["serving/mla_kernel_active"] = int(self._mla_kernel_costed)
         if self._state_kind is not None:
             # the state a slot keeps beside its pages (of arena_bytes), whether
             # its recurrence runs the ssm_scan kernel, and whether the programs
@@ -3451,15 +3497,19 @@ def _expert_load(mutated) -> tuple:
     return (jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in leaves], axis=0),)
 
 
-def _load_args(sp, load, pairs_all: int) -> None:
+def _load_args(sp, load, pairs_all: int, rows: int) -> None:
     """Expert load on a dispatch span: ``expert_pairs`` (pairs on held
     experts), ``expert_pairs_all`` (tokens x k over the expert layers),
-    ``expert_load_max`` (most pairs on one expert of one layer) and
-    ``experts_idle`` (held experts of a layer that got no token)."""
+    ``expert_load_max`` (most pairs on one expert of one layer),
+    ``experts_idle`` (held experts of a layer that got no token) and
+    ``expert_chunks`` (grouped products of ``rows`` rows the program made,
+    over the expert layers: one a layer with a held pair where the load fits
+    ``models/moe.expert_rows``, more where a burst overflowed it)."""
     sp.args["expert_pairs"] = int(load.sum())
     sp.args["expert_pairs_all"] = int(pairs_all)
     sp.args["expert_load_max"] = int(load.max())
     sp.args["experts_idle"] = int((load == 0).sum())
+    sp.args["expert_chunks"] = sum(expert_chunks(n, rows) for n in load.sum(axis=-1))
 
 
 class _StepFlight(NamedTuple):

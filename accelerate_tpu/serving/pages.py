@@ -740,8 +740,11 @@ class CacheKind:
 # with their payloads through every generic tree op below (page gathers,
 # installs, CoW forks), and everything else (``cache_index``, an encoder's
 # memory) is not paged.
+# A latent kind's one leaf is ``cached_latent`` [num_pages, 1, page_size,
+# lanes]: one entry a token for all heads, the unit axis where the others
+# have their kv heads, so that its page axis is theirs; it has no value twin.
 PAGED_LEAF_NAMES = frozenset(
-    ("cached_key", "cached_value", "cached_key_scale", "cached_value_scale"))
+    ("cached_key", "cached_value", "cached_key_scale", "cached_value_scale", "cached_latent"))
 # ... and so is a slot's recurrent state: ``ssm_state`` [slots, N, D] and
 # ``conv_state`` [slots, K - 1, D] (models/ssm.py), shaped by the number of
 # slots and not by a pool. Under a scanned stack ``ssm_state`` has the rank

@@ -417,6 +417,129 @@ def eva_phase(S: Sizes, seed: int, on_chip: bool) -> None:
         say(line)
 
 
+def latent_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    """Latent attention's two kernels against their dense reads at the
+    published widths of the gigachat3 cell (64 query heads against one entry
+    of 512 + 64 values stored in 640 lanes, pages of 16, a stack of two layers
+    of which the second is read and written; tiny and interpreted in the
+    rehearsal): a decode step over slots at four depths, one idle, and a pack
+    of two slots' rows behind cached entries, both writing their new entries
+    in place. On the chip also what the two forms of a pack's attention cost
+    at depths 2k, 8k and 24k: the absorbed kernel over the entries as stored,
+    against up-projecting the slot's cached entries into every head's keys
+    and values (a gather and one product) and attending those (XLA's dense
+    attention: no kernel takes 192-wide heads with a shared rotated key)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops import attention as A
+
+    h, r, p, n, dv, ps, pages = (64, 512, 64, 128, 192, 16, 4096) if on_chip else (4, 32, 8, 16, 16, 8, 64)
+    lanes = A.paged_key_lanes(r + p)
+    mode = S.kernel_mode  # None on the chip: the compiled kernel
+    scale = (n + p) ** -0.5
+    key = jax.random.split(jax.random.key(seed + 42), 6)
+    live = jnp.arange(lanes) < r + p  # the lanes past the entry are zeros, as stored
+    rand = lambda k, *shape: jnp.where(live, jax.random.normal(k, shape + (lanes,)), 0.0).astype(jnp.bfloat16)
+    stack = rand(key[0], 2, pages, 1, ps)
+    per_slot = pages // 4 // 2
+    table = jnp.asarray(np.random.RandomState(seed).permutation(np.arange(1, pages))[:4 * per_slot].reshape(4, per_slot),
+                        jnp.int32)
+    err_of = lambda got, want: float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
+
+    # a decode step: slots at four depths, the first idle
+    depths = (0, 5 * ps + 3, per_slot * ps // 2, per_slot * ps - 1)
+    lengths = jnp.asarray(depths, jnp.int32)
+    pos = jnp.maximum(lengths - 1, 0)[:, None]
+    q, new = rand(key[1], 4, h, 1), rand(key[2], 4, 1, 1)
+    step = jax.jit(lambda st: A.paged_latent_attention(
+        q, st, page_table=table, q_positions=pos, latent=r, sm_scale=scale, kv_lengths=lengths, impl=mode,
+        layer=jnp.int32(1), new=new))
+    got, written = jax.block_until_ready(step(stack))
+    page = table[jnp.arange(4)[:, None], pos // ps]
+    keep = (lengths == 0)[:, None, None, None]
+    scattered = stack[1].at[page, :, pos % ps].set(jnp.where(keep, stack[1][page, :, pos % ps], jnp.swapaxes(new, 1, 2)))
+    want = A.paged_latent_attention(q, scattered, page_table=table, q_positions=pos, latent=r, sm_scale=scale,
+                                    kv_lengths=lengths, impl="dense")
+    err = err_of(got[1:], want[1:])
+    assert err <= BF16_ATOL and not np.asarray(got[0]).any(), err
+    assert np.array_equal(np.asarray(written[1]), np.asarray(scattered)) and np.array_equal(
+        np.asarray(written[0]), np.asarray(stack[0]))
+    say(f"  mla_attn decode step (depths {depths}, {h} heads x {lanes} lanes, values the first {r}, pages of {ps}, "
+        f"{mode or 'compiled (Mosaic)'}): max|kernel-dense|={err:.4f}; the new entries written in place, layer 0 untouched")
+
+    # a pack: slot 2 brings a block and a half behind cached entries, slot 0 one block behind none
+    bt, cap = (32, 128) if on_chip else (8, 32)
+    row_slot, row_pos = np.full(cap, -1, np.int32), np.full(cap, -1, np.int32)
+    hist2 = 5 * ps + 3
+    row_slot[:2 * bt], row_pos[:bt + bt // 2] = 2, np.arange(hist2, hist2 + bt + bt // 2)
+    row_slot[2 * bt:3 * bt], row_pos[2 * bt:3 * bt - 3] = 0, np.arange(0, bt - 3)
+    hist = jnp.asarray([0, 0, hist2, 0], jnp.int32)
+    qp, newp = rand(key[3], 1, h, cap), rand(key[4], 1, 1, cap)
+    kw = dict(page_table=table, row_slot=row_slot, row_pos=row_pos, slot_hist=hist, latent=r, sm_scale=scale,
+              token_block=bt)
+    pack = jax.jit(lambda st: A.ragged_latent_attention(qp, newp, st, impl=mode, layer=jnp.int32(1), **kw))
+    got, written = jax.block_until_ready(pack(stack))
+    want, payload = A.ragged_latent_attention(qp, newp, stack[1], impl="dense", **kw)
+    err = err_of(got, want)
+    assert err <= BF16_ATOL, err
+    valid = row_pos >= 0
+    there = np.asarray(table)[np.maximum(row_slot, 0), np.maximum(row_pos, 0) // ps]
+    expect = np.asarray(stack[1]).copy()
+    expect[there[valid], :, row_pos[valid] % ps] = np.asarray(payload)[valid]
+    assert np.array_equal(np.asarray(written[1]), expect)
+    say(f"  mla_prefill_attn pack ({cap} rows in blocks of {bt}, a slot behind {hist2} cached entries and one behind none, "
+        f"{mode or 'compiled (Mosaic)'}): max|kernel-dense|={err:.4f}; the pack's entries written in place")
+    if not on_chip:
+        return
+
+    # the two forms of a pack's attention, one slot's rows at three depths (host clock around 10 calls)
+    wkv = (jax.random.normal(key[5], (r, h, n + dv)) * r ** -0.5).astype(jnp.bfloat16)
+    long_table = jnp.tile(jnp.arange(1, pages, dtype=jnp.int32)[None, :24576 // ps + 16], (4, 1))
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / 10
+
+    def absorbed_ms(rows, depth, block):
+        """One slot's ``rows`` behind ``depth`` cached entries through the pack kernel, one layer's pages."""
+        rs_, rp_ = np.zeros(rows, np.int32), np.arange(depth, depth + rows, dtype=np.int32)
+        q1, new1 = rand(key[1], 1, h, rows), rand(key[2], 1, 1, rows)
+        hist1 = jnp.asarray([depth, 0, 0, 0], jnp.int32)
+        return q1, timed(jax.jit(lambda one_layer: A.ragged_latent_attention(
+            q1, new1, one_layer, page_table=long_table, row_slot=rs_, row_pos=rp_, slot_hist=hist1, latent=r,
+            sm_scale=scale, token_block=block)[0]), stack[1])
+
+    for block in (16, 32, 64):  # the pack kernel's token block
+        say(f"  token block {block}: 256 rows at depth 8192 absorbed in {absorbed_ms(256, 8192, block)[1]:.3f} ms")
+    for rows in (64, 256):
+        for depth in (2048, 8192, 24576):
+            q1, t_abs = absorbed_ms(rows, depth, bt)
+
+            def expand(st):  # the slot's cached entries into every head's keys and values
+                ent = A.gather_kv_pages(st[1], long_table[:1, :depth // ps])[0, 0]      # [depth, lanes]
+                kv = jnp.einsum("lr,rhd->hld", ent[:, :r], wkv)
+                k = jnp.concatenate([kv[..., :n], jnp.broadcast_to(ent[None, :, r:r + p], (h, depth, p))], axis=-1)
+                return k, kv[..., n:]
+
+            def attend(k, v):  # rows x depth, every head its own keys and values (the pack's own rows left out)
+                return A.mha_reference(q1[..., :n + p], k[None], v[None], sm_scale=scale)
+
+            up = jax.jit(expand)
+            k_, v_ = up(stack)
+            t_up, t_att = timed(up, stack), timed(jax.jit(attend), k_, v_)
+            pairs = rows * (depth + (rows + 1) / 2)
+            say(f"  {rows} rows of one slot at depth {depth}: absorbed mla_prefill_attn {t_abs:.3f} ms "
+                f"({1e-9 * pairs * h * 2 * (2 * r + p) / t_abs:.1f} TFLOP/s of its 2 x ({r + p} + {r}) a pair a head); "
+                f"expanded: up-projection {t_up:.3f} ms + XLA's dense attention {t_att:.3f} ms = {t_up + t_att:.3f} ms "
+                "(host clock, 10 calls each, dispatch included)")
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -740,6 +863,8 @@ def main() -> int:
                         help="4: only the sharded trainer and its one-device comparison")
     parser.add_argument("--cpu-rehearsal", action="store_true",
                         help="tiny widths on the CPU, kernels interpreted; never a result")
+    parser.add_argument("--phase", default=None,
+                        help="run only the phases whose name contains this (the last line then says so)")
     args = parser.parse_args()
     if args.cpu_rehearsal:
         _PREFIX = "[CPU REHEARSAL, not a chip run] "
@@ -796,8 +921,13 @@ def main() -> int:
         if args.chips == 4 else
         [("kernels vs references", kernels_phase), ("state-space scan vs reference", ssm_phase),
          ("closing-window pooling vs reference", eva_phase),
+         ("latent attention's kernels vs their dense reads", latent_phase),
          ("train", accelerator_train), ("serve", serve_phase)]
     )
+    if args.phase:
+        phases = [(name, fn) for name, fn in phases if args.phase in name]
+        if not phases:
+            parser.error(f"no phase's name contains {args.phase!r}")
     for name, fn in phases:
         say(f"== {name}")
         before, t0 = compile_counts(), time.perf_counter()
@@ -813,7 +943,7 @@ def main() -> int:
     counts = compile_counts()
     say(f"total wall {time.perf_counter() - t_start:.1f}s; compile events {counts['count']} "
         f"({counts['seconds']:.1f}s), persistent-cache hits {counts['cache_hits']}")
-    say(json.dumps({"ok": True, "device": {
+    say(json.dumps({"ok": True, **({"only": [name for name, _ in phases]} if args.phase else {}), "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": len(devices)}}))
     return 0
 

@@ -56,7 +56,7 @@ def runs():
 
 @pytest.mark.parametrize("name, chips, phases", [
     ("one_chip", 1, ("kernels vs references", "state-space scan vs reference", "closing-window pooling vs reference",
-                    "train", "serve")),
+                    "latent attention's kernels vs their dense reads", "train", "serve")),
     ("four_chips", 4, ("four chips: fsdp2 x tp2 trainer vs one device",)),
 ], ids=["one_chip", "four_chips"])
 def test_cpu_rehearsal_runs_every_phase(runs, name, chips, phases):

@@ -546,3 +546,61 @@ def test_the_ring_holds_a_whole_run_of_iterations(model_and_params):
     assert spans_mod.RING_SPANS >= 4000 * per_admitting
     for iterations, admit_share in RUNS_ON_THE_CHIP:
         assert iterations * (admit_share * per_admitting + (1 - admit_share) * 7) <= 2 / 3 * spans_mod.RING_SPANS
+
+
+def test_a_latent_cache_says_what_its_kernels_are_handed():
+    """A model with latent attention and experts (the gigachat3 cell's, at
+    its rehearsal's widths): ``latent_tokens`` on ``serving/decode_dispatch``
+    is the page-rounded entries one layer's kernel reads for the slots the
+    step grew; ``latent_pairs`` / ``latent_entries`` / ``latent_expanded``
+    on ``serving/prefill_dispatch`` the visible (row, entry) pairs of the
+    pack, the entries its rows see, and the cached entries it up-projected
+    (none: both programs read absorbed); ``expert_chunks`` on both the
+    grouped products the expert layers made; the gauges the stored width."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import jax.numpy as jnp
+    import manifest
+    import weights
+
+    arch = manifest.load_arch("deepseek_v3")
+    with open(os.path.join(root, "benchmarks", "configs", "gigachat3.1-702b-serve-6l-ep32.json")) as f:
+        c = json.load(f)
+    c.update({k: v for k, v in c.pop("rehearsal").items() if not isinstance(v, dict)})
+    c["num_hidden_layers"] = 3
+    cfg = dataclasses.replace(arch.decoder_config(c, max_seq_len=128, remat=False), dtype=jnp.float32,
+                              decode_kernel="interpret", prefill_kernel="interpret")
+    params = weights.make_jit(arch.reference, c, 3, jnp.float32, adapt=arch.to_program_tree(c))
+    eng = ServingEngine(arch.module(cfg), params, num_slots=2, max_cache_len=128, page_size=8,
+                        prefill_chunks=(16, 32), prefix_cache=False)
+    m = eng.metrics()
+    assert m["serving/latent_bytes_per_token"] == 40 * 4 and m["serving/mla_kernel_active"] == 1
+    eng.warmup()
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(3, 512, (n,)) for n in (45, 7, 20)]
+    mark = _mark()
+    reqs = [eng.submit(p, max_new_tokens=4, seed=i) for i, p in enumerate(prompts)]
+    eng.run()
+    assert all(r.outcome == "finished" for r in reqs)
+    new = _spans_since(mark)
+    packs = [s[5] for s in new if s[2] == "serving/prefill_dispatch"]
+    steps = [s[5] for s in new if s[2] == "serving/decode_dispatch"]
+    grows = [s[5] for s in new if s[2] == "serving/decode_grow"]
+    assert packs and steps and len(steps) == len(grows)
+    # every prompt row sees the entries up to its own: sum over prompts of n (n + 1) / 2
+    assert sum(a["latent_pairs"] for a in packs) == sum(n * (n + 1) // 2 for n in (45, 7, 20))
+    assert all(a["latent_expanded"] == 0 and a["arena_in_place"] == 1 for a in packs)
+    # the 45-token prompt takes two packs: the second's rows see 45 entries, those of the first cached
+    assert max(a["latent_entries"] for a in packs) >= 45 and len(packs) >= 2
+    # one latent layer's walk is the first kind's walk of the same round: the kind is the only one
+    assert [a["latent_tokens"] for a in steps] == [g["walked_tokens"] for g in grows]
+    assert all(a["latent_tokens"] > 0 and a["latent_tokens"] % 8 == 0 and a["arena_in_place"] == 1 for a in steps)
+    # two expert layers: a chunk a layer that got a held pair, more only where a burst overflowed
+    for a in packs + steps:
+        assert 0 <= a["expert_chunks"] and (a["expert_pairs"] == 0) == (a["expert_chunks"] == 0)
+    assert any(a["expert_chunks"] >= 1 for a in steps)

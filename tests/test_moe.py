@@ -439,3 +439,142 @@ def test_the_gauge_says_whether_the_kernel_reads_the_stack(case, gauge):
         shape, kernel="dense" if case == "dense_kernel" else "interpret", experts=0 if case == "no_experts" else 4,
         params_dtype=jnp.float32 if case == "float32_leaves" else jnp.bfloat16)
     assert _serving_engine(shape, model, params).metrics()["serving/experts_from_stack"] == gauge
+
+
+# -- group-limited routing, the scaling factor, a shared expert (ISSUE 42) ---------
+
+def _brute_force_choice(scores, bias, k, n_group, topk_group):
+    """The group stage by hand, a token at a time: a group's score is the sum
+    of its two largest biased scores; experts outside the best groups are
+    out; the k largest biased scores among the rest are chosen."""
+    chosen = []
+    for row, brow in zip(np.asarray(scores, np.float64), np.asarray(scores + bias, np.float64)):
+        size = len(row) // n_group
+        group_score = [sum(sorted(brow[g * size:(g + 1) * size])[-2:]) for g in range(n_group)]
+        best = sorted(range(n_group), key=lambda g: -group_score[g])[:topk_group]
+        allowed = [e for e in range(len(row)) if e // size in best]
+        chosen.append(sorted(sorted(allowed, key=lambda e: -brow[e])[:k]))
+    return chosen
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_the_group_stage_is_a_brute_force_choice(seed):
+    """32 outputs in 8 groups of 4, 4 groups kept, 8 chosen, over 64 tokens
+    of random sigmoid scores with a selection bias: the same experts as the
+    choice by hand, and never one outside the kept groups."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    scores = jax.nn.sigmoid(jax.random.normal(k1, (64, 32)))
+    bias = 0.05 * jax.random.normal(k2, (32,))
+    experts, weights = top_k_routing(scores, 8, bias, n_group=8, topk_group=4)
+    want = _brute_force_choice(scores, bias, 8, 8, 4)
+    assert [sorted(map(int, row)) for row in np.asarray(experts)] == want
+    # without the stage some token chooses otherwise: the stage binds
+    plain, _ = top_k_routing(scores, 8, bias)
+    assert any(sorted(map(int, a)) != b for a, b in zip(np.asarray(plain), want))
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, rtol=1e-6)
+
+
+def test_a_token_whose_best_eight_lie_in_five_groups_loses_one():
+    """Scores by hand: the eight largest lie two each in groups 0-2 and one
+    each in groups 3 and 4; group 4's second best is smaller than group 3's,
+    so group 4 is out, its expert (the fifth best of all) with it, and the
+    ninth best, in group 3, is chosen in its place."""
+    scores = np.full((1, 32), 0.01, np.float32)
+    scores[0, [0, 1, 4, 5, 8, 9]] = [0.9, 0.8, 0.85, 0.75, 0.7, 0.65]
+    scores[0, 12], scores[0, 13] = 0.6, 0.3     # group 3: its two best 0.9
+    scores[0, 16], scores[0, 17] = 0.78, 0.02   # group 4: 0.80, under group 3's
+    experts, weights = top_k_routing(jnp.asarray(scores), 8, n_group=8, topk_group=4)
+    assert sorted(map(int, experts[0])) == [0, 1, 4, 5, 8, 9, 12, 13]
+    plain, _ = top_k_routing(jnp.asarray(scores), 8)
+    assert sorted(map(int, plain[0])) == [0, 1, 4, 5, 8, 9, 12, 16]
+    assert _brute_force_choice(jnp.asarray(scores), jnp.zeros(32), 8, 8, 4) == [[0, 1, 4, 5, 8, 9, 12, 13]]
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.5])
+def test_the_routed_scaling_factor_multiplies_the_normalised_weights(scale):
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(5), (16, 32)))
+    experts, weights = top_k_routing(scores, 8, n_group=8, topk_group=4, routed_scale=scale)
+    same, unit = top_k_routing(scores, 8, n_group=8, topk_group=4)
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(same))
+    np.testing.assert_allclose(np.asarray(weights), scale * np.asarray(unit), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), scale, rtol=1e-6)
+
+
+@pytest.mark.parametrize("fault", [dict(moe_n_group=5), dict(moe_n_group=8, moe_topk_group=9),
+                                   dict(moe_n_group=8, moe_topk_group=1, moe_top_k=8), dict(moe_n_group=0)])
+def test_a_group_stage_that_cannot_be_right_is_refused(fault):
+    base = dict(moe_num_experts=32, moe_top_k=8, moe_n_group=8, moe_topk_group=4)
+    DecoderConfig.tiny(**base)
+    with pytest.raises(ValueError, match="group"):
+        DecoderConfig.tiny(**dict(base, **fault))
+
+
+def _deepseek_arch():
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import manifest
+
+    return manifest.load_arch("deepseek_v3")
+
+
+def test_the_shares_of_all_holders_add_up_to_the_uncut_layer_with_the_shared_expert_once():
+    """The share test of the model-configs guide, section 4, for a layer with
+    a group stage, a scaling factor and a shared expert: 4 chips hold 8 of
+    32 experts each (a whole group of the router's 8 groups of 4, twice: the
+    burstiest case, a token whose kept groups leave a chip's experts out
+    sends it nothing); the program's expert layer on each computes its own
+    experts' part and the shared expert whole; the four parts less three
+    times the shared expert's result equal what the reference gives for the
+    whole layer with all 32 held."""
+    import json
+    import os
+
+    arch = _deepseek_arch()
+    import weights as W
+
+    ref = arch.reference
+    with open(os.path.join(os.path.dirname(arch.__file__), "..", "configs", "gigachat3.1-702b-serve-6l-ep32.json")) as f:
+        c = json.load(f)
+    c.update({k: v for k, v in c.pop("rehearsal").items() if not isinstance(v, dict)})
+    whole = dict(c, num_hidden_layers=1, stage_first_layer=3, n_routed_experts=32, published={"n_routed_experts": 32})
+    w = W.make_jit(ref, whole, 5, jnp.float32)
+    lw = ref.layer_weights(whole, w, 0)
+    y = jax.random.normal(jax.random.PRNGKey(0), (24, c["hidden_size"]))
+    want = np.asarray(ref.experts(whole, "float32", y, lw))
+    shared = np.asarray(ref._mlp(y, lw["gate_shared"], lw["up_shared"], lw["down_shared"], "float32"))
+    parts = 0
+    for first in range(0, 32, 8):
+        cfg = DecoderConfig.tiny(
+            embed_dim=c["hidden_size"], mlp_dim=c["moe_intermediate_size"], moe_num_experts=8,
+            moe_router_outputs=32, moe_experts_held=(first, 8), moe_top_k=8, moe_scoring="sigmoid",
+            moe_selection_bias=True, moe_n_group=8, moe_topk_group=4, moe_routed_scale=2.5, moe_shared_experts=1)
+        held = {"router": lw["router"], "selection_bias": lw["router_bias"],
+                "w_gate": lw["gate_exp"][first:first + 8], "w_up": lw["up_exp"][first:first + 8],
+                "w_down": lw["down_exp"][first:first + 8], "shared_gate": lw["gate_shared"],
+                "shared_up": lw["up_shared"], "shared_down": lw["down_shared"]}
+        part, _ = MoeMLP(cfg).apply({"params": held}, y[None])
+        # the reference given the same share says the same of it
+        share = dict(whole, n_routed_experts=8, experts_first=first)
+        cut = dict(lw, gate_exp=held["w_gate"], up_exp=held["w_up"], down_exp=held["w_down"])
+        np.testing.assert_allclose(np.asarray(part[0]), np.asarray(ref.experts(share, "float32", y, cut)), atol=2e-5)
+        parts = parts + np.asarray(part[0])
+    np.testing.assert_allclose(parts - 3 * shared, want, atol=3e-5)
+    assert np.abs(want - shared).max() > 0.05 and np.abs(shared).max() > 0.05  # both add something to be right about
+
+
+def test_expert_chunks_count_the_loops_passes():
+    """``expert_chunks`` is the ``while_loop``'s own count: a decode step of
+    16 tokens with 8 of 256 held multiplies 16 rows at once; 16 held pairs
+    are one pass, 17 two, none none; a layer that holds every expert makes
+    its one pass over all pairs."""
+    from accelerate_tpu.models.moe import expert_chunks
+
+    rows = expert_rows(16, 8, 8, 256)
+    assert rows == 16 and expert_rows(256, 8, 8, 256) == 128
+    assert [expert_chunks(n, rows) for n in (0, 1, 16, 17, 33)] == [0, 1, 1, 2, 3]
+    assert expert_chunks(64, expert_rows(8, 8, 16, 16)) == 1

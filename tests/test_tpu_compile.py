@@ -130,6 +130,38 @@ def _ragged_prefill(chip, *, h=32, kvh=32, d=128, ps=16, bits=0, cap=256, bt=8, 
                 S((h,), jnp.float32) if sink else None, None if layers is None else S((), jnp.int32))
 
 
+def _latent_decode(chip, *, h=64, lanes=640, latent=512, ps=16, slots=16, pages=16384, table=1600, layers=5,
+                   write=True):
+    """Latent attention's decode step at the published widths (the
+    gigachat3 cell's shape): 64 absorbed query heads against one 640-lane
+    entry a token, the expert run's five layers' stack and a layer index."""
+    S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    def fn(q, pages_, table_, pos, lengths, layer, new):
+        return A._paged_decode_kernel_call(
+            q, pages_, None, table_, pos, lengths, SM_SCALE, False, layer=layer, k_new=new, latent=latent)
+
+    return fn, (S((slots, h, 1, lanes)), S((layers, pages, 1, ps, lanes)), S((slots, table), jnp.int32),
+                S((slots, 1), jnp.int32), S((slots,), jnp.int32), S((), jnp.int32),
+                S((slots, 1, 1, lanes)) if write else None)
+
+
+def _latent_prefill(chip, *, h=64, lanes=640, latent=512, ps=16, cap=256, bt=32, slots=16, pages=16384,
+                    table=1600, layers=5):
+    """Latent attention's pack at the published widths: the rows' absorbed
+    queries and their own entries, the layers' stack written in place."""
+    S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    rows = S((cap,), jnp.int32)
+
+    def fn(q, new, pages_, table_, row_slot, row_pos, hist, layer):
+        return A._ragged_prefill_kernel_call(
+            q, new, None, pages_, None, table_, row_slot, row_pos, hist, SM_SCALE, bt, False,
+            layer=layer, latent=latent)
+
+    return fn, (S((1, h, cap, lanes)), S((1, 1, cap, lanes)), S((layers, pages, 1, ps, lanes)),
+                S((slots, table), jnp.int32), rows, rows, S((slots,), jnp.int32), S((), jnp.int32))
+
+
 def _moe_experts(chip, *, rows, layers=4, held=16, d=4096, m=2048):
     """The MiMo cell's window run: the four layers' stacks and a layer index."""
     from accelerate_tpu.models import moe
@@ -169,6 +201,13 @@ def _eva_pool(chip, *, steps, kvh=32, d=128, ps=16, layers=8, pages=1792):
 
 
 CASES = {
+    # latent attention read absorbed, the gigachat3 cell's shapes
+    # (benchmarks/configs/gigachat3.1-702b-serve-6l-ep32.json): the entry is
+    # 576 values stored in 640 lanes, its first 512 the value
+    "latent_decode_in_place": (_latent_decode, dict()),
+    "latent_decode_one_layer_no_write": (_latent_decode, dict(layers=1, write=False)),
+    "latent_prefill_pack_256_rows_in_place": (_latent_prefill, dict()),
+    "latent_prefill_pack_64_rows_in_place": (_latent_prefill, dict(cap=64)),
     # flash attention, forward + backward (what the train step holds)
     "flash_mha_d128_fwd_bwd": (_flash, dict(b=1, h=32, kvh=32, s=2048, d=128)),
     "flash_gqa_32q8kv_fwd_bwd": (_flash, dict(b=1, h=32, kvh=8, s=2048, d=128)),
@@ -344,6 +383,8 @@ KERNEL_NAMES = {
     "paged_decode_bf16_d128_sq1": {"attn"},
     "paged_decode_serving_cell_in_place": {"attn"},
     "moe_experts_decode_rows": {"moe_experts"},
+    "latent_decode_in_place": {"mla_attn"},
+    "latent_prefill_pack_256_rows_in_place": {"mla_prefill_attn"},
     "ssm_scan_decode_step_128_slots": {"ssm_scan"},
     "ssm_scan_pack_256_rows": {"ssm_scan"},
     "eva_pool_decode_step_16_slots": {"eva_pool"},
@@ -630,3 +671,57 @@ def test_gates_admit_only_what_compiles(monkeypatch):
         quantized = DecoderConfig(num_heads=32, num_kv_heads=8, head_dim=128, kv_page_size=16, kv_num_pages=64,
                                   kv_cache_dtype=kv)
         assert not A.decode_kernel_active(quantized) and A.prefill_kernel_active(quantized)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
+def test_the_latent_serving_programs_compile_at_the_published_widths(chip, monkeypatch, program):
+    """The gigachat3 cell's engine as its configuration file sizes it (six
+    layers at published widths, 8 of 256 experts, 16 slots of 25,600; built
+    from shapes alone, nothing is allocated), both programs compiled for the
+    described chip: Mosaic takes the 640-lane slices of the latent pages, the
+    program's kernels are the latent ones and ``moe_experts``, the arena is
+    aliased to the program's output and no operation copies, slices or
+    scatters it, and the program's temporaries are a few MB. What the backend's
+    ``memory_peak_bytes`` cannot see (PERF.md section 7) is here:
+    ``compiled.memory_analysis()`` of both programs."""
+    import json
+    import os
+    import re
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import manifest
+    import weights
+    from accelerate_tpu.serving import ServingEngine
+
+    with open(os.path.join(root, "benchmarks", "configs", "gigachat3.1-702b-serve-6l-ep32.json")) as f:
+        c = json.load(f)
+    arch, s = manifest.load_arch(c["model_type"]), c["serving"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = arch.decoder_config(c, max_seq_len=s["max_cache_len"], remat=False)
+    params = jax.eval_shape(
+        lambda k: arch.to_program_tree(c)(weights.make(arch.reference, c, k, jnp.bfloat16)), weights.seed_key(1))
+    eng = ServingEngine(arch.module(cfg), params, page_size=s["page_size"], num_slots=s["num_slots"],
+                        max_cache_len=s["max_cache_len"], num_pages=s["num_pages"], **s["engine_kwargs"])
+    m = eng.metrics()
+    assert m["serving/mla_kernel_active"] == 1 and m["serving/latent_bytes_per_token"] == 1280
+    assert m["serving/arena_in_place"] == m["serving/prefill_arena_in_place"] == m["serving/experts_from_stack"] == 1
+    compiled = _compile_serving_program(eng, chip, program)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernel_names(text) == {"moe_experts", {"decode_step": "mla_attn", "packed_prefill": "mla_prefill_attn"}[program]}
+    paged = [x for x in jax.tree_util.tree_leaves(eng._arena) if x.ndim == 5]
+    assert sorted(x.shape for x in paged) == [(1, s["num_pages"], 1, 16, 640), (5, s["num_pages"], 1, 16, 640)]
+    shapes = {",".join(map(str, shp)) for x in paged for shp in (x.shape, x.shape[1:])}
+    moved = re.compile(r"= \w+\[(%s)\]\S* (copy|copy-start|dynamic-slice|dynamic-update-slice|scatter)\("
+                       % "|".join(shapes))
+    assert not moved.search(text)
+    arena = sum(x.nbytes for x in paged)
+    weights_bytes = 2 * arch.total_params(c)
+    assert arena == s["num_pages"] * 16 * 1280 * 6 and mem.alias_size_in_bytes >= arena
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem.temp_size_in_bytes
+    assert weights_bytes + arena <= mem.argument_size_in_bytes < weights_bytes + arena + 64 * 2**20
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes,
+          "aliased", mem.alias_size_in_bytes)
