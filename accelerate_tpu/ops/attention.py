@@ -859,12 +859,12 @@ def _fold_row_positions(pos_ref, b, sq, shape, bound=None):
     return rowpos
 
 
-def _online_softmax_step(s, v, m_scr, l_scr, acc, valid=None):
-    """Fold one block of masked fp32 scores ``s`` [G, kv] and its values
-    ``v`` [kv, D] into the running max, sum and accumulator (fp32; the
-    probabilities are cast to the value dtype before PV). ``valid`` (the
-    mask ``s`` was made with) where a row may see nothing at all: such a
-    row keeps its maximum at NEG_INF, where exp(s - m) is 1, not 0, so its
+def _online_softmax_probs(s, m_scr, l_scr, valid=None):
+    """Fold one block of masked fp32 scores ``s`` [G, kv] into the running
+    max and sum (fp32) and return the block's probabilities [G, kv] with the
+    factor [G, 1] that rescales what was accumulated before it. ``valid``
+    (the mask ``s`` was made with) where a row may see nothing at all: such
+    a row keeps its maximum at NEG_INF, where exp(s - m) is 1, not 0, so its
     masked entries are zeroed by name and its sum stays 0 (a row that sees
     something already underflows to 0 at the exp)."""
     m_prev = m_scr[...][:, :1]
@@ -877,11 +877,19 @@ def _online_softmax_step(s, v, m_scr, l_scr, acc, valid=None):
     l_scr[...] = jnp.broadcast_to(
         l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_scr.shape
     )
+    m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
+    return p, alpha
+
+
+def _online_softmax_step(s, v, m_scr, l_scr, acc, valid=None):
+    """:func:`_online_softmax_probs`, and the block's values ``v`` [kv, D]
+    into the accumulator (fp32; the probabilities are cast to the value
+    dtype before PV)."""
+    p, alpha = _online_softmax_probs(s, m_scr, l_scr, valid)
     acc[...] = acc[...] * alpha + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
 
 
 def _decode_kernel_body(maxblk_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -969,9 +977,14 @@ def _positions_2d(q_positions, b):
 
 # VMEM the paged decode kernel may spend on its K/V page buffers (two
 # halves each, scale pages included), and the most pages one block holds.
-# From the chip sweep of PR 25 at the serving cells' shape (PERF.md
-# section 6): the block size follows from the page's bytes, so a 64-wide
-# head or an int4 payload doubles the pages within the same budget.
+# From the chip sweep of PR 25 at 8 kv heads and four rows a product: the
+# block size follows from the page's bytes, so a 64-wide head or an int4
+# payload doubles the pages within the same budget. PR 43's sweep at the
+# other cells' shapes (PERF.md section 6) left it: at 32 kv heads of one row
+# (16 pages a block) 64 pages read 82% of the bytes' bound against 44, but
+# by making four softmax chains a head of sixteen, which the gathered form
+# (_decode_rows_gathered) does away with at 88% with no more VMEM; at 8 and
+# at 1 kv heads 128 pages gained a point or none, 32 pages lost seven.
 _PAGED_DECODE_VMEM_BUDGET = 8 * 1024 * 1024
 _PAGED_DECODE_MAX_BLOCK_PAGES = 64
 
@@ -1039,10 +1052,40 @@ def paged_decode_block_pages(config, table_len: int) -> int:
     )
 
 
+def _decode_rows_gathered(kvh: int, g: int) -> bool:
+    """Whether the paged decode kernel runs a block's softmax once over
+    every kv head's rows (its ``gathered`` form) and not once a head: where
+    a head's ``g`` folded query rows leave most of a sublane tile (8 rows of
+    fp32) empty and there are heads to share it. From the chip sweep of
+    PR 43 (PERF.md section 6): 32 kv heads x 1 row read 44% of the bytes'
+    bound a head at a time and 88% gathered, 16 x 2 64% and 84%, 8 x 4 57%
+    and 76%; from 8 rows on a head's softmax works on whole tiles."""
+    return kvh > 1 and g < 8
+
+
+def _decode_kv_heads(config) -> int:
+    return 1 if getattr(config, "kv_lora_rank", None) is not None else config.num_kv_heads
+
+
+def paged_decode_rows_per_product(config) -> int:
+    """Query rows one product of a decode step's kernel holds: the query
+    heads folded over one kv head (all of them in the latent mode, whose
+    one entry a token every head shares)."""
+    return config.num_heads // _decode_kv_heads(config)
+
+
+def paged_decode_gathers_rows(config) -> bool:
+    """Whether a decode step's kernel takes its ``gathered`` form for a
+    model with this config (``_decode_rows_gathered`` at one query row a
+    slot)."""
+    return _decode_rows_gathered(_decode_kv_heads(config), paged_decode_rows_per_product(config))
+
+
 def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
                          sm_scale, sq, group, block_pages, quant_bits,
                          out_dtype, window=None, has_sink=False,
-                         value_scale=1.0, write=False, latent=0):
+                         value_scale=1.0, write=False, latent=0,
+                         gathered=False):
     """One slot a grid step; inside, a loop over blocks of ``block_pages``
     consecutive table entries. Every live page of a block comes from the
     arena (left in HBM) by one asynchronous copy that brings all kv heads
@@ -1082,9 +1125,20 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
     there are no value pages, no value buffer and no new value row. A page
     holds one entry a token that every query head shares (one kv head), and
     its first ``latent`` lanes are the value too: a block is copied once
-    and attended as keys (all its lanes) and as values (those lanes)."""
+    and attended as keys (all its lanes) and as values (those lanes).
+
+    ``gathered`` (``_decode_rows_gathered``: several kv heads whose folded
+    query rows are fewer than a sublane tile): a head's score rows would
+    fill an eighth or a half of the tiles its softmax works on, once a head
+    a block. The heads' score rows go into one ``[KVH x G, block]`` tile
+    instead, the block's softmax runs once over all of them, and each head
+    takes its probabilities' rows back out for its PV product: the same
+    numbers in the same order, row for row. The running maximum, sum and
+    accumulator, the sink and the output are ``[KVH x G, .]`` then."""
     if has_sink:
         sink_ref, refs = refs[0], refs[1:]
+    if gathered:
+        *refs, s_scr, o_scr = refs
     vnew_ref = v_hbm = vbuf = None
     if latent:
         ks_hbm = vs_hbm = ksbuf = vsbuf = wsems = None
@@ -1238,11 +1292,14 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
                         buf[half, j_new] = jnp.where(row == off, new, page)
                     for copy in back:
                         copy.start()
+            # row r of a fold is query token r % sq, of one head's rows and
+            # of all heads' rows alike (a head has group x sq of them)
+            rows = kvh * g if gathered else g
             kvpos = (p0 * ps + ib * bk
-                     + jax.lax.broadcasted_iota(jnp.int32, (g, bk), 1))
+                     + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1))
             # a row sees kv position c iff c <= its own position, and never
             # past the live length: beyond it no page was copied
-            rowpos = _fold_row_positions(pos_ref, b, sq, (g, bk), bound=live - 1)
+            rowpos = _fold_row_positions(pos_ref, b, sq, (rows, bk), bound=live - 1)
             valid = kvpos <= rowpos
             if window is not None:
                 valid = valid & (rowpos - kvpos < window)
@@ -1259,31 +1316,63 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
                 return dequantize_kv(
                     x, sbuf[half, :, h_].reshape(bk, 1), quant_bits, out_dtype)
 
-            def head(h_):
+            def scores(h_, k):
                 q = q_ref[0, h_]  # [G, D]: the kv head's query group x Sq rows
-                k = load(kbuf, ksbuf, h_)
-                v = k[:, :latent] if latent else load(vbuf, vsbuf, h_)
-                s = jax.lax.dot_general(
+                return jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 ) * sm_scale
-                s = jnp.where(valid, s, NEG_INF)
+
+            def head(h_):
+                k = load(kbuf, ksbuf, h_)
+                v = k[:, :latent] if latent else load(vbuf, vsbuf, h_)
+                s = jnp.where(valid, scores(h_, k), NEG_INF)
                 _online_softmax_step(s, v, m_scr.at[h_], l_scr.at[h_], acc.at[h_])
 
             # eight heads at a time as straight-line code: their matmuls
             # and softmaxes then overlap (1.2-2.3x on the chip at 8 kv
-            # heads against a loop over single heads)
-            together = next(c for c in (8, 4, 2, 1) if kvh % c == 0)
+            # heads against a loop over single heads; PR 43's sweep at 32
+            # kv heads of one row: 16 / 32 together read 45.0 / 49.6% of
+            # the bytes' bound against 43.9 in eights). Gathered, all of
+            # them: a head's rows lie at an offset into the shared tile
+            # that Mosaic has to know when it compiles (the same sweep:
+            # 88.2% with 32 heads together, 86.8 in eights at offsets of
+            # whole tiles)
+            together = kvh if gathered else next(c for c in (8, 4, 2, 1) if kvh % c == 0)
 
-            def heads(i, _):
-                for j in range(together):
-                    head(i * together + j)
-                return _
+            def for_each_head(one):
+                def heads(i, _):
+                    for j in range(together):
+                        one(i * together + j)
+                    return _
 
-            if together == kvh:  # no loop at all: what the chip sweep timed
-                heads(0, None)
+                if together == kvh:  # no loop at all: what the chip sweep timed
+                    heads(0, None)
+                else:
+                    jax.lax.fori_loop(0, kvh // together, heads, None)
+
+            if gathered:  # several kv heads: never the latent mode's one
+                def mine(h_):  # the head's rows of the tile every head shares
+                    return pl.ds(h_ * g, g)
+
+                def put_scores(h_):
+                    s_scr[mine(h_), :] = scores(h_, load(kbuf, ksbuf, h_))
+
+                def put_values(h_):
+                    v = load(vbuf, vsbuf, h_)
+                    o_scr[mine(h_), :] = jax.lax.dot_general(
+                        s_scr[mine(h_), :].astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+
+                for_each_head(put_scores)
+                p, alpha = _online_softmax_probs(
+                    jnp.where(valid, s_scr[...], NEG_INF), m_scr, l_scr)
+                s_scr[...] = p
+                for_each_head(put_values)
+                acc[...] = acc[...] * alpha + o_scr[...]
             else:
-                jax.lax.fori_loop(0, kvh // together, heads, None)
+                for_each_head(head)
             if write:
                 # the page is back before its half of the buffer is filled again
                 @pl.when(here)
@@ -1294,7 +1383,7 @@ def _paged_decode_kernel(len_ref, pos_ref, table_ref, layer_ref, q_ref, *refs,
 
         state[0] = jax.lax.fori_loop(0, n_blocks, block, first_half)
         # a row's own position is valid for it, so the sum is never zero
-        out = acc[...] / l_scr[...][:, :, :1]
+        out = acc[...] / l_scr[...][..., :1]
         if value_scale != 1.0:
             out = out * value_scale
         o_ref[0] = out.astype(o_ref.dtype)
@@ -1329,23 +1418,27 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         pdv=None if pdv == pd else pdv,
         window_pages=None if window is None else window_span_pages(window, ps, sq))
     q_r = _fold_q_heads(q, kvh)
+    gathered = _decode_rows_gathered(kvh, g)
+    # the rows the kernel keeps its softmax state, its accumulator and its
+    # output by: a tile a kv head, or every head's rows in one
+    folded = (kvh * g,) if gathered else (kvh, g)
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, sq=sq, group=group,
         block_pages=n, quant_bits=quant_bits, out_dtype=q.dtype,
         window=window, has_sink=sink is not None, value_scale=value_scale,
-        write=write, latent=latent,
+        write=write, latent=latent, gathered=gathered,
     )
 
     def per_slot(*block):
-        return pl.BlockSpec((1,) + block, lambda b_, ln, po, tb, ly: (b_, 0, 0, 0))
+        return pl.BlockSpec((1,) + block, lambda b_, ln, po, tb, ly: (b_,) + (0,) * len(block))
 
     arena = pl.BlockSpec(memory_space=pl.ANY)
     in_specs, operands = [per_slot(kvh, g, d)], [q_r]
     if sink is not None:
         # row r of a kv head's fold is query head r // sq of its group
         rows = jnp.repeat(sink.astype(jnp.float32).reshape(kvh, group), sq, axis=1)
-        operands.append(jnp.broadcast_to(rows[:, :, None], (kvh, g, 128)))
-        in_specs.append(pl.BlockSpec((kvh, g, 128), lambda b_, ln, po, tb, ly: (0, 0, 0)))
+        operands.append(jnp.broadcast_to(rows.reshape(folded)[..., None], (*folded, 128)))
+        in_specs.append(pl.BlockSpec((*folded, 128), lambda b_, ln, po, tb, ly: (0,) * (len(folded) + 1)))
     arenas = [k_pages] if latent else [k_pages, v_pages]
     if write:
         news = [k_new] if latent else [k_new, v_new]
@@ -1361,12 +1454,12 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         operands += [k_scale, v_scale]
         buffers += [pltpu.VMEM((2, n, kvh, ps, 1), jnp.float32)] * 2
     in_specs += [arena] * (4 if quant_bits else len(arenas))
-    out_specs = per_slot(kvh, g, dv)
-    out_shape = jax.ShapeDtypeStruct((b, kvh, g, dv), q.dtype)
+    out_specs = per_slot(*folded, dv)
+    out_shape = jax.ShapeDtypeStruct((b, *folded, dv), q.dtype)
     scratch = buffers + [
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.SMEM((2,), jnp.int32),
-        _vmem((kvh, g, dv)), _vmem((kvh, g, 128)), _vmem((kvh, g, 128)),
+        _vmem((*folded, dv)), _vmem((*folded, 128)), _vmem((*folded, 128)),
     ]
     aliases = {}
     if write:
@@ -1374,6 +1467,9 @@ def _paged_decode_kernel_call(q, k_pages, v_pages, page_table, pos, lengths,
         out_shape = [out_shape] + [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arenas]
         scratch.append(pltpu.SemaphoreType.DMA((2,)))
         aliases = {first_arena + i: 1 + i for i in range(len(arenas))}
+    if gathered:
+        # a block's scores, then its probabilities, and its PV products, every head's rows
+        scratch += [_vmem((kvh * g, n * ps)), _vmem((kvh * g, dv))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b,),

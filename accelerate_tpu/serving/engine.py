@@ -57,6 +57,8 @@ from ..models.moe import expert_chunks, expert_rows
 from ..ops.attention import (
     decode_kernel_active,
     paged_decode_block_pages,
+    paged_decode_gathers_rows,
+    paged_decode_rows_per_product,
     prefill_kernel_active,
     prefill_token_block,
     prefill_walk_pages,
@@ -472,6 +474,12 @@ class ServingEngine:
         stacks = expert_stacks(pcfg, True, params)
         self._experts_from_stack = bool(stacks) and None not in stacks.values()
         self._walk_block_pages = paged_decode_block_pages(run_cfgs[0], self.pages_per_slot)
+        # ... and the query rows one of its products holds, and whether the
+        # engaged kernel gathers every kv head's rows into one softmax (the
+        # decode_rows_per_product and decode_narrow_form gauges beside
+        # decode_block_pages: which form of the decode kernel this engine runs)
+        self._decode_rows_per_product = paged_decode_rows_per_product(run_cfgs[0])
+        self._decode_narrow_form = self._kernel_costed and paged_decode_gathers_rows(run_cfgs[0])
         # a latent kind (latent attention, read absorbed by both programs):
         # what the latent_* counts of the dispatch spans are reckoned for,
         # and whether both its kernels engage (the mla_kernel_active gauge)
@@ -3360,6 +3368,9 @@ class ServingEngine:
         out["serving/rows_discarded"] = self.rows_discarded
         out["serving/decode_kernel_active"] = bool(self._kernel_costed)
         out["serving/arena_in_place"] = int(self._arena_in_place)
+        out["serving/decode_rows_per_product"] = self._decode_rows_per_product
+        out["serving/decode_block_pages"] = self._walk_block_pages
+        out["serving/decode_narrow_form"] = int(self._decode_narrow_form)
         out["serving/prefill_arena_in_place"] = int(self._prefill_in_place)
         out["serving/experts_from_stack"] = int(self._experts_from_stack)
         out["serving/prefill_kernel_active"] = bool(self._prefill_kernel_costed)

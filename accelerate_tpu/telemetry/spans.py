@@ -55,8 +55,9 @@ from typing import Optional
 # mixes that is 9.4 to 13.4 spans an iteration, and a traced run of 45 s
 # with its warm-in holds 18,997 (re-ask, 1,420 iterations) to 36,187 (MiMo,
 # 3,034 iterations of which 78% admit, since PR 41 took the experts' copies
-# out of its step: 24,558 in 2,032 before; 33,990 in EvaByte's 3,636
-# iterations at 12.5 ms, 32,727 in the state-space cell's 2,891) of the
+# out of its step: 24,558 in 2,032 before; 40,394 in EvaByte's about 4,340
+# iterations since PR 43 shortened its step (PERF.md, PR 43), 33,990 in 3,636
+# before; 32,727 in the state-space cell's 2,891) of the
 # 65,536 (PERF.md, PR 40 and PR 41): the longest stays under two thirds of
 # the ring, 43,690, which is the mark at which to raise the constant. The
 # mark is for a run of the benchmark's length, its ``run_seconds`` of 45 and a

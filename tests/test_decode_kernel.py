@@ -287,6 +287,66 @@ class TestPagedWalk:
         assert tpu_interpreter.races.races_found is False
 
 
+_NARROW_EDGES = ("inside_a_block", "on_a_blocks_edge", "in_a_blocks_first_page")
+
+
+class TestNarrowQueryGroup:
+    """Many kv heads under a query group of one or two (PR 43): the shape at
+    which the kernel sizes its block and forms its products differently,
+    against ``mha_reference`` over each slot's gathered live keys."""
+
+    @pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
+    @pytest.mark.parametrize("edge", _NARROW_EDGES)
+    @pytest.mark.parametrize("kvh,group", [(32, 1), (16, 2)], ids=["32x1", "16x2"])
+    def test_parity_with_the_reference_at_block_edges(self, kvh, group, edge, write):
+        import accelerate_tpu.ops.attention as A
+
+        ps, d, layers = 16, 128, 2
+        h = kvh * group
+        table_len = 160  # longer than the largest block the kernel may take
+        n = A._paged_decode_block_pages(kvh, ps, d, jnp.bfloat16, 0, table_len)
+        assert 2 * n + 2 <= table_len
+        under_test = {"inside_a_block": n * ps + 5 * ps + 3, "on_a_blocks_edge": 2 * n * ps,
+                      "in_a_blocks_first_page": n * ps + 7}[edge]
+        # the slot under test, an empty slot, a second live one that ends in its first block
+        lens = np.array([under_test, 0, 3 * ps + 1])
+        need = -(-lens // ps)
+        rng = np.random.RandomState(_NARROW_EDGES.index(edge) + 10 * group)
+        num_pages = 1 + int(need.sum())
+        free = list(rng.permutation(np.arange(1, num_pages)))
+        table = np.zeros((len(lens), table_len), np.int32)  # 0: the parking page
+        for s_, cnt in enumerate(need):
+            for e in range(int(cnt)):
+                table[s_, e] = free.pop()
+        bf = lambda *shape: _rand(rng, shape).astype(jnp.bfloat16)
+        q, kp, vp = bf(len(lens), h, 1, d), bf(layers, num_pages, kvh, ps, d), bf(layers, num_pages, kvh, ps, d)
+        pos = jnp.asarray(np.where(lens > 0, lens - 1, table_len * ps - 1), jnp.int32)[:, None]
+        table, lens_j = jnp.asarray(table), jnp.asarray(lens, jnp.int32)
+        k_want, v_want, new = kp, vp, {}
+        if write:
+            k_new, v_new = bf(len(lens), kvh, 1, d), bf(len(lens), kvh, 1, d)
+            rows = jnp.arange(len(lens))[:, None]
+            page, off = table[rows, pos // ps], pos % ps
+            live = (lens_j > 0)[:, None, None, None]
+            put = lambda stack, x: stack.at[1, page, :, off].set(
+                jnp.where(live, jnp.swapaxes(x, 1, 2), stack[1, page, :, off]))
+            k_want, v_want, new = put(kp, k_new), put(vp, v_new), dict(k_new=k_new, v_new=v_new)
+        got = A._paged_decode_kernel_call(q, kp, vp, table, pos, lens_j, d ** -0.5, True, layer=1, **new)
+        if write:
+            got, k_out, v_out = got
+            np.testing.assert_array_equal(np.asarray(k_out, np.float32), np.asarray(k_want, np.float32))
+            np.testing.assert_array_equal(np.asarray(v_out, np.float32), np.asarray(v_want, np.float32))
+        got = np.asarray(got, np.float32)
+        for s_, length in enumerate(lens):
+            if not length:
+                np.testing.assert_array_equal(got[s_], 0.0)
+                continue
+            entries = table[s_, :need[s_]]
+            dense = lambda stack: jnp.swapaxes(stack[1, entries], 0, 1).reshape(1, kvh, -1, d)[:, :, :length]
+            want = A.mha_reference(q[s_:s_ + 1], dense(k_want), dense(v_want), sm_scale=d ** -0.5)
+            np.testing.assert_allclose(got[s_:s_ + 1], np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
+
+
 class TestDenseArenaKernel:
     def test_shared_positions_single_stream_form(self):
         """[Sq] shared positions — the single-stream generate() decode

@@ -124,6 +124,12 @@ def test_forty_steps_give_the_same_tokens_and_the_same_arena(served, monkeypatch
     shape, model, params = served
     eng = _engine(shape, model, params)
     assert eng.metrics()["serving/arena_in_place"] == 1 and eng.metrics()["serving/decode_kernel_active"]
+    # which shape of the kernel it runs, of its first cache kind: the query heads folded over a
+    # kv head (4 over 2, or 4 over the full kind's one) and the pages a block holds (the table's 12)
+    assert eng.metrics()["serving/decode_rows_per_product"] == {"mistral": 2, "by_kind": 4}[shape]
+    assert eng.metrics()["serving/decode_block_pages"] == eng._walk_block_pages == 8
+    # two kv heads of two rows share a softmax; the full kind's one kv head has nothing to share with
+    assert eng.metrics()["serving/decode_narrow_form"] == {"mistral": 1, "by_kind": 0}[shape]
     mark = _mark()
     got, arena = _serve(eng, PROMPTS)
     mine = _decode_spans(mark)

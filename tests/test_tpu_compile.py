@@ -382,6 +382,7 @@ KERNEL_NAMES = {
     "ragged_prefill_mimo_cell_window_kind": {"ragged_prefill_attn"},
     "paged_decode_bf16_d128_sq1": {"attn"},
     "paged_decode_serving_cell_in_place": {"attn"},
+    "paged_decode_32_kv_heads_closing_cell_in_place": {"attn"},  # the gathered form, like the cell before it
     "moe_experts_decode_rows": {"moe_experts"},
     "latent_decode_in_place": {"mla_attn"},
     "latent_prefill_pack_256_rows_in_place": {"mla_prefill_attn"},
@@ -404,6 +405,53 @@ def test_kernels_carry_their_names_into_the_hlo(chip, case):
     fn, args = build(chip, **kw)
     text = jax.jit(jax.named_scope("attn")(fn)).lower(*args).compile().as_text()
     assert _kernel_names(text) == KERNEL_NAMES[case]
+
+
+# (kv heads, key lanes, value lanes, table entries, a window's pages, query heads) of each serving cell's cache kinds
+# (benchmarks/configs/*.json), with the pages a block of the decode kernel's walk holds there, whether its softmax
+# gathers every kv head's rows, and the pages a block of the prefill kernel's walk holds at the engine's token block
+CELL_SHAPES = {
+    "mistral_8x4": ((8, 128, None, 256, None, 32), 64, True, 32),
+    "mimo_full_kind_4x16": ((4, 256, 128, 512, None, 64), 64, False, 32),
+    "mimo_window_kind_8x8": ((8, 256, 128, 512, 9, 64), 16, False, 16),
+    "jamba_1x20": ((1, 128, None, 512, None, 20), 64, False, 32),
+    "evabyte_32x1": ((32, 128, None, 1280, None, 32), 16, True, 8),
+    "gigachat_latent_1x64": ((1, 640, 0, 1600, None, 64), 64, False, None),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_walks_blocks_and_the_softmaxs_form_at_every_cells_shape(cell):
+    """What the kernels choose from the shapes alone, held by name: PR 43 changed the form of the decode kernel's
+    softmax where a kv head's query rows are under a sublane tile and left every block as it was."""
+    (kvh, lanes, value_lanes, table, window_pages, heads), block, gathered, prefill_block = CELL_SHAPES[cell]
+    pdv = value_lanes or None
+    assert A._paged_decode_block_pages(kvh, 16, lanes, jnp.bfloat16, 0, table, pdv=pdv,
+                                       window_pages=window_pages) == block
+    assert A._decode_rows_gathered(kvh, heads // kvh) is gathered
+    if prefill_block is not None:  # the latent mode's pack kernel sizes its own block
+        assert A._prefill_block_pages(kvh, 16, lanes, jnp.bfloat16, 0, table, 64 * (heads // kvh), pdv=pdv,
+                                      window_pages=window_pages) == prefill_block
+
+
+@pytest.mark.parametrize("case", ["paged_decode_32_kv_heads_closing_cell_in_place", "paged_decode_serving_cell_in_place",
+                                  "paged_decode_bf16_d128_sq5"])
+def test_the_gathered_forms_vmem_request_is_under_its_limit(chip, case):
+    """The gathered form adds a tile of scores and one of PV products to the page buffers (2.2 MB at 32 kv heads of
+    five rows, the most): what the kernel asks of VMEM stays under the compiler's own limit of 16 MiB, and the call
+    states none of its own."""
+    import json
+    import re
+
+    build, kw = CASES[case]
+    fn, args = build(chip, **kw)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    (call,) = re.findall(r"[^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    sizes = {key: [int(c["size"]) for c in json.loads(found)]
+             for key, found in re.findall(r'"((?:used_)?scoped_memory_configs)":(\[[^\]]*\])', call)}
+    assert sizes["scoped_memory_configs"] == []  # a call that stated a limit would carry it here
+    (used,) = sizes["used_scoped_memory_configs"]
+    assert 8 * 2**20 < used < 16 * 2**20
 
 
 def _small_model(by_kind: bool, experts: bool = False):
