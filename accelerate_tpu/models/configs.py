@@ -159,6 +159,21 @@ class DecoderConfig:
     moe_topk_group: int = 1
     moe_routed_scale: float = 1.0
     moe_shared_experts: int = 0
+    # ``moe_latent_dim``: the routed experts take and return this width
+    # (LatentMoE): one projection ``embed_dim -> moe_latent_dim`` before the
+    # dispatch and one back after the combine, each computed once whatever
+    # share of the experts is held (the way back is linear, so the shares'
+    # partial sums add up); the router and the shared expert read the full
+    # width. ``moe_shared_dim``: the shared expert's width where it is not
+    # ``moe_shared_experts x mlp_dim`` (it then exists without that count).
+    moe_latent_dim: Optional[int] = None
+    moe_shared_dim: Optional[int] = None
+    # ``mlp_kind``: a layer's feed-forward part, dense or each expert:
+    # "swiglu" (three matrices, ``W_down(silu(W_gate x) * W_up x)``), "relu2"
+    # (two, ``W_down relu(W_up x)^2``, no gate matrix) or "none": the layer
+    # is its mixer alone, with the one norm before it (a half-block, as the
+    # nemotron_h family alternates them).
+    mlp_kind: str = "swiglu"
     # -- attention by layer kind. The five fields describe every layer of
     # a model with one kind, and one kind's layers where ``layer_kinds``
     # states several. ``v_head_dim``: the values' width (None: head_dim).
@@ -219,7 +234,21 @@ class DecoderConfig:
     # the convolution's last inputs a slot, not pages (cache kind "state").
     # ``ssm_kernel``: None (the ``ssm_scan`` kernel on a TPU, its
     # ``jax.numpy`` reference elsewhere), "scan", "reference" or "interpret".
+    # "ssd" is the mixer with heads (Mamba-2; models/ssm.Mamba2Mixer):
+    # ``ssm_num_heads`` heads of ``ssm_head_dim`` channels (their product the
+    # inner width), one scalar decay and one step a head, the step from the
+    # input projection itself, B and C of ``ssm_state_dim`` by group
+    # (``ssm_n_groups``; a head reads group ``head // (heads / groups)``), the
+    # convolution over the channels, B and C together, and a gated RMSNorm
+    # within each group before the output projection. Its state is
+    # ``heads x head_dim x ssm_state_dim`` float32 a slot a layer (the
+    # ``ssd_scan`` kernel; cache kind "state" as well). "none": the layer has
+    # no mixer and is its feed-forward part alone, with the one norm before
+    # it, and keeps nothing in the cache.
     mixer: str = "attention"
+    ssm_num_heads: Optional[int] = None
+    ssm_head_dim: Optional[int] = None
+    ssm_n_groups: int = 1
     ssm_state_dim: int = 16
     ssm_conv_width: int = 4
     ssm_expand: int = 2
@@ -346,12 +375,26 @@ class DecoderConfig:
             raise ValueError(
                 f"rope_dim must be even and at most head_dim {self.head_dim}, "
                 f"got {self.rope_dim}")
-        if self.mixer not in ("attention", "ssm"):
-            raise ValueError(f"mixer must be 'attention' or 'ssm', got {self.mixer!r}")
+        if self.mixer not in ("attention", "ssm", "ssd", "none"):
+            raise ValueError(f"mixer must be 'attention', 'ssm', 'ssd' or 'none', got {self.mixer!r}")
+        if self.mlp_kind not in ("swiglu", "relu2", "none"):
+            raise ValueError(f"mlp_kind must be 'swiglu', 'relu2' or 'none', got {self.mlp_kind!r}")
+        if self.mixer == "none" and self.mlp_kind == "none":
+            raise ValueError("a layer has a mixer, a feed-forward part or both")
+        if self.mixer == "ssd":
+            heads, groups = self.ssm_num_heads, self.ssm_n_groups
+            if not heads or not self.ssm_head_dim or groups < 1 or heads % groups:
+                raise ValueError(
+                    "an 'ssd' mixer needs ssm_num_heads and ssm_head_dim >= 1 and ssm_n_groups "
+                    f"that divides the heads; got {heads}, {self.ssm_head_dim}, {groups}")
+        if self.moe_latent_dim is not None and self.moe_latent_dim < 1:
+            raise ValueError(f"moe_latent_dim must be >= 1, got {self.moe_latent_dim}")
         if self.ssm_kernel not in (None, "scan", "reference", "interpret"):
             raise ValueError(
                 "ssm_kernel must be None, 'scan', 'reference' or 'interpret', "
                 f"got {self.ssm_kernel!r}")
+        if self.mixer == "ssd" and (self.ssm_state_dim < 1 or self.ssm_conv_width < 2):
+            raise ValueError("a state-space mixer needs ssm_state_dim >= 1 and ssm_conv_width >= 2")
         if self.mixer == "ssm" and min(
                 self.ssm_state_dim, self.ssm_conv_width - 1, self.ssm_expand, self.ssm_rank) < 1:
             raise ValueError(
@@ -491,7 +534,38 @@ class DecoderConfig:
 
     @property
     def ssm_inner_dim(self) -> int:
+        if self.mixer == "ssd":
+            return self.ssm_num_heads * self.ssm_head_dim
         return self.ssm_expand * self.embed_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """The channels the convolution runs over, of which a slot keeps the
+        last ``ssm_conv_width - 1`` inputs: the inner width, and with heads
+        (``ssd``) every group's B and C beside it."""
+        extra = 2 * self.ssm_n_groups * self.ssm_state_dim if self.mixer == "ssd" else 0
+        return self.ssm_inner_dim + extra
+
+    @property
+    def has_state(self) -> bool:
+        """A state-space mixer of either form: a state a slot, not pages."""
+        return self.mixer in ("ssm", "ssd")
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Bytes one slot keeps in one layer of this (one-kind) config: the
+        float32 state and the convolution's last inputs (in ``dtype``; float32
+        for the mixer with heads)."""
+        if not self.has_state:
+            return 0
+        conv_itemsize = 4 if self.mixer == "ssd" else jnp.dtype(self.dtype).itemsize
+        return (self.ssm_inner_dim * self.ssm_state_dim * 4
+                + (self.ssm_conv_width - 1) * self.ssm_conv_dim * conv_itemsize)
+
+    @property
+    def shared_mlp_dim(self) -> int:
+        """The shared expert's width (0: none)."""
+        return self.moe_shared_dim or self.mlp_dim * self.moe_shared_experts
 
     @property
     def ssm_rank(self) -> int:
@@ -503,8 +577,10 @@ class DecoderConfig:
         for the serving cache: attention layers of one name share a page
         pool and a page table; "state" is a state-space mixer's, which is
         of a fixed size a slot and not paged."""
-        if self.mixer == "ssm":
+        if self.has_state:
             return "state"
+        if self.mixer == "none":
+            return "none"  # a layer without a mixer keeps nothing
         if self.eva_window is not None:
             return f"closing{self.eva_window}"
         if self.kv_lora_rank is not None:
@@ -513,7 +589,16 @@ class DecoderConfig:
 
     def _layer_params(self, active: bool = False) -> int:
         e, h, kv = self.embed_dim, self.num_heads, self.num_kv_heads
-        if self.mixer == "ssm":
+        mats = {"swiglu": 3, "relu2": 2, "none": 0}[self.mlp_kind]
+        if self.mixer == "none":
+            attn = 0
+        elif self.mixer == "ssd":
+            # in and out projections, the convolution and its bias, a step's
+            # bias, a decay and a skip a head, the gated norm's weight
+            d, c = self.ssm_inner_dim, self.ssm_conv_dim
+            attn = e * (d + c + self.ssm_num_heads) + d * e + self.ssm_conv_width * c \
+                + (c if self.ssm_conv_bias else 0) + 3 * self.ssm_num_heads + d
+        elif self.mixer == "ssm":
             # in and out projections, the convolution, the step's, B's and
             # C's projection with their norms, the step's expansion and
             # bias, A and the skip
@@ -531,15 +616,21 @@ class DecoderConfig:
             attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
                 + h * self.value_dim * e + (h if self.attn_sink else 0) \
                 + (2 * kv * self.head_dim if self.eva_window is not None else 0)
-        if self.moe_num_experts > 1:
-            # per-expert gate/up/down + the router (and its selection bias)
+        if self.mlp_kind == "none":
+            mlp = 0
+        elif self.moe_num_experts > 1:
+            # per-expert matrices (in the latent where there is one, with its
+            # two projections), the shared expert, the router (and its bias)
             outputs = self.moe_router_outputs or self.moe_num_experts
             experts = self.moe_top_k if active else self.moe_num_experts
-            mlp = (experts + self.moe_shared_experts) * 3 * e * self.mlp_dim + e * outputs \
+            lat = self.moe_latent_dim
+            mlp = experts * mats * (lat or e) * self.mlp_dim + (2 * e * lat if lat else 0) \
+                + mats * e * self.shared_mlp_dim + e * outputs \
                 + (outputs if self.moe_selection_bias else 0)
         else:
-            mlp = 3 * e * self.mlp_dim
-        return attn + mlp + 2 * e  # + the two norms
+            mlp = mats * e * self.mlp_dim
+        # + a norm before each part the layer has
+        return attn + mlp + e * ((self.mixer != "none") + (self.mlp_kind != "none"))
 
     def _count_params(self, active: bool) -> int:
         layers = sum(c.num_layers * c._layer_params(active) for c in self.run_configs())

@@ -834,7 +834,7 @@ def expert_stacks(config, decode: bool, params) -> dict:
 
     out = {}
     for i, run_cfg in enumerate(config.run_configs()):
-        if run_cfg.moe_num_experts <= 1:
+        if run_cfg.moe_num_experts <= 1 or run_cfg.mlp_kind == "none":
             continue
         name = _run_name(config, i)
         out[name] = None
@@ -842,8 +842,9 @@ def expert_stacks(config, decode: bool, params) -> dict:
                 or experts_impl(run_cfg, decode) == "xla"):
             continue
         moe = params[name]["block"]["moe_mlp"]
-        stack = tuple(nn.unbox(moe[leaf]) for leaf in ("w_gate", "w_up", "w_down"))
-        if all(w.dtype == run_cfg.dtype for w in stack):
+        # (two-matrix experts have no gate: the stack's first entry is None)
+        stack = tuple(nn.unbox(moe[leaf]) if leaf in moe else None for leaf in ("w_gate", "w_up", "w_down"))
+        if all(w is None or w.dtype == run_cfg.dtype for w in stack):
             out[name] = stack
     return out
 
@@ -856,21 +857,30 @@ class DecoderMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         e, m = cfg.embed_dim, cfg.mlp_dim
-        wg = self.param("w_gate", nn.with_logical_partitioning(_dense_init(), ("embed", "mlp")), (e, m))
+        # "relu2": two matrices, relu(x W_up)^2 W_down (the sequence-to-sequence config, which shares this MLP, states none)
+        gated = getattr(cfg, "mlp_kind", "swiglu") != "relu2"
+        if gated:
+            wg = self.param("w_gate", nn.with_logical_partitioning(_dense_init(), ("embed", "mlp")), (e, m))
         wu = self.param("w_up", nn.with_logical_partitioning(_dense_init(), ("embed", "mlp")), (e, m))
         wd = self.param("w_down", nn.with_logical_partitioning(_dense_init(), ("mlp", "embed")), (m, e))
         dt = cfg.dtype
         from ..ops.fp8 import module_fp8_dot
 
-        gate = module_fp8_dot(self, "gate", x, wg.astype(dt), cfg)
         up = module_fp8_dot(self, "up", x, wu.astype(dt), cfg)
-        hidden = _constrain(swiglu(gate, up), ("batch", "seq", "mlp"), self.mesh)
+        if gated:
+            up = swiglu(module_fp8_dot(self, "gate", x, wg.astype(dt), cfg), up)
+        else:
+            up = jnp.square(jax.nn.relu(up))
+        hidden = _constrain(up, ("batch", "seq", "mlp"), self.mesh)
         return _constrain(module_fp8_dot(self, "down", hidden, wd.astype(dt), cfg), ("batch", "seq", "embed"), self.mesh)
 
 
 class DecoderBlock(nn.Module):
     """Returns (x, aux_loss) — aux_loss is the MoE router load-balancing
-    term (0.0 for dense MLP blocks)."""
+    term (0.0 for dense MLP blocks). A block is ``x + mixer(norm(x))`` then
+    ``x + ffn(norm(x))``; a kind with ``mixer`` "none" or ``mlp_kind`` "none"
+    is the other half alone, with its one norm (the nemotron_h family's
+    layers: a mixer layer followed by an expert layer is one block of both)."""
 
     config: DecoderConfig
     mesh: Optional[Mesh] = None
@@ -882,31 +892,13 @@ class DecoderBlock(nn.Module):
                  page_table=None, ragged_slots=None, slot_hist=None, kv_lengths=None,
                  token_mask=None, cache_layer=None, expert_stack=None):
         cfg = self.config
-        ln1 = self.param("ln_attn", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
+        mixer = getattr(cfg, "mixer", "attention")
+        if mixer != "none":
+            x = self._mix(x, mixer, sin, cos, deterministic, cache_positions, page_table, ragged_slots,
+                          slot_hist, kv_lengths, cache_layer)
+        if getattr(cfg, "mlp_kind", "swiglu") == "none":
+            return x, jnp.float32(0.0)
         ln2 = self.param("ln_mlp", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
-        # the stream may be carried wider than the activations
-        # (config.residual_dtype); the layers' inputs are cfg.dtype either way
-        y = _norm(x, ln1, cfg).astype(cfg.dtype)
-        if getattr(cfg, "mixer", "attention") == "ssm":
-            from .ssm import SelectiveSSM
-
-            y = SelectiveSSM(cfg, self.mesh, self.use_cache, self.decode, name="ssm")(
-                y, cache_positions=cache_positions, ragged_slots=ragged_slots,
-                slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer)
-        elif getattr(cfg, "kv_lora_rank", None) is not None:
-            y = LatentAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
-                y, sin, cos, cache_positions=cache_positions, page_table=page_table,
-                ragged_slots=ragged_slots, slot_hist=slot_hist, kv_lengths=kv_lengths,
-                cache_layer=cache_layer)
-        else:
-            y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
-                y, sin, cos, deterministic, cache_positions=cache_positions,
-                page_table=page_table, ragged_slots=ragged_slots,
-                slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer,
-            )
-        if cfg.dropout_rate > 0.0:
-            y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
-        x = x + y.astype(x.dtype)
         y_stream = _norm(x, ln2, cfg)  # in the stream's dtype
         y = y_stream.astype(cfg.dtype)
         if cfg.moe_num_experts > 1:
@@ -922,6 +914,38 @@ class DecoderBlock(nn.Module):
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
         return x + y.astype(x.dtype), aux
+
+    def _mix(self, x, mixer, sin, cos, deterministic, cache_positions, page_table, ragged_slots,
+             slot_hist, kv_lengths, cache_layer):
+        """``x + mixer(norm(x))``, the mixer by the kind's ``mixer``."""
+        cfg = self.config
+        ln1 = self.param("ln_attn", nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (cfg.embed_dim,))
+        # the stream may be carried wider than the activations
+        # (config.residual_dtype); the layers' inputs are cfg.dtype either way
+        y_stream = _norm(x, ln1, cfg)  # in the stream's dtype
+        y = y_stream.astype(cfg.dtype)
+        if mixer in ("ssm", "ssd"):
+            from .ssm import Mamba2Mixer, SelectiveSSM
+
+            # (the mixer with heads reads the normed stream as it is carried)
+            y = (SelectiveSSM if mixer == "ssm" else Mamba2Mixer)(
+                cfg, self.mesh, self.use_cache, self.decode, name="ssm")(
+                y if mixer == "ssm" else y_stream, cache_positions=cache_positions, ragged_slots=ragged_slots,
+                slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer)
+        elif getattr(cfg, "kv_lora_rank", None) is not None:
+            y = LatentAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
+                y, sin, cos, cache_positions=cache_positions, page_table=page_table,
+                ragged_slots=ragged_slots, slot_hist=slot_hist, kv_lengths=kv_lengths,
+                cache_layer=cache_layer)
+        else:
+            y = DecoderAttention(cfg, self.mesh, self.use_cache, self.decode, name="attn")(
+                y, sin, cos, deterministic, cache_positions=cache_positions,
+                page_table=page_table, ragged_slots=ragged_slots,
+                slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer,
+            )
+        if cfg.dropout_rate > 0.0:
+            y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
+        return x + y.astype(x.dtype)
 
 
 class _ScanBlock(nn.Module):
@@ -1132,8 +1156,8 @@ class DecoderLM(nn.Module):
                 if cfg.layer_kinds:
                     sin, cos = _rotary_tables(positions, run_cfg, cfg.dtype)
                 ptab = page_table
-                if isinstance(page_table, dict):
-                    ptab = page_table[run_cfg.cache_kind]
+                if isinstance(page_table, dict):  # (a state or no mixer at all has no table)
+                    ptab = page_table.get(run_cfg.cache_kind)
                 # a paged decode step or packed prefill the kernel serves
                 # carries the stacked arena through the scan whole and the
                 # kernel updates it in place; every other call splits it by
@@ -1143,7 +1167,7 @@ class DecoderLM(nn.Module):
                 # few slots of a state that is of all of them.
                 name = _run_name(cfg, i)
                 in_place = (use_cache and decode and page_table is not None
-                            and (run_cfg.mixer == "ssm"
+                            and (run_cfg.has_state
                                  or arena_in_place(run_cfg, s, packed=ragged_slots is not None))
                             and name in self.variables.get("cache", {}))
                 split = {"params": 0, "cache": 0, "fp8_stats": 0, MOE_LOAD: 0}

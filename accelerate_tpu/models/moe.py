@@ -16,8 +16,19 @@ routes by sorting:
   biased scores, and the choice is among the experts of the
   ``moe_topk_group`` best groups only;
 - ``moe_shared_experts`` gated MLPs of the experts' width (one of their
-  summed width) take every token, and their result is added to the routed
-  one: a program that holds a share of the routed experts computes it whole;
+  summed width, or of ``moe_shared_dim``) take every token, and their result
+  is added to the routed one: a program that holds a share of the routed
+  experts computes it whole;
+- with ``moe_latent_dim`` (LatentMoE) the routed experts live in a narrower
+  width: one projection down before the dispatch, the experts and the
+  combine there, one projection back, both computed once whatever share is
+  held (the way back is linear, so the shares' partial sums add up); the
+  router and the shared expert read the full width;
+- ``mlp_kind`` "relu2": an expert (and the shared one) is two matrices,
+  ``W_down relu(W_up x)^2``, with no gate matrix (:func:`grouped_mlp` with
+  ``wg`` None; the kernel ``moe_experts_relu2``, which multiplies an
+  expert's own row tiles only: 22 experts a token make a thousand rows a
+  decode step, which every expert cannot all multiply);
 - every (token, chosen expert) pair whose expert is held here
   (``moe_experts_held = (first, count)``; all of them by default) is sorted
   by expert, the tokens' rows are gathered in that order, and the three
@@ -55,7 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ..ops.layers import swiglu
+from ..ops.layers import swiglu, two_term_matmul, two_terms
 from .configs import MOE_LOAD_COLLECTION as LOAD_COLLECTION
 from .configs import DecoderConfig
 
@@ -177,6 +188,21 @@ def _experts_kernel(live_ref, gmap_ref, start_ref, layer_ref, x_ref, wg_ref, wu_
             o_ref[...] += jnp.where(mine, acc[...], 0.0)
 
 
+def _grid_order(sizes):
+    """What both kernels' grids read of the pairs on each expert: how many
+    experts have a row, the experts in grid order (those with a row first, in
+    order; the rest repeat the last of them, so that nothing new is fetched
+    for them) and each expert's first row."""
+    count = sizes.shape[0]
+    live = sizes > 0
+    n_live = jnp.sum(live.astype(jnp.int32))
+    ranked = jnp.argsort(jnp.where(live, 0, 1), stable=True).astype(jnp.int32)
+    last = ranked[jnp.maximum(n_live - 1, 0)]
+    gmap = jnp.where(jnp.arange(count) < n_live, ranked, last)
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
+    return n_live, gmap, starts
+
+
 def _experts_kernel_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
     """``wg``, ``wu`` [L, E, d, m] and ``wd`` [L, E, m, d] are the layers'
     stacks as the layer scan holds them, ``layer`` which of them to
@@ -190,13 +216,7 @@ def _experts_kernel_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
     rows, d = xs.shape
     _, count, _, m = wg.shape
     tile = next(c for c in (_EXPERT_TILE, 128, m) if m % c == 0)
-    live = sizes > 0
-    n_live = jnp.sum(live.astype(jnp.int32))
-    # live experts first, in order; the rest repeat the last live one
-    ranked = jnp.argsort(jnp.where(live, 0, 1), stable=True).astype(jnp.int32)
-    last = ranked[jnp.maximum(n_live - 1, 0)]
-    gmap = jnp.where(jnp.arange(count) < n_live, ranked, last)
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes).astype(jnp.int32)])
+    n_live, gmap, starts = _grid_order(sizes)
 
     def w_in(g, t, lv, gm, st, ly):
         return (ly[0], gm[g], 0, jnp.where(g < lv[0], t, m // tile - 1))
@@ -224,6 +244,105 @@ def _experts_kernel_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
     )(n_live.reshape(1), gmap, starts, jnp.asarray(layer, jnp.int32).reshape(1), xs, wg, wu, wd)
 
 
+def _experts2_kernel(live_ref, gmap_ref, start_ref, layer_ref, x_ref, wu_ref, wd_ref, o_ref, *, tm: int):
+    """Grid (experts, tiles of the expert width), two-matrix experts
+    (``relu(x W_up)^2 W_down``). Step (g, t) walks the row tiles of ``tm``
+    rows that hold rows of expert ``gmap[g]`` (the rows are sorted by expert,
+    so they are consecutive), multiplies each by tile t of that expert of
+    layer ``layer[0]`` and adds the expert's own rows of the product to the
+    output, which stays in VMEM over the whole grid. Experts without a row
+    come last in ``gmap`` as repeats of the last live one, as in
+    :func:`_experts_kernel`."""
+    from jax.experimental import pallas as pl
+
+    g, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((g == 0) & (t == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(g < live_ref[0])
+    def _multiply():
+        e = gmap_ref[g]
+        lo, hi = start_ref[e], start_ref[e + 1]
+        w_up, w_down = wu_ref[0, 0], wd_ref[0, 0]
+
+        def product(v, w):
+            """``v @ w`` in float32. Float32 rows against bfloat16 weights go
+            in two terms (``ops/layers.two_term_matmul``, here as one tile of
+            twice the rows: the matrix unit loads a tile's weights once either
+            way, which is what a tile of 16 rows costs)."""
+            if v.dtype != jnp.float32 or w.dtype != jnp.bfloat16:
+                return jnp.dot(v.astype(w.dtype), w, preferred_element_type=jnp.float32)
+            both = jnp.dot(jnp.concatenate(two_terms(v), axis=0).astype(w.dtype), w,
+                           preferred_element_type=jnp.float32)
+            return both[:tm] + both[tm:]
+
+        def tile(r, carry):
+            at = pl.ds(pl.multiple_of(r * tm, tm), tm)
+            hidden = jnp.square(jnp.maximum(product(x_ref[at, :], w_up), 0.0))
+            out = product(hidden.astype(x_ref.dtype), w_down)
+            row = r * tm + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            o_ref[at, :] += jnp.where((row >= lo) & (row < hi), out, 0.0)
+            return carry
+
+        jax.lax.fori_loop(lo // tm, (hi + tm - 1) // tm, tile, 0)
+
+
+def _experts2_tiles(rows: int, count: int, m: int) -> tuple:
+    """``(row tile, width tile)`` of the two-matrix kernel: the row tile is 16
+    rows (a bfloat16 tile) or, where an expert expects more (``rows`` is twice
+    the expected pairs), the power of two up to 128 that holds them; the width
+    tile the widest multiple of 128 up to 1,024 that divides ``m`` (``m``
+    itself where none does)."""
+    tm = 16
+    while tm < 128 and tm < rows // (2 * count):
+        tm *= 2
+    tile = next((c for c in range(1024, 0, -128) if m % c == 0), m)
+    return tm, tile
+
+
+def _experts2_kernel_call(xs, wu, wd, sizes, layer, interpret: bool):
+    """As :func:`_experts_kernel_call` for two-matrix experts: ``wu`` [L, E,
+    d, m], ``wd`` [L, E, m, d], the layers' stacks, read where they are."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, d = xs.shape
+    _, count, _, m = wu.shape
+    tm, tile = _experts2_tiles(rows, count, m)
+    padded = -(-rows // tm) * tm
+    if padded != rows:  # (expert_rows gives multiples of 8: a few zero rows no expert owns)
+        xs = jnp.pad(xs, ((0, padded - rows), (0, 0)))
+    n_live, gmap, starts = _grid_order(sizes)
+
+    def w_in(g, t, lv, gm, st, ly):
+        return (ly[0], gm[g], 0, jnp.where(g < lv[0], t, m // tile - 1))
+
+    def w_out(g, t, lv, gm, st, ly):
+        return (ly[0], gm[g], jnp.where(g < lv[0], t, m // tile - 1), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(count, m // tile),
+        in_specs=[
+            pl.BlockSpec((padded, d), lambda g, t, *_: (0, 0)),
+            pl.BlockSpec((1, 1, d, tile), w_in),
+            pl.BlockSpec((1, 1, tile, d), w_out),
+        ],
+        out_specs=pl.BlockSpec((padded, d), lambda g, t, *_: (0, 0)),
+    )
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_EXPERT_VMEM)}
+    out = pl.pallas_call(
+        functools.partial(_experts2_kernel, tm=tm), grid_spec=grid_spec, name="moe_experts_relu2",
+        interpret=interpret, out_shape=jax.ShapeDtypeStruct((padded, d), jnp.float32), **params,
+    )(n_live.reshape(1), gmap, starts, jnp.asarray(layer, jnp.int32).reshape(1), xs, wu, wd)
+    return out[:rows]
+
+
 def grouped_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
                 sizes: jax.Array, impl: str = "xla", layer=None) -> jax.Array:
     """``(silu(x Wg_e) * (x Wu_e)) Wd_e`` for rows ``xs`` [rows, d] sorted
@@ -232,15 +351,22 @@ def grouped_mlp(xs: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     "pallas" or "interpret" (the ``moe_experts`` kernel). With ``layer``
     (the kernel only), the weights are the layers' stacks ([L, E, ...]) and
     it says which layer's experts multiply: the kernel reads them where they
-    are."""
+    are. ``wg`` None: two-matrix experts, ``relu(x Wu_e)^2 Wd_e`` (the kernel
+    ``moe_experts_relu2``)."""
     if impl != "xla":
         if layer is None:
-            wg, wu, wd, layer = wg[None], wu[None], wd[None], 0
+            wg, wu, wd, layer = None if wg is None else wg[None], wu[None], wd[None], 0
+        if wg is None:
+            return _experts2_kernel_call(xs, wu, wd, sizes, layer, impl == "interpret")
         return _experts_kernel_call(xs, wg, wu, wd, sizes, layer, impl == "interpret")
     sizes = sizes.astype(jnp.int32)
-    gate = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=jnp.float32)
+    xs = xs.astype(wu.dtype)  # (float32 rows are the kernel's two terms; here they are one)
     up = jax.lax.ragged_dot(xs, wu, sizes, preferred_element_type=jnp.float32)
-    hidden = swiglu(gate, up).astype(xs.dtype)
+    if wg is None:
+        hidden = jnp.square(jax.nn.relu(up)).astype(xs.dtype)
+    else:
+        gate = jax.lax.ragged_dot(xs, wg, sizes, preferred_element_type=jnp.float32)
+        hidden = swiglu(gate, up).astype(xs.dtype)
     return jax.lax.ragged_dot(hidden, wd, sizes, preferred_element_type=jnp.float32)
 
 
@@ -264,7 +390,7 @@ def routed_experts(x, experts, weights, wg, wu, wd, *, first: int = 0,
     [tokens, d], and the pairs on each held expert [count]. ``layer``: as
     :func:`grouped_mlp` takes it."""
     tokens, d = x.shape
-    k, count = experts.shape[-1], wg.shape[-3]
+    k, count = experts.shape[-1], wu.shape[-3]
     order, sizes, n_held = sort_pairs(experts, first, count, token_mask)
     rows = expert_rows(tokens, k, count, outputs or count)
     pair_token = order // k
@@ -318,6 +444,8 @@ class MoeMLP(nn.Module):
         b, s, d = x.shape
         m = cfg.mlp_dim
         dt = cfg.dtype
+        gated = cfg.mlp_kind != "relu2"
+        dl = cfg.moe_latent_dim or d  # the width the routed experts take and return
 
         router_w = self.param(
             "router",
@@ -337,17 +465,17 @@ class MoeMLP(nn.Module):
             wg = self.param(
                 "w_gate",
                 nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
-                (E, d, m),
-            ).astype(dt)
+                (E, dl, m),
+            ).astype(dt) if gated else None
             wu = self.param(
                 "w_up",
                 nn.with_logical_partitioning(_dense_init(), ("expert", "embed", "mlp")),
-                (E, d, m),
+                (E, dl, m),
             ).astype(dt)
             wd = self.param(
                 "w_down",
                 nn.with_logical_partitioning(_dense_init(), ("expert", "mlp", "embed")),
-                (E, m, d),
+                (E, m, dl),
             ).astype(dt)
 
         flat = x.reshape(b * s, d)
@@ -363,22 +491,43 @@ class MoeMLP(nn.Module):
         # the grouped routing before it had it
         aux_loss = jnp.mean(load_balance_loss(
             scores.reshape(b, s, R), experts.reshape(b, s, k)))
+        into = flat.astype(dt)
+        if cfg.moe_latent_dim:
+            # the experts' own width: down before the dispatch, back after the
+            # combine, once each whatever share of the experts is held. These
+            # two and the two-matrix shared expert read the normed stream as it
+            # is carried, in two terms (ops/layers.two_term_matmul): the next
+            # layers' routers turn their rounding into other experts
+            latent = [self.param(name, nn.with_logical_partitioning(_dense_init(), axes), shape).astype(dt)
+                      for name, axes, shape in (("w_latent_in", ("embed", None), (d, dl)),
+                                                ("w_latent_out", (None, "embed"), (dl, d)))]
+            with jax.named_scope("moe_latent"):
+                into = two_term_matmul(routed, latent[0])  # float32 rows: the experts' kernel takes them in two terms
         y, sizes = routed_experts(
-            flat.astype(dt), experts, weights, wg, wu, wd, first=first, outputs=R, token_mask=token_mask,
+            into, experts, weights, wg, wu, wd, first=first, outputs=R, token_mask=token_mask,
             impl=experts_impl(cfg, self.decode), layer=layer)
+        if cfg.moe_latent_dim:
+            with jax.named_scope("moe_latent"):
+                y = two_term_matmul(y, latent[1])
         if self.is_mutable_collection(LOAD_COLLECTION):
             self.variable(LOAD_COLLECTION, "pairs", lambda: jnp.zeros((E,), jnp.int32)).value = sizes
-        if cfg.moe_shared_experts:
+        ms = cfg.shared_mlp_dim
+        if ms:
             # the shared expert: every token, once, beside whatever share of
             # the routed experts is held
-            ms = m * cfg.moe_shared_experts
+            names = (("shared_gate", ("embed", "mlp"), (d, ms)),) if gated else ()
+            names += (("shared_up", ("embed", "mlp"), (d, ms)), ("shared_down", ("mlp", "embed"), (ms, d)))
             shared = [self.param(name, nn.with_logical_partitioning(_dense_init(), axes), shape).astype(dt)
-                      for name, axes, shape in (("shared_gate", ("embed", "mlp"), (d, ms)),
-                                                ("shared_up", ("embed", "mlp"), (d, ms)),
-                                                ("shared_down", ("mlp", "embed"), (ms, d)))]
+                      for name, axes, shape in names]
             with jax.named_scope("moe_shared"):
                 xs = flat.astype(dt)
-                hidden = swiglu(xs @ shared[0], xs @ shared[1])
-                y = y + jnp.matmul(hidden, shared[2], preferred_element_type=jnp.float32)
-        y = y.astype(dt).reshape(b, s, d)
+                if gated:
+                    hidden = swiglu(xs @ shared[0], xs @ shared[1])
+                    y = y + jnp.matmul(hidden, shared[2], preferred_element_type=jnp.float32)
+                else:
+                    hidden = jnp.square(jax.nn.relu(two_term_matmul(routed, shared[0])))
+                    y = y + two_term_matmul(hidden, shared[1])
+        if gated:
+            y = y.astype(dt)  # (two-matrix experts hand the stream their float32 sum)
+        y = y.reshape(b, s, d)
         return _constrain(y, ("batch", "seq", "embed"), self.mesh), aux_loss

@@ -1,6 +1,7 @@
-"""The selective state-space mixer (Mamba-1, with the inner norms of the
-Jamba family) that a layer kind may state in attention's place
-(``DecoderConfig.mixer == "ssm"``).
+"""The state-space mixers a layer kind may state in attention's place:
+:class:`SelectiveSSM` (``DecoderConfig.mixer == "ssm"``: Mamba-1, with the
+inner norms of the Jamba family) and :class:`Mamba2Mixer` (``"ssd"``: heads,
+further down). Mamba-1:
 
     [u, z] = h W_in                               (E -> 2 x D, D = ssm_expand x E)
     u'     = silu(conv(u) + b_conv)               causal, depthwise, ssm_conv_width taps
@@ -32,7 +33,9 @@ Three call forms, one mathematics:
 What a slot keeps, in the "cache" collection beside the paged leaves:
 ``ssm_state`` [slots, N, D] float32 (the wide dimension on lanes) and
 ``conv_state`` [slots, ssm_conv_width - 1, D], the convolution's last raw
-inputs (``serving/pages.STATE_LEAF_NAMES`` finds them by these names). Where the layer scan carries the collection whole (``cache_layer``),
+inputs (``serving/pages.STATE_LEAF_NAMES`` finds them by these names; with
+heads the state is [slots, D / lane, N, lane], ``ops/ssm.ssd_state_shape``,
+and the convolution's channels are D + 2 G N, kept in float32). Where the layer scan carries the collection whole (``cache_layer``),
 both are the layers' stacks with a leading layer axis and are updated in
 place: the kernel takes the stack and the layer, and the convolution's
 inputs are one dynamic slice in and one out.
@@ -47,8 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ..ops.layers import rms_norm
-from ..ops.ssm import ssm_scan
+from ..ops.layers import rms_norm, two_term_matmul
+from ..ops.ssm import ssd_scan, ssd_state_shape, ssm_scan
 from .configs import DecoderConfig
 
 def _inverse_softplus_log_uniform(lo: float = 1e-3, hi: float = 1e-1):
@@ -65,6 +68,76 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     del key
     n, d = shape
     return jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32))[:, None], (n, d)).astype(dtype)
+
+
+def _blocks_and_halo(cfg, u, conv, read, serving: bool, resume: bool,
+                     cache_positions, ragged_slots, slot_hist, kv_lengths):
+    """The blocks the recurrence walks and the convolution's inputs before
+    each, for the three call forms (the module's docstring), of either
+    mixer: ``u`` [b, s, channels] are the convolution's raw inputs. Returns
+    ``(blocks, rows a block, slot [blocks], live rows [blocks], fresh
+    [blocks], u as blocks, halo [blocks, k - 1, channels])``."""
+    k, dt_ = cfg.ssm_conv_width, u.dtype
+    b, s, d = u.shape
+    if serving and ragged_slots is not None:
+        # a pack: token blocks of one slot each; a block's first rows take
+        # the block before it where that continues its slot's segment, else
+        # the slot's kept inputs (none before position 0)
+        bt = cfg.prefill_kernel_block
+        if not bt or s % bt or b != 1:
+            raise ValueError(
+                f"a packed prefill is one batch row of whole token blocks "
+                f"(prefill_kernel_block={bt}), got {u.shape[:2]}")
+        nb = s // bt
+        row_pos = jnp.reshape(cache_positions, (nb, bt))
+        first = row_pos[:, 0]
+        rows = jnp.sum(row_pos >= 0, axis=1).astype(jnp.int32)
+        slot = jnp.where(rows > 0, ragged_slots[::bt], -1)
+        at = jnp.maximum(slot, 0)
+        fresh = (first == 0).astype(jnp.int32)
+        blocks = u.reshape(nb, bt, d)
+        kept = read(conv)[at]                                           # [nb, k-1, d]
+        back = first[:, None] - jnp.arange(k - 1, 0, -1)[None, :]       # the positions before the block
+        kept = jnp.where((back >= 0)[..., None], kept, 0)
+        before = jnp.concatenate([jnp.zeros((1, k - 1, d), dt_), blocks[:-1, bt - (k - 1):]], axis=0)
+        continues = (first > slot_hist[at]) & (slot >= 0)
+        halo = jnp.where(continues[:, None, None], before, kept)
+    else:
+        # a sequence a batch row (or a decode step's one row a slot)
+        nb, bt = b, s
+        slot = jnp.arange(b, dtype=jnp.int32)
+        live = jnp.ones((b,), bool) if not serving or kv_lengths is None else kv_lengths > 0
+        rows = jnp.where(live, s, 0).astype(jnp.int32)
+        fresh = jnp.full((b,), 0 if resume else 1, jnp.int32)
+        blocks = u
+        halo = read(conv) if resume else jnp.zeros((b, k - 1, d), dt_)
+    return nb, bt, slot, rows, fresh, blocks, halo
+
+
+def _stack_and_layer(state, cache_layer, zero_shape):
+    """The stack of states the recurrence advances and this layer's index in
+    it: the scanned stack carried whole (``cache_layer``), this layer's own
+    leaf as a stack of one, or zeros a block where nothing is kept."""
+    if state is None:
+        return jnp.zeros((1, *zero_shape), jnp.float32), 0
+    return (state.value, cache_layer) if cache_layer is not None else (state.value[None], 0)
+
+
+def _keep_conv_tail(conv, read, ext, rows, slot, packed: bool, cache_layer):
+    """Write the last ``k - 1`` raw inputs of each block that ends its slot's
+    rows here into the slot's ``conv_state`` (``ext``: halo and block)."""
+    k1 = conv.value.shape[-2]
+    tail = jax.vmap(lambda e_, n_: jax.lax.dynamic_slice_in_dim(e_, n_, k1, axis=0))(ext, rows)
+    tail = tail.astype(conv.value.dtype)
+    if packed:
+        # (the next block is another slot's or has no rows); the others
+        # write past the last slot and are dropped
+        ends = (slot >= 0) & (jnp.concatenate([slot[1:], jnp.full((1,), -1, slot.dtype)]) != slot)
+        where = jnp.where(ends, slot, read(conv).shape[0])
+        kept = read(conv).at[where].set(tail, mode="drop")
+    else:
+        kept = jnp.where((rows > 0)[:, None, None], tail, read(conv))
+    conv.value = kept if cache_layer is None else conv.value.at[cache_layer].set(kept)
 
 
 class SelectiveSSM(nn.Module):
@@ -111,40 +184,9 @@ class SelectiveSSM(nn.Module):
         stacked = cache_layer is not None
         read = lambda var: var.value[cache_layer] if stacked else var.value
 
-        # -- the blocks the recurrence walks, and the inputs before each --
-        if serving and ragged_slots is not None:
-            # a pack: token blocks of one slot each; a block's first rows take
-            # the block before it where that continues its slot's segment, else
-            # the slot's kept inputs (none before position 0)
-            bt = cfg.prefill_kernel_block
-            if not bt or s % bt or b != 1:
-                raise ValueError(
-                    f"a packed prefill is one batch row of whole token blocks "
-                    f"(prefill_kernel_block={bt}), got {x.shape[:2]}")
-            nb = s // bt
-            row_pos = jnp.reshape(cache_positions, (nb, bt))
-            first = row_pos[:, 0]
-            rows = jnp.sum(row_pos >= 0, axis=1).astype(jnp.int32)
-            slot = jnp.where(rows > 0, ragged_slots[::bt], -1)
-            at = jnp.maximum(slot, 0)
-            fresh = (first == 0).astype(jnp.int32)
-            blocks = u.reshape(nb, bt, d)
-            kept = read(conv)[at]                                           # [nb, k-1, d]
-            back = first[:, None] - jnp.arange(k - 1, 0, -1)[None, :]       # the positions before the block
-            kept = jnp.where((back >= 0)[..., None], kept, 0)
-            before = jnp.concatenate([jnp.zeros((1, k - 1, d), dt_), blocks[:-1, bt - (k - 1):]], axis=0)
-            continues = (first > slot_hist[at]) & (slot >= 0)
-            halo = jnp.where(continues[:, None, None], before, kept)
-        else:
-            # a sequence a batch row (or a decode step's one row a slot)
-            nb, bt = b, s
-            slot = jnp.arange(b, dtype=jnp.int32)
-            live = jnp.ones((b,), bool) if not serving or kv_lengths is None else kv_lengths > 0
-            rows = jnp.where(live, s, 0).astype(jnp.int32)
-            resume = self.use_cache and self.decode
-            fresh = jnp.full((b,), 0 if resume else 1, jnp.int32)
-            blocks = u
-            halo = read(conv) if resume else jnp.zeros((b, k - 1, d), dt_)
+        nb, bt, slot, rows, fresh, blocks, halo = _blocks_and_halo(
+            cfg, u, conv, read, serving, self.use_cache and self.decode,
+            cache_positions, ragged_slots, slot_hist, kv_lengths)
         ext = jnp.concatenate([halo.astype(dt_), blocks], axis=1)            # [nb, k-1+bt, d]
         acc = sum(ext[:, j:j + bt].astype(f32) * conv_w[j].astype(f32) for j in range(k))
         if conv_b is not None:
@@ -163,12 +205,7 @@ class SelectiveSSM(nn.Module):
         a = -jnp.exp(a_log.astype(f32))
 
         # -- the recurrence, and what the slot keeps --
-        if state is None:
-            stack, layer = jnp.zeros((1, nb, n, d), f32), 0
-        elif stacked:
-            stack, layer = state.value, cache_layer
-        else:
-            stack, layer = state.value[None], 0
+        stack, layer = _stack_and_layer(state, cache_layer, (nb, n, d))
         y, stack = ssm_scan(
             u_c, step, b_t, c_t, a, d_skip, stack, block_slot=slot, block_rows=rows,
             block_fresh=fresh, layer=layer,
@@ -176,17 +213,108 @@ class SelectiveSSM(nn.Module):
             impl=cfg.ssm_kernel if serving else "reference")
         if state is not None:
             state.value = stack if stacked else stack[0]
-            # the last k-1 raw inputs of each block that ends its slot's rows here
-            tail = jax.vmap(lambda e_, n_: jax.lax.dynamic_slice_in_dim(e_, n_, k - 1, axis=0))(ext, rows)
-            if serving and ragged_slots is not None:
-                # (the next block is another slot's or has no rows); the others
-                # write past the last slot and are dropped
-                ends = (slot >= 0) & (jnp.concatenate([slot[1:], jnp.full((1,), -1, slot.dtype)]) != slot)
-                where = jnp.where(ends, slot, read(conv).shape[0])
-                kept = read(conv).at[where].set(tail.astype(dt_), mode="drop")
-            else:
-                kept = jnp.where((rows > 0)[:, None, None], tail.astype(dt_), read(conv))
-            conv.value = conv.value.at[cache_layer].set(kept) if stacked else kept
+            _keep_conv_tail(conv, read, ext, rows, slot, serving and ragged_slots is not None, cache_layer)
 
         out = (y * jax.nn.silu(z.reshape(nb, bt, d).astype(f32))).astype(dt_) @ w_out.astype(dt_)
         return out.reshape(b, s, e)
+
+
+def _a_log_uniform(lo: float = 1.0, hi: float = 16.0):
+    """``A = -a`` with ``a`` uniform in [lo, hi] a head, as Mamba-2 draws it."""
+    def init(key, shape, dtype=jnp.float32):
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, lo, hi)).astype(dtype)
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """The state-space mixer with heads (Mamba-2, as ``nemotron_h`` has it).
+    ``H`` = ``ssm_num_heads``, ``P`` = ``ssm_head_dim``, ``D = H x P``, ``G`` =
+    ``ssm_n_groups``, ``N`` = ``ssm_state_dim``:
+
+        [z | xBC | dt] = h W_in                       (E -> D + (D + 2 G N) + H)
+        xBC    = silu(conv(xBC) + b_conv)             causal, depthwise, over all D + 2 G N channels
+        [x | B | C] = xBC                             x [H, P]; B, C [G, N]; head h reads group h // (H / G)
+        dt     = softplus(dt + b_dt),  A = -exp(A_log)          one scalar a head each
+        S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t[g]
+        y_t[h] = S_t[h] C_t[g] + D_skip[h] x_t[h]
+        y      = RMSNorm within each of the G groups of D / G channels of (y * silu(z)), times a weight [D]
+        out    = y W_out                              (D -> E)
+
+    The step comes out of the input projection (no ``W_x``, no ``W_dt``, no
+    inner norms). The three call forms, the blocks and what a slot keeps are
+    :class:`SelectiveSSM`'s (the module's docstring); the recurrence is
+    ``ops/ssm.ssd_scan``, float32, its state ``H x P x N`` a slot a layer."""
+
+    config: DecoderConfig
+    mesh: Optional[Mesh] = None
+    use_cache: bool = False
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, cache_positions=None, ragged_slots=None, slot_hist=None,
+                 kv_lengths=None, cache_layer=None):
+        cfg = self.config
+        e, d, n, k = cfg.embed_dim, cfg.ssm_inner_dim, cfg.ssm_state_dim, cfg.ssm_conv_width
+        h, p, g, cd = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_n_groups, cfg.ssm_conv_dim
+        dt_, f32 = cfg.dtype, jnp.float32
+        b, s = x.shape[0], x.shape[1]
+        dense = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        part = nn.with_logical_partitioning
+        w_in = self.param("w_in", part(dense, ("embed", "mlp")), (e, d + cd + h))
+        conv_w = self.param("conv_w", part(dense, (None, "mlp")), (k, cd))
+        conv_b = self.param("conv_b", part(nn.initializers.zeros, ("mlp",)), (cd,)) if cfg.ssm_conv_bias else None
+        b_dt = self.param("b_dt", part(_inverse_softplus_log_uniform(), (None,)), (h,), f32)
+        a_log = self.param("a_log", part(_a_log_uniform(), (None,)), (h,), f32)
+        d_skip = self.param("d_skip", part(nn.initializers.ones, (None,)), (h,), f32)
+        norm_w = self.param("norm_w", part(nn.initializers.ones, ("mlp",)), (d,))
+        w_out = self.param("w_out", part(dense, ("mlp", "embed")), (d, e))
+
+        # both projections take their input as two terms of the weights' dtype
+        # (ops/layers.two_term_matmul): what this mixer rounds, the expert
+        # layer behind it turns into another choice of 22 experts. Nothing is
+        # rounded between the input projection and the recurrence, and the
+        # convolution's kept inputs are float32 like the state
+        zxd = two_term_matmul(x, w_in.astype(dt_))
+        z, xbc, step = zxd[..., :d], zxd[..., d:d + cd], zxd[..., d + cd:]
+
+        serving = self.use_cache and self.decode and cache_positions is not None
+        if serving and ragged_slots is None and s != 1:
+            raise NotImplementedError(
+                "a state-space layer decodes one token a slot: several (speculative "
+                "verify) would need the state rolled back on a rejected draft")
+        state = conv = None
+        if self.use_cache:
+            state = self.variable("cache", "ssm_state", jnp.zeros, (b, *ssd_state_shape(d, g, n)), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros, (b, k - 1, cd), f32)
+        stacked = cache_layer is not None
+        read = lambda var: var.value[cache_layer] if stacked else var.value
+
+        nb, bt, slot, rows, fresh, blocks, halo = _blocks_and_halo(
+            cfg, xbc, conv, read, serving, self.use_cache and self.decode,
+            cache_positions, ragged_slots, slot_hist, kv_lengths)
+        ext = jnp.concatenate([halo, blocks], axis=1)                        # [nb, k-1+bt, cd]
+        acc = sum(ext[:, j:j + bt].astype(f32) * conv_w[j].astype(f32) for j in range(k))
+        if conv_b is not None:
+            acc = acc + conv_b.astype(f32)
+        xbc_c = jax.nn.silu(acc)                                             # [nb, bt, cd] float32
+        x_c = xbc_c[..., :d]
+        b_t = xbc_c[..., d:d + g * n].reshape(nb, bt, g, n)
+        c_t = xbc_c[..., d + g * n:].reshape(nb, bt, g, n)
+        step = jax.nn.softplus(step.reshape(nb, bt, h) + b_dt)
+        a = -jnp.exp(a_log.astype(f32))
+        a_head = lambda v: jnp.repeat(v, p, axis=-1)  # a head's scalar for each of its channels
+
+        stack, layer = _stack_and_layer(state, cache_layer, (nb, *ssd_state_shape(d, g, n)))
+        y, stack = ssd_scan(
+            x_c, a_head(step), b_t, c_t, a_head(a), a_head(d_skip), stack, block_slot=slot,
+            block_rows=rows, block_fresh=fresh, layer=layer,
+            impl=cfg.ssm_kernel if serving else "reference")
+        if state is not None:
+            state.value = stack if stacked else stack[0]
+            _keep_conv_tail(conv, read, ext, rows, slot, serving and ragged_slots is not None, cache_layer)
+
+        # the gate first, then the norm within each group of D / G channels
+        y = (y * jax.nn.silu(z.reshape(nb, bt, d))).reshape(nb, bt, g, d // g)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
+        y = y.reshape(nb, bt, d) * norm_w.astype(f32)
+        return two_term_matmul(y, w_out.astype(dt_)).reshape(b, s, e)  # float32, as the stream takes it

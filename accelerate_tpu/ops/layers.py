@@ -34,6 +34,34 @@ def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
+def two_terms(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Float32 ``x`` as ``hi + lo``, both float32: hi is x with its low 16 bits
+    cleared, which bfloat16 holds exactly, lo the rest, which bfloat16 holds
+    to 8 bits more. (Not x rounded to bfloat16 and back: the chip's compiler
+    may drop such a pair of conversions as excess precision, and lo with it:
+    my chip run, PR 44.) Plain ``lax``, so a pallas kernel may call it."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000)
+    hi = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return hi, x - hi
+
+
+def two_term_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` in float32 with ``x`` taken as two terms of ``w``'s dtype, ``x
+    = hi + lo``: both terms go through one product, stacked, so that the
+    weights are read once (a bandwidth-bound step pays no byte for it, its
+    matrix unit twice the rows) and the result carries ``x`` to 2^-16 where
+    one bfloat16 term carries it to 2^-8. For the layers whose rounding a
+    discrete choice downstream amplifies (the mixer with heads and the dense
+    parts of a LatentMoE layer, whose router picks 22 of 512 by score: PERF.md
+    section 6, PR 44). Weights of another dtype than bfloat16 take ``x`` in
+    theirs, in one term."""
+    f32 = jnp.float32
+    if w.dtype != jnp.bfloat16:
+        return jnp.matmul(x.astype(w.dtype), w, preferred_element_type=f32)
+    out = jnp.matmul(jnp.stack(two_terms(x.astype(f32))).astype(w.dtype), w, preferred_element_type=f32)
+    return out[0] + out[1]
+
+
 def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     """YaRN's attention scale for a context stretched ``factor`` times:
     ``0.1 x mscale x ln(factor) + 1`` (1 where nothing is stretched)."""

@@ -233,3 +233,208 @@ def ssm_scan(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows, block_fre
                                         block_rows=block_rows, block_fresh=block_fresh, layer=layer)
     return _ssm_scan_call(u, dt, b, c, a, d_skip, state, block_slot, block_rows, block_fresh, layer,
                           mode == "interpret")
+
+
+# -- the recurrence with heads (Mamba-2) ---------------------------------------
+#
+# One token advances a state ``S`` in R^(H x P x N) (``H`` heads of ``P``
+# channels, ``N`` = ``d_state``):
+#
+#     S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t[g(h)]
+#     y_t[h] = S_t[h] C_t[g(h)] + D[h] x_t[h]
+#
+# ``A`` and ``dt`` are one scalar a head and ``B``, ``C`` one vector a group of
+# heads. Per channel ``d = h P + p`` that is Mamba-1's update with a decay that
+# does not depend on ``n`` and with ``B``, ``C`` chosen by the channel's group,
+# so the callers hand the per-head quantities over **a channel** (``dt``, ``a``,
+# ``d_skip`` repeated ``P`` times: a few KB a row beside a state of megabytes)
+# and the blocks' descriptors are the ones above. The state is laid out
+# ``[layers, slots, D / lane, N, lane]``: the channels in chunks of ``lane``
+# (128 where a group's channels are whole lanes; a chunk then lies in one
+# group), ``N`` on sublanes, so that a grid step walks its slot's state chunk
+# by chunk, 16 vector registers at a time at N = 128, with B and C down the
+# sublanes, the same in every lane, and the read a sum over sublanes. Rows,
+# B and C keep the layout they are computed in (a chunk is an index, not a
+# copy).
+
+
+def ssd_state_shape(channels: int, groups: int, n: int) -> tuple:
+    """``(chunks, n, lane)``: a slot's state in one layer of ``channels``
+    (heads x head_dim) channels in ``groups`` groups of B and C."""
+    per_group = channels // groups
+    lane = 128 if per_group % 128 == 0 else per_group
+    return channels // lane, n, lane
+
+
+def ssd_scan_reference(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows, block_fresh,
+                       layer=0):
+    """The recurrence in ``jax.numpy``: blocks in order, rows in order.
+    ``u``, ``dt`` [blocks, rows, D] (the step a channel); ``b``, ``c``
+    [blocks, rows, G, N]; ``a``, ``d_skip`` [D]; ``state`` [layers, slots,
+    D / lane, N, lane] float32. Returns ``(y [blocks, rows, D] float32,
+    state)``."""
+    f32 = jnp.float32
+    _, _, chunks, _, lane = state.shape
+    rows, width = u.shape[1], u.shape[2]
+    per = chunks // b.shape[2]  # chunks a group
+    a_c = a.astype(f32).reshape(chunks, 1, lane)
+    skip_c = d_skip.astype(f32).reshape(chunks, lane)
+    stack = state[layer]
+
+    def block(stack, xs):
+        u_b, dt_b, b_b, c_b, slot, n, fresh = xs
+        at = jnp.maximum(slot, 0)
+        before = stack[at]
+
+        def row(s, r):
+            u_t, dt_t, b_t, c_t, i = r
+            dt_t = jnp.where(i < n, dt_t, 0.0).reshape(chunks, 1, lane)  # dt = 0: the state stays
+            u_t = u_t.reshape(chunks, 1, lane)
+            b_t = jnp.repeat(b_t, per, axis=0)[:, :, None]               # [chunks, N, 1]
+            c_t = jnp.repeat(c_t, per, axis=0)[:, :, None]
+            s = jnp.exp(dt_t * a_c) * s + (dt_t * u_t) * b_t
+            return s, (jnp.sum(s * c_t, axis=1) + skip_c * u_t[:, 0]).reshape(width)
+
+        s, y = jax.lax.scan(row, jnp.where(fresh > 0, 0.0, before),
+                            (u_b.astype(f32), dt_b.astype(f32), b_b.astype(f32), c_b.astype(f32),
+                             jnp.arange(rows)))
+        keep = (slot < 0) | ((n <= 0) & (fresh <= 0))
+        return stack.at[at].set(jnp.where(keep, before, s)), y
+
+    stack, y = jax.lax.scan(block, stack, (u, dt, b, c, block_slot, block_rows, block_fresh))
+    return y, state.at[layer].set(stack)
+
+
+_SSD_VMEM = 64 * 2 ** 20  # a slot's 4 MB state in and out, double-buffered, beside a 64-row block's rows
+
+
+def _ssd_scan_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
+                     u_ref, dt_ref, b_ref, c_ref, a_ref, dskip_ref, s_in_ref,
+                     y_ref, s_out_ref, bb, cc, *, group: int):
+    del layer_ref, slot_ref  # the block specs read them
+    f32 = jnp.float32
+    j = pl.program_id(0)
+    rows, chunks, lane = u_ref.shape[1:]
+    groups, n_state = b_ref.shape[2:]
+    per = chunks // groups
+    n = rows_ref[j]
+
+    # as ssm_scan: the state stays in the output block while consecutive
+    # blocks continue one slot
+    @pl.when(cont_ref[j] == 0)
+    def _():
+        s_out_ref[...] = s_in_ref[...]
+
+    @pl.when(fresh_ref[j] == 1)
+    def _():
+        s_out_ref[...] = jnp.zeros(s_out_ref.shape, f32)
+
+    @pl.when(n < rows)
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    def walk(base):  # ``group`` rows from row ``base``, over every chunk of the state
+        def of_group(g, carry):
+            # a row's B and C are vectors along the lanes; the state wants
+            # them down the sublanes, the same in every lane: the row spread
+            # over a square tile and turned (once a group of heads, for all
+            # its chunks)
+            for i in range(group):
+                for ref, tile in ((b_ref, bb), (c_ref, cc)):
+                    tile[i] = jnp.broadcast_to(ref[0, base + i, pl.ds(g, 1), :], (lane, n_state)).T
+
+            def of_chunk(k, carry):
+                ch = g * per + k
+                a, d_skip = a_ref[pl.ds(ch, 1), :], dskip_ref[pl.ds(ch, 1), :]    # [1, lane]
+                s = s_out_ref[0, 0, ch]                                            # [N, lane]
+                for i in range(group):
+                    u_i = u_ref[0, base + i, pl.ds(ch, 1), :]
+                    dt_i = jnp.where(base + i < n, dt_ref[0, base + i, pl.ds(ch, 1), :], 0.0)  # padding: no advance
+                    s = jnp.exp(dt_i * a) * s + (dt_i * u_i) * bb[i]
+                    y_ref[0, base + i, pl.ds(ch, 1), :] = (
+                        jnp.sum(s * cc[i], axis=0, keepdims=True) + d_skip * u_i).astype(y_ref.dtype)
+                s_out_ref[0, 0, ch] = s
+                return carry
+
+            return jax.lax.fori_loop(0, per, of_chunk, carry)
+
+        jax.lax.fori_loop(0, groups, of_group, 0)
+
+    if rows == group:  # one group (a decode step's one row): no loop
+        pl.when(n > 0)(lambda: walk(0))
+    else:  # (a row is a leading index of its blocks: any base will do)
+        def body(r, carry):
+            walk(r * group)
+            return carry
+
+        jax.lax.fori_loop(0, (n + group - 1) // group, body, 0)
+
+
+def _ssd_scan_call(u, dt, b, c, a, d_skip, state, block_slot, block_rows, block_fresh, layer,
+                   interpret: bool):
+    f32 = jnp.float32
+    nb, rows, width = u.shape
+    _, _, chunks, n, lane = state.shape
+    groups = b.shape[2]
+    group = 8 if rows % 8 == 0 else 1
+    if group == 1 and rows != 1:
+        raise ValueError(f"ssd_scan walks blocks of 1 row or of a multiple of 8, got {rows}")
+    if chunks * lane != width or chunks % groups:
+        raise ValueError(f"the state {state.shape} does not lay out {width} channels in {groups} groups")
+    at, n_rows, fresh, cont = _block_descriptors(block_slot, block_rows, block_fresh)
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1), at, n_rows, fresh, cont)
+    by_chunk = lambda v: v.astype(f32).reshape(*v.shape[:-1], chunks, lane)  # a chunk by an index, no copy
+
+    def a_block(j, *_):
+        return (j, 0, 0, 0)
+
+    def whole(j, *_):
+        return (0, 0)
+
+    def of_slot(j, ly, sl, *_):
+        return (ly[0], sl[j], 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, rows, chunks, lane), a_block),    # u
+        pl.BlockSpec((1, rows, chunks, lane), a_block),    # dt, a channel
+        pl.BlockSpec((1, rows, groups, n), a_block),       # b
+        pl.BlockSpec((1, rows, groups, n), a_block),       # c
+        pl.BlockSpec((chunks, lane), whole),               # a, a channel
+        pl.BlockSpec((chunks, lane), whole),               # d_skip
+        pl.BlockSpec((1, 1, chunks, n, lane), of_slot),    # the layers' states, this block's slot
+    ]
+    out_specs = [pl.BlockSpec((1, rows, chunks, lane), a_block),
+                 pl.BlockSpec((1, 1, chunks, n, lane), of_slot)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars), grid=(nb,), in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((group, n, lane), f32), pltpu.VMEM((group, n, lane), f32)])
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_SSD_VMEM)}
+    operands = (by_chunk(u), by_chunk(dt), b.astype(f32), c.astype(f32), by_chunk(a), by_chunk(d_skip), state)
+    y, state = pl.pallas_call(
+        functools.partial(_ssd_scan_kernel, group=group),
+        grid_spec=grid_spec, name="ssd_scan", interpret=interpret,
+        out_shape=[jax.ShapeDtypeStruct((nb, rows, chunks, lane), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={len(scalars) + len(operands) - 1: 1},
+        **params,
+    )(*scalars, *operands)
+    return y.reshape(nb, rows, width), state
+
+
+def ssd_scan(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows, block_fresh, layer=0,
+             impl: Optional[str] = None):
+    """The recurrence with heads over blocks (the comment above): ``u``,
+    ``dt`` [blocks, rows, D] with the step a channel, ``b``, ``c`` [blocks,
+    rows, G, N], ``a``, ``d_skip`` [D], ``state`` [layers, slots,
+    *ssd_state_shape] float32. Returns ``(y [blocks, rows, D] float32,
+    state)`` with this ``layer``'s slots advanced. ``impl``:
+    :func:`resolve_ssm_kernel`; the kernel is ``pallas_call(name="ssd_scan")``."""
+    mode = resolve_ssm_kernel(impl)
+    if state.dtype != jnp.float32:
+        raise ValueError(f"the recurrent state is float32, got {state.dtype}")
+    if mode == "reference":
+        return ssd_scan_reference(u, dt, b, c, a, d_skip, state, block_slot=block_slot,
+                                  block_rows=block_rows, block_fresh=block_fresh, layer=layer)
+    return _ssd_scan_call(u, dt, b, c, a, d_skip, state, block_slot, block_rows, block_fresh, layer,
+                          mode == "interpret")

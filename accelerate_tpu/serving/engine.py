@@ -330,7 +330,7 @@ class ServingEngine:
         # a state-space mixer's state a slot (pages.CacheKind "state"): a
         # cached prefix would need its snapshot at the page boundary, a
         # page-out its copy, a rejected draft its rollback
-        has_state = any(getattr(c, "mixer", "attention") == "ssm" for c in run_cfgs)
+        has_state = any(getattr(c, "has_state", False) for c in run_cfgs)
         # a closing window with pooled summaries (EVA attention): a shared
         # prefix would have to end at a window's close, a page-out needs the
         # open window's summaries, a rejected draft may have pooled a page
@@ -453,13 +453,17 @@ class ServingEngine:
         # serving/decode_kernel_active gauge): in every layer kind
         pcfg = self._paged_def.config
         all_runs = pcfg.run_configs()
-        ssm_runs = [c for c in all_runs if c.mixer == "ssm"]
-        run_cfgs = [c for c in all_runs if c.mixer != "ssm"]  # the attention kinds
-        # a state-space kind: whether its recurrence runs the ssm_scan kernel,
+        state_runs = [c for c in all_runs if c.has_state]
+        run_cfgs = [c for c in all_runs if c.mixer == "attention"]  # the attention kinds
+        # a state-space kind: whether its recurrence runs its kernel (ssm_scan
+        # for Mamba-1's, ssd_scan for the mixer with heads: the
+        # ssm_kernel_active and ssd_kernel_active gauges say which is engaged),
         # and whether both programs carry the layers' states whole and update
-        # them in place (the ssm_kernel_active and state_in_place gauges)
-        self._ssm_kernel_costed = bool(ssm_runs) and all(ssm_kernel_active(c) for c in ssm_runs)
-        self._state_in_place = bool(ssm_runs) and all(c.scan_layers for c in ssm_runs)
+        # them in place (the state_in_place gauge)
+        by_mixer = lambda name: [c for c in state_runs if c.mixer == name]
+        self._ssm_kernel_costed = bool(by_mixer("ssm")) and all(ssm_kernel_active(c) for c in by_mixer("ssm"))
+        self._ssd_kernel_costed = bool(by_mixer("ssd")) and all(ssm_kernel_active(c) for c in by_mixer("ssd"))
+        self._state_in_place = bool(state_runs) and all(c.scan_layers for c in state_runs)
         self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
         # ... and whether that step updates the arena in place, the
         # stacked leaves carried through the layer scan (the
@@ -678,7 +682,7 @@ class ServingEngine:
         names = set()
         for name, over in cfg.layer_kinds:
             kcfg = dataclasses.replace(cfg, **over, layer_kinds=(), layer_pattern=())
-            if kcfg.mixer != "ssm":  # a state a slot has no pool to size
+            if kcfg.mixer == "attention":  # a state a slot has no pool to size, a layer without a mixer nothing
                 names.add(kcfg.cache_kind)
             if kcfg.attn_window is not None:
                 span = -(-(kcfg.attn_window + self.prefill_chunks[-1]) // self.page_size) + 1
@@ -704,10 +708,11 @@ class ServingEngine:
         itemsize = jnp.dtype(pcfg.dtype).itemsize
         state_layers = slot_bytes = 0
         for c in pcfg.run_configs():
-            if c.mixer == "ssm":
+            if c.has_state:
                 state_layers += c.num_layers
-                slot_bytes += c.num_layers * c.ssm_inner_dim * (
-                    c.ssm_state_dim * 4 + (c.ssm_conv_width - 1) * itemsize)
+                slot_bytes += c.num_layers * c.state_slot_bytes
+                continue
+            if c.mixer == "none":  # a feed-forward part alone keeps nothing
                 continue
             if c.kv_lora_rank is not None:
                 # a latent kind: one entry a token for all heads, at the
@@ -3098,7 +3103,7 @@ class ServingEngine:
                       if self._latent_kind else {}),
                    # the live slots' states advance one token each (an idle
                    # slot's state is copied in and out unchanged: not counted)
-                   **({"ssm_slots": len(roster)} if self._state_kind else {})) as sp_d:
+                   **({"ssm_slots": len(roster), "ssm_rows": len(roster)} if self._state_kind else {})) as sp_d:
             self._note_forensics(
                 "decode_step" if k == 1 else f"decode_burst{k}",
                 {"tokens": self._tokens, "lengths": self._lengths,
@@ -3380,12 +3385,13 @@ class ServingEngine:
             out["serving/latent_bytes_per_token"] = self._latent_kind.token_bytes
             out["serving/mla_kernel_active"] = int(self._mla_kernel_costed)
         if self._state_kind is not None:
-            # the state a slot keeps beside its pages (of arena_bytes), whether
-            # its recurrence runs the ssm_scan kernel, and whether the programs
-            # hold one copy of it
+            # the state a slot keeps beside its pages (of arena_bytes), which
+            # of the two recurrences' kernels is engaged (ssm_scan, ssd_scan),
+            # and whether the programs hold one copy of the state
             out["serving/state_bytes"] = self.state_bytes
             out["serving/state_bytes_per_slot"] = self._state_kind.slot_bytes
             out["serving/ssm_kernel_active"] = int(self._ssm_kernel_costed)
+            out["serving/ssd_kernel_active"] = int(self._ssd_kernel_costed)
             out["serving/state_in_place"] = int(self._state_in_place)
         for kind in self._kinds[1:]:
             out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use
@@ -3512,14 +3518,16 @@ def _load_args(sp, load, pairs_all: int, rows: int) -> None:
     """Expert load on a dispatch span: ``expert_pairs`` (pairs on held
     experts), ``expert_pairs_all`` (tokens x k over the expert layers),
     ``expert_load_max`` (most pairs on one expert of one layer),
-    ``experts_idle`` (held experts of a layer that got no token) and
-    ``expert_chunks`` (grouped products of ``rows`` rows the program made,
+    ``experts_idle`` (held experts of a layer that got no token),
+    ``experts_touched`` (those that got one: the experts whose weights the
+    step reads) and ``expert_chunks`` (grouped products of ``rows`` rows the program made,
     over the expert layers: one a layer with a held pair where the load fits
     ``models/moe.expert_rows``, more where a burst overflowed it)."""
     sp.args["expert_pairs"] = int(load.sum())
     sp.args["expert_pairs_all"] = int(pairs_all)
     sp.args["expert_load_max"] = int(load.max())
     sp.args["experts_idle"] = int((load == 0).sum())
+    sp.args["experts_touched"] = int((load > 0).sum())
     sp.args["expert_chunks"] = sum(expert_chunks(n, rows) for n in load.sum(axis=-1))
 
 

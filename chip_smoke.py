@@ -368,6 +368,90 @@ def ssm_phase(S: Sizes, seed: int, on_chip: bool) -> None:
         say(line)
 
 
+def ssd_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    """The two kernels ISSUE 44 brought, compiled by Mosaic at the published
+    shapes of the Nemotron-3-Super cell against their ``jax.numpy`` reads (tiny
+    and interpreted in the rehearsal): ``ssd_scan`` (128 heads x 64 x 128 of
+    state a slot, 8 groups, 96 slots: a decode step with dead slots between,
+    and a pack of four 64-row token blocks, on a stack of two layers of which
+    the second is advanced; what no block advances comes back bit for bit) and
+    ``moe_experts_relu2`` (128 two-matrix experts of 1,024 -> 2,688 -> 1,024 out
+    of a stack of two layers, at a decode step's 1,056 rows and a pack's 2,816,
+    against a loop over the experts by hand)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.models.moe import grouped_mlp
+    from accelerate_tpu.ops.ssm import resolve_ssm_kernel, ssd_scan, ssd_state_shape
+
+    def timed(fn, args, line):
+        if on_chip:
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            line += f"; {1e6 * (time.perf_counter() - t0) / 20:.0f} us a call, host clock, dispatch included"
+        say(line)
+
+    heads, p, groups, n, slots, bt = (128, 64, 8, 128, 96, 64) if on_chip else (8, 8, 2, 16, 8, 8)
+    width, mode = heads * p, resolve_ssm_kernel(S.kernel_mode)
+    live = (np.arange(slots) % 5 != 3).astype(np.int32)
+    shapes = {
+        "decode step": (np.arange(slots), live, np.zeros(slots, np.int32), 1),
+        "packed prefill": (np.array([2, 2, 0, -1]), np.array([bt, bt - 3, bt // 2 + 1, 0]), np.array([1, 0, 0, 0]), bt),
+    }
+    a_head = lambda v: jnp.repeat(v, p, axis=-1)
+    for name, (slot, rows, fresh, block) in shapes.items():
+        key = jax.random.split(jax.random.key(seed + block), 7)
+        nb = len(slot)
+        args = (jax.random.normal(key[0], (nb, block, width)),
+                a_head(jax.nn.softplus(jax.random.normal(key[1], (nb, block, heads)) - 3.0)),
+                jax.random.normal(key[2], (nb, block, groups, n)), jax.random.normal(key[3], (nb, block, groups, n)),
+                a_head(-jnp.exp(0.5 * jax.random.normal(key[4], (heads,)))), a_head(jax.random.normal(key[5], (heads,))),
+                jax.random.normal(key[6], (2, slots, *ssd_state_shape(width, groups, n))))
+        kw = dict(block_slot=jnp.asarray(slot, jnp.int32), block_rows=jnp.asarray(rows, jnp.int32),
+                  block_fresh=jnp.asarray(fresh, jnp.int32), layer=1)
+        run = {impl: jax.jit(lambda *a, impl=impl: ssd_scan(*a, impl=impl, **kw)) for impl in (mode, "reference")}
+        (y, state), (y_ref, state_ref) = (jax.block_until_ready(run[impl](*args)) for impl in (mode, "reference"))
+        err_y = max(float(jnp.max(jnp.abs(y[j, :r] - y_ref[j, :r]))) for j, r in enumerate(rows) if slot[j] >= 0 and r)
+        err_s = float(jnp.max(jnp.abs(state - state_ref)))
+        # (compared on the device: a stack of states is 0.8 GB at the published shapes)
+        assert err_s <= 1e-4 * (1.0 + float(jnp.max(jnp.abs(state_ref)))) and err_y <= 2e-3, (err_s, err_y)
+        advanced = {int(s) for s, r, f in zip(slot, rows, fresh) if s >= 0 and (r or f)}
+        kept = jnp.asarray([i for i in range(slots) if i not in advanced])
+        assert bool(jnp.array_equal(state[0], args[-1][0]))
+        assert bool(jnp.array_equal(state[1, kept], args[-1][1, kept]))
+        timed(run[mode], args, f"  ssd_scan {name} ({nb} blocks x {block} rows, {heads} x {p} x {n}, {groups} groups, "
+                               f"{mode}): max|y-ref|={err_y:.2e} max|S-ref|={err_s:.2e}")
+
+    held, d, m = (128, 1024, 2688) if on_chip else (8, 32, 128)
+    impl = "pallas" if on_chip else "interpret"
+    key = jax.random.split(jax.random.key(seed + 7), 4)
+    wu = (jax.random.normal(key[0], (2, held, d, m)) * d ** -0.5).astype(jnp.bfloat16)
+    wd = (jax.random.normal(key[1], (2, held, m, d)) * m ** -0.5).astype(jnp.bfloat16)
+    for name, rows in (("decode step", 1056 if on_chip else 48), ("packed prefill", 2816 if on_chip else 96)):
+        xs = jax.random.normal(key[2], (rows, d))  # float32 rows, which the kernel multiplies in two terms
+        # the expected load: half the rows filled, two experts without a pair, sizes uneven
+        load = np.random.default_rng(seed).multinomial(rows // 2, np.ones(held - 2) / (held - 2))
+        sizes = jnp.asarray(np.concatenate([load[:3], [0], load[3:-1], [0], load[-1:]]), jnp.int32)
+        # (the stacks are arguments: a jitted function that closes over 1.4 GB bakes it into its program)
+        kernel = jax.jit(lambda x, s, wu, wd: grouped_mlp(x, None, wu, wd, s, impl, layer=jnp.int32(1)))
+        got = np.asarray(jax.block_until_ready(kernel(xs, sizes, wu, wd)))
+        # by hand, an expert at a time over its own rows, in float32
+        want, lo, x32 = np.zeros((rows, d), np.float32), 0, np.asarray(xs, np.float32)
+        for e, n in enumerate(np.asarray(sizes)):
+            if n:
+                hidden = np.square(np.maximum(x32[lo:lo + n] @ np.asarray(wu[1, e], np.float32), 0.0))
+                want[lo:lo + n] = hidden @ np.asarray(wd[1, e], np.float32)
+            lo += n
+        err, scale = float(np.abs(got - want).max()), float(np.abs(want).max())
+        assert err <= 2e-4 * max(scale, 1.0), (err, scale)
+        assert not got[rows // 2:].any()
+        timed(kernel, (xs, sizes, wu, wd), f"  moe_experts_relu2 {name} ({rows} rows, {held} experts of {d} -> {m} -> {d}, "
+                                   f"{int((np.asarray(sizes) > 0).sum())} with a pair, {impl}): max|y-ref|={err:.2e} of {scale:.2f}")
+
+
 def eva_phase(S: Sizes, seed: int, on_chip: bool) -> None:
     """The ``eva_pool`` kernel against ``eva_pool_reference`` at the published
     widths of the closing-window cell (32 kv heads of 128, pages of 16, a
@@ -922,6 +1006,7 @@ def main() -> int:
         [("kernels vs references", kernels_phase), ("state-space scan vs reference", ssm_phase),
          ("closing-window pooling vs reference", eva_phase),
          ("latent attention's kernels vs their dense reads", latent_phase),
+         ("ssd: the recurrence with heads and two-matrix experts vs their jax.numpy reads", ssd_phase),
          ("train", accelerator_train), ("serve", serve_phase)]
     )
     if args.phase:

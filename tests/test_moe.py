@@ -578,3 +578,141 @@ def test_expert_chunks_count_the_loops_passes():
     assert rows == 16 and expert_rows(256, 8, 8, 256) == 128
     assert [expert_chunks(n, rows) for n in (0, 1, 16, 17, 33)] == [0, 1, 1, 2, 3]
     assert expert_chunks(64, expert_rows(8, 8, 16, 16)) == 1
+
+
+# -- two-matrix experts in a latent, 22 experts a token (ISSUE 44) ------------------
+
+def _relu2_loop(xs, wu, wd, sizes):
+    """``relu(x Wu_e)^2 Wd_e`` expert by expert over rows sorted by expert."""
+    out, lo = np.zeros((xs.shape[0], wd.shape[-1]), np.float32), 0
+    for e, n in enumerate(np.asarray(sizes)):
+        rows = np.asarray(xs[lo:lo + n], np.float32)
+        out[lo:lo + n] = np.square(np.maximum(rows @ np.asarray(wu[e], np.float32), 0.0)) @ np.asarray(wd[e], np.float32)
+        lo += n
+    return out
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("sizes", [[3, 0, 9, 1, 0, 0, 20, 7], [0, 0, 0, 0, 0, 0, 0, 5], [40, 0, 0, 0, 0, 0, 0, 0],
+                                   [0] * 8], ids=["uneven", "the_last_expert_alone", "one_expert_over_row_tiles", "none"])
+def test_two_matrix_relu2_experts_are_a_loop_over_the_experts(impl, sizes):
+    """``grouped_mlp`` without a gate matrix: ``ragged_dot`` and the
+    ``moe_experts_relu2`` kernel interpreted (rows in tiles of 16, an expert's
+    rows anywhere in them, rows past the experts' sum left zero) against a
+    loop over the experts."""
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    xs = jax.random.normal(k[0], (48, 32))
+    wu = jax.random.normal(k[1], (8, 32, 128)) * 32 ** -0.5
+    wd = jax.random.normal(k[2], (8, 128, 32)) * 128 ** -0.5
+    got = grouped_mlp(xs, None, wu, wd, jnp.asarray(sizes, jnp.int32), impl)
+    np.testing.assert_allclose(np.asarray(got), _relu2_loop(xs, wu, wd, sizes), atol=2e-5)
+    assert not np.asarray(got[sum(sizes):]).any()
+
+
+def test_the_relu2_kernel_reads_its_layer_out_of_the_stack():
+    k = jax.random.split(jax.random.PRNGKey(4), 3)
+    xs = jax.random.normal(k[0], (24, 32))
+    wu = jax.random.normal(k[1], (3, 4, 32, 128)) * 32 ** -0.5
+    wd = jax.random.normal(k[2], (3, 4, 128, 32)) * 128 ** -0.5
+    sizes = jnp.asarray([5, 0, 11, 2], jnp.int32)
+    for layer in range(3):
+        got = grouped_mlp(xs, None, wu, wd, sizes, "interpret", layer=jnp.int32(layer))
+        np.testing.assert_allclose(np.asarray(got), _relu2_loop(xs, wu[layer], wd[layer], sizes), atol=2e-5)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_22_of_512_like_routing_is_a_brute_force_choice(seed):
+    """11 of 64 sigmoid scores with a selection bias and a scaling factor of
+    5, no group stage: the same experts as the choice by hand over the biased
+    scores, the weights the unbiased scores normalised over the chosen, times
+    5; and the pairs sorted for a held quarter are that quarter's, by expert."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    scores = jax.nn.sigmoid(jax.random.normal(k1, (48, 64)))
+    bias = 0.05 * jax.random.normal(k2, (64,))
+    experts, weights = top_k_routing(scores, 11, bias, routed_scale=5.0)
+    assert [sorted(map(int, row)) for row in np.asarray(experts)] == _brute_force_choice(scores, bias, 11, 1, 1)
+    chosen = np.take_along_axis(np.asarray(scores), np.asarray(experts), axis=-1)
+    np.testing.assert_allclose(np.asarray(weights), 5.0 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    order, sizes, n_held = sort_pairs(experts, 16, 16)
+    flat = np.asarray(experts).reshape(-1)
+    held = [(e - 16, i) for i, e in enumerate(flat) if 16 <= e < 32]
+    assert int(n_held) == len(held) and list(np.asarray(sizes)) == [sum(1 for e, _ in held if e == j) for j in range(16)]
+    assert [int(i) for i in np.asarray(order)[:len(held)]] == [i for _, i in sorted(held)]
+    # what the program multiplies at once: twice the expected pairs of the held quarter
+    assert expert_rows(48, 11, 16, 64) == 264 and expert_rows(96, 22, 128, 512) == 1056
+    assert expert_rows(256, 22, 128, 512) == 2816
+
+
+def _nemotron_arch():
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import manifest
+
+    return manifest.load_arch("nemotron_h")
+
+
+@pytest.mark.parametrize("impl", [None, "interpret"], ids=["ragged_dot", "kernel_interpreted"])
+def test_the_four_shares_of_a_latent_layer_add_up_to_the_uncut_layer(impl):
+    """The share test of the model-configs guide, section 4, for LatentMoE: 4
+    chips hold 8 of 32 two-matrix relu2 experts each; the program's expert
+    layer on each projects into the latent, computes its own experts' part
+    there, projects the partial sum back (linear, so the shares add up) and
+    adds the shared expert whole; the four parts less three times the shared
+    expert's result equal what the reference gives for the whole layer with
+    all 32 held: both latent projections and the shared expert counted once."""
+    import json
+    import os
+
+    arch = _nemotron_arch()
+    import weights as W
+
+    ref = arch.reference
+    with open(os.path.join(os.path.dirname(arch.__file__), "..", "configs",
+                           "nemotron3-super-120b-serve-11l-ep4.json")) as f:
+        c = json.load(f)
+    c.update({k: v for k, v in c.pop("rehearsal").items() if not isinstance(v, dict)})
+    whole = dict(c, num_hidden_layers=1, hybrid_override_pattern="E", n_routed_experts=32,
+                 published={"n_routed_experts": 32})
+    w = W.make_jit(ref, whole, 5, jnp.float32)
+    lw = ref.layer_weights(whole, w, 0)
+    u = jax.random.normal(jax.random.PRNGKey(0), (24, c["hidden_size"]))
+    want = np.asarray(ref.latent_moe(whole, "float32", u, lw))
+    shared = np.asarray(ref._relu2_mlp(u, lw["up_shared"], lw["down_shared"], "float32"))
+    parts = 0
+    for first in range(0, 32, 8):
+        cfg = DecoderConfig.tiny(
+            embed_dim=c["hidden_size"], mlp_dim=c["moe_intermediate_size"], mlp_kind="relu2", moe_num_experts=8,
+            moe_router_outputs=32, moe_experts_held=(first, 8), moe_top_k=c["num_experts_per_tok"],
+            moe_scoring="sigmoid", moe_selection_bias=True, moe_routed_scale=5.0,
+            moe_latent_dim=c["moe_latent_size"], moe_shared_dim=c["moe_shared_expert_intermediate_size"],
+            decode_kernel=impl)
+        held = {"router": lw["router"], "selection_bias": lw["router_bias"], "w_latent_in": lw["latent_in"],
+                "w_latent_out": lw["latent_out"], "w_up": lw["up_exp"][first:first + 8],
+                "w_down": lw["down_exp"][first:first + 8], "shared_up": lw["up_shared"],
+                "shared_down": lw["down_shared"]}
+        part, _ = MoeMLP(cfg, decode=impl is not None).apply({"params": held}, u[None])
+        # the reference given the same share says the same of it
+        share = dict(whole, n_routed_experts=8, experts_first=first)
+        cut = dict(lw, up_exp=held["w_up"], down_exp=held["w_down"])
+        np.testing.assert_allclose(np.asarray(part[0]), np.asarray(ref.latent_moe(share, "float32", u, cut)), atol=3e-5)
+        parts = parts + np.asarray(part[0])
+    np.testing.assert_allclose(parts - 3 * shared, want, atol=6e-5)
+    assert np.abs(want - shared).max() > 0.05 and np.abs(shared).max() > 0.05  # both add something to be right about
+
+
+def test_a_dense_relu2_mlp_is_two_matrices():
+    from accelerate_tpu.models.decoder import DecoderMLP
+
+    cfg = DecoderConfig.tiny(mlp_kind="relu2")
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, cfg.embed_dim))
+    params = DecoderMLP(cfg).init(jax.random.PRNGKey(1), x)["params"]
+    assert set(params) == {"w_up", "w_down"}
+    up, down = (np.asarray(jax.tree_util.tree_leaves(params[k])[0], np.float32) for k in ("w_up", "w_down"))
+    want = np.square(np.maximum(np.asarray(x[0]) @ up, 0.0)) @ down
+    np.testing.assert_allclose(np.asarray(DecoderMLP(cfg).apply({"params": params}, x)[0]), want, atol=1e-5)
+    assert cfg.num_params == DecoderConfig.tiny().num_params - cfg.num_layers * cfg.embed_dim * cfg.mlp_dim

@@ -185,6 +185,36 @@ def _ssm_scan(chip, *, blocks, rows, width=5120, n=16, layers=13, slots=128):
     return fn, (S((blocks, rows, width), jnp.bfloat16), S((blocks, rows, width)), S((blocks, rows, n)),
                 S((blocks, rows, n)), S((n, width)), S((width,)), S((layers, slots, n, width)),
                 per_block, per_block, per_block, S((), jnp.int32))
+def _ssd_scan(chip, *, blocks, rows, heads=128, head_dim=64, groups=8, n=128, layers=3, slots=96):
+    """The recurrence with heads over the layers' stack of states and a layer
+    index, the stack its output, at the nemotron3 cell's published shapes: a
+    slot's state in a layer is 64 chunks of [128, 128] float32, 4 MB."""
+    from accelerate_tpu.ops import ssm
+
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    width = heads * head_dim
+    fn = lambda u, dt, b, c, a, d, state, slot, n_rows, fresh, layer: ssm._ssd_scan_call(
+        u, dt, b, c, a, d, state, slot, n_rows, fresh, layer, False)
+    per_block = S((blocks,), jnp.int32)
+    return fn, (S((blocks, rows, width)), S((blocks, rows, width)), S((blocks, rows, groups, n)),
+                S((blocks, rows, groups, n)), S((width,)), S((width,)),
+                S((layers, slots, *ssm.ssd_state_shape(width, groups, n))),
+                per_block, per_block, per_block, S((), jnp.int32))
+
+
+def _moe_experts_relu2(chip, *, rows, layers=3, held=128, d=1024, m=2688):
+    """The two-matrix experts' kernel out of a run's stacks (the nemotron3
+    cell's run of three expert layers, 128 held experts in a 1,024-wide
+    latent) at a decode step's and a pack's rows."""
+    from accelerate_tpu.models import moe
+
+    S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    fn = lambda x, wu, wd, sizes, layer: moe._experts2_kernel_call(x, wu, wd, sizes, layer, False)
+    # (float32 rows, as the latent projection hands them: the kernel multiplies them in two terms)
+    return fn, (S((rows, d), jnp.float32), S((layers, held, d, m)), S((layers, held, m, d)), S((held,), jnp.int32),
+                S((), jnp.int32))
+
+
 def _eva_pool(chip, *, steps, kvh=32, d=128, ps=16, layers=8, pages=1792):
     """The pooling kernel of a closing window (ops/eva.py) at published widths:
     ``steps`` pages pooled in one layer of the layers' stack (a decode step's
@@ -297,6 +327,11 @@ CASES = {
     "ssm_scan_decode_step_128_slots": (_ssm_scan, dict(blocks=128, rows=1)),
     "ssm_scan_pack_256_rows": (_ssm_scan, dict(blocks=4, rows=64)),
     "ssm_scan_pack_64_rows": (_ssm_scan, dict(blocks=1, rows=64)),
+    # the recurrence with heads and the two-matrix experts in a latent (ISSUE 44), at published shapes
+    "ssd_scan_decode_step_96_slots": (_ssd_scan, dict(blocks=96, rows=1)),
+    "ssd_scan_pack_256_rows": (_ssd_scan, dict(blocks=4, rows=64)),
+    "moe_experts_relu2_decode_rows": (_moe_experts_relu2, dict(rows=1056)),
+    "moe_experts_relu2_prefill_rows": (_moe_experts_relu2, dict(rows=2816)),
     "paged_decode_one_kv_head_group20_in_place": (
         _paged_decode, dict(h=20, kvh=1, slots=128, pages=16384, table=512, write=True)),
     "ragged_prefill_one_kv_head_group20": (
@@ -770,6 +805,61 @@ def test_the_latent_serving_programs_compile_at_the_published_widths(chip, monke
     weights_bytes = 2 * arch.total_params(c)
     assert arena == s["num_pages"] * 16 * 1280 * 6 and mem.alias_size_in_bytes >= arena
     assert mem.temp_size_in_bytes < 64 * 2**20, mem.temp_size_in_bytes
+    assert weights_bytes + arena <= mem.argument_size_in_bytes < weights_bytes + arena + 64 * 2**20
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes,
+          "aliased", mem.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
+def test_the_half_block_serving_programs_compile_at_the_published_widths(chip, monkeypatch, program):
+    """The nemotron3 cell's engine as its configuration file sizes it (11
+    published layers as 6 blocks in 4 scans, 128 of 512 experts, 96 slots of
+    9,216; built from shapes alone, nothing is allocated), both programs
+    compiled for the described chip: the program's kernels are ``ssd_scan``,
+    ``moe_experts_relu2`` and the attention kernel; the arena (2.07 GB of state,
+    0.27 GB of pages) is aliased to the program's output, and no operation
+    copies, slices or scatters a layer's states or the stack of them; the
+    arguments are the weights and the arena (11.6 GB), the temporaries under
+    128 MB."""
+    import json
+    import os
+    import re
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import manifest
+    import weights
+    from accelerate_tpu.serving import ServingEngine, pages
+
+    with open(os.path.join(root, "benchmarks", "configs", "nemotron3-super-120b-serve-11l-ep4.json")) as f:
+        c = json.load(f)
+    arch, s = manifest.load_arch(c["model_type"]), c["serving"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = arch.decoder_config(c, max_seq_len=s["max_cache_len"], remat=False)
+    params = jax.eval_shape(
+        lambda k: arch.to_program_tree(c)(weights.make(arch.reference, c, k, jnp.bfloat16)), weights.seed_key(1))
+    eng = ServingEngine(arch.module(cfg), params, page_size=s["page_size"], num_slots=s["num_slots"],
+                        max_cache_len=s["max_cache_len"], num_pages=s["num_pages"], **s["engine_kwargs"])
+    m = eng.metrics()
+    assert m["serving/ssd_kernel_active"] == 1 and m["serving/state_bytes_per_slot"] == arch.slot_state_bytes(c) == 21_585_920
+    assert m["serving/arena_in_place"] == m["serving/prefill_arena_in_place"] == 1
+    assert m["serving/state_in_place"] == m["serving/experts_from_stack"] == 1
+    compiled = _compile_serving_program(eng, chip, program)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernel_names(text) == {"ssd_scan", "moe_experts_relu2",
+                                   {"decode_step": "attn", "packed_prefill": "ragged_prefill_attn"}[program]}
+    states = [x for p, x in jax.tree_util.tree_flatten_with_path(eng._arena)[0] if p[-1].key == "ssm_state"]
+    assert sorted(x.shape for x in states) == [(1, 96, 64, 128, 128)] * 2 + [(3, 96, 64, 128, 128)]
+    shapes = {",".join(map(str, shp)) for x in states for shp in (x.shape, x.shape[1:])}
+    moved = re.compile(r"= \w+\[(%s)\]\S* (copy|copy-start|dynamic-slice|dynamic-update-slice|scatter)\("
+                       % "|".join(shapes))
+    assert not moved.search(text)
+    arena, weights_bytes = pages.arena_nbytes(eng._arena), 2 * arch.total_params(c)
+    assert pages.state_nbytes(eng._arena) == 96 * 21_585_920 and mem.alias_size_in_bytes >= arena
+    assert mem.temp_size_in_bytes < 128 * 2**20, mem.temp_size_in_bytes
     assert weights_bytes + arena <= mem.argument_size_in_bytes < weights_bytes + arena + 64 * 2**20
     print(program, "arguments", mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes,
           "aliased", mem.alias_size_in_bytes)
