@@ -1965,24 +1965,23 @@ class ServingEngine:
 
     def _prefix_work(self) -> tuple:
         """The prefix cache's work counters now, for a span to difference:
-        ``(probes, ghost_probes, hashed_tokens, evictions, evict_scanned)``,
-        the first and third with the ghost shadows' own digests among them.
-        Zeros in an engine without a prefix cache."""
+        ``(probes, ghost_probes, hashed_tokens, entries_probed, evictions,
+        evict_scanned)``, the first and third with the ghost shadows' own
+        digests among them. Zeros in an engine without a prefix cache."""
         cache = self._prefix
         if cache is None:
-            return (0, 0, 0, 0, 0)
+            return (0, 0, 0, 0, 0, 0)
         ghost_n, ghost_tokens = (
             (cache.ghost.digests, cache.ghost.digested_tokens) if cache.ghost is not None else (0, 0))
         return (cache.digests + ghost_n, ghost_n, cache.digested_tokens + ghost_tokens,
-                cache.evictions, cache.evict_scanned)
+                cache.entries_probed, cache.evictions, cache.evict_scanned)
 
     def _lookup_prefix(self, req: Request, seq: np.ndarray, cold_chunks: int):
         """The longest cached prefix of ``seq`` the admission commits to,
         as ``(hit_len, entry)``, under its own ``serving/prefix_lookup``
         span (the counts of the work at the same boundary)."""
         cache = self._prefix
-        with _span("serving/prefix_lookup", request_id=req.id,
-                   entries=len(cache.entries)) as sp:
+        with _span("serving/prefix_lookup", request_id=req.id) as sp:
             work0 = self._prefix_work()
             hit_len, entry = cache.lookup(seq, limit=seq.size - 1)
             # the tail plan must still fit the slot (its padded cover can
@@ -2003,9 +2002,10 @@ class ServingEngine:
             if hit_len == 0:
                 entry = None
             cache.record_hit(hit_len, entry)
-            probes, ghost_probes, hashed, *_ = (
+            probes, ghost_probes, hashed, probed, *_ = (
                 b - a for a, b in zip(work0, self._prefix_work()))
-            sp.args.update(probes=probes, ghost_probes=ghost_probes,
+            # entries: those the lookup visited, one a length it probed
+            sp.args.update(entries=probed, probes=probes, ghost_probes=ghost_probes,
                            hashed_tokens=hashed, hit_tokens=hit_len)
         return hit_len, entry
 
@@ -2026,7 +2026,7 @@ class ServingEngine:
             cache.insert(
                 req.prompt, self._tables_host.rows[slot], tenant=req.tenant
             )
-            probes, _, hashed, evictions, scanned = (
+            probes, _, hashed, _, evictions, scanned = (
                 b - a for a, b in zip(work0, self._prefix_work()))
             sp.args.update(probes=probes, hashed_tokens=hashed,
                            evictions=evictions, evict_scanned=scanned,

@@ -36,8 +36,10 @@ gathers and installs) import jax lazily at call time.
 
 from __future__ import annotations
 
+import bisect
 import hashlib
 import re
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -98,6 +100,67 @@ def _digest(tokens: np.ndarray) -> bytes:
     return hashlib.blake2b(
         np.ascontiguousarray(tokens, np.int32).tobytes(), digest_size=16
     ).digest()
+
+
+class _PrefixDigests:
+    """``_digest(prompt[:length])`` at many lengths of one prompt, for one
+    reading of its bytes a pass: ``blake2b`` streams, so one hasher fed the
+    prompt up to each length in ascending order gives at every stop the
+    digest of the prefix so far. The keys are those of :func:`_digest`, byte
+    for byte (peers exchange them: ``ServingEngine.kv_directory``,
+    serving/tiers.py). ``keys`` maps a length to its key."""
+
+    __slots__ = ("_bytes", "keys")
+
+    def __init__(self, prompt: np.ndarray):
+        self._bytes = memoryview(np.ascontiguousarray(prompt, np.int32).reshape(-1)).cast("B")
+        self.keys: dict = {}
+
+    def extend(self, lengths) -> tuple:
+        """Digest those of ``lengths`` that ``keys`` lacks, in one ascending
+        pass from the prompt's first token. Returns ``(digests made, tokens
+        read)``: the work, for the caller's counters."""
+        keys = self.keys
+        missing = sorted({length for length in lengths if length not in keys})
+        hasher, at, data = hashlib.blake2b(digest_size=16), 0, self._bytes
+        for length in missing:
+            hasher.update(data[4 * at: 4 * length])
+            at = length
+            keys[length] = hasher.digest()
+        return len(missing), at
+
+
+class _LengthIndex:
+    """The distinct ``token_len`` of an index's entries, counted as entries
+    come and go: the candidate lengths of a lookup without a visit to the
+    entries. The sorted list is built again only after a length appeared or
+    disappeared."""
+
+    __slots__ = ("_count", "_ascending")
+
+    def __init__(self):
+        self._count: dict = {}  # token_len -> entries of that length
+        self._ascending: Optional[list] = []
+
+    def add(self, length: int):
+        held = self._count.get(length, 0)
+        self._count[length] = held + 1
+        if not held:
+            self._ascending = None
+
+    def discard(self, length: int):
+        left = self._count[length] - 1
+        if left:
+            self._count[length] = left
+        else:
+            del self._count[length]
+            self._ascending = None
+
+    def upto(self, n: int) -> list:
+        """The lengths ``<= n``, ascending."""
+        if self._ascending is None:
+            self._ascending = sorted(self._count)
+        return self._ascending[: bisect.bisect_right(self._ascending, n)]
 
 
 class PageAllocator:
@@ -179,17 +242,21 @@ class _GhostShadow:
     equals a brute-force ``PrefixCache(max_entries=N*base)`` replaying the
     same trace — the oracle tests/test_loadgen.py asserts against.
 
-    Every touch takes a new, larger tick and moves the entry to the end of
-    the dict, so the dict's first key is the one of least ``last_used``:
-    an eviction costs the same at any capacity. (A scan for the minimum was
-    three quarters of an admission's host time with the cache full: 100,000
-    calls a 512-token prompt over the 2x/4x/10x shadows; PERF.md, PR 37.)"""
+    What an operation costs is what it touches, at any capacity, as in the
+    real cache: ``entries`` is in order of ``last_used`` (every touch takes a
+    new, larger tick and moves the entry to the end, so an eviction pops the
+    first key), and ``lengths`` counts the entries by ``token_len``, so a
+    lookup takes its candidate lengths without a visit to the entries. (A
+    scan for the minimum was three quarters of an admission's host time with
+    the cache full, PERF.md, PR 37; the set of lengths over up to 65,000
+    entries a lookup, PR 45.)"""
 
-    __slots__ = ("max_entries", "entries", "_clock", "hits")
+    __slots__ = ("max_entries", "entries", "lengths", "_clock", "hits")
 
     def __init__(self, max_entries: int):
         self.max_entries = int(max_entries)
-        self.entries: dict = {}  # key bytes -> [token_len, last_used]
+        self.entries: OrderedDict = OrderedDict()  # key bytes -> [token_len, last_used], least recent first
+        self.lengths = _LengthIndex()
         self._clock = 0
         self.hits = 0
 
@@ -197,15 +264,13 @@ class _GhostShadow:
         self._clock += 1
         return self._clock
 
-    def lookup(self, n: int, dig) -> int:
-        """Probe like ``PrefixCache.peek`` (longest cached length
-        ``<= n`` whose prefix digest matches), self-committing the hit:
-        the simulation has no engine to decline it."""
-        for length in sorted({e[0] for e in self.entries.values()},
-                             reverse=True):
-            if length > n:
-                continue
-            key = dig(length)
+    def lookup(self, lengths: list, keys: dict) -> int:
+        """Probe like ``PrefixCache.peek`` (the longest of ``lengths``, this
+        shadow's own up to the prompt's limit, whose key in ``keys`` is an
+        entry of that length), self-committing the hit: the simulation has
+        no engine to decline it."""
+        for length in reversed(lengths):
+            key = keys[length]
             e = self.entries.get(key)
             if e is not None and e[0] == length:
                 self.hits += 1
@@ -215,8 +280,7 @@ class _GhostShadow:
 
     def _touch(self, key, e):
         e[1] = self._tick()
-        del self.entries[key]
-        self.entries[key] = e  # the newest tick goes last
+        self.entries.move_to_end(key)  # the newest tick goes last
 
     def insert(self, keyed_lengths):
         for length, key in keyed_lengths:
@@ -225,8 +289,10 @@ class _GhostShadow:
                 self._touch(key, e)
                 continue
             self.entries[key] = [length, self._tick()]
+            self.lengths.add(length)
         while len(self.entries) > self.max_entries:
-            del self.entries[next(iter(self.entries))]  # least last_used: see the class
+            _, (length, _) = self.entries.popitem(last=False)  # least last_used: see the class
+            self.lengths.discard(length)
 
 
 class GhostCache:
@@ -264,8 +330,9 @@ class GhostCache:
         self.lookups = 0
         self.reuses = 0
         # what the shadows themselves cost an admission: the digests
-        # observe_lookup computes and the tokens they read (lifetime;
-        # ghost_probes of the serving/prefix_lookup span)
+        # observe_lookup had to compute itself, at lengths only a shadow
+        # holds, and the tokens that pass read (lifetime; ghost_probes of
+        # the serving/prefix_lookup span)
         self.digests = 0
         self.digested_tokens = 0
         self._evicted: dict = {}  # key -> lookup count at eviction
@@ -273,21 +340,22 @@ class GhostCache:
         self._distances: list = []
         self._max_distances = int(max_distances)
 
-    def observe_lookup(self, prompt: np.ndarray, limit: Optional[int] = None):
+    def observe_lookup(self, prompt: np.ndarray, limit: Optional[int] = None,
+                       digests: Optional[_PrefixDigests] = None):
+        """Replay one lookup through every shadow. ``digests``: the table the
+        real cache's lookup made of this prompt; the lengths it holds are not
+        digested again, those only a shadow holds (entries the real cache has
+        dropped) take one pass of their own."""
         self.lookups += 1
         n = int(prompt.size if limit is None else min(prompt.size, limit))
-        memo: dict = {}
-
-        def dig(length):
-            d = memo.get(length)
-            if d is None:
-                d = memo[length] = _digest(prompt[:length])
-                self.digests += 1
-                self.digested_tokens += length
-            return d
-
-        for shadow in self.shadows.values():
-            shadow.lookup(n, dig)
+        if digests is None:
+            digests = _PrefixDigests(prompt)
+        wanted = [shadow.lengths.upto(n) for shadow in self.shadows.values()]
+        made, tokens = digests.extend(length for lengths in wanted for length in lengths)
+        self.digests += made
+        self.digested_tokens += tokens
+        for shadow, lengths in zip(self.shadows.values(), wanted):
+            shadow.lookup(lengths, digests.keys)
 
     def observe_insert(self, keyed_lengths):
         """``keyed_lengths``: the ``(length, key)`` pairs the real
@@ -348,6 +416,17 @@ class PrefixCache:
     token-by-token trie. Eviction is LRU at entry granularity; a page's
     storage is reclaimed only when every referencing entry AND every
     mapped slot has released it (the allocator's refcount).
+
+    **What an operation costs is what it touches, not what the cache holds**
+    (PERF.md, PR 45): ``entries`` is in order of ``last_used`` (every touch
+    takes its tick and moves the entry to the end), so an eviction pops the
+    first key and releases the victim's pages; the candidate lengths are
+    counted as entries come and go (:class:`_LengthIndex`), so a lookup
+    takes them without a visit to the entries; and a call reads its prompt
+    once (:class:`_PrefixDigests`): a lookup one pass up to its longest
+    candidate length and a dict probe a length until the hit, an insert one
+    pass over the prompt, a dict probe a length and one retain a page a new
+    entry covers.
     """
 
     def __init__(self, allocator: PageAllocator, page_size: int,
@@ -357,7 +436,8 @@ class PrefixCache:
         self.allocator = allocator
         self.page_size = int(page_size)
         self.max_entries = int(max_entries)
-        self.entries: dict = {}  # key bytes -> PrefixEntry
+        self.entries: OrderedDict = OrderedDict()  # key bytes -> PrefixEntry, least recently used first
+        self._lengths = _LengthIndex()
         self._clock = 0
         self.lookups = 0
         self.hits = 0
@@ -365,10 +445,12 @@ class PrefixCache:
         # the work done, counted where it is done (lifetime; the engine's
         # serving/prefix_lookup, prefix_insert and page_grow spans take the
         # differences): digests computed by peek and insert and the tokens
-        # they read (the ghost shadows count their own), entries evicted,
-        # and entries evict_lru looked at to find them
+        # their passes read, a pass its tokens once (the ghost shadows count
+        # their own), entries a lookup probed, entries evicted, and entries
+        # evict_lru looked at to find them (one an eviction)
         self.digests = 0
         self.digested_tokens = 0
+        self.entries_probed = 0
         self.evictions = 0
         self.evict_scanned = 0
         # demote-on-evict hook: called with the victim PrefixEntry
@@ -395,8 +477,9 @@ class PrefixCache:
         self._clock += 1
         return self._clock
 
-    def _candidate_lengths(self) -> list:
-        return sorted({e.token_len for e in self.entries.values()}, reverse=True)
+    def _touch(self, key: bytes, entry: PrefixEntry):
+        entry.last_used = self._tick()
+        self.entries.move_to_end(key)  # the newest tick goes last
 
     def lookup(self, prompt: np.ndarray, limit: Optional[int] = None):
         """Longest cached prefix of ``prompt`` with ``token_len <= limit``.
@@ -408,9 +491,11 @@ class PrefixCache:
         or would cost more prefill dispatches than a cold admission, and
         the hit-ratio gauges must reflect the final decision)."""
         self.lookups += 1
+        digests = _PrefixDigests(prompt)
+        found = self._probe(prompt, limit, digests)
         if self.ghost is not None:
-            self.ghost.observe_lookup(prompt, limit)
-        return self.peek(prompt, limit)
+            self.ghost.observe_lookup(prompt, limit, digests)
+        return found
 
     def peek(self, prompt: np.ndarray, limit: Optional[int] = None):
         """:meth:`lookup` without side effects: the hit/lookup gauges and
@@ -418,15 +503,22 @@ class PrefixCache:
         shipping cached pages to a peer) and router introspection probe
         with this — a probe is not serving traffic and must not skew the
         hit-ratio gauges or LRU-protect an entry it never admitted."""
+        return self._probe(prompt, limit, _PrefixDigests(prompt))
+
+    def _probe(self, prompt: np.ndarray, limit: Optional[int], digests: _PrefixDigests):
+        """The cached lengths up to the limit digested in one ascending pass
+        into ``digests``, then probed longest first."""
         n = int(prompt.size if limit is None else min(prompt.size, limit))
-        for length in self._candidate_lengths():
-            if length > n:
-                continue
-            self.digests += 1
-            self.digested_tokens += length
-            entry = self.entries.get(_digest(prompt[:length]))
+        lengths = self._lengths.upto(n)
+        made, tokens = digests.extend(lengths)
+        self.digests += made
+        self.digested_tokens += tokens
+        for at, length in enumerate(reversed(lengths), 1):
+            entry = self.entries.get(digests.keys[length])
             if entry is not None and entry.token_len == length:
+                self.entries_probed += at
                 return length, entry
+        self.entries_probed += len(lengths)
         return 0, None
 
     def record_hit(self, tokens: int, entry: Optional[PrefixEntry] = None):
@@ -439,7 +531,7 @@ class PrefixCache:
             self.hit_tokens += int(tokens)
             if entry is not None:
                 entry.hits += 1
-                entry.last_used = self._tick()
+                self._touch(entry.key, entry)
 
     def insert(self, prompt: np.ndarray, pages, tenant: str = "default") -> int:
         """Register ``prompt`` (whose KV now lives in ``pages``, position
@@ -447,29 +539,32 @@ class PrefixCache:
         Each new entry retains its covered pages. Returns the number of
         entries created."""
         ps = self.page_size
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
+        prompt = np.array(prompt, np.int32).reshape(-1)  # one private copy, which the new entries slice
         n = int(prompt.size)
         lengths = list(range(ps, n + 1, ps))
         if n % ps:
             lengths.append(n)  # partial-page tail: the COW-fork case
-        keyed = [(length, _digest(prompt[:length])) for length in lengths]
-        self.digests += len(lengths)
-        self.digested_tokens += sum(lengths)
+        digests = _PrefixDigests(prompt)
+        made, tokens = digests.extend(lengths)
+        self.digests += made
+        self.digested_tokens += tokens
+        keyed = [(length, digests.keys[length]) for length in lengths]
+        covered = tuple(int(p) for p in pages[: -(-n // ps)])
+        tenant, retain = str(tenant or "default"), self.allocator.retain
         created = 0
         for length, key in keyed:
             hit = self.entries.get(key)
             if hit is not None:
-                hit.last_used = self._tick()
+                self._touch(key, hit)
                 continue
-            n_pages = -(-length // ps)
             entry = PrefixEntry(
-                key=key, token_len=length, pages=tuple(int(p) for p in pages[:n_pages]),
-                last_used=self._tick(),
-                tokens=prompt[:length].copy(), tenant=str(tenant or "default"),
+                key=key, token_len=length, pages=covered[: -(-length // ps)],
+                last_used=self._tick(), tokens=prompt[:length], tenant=tenant,
             )
             for p in entry.pages:
-                self.allocator.retain(p)
+                retain(p)
             self.entries[key] = entry
+            self._lengths.add(length)
             created += 1
         if self.ghost is not None:
             self.ghost.observe_insert(keyed)
@@ -486,9 +581,9 @@ class PrefixCache:
         if not self.entries:
             return False
         self.evictions += 1
-        self.evict_scanned += len(self.entries)
-        key = min(self.entries, key=lambda k: self.entries[k].last_used)
-        entry = self.entries.pop(key)
+        self.evict_scanned += 1
+        key, entry = self.entries.popitem(last=False)  # least last_used: see the class
+        self._lengths.discard(entry.token_len)
         if self.on_evict is not None:
             # pages are still retained here: the hook may gather them
             try:

@@ -278,12 +278,16 @@ def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
         assert all(set(s[5]) == {"request_id", "probes", "hashed_tokens", "evictions", "evict_scanned", "entries"}
                    for s in inserts)
         assert len(lookups) == cache.lookups and lookups[0][5]["entries"] == 0
-        # an insert digests every page-aligned prefix and the prompt itself, each once
+        # a lookup visits an entry a length it probed: no more than the distinct lengths, whatever the cache holds
+        distinct = len({length for r in run.requests for length in (*range(8, r.prompt.size + 1, 8), r.prompt.size)})
+        assert total("entries", lookups) == cache.entries_probed
+        assert all(s[5]["entries"] <= min(distinct, s[5]["probes"] - s[5]["ghost_probes"]) for s in lookups)
+        # an insert digests every page-aligned prefix and the prompt itself in one pass over the prompt
         by_request = {r.id: r for r in run.requests}
         for s in inserts:
             n = by_request[s[5]["request_id"]].prompt.size
             lengths = list(range(8, n + 1, 8)) + ([n] if n % 8 else [])
-            assert (s[5]["probes"], s[5]["hashed_tokens"]) == (len(lengths), sum(lengths))
+            assert (s[5]["probes"], s[5]["hashed_tokens"]) == (len(lengths), n)
     assert all(s[5]["walked_tokens"] % 8 == 0 and s[5]["walked_tokens"] > 0 for s in grows)
     assert last["pages_in_use"] + last["pages_free"] > 0
     reaps = run.named("serving/reap")
