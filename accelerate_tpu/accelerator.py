@@ -1014,6 +1014,7 @@ class TrainEngine:
                 statics={"micro_steps": micro, "steps_per_call": steps_per_call},
             )
         cost_captured = []
+        from .parallel.context import record_exchanged_products
 
         def run(batch):
             tm = self.telemetry
@@ -1025,9 +1026,12 @@ class TrainEngine:
                 # fingerprint BEFORE dispatch: a changed batch signature
                 # here is the recompile this very call is about to pay
                 _forensics.note_call("train_step", {"batch": batch})
-            new_params, new_opt, new_extra, new_scale, skipped, metrics = jitted(
-                self.params, self.opt_state, self.extra_state, self.scale_state, rng_key, batch
-            )
+            with record_exchanged_products() as exchanged:  # (filled by the call that traces)
+                new_params, new_opt, new_extra, new_scale, skipped, metrics = jitted(
+                    self.params, self.opt_state, self.extra_state, self.scale_state, rng_key, batch
+                )
+            if exchanged:
+                run._audit_tp_products = tuple(sorted(exchanged))
             if self.sharding_config.offload_params_to_host:
                 new_params = self._replace_offloaded_params(new_params)
             if self.sharding_config.offload_optimizer_state:
@@ -1069,6 +1073,9 @@ class TrainEngine:
         # and the auditor needs the fn + effective donation set to trace
         run._audit_fn = jitted
         run._audit_donate = donate
+        # the tensor-parallel products of a block that exchange their rows
+        # while they multiply (parallel/context.gather_einsum), by their einsum
+        run._audit_tp_products = ()
         return run
 
     def audit_entrypoints(self, step, batch) -> list:

@@ -34,7 +34,7 @@ from ..ops.layers import (
     yarn_mscale,
 )
 from ..ops.losses import fused_linear_cross_entropy
-from ..parallel.sharding import DEFAULT_AXIS_RULES, logical_to_spec
+from ..parallel.sharding import DEFAULT_AXIS_RULES, ROWS_OVER_TENSOR_RULES, logical_to_spec
 from .configs import MOE_LOAD_COLLECTION as MOE_LOAD
 from .configs import DecoderConfig
 
@@ -45,6 +45,13 @@ def _constrain(x, names, mesh: Optional[Mesh], rules=DEFAULT_AXIS_RULES):
     from ..parallel.sharding import constrain_activation
 
     return constrain_activation(x, names, mesh, rules)
+
+
+def _constrain_stream(x, mesh: Optional[Mesh], exchange: int):
+    """The residual stream's constraint: [batch, seq, embed] with the batch
+    over the data axes, and the rows over "tensor" where the block's products
+    exchange them (``exchange``: parallel/context.tp_exchange_size)."""
+    return _constrain(x, ("batch", "seq", "embed"), mesh, ROWS_OVER_TENSOR_RULES if exchange else DEFAULT_AXIS_RULES)
 
 
 def _rotary_tables(positions, cfg, dtype):
@@ -293,6 +300,12 @@ class DecoderAttention(nn.Module):
             plain = False
 
         dt = cfg.dtype
+        from ..parallel.context import einsum_scatter, gather_einsum, tp_exchange_size
+
+        # the "tensor" axis's size where x's rows are sharded over it and the
+        # products exchange them while they multiply, else 0
+        exchange = tp_exchange_size(self.mesh, s, (h, kv), use_cache=self.use_cache,
+                                    use_fp8=getattr(cfg, "use_fp8", False))
         if getattr(cfg, "use_fp8", False):
             # TE parity: QKV through the fp8 recipe (ops/fp8.fp8_attn_proj)
             from ..ops.fp8 import fp8_attn_proj
@@ -300,6 +313,9 @@ class DecoderAttention(nn.Module):
             q = fp8_attn_proj(self, "wq_fp8", x, wq.astype(dt), h, d, cfg)
             k = fp8_attn_proj(self, "wk_fp8", x, wk.astype(dt), kv, d, cfg)
             v = fp8_attn_proj(self, "wv_fp8", x, wv.astype(dt), kv, d, cfg)
+        elif exchange:
+            q, k, v = gather_einsum("bse,ehd->bhsd", x, (wq.astype(dt), wk.astype(dt), wv.astype(dt)),
+                                    self.mesh, shard="h")
         else:
             q = jnp.einsum("bse,ehd->bhsd", x, wq.astype(dt))
             k = jnp.einsum("bse,ehd->bhsd", x, wk.astype(dt))
@@ -649,9 +665,11 @@ class DecoderAttention(nn.Module):
             from ..ops.fp8 import fp8_attn_out
 
             out = fp8_attn_out(self, "wo_fp8", out, wo.astype(dt), cfg)
+        elif exchange:
+            out = einsum_scatter("bhsd,hde->bse", out, wo.astype(dt), self.mesh, shard="h")
         else:
             out = jnp.einsum("bhsd,hde->bse", out, wo.astype(dt))
-        return _constrain(out, ("batch", "seq", "embed"), self.mesh)
+        return _constrain_stream(out, self.mesh, exchange)
 
 
 class LatentAttention(nn.Module):
@@ -849,9 +867,14 @@ def expert_stacks(config, decode: bool, params) -> dict:
     return out
 
 
+def _relu2(up):
+    return jnp.square(jax.nn.relu(up))
+
+
 class DecoderMLP(nn.Module):
     config: DecoderConfig
     mesh: Optional[Mesh] = None
+    use_cache: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -865,14 +888,23 @@ class DecoderMLP(nn.Module):
         wd = self.param("w_down", nn.with_logical_partitioning(_dense_init(), ("mlp", "embed")), (m, e))
         dt = cfg.dtype
         from ..ops.fp8 import module_fp8_dot
+        from ..parallel.context import mlp_exchange, tp_exchange_size
 
-        up = module_fp8_dot(self, "up", x, wu.astype(dt), cfg)
-        if gated:
-            up = swiglu(module_fp8_dot(self, "gate", x, wg.astype(dt), cfg), up)
+        # as in DecoderAttention: x's rows over "tensor", the products exchange them
+        exchange = tp_exchange_size(self.mesh, x.shape[1], (m,), use_cache=self.use_cache,
+                                    use_fp8=getattr(cfg, "use_fp8", False))
+        if exchange:
+            w_in = (wg.astype(dt), wu.astype(dt)) if gated else (wu.astype(dt),)
+            down = mlp_exchange(x, w_in, wd.astype(dt), swiglu if gated else _relu2, self.mesh)
         else:
-            up = jnp.square(jax.nn.relu(up))
-        hidden = _constrain(up, ("batch", "seq", "mlp"), self.mesh)
-        return _constrain(module_fp8_dot(self, "down", hidden, wd.astype(dt), cfg), ("batch", "seq", "embed"), self.mesh)
+            up = module_fp8_dot(self, "up", x, wu.astype(dt), cfg)
+            if gated:
+                up = swiglu(module_fp8_dot(self, "gate", x, wg.astype(dt), cfg), up)
+            else:
+                up = _relu2(up)
+            hidden = _constrain(up, ("batch", "seq", "mlp"), self.mesh)
+            down = module_fp8_dot(self, "down", hidden, wd.astype(dt), cfg)
+        return _constrain_stream(down, self.mesh, exchange)
 
 
 class DecoderBlock(nn.Module):
@@ -909,7 +941,7 @@ class DecoderBlock(nn.Module):
             y, aux = MoeMLP(cfg, self.mesh, self.decode, name="moe_mlp")(
                 y, token_mask, router_input=y_stream, stack=expert_stack)
         else:
-            y = DecoderMLP(cfg, self.mesh, name="mlp")(y)
+            y = DecoderMLP(cfg, self.mesh, self.use_cache, name="mlp")(y)
             aux = jnp.float32(0.0)
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
@@ -1072,6 +1104,11 @@ class DecoderLM(nn.Module):
             (cfg.vocab_size, cfg.embed_dim),
         )
         x = _embed_lookup(embedding, input_ids, cfg, self.mesh)
+        from ..parallel.context import tp_exchange_size
+
+        if tp_exchange_size(self.mesh, s, use_cache=use_cache, use_fp8=cfg.use_fp8):
+            # the blocks' products carry the stream with its rows over "tensor"
+            x = _constrain_stream(x, self.mesh, True)
         if getattr(cfg, "residual_dtype", None) is not None:
             x = x.astype(cfg.residual_dtype)
 

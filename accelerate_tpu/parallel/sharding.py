@@ -40,6 +40,11 @@ DEFAULT_AXIS_RULES = (
     ("norm", None),
 )
 
+# the residual stream where a block's tensor-parallel products exchange its
+# rows (parallel/context.gather_einsum): rows over "tensor", which "embed"
+# cannot take because "batch" has used "fsdp"
+ROWS_OVER_TENSOR_RULES = DEFAULT_AXIS_RULES + (("seq", "tensor"),)
+
 
 def logical_to_spec(logical_axes: tuple, rules=DEFAULT_AXIS_RULES, mesh: Optional[Mesh] = None) -> P:
     """("embed", "mlp") -> PartitionSpec per rules, dropping mesh axes of

@@ -721,6 +721,49 @@ def test_flash_under_a_four_chip_mesh_compiles(v5e, monkeypatch, outer_manual):
             step(bare).lower(x, x, x)
 
 
+def test_a_tensor_parallel_block_exchanges_rows_instead_of_all_reducing(v5e, monkeypatch):
+    """The training cell's block (hidden 4096, MLP 14336, 32 heads over 8 kv
+    heads, rows [8, 4096], remat'd and scanned) forward and backward under
+    fsdp2 x tp2: its four tensor-parallel products take the exchange path
+    (parallel/context.gather_einsum, einsum_scatter), so the compiled program
+    holds no all-reduce of the residual's shape, and the hops are
+    asynchronous (a start and a done the scheduler puts products between)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu.models import DecoderConfig
+    from accelerate_tpu.models.decoder import StageStack, _rotary_tables
+    from accelerate_tpu.parallel.context import record_exchanged_products
+    from accelerate_tpu.parallel.sharding import infer_param_sharding, unbox_params
+    from accelerate_tpu.utils.dataclasses import ShardingConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(v5e).reshape(2, 2), ("fsdp", "tensor"))
+    cfg = DecoderConfig(vocab_size=512, num_layers=1, embed_dim=4096, num_heads=32, num_kv_heads=8, head_dim=128,
+                        mlp_dim=14336, max_seq_len=4096, dtype=jnp.bfloat16, scan_layers=True, remat=True,
+                        remat_policy="save_attention")
+    stack = StageStack(cfg, mesh)
+    sin, cos = _rotary_tables(jnp.arange(4096), cfg, cfg.dtype)
+    rows = jnp.zeros((8, 4096, 4096), cfg.dtype)
+    raw, axes = unbox_params(jax.eval_shape(lambda: stack.init(jax.random.PRNGKey(0), rows, sin, cos))["params"])
+    shardings = infer_param_sharding(raw, mesh, ShardingConfig(fsdp=2, tensor_parallel=2), axes)
+    params = jax.tree_util.tree_map(lambda p, s: jax.ShapeDtypeStruct(p.shape, cfg.dtype, sharding=s), raw, shardings)
+    x = jax.ShapeDtypeStruct(rows.shape, cfg.dtype, sharding=NamedSharding(mesh, P("fsdp", "tensor")))
+    loss = lambda p, x: stack.apply({"params": p}, x, sin, cos).astype(jnp.float32).sum()
+    unoptimized = jax.config.values["jax_disable_most_optimizations"]
+    jax.config.update("jax_disable_most_optimizations", False)
+    try:
+        with record_exchanged_products() as exchanged:
+            text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    finally:
+        jax.config.update("jax_disable_most_optimizations", unoptimized)
+    assert len(exchanged) == 4
+    assert "collective-permute-start" in text
+    assert not re.findall(r"= bf16\[4,4096,4096\]\S* all-reduce(?:-start)?\(", text)
+
+
 def test_gates_admit_only_what_compiles(monkeypatch):
     """Every (head_dim, page, KV storage) the shape gates admit on the chip
     is among the compiled cases above, and what they refuse they refuse by
