@@ -4,7 +4,7 @@ Two analyzers behind one findings model and one CLI (``accelerate-tpu
 audit``):
 
 - :mod:`~.program_audit` walks the jaxpr/lowering of every registered
-  jitted entry point (serving prefill/decode/verify, the fused train
+  jitted entry point (serving prefill/decode, the fused train
   step) for baked constants, donation misses, f32 drift, host callbacks
   and weak-shape dependencies — lazy-jax, tracing only.
 - :mod:`~.host_lint` AST-lints the telemetry/serving host modules for

@@ -1,10 +1,9 @@
 """Static program auditor: invariant checks over jaxprs and lowerings.
 
 Every hot program this repo dispatches — the fused train step, the
-serving prefill buckets, the decode step/burst, spec verify, the
-dispatched forward — obeys invariants the runtime tests can only catch
-*after* the damage: trace-time constants bloat HBM at first dispatch, a
-missed donation doubles the arena per step, an f32 upcast halves MXU
+serving prefill buckets, the decode step, the dispatched forward — obeys
+invariants the runtime tests can only catch *after* the damage:
+trace-time constants bloat HBM at first dispatch, a missed donation doubles the arena per step, an f32 upcast halves MXU
 throughput silently, a host callback turns a 2 ms step into a 50 ms
 round trip, and a python scalar re-derived from a per-call shape breaks
 the zero-recompile contract the whole serving tier is built on. All of
@@ -444,10 +443,7 @@ def audit_entrypoints(specs, *, registered=None, compile_check: bool = False,
                 message=f"could not trace {spec.name} for audit: {e!r}",
             ))
     for name in sorted(registered or ()):
-        base = name.split("<")[0]  # decode_burst<k> family
-        if name not in audited and base not in audited and not any(
-            a.startswith(base) for a in audited
-        ):
+        if name not in audited:
             findings.append(Finding(
                 check="unaudited-entrypoint", severity="P3", target=name,
                 message=f"{name} is registered with the forensics/cost "
@@ -492,7 +488,7 @@ def audit_engine(engine, *, cross_check_registry: bool = True,
 
 def self_audit(*, include_train: bool = True, warmup: bool = False,
                compile_check: bool = False, **thresholds) -> list:
-    """Audit the repo's own registered entry points: a paged+speculative
+    """Audit the repo's own registered entry points: a paged
     tiny serving engine (the full warmup program set) and the fused
     train step, built on whatever backend is available. This is what
     ``accelerate-tpu audit`` and the tier-1 gate run; it needs jax but
@@ -509,7 +505,7 @@ def self_audit(*, include_train: bool = True, warmup: bool = False,
     params, _ = unbox_params(variables["params"])
     engine = ServingEngine(
         model, params, num_slots=2, max_cache_len=64, prefill_chunks=(4, 8),
-        page_size=8, spec_draft_len=3, steps_per_call=2,
+        page_size=8,
     )
     if warmup:
         engine.warmup()

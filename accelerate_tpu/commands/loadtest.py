@@ -90,7 +90,7 @@ def _demo_engine():
     args = _ap.Namespace(
         config="tiny", max_seq_len=256, init_seed=0, num_slots=4,
         max_cache_len=160, prefill_chunks="16,64", page_size=16,
-        temperature=0.0, top_k=None, steps_per_call=1,
+        temperature=0.0, top_k=None,
         kv_cache_dtype=None, name="loadtest",
     )
     engine = build_replica_engine(args)

@@ -120,7 +120,6 @@ def register(subparsers):
                               "the peer's /v1/kv/directory")
     replica.add_argument("--temperature", type=float, default=0.0)
     replica.add_argument("--top-k", type=int, default=None)
-    replica.add_argument("--steps-per-call", type=int, default=1)
     replica.add_argument("--init-seed", type=int, default=0,
                          help="model-init PRNG seed (two replicas launched "
                               "with the same config+seed serve the same "
@@ -243,7 +242,6 @@ def build_replica_engine(args):
         page_size=int(args.page_size),
         temperature=float(args.temperature),
         top_k=args.top_k,
-        steps_per_call=int(args.steps_per_call),
         kv_cache_dtype=args.kv_cache_dtype,
         replica=args.name,
         kv_tiers=kv_tiers,

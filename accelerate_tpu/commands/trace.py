@@ -137,7 +137,7 @@ def summarize_requests(records: list) -> dict:
     outcomes: dict = {}
     preemptions = 0
     prefix_hits = prefix_tokens = prompt_tokens = 0
-    spec_proposed = spec_accepted = pages = 0
+    pages = 0
     for rec in records:
         for key in ("queue_wait_ms", "ttft_ms", "total_ms"):
             v = rec.get(key)
@@ -156,8 +156,6 @@ def summarize_requests(records: list) -> dict:
         prefix_hits += 1 if hit else 0
         prefix_tokens += hit
         prompt_tokens += rec.get("prompt_len") or 0
-        spec_proposed += rec.get("spec_proposed") or 0
-        spec_accepted += rec.get("spec_accepted") or 0
         pages += rec.get("pages_allocated") or 0
     agg = {"requests": len(records), "tokens": tokens, "finish_reasons": reasons}
     if outcomes:
@@ -167,16 +165,14 @@ def summarize_requests(records: list) -> dict:
         agg["outcomes"] = outcomes
     if preemptions:
         agg["preemptions"] = preemptions
-    if prefix_tokens or spec_proposed or pages:
+    if prefix_tokens or pages:
         # paged-arena attribution: which share of requests (and of prompt
-        # tokens) the prefix cache served, and how speculation fared
+        # tokens) the prefix cache served
         agg["prefix_hit_requests"] = prefix_hits
         agg["prefix_hit_ratio"] = round(prefix_hits / len(records), 4) if records else 0.0
         if prompt_tokens:
             agg["prefix_hit_token_frac"] = round(prefix_tokens / prompt_tokens, 4)
         agg["pages_allocated"] = pages
-        if spec_proposed:
-            agg["spec_accept_rate"] = round(spec_accepted / spec_proposed, 4)
     for key, hist in hists.items():
         snap = hist.snapshot()
         if snap:

@@ -98,11 +98,8 @@ class DecoderConfig:
     # decode kernel on TPU — HBM read ∝ live tokens — with a warn-once
     # masked-dense fallback elsewhere; "dense" forces the masked-dense reference path;
     # "interpret" runs the same kernel through the pallas interpreter
-    # (the CPU test/CI mode). ``decode_kernel_block`` tunes the
-    # dense-arena kernel's kv block size (must divide the cache length;
-    # the paged arena always walks in kv_page_size blocks).
+    # (the CPU test/CI mode).
     decode_kernel: Optional[str] = None
-    decode_kernel_block: Optional[int] = None
     # prefill-attention implementation for the packed ragged prefill over
     # the paged arena (ops/attention.ragged_prefill_attention). None ->
     # "ragged": the flash online-softmax pallas kernel on TPU — one
@@ -344,11 +341,6 @@ class DecoderConfig:
             raise ValueError(
                 "decode_kernel must be None, 'paged', 'dense' or "
                 f"'interpret', got {self.decode_kernel!r}"
-            )
-        if self.decode_kernel_block is not None and self.decode_kernel_block < 1:
-            raise ValueError(
-                f"decode_kernel_block must be a positive block size, got "
-                f"{self.decode_kernel_block}"
             )
         if self.prefill_kernel not in (None, "ragged", "dense", "interpret"):
             raise ValueError(

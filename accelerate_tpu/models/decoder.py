@@ -197,11 +197,11 @@ class DecoderAttention(nn.Module):
     cache to slot-arena semantics (``serving/``): each batch row is an
     independent request whose new K/V lands at its OWN offset(s) and whose
     attention sees only its own prefix — admission/eviction become pure
-    data changes with no shape change and no recompile. The [B, S] form is
-    the speculative-verify step: S tokens per slot land at per-token
-    positions and each query attends ``<= its own position`` (so draft
-    token i sees drafts 0..i written in the same call — exactly the
-    incremental-decode semantics, batched).
+    data changes with no shape change and no recompile. In the [B, S] form
+    S tokens per slot land at per-token positions and each query attends
+    ``<= its own position`` (token i sees tokens 0..i written in the same
+    call — exactly the incremental-decode semantics, batched; what a
+    verify step over a model's own drafts would run, ROADMAP R12).
 
     ``page_table`` ([B, P] int32, with ``config.kv_page_size`` /
     ``kv_num_pages`` set) switches the cache storage to physical pages
@@ -516,10 +516,9 @@ class DecoderAttention(nn.Module):
                 # slot-arena decode (serving/): every batch row writes its
                 # new K/V at its own per-slot offset(s) and attends only
                 # its own prefix. Stale entries past a slot's frontier
-                # (previous occupant, rolled-back speculative drafts) are
-                # always overwritten at the write position BEFORE being
-                # attended, so neither slot reuse nor speculative rollback
-                # needs any cache clearing.
+                # (the previous occupant's) are always overwritten at the
+                # write position BEFORE being attended, so slot reuse
+                # needs no cache clearing.
                 pos2d = (
                     cache_positions[:, None]
                     if cache_positions.ndim == 1 else cache_positions
@@ -626,7 +625,6 @@ class DecoderAttention(nn.Module):
                 out = decode_attention(
                     q, k_full, v_full, q_positions=cur + jnp.arange(s),
                     impl=getattr(cfg, "decode_kernel", None),
-                    block_kv=getattr(cfg, "decode_kernel_block", None),
                     **scale_kw, **extras,
                 )
         elif eva:
@@ -818,8 +816,8 @@ def arena_in_place(config, sq: int = 1, packed: bool = False) -> bool:
     takes: the scanned stack, unquantized pages, and the kernel engaged: a
     decode step's (``decode_kernel_active``: on the chip or interpreted,
     128-multiple page widths) with one new token a slot, a pack's
-    (``prefill_writes_pages``: likewise). Everything else (speculative
-    verify, the dense fallback, a quantized cache, pages of no whole lanes)
+    (``prefill_writes_pages``: likewise). Everything else (several new
+    tokens a slot, the dense fallback, a quantized cache, pages of no whole lanes)
     splits the collection along the layers as before. The serving engine's
     ``arena_in_place`` gauges and span counters read this."""
     from ..ops.attention import decode_kernel_active, prefill_writes_pages

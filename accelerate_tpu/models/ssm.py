@@ -175,8 +175,8 @@ class SelectiveSSM(nn.Module):
         serving = self.use_cache and self.decode and cache_positions is not None
         if serving and ragged_slots is None and s != 1:
             raise NotImplementedError(
-                "a state-space layer decodes one token a slot: several (speculative "
-                "verify) would need the state rolled back on a rejected draft")
+                "a state-space layer decodes one token a slot: several would need "
+                "the state rolled back on a rejected draft (ROADMAP R12)")
         state = conv = None
         if self.use_cache:
             state = self.variable("cache", "ssm_state", jnp.zeros, (b, n, d), f32)
@@ -280,8 +280,8 @@ class Mamba2Mixer(nn.Module):
         serving = self.use_cache and self.decode and cache_positions is not None
         if serving and ragged_slots is None and s != 1:
             raise NotImplementedError(
-                "a state-space layer decodes one token a slot: several (speculative "
-                "verify) would need the state rolled back on a rejected draft")
+                "a state-space layer decodes one token a slot: several would need "
+                "the state rolled back on a rejected draft (ROADMAP R12)")
         state = conv = None
         if self.use_cache:
             state = self.variable("cache", "ssm_state", jnp.zeros, (b, *ssd_state_shape(d, g, n)), f32)

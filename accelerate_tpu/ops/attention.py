@@ -649,16 +649,16 @@ def flash_attention_bwd(
 # frontier clamped in the BlockSpec index map (the pipeline elides the
 # re-fetch) and skipped by ``pl.when`` — the same win for the
 # single-stream decode loop. GQA folds the query
-# head group (× the Sq query rows: the multi-query form spec_verify and
-# fused bursts use) into one [group*Sq, D] block per kv head, so K/V are
-# never expanded.
+# head group (× the Sq query rows of the multi-query form, held on the
+# chip by ``chip_smoke.py``; ROADMAP R12) into one [group*Sq, D] block per
+# kv head, so K/V are never expanded.
 # ---------------------------------------------------------------------------
 
 _DECODE_KERNEL_MODES = ("paged", "dense", "interpret")
-# multi-query width the kernel accepts: decode (1), fused bursts (1/step),
-# speculative verify (K+1). Prefill-size chunks (64+) stay on the dense
-# path by design — they are compute-shaped, and the row-position unroll
-# below is linear in Sq.
+# multi-query width the kernel accepts: the decode step's is 1, the wider
+# forms are held on the chip by ``chip_smoke.py`` (ROADMAP R12).
+# Prefill-size chunks (64+) stay on the dense path by design — they are
+# compute-shaped, and the row-position unroll below is linear in Sq.
 _DECODE_KERNEL_MAX_SQ = 16
 _decode_fallback_warned: set = set()
 
@@ -805,11 +805,10 @@ def _decode_kernel_gate(mode: str, sq: int, d: int, blk: int,
 
 
 def decode_kernel_active(config, sq: int = 1) -> bool:
-    """Would a paged decode dispatch of query width ``sq`` (1 = the plain
-    decode step; spec_draft_len+1 = the verify program) on a model with
-    this config run the pallas kernel in this process? The serving engine's
-    ``serving/decode_kernel_active`` gauge and bench read it — it must
-    mirror :func:`paged_decode_attention`'s gate exactly, or the gauge
+    """Would a paged decode dispatch of query width ``sq`` (1 = the decode
+    step) on a model with this config run the pallas kernel in this
+    process? The serving engine's ``serving/decode_kernel_active`` gauge
+    and bench read it — it must mirror :func:`paged_decode_attention`'s gate exactly, or the gauge
     would claim a kernel a fallback path never ran."""
     page_size = getattr(config, "kv_page_size", None)
     if not page_size:
@@ -1598,9 +1597,9 @@ def decode_attention(
         if block_kv and bk and bk != int(block_kv):
             _warn_once(
                 f"block_kv {block_kv}/{k.shape[2]}",
-                "decode_kernel_block %s does not divide the cache length "
+                "block_kv %s does not divide the cache length "
                 "%s; the dense-arena decode kernel is using block %s "
-                "instead — pick a divisor to make the knob effective.",
+                "instead — pick a divisor to make it effective.",
                 block_kv, k.shape[2], bk,
             )
         use, interpret = _decode_kernel_gate(mode, sq, d, bk, kv_quant_bits)

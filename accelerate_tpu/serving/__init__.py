@@ -1,9 +1,9 @@
 """Continuous-batching serving: a paged KV cache with a copy-on-write
-prefix cache, packed prefill admission, donated in-place batched decode,
-and speculative decoding (docs/serving.md).
+prefix cache, packed prefill admission and donated in-place batched
+decode (docs/serving.md).
 
 PEP 562 lazy re-exports: ``serving.pages`` is host-side bookkeeping
-(free lists, refcounts, prefix hashing, the n-gram drafter) that a
+(free lists, refcounts, prefix hashing) that a
 router/scheduler tier imports on machines with no accelerator stack, so
 importing it must not drag the jax-heavy engine in (tests/test_imports).
 """
@@ -13,7 +13,6 @@ _EXPORTS = {
     "Request": "engine",
     "ServingEngine": "engine",
     "generate_batched": "engine",
-    "NGramDrafter": "pages",
     "PageAllocator": "pages",
     "PrefixCache": "pages",
     "kv_cache_bits": "pages",

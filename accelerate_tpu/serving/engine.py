@@ -65,7 +65,6 @@ from ..ops.attention import (
 )
 from ..ops.ssm import ssm_kernel_active
 from .pages import (
-    NGramDrafter,
     CacheKind,
     PrefixCache,
     arena_nbytes,
@@ -154,8 +153,6 @@ class Request:
     # `accelerate-tpu trace`/`report` can attribute per-request TTFT wins)
     prefix_hit: int = 0        # prompt tokens served from the prefix cache
     pages_allocated: int = 0   # fresh pages this request consumed (forks incl.)
-    spec_proposed: int = 0     # draft tokens proposed for this request
-    spec_accepted: int = 0     # draft tokens accepted by verify steps
     # hierarchical KV tiering (serving/tiers.py): which tier the prefix
     # was restored from (None = HBM hit or cold), how long the restore
     # took, and how many pages it installed — the request-record hop the
@@ -201,14 +198,7 @@ class ServingEngine:
     dispatch its grid capacities (each rounded up to the token block) and
     the admit plan its unit. With ``prefix_cache`` on,
     admissions whose prompt prefix is cached map the shared pages
-    (copy-on-write) and prefill only the tail. ``spec_draft_len=K`` adds
-    speculative decoding: the host-side ``drafter`` (default
-    :class:`~.pages.NGramDrafter`) proposes K tokens and ONE batched
-    verify step checks all of them, emitting the longest accepted prefix
-    plus one fresh token — token-exact vs. sequential decode under both
-    greedy and sampled decoding (rollback is free: rejected drafts land
-    beyond the frontier, where the decode mask already hides them). Spec
-    reserves ``spec_draft_len`` tokens of per-slot KV headroom.
+    (copy-on-write) and prefill only the tail.
 
     ``kv_cache_dtype`` ("int8"/"int4"; default: the config's, else bf16)
     stores the KV arena quantized — int8/packed-int4 payloads plus a
@@ -223,9 +213,9 @@ class ServingEngine:
 
     The decode step and every packed-prefill grid capacity compile exactly once;
     after ``mark_steady()`` the ``admission_recompiles`` property must
-    stay 0 no matter what traffic arrives — admissions, prefix hits, page
-    forks and speculative verify steps are all pure data changes — the
-    recompile invariant the tests assert.
+    stay 0 no matter what traffic arrives — admissions, prefix hits and
+    page forks are all pure data changes — the recompile invariant the
+    tests assert.
     """
 
     def __init__(
@@ -239,7 +229,6 @@ class ServingEngine:
         temperature: float = 0.0,
         top_k: Optional[int] = None,
         eos_token_id: Optional[int] = None,
-        steps_per_call: int = 1,
         param_placer=None,
         donate: Optional[bool] = None,
         telemetry=None,
@@ -247,8 +236,6 @@ class ServingEngine:
         num_pages: Optional[int] = None,
         prefix_cache: bool = True,
         prefix_max_entries: Optional[int] = None,
-        spec_draft_len: int = 0,
-        drafter=None,
         scheduler=None,
         faults=None,
         kv_cache_dtype: Optional[str] = None,
@@ -274,7 +261,7 @@ class ServingEngine:
         # KV-cache storage precision: the engine knob wins, else whatever
         # the config already carries. Cloning the definition here (before
         # cache sizing) makes every program this engine compiles — the
-        # packed prefill, the fused decode step, spec verify —
+        # packed prefill, the fused decode step —
         # create/consume the quantized payload + scale cache leaves.
         kvq = kv_cache_dtype or getattr(cfg, "kv_cache_dtype", "bf16") or "bf16"
         kv_cache_bits(kvq)  # validate early (raises on typos)
@@ -297,13 +284,6 @@ class ServingEngine:
         self.temperature = float(temperature)
         self.top_k = top_k
         self.eos_token_id = eos_token_id
-        # fuse up to K decode steps into one dispatch (a lax.scan of the
-        # SAME step body — bit-identical tokens): the per-dispatch host
-        # round trip is otherwise part of every token's latency, the same
-        # reason build_train_step grew steps_per_call.
-        # Bursts only run when they cannot delay an admission or overshoot
-        # a request's budget, so scheduling behavior is unchanged.
-        self.steps_per_call = max(1, int(steps_per_call))
         if param_placer is None:
             from ..utils.quantization import dequantize_params as param_placer
         self._placer = param_placer
@@ -313,14 +293,13 @@ class ServingEngine:
             donate if donate is not None else jax.default_backend() != "cpu"
         )
 
-        # -- paged arena / prefix cache / speculative decoding -------------
+        # -- paged arena / prefix cache -------------------------------------
         if not page_size:
             raise ValueError(
                 "ServingEngine: the flat slot arena is gone; the KV cache is "
                 f"paged and page_size must be a positive integer, got {page_size!r}"
             )
         self.page_size = int(page_size)
-        self.spec_k = max(0, int(spec_draft_len))
         # a model that states layer kinds, a window, a sink, experts or a
         # recurrent state runs on the paged arena's normal path only; what
         # cannot yet be right for it refuses here, by the feature's name,
@@ -329,11 +308,11 @@ class ServingEngine:
         run_cfgs = mcfg.run_configs() if hasattr(mcfg, "run_configs") else [mcfg]
         # a state-space mixer's state a slot (pages.CacheKind "state"): a
         # cached prefix would need its snapshot at the page boundary, a
-        # page-out its copy, a rejected draft its rollback
+        # page-out its copy
         has_state = any(getattr(c, "has_state", False) for c in run_cfgs)
         # a closing window with pooled summaries (EVA attention): a shared
         # prefix would have to end at a window's close, a page-out needs the
-        # open window's summaries, a rejected draft may have pooled a page
+        # open window's summaries
         closing = [c for c in run_cfgs if getattr(c, "eva_window", None) is not None]
         self._closing = tuple(sorted({c.eva_window for c in closing}))  # the windows that close
         self._by_kind = bool(
@@ -343,14 +322,13 @@ class ServingEngine:
         if self._by_kind:
             refused = {
                 "prefix_cache (page sharing across a window kind, past a closing window, "
-                "without a state's snapshot at the page boundary, or of latent pages, whose "
-                "sharing waits for a prefix index that does not scan: ROADMAP R5)": bool(prefix_cache),
+                "without a state's snapshot at the page boundary, or of latent pages: it waits "
+                "for sharing to go behind CacheKind, ROADMAP D4, and for the page references "
+                "of S3)": bool(prefix_cache),
                 "kv_tiers": kv_tiers is not None,
                 "preemption by page-out and restore (scheduler.config.preemption)": (
                     scheduler is not None
                     and getattr(getattr(scheduler, "config", scheduler), "preemption", False)),
-                "speculative verify (spec_draft_len)": bool(self.spec_k),
-                "fused decode bursts (steps_per_call)": self.steps_per_call > 1,
                 "quantized pages (kv_cache_dtype)": kvq != "bf16",
             }
             for feature, asked in refused.items():
@@ -443,7 +421,6 @@ class ServingEngine:
             )
             if prefix_cache else None
         )
-        self._drafter = drafter or (NGramDrafter() if self.spec_k else None)
         self._arena = init_paged_arena(
             self._paged_def, params, self.num_slots, self.pages_per_slot,
             self._placer,
@@ -525,11 +502,6 @@ class ServingEngine:
         # demotions never recompile (a gather by a per-call id list
         # would compile per distinct page count)
         self._gather_page = jax.jit(gather_page)
-        self._verify_step = (
-            jax.jit(self._build_verify_core(),
-                    donate_argnums=(1, 2, 4, 6) if self._donate else ())
-            if self.spec_k else None
-        )
         self.page_forks = 0
         self.kv_pages_exported = 0
         self.kv_pages_imported = 0
@@ -544,8 +516,6 @@ class ServingEngine:
         self._restore = None  # live restore state (see _plan_restore)
         self._restored_tier = None  # transient: which tier fed the
         self._kv_paths = None       # admission being planned right now
-        self.spec_proposed = 0
-        self.spec_accepted = 0
         self.prefill_chunks_skipped = 0
         # prefill padding-waste accounting (a dispatch has the FIXED row
         # count of its grid capacity, so waste = 1 - live/dispatched):
@@ -602,7 +572,6 @@ class ServingEngine:
 
         self._step_core = self._build_step_core()
         self._decode_step = jax.jit(self._step_core, donate_argnums=self._step_donate())
-        self._decode_bursts: dict = {}
         self._ragged_fns: dict = {}
         # admission on the device: a request's two keys are staged into
         # these rows when it takes its slot (the pack program samples with
@@ -634,7 +603,7 @@ class ServingEngine:
         self.resumptions = 0
         self.generated_tokens = 0
         self.rows_discarded = 0  # decode rows dispatched for a request whose eos was still in flight
-        self._step_samples: deque = deque(maxlen=512)  # (wall_s, tokens, steps)
+        self._step_samples: deque = deque(maxlen=512)  # (wall_s, tokens) a decode step
         self._itl: deque = deque(maxlen=2048)  # inter-token gaps, seconds
         self._itl_emitted = 0   # lifetime gap count; the controller only
         self._itl_observed = 0  # observes when these differ (fresh data)
@@ -747,7 +716,7 @@ class ServingEngine:
     # -- compiled programs -------------------------------------------------
 
     def _step_donate(self) -> tuple:
-        """What the decode step and its bursts donate: the arena, the
+        """What the decode step donates: the arena, the
         lengths and the key chains. Not the tokens: a step's tokens are
         read by the host after the next step, which takes them as its
         input, is enqueued."""
@@ -762,8 +731,7 @@ class ServingEngine:
         mutable = ["cache"] + ([MOE_LOAD] if self._expert_layers else [])
 
         def step(params, arena, tokens, lengths, active, rngs, page_tables):
-            """One batched decode step -> (arena, tokens, lengths, rngs).
-            Jitted directly as the single step and scanned by the bursts."""
+            """One batched decode step -> (arena, tokens, lengths, rngs)."""
             # inactive slots still flow through the fused step (fixed batch)
             # but must NOT write at ``lengths``: a slot mid-admission has
             # prefill dispatches landing in the arena while decode steps run
@@ -804,96 +772,6 @@ class ServingEngine:
             return (mutated["cache"], nxt, new_lengths, new_rngs) + _expert_load(mutated)
 
         return step
-
-    def _build_verify_core(self):
-        """The speculative verify step: feed ``[last_token, d1..dK]`` per
-        slot at positions ``lengths..lengths+K``, sample a candidate at
-        every position with the EXACT per-step RNG subkeys the sequential
-        chain would draw, and accept the longest draft prefix that matches.
-        Emitted tokens are always the target model's own samples — drafts
-        only decide how many verify in one dispatch — so output is
-        token-exact vs. K+1 sequential steps for greedy AND sampled
-        decoding. Rollback costs nothing: rejected drafts' K/V sit beyond
-        the new frontier, where the decode mask already hides them and the
-        next write overwrites them (the same argument that makes slot reuse
-        clearing-free)."""
-        placer = self._placer
-        temperature, top_k = self.temperature, self.top_k
-        definition = self._paged_def
-        last_pos = self.max_cache_len - 1
-
-        @jax.named_scope("spec_verify")
-        def verify(params, arena, tokens, drafts, lengths, active, rngs, page_tables):
-            n, k = drafts.shape
-            seq = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [N, K+1]
-            pos = lengths[:, None] + jnp.arange(k + 1)[None, :]
-            write_pos = jnp.where(active[:, None], pos, last_pos)
-            out, mutated = definition.apply(
-                {"params": placer(params), "cache": arena},
-                seq,
-                positions=write_pos,
-                use_cache=True,
-                decode=True,
-                cache_positions=write_pos,
-                page_table=page_tables,
-                kv_lengths=jnp.where(active, lengths + k + 1, 0),
-                mutable=["cache"],
-            )
-            logits = out["logits"]  # [N, K+1, V]
-
-            def chain(rng):
-                # replay the sequential loop's split discipline: at each
-                # step split -> (carry, sub); collect each step's sub AND
-                # the carry after it, so any accepted count lands on the
-                # exact chain state sequential decode would hold
-                def body(r, _):
-                    nxt = jax.random.split(r)
-                    return nxt[0], (nxt[1], nxt[0])
-
-                _, (subs, states) = jax.lax.scan(body, rng, None, length=k + 1)
-                return subs, states  # each [K+1, 2]
-
-            subs, states = jax.vmap(chain)(rngs)
-            cand = jax.vmap(
-                jax.vmap(lambda key, row: _sample(row[None], key, temperature, top_k)[0])
-            )(subs, logits)  # [N, K+1]
-            matched = (cand[:, :k] == drafts).astype(jnp.int32)
-            m = jnp.sum(jnp.cumprod(matched, axis=1), axis=1)  # accepted drafts
-            rows = jnp.arange(n)
-            new_last = cand[rows, m]           # first non-matching / bonus token
-            new_rngs = states[rows, m]         # chain after m+1 splits
-            new_tokens = jnp.where(active, new_last, tokens)
-            new_lengths = jnp.where(active, lengths + m + 1, lengths)
-            new_rngs = jnp.where(active[:, None], new_rngs, rngs)
-            return mutated["cache"], new_tokens, new_lengths, new_rngs, cand, m
-
-        return verify
-
-    def _decode_burst(self, k: int):
-        """K fused decode steps in one dispatch: a lax.scan over the single
-        step body, so tokens are bit-identical to K separate steps. Returns
-        (arena, tokens, lengths, rngs, toks[K, N])."""
-        fn = self._decode_bursts.get(k)
-        if fn is not None:
-            return fn
-        core = self._step_core
-
-        def burst(params, arena, tokens, lengths, active, rngs, page_tables):
-            def body(carry, _):
-                arena, tokens, lengths, rngs = carry
-                arena, tokens, lengths, rngs = core(
-                    params, arena, tokens, lengths, active, rngs, page_tables
-                )
-                return (arena, tokens, lengths, rngs), tokens
-
-            (arena, tokens, lengths, rngs), toks = jax.lax.scan(
-                body, (arena, tokens, lengths, rngs), None, length=k
-            )
-            return arena, tokens, lengths, rngs, toks
-
-        fn = jax.jit(burst, donate_argnums=self._step_donate())
-        self._decode_bursts[k] = fn
-        return fn
 
     def _ragged_prefill_fn(self, cap: int):
         fn = self._ragged_fns.get(cap)
@@ -958,9 +836,8 @@ class ServingEngine:
     def warmup(self):
         """Compile every program this engine can ever dispatch — each
         packed-prefill grid capacity, the two admission programs (a request's
-        keys staged on the device, a pack's slots put live), the single decode
-        step and the ``steps_per_call`` burst — by running them once against
-        the (idle) arena. After
+        keys staged on the device, a pack's slots put live) and the decode
+        step — by running them once against the (idle) arena. After
         ``warmup(); mark_steady()``, ``admission_recompiles`` staying 0 is
         deterministic, not a function of what traffic happened to arrive.
         All-inactive decode steps park their writes (see the step body), so
@@ -1042,42 +919,6 @@ class ServingEngine:
             self.params, self._arena, self._tokens, self._lengths, self._active,
             self._rngs, self._tables_arg(),
         )
-        if self.steps_per_call > 1:
-            self._arena, self._tokens, self._lengths, self._rngs, _ = (
-                self._decode_burst(self.steps_per_call)(
-                    self.params, self._arena, self._tokens, self._lengths,
-                    self._active, self._rngs, self._tables_arg(),
-                )
-            )
-        if self._verify_step is not None:
-            # the speculative verify program: all-inactive, so state freezes
-            warm_drafts = jnp.zeros((self.num_slots, self.spec_k), jnp.int32)
-            # fingerprint the FULL steady-state arg set (what
-            # _spec_verify_once notes), so a later diagnosed recompile
-            # diffs against it instead of reporting every arg as new
-            self._note_forensics(
-                "spec_verify",
-                {"tokens": self._tokens, "drafts": warm_drafts,
-                 "lengths": self._lengths, "active": self._active,
-                 "rngs": self._rngs},
-            )
-            self._arena, self._tokens, self._lengths, self._rngs, _, _ = (
-                self._verify_step(
-                    self.params, self._arena, self._tokens, warm_drafts,
-                    self._lengths, self._active, self._rngs, self._page_tables,
-                )
-            )
-            if costs is not None:
-                # CostRegistry row for the verify executable, so the
-                # speculative win is attributable in the roofline table
-                try:
-                    costs.capture_lowered("spec_verify", self._verify_step.lower(
-                        self.params, self._arena, self._tokens, warm_drafts,
-                        self._lengths, self._active, self._rngs,
-                        self._page_tables,
-                    ))
-                except Exception:
-                    pass
         jax.device_get(self._tokens)
         # snapshot the decode step's memory_analysis here on the engine
         # thread so a later flight dump never has to; the AOT re-lower hits
@@ -1088,10 +929,9 @@ class ServingEngine:
     def audit_entrypoints(self) -> list:
         """Entry-point specs for the static program auditor
         (``accelerate_tpu.analysis.program_audit``): every program
-        ``warmup()`` compiles — the packed prefill grids, the decode step and the
-        ``steps_per_call`` burst, spec verify, the page-table maintenance
-        programs — with the example args warmup itself would pass and the
-        *effective* donation sets. Trace-only consumers: building the
+        ``warmup()`` compiles — the packed prefill grids, the decode step,
+        the page-table maintenance programs — with the example args warmup
+        itself would pass and the *effective* donation sets. Trace-only consumers: building the
         specs executes nothing and compiles nothing, so this is safe on
         a live engine (the jitted-fn caches it touches are the ones
         warmup populates anyway). ``donate_expected`` mirrors
@@ -1115,23 +955,6 @@ class ServingEngine:
             name="decode_step", fn=self._decode_step, args=step_args,
             donate=step_donate, donate_expected=donate_on, compute_dtype=dtype,
         ))
-        if self.steps_per_call > 1:
-            specs.append(dict(
-                name=f"decode_burst{self.steps_per_call}",
-                fn=self._decode_burst(self.steps_per_call), args=step_args,
-                donate=step_donate, donate_expected=donate_on,
-                compute_dtype=dtype,
-            ))
-        if self._verify_step is not None:
-            warm_drafts = jnp.zeros((self.num_slots, self.spec_k), jnp.int32)
-            specs.append(dict(
-                name="spec_verify", fn=self._verify_step,
-                args=(self.params, self._arena, self._tokens, warm_drafts,
-                      self._lengths, self._active, self._rngs,
-                      self._page_tables),
-                donate=(1, 2, 4, 6) if donate_on else (),
-                donate_expected=donate_on, compute_dtype=dtype,
-            ))
         table_donate = (0,) if donate_on else ()
         specs.append(dict(
             name="table_set_row", fn=self._set_row,
@@ -1223,14 +1046,11 @@ class ServingEngine:
             cover = max(
                 cover, self._plan_cover(prompt.size + max_new_tokens - 1)
             )
-        # speculative verify writes up to spec_k positions past the last
-        # sequential write, so spec reserves that much per-slot headroom
-        need = prompt.size + max_new_tokens + self.spec_k
+        need = prompt.size + max_new_tokens
         if need > self.max_cache_len or cover > self.max_cache_len:
             raise ValueError(
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new_tokens})"
-                + (f" + spec headroom ({self.spec_k})" if self.spec_k else "")
-                + f" exceeds the slot KV capacity ({self.max_cache_len}); "
+                f" exceeds the slot KV capacity ({self.max_cache_len}); "
                 "raise max_cache_len"
             )
         with self._id_lock:
@@ -2900,19 +2720,6 @@ class ServingEngine:
         return {"ssm_rows": rows, "ssm_slots": len(packs),
                 "ssm_fresh_slots": sum(1 for _, _, s0, *_ in packs if s0 == 0)}
 
-    def _burst_len(self) -> int:
-        """steps_per_call when a fused burst cannot delay an admission or
-        overshoot any request's token budget, else 1. Only these two values
-        ever compile, keeping the program set bounded."""
-        k = self.steps_per_call
-        if k <= 1 or self._admitting is not None or (self._queued_depth() and self._free):
-            return 1
-        remaining = min(
-            req.max_new_tokens - req._dispatched
-            for slot, req in self._slot_req.items() if self._active[slot]
-        )
-        return k if remaining >= k else 1
-
     def _next_write_pos(self, req: Request) -> int:
         """The slot's next cache write position: the latest dispatched
         token's K/V has not been written yet (prefill samples the first
@@ -2940,90 +2747,8 @@ class ServingEngine:
         for kind in others:
             sp.args[f"walked_tokens.{kind.name}"] = sum(kind.walked_tokens(p) for p in walked)
 
-    def _spec_verify_once(self) -> bool:
-        """One speculative round: host drafter proposes K tokens per slot,
-        one batched verify dispatch checks them all, the longest accepted
-        prefix (plus the bonus sample) is emitted. Replaces the burst when
-        spec is on — both amortize the host round trip, but verify turns
-        the decode step's idle MXU into accepted tokens."""
-        k = self.spec_k
-        drafts = np.zeros((self.num_slots, k), np.int32)
-        # a drafter exposing `lookback` only reads that many trailing
-        # tokens, so build just the context tail — rebuilding the full
-        # prompt+generation history every round is O(T^2) over a generation
-        lb = int(getattr(self._drafter, "lookback", 0) or 0)
-        with _span("serving/decode_grow") as sp:
-            pages0 = self.pages_allocated
-            walked = []
-            for slot, req in list(self._slot_req.items()):
-                if slot not in self._slot_req:
-                    continue  # shed/preempted while relieving another slot
-                gen = np.asarray(req.tokens[-lb:] if lb else req.tokens, np.int32)
-                if lb and gen.size >= lb:
-                    ctx = gen
-                else:
-                    head = req.prompt[-(lb - gen.size):] if lb else req.prompt
-                    ctx = np.concatenate([np.asarray(head, np.int32), gen])
-                drafts[slot] = self._drafter.propose(ctx, k)
-                pos = self._next_write_pos(req)
-                if self._grow_or_resolve(req, slot, pos, pos + k):
-                    walked.append(pos + k)
-            sp.args["pages_allocated"] = self.pages_allocated - pages0
-            self._note_walk(sp, walked)
-            if not self._slot_req:
-                return True  # every live slot was shed under page pressure
-            drafts_dev = jnp.asarray(drafts)
-            self._note_forensics(
-                "spec_verify",
-                {"tokens": self._tokens, "drafts": drafts_dev,
-                 "lengths": self._lengths, "active": self._active,
-                 "rngs": self._rngs},
-            )
-        with _span("serving/decode_dispatch", slots=len(self._slot_req),
-                   arena_in_place=0) as sp_d:  # several rows a slot: the scatter
-            (self._arena, self._tokens, self._lengths, self._rngs, cand, m) = (
-                self._verify_step(
-                    self.params, self._arena, self._tokens, drafts_dev,
-                    self._lengths, self._active, self._rngs, self._page_tables,
-                )
-            )
-        with _span("serving/token_fetch", in_flight=0) as sp_f:
-            cand_h = np.asarray(jax.device_get(cand))  # [N, K+1]; forces the step
-            m_h = np.asarray(jax.device_get(m))
-        t0, wall = sp_d.t0, sp_f.t1 - sp_d.t0
-        self._last_result_t = sp_f.t1
-        with _span("serving/emit", discarded=0) as sp_e:
-            done0 = self.requests_completed
-            self.step_count += 1
-            self._usage_note_step(wall, list(self._slot_req.items()))
-            emitted = 0
-            for slot, req in list(self._slot_req.items()):
-                accepted = int(m_h[slot])
-                n_emit = accepted + 1
-                req.spec_proposed += k
-                req.spec_accepted += accepted
-                self.spec_proposed += k
-                self.spec_accepted += accepted
-                for i in range(n_emit):
-                    # amortize the verify wall across this slot's emitted run
-                    # (same reasoning as the fused-burst ITL amortization)
-                    self._emit(req, int(cand_h[slot, i]), t0 + wall * (i + 1) / n_emit)
-                    emitted += 1
-                    if req.done:
-                        break  # budget/eos hit mid-run: drop the rest
-                req._dispatched = len(req.tokens)  # read as soon as dispatched
-            self._step_samples.append((wall, emitted, 1))
-            if self.telemetry is not None:
-                self.telemetry.on_step(self, wall, tokens=emitted, steps=1)
-                costs = getattr(self.telemetry, "costs", None)
-                if costs is not None:
-                    costs.note_wall("spec_verify", wall)
-            sp_e.args["emitted"] = emitted
-            sp_e.args["finished"] = self.requests_completed - done0
-        return True
-
-    def _grow_or_resolve(self, req: Request, slot: int, lo: int, hi: int) -> bool:
-        """Grow a live slot's pages for the next write range, resolving
+    def _grow_or_resolve(self, req: Request, slot: int, pos: int) -> bool:
+        """Grow a live slot's pages for its next write position, resolving
         page pressure by reading what is in flight (its tokens may end
         requests, this one too, and free their pages), then by preempting
         a strictly-lower-priority victim (its pages move here) or, when
@@ -3032,7 +2757,7 @@ class ServingEngine:
         slot is still live and writable."""
         while True:
             try:
-                self._ensure_writable(req, slot, lo, hi)
+                self._ensure_writable(req, slot, pos, pos)
                 return True
             except PagePressure:
                 if self._settle():
@@ -3054,14 +2779,8 @@ class ServingEngine:
         tokens of the step before it: the device runs one while the host
         emits the other. Where there is nothing to enqueue, what is in
         flight is read with nothing behind it."""
-        if self.spec_k:
-            # page growth follows the fetched acceptance counts, and the
-            # drafter reads the tokens: nothing stays in flight (depth 0)
-            self._settle()
-            return self._spec_verify_once() if self._slot_req else False
         if not self._active.any():
             return self._settle_step()
-        k = self._burst_len()
         with _span("serving/decode_grow") as sp:
             pages0, released0 = self.pages_allocated, self.pages_released
             # each grown slot's last write position of this round: the
@@ -3076,8 +2795,8 @@ class ServingEngine:
                     continue
                 pos = self._next_write_pos(req)
                 self._release_behind_window(req, slot, pos)
-                if self._grow_or_resolve(req, slot, pos, pos + k - 1):
-                    walked.append(pos + k - 1)
+                if self._grow_or_resolve(req, slot, pos):
+                    walked.append(pos)
             sp.args["pages_allocated"] = self.pages_allocated - pages0
             self._note_walk(sp, walked)
             if self._by_kind:
@@ -3105,34 +2824,25 @@ class ServingEngine:
                    # slot's state is copied in and out unchanged: not counted)
                    **({"ssm_slots": len(roster), "ssm_rows": len(roster)} if self._state_kind else {})) as sp_d:
             self._note_forensics(
-                "decode_step" if k == 1 else f"decode_burst{k}",
+                "decode_step",
                 {"tokens": self._tokens, "lengths": self._lengths,
                  "active": self._active, "rngs": self._rngs},
             )
             # the mask the program reads is its own copy: the engine changes
             # its own below, before the step has run
             active = self._active.copy()
-            if k > 1:
-                self._arena, self._tokens, self._lengths, self._rngs, toks = (
-                    self._decode_burst(k)(
-                        self.params, self._arena, self._tokens, self._lengths,
-                        active, self._rngs, self._tables_arg(),
-                    )
-                )
-            else:
-                self._arena, self._tokens, self._lengths, self._rngs, *load = self._decode_step(
-                    self.params, self._arena, self._tokens, self._lengths, active,
-                    self._rngs, self._tables_arg(),
-                )
-                toks = self._tokens
+            self._arena, self._tokens, self._lengths, self._rngs, *load = self._decode_step(
+                self.params, self._arena, self._tokens, self._lengths, active,
+                self._rngs, self._tables_arg(),
+            )
             for slot, req in roster:
-                req._dispatched += k
+                req._dispatched += 1
                 if req._dispatched >= req.max_new_tokens:
                     # its budget is on the device: it rides no further step (no
                     # wasted row), and keeps slot and pages until the host has
                     # read and emitted its last token
                     self._active[slot] = False
-        before, self._flight = self._flight, _StepFlight(toks, tuple(load), k, roster, sp_d)
+        before, self._flight = self._flight, _StepFlight(self._tokens, tuple(load), roster, sp_d)
         if before is not None:
             self._read_step(before, in_flight=1)
         return True
@@ -3148,73 +2858,58 @@ class ServingEngine:
     def _settle(self) -> bool:
         """Read everything in flight now, oldest first: the serial order,
         for whoever must know the tokens before acting (preemption, which
-        saves a slot's chain; page pressure; ``drain()``; a fault script;
-        speculative verify). Returns whether anything was read."""
+        saves a slot's chain; page pressure; ``drain()``; a fault script).
+        Returns whether anything was read."""
         step = self._settle_step()
         return self._read_packs(0) or step
 
     def _read_step(self, flight: "_StepFlight", in_flight: int):
-        """Fetch a decode step's (or burst's) tokens and emit them to the
-        requests that rode it. ``in_flight``: the decode dispatches enqueued
-        behind it when the host began to wait (1 overlapped, 0 settled)."""
-        k, roster, sp_d = flight.k, flight.roster, flight.span
+        """Fetch a decode step's tokens and emit them to the requests that
+        rode it. ``in_flight``: the decode dispatches enqueued behind it
+        when the host began to wait (1 overlapped, 0 settled)."""
+        roster, sp_d = flight.roster, flight.span
         with _span("serving/token_fetch", in_flight=in_flight) as sp_f:
-            host, *load = jax.device_get((flight.toks, *flight.load))  # forces the step or burst; one fetch
-            host = np.asarray(host)
-            if k == 1:
-                host = host[None]  # [1, N]
+            host, *load = jax.device_get((flight.toks, *flight.load))  # forces the step; one fetch
+            host = np.asarray(host)  # [N]
         if load:
             # the step's own span, one iteration after it closed
             _load_args(sp_d, np.asarray(load[0]), len(roster) * self._pairs_per_token,
-                       self._expert_rows(self.num_slots * k))
+                       self._expert_rows(self.num_slots))
         # the device took the step up when it had finished what lay before it
-        t0 = max(sp_d.t0, self._last_result_t)
-        wall = sp_f.t1 - t0
+        wall = sp_f.t1 - max(sp_d.t0, self._last_result_t)
         self._last_result_t = sp_f.t1
         with _span("serving/emit") as sp_e:
             done0 = self.requests_completed
-            self.step_count += k
+            self.step_count += 1
             self._usage_note_step(wall, roster)
             # rows computed for nothing: the request's eos was still in flight
             # when this step was enqueued (the write landed in its own page)
-            discarded = k * sum(1 for _, req in roster
-                                if req.done and req.finish_reason == "eos")
+            discarded = sum(1 for _, req in roster
+                            if req.done and req.finish_reason == "eos")
             emitted = 0
-            for i in range(k):
-                # a fused burst delivers k tokens in one host RTT; amortize the
-                # burst wall across them so ITL samples measure the chip's
-                # per-token pace instead of k-1 zeros plus one k-sized spike
-                # (the gaps feeding both the engine deque and the serving/itl
-                # SLO histogram — and through it the p99 profiler trigger)
-                ts = t0 + wall * (i + 1) / k
-                for slot, req in roster:
-                    if req.done:
-                        # ended since the dispatch: by that late eos, by an
-                        # eos earlier in this burst, or cancelled, timed out
-                        # or shed with this token in flight (it is dropped)
-                        continue
-                    self._emit(req, int(host[i, slot]), ts)
-                    emitted += 1
+            for slot, req in roster:
+                if req.done:
+                    # ended since the dispatch: by that late eos, or
+                    # cancelled, timed out or shed with this token in
+                    # flight (it is dropped)
+                    continue
+                self._emit(req, int(host[slot]), sp_f.t1)
+                emitted += 1
             self.rows_discarded += discarded
-            # count DELIVERED tokens, not n_active*k: an eos finish mid-burst
-            # drops its slot's remaining burst tokens, and tokens/s must not
-            # claim them
-            self._step_samples.append((wall, emitted, k))
+            # count DELIVERED tokens, not the roster: tokens/s must not
+            # claim a row whose request had ended
+            self._step_samples.append((wall, emitted))
             if self.telemetry is not None:
-                self.telemetry.on_step(self, wall, tokens=emitted, steps=k)
+                self.telemetry.on_step(self, wall, tokens=emitted)
                 costs = getattr(self.telemetry, "costs", None)
                 if costs is not None:
-                    # a fused burst is a lax.scan of k step BODIES, so its wall
-                    # bills the captured decode_step program as k executions —
-                    # the roofline row keeps accumulating in burst mode instead
-                    # of splitting into an uncaptured decode_burst<k> row
-                    costs.note_wall("decode_step", wall, calls=k)
+                    costs.note_wall("decode_step", wall)
             sp_e.args["emitted"] = emitted
             sp_e.args["discarded"] = discarded
             sp_e.args["finished"] = self.requests_completed - done0
 
     def _usage_note_step(self, wall_s: float, roster):
-        """Attribute one batched decode/verify dispatch's wall across the
+        """Attribute one batched decode dispatch's wall across the
         tenants of the requests that rode it, evenly."""
         usage = self._usage()
         if usage is None or not roster:
@@ -3338,12 +3033,12 @@ class ServingEngine:
         if self._draining:
             out["serving/draining"] = True
         if self._step_samples:
-            wall = sum(w for w, _, _ in self._step_samples)
-            toks = sum(n for _, n, _ in self._step_samples)
+            wall = sum(w for w, _ in self._step_samples)
+            toks = sum(n for _, n in self._step_samples)
             if wall > 0:
                 out["serving/tokens_per_s"] = toks / wall
             out["serving/decode_step_ms_p50"] = 1e3 * float(
-                np.median([w / s for w, _, s in self._step_samples])
+                np.median([w for w, _ in self._step_samples])
             )
         # the terminal-outcome denominator the shed-rate burn alert
         # divides by (telemetry/alerts.py): every request that reached an
@@ -3437,13 +3132,6 @@ class ServingEngine:
             out["serving/prefill_pad_waste_frac"] = (
                 1.0 - self.prefill_packed_tokens / self._prefill_rows_dispatched
             )
-        if self.spec_k:
-            out["serving/spec_proposed"] = self.spec_proposed
-            out["serving/spec_accepted"] = self.spec_accepted
-            out["serving/spec_accept_rate"] = (
-                self.spec_accepted / self.spec_proposed if self.spec_proposed
-                else 0.0
-            )
         if self._steady_mark is not None:
             out["serving/admission_recompiles"] = self.admission_recompiles
         # the placement-signal contract (telemetry/fleet.py, documented in
@@ -3532,13 +3220,12 @@ def _load_args(sp, load, pairs_all: int, rows: int) -> None:
 
 
 class _StepFlight(NamedTuple):
-    """A decode step (or burst) enqueued and not read: its tokens and
+    """A decode step enqueued and not read: its tokens and
     expert load on the device, the requests that rode it by slot, and its
     ``serving/decode_dispatch`` span."""
 
     toks: jax.Array
     load: tuple
-    k: int
     roster: list
     span: object
 

@@ -1,5 +1,5 @@
-"""Paged KV arena: fixed-size pages, refcounted free list, copy-on-write
-prefix cache, and the n-gram drafter for speculative decoding.
+"""Paged KV arena: fixed-size pages, refcounted free list and a
+copy-on-write prefix cache.
 
 A dense ``num_slots x max_cache_len`` block would reserve ``max_cache_len``
 of KV per slot no matter how long the request actually is, and every
@@ -21,11 +21,6 @@ system prompt. The serving engine's storage layer is **pages**:
   near-zero TTFT for templated traffic. Shared pages are **copy-on-write**:
   the engine forks (copies) a page before the first divergent write, so a
   mutation by one slot can never perturb another slot's tokens.
-- the **n-gram drafter** (:class:`NGramDrafter`) is the host-side,
-  model-free proposer for speculative decoding: it looks the request's most
-  recent n-gram up in its own prompt+generation history and proposes the
-  continuation — free draft tokens for templated/repetitive traffic that
-  the batched verify step then accepts or rolls back token-exactly.
 
 Everything above the device helpers is plain-python/numpy bookkeeping and
 imports **without jax or flax** (locked by tests/test_imports.py): a
@@ -605,53 +600,6 @@ class PrefixCache:
     @property
     def hit_ratio(self) -> float:
         return self.hits / self.lookups if self.lookups else 0.0
-
-
-class NGramDrafter:
-    """Prompt-lookup speculative drafter (model-free, host-side).
-
-    ``propose(context, k)`` matches the last ``order`` tokens of the
-    request's prompt+generation history against earlier occurrences
-    (longest order first, most recent match first) and proposes the ``k``
-    tokens that followed; short/no matches pad by repeating the last token
-    (a padded draft that happens to match is still token-exact — accepted
-    tokens are always the *target model's* samples, drafts only decide how
-    many verify in one step). Accept-rate expectations: high for
-    templated/repetitive continuations (code, JSON, retrieval-grounded
-    text), near zero for high-entropy sampling — the verify step then
-    degrades to one-token-per-call, never to wrong tokens.
-    """
-
-    def __init__(self, order: int = 3, min_order: int = 1,
-                 lookback: int = 1024):
-        if order < 1 or min_order < 1 or min_order > order:
-            raise ValueError(f"bad n-gram orders ({order}, {min_order})")
-        if lookback < 2:
-            raise ValueError(f"lookback must be >= 2, got {lookback}")
-        self.order = int(order)
-        self.min_order = int(min_order)
-        # bound the per-proposal scan: without it the sliding-window match
-        # walks the FULL prompt+generation history every verify round,
-        # which is quadratic host work over a long generation
-        self.lookback = int(lookback)
-
-    def propose(self, context: np.ndarray, k: int) -> np.ndarray:
-        context = np.asarray(context, np.int32).reshape(-1)[-self.lookback:]
-        out = np.full((k,), int(context[-1]) if context.size else 0, np.int32)
-        if context.size < 2:
-            return out
-        for n in range(min(self.order, context.size - 1), self.min_order - 1, -1):
-            pat = context[-n:]
-            # most recent earlier occurrence of the n-gram
-            windows = np.lib.stride_tricks.sliding_window_view(context[:-1], n)
-            matches = np.nonzero((windows == pat).all(axis=1))[0]
-            if matches.size == 0:
-                continue
-            j = int(matches[-1])
-            cont = context[j + n : j + n + k]
-            out[: cont.size] = cont
-            return out
-        return out
 
 
 class PagedTables:

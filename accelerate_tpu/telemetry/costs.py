@@ -156,7 +156,7 @@ class CostRegistry:
         come from call sites that already hold a compiled executable)."""
         return self.capture(name, lowered)
 
-    def note_wall(self, name: str, wall_s: float, calls: int = 1):
+    def note_wall(self, name: str, wall_s: float):
         """Attribute measured wall to an executable (one dict update per
         step — the whole per-step cost of the attribution)."""
         with self._lock:
@@ -164,7 +164,7 @@ class CostRegistry:
             if row is None:
                 row = self.entries[name] = {"name": name, "wall_s": 0.0, "calls": 0}
             row["wall_s"] = row.get("wall_s", 0.0) + float(wall_s)
-            row["calls"] = row.get("calls", 0) + int(calls)
+            row["calls"] = row.get("calls", 0) + 1
 
     # -- consumers ---------------------------------------------------------
 

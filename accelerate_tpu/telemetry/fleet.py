@@ -353,7 +353,6 @@ _COUNTER_KEYS = frozenset({
     "serving/requests_completed", "serving/generated_tokens",
     "serving/requests_terminal", "serving/shed", "serving/cancelled",
     "serving/preemptions", "serving/resumptions",
-    "serving/spec_proposed", "serving/spec_accepted",
     "serving/prefill_chunks_skipped", "serving/page_forks",
     "serving/prefix_hit_tokens", "serving/admission_recompiles",
     "serving/itl_slo_breaches", "serving/itl_budget_adjustments",

@@ -218,13 +218,11 @@ class RequestTracer:
         if shed_reason:
             rec["shed_reason"] = shed_reason
         rec["finish_unix_s"] = round(time.time(), 6)
-        # paged-arena / speculative attribution (engine-owned counters on
-        # the request): how much of this
-        # request's TTFT the prefix cache saved, what it cost in pages,
-        # and how its draft tokens fared — what `accelerate-tpu trace`
-        # aggregates into per-burst hit/accept rates
-        for attr in ("prefix_hit", "pages_allocated", "spec_proposed",
-                     "spec_accepted"):
+        # paged-arena attribution (engine-owned counters on the request):
+        # how much of this request's TTFT the prefix cache saved and what
+        # it cost in pages — what `accelerate-tpu trace` aggregates into
+        # per-burst hit rates
+        for attr in ("prefix_hit", "pages_allocated"):
             rec[attr] = int(getattr(req, attr, 0) or 0)
         # tiered-KV restore hop (PR 17): which tier fed this request's
         # prefix hit and what the pull cost — the waterfall's kv_restore

@@ -17,7 +17,7 @@ is off, the established hot-path contract), it meters per tenant:
   time × held pages accrues continuously — the "who is consuming the
   HBM budget" number;
 - **compute_ms** — measured dispatch wall attributed per tenant: a
-  prefill chunk bills its admitting tenant, a batched decode/verify step
+  prefill chunk bills its admitting tenant, a batched decode step
   splits its wall evenly across the live slots' tenants (the same
   dispatches the CostRegistry's roofline rows record);
 - **outcome counts** — submitted / finished / shed / cancelled /

@@ -73,7 +73,7 @@ class Sizes:
     new_tokens: int
     kv_heads_gqa: int
     kv_bits: tuple         # KV storage the kernel checks cover (0 = bf16)
-    decode_widths: tuple   # query widths: 1 = decode step, 5 = a speculative verify
+    decode_widths: tuple   # query widths: 1 = decode step, 5 = several rows a slot (ROADMAP R12)
 
 
 # Depths come from compiled.memory_analysis() of the AOT rehearsal for

@@ -9,8 +9,8 @@ Contracts of record:
   constant, donation miss, f32 drift, host callback, weak shape) on
   deliberately-bad jitted programs, again with exact fingerprints;
 - the repo's OWN programs and host modules are clean: zero findings over
-  the serving engine's full warmup program set (paged + speculative +
-  flat + donation-on), zero host-lint findings over the tree, and the
+  the serving engine's full warmup program set (paged, flat and
+  donation-on), zero host-lint findings over the tree, and the
   `accelerate-tpu audit` gate exits 0 modulo the checked-in baseline —
   this tier-1 test IS the CI gate;
 - `audit` exits non-zero on unbaselined P1 findings; baselined findings
@@ -329,12 +329,9 @@ class TestProgramAuditCorpus:
             return x + 1.0
 
         fs = pa.audit_entrypoints(
-            [dict(name="decode_step", fn=jax.jit(fine), args=(jnp.ones((4,)),)),
-             dict(name="decode_burst2", fn=jax.jit(fine), args=(jnp.ones((4,)),))],
-            # decode_burst<4> is covered by the audited decode_burst family;
+            [dict(name="decode_step", fn=jax.jit(fine), args=(jnp.ones((4,)),))],
             # ghost_program is covered by nothing -> the P3 coverage finding
-            registered={"decode_step": {}, "decode_burst<4>": {},
-                        "ghost_program": {}},
+            registered={"decode_step": {}, "ghost_program": {}},
         )
         ghosts = [f for f in fs if f.check == "unaudited-entrypoint"]
         assert [f.target for f in ghosts] == ["ghost_program"]
@@ -356,7 +353,7 @@ def audited_model():
 class TestEngineWarmupSetZeroFalsePositives:
     """The acceptance half of the golden corpus: the SAME checks that
     flag every seeded violation must emit nothing over the engine's real
-    program set — paged + speculative + burst, flat, and donation-on."""
+    program set — paged, flat, and donation-on."""
 
     def _engine(self, audited_model, **kw):
         from accelerate_tpu.serving import ServingEngine
@@ -367,18 +364,16 @@ class TestEngineWarmupSetZeroFalsePositives:
         kw.setdefault("prefill_chunks", (4, 8))
         return ServingEngine(model, params, **kw)
 
-    def test_paged_spec_warmup_set_clean(self, audited_model):
-        eng = self._engine(audited_model, page_size=8, spec_draft_len=3,
-                           steps_per_call=2)
+    def test_paged_warmup_set_clean(self, audited_model):
+        eng = self._engine(audited_model, page_size=8)
         eng.warmup()
         fs = pa.audit_engine(eng)
         assert fs == [], [f.to_dict() for f in fs]
         names = {pa.EntrypointSpec.normalize(s).name
                  for s in eng.audit_entrypoints()}
         # the full warmup program set is enumerated
-        assert {"ragged_prefill_8", "decode_step", "decode_burst2",
-                "spec_verify", "table_set_row", "table_set_entry",
-                "page_fork"} <= names
+        assert {"ragged_prefill_8", "decode_step", "table_set_row",
+                "table_set_entry", "page_fork"} <= names
 
     def test_default_engine_clean(self, audited_model):
         eng = self._engine(audited_model)
@@ -389,8 +384,7 @@ class TestEngineWarmupSetZeroFalsePositives:
         # trace-only: donate=True never executes here, so the CPU sim's
         # warn-and-copy behavior is irrelevant — the audit checks that
         # every aval-matched buffer IS in the declared donate sets
-        eng = self._engine(audited_model, page_size=8, spec_draft_len=3,
-                           donate=True)
+        eng = self._engine(audited_model, page_size=8, donate=True)
         fs = pa.audit_engine(eng)
         assert fs == [], [f.to_dict() for f in fs]
 

@@ -6,7 +6,7 @@ CPU (the compiled TPU path shares every line but the `interpret` flag):
 - the paged kernel (direct page-table walk) matches the gathered
   masked-dense reference across length edges — position 0, 1, page
   boundaries, full arena, ragged mixes — for every GQA group size and for
-  multi-query Sq > 1 (the spec-verify shape);
+  multi-query Sq > 1 (several rows a slot; ROADMAP R12);
 - the dense-arena kernel matches the masked-dense reference for shared
   ([Sq]) and per-slot ([B, Sq]) positions at any valid kv block size;
 - the parking page (page 0) is never *observable*: arbitrary garbage in
@@ -95,10 +95,10 @@ class TestPagedKernelExactness:
                                    atol=ATOL, rtol=1e-5)
 
     @pytest.mark.parametrize("sq", [2, 3, 5])
-    def test_multi_query_spec_verify_shape(self, sq):
-        """Sq > 1 with per-row consecutive positions — the spec_verify /
-        fused-burst form: row t attends <= its own position, so draft
-        token i sees drafts 0..i written in the same call."""
+    def test_multi_query_shape(self, sq):
+        """Sq > 1 with per-row consecutive positions — several rows a slot
+        (no engine program dispatches it; ROADMAP R12): row t attends <= its
+        own position, so token i sees tokens 0..i written in the same call."""
         rng = np.random.RandomState(2)
         q, kp, vp, table = _paged_setup(rng, sq=sq)
         base = jnp.asarray([0, 7, 20], jnp.int32)

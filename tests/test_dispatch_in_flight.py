@@ -36,7 +36,12 @@ def _params(model):
 def models():
     """``dense``: one kind. ``window``: a full and a window kind (pages
     released behind the window). ``state``: state-space layers beside an
-    attention layer (a recurrent state a slot, zeroed inside the pack)."""
+    attention layer (a recurrent state a slot, zeroed inside the pack);
+    ``heads``: the same with the mixer that has heads. ``latent``: latent
+    attention (one entry a token for all heads). ``closing``: a closing
+    window of 16 in chunks of 4 (a step that fills a page pools it, a close
+    gives the window's pages back). ``experts``: routed experts (both
+    programs return their load)."""
     common = dict(vocab_size=128, embed_dim=64, mlp_dim=128, num_heads=4, max_seq_len=96, dtype=jnp.float32,
                   scan_layers=True, remat=False)
     window = DecoderLM(DecoderConfig(
@@ -46,9 +51,20 @@ def models():
         num_layers=3, num_kv_heads=1, head_dim=16, rope_dim=0, layer_pattern=(0, 1, 0),
         layer_kinds=(("state_space", dict(mixer="ssm", ssm_state_dim=8, ssm_dt_rank=8)),
                      ("attention", dict(mixer="attention"))), **common))
+    heads = DecoderLM(DecoderConfig(
+        num_layers=3, num_kv_heads=1, head_dim=16, rope_dim=0, layer_pattern=(0, 1, 0),
+        layer_kinds=(("state_space", dict(mixer="ssd", ssm_num_heads=4, ssm_head_dim=16, ssm_state_dim=8)),
+                     ("attention", dict(mixer="attention"))), **common))
+    latent = DecoderLM(DecoderConfig(
+        num_layers=2, head_dim=24, v_head_dim=16, kv_lora_rank=32, q_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, **common))
+    closing = DecoderLM(DecoderConfig(num_layers=2, num_kv_heads=2, head_dim=16, eva_window=16, eva_chunk=4, **common))
+    experts = DecoderLM(DecoderConfig(num_layers=2, num_kv_heads=2, head_dim=16, moe_num_experts=4, moe_top_k=2,
+                                      **common))
     dense = DecoderLM(DecoderConfig.tiny(max_seq_len=96))
-    return {"dense": (dense, _params(dense)), "window": (window, _params(window)),
-            "state": (state, _params(state))}
+    found = dict(dense=dense, window=window, state=state, heads=heads, latent=latent, closing=closing,
+                 experts=experts)
+    return {name: (model, _params(model)) for name, model in found.items()}
 
 
 BASE = dict(num_slots=2, max_cache_len=96, page_size=8, prefill_chunks=(8, 16))
@@ -60,13 +76,22 @@ PATHS = {
     "greedy": ("dense", {}),
     "sampled": ("dense", dict(temperature=0.8, top_k=5)),
     "donated": ("dense", dict(donate=True)),
-    "burst": ("dense", dict(steps_per_call=3)),
     "scheduler": ("dense", dict(scheduler=SchedulerConfig())),
     "one_slot": ("dense", dict(num_slots=1)),
-    "verify": ("dense", dict(spec_draft_len=2)),
     "window": ("window", dict(prefix_cache=False, num_pages=1 + 2 * 12, kind_pages={"window16": 1 + 2 * 5})),
     "state": ("state", dict(prefix_cache=False)),
+    "heads": ("heads", dict(prefix_cache=False)),
+    "latent": ("latent", dict(prefix_cache=False)),
+    "closing": ("closing", dict(prefix_cache=False, page_size=4)),
+    "experts": ("experts", dict(prefix_cache=False)),
 }
+# the paths of a model by kind: a late eos frees a slot that the next pack takes
+BY_KIND = ("window", "state", "heads", "latent", "closing", "experts")
+
+
+def _base(kind):
+    """The engine's arguments for a model by its name (a path by kind has its model's name)."""
+    return {**BASE, **(PATHS[kind][1] if kind in BY_KIND else {})}
 
 
 def _mark():
@@ -119,14 +144,11 @@ def test_the_deferred_order_serves_what_the_settled_order_serves(models, path):
     assert all(r.outcome == "finished" and len(r.tokens) == new for r, (_, new) in zip(deferred[1], REQUESTS))
     assert deferred[0].rows_discarded == 0  # no eos: no row is computed for nothing
     overlapped = sum(f[5]["in_flight"] for f in fetches)
-    if path == "verify":
-        assert overlapped == 0  # speculative verify keeps depth 0
-    else:
-        # all but the reads that found nothing left to enqueue
-        assert overlapped >= len(fetches) / 2 and len(fetches) - overlapped <= len(REQUESTS)
+    # all but the reads that found nothing left to enqueue
+    assert overlapped >= len(fetches) / 2 and len(fetches) - overlapped <= len(REQUESTS)
 
 
-@pytest.mark.parametrize("path", ["greedy", "sampled", "burst", "one_slot", "window", "state"])
+@pytest.mark.parametrize("path", ["greedy", "sampled", "one_slot", *BY_KIND])
 def test_a_late_eos_discards_one_row_and_nothing_follows_it(models, path):
     """The end of a request by eos is learnt one dispatch late: the step
     already enqueued computed a row for it, which is counted and thrown
@@ -135,8 +157,9 @@ def test_a_late_eos_discards_one_row_and_nothing_follows_it(models, path):
     it), and that request is served what the settled order serves it."""
     requests = ((20, 12), (5, 12), (12, 12), (3, 12), (9, 12))
     _, free = _serve(models, path, settled=True, requests=requests)
-    # a token that the first request emits mid-stream, and others may too
-    eos = free[0].tokens[4]
+    # a token that the first request emits mid-stream, and others may too, but none as its first (that one's
+    # row is computed in either order: a pack's slots ride the decode step enqueued before its firsts are read)
+    eos = next(t for t in (free[0].tokens[4], *free[0].tokens[1:]) if all(t != r.tokens[0] for r in free))
     stale, reused = set(), []
 
     def each(eng):
@@ -151,27 +174,27 @@ def test_a_late_eos_discards_one_row_and_nothing_follows_it(models, path):
     want_eng, want = _serve(models, path, settled=True, requests=requests, eos_token_id=eos)
     assert _facts(eng, reqs, pages=False) == _facts(want_eng, want, pages=False)
     ended = [r for r in reqs if r.finish_reason == "eos"]
-    assert reqs[0] in ended and len(reqs[0].tokens) <= 5
+    assert reqs[0] in ended and len(reqs[0].tokens) == free[0].tokens.index(eos) + 1 < 12
     for r in ended:  # nothing emitted after the eos
         assert r.tokens[-1] == eos and eos not in r.tokens[:-1]
     late = [r for r in ended if len(r.tokens) < r.max_new_tokens and len(r.tokens) > 1]
-    if path == "burst":
-        # a burst only runs while no request could end inside it by its
-        # budget, but an eos inside one drops the burst's rest, as it did
-        assert sum(s[5]["discarded"] for s in emits) == eng.rows_discarded
-    else:
-        assert sum(s[5]["discarded"] for s in emits) == eng.rows_discarded == len(late) > 0
-        assert want_eng.rows_discarded == 0
-        # a discarded row may have taken the page after the request's last
-        assert 0 <= eng.pages_allocated - want_eng.pages_allocated <= len(late)
-    if path in ("one_slot", "state", "window"):
+    assert sum(s[5]["discarded"] for s in emits) == eng.rows_discarded == len(late) > 0
+    assert want_eng.rows_discarded == 0
+    # a discarded row may have taken the page after the request's last
+    assert 0 <= eng.pages_allocated - want_eng.pages_allocated <= len(late)
+    if path == "one_slot" or path in BY_KIND:
         assert reused  # a slot freed by the late eos, taken while the step that wrote into it was in flight
 
 
+@pytest.mark.parametrize("kind", ["dense", "heads", "closing"])
 @pytest.mark.parametrize("how", ["cancel", "timeout"])
-def test_a_request_ended_by_reap_drops_its_token_in_flight(models, how):
-    model, params = models["dense"]
-    eng = ServingEngine(model, params, **BASE)
+def test_a_request_ended_by_reap_drops_its_token_in_flight(models, how, kind):
+    """... and what the dropped row left behind (a state advanced once more,
+    a page of a closing kind filled and pooled) is nothing to the request
+    that stays, nor to whoever takes the slot next."""
+    model, params = models[kind]
+    base = _base(kind)
+    eng = ServingEngine(model, params, **base)
     rng = np.random.RandomState(5)
     victim = eng.submit(rng.randint(3, 120, (9,)), max_new_tokens=20, seed=0)
     other = eng.submit(rng.randint(3, 120, (6,)), max_new_tokens=10, seed=1)
@@ -190,20 +213,27 @@ def test_a_request_ended_by_reap_drops_its_token_in_flight(models, how):
     assert len(victim.tokens) == held and victim.slot is None
     (emit,) = _since(mark, "serving/emit")
     assert emit[5]["emitted"] == 1 and emit[5]["discarded"] == 0  # the other's token; a drop is no late eos
+    after = eng.submit(rng.randint(3, 120, (7,)), max_new_tokens=6, seed=2)  # takes the victim's slot
     _drive(eng, settled=False)
-    want = ServingEngine(model, params, **BASE).generate_batched([other.prompt], max_new_tokens=10, seeds=[1])[0]
-    np.testing.assert_array_equal(other.result(), want)
+    want = ServingEngine(model, params, **base).generate_batched([other.prompt, after.prompt], max_new_tokens=10,
+                                                                 seeds=[1, 2])
+    np.testing.assert_array_equal(other.result(), want[0])
+    np.testing.assert_array_equal(after.result(), want[1][:after.prompt.size + 6])
 
 
-def test_page_pressure_that_preempts_reads_the_tokens_first(models):
+@pytest.mark.parametrize("relief", ["preempts", "sheds"])
+def test_page_pressure_reads_the_tokens_first(models, relief):
     """A live slot cannot grow: the engine settles (the victim's chain is
     saved behind every dispatched step, so its tokens must all be read),
-    pages the victim out, and both requests end as in the settled order."""
+    pages the victim out, and both requests end as in the settled order.
+    With no scheduler to name a victim, the request that cannot grow is shed
+    with every token it was served read, and the other is served to its end."""
     model, params = models["dense"]
 
     def run(settled):
         eng = ServingEngine(model, params, num_slots=2, max_cache_len=24, prefill_chunks=(4, 8), page_size=8,
-                            num_pages=6, prefix_cache=False, scheduler=SchedulerConfig())
+                            num_pages=6, prefix_cache=False,
+                            scheduler=SchedulerConfig() if relief == "preempts" else None)
         rng = np.random.RandomState(7)
         low = eng.submit(rng.randint(3, 120, (8,)), max_new_tokens=16, seed=1, priority=0)
         high = eng.submit(rng.randint(3, 120, (3,)), max_new_tokens=20, seed=2, priority=5)
@@ -212,10 +242,17 @@ def test_page_pressure_that_preempts_reads_the_tokens_first(models):
 
     eng, reqs = run(False)
     want_eng, want = run(True)
-    assert eng.preemptions == want_eng.preemptions >= 1 and eng.resumptions == want_eng.resumptions
-    assert [(list(r.tokens), r.outcome, r.preemptions) for r in reqs] == \
-        [(list(r.tokens), r.outcome, r.preemptions) for r in want]
-    assert reqs[1].outcome == "finished" and len(reqs[1].tokens) == 20
+    assert eng.preemptions == want_eng.preemptions == int(relief == "preempts")
+    assert eng.resumptions == want_eng.resumptions
+    assert [(list(r.tokens), r.outcome, r.shed_reason, r.preemptions) for r in reqs] == \
+        [(list(r.tokens), r.outcome, r.shed_reason, r.preemptions) for r in want]
+    if relief == "preempts":
+        assert reqs[1].outcome == "finished" and len(reqs[1].tokens) == 20
+    else:
+        assert sorted(r.outcome for r in reqs) == ["finished", "shed"] and eng.requests_shed == 1
+        shed = next(r for r in reqs if r.outcome == "shed")
+        assert shed.shed_reason == "page_exhausted" and 0 < len(shed.tokens) == shed._dispatched < shed.max_new_tokens
+        assert eng._allocator.in_use == 0 and eng._flight is None
 
 
 def test_drain_delivers_what_was_computed_and_leaves_nothing_unread(models):
@@ -266,11 +303,13 @@ def test_first_and_second_token_are_never_read_together(models):
     assert any(f[5]["in_flight"] == 1 for f in firsts)
 
 
-def test_step_waits_for_the_device_only_under_the_two_fetch_spans(models, monkeypatch):
+@pytest.mark.parametrize("kind", ["dense", *BY_KIND])
+def test_step_waits_for_the_device_only_under_the_two_fetch_spans(models, monkeypatch, kind):
     """Every ``jax.device_get`` and ``block_until_ready`` of a run lies inside
     a ``serving/token_fetch`` or ``serving/prefill_fetch`` span, and nothing
     compiles after ``mark_steady()`` across admissions, finishes and slots
-    used again."""
+    used again: whatever the kinds keep (pages released behind a window or
+    at a close, a state a slot, the experts' load read with the tokens)."""
     calls = []
 
     def timed(fn):
@@ -282,8 +321,8 @@ def test_step_waits_for_the_device_only_under_the_two_fetch_spans(models, monkey
                 calls.append((t0, time.perf_counter()))
         return wrapper
 
-    model, params = models["dense"]
-    eng = ServingEngine(model, params, **BASE, temperature=0.7, top_k=8, eos_token_id=5)
+    model, params = models[kind]
+    eng = ServingEngine(model, params, **_base(kind), temperature=0.7, top_k=8, eos_token_id=5)
     eng.warmup()
     eng.mark_steady()
     monkeypatch.setattr(jax, "device_get", timed(jax.device_get))

@@ -3,7 +3,7 @@
 as children, in order, and the request-level stamps and spans beside them.
 
 One engine run a path (the packed prefill on its kernel and on its dense
-reference, quantized pages, fused burst, speculative verify), read back
+reference, quantized pages, a prefix cache that evicts and none), read back
 from the process-wide span ring.
 """
 
@@ -27,10 +27,6 @@ from accelerate_tpu.telemetry import spans as spans_mod
 PHASES = ("serving/reap", "serving/admit_plan", "serving/prefill_dispatch",
           "serving/decode_grow", "serving/decode_dispatch", "serving/token_fetch",
           "serving/emit", "serving/prefill_fetch", "serving/prefill_commit")
-# speculative verify keeps depth 0 (its page growth follows the fetched
-# acceptance counts): every result is read before the next dispatch
-SERIAL_PHASES = PHASES[:3] + PHASES[7:] + PHASES[3:7]
-SERIAL_PATHS = ("verify",)
 # the admission's host work, each under the phase that encloses it (its parent
 # in the ring): recorded only where the work exists
 CHILDREN = {"serving/prefix_lookup": "serving/admit_plan", "serving/page_grow": "serving/admit_plan",
@@ -52,21 +48,41 @@ PATHS = {
     "dense": dict(page_size=8),  # the packed dispatch on its reference
     # quantized pages: the kernels with the split-threading arm of the decode step
     "int8": dict(page_size=8, kernels=True, kv_cache_dtype="int8"),
-    "burst": dict(page_size=8, kernels=True, steps_per_call=2),
-    "verify": dict(page_size=8, kernels=True, spec_draft_len=2),
     # a prefix cache of two entries: every insert evicts (a 20-token prompt registers three prefixes)
     "evict": dict(page_size=8, kernels=True, prefix_max_entries=2),
     "noprefix": dict(page_size=8, kernels=True, prefix_cache=False),
+    # a model by kind each (``KINDS``), on the programs' references and with no prefix cache (it is refused them)
+    "window": dict(page_size=8, prefix_cache=False, kind="window"),
+    "closing": dict(page_size=4, prefix_cache=False, kind="closing"),
+    "heads": dict(page_size=8, prefix_cache=False, kind="heads"),
 }
+# what each model by kind states beside the tiny model's own widths: a window kind beside a full one, a closing
+# window of 16 in chunks of 4, and a state with heads beside an attention layer. (Latent attention and experts
+# keep host work between the phases, ``latent_tokens`` reckoned before the dispatch span opens and the expert load
+# written between fetch and emit: more than the cover this file asks of a step on the CPU.)
+KINDS = {
+    "window": dict(num_layers=3, layer_pattern=(0, 1, 1), layer_kinds=(
+        ("full", dict(num_kv_heads=1)), ("window", dict(num_kv_heads=2, attn_window=16)))),
+    "closing": dict(eva_window=16, eva_chunk=4),
+    "heads": dict(num_layers=3, rope_dim=0, layer_pattern=(0, 1, 0), layer_kinds=(
+        ("state_space", dict(mixer="ssd", ssm_num_heads=4, ssm_head_dim=16, ssm_state_dim=8)),
+        ("attention", dict(mixer="attention")))),
+}
+NO_PREFIX = ("noprefix", *KINDS)
 
 
-@pytest.fixture(scope="module")
-def model_and_params():
-    cfg = DecoderConfig.tiny(max_seq_len=64)
+def _served(**kind):
+    """The tiny model, with what a kind states beside its widths."""
+    cfg = DecoderConfig.tiny(max_seq_len=64, **kind)
     model = DecoderLM(cfg)
     variables = model.init_variables(jax.random.PRNGKey(0), batch_size=1, seq_len=16)
     params, _ = unbox_params(variables["params"])
     return model, cfg, params
+
+
+@pytest.fixture(scope="module")
+def model_and_params():
+    return _served()
 
 
 def _mark():
@@ -83,8 +99,7 @@ def _spans_since(mark):
 def _engine(model, cfg, params, *, kernels=False, **kw):
     """The tests' engine; ``kernels``: both serving kernels, interpreted."""
     if kernels:
-        cfg = dataclasses.replace(cfg, decode_kernel="interpret", decode_kernel_block=8,
-                                  prefill_kernel="interpret")
+        cfg = dataclasses.replace(cfg, decode_kernel="interpret", prefill_kernel="interpret")
         model = model.clone(config=cfg)
     args = dict(num_slots=2, max_cache_len=64, page_size=8, prefill_chunks=(8, 16))
     args.update(kw)
@@ -128,7 +143,9 @@ def runs(model_and_params):
 
     def get(path):
         if path not in cache:
-            cache[path] = Run(*model_and_params, **PATHS[path])
+            kw = dict(PATHS[path])
+            served = _served(**KINDS[kw.pop("kind")]) if "kind" in kw else model_and_params
+            cache[path] = Run(*served, **kw)
         return cache[path]
 
     return get
@@ -146,8 +163,7 @@ def test_every_step_has_its_phases_in_order_and_they_cover_it(runs, path):
         names = [k[2] for k in kids]
         assert len(kids) + 1 <= 12, names  # the budget: at most 12 spans an iteration
         # in order, none twice (no scheduler here: one admission an iteration)
-        phases = SERIAL_PHASES if path in SERIAL_PATHS else PHASES
-        order = [phases.index(n) for n in names]
+        order = [PHASES.index(n) for n in names]
         assert order == sorted(set(order)), names
         assert names[:2] == ["serving/reap", "serving/admit_plan"]
         by_name = {k[2]: k for k in kids}
@@ -157,15 +173,10 @@ def test_every_step_has_its_phases_in_order_and_they_cover_it(runs, path):
             # the iteration that sent it, every time
             assert "serving/prefill_commit" in names and "serving/prefill_fetch" in names
             # ... behind the decode dispatch, where there is one
-            assert by_name["serving/prefill_fetch"][5]["in_flight"] == int(
-                "serving/decode_dispatch" in names and path not in SERIAL_PATHS)
+            assert by_name["serving/prefill_fetch"][5]["in_flight"] == int("serving/decode_dispatch" in names)
         if "serving/decode_dispatch" in names:
             assert "serving/decode_grow" in names
-        if path in SERIAL_PATHS:
-            if "serving/decode_dispatch" in names:
-                assert names[-3:] == ["serving/decode_dispatch", "serving/token_fetch", "serving/emit"]
-                assert by_name["serving/token_fetch"][5]["in_flight"] == 0
-        elif "serving/token_fetch" in names:
+        if "serving/token_fetch" in names:
             # the tokens read are the previous dispatch's: read behind this
             # iteration's dispatch, or alone once nothing is left to enqueue
             assert names[names.index("serving/token_fetch") + 1] == "serving/emit"
@@ -203,12 +214,12 @@ def test_every_step_has_its_phases_in_order_and_they_cover_it(runs, path):
     # that the device works while the host does)
     assert uncovered <= 0.05 * sum(s[4] - s[3] for s in steps) + 1e-4 * len(steps)
     assert dispatched == len(run.named("serving/prefill_dispatch")) > 0
-    # every decode dispatch's tokens were read, one fetch each, and on the
-    # overlapped paths all but the last behind the next dispatch
+    # every decode dispatch's tokens were read, one fetch each, all but the
+    # last behind the next dispatch
     fetches = run.named("serving/token_fetch")
     assert len(fetches) == len(run.named("serving/decode_dispatch")) > 0
     overlapped = sum(f[5]["in_flight"] for f in fetches)
-    assert overlapped == 0 if path in SERIAL_PATHS else overlapped >= len(fetches) - len(PROMPT_LENS)
+    assert overlapped >= len(fetches) - len(PROMPT_LENS)
     assert run.engine.metrics()["serving/dispatch_depth"] == 0  # run() leaves nothing unread
     # no span per token or per slot: everything recorded is one of these
     allowed = set(PHASES) | set(CHILDREN) | {"serving/step", "serving/warmup", "serving/queue_wait",
@@ -218,7 +229,7 @@ def test_every_step_has_its_phases_in_order_and_they_cover_it(runs, path):
     by_id = {s[0]: s for s in run.spans}
     assert all(by_id[s[1]][2] == CHILDREN[s[2]] for s in run.spans if s[2] in CHILDREN)
     # a lookup and an insert a request where there is a prefix cache, none where there is none
-    per_request = 0 if path == "noprefix" else len(PROMPT_LENS)
+    per_request = 0 if path in NO_PREFIX else len(PROMPT_LENS)
     assert len(run.named("serving/prefix_lookup")) == len(run.named("serving/prefix_insert")) == per_request
     assert len(run.named("serving/pack_upload")) == dispatched
 
@@ -234,8 +245,8 @@ def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
     if path == "ragged":
         assert any(s[5]["requests"] > 1 for s in dispatches)  # the long prompt's tail and a short prompt share a grid
     # the pack program carries the arena and its kernel writes the pack's pages: wherever that kernel runs over
-    # unquantized pages (the decode dispatch's own counter says 0 on every verify dispatch; the pack's does not)
-    in_place = int(path in ("ragged", "burst", "verify", "evict", "noprefix"))
+    # unquantized pages
+    in_place = int(path in ("ragged", "evict", "noprefix"))
     assert {s[5]["arena_in_place"] for s in dispatches} == {in_place}
     assert eng.metrics()["serving/prefill_arena_in_place"] == in_place
     assert sum(s[5]["emitted"] for s in run.named("serving/step")) == eng.generated_tokens
@@ -288,7 +299,7 @@ def test_counts_at_the_spans_sum_to_the_engines_counters(runs, path):
             n = by_request[s[5]["request_id"]].prompt.size
             lengths = list(range(8, n + 1, 8)) + ([n] if n % 8 else [])
             assert (s[5]["probes"], s[5]["hashed_tokens"]) == (len(lengths), n)
-    assert all(s[5]["walked_tokens"] % 8 == 0 and s[5]["walked_tokens"] > 0 for s in grows)
+    assert all(s[5]["walked_tokens"] % eng.page_size == 0 and s[5]["walked_tokens"] > 0 for s in grows)
     assert last["pages_in_use"] + last["pages_free"] > 0
     reaps = run.named("serving/reap")
     assert all(s[5] == {"reaped": 0, "shed": 0, "preempted": 0} for s in reaps)

@@ -401,8 +401,6 @@ REFUSED = {
     "prefix_cache": dict(prefix_cache=True),
     "kv_tiers": dict(kv_tiers=TierConfig(host_bytes=1 << 20)),
     "preemption": dict(scheduler=SchedulerConfig(preemption=True)),
-    "spec_draft_len": dict(spec_draft_len=2),
-    "steps_per_call": dict(steps_per_call=4),
 }
 
 

@@ -107,14 +107,13 @@ class TestNoEagerHeavyImports:
 
     def test_paged_kv_bookkeeping_stays_light(self):
         """The paged-arena host layer (free list, refcounts, prefix-cache
-        hashing, n-gram drafter) is what a router/scheduler tier imports to
+        hashing) is what a router/scheduler tier imports to
         reason about page budgets — numpy-only, never jax/flax."""
         _probe(
             "import sys\n"
             "import accelerate_tpu.serving.pages as pages\n"
             "alloc = pages.PageAllocator(8)\n"
             "cache = pages.PrefixCache(alloc, page_size=4)\n"
-            "pages.NGramDrafter()\n"
             "# the quantized-arena capacity helpers are part of the same\n"
             "# jax-free contract: a router sizes int8/int4 KV budgets with\n"
             "# these on accelerator-less machines\n"

@@ -249,8 +249,6 @@ REFUSALS = {
     "prefix_cache": dict(prefix_cache=True),
     "kv_tiers": dict(kv_tiers=object()),
     "preemption by page-out": dict(scheduler=SchedulerConfig(preemption=True)),
-    "speculative verify": dict(spec_draft_len=2),
-    "fused decode bursts": dict(steps_per_call=4),
     "quantized pages": dict(kv_cache_dtype="int8"),
 }
 
@@ -270,8 +268,7 @@ def _state_model(attention_behind: bool = False):
 @pytest.mark.parametrize("feature", sorted(REFUSALS))
 def test_what_cannot_be_right_for_layer_kinds_refuses_by_name(feature, kind):
     """... and for a recurrent state a slot: a cached prefix would need the
-    state's snapshot at its page boundary, a page-out its copy, a rejected
-    draft its rollback."""
+    state's snapshot at its page boundary, a page-out its copy."""
     if kind == "state":
         model, params = _state_model()
         assert model.config.cache_kind == "state" and not model.config.layer_kinds

@@ -419,8 +419,6 @@ REFUSALS = {
     "prefix_cache": dict(prefix_cache=True),
     "kv_tiers": dict(kv_tiers=object()),
     "preemption by page-out": dict(scheduler=SchedulerConfig(preemption=True)),
-    "speculative verify": dict(spec_draft_len=2),
-    "fused decode bursts": dict(steps_per_call=4),
     "quantized pages": dict(kv_cache_dtype="int8"),
 }
 
@@ -437,9 +435,9 @@ def _latent_only_model():
 @pytest.mark.parametrize("feature", sorted(REFUSALS))
 def test_what_cannot_be_right_for_a_latent_cache_refuses_by_name(feature, model_kind):
     """A latent kind is served on the normal path only, as every model by
-    kind: the prefix cache (page sharing of latent pages waits for a prefix
-    index that does not scan, ROADMAP R5), tiers, page-out, speculative
-    verify, bursts and quantized pages refuse by the feature's name."""
+    kind: the prefix cache (page sharing of latent pages waits for sharing
+    behind ``CacheKind`` and S3's page references, ROADMAP R5), tiers,
+    page-out and quantized pages refuse by the feature's name."""
     if model_kind == "latent_attention_alone":
         model, params = _latent_only_model()
         assert model.config.cache_kind == "latent" and not model.config.layer_kinds

@@ -1,4 +1,4 @@
-"""Paged KV arena, copy-on-write prefix cache, speculative decoding
+"""Paged KV arena and copy-on-write prefix cache
 (accelerate_tpu/serving/pages.py + the paged ServingEngine mode).
 
 The contracts of record:
@@ -9,12 +9,8 @@ The contracts of record:
   yields bit-identical tokens; a slot mutating a shared page forks it
   (copy-on-write) without perturbing any other slot or the cached copy;
 - page free-list accounting survives admit/evict churn with no leak;
-- speculative decoding is token-exact vs. sequential generate() for
-  greedy AND sampled chains, at both edges (all drafts rejected / all
-  accepted);
 - a warmed paged engine triggers ZERO compiles across admissions, prefix
-  hits, page forks and verify steps (the jax.monitoring counters are the
-  witness).
+  hits and page forks (the jax.monitoring counters are the witness).
 """
 
 import numpy as np
@@ -70,27 +66,6 @@ def _engine(model, params, **kw):
     kw.setdefault("prefill_chunks", (4, 8))
     kw.setdefault("page_size", PS)
     return ServingEngine(model, params, **kw)
-
-
-class OracleDrafter:
-    """Drafts the TRUE continuation (from precomputed reference streams):
-    the all-accepted edge. ``offset`` shifts every draft to a wrong token:
-    the all-rejected edge."""
-
-    def __init__(self, refs, vocab_size, offset=0):
-        self.refs = [np.asarray(r, np.int64) for r in refs]
-        self.vocab = vocab_size
-        self.offset = offset
-
-    def propose(self, context, k):
-        context = np.asarray(context, np.int64)
-        out = np.full((k,), int(context[-1]), np.int32)
-        for ref in self.refs:
-            if context.size <= ref.size and np.array_equal(ref[: context.size], context):
-                cont = ref[context.size : context.size + k]
-                out[: cont.size] = cont
-                break
-        return ((out + self.offset) % self.vocab).astype(np.int32)
 
 
 class TestPagedParity:
@@ -271,75 +246,13 @@ class TestFreeList:
         assert engine._allocator.in_use <= engine.num_pages - 1
 
 
-class TestSpeculative:
-    def test_all_accepted_edge_greedy(self, served_model):
-        """Oracle drafter: every draft verifies, max_new lands in one
-        verify round after prefill, tokens exactly the sequential ref."""
-        model, cfg, params, prompts = served_model
-        p = prompts[1]
-        ref = _refs(model, params, [p], 5)[0][: p.size + 5]
-        engine = _engine(
-            model, params, num_slots=1, spec_draft_len=4,
-            drafter=OracleDrafter([_refs(model, params, [p], 6)[0]], cfg.vocab_size),
-        )
-        req = engine.submit(p, max_new_tokens=5, seed=1)
-        engine.run()
-        np.testing.assert_array_equal(req.result(), ref)
-        assert req.spec_accepted == req.spec_proposed == 4
-        assert engine.metrics()["serving/spec_accept_rate"] == 1.0
-        assert engine.step_count == 1  # ONE verify call delivered 5 tokens
-
-    def test_all_rejected_edge_greedy(self, served_model):
-        """Adversarial drafter (every draft off by one): zero accepts,
-        one token per verify call, output still exactly the ref."""
-        model, cfg, params, prompts = served_model
-        p = prompts[1]
-        ref = _refs(model, params, [p], 5)[0]
-        engine = _engine(
-            model, params, num_slots=1, spec_draft_len=3,
-            drafter=OracleDrafter(
-                [_refs(model, params, [p], 6)[0]], cfg.vocab_size, offset=1
-            ),
-        )
-        req = engine.submit(p, max_new_tokens=5, seed=1)
-        engine.run()
-        np.testing.assert_array_equal(req.result(), ref)
-        assert req.spec_accepted == 0 and req.spec_proposed > 0
-        assert engine.metrics()["serving/spec_accept_rate"] == 0.0
-
-    def test_ngram_drafter_greedy_and_sampled_exact(self, served_model):
-        """The default n-gram drafter at any accept rate never changes
-        tokens — greedy and sampled chains both match sequential refs."""
-        model, cfg, params, prompts = served_model
-        for temperature, top_k in ((0.0, None), (1.0, 8)):
-            refs = _refs(model, params, prompts, 6, temperature=temperature,
-                         top_k=top_k)
-            engine = _engine(
-                model, params, num_slots=2, spec_draft_len=3,
-                temperature=temperature, top_k=top_k,
-            )
-            outs = engine.generate_batched(prompts, max_new_tokens=6)
-            for out, ref in zip(outs, refs):
-                np.testing.assert_array_equal(out, ref)
-
-    def test_spec_headroom_capacity_guard(self, served_model):
-        model, cfg, params, prompts = served_model
-        engine = _engine(model, params, num_slots=1, max_cache_len=32,
-                         prefill_chunks=(8,), spec_draft_len=4)
-        with pytest.raises(ValueError, match="spec headroom"):
-            engine.submit(np.zeros(20, np.int32), max_new_tokens=9)
-        engine.submit(np.zeros(20, np.int32), max_new_tokens=8)
-
-
 class TestPagedRecompileInvariant:
-    def test_zero_compiles_across_hits_forks_and_verify(self, served_model):
-        """After warmup(), admissions at fresh lengths, prefix hits, COW
-        forks and speculative verify steps are ALL pure data changes: the
-        compile counters must not move."""
+    def test_zero_compiles_across_hits_and_forks(self, served_model):
+        """After warmup(), admissions at fresh lengths, prefix hits and COW
+        forks are ALL pure data changes: the compile counters must not
+        move."""
         model, cfg, params, prompts = served_model
-        engine = _engine(
-            model, params, num_slots=3, spec_draft_len=3, steps_per_call=1
-        )
+        engine = _engine(model, params, num_slots=3)
         # steady IMMEDIATELY after warmup: the invariant is deterministic,
         # not a function of what warm traffic happened to absorb first
         engine.warmup()
@@ -363,7 +276,7 @@ class TestPagedRecompileInvariant:
 class TestPagedTelemetry:
     def test_gauges_records_and_exposition(self, served_model, tmp_path):
         """The new gauges ride the session rollup and the Prometheus
-        exposition; request records carry the paged/spec attribution
+        exposition; request records carry the paged attribution
         fields and the trace CLI aggregates them."""
         import json as json_mod
 
@@ -376,21 +289,19 @@ class TestPagedTelemetry:
             trace_dir=str(tmp_path), watchdog=False, flight_hooks=False,
         ))
         try:
-            engine = _engine(model, params, num_slots=2, spec_draft_len=3,
-                             telemetry=session)
+            engine = _engine(model, params, num_slots=2, telemetry=session)
             p = prompts[2]
             for seed in (0, 1):
                 engine.submit(p, max_new_tokens=3, seed=seed)
             engine.run()
             rollup = session.rollup()
             for key in ("serving/prefix_hit_ratio", "serving/pages_in_use",
-                        "serving/spec_accept_rate", "serving/page_forks"):
+                        "serving/page_forks"):
                 assert key in rollup, key
             assert rollup["serving/prefix_hit_ratio"] == 0.5
             text = prometheus_text(session)
             for name in ("att_serving_prefix_hit_ratio",
-                         "att_serving_pages_in_use",
-                         "att_serving_spec_accept_rate"):
+                         "att_serving_pages_in_use"):
                 assert name in text, name
 
             recs = [json_mod.loads(l)
@@ -400,11 +311,9 @@ class TestPagedTelemetry:
             assert by_hit[0]["prefix_hit"] == 0 and by_hit[1]["prefix_hit"] == 8
             for rec in recs:
                 assert rec["pages_allocated"] >= 1
-                assert rec["spec_proposed"] >= rec["spec_accepted"] >= 0
             agg = summarize_requests(load_requests(str(tmp_path)))
             assert agg["prefix_hit_requests"] == 1
             assert agg["prefix_hit_ratio"] == 0.5
-            assert "spec_accept_rate" in agg
             assert agg["pages_allocated"] >= 2
         finally:
             session.close()
@@ -415,8 +324,7 @@ class TestPagedDecodeKernelServing:
     engine (interpret mode on CPU; the compiled TPU path differs only by
     the `interpret` flag). Contracts: serving output stays token-exact vs
     sequential generate() with the kernel ON (both sides kernelized:
-    sequential decode rides the dense-arena kernel at block = page_size,
-    so the two walks are structurally bit-identical), the post-steady
+    sequential decode rides the dense-arena kernel), the post-steady
     recompile count stays 0, and the kernel shows up as its own dynamic
     roofline row in the CostRegistry/rollup."""
 
@@ -425,9 +333,7 @@ class TestPagedDecodeKernelServing:
         import dataclasses
 
         model, cfg, params, prompts = served_model
-        kcfg = dataclasses.replace(
-            cfg, decode_kernel="interpret", decode_kernel_block=PS
-        )
+        kcfg = dataclasses.replace(cfg, decode_kernel="interpret")
         return model.clone(config=kcfg), kcfg, params, prompts
 
     def _kengine(self, model, params, **kw):
@@ -467,24 +373,6 @@ class TestPagedDecodeKernelServing:
         outs = engine.generate_batched(prompts, max_new_tokens=6)
         for out, ref in zip(outs, refs):
             np.testing.assert_array_equal(out, ref)
-
-    def test_spec_verify_rides_multi_query_kernel(self, kernel_model):
-        """Speculative verify (Sq = K+1 through the same kernel) stays
-        token-exact with drafts accepted and rejected."""
-        model, cfg, params, prompts = kernel_model
-        p = prompts[1]
-        ref = self._krefs(model, params, [p], 5)[0]
-        engine = self._kengine(
-            model, params, num_slots=1, spec_draft_len=3,
-            drafter=OracleDrafter(
-                [self._krefs(model, params, [p], 6)[0]], cfg.vocab_size
-            ),
-        )
-        req = engine.submit(p, max_new_tokens=5, seed=0)
-        engine.run()
-        np.testing.assert_array_equal(req.result(), ref)
-        assert req.spec_accepted > 0
-
 
     def test_free_and_mid_admission_slots_are_skipped_whole(self, kernel_model):
         """Decode steps run while one slot is free and another is in the
@@ -532,11 +420,11 @@ class TestPagedDecodeKernelServing:
 class TestPagedBurstIntegration:
     def test_long_mixed_burst_exact_and_leak_free(self, served_model):
         """The long haul: dozens of requests through few slots with a mix
-        of prefix hits, forks, spec verify, eos finishes and staggered
+        of prefix hits, forks, eos finishes and staggered
         lengths — every output token-exact, zero recompiles post-warmup,
         and page accounting clean at the end."""
         model, cfg, params, prompts = served_model
-        engine = _engine(model, params, num_slots=3, spec_draft_len=3,
+        engine = _engine(model, params, num_slots=3,
                          temperature=1.0, top_k=8)
         engine.warmup()
         engine.generate_batched(prompts[:2], max_new_tokens=4)

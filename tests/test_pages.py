@@ -2,9 +2,8 @@
 
 Pure-python/numpy contracts — no jax, no device: the refcounted free
 list never leaks or double-frees, prefix-cache keying finds the longest
-cached page-aligned prefix (and the partial-tail entry) by content, LRU
-eviction releases page references, and the n-gram drafter proposes the
-continuation of the most recent matching n-gram. The engine-level twins
+cached page-aligned prefix (and the partial-tail entry) by content, and
+LRU eviction releases page references. The engine-level twins
 (real arenas, real decode) live in tests/test_paged_serving.py.
 """
 
@@ -12,7 +11,6 @@ import numpy as np
 import pytest
 
 from accelerate_tpu.serving.pages import (
-    NGramDrafter,
     PageAllocator,
     PagedTables,
     PrefixCache,
@@ -429,32 +427,6 @@ def test_streamed_digests_are_the_keys_peers_exchange(page_size):
     assert digests.extend(aligned + partial) == (0, 0)
     for length in aligned + partial:
         assert digests.keys[length] == _digest(prompt[:length]) == _digest(prompt.astype(np.int32)[:length])
-
-
-class TestNGramDrafter:
-    def test_repetition_is_predicted(self):
-        d = NGramDrafter(order=2)
-        ctx = np.array([7, 8, 9, 7, 8], np.int32)
-        np.testing.assert_array_equal(d.propose(ctx, 3), [9, 7, 8])
-
-    def test_prefers_most_recent_match(self):
-        d = NGramDrafter(order=1)
-        ctx = np.array([5, 1, 5, 2, 5], np.int32)
-        assert d.propose(ctx, 1)[0] == 2  # continuation of the LAST earlier 5
-
-    def test_no_match_pads_with_last_token(self):
-        d = NGramDrafter(order=3)
-        ctx = np.array([1, 2, 3, 4], np.int32)
-        np.testing.assert_array_equal(d.propose(ctx, 2), [4, 4])
-
-    def test_short_context(self):
-        d = NGramDrafter()
-        np.testing.assert_array_equal(d.propose(np.array([3], np.int32), 2), [3, 3])
-
-    def test_fixed_length_output(self):
-        d = NGramDrafter(order=2)
-        ctx = np.array([1, 2, 1, 2], np.int32)
-        assert d.propose(ctx, 5).shape == (5,)
 
 
 class TestPagedTables:

@@ -514,7 +514,7 @@ class TestKillDrillThreeProcesses:
             ref_engine = build_replica_engine(argparse.Namespace(
                 config="tiny", max_seq_len=64, init_seed=0, num_slots=2,
                 max_cache_len=None, prefill_chunks="4,8", page_size=4,
-                temperature=0.0, top_k=None, steps_per_call=1,
+                temperature=0.0, top_k=None,
                 kv_cache_dtype=None, name=None,
             ))
             rng = np.random.RandomState(0)

@@ -86,19 +86,6 @@ class TestBatchedParity:
         for out, ref in zip(outs, refs):
             np.testing.assert_array_equal(out, ref)
 
-    def test_fused_burst_matches_single_steps(self, served_model):
-        """steps_per_call>1 runs the SAME step body under lax.scan —
-        bit-identical tokens, fewer host round trips."""
-        model, cfg, params, prompts = served_model
-        refs = _refs(model, params, prompts, 6, temperature=1.0, top_k=8)
-        engine = ServingEngine(
-            model, params, num_slots=2, max_cache_len=64, prefill_chunks=(4, 8),
-            temperature=1.0, top_k=8, steps_per_call=4,
-        )
-        outs = engine.generate_batched(prompts, max_new_tokens=6)
-        for out, ref in zip(outs, refs):
-            np.testing.assert_array_equal(out, ref)
-
     def test_prompt_over_several_packed_dispatches_matches_generate(self, served_model):
         """A prompt longer than the largest grid capacity, admitted over
         several packed dispatches (the last one padded), yields the same
@@ -195,13 +182,28 @@ class TestSlotLifecycle:
         assert req.done and req.tokens[-1] == eos and len(req.tokens) == 3
         assert len(engine._free) == 1
 
-    def test_capacity_guard(self, served_model):
+    @pytest.mark.parametrize("new_tokens,fits", [(12, True), (13, False)])
+    def test_capacity_guard(self, served_model, new_tokens, fits):
+        """A slot holds its prompt and every token it may generate, to the
+        last position and no further (no head-room is kept beyond them)."""
         model, cfg, params, prompts = served_model
         engine = ServingEngine(
             model, params, num_slots=1, max_cache_len=32, prefill_chunks=(8,)
         )
-        with pytest.raises(ValueError, match="capacity"):
-            engine.submit(np.zeros(30, np.int32), max_new_tokens=10)
+        if fits:
+            req = engine.submit(np.zeros(20, np.int32), max_new_tokens=new_tokens)
+            engine.run()
+            assert req.outcome == "finished" and len(req.tokens) == new_tokens
+        else:
+            with pytest.raises(ValueError, match="capacity"):
+                engine.submit(np.zeros(20, np.int32), max_new_tokens=new_tokens)
+
+    @pytest.mark.parametrize("option", [dict(steps_per_call=2), dict(spec_draft_len=2), dict(drafter=object())],
+                             ids=lambda o: next(iter(o)))
+    def test_there_is_one_decode_program_and_no_option_for_another(self, served_model, option):
+        model, cfg, params, prompts = served_model
+        with pytest.raises(TypeError, match=next(iter(option))):
+            ServingEngine(model, params, num_slots=1, max_cache_len=32, **option)
 
 
 class TestRecompileInvariant:
@@ -212,11 +214,10 @@ class TestRecompileInvariant:
         model, cfg, params, prompts = served_model
         engine = ServingEngine(
             model, params, num_slots=3, max_cache_len=64, prefill_chunks=(4, 8),
-            steps_per_call=4,
         )
         engine.warmup()
-        # one traffic wave through every code path (admission, burst,
-        # eviction, slot reuse), then freeze the program set
+        # one traffic wave through every code path (admission, eviction,
+        # slot reuse), then freeze the program set
         engine.generate_batched(prompts[:3], max_new_tokens=6)
         engine.mark_steady()
         rng = np.random.RandomState(3)
@@ -426,6 +427,31 @@ class TestTelemetryIntegration:
             assert rollup["serving/slot_occupancy"] == 0.0
             # decode steps also fed the rolling window like engine steps do
             assert rollup["sys/window_steps"] >= 1
+        finally:
+            session.close()
+
+
+    def test_each_dispatch_bills_its_program_one_call(self, served_model, tmp_path):
+        """The cost registry's rows (the roofline table's walls): the decode
+        step is billed one call and its wall a step read, each packed
+        prefill capacity one a dispatch."""
+        from accelerate_tpu.telemetry import TelemetryConfig, TelemetrySession
+
+        model, cfg, params, prompts = served_model
+        session = TelemetrySession(TelemetryConfig(trace_dir=str(tmp_path), watchdog=False))
+        try:
+            engine = ServingEngine(
+                model, params, num_slots=2, max_cache_len=64, prefill_chunks=(4, 8), telemetry=session,
+            )
+            reqs = [engine.submit(p, max_new_tokens=5, seed=i) for i, p in enumerate(prompts)]
+            engine.run()
+            rows = session.costs.entries
+            assert rows["decode_step"]["calls"] == engine.step_count > 0
+            assert rows["decode_step"]["wall_s"] == pytest.approx(sum(w for w, _ in engine._step_samples))
+            packs = sum(row["calls"] for name, row in rows.items() if name.startswith("ragged_prefill_"))
+            assert packs >= len(prompts) and set(rows) <= {"decode_step", "ragged_prefill_4", "ragged_prefill_8"}
+            # every request's tokens but its first came from a decode step
+            assert sum(n for _, n in engine._step_samples) == sum(len(r.tokens) - 1 for r in reqs)
         finally:
             session.close()
 

@@ -244,7 +244,7 @@ CASES = {
     "flash_d64_kv_mask_fwd_bwd": (_flash, dict(b=8, h=12, kvh=12, s=512, d=64, mask="kv_mask")),
     "flash_d64_segment_ids_fwd_bwd": (_flash, dict(b=8, h=12, kvh=12, s=512, d=64, mask="segments")),
     "flash_32k_context_fwd": (_flash, dict(b=1, h=8, kvh=8, s=32768, d=128, grad=False)),
-    # paged decode, query width 1 = decode, 5 = verify; the serving cells'
+    # paged decode, query width 1 = decode, 5 = several rows a slot (ROADMAP R12); the serving cells'
     # own shape (benchmarks/configs/mistral-7b-v0.3-serve-16l.json)
     "paged_decode_bf16_d128_sq1": (_paged_decode, dict(sq=1)),
     "paged_decode_bf16_d128_sq5": (_paged_decode, dict(sq=5)),
