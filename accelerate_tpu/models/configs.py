@@ -156,6 +156,10 @@ class DecoderConfig:
     moe_topk_group: int = 1
     moe_routed_scale: float = 1.0
     moe_shared_experts: int = 0
+    # ``moe_shared_gate``: the shared expert's result is multiplied by a gate
+    # of its own, ``sigmoid(x w)`` with ``w`` [embed_dim], one scalar a token,
+    # before it is added (computed once, like the shared expert itself).
+    moe_shared_gate: bool = False
     # ``moe_latent_dim``: the routed experts take and return this width
     # (LatentMoE): one projection ``embed_dim -> moe_latent_dim`` before the
     # dispatch and one back after the combine, each computed once whatever
@@ -203,6 +207,14 @@ class DecoderConfig:
     attn_window: Optional[int] = None
     attn_sink: bool = False
     attn_value_scale: float = 1.0
+    # ``attn_qk_norm``: an RMS norm over each query head and each key head
+    # (one learned scale of ``head_dim`` for the queries, one for the keys, by
+    # ``norm_unit_offset`` like every norm) before the rotation.
+    # ``attn_output_gate``: the query projection is doubled, and the second
+    # half, through a sigmoid, multiplies the attention's result element by
+    # element before the output projection.
+    attn_qk_norm: bool = False
+    attn_output_gate: bool = False
     # ``residual_dtype``: the dtype the residual stream is carried and added
     # in between the layers (None: ``dtype``, as every model before). With
     # experts, float32: the top-k choice is discrete, and bfloat16's rounding
@@ -241,7 +253,14 @@ class DecoderConfig:
     # ``heads x head_dim x ssm_state_dim`` float32 a slot a layer (the
     # ``ssd_scan`` kernel; cache kind "state" as well). "none": the layer has
     # no mixer and is its feed-forward part alone, with the one norm before
-    # it, and keeps nothing in the cache.
+    # it, and keeps nothing in the cache. "gdn" is Gated DeltaNet
+    # (models/ssm.GatedDeltaNet): ``ssm_num_heads`` value heads of
+    # ``ssm_head_dim``, ``ssm_n_groups`` key heads of ``ssm_state_dim`` (value
+    # head ``h`` reads key head ``h // (heads / groups)``), a convolution over
+    # queries, keys and values together, a decay and a write strength of one
+    # scalar a value head a token, l2-normed queries and keys, and a state
+    # ``heads x ssm_state_dim x ssm_head_dim`` float32 a slot a layer that the
+    # delta rule corrects (the ``gdn_scan`` kernel; cache kind "state").
     mixer: str = "attention"
     ssm_num_heads: Optional[int] = None
     ssm_head_dim: Optional[int] = None
@@ -367,17 +386,17 @@ class DecoderConfig:
             raise ValueError(
                 f"rope_dim must be even and at most head_dim {self.head_dim}, "
                 f"got {self.rope_dim}")
-        if self.mixer not in ("attention", "ssm", "ssd", "none"):
-            raise ValueError(f"mixer must be 'attention', 'ssm', 'ssd' or 'none', got {self.mixer!r}")
+        if self.mixer not in ("attention", "ssm", "ssd", "gdn", "none"):
+            raise ValueError(f"mixer must be 'attention', 'ssm', 'ssd', 'gdn' or 'none', got {self.mixer!r}")
         if self.mlp_kind not in ("swiglu", "relu2", "none"):
             raise ValueError(f"mlp_kind must be 'swiglu', 'relu2' or 'none', got {self.mlp_kind!r}")
         if self.mixer == "none" and self.mlp_kind == "none":
             raise ValueError("a layer has a mixer, a feed-forward part or both")
-        if self.mixer == "ssd":
+        if self._with_heads:
             heads, groups = self.ssm_num_heads, self.ssm_n_groups
             if not heads or not self.ssm_head_dim or groups < 1 or heads % groups:
                 raise ValueError(
-                    "an 'ssd' mixer needs ssm_num_heads and ssm_head_dim >= 1 and ssm_n_groups "
+                    f"a {self.mixer!r} mixer needs ssm_num_heads and ssm_head_dim >= 1 and ssm_n_groups "
                     f"that divides the heads; got {heads}, {self.ssm_head_dim}, {groups}")
         if self.moe_latent_dim is not None and self.moe_latent_dim < 1:
             raise ValueError(f"moe_latent_dim must be >= 1, got {self.moe_latent_dim}")
@@ -385,7 +404,7 @@ class DecoderConfig:
             raise ValueError(
                 "ssm_kernel must be None, 'scan', 'reference' or 'interpret', "
                 f"got {self.ssm_kernel!r}")
-        if self.mixer == "ssd" and (self.ssm_state_dim < 1 or self.ssm_conv_width < 2):
+        if self._with_heads and (self.ssm_state_dim < 1 or self.ssm_conv_width < 2):
             raise ValueError("a state-space mixer needs ssm_state_dim >= 1 and ssm_conv_width >= 2")
         if self.mixer == "ssm" and min(
                 self.ssm_state_dim, self.ssm_conv_width - 1, self.ssm_expand, self.ssm_rank) < 1:
@@ -401,7 +420,8 @@ class DecoderConfig:
                     "latent attention needs kv_lora_rank >= 1 and head_dim = qk_nope_head_dim + "
                     f"qk_rope_head_dim (even); got {self.kv_lora_rank}, {self.head_dim} = {n} + {p}")
             if (self.attn_window is not None or self.attn_sink or self.attn_value_scale != 1.0
-                    or self.eva_window is not None or self.mixer != "attention"):
+                    or self.eva_window is not None or self.mixer != "attention"
+                    or self.attn_qk_norm or self.attn_output_gate):
                 raise ValueError("latent attention takes no window, sink, value scale or closing window")
             if self.kv_cache_dtype != "bf16":
                 raise NotImplementedError(
@@ -411,6 +431,10 @@ class DecoderConfig:
                 raise NotImplementedError("latent attention has no fp8 recipe")
         elif self.q_lora_rank is not None or self.rope_yarn is not None:
             raise ValueError("q_lora_rank and rope_yarn are latent attention's (kv_lora_rank)")
+        if self.attn_output_gate and self.value_dim != self.head_dim:
+            raise ValueError("attn_output_gate doubles the query projection: it needs v_head_dim == head_dim")
+        if self.moe_shared_gate and not self.shared_mlp_dim:
+            raise ValueError("moe_shared_gate gates a shared expert (moe_shared_experts or moe_shared_dim)")
         if self.rope_yarn is not None:
             self.rope_yarn = tuple(float(x) for x in self.rope_yarn)
             if len(self.rope_yarn) != 6:
@@ -437,6 +461,8 @@ class DecoderConfig:
                     "is a chunk, and a window's summaries fill whole pages")
             if self.attn_window is not None or self.attn_sink or self.mixer != "attention":
                 raise ValueError("EVA attention takes no sliding window and no sink")
+            if self.attn_qk_norm or self.attn_output_gate:
+                raise ValueError("EVA attention takes no query and key norms and no output gate")
             if self.kv_page_size is not None and self.kv_page_size != c:
                 raise ValueError(
                     f"EVA attention pools a filled page into one entry: kv_page_size "
@@ -525,8 +551,14 @@ class DecoderConfig:
         return scale
 
     @property
+    def _with_heads(self) -> bool:
+        """The mixers whose state is by head (``ssm_num_heads`` of
+        ``ssm_head_dim``, ``ssm_n_groups`` groups or key heads of ``ssm_state_dim``)."""
+        return self.mixer in ("ssd", "gdn")
+
+    @property
     def ssm_inner_dim(self) -> int:
-        if self.mixer == "ssd":
+        if self._with_heads:
             return self.ssm_num_heads * self.ssm_head_dim
         return self.ssm_expand * self.embed_dim
 
@@ -534,23 +566,25 @@ class DecoderConfig:
     def ssm_conv_dim(self) -> int:
         """The channels the convolution runs over, of which a slot keeps the
         last ``ssm_conv_width - 1`` inputs: the inner width, and with heads
-        (``ssd``) every group's B and C beside it."""
-        extra = 2 * self.ssm_n_groups * self.ssm_state_dim if self.mixer == "ssd" else 0
+        every group's B and C (``ssd``) or every key head's query and key
+        (``gdn``) beside it."""
+        extra = 2 * self.ssm_n_groups * self.ssm_state_dim if self._with_heads else 0
         return self.ssm_inner_dim + extra
 
     @property
     def has_state(self) -> bool:
-        """A state-space mixer of either form: a state a slot, not pages."""
-        return self.mixer in ("ssm", "ssd")
+        """A mixer with a recurrent state (either state-space form, or the
+        delta rule's): a state a slot, not pages."""
+        return self.mixer in ("ssm", "ssd", "gdn")
 
     @property
     def state_slot_bytes(self) -> int:
         """Bytes one slot keeps in one layer of this (one-kind) config: the
         float32 state and the convolution's last inputs (in ``dtype``; float32
-        for the mixer with heads)."""
+        for the mixers with heads)."""
         if not self.has_state:
             return 0
-        conv_itemsize = 4 if self.mixer == "ssd" else jnp.dtype(self.dtype).itemsize
+        conv_itemsize = jnp.dtype(self.dtype).itemsize if self.mixer == "ssm" else 4
         return (self.ssm_inner_dim * self.ssm_state_dim * 4
                 + (self.ssm_conv_width - 1) * self.ssm_conv_dim * conv_itemsize)
 
@@ -590,6 +624,13 @@ class DecoderConfig:
             d, c = self.ssm_inner_dim, self.ssm_conv_dim
             attn = e * (d + c + self.ssm_num_heads) + d * e + self.ssm_conv_width * c \
                 + (c if self.ssm_conv_bias else 0) + 3 * self.ssm_num_heads + d
+        elif self.mixer == "gdn":
+            # the projections to q, k, v, z and to the two scalars a head, the
+            # convolution, a decay and a step's bias a head, the gated norm's
+            # weight of one head's width, the output projection
+            d, c, hv = self.ssm_inner_dim, self.ssm_conv_dim, self.ssm_num_heads
+            attn = e * (c + d + 2 * hv) + self.ssm_conv_width * c + (c if self.ssm_conv_bias else 0) \
+                + 2 * hv + self.ssm_head_dim + d * e
         elif self.mixer == "ssm":
             # in and out projections, the convolution, the step's, B's and
             # C's projection with their norms, the step's expansion and
@@ -605,8 +646,10 @@ class DecoderConfig:
             attn = q + e * (r + p) + r + r * h * (self.qk_nope_head_dim + self.value_dim) \
                 + h * self.value_dim * e
         else:
-            attn = e * h * self.head_dim + e * kv * (self.head_dim + self.value_dim) \
+            attn = e * h * self.head_dim * (2 if self.attn_output_gate else 1) \
+                + e * kv * (self.head_dim + self.value_dim) \
                 + h * self.value_dim * e + (h if self.attn_sink else 0) \
+                + (2 * self.head_dim if self.attn_qk_norm else 0) \
                 + (2 * kv * self.head_dim if self.eva_window is not None else 0)
         if self.mlp_kind == "none":
             mlp = 0
@@ -618,7 +661,7 @@ class DecoderConfig:
             lat = self.moe_latent_dim
             mlp = experts * mats * (lat or e) * self.mlp_dim + (2 * e * lat if lat else 0) \
                 + mats * e * self.shared_mlp_dim + e * outputs \
-                + (outputs if self.moe_selection_bias else 0)
+                + (outputs if self.moe_selection_bias else 0) + (e if self.moe_shared_gate else 0)
         else:
             mlp = mats * e * self.mlp_dim
         # + a norm before each part the layer has

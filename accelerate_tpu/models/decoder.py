@@ -56,8 +56,8 @@ def _constrain_stream(x, mesh: Optional[Mesh], exchange: int):
 
 def _rotary_tables(positions, cfg, dtype):
     """(sin, cos) of a config's rotated width, or (None, None) where its
-    layers do not rotate (a state-space mixer; attention with ``rope_dim`` 0)."""
-    if getattr(cfg, "mixer", "attention") == "ssm" or not cfg.rotary_dim:
+    layers do not rotate (a mixer with a recurrent state; attention with ``rope_dim`` 0)."""
+    if getattr(cfg, "has_state", False) or not cfg.rotary_dim:
         return None, None
     if getattr(cfg, "kv_lora_rank", None) is not None:
         # latent attention rotates qk_rope_head_dim dimensions, under YaRN
@@ -322,6 +322,15 @@ class DecoderAttention(nn.Module):
             v = jnp.einsum("bse,ehd->bhsd", x, wv.astype(dt))
         q = _constrain(q, ("batch", "heads", "seq", "head_dim"), self.mesh)
         k = _constrain(k, ("batch", "kv_heads", "seq", "head_dim"), self.mesh)
+        if getattr(cfg, "attn_qk_norm", False):
+            # an RMS norm over each head's width, one scale for the queries and one for the keys, before the rotation
+            q, k = (_norm(t, self.param(name, nn.with_logical_partitioning(_norm_init(cfg), ("norm",)), (d,)), cfg)
+                    for t, name in ((q, "q_norm"), (k, "k_norm")))
+        gate = None
+        if getattr(cfg, "attn_output_gate", False):
+            # the doubled query projection's second half: a sigmoid gate on the attention's result
+            wg = self.param("wg", nn.with_logical_partitioning(_dense_init(), ("embed", "heads", "head_dim")), (e, h, d))
+            gate = jnp.einsum("bse,ehd->bhsd", x, wg.astype(dt))
         if sin is not None:  # None: a kind without rotation (rope_dim 0)
             q = apply_rotary_embedding(q, sin, cos)
             k = apply_rotary_embedding(k, sin, cos)
@@ -659,6 +668,8 @@ class DecoderAttention(nn.Module):
                 impl=cfg.attention_impl,
             )
         out = _constrain(out, ("batch", "heads", "seq", "head_dim"), self.mesh)
+        if gate is not None:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
         if getattr(cfg, "use_fp8", False):
             from ..ops.fp8 import fp8_attn_out
 
@@ -954,11 +965,11 @@ class DecoderBlock(nn.Module):
         # (config.residual_dtype); the layers' inputs are cfg.dtype either way
         y_stream = _norm(x, ln1, cfg)  # in the stream's dtype
         y = y_stream.astype(cfg.dtype)
-        if mixer in ("ssm", "ssd"):
-            from .ssm import Mamba2Mixer, SelectiveSSM
+        if mixer in ("ssm", "ssd", "gdn"):
+            from .ssm import GatedDeltaNet, Mamba2Mixer, SelectiveSSM
 
             # (the mixer with heads reads the normed stream as it is carried)
-            y = (SelectiveSSM if mixer == "ssm" else Mamba2Mixer)(
+            y = {"ssm": SelectiveSSM, "ssd": Mamba2Mixer, "gdn": GatedDeltaNet}[mixer](
                 cfg, self.mesh, self.use_cache, self.decode, name="ssm")(
                 y if mixer == "ssm" else y_stream, cache_positions=cache_positions, ragged_slots=ragged_slots,
                 slot_hist=slot_hist, kv_lengths=kv_lengths, cache_layer=cache_layer)

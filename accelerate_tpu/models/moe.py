@@ -17,8 +17,9 @@ routes by sorting:
   ``moe_topk_group`` best groups only;
 - ``moe_shared_experts`` gated MLPs of the experts' width (one of their
   summed width, or of ``moe_shared_dim``) take every token, and their result
-  is added to the routed one: a program that holds a share of the routed
-  experts computes it whole;
+  is added to the routed one, behind a scalar gate of its own a token
+  (``sigmoid(x w)``) where ``moe_shared_gate`` says so: a program that holds
+  a share of the routed experts computes it whole;
 - with ``moe_latent_dim`` (LatentMoE) the routed experts live in a narrower
   width: one projection down before the dispatch, the experts and the
   combine there, one projection back, both computed once whatever share is
@@ -33,7 +34,8 @@ routes by sorting:
   (``moe_experts_held = (first, count)``; all of them by default) is sorted
   by expert, the tokens' rows are gathered in that order, and the three
   expert matrices multiply them group by group (:func:`grouped_mlp`: a
-  pallas kernel named ``moe_experts`` where the serving kernels run, and
+  pallas kernel named ``moe_experts`` where the serving kernels run, every
+  expert over every row up to 256 rows and over its own row tiles past that, and
   ``jax.lax.ragged_dot`` elsewhere, which differentiates); the rows come
   back weighted and are added to their tokens;
 - shapes are static. The rows multiplied at once are :func:`expert_rows`:
@@ -203,13 +205,36 @@ def _grid_order(sizes):
     return n_live, gmap, starts
 
 
+# Rows up to which every expert multiplies every row (:func:`_experts_kernel_call`). Measured (``chip_smoke.py
+# --phase gdn``; PERF.md section 6, PR 48, call 5; us a call, every row | own row tiles): at 64 experts of 2,048 ->
+# 512 the two change places between 128 and 256 rows (64 rows 280 | 460, 128 rows 373 | 373, 256 rows 555 | 491, 320
+# rows 694 | 520, 640 rows 1,453 | 559); at MiMo's 16 experts of 4,096 -> 2,048 (calls of 64-256 rows) and
+# GigaChat's 8 of 7,168 -> 2,048 (16-128 rows) the own-tiles kernel does not compile at its width tile of 1,024
+# (172-177 MB of the chip's 128 MB of VMEM), so 256 is the least that keeps their calls on the kernel that runs
+# them, and no call of the narrow experts' cell has fewer than 320 rows.
+_ALL_ROWS_MAX = 256
+
+
 def _experts_kernel_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
     """``wg``, ``wu`` [L, E, d, m] and ``wd`` [L, E, m, d] are the layers'
     stacks as the layer scan holds them, ``layer`` which of them to
     multiply by: the kernel fetches its tiles out of the stack, so no
     layer's experts are sliced out (805 MB a layer in the MiMo cell) before
     a call that reads a few of them. One layer's leaves are a stack of one
-    (``w[None]``, layer 0: a bitcast)."""
+    (``w[None]``, layer 0: a bitcast).
+
+    Up to ``_ALL_ROWS_MAX`` rows every expert multiplies every row and keeps
+    its own (:func:`_experts_all_rows_call`): the weights' bytes bound that (6
+    operations a weight byte a row against the chip's 240 a byte). Past it the
+    products would bound it, 64 narrow experts over a step's 320 rows or a
+    pack's 640 (ISSUE 48: half the device's time, my chip run), and the kernel
+    walks each expert's own row tiles instead, as the two-matrix one does."""
+    if xs.shape[0] > _ALL_ROWS_MAX:
+        return _experts2_kernel_call(xs, wu, wd, sizes, layer, interpret, wg=wg)
+    return _experts_all_rows_call(xs, wg, wu, wd, sizes, layer, interpret)
+
+
+def _experts_all_rows_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -242,6 +267,42 @@ def _experts_kernel_call(xs, wg, wu, wd, sizes, layer, interpret: bool):
         _experts_kernel, grid_spec=grid_spec, name="moe_experts", interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32), **params,
     )(n_live.reshape(1), gmap, starts, jnp.asarray(layer, jnp.int32).reshape(1), xs, wg, wu, wd)
+
+
+def _experts_own_tiles_kernel(live_ref, gmap_ref, start_ref, layer_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
+                              *, tm: int):
+    """Grid (experts, tiles of the expert width), gated experts, many rows.
+    Step (g, t) walks the row tiles of ``tm`` rows that hold rows of expert
+    ``gmap[g]`` (the rows are sorted by expert, so they are consecutive),
+    multiplies each by tile t of that expert of layer ``layer[0]`` and adds the
+    expert's own rows of the product to the output, which stays in VMEM over
+    the whole grid. Experts without a row come last in ``gmap`` as repeats of
+    the last live one, as in :func:`_experts_kernel`."""
+    from jax.experimental import pallas as pl
+
+    g, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((g == 0) & (t == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(g < live_ref[0])
+    def _multiply():
+        e = gmap_ref[g]
+        lo, hi = start_ref[e], start_ref[e + 1]
+        w_gate, w_up, w_down = wg_ref[0, 0], wu_ref[0, 0], wd_ref[0, 0]
+
+        def tile(r, carry):
+            at = pl.ds(pl.multiple_of(r * tm, tm), tm)
+            x = x_ref[at, :]
+            gate = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+            up = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+            out = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype), w_down, preferred_element_type=jnp.float32)
+            row = r * tm + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            o_ref[at, :] += jnp.where((row >= lo) & (row < hi), out, 0.0)
+            return carry
+
+        jax.lax.fori_loop(lo // tm, (hi + tm - 1) // tm, tile, 0)
 
 
 def _experts2_kernel(live_ref, gmap_ref, start_ref, layer_ref, x_ref, wu_ref, wd_ref, o_ref, *, tm: int):
@@ -302,9 +363,11 @@ def _experts2_tiles(rows: int, count: int, m: int) -> tuple:
     return tm, tile
 
 
-def _experts2_kernel_call(xs, wu, wd, sizes, layer, interpret: bool):
+def _experts2_kernel_call(xs, wu, wd, sizes, layer, interpret: bool, wg=None):
     """As :func:`_experts_kernel_call` for two-matrix experts: ``wu`` [L, E,
-    d, m], ``wd`` [L, E, m, d], the layers' stacks, read where they are."""
+    d, m], ``wd`` [L, E, m, d], the layers' stacks, read where they are. With
+    ``wg`` the experts are gated and many rows (:func:`_experts_own_tiles_kernel`,
+    under the gated kernel's name): the same grid, row tiles and weight blocks."""
     import functools
 
     from jax.experimental import pallas as pl
@@ -329,17 +392,19 @@ def _experts2_kernel_call(xs, wu, wd, sizes, layer, interpret: bool):
         grid=(count, m // tile),
         in_specs=[
             pl.BlockSpec((padded, d), lambda g, t, *_: (0, 0)),
-            pl.BlockSpec((1, 1, d, tile), w_in),
+            *([pl.BlockSpec((1, 1, d, tile), w_in)] * (1 if wg is None else 2)),
             pl.BlockSpec((1, 1, tile, d), w_out),
         ],
         out_specs=pl.BlockSpec((padded, d), lambda g, t, *_: (0, 0)),
     )
     params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_EXPERT_VMEM)}
+    kernel, name, weights = ((_experts2_kernel, "moe_experts_relu2", (wu, wd)) if wg is None
+                             else (_experts_own_tiles_kernel, "moe_experts", (wg, wu, wd)))
     out = pl.pallas_call(
-        functools.partial(_experts2_kernel, tm=tm), grid_spec=grid_spec, name="moe_experts_relu2",
+        functools.partial(kernel, tm=tm), grid_spec=grid_spec, name=name,
         interpret=interpret, out_shape=jax.ShapeDtypeStruct((padded, d), jnp.float32), **params,
-    )(n_live.reshape(1), gmap, starts, jnp.asarray(layer, jnp.int32).reshape(1), xs, wu, wd)
+    )(n_live.reshape(1), gmap, starts, jnp.asarray(layer, jnp.int32).reshape(1), xs, *weights)
     return out[:rows]
 
 
@@ -523,10 +588,16 @@ class MoeMLP(nn.Module):
                 xs = flat.astype(dt)
                 if gated:
                     hidden = swiglu(xs @ shared[0], xs @ shared[1])
-                    y = y + jnp.matmul(hidden, shared[2], preferred_element_type=jnp.float32)
+                    own = jnp.matmul(hidden, shared[2], preferred_element_type=jnp.float32)
                 else:
                     hidden = jnp.square(jax.nn.relu(two_term_matmul(routed, shared[0])))
-                    y = y + two_term_matmul(hidden, shared[1])
+                    own = two_term_matmul(hidden, shared[1])
+                if cfg.moe_shared_gate:
+                    # a scalar gate of the shared expert's own, a token: sigmoid(x w)
+                    w_sg = self.param("shared_out_gate", nn.with_logical_partitioning(_dense_init(), ("embed", None)),
+                                      (d, 1)).astype(dt)
+                    own = own * jax.nn.sigmoid(jnp.matmul(xs, w_sg, preferred_element_type=jnp.float32))
+                y = y + own
         if gated:
             y = y.astype(dt)  # (two-matrix experts hand the stream their float32 sum)
         y = y.reshape(b, s, d)

@@ -1,7 +1,8 @@
 """The state-space mixers a layer kind may state in attention's place:
 :class:`SelectiveSSM` (``DecoderConfig.mixer == "ssm"``: Mamba-1, with the
-inner norms of the Jamba family) and :class:`Mamba2Mixer` (``"ssd"``: heads,
-further down). Mamba-1:
+inner norms of the Jamba family), :class:`Mamba2Mixer` (``"ssd"``: heads,
+further down) and :class:`GatedDeltaNet` (``"gdn"``: a state the delta rule
+corrects, last). Mamba-1:
 
     [u, z] = h W_in                               (E -> 2 x D, D = ssm_expand x E)
     u'     = silu(conv(u) + b_conv)               causal, depthwise, ssm_conv_width taps
@@ -35,7 +36,8 @@ What a slot keeps, in the "cache" collection beside the paged leaves:
 ``conv_state`` [slots, ssm_conv_width - 1, D], the convolution's last raw
 inputs (``serving/pages.STATE_LEAF_NAMES`` finds them by these names; with
 heads the state is [slots, D / lane, N, lane], ``ops/ssm.ssd_state_shape``,
-and the convolution's channels are D + 2 G N, kept in float32). Where the layer scan carries the collection whole (``cache_layer``),
+and the convolution's channels are D + 2 G N, kept in float32; Gated DeltaNet
+keeps [slots, value heads, dk, dv] and the channels of q, k and v). Where the layer scan carries the collection whole (``cache_layer``),
 both are the layers' stacks with a leading layer axis and are updated in
 place: the kernel takes the stack and the layer, and the convolution's
 inputs are one dynamic slice in and one out.
@@ -51,7 +53,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ..ops.layers import rms_norm, two_term_matmul
-from ..ops.ssm import ssd_scan, ssd_state_shape, ssm_scan
+from ..ops.ssm import gdn_scan, ssd_scan, ssd_state_shape, ssm_scan
 from .configs import DecoderConfig
 
 def _inverse_softplus_log_uniform(lo: float = 1e-3, hi: float = 1e-1):
@@ -318,3 +320,100 @@ class Mamba2Mixer(nn.Module):
         y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
         y = y.reshape(nb, bt, d) * norm_w.astype(f32)
         return two_term_matmul(y, w_out.astype(dt_)).reshape(b, s, e)  # float32, as the stream takes it
+
+
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet (as ``qwen3_next`` has it). ``Hv`` = ``ssm_num_heads``
+    value heads of ``dv`` = ``ssm_head_dim``, ``Hk`` = ``ssm_n_groups`` key
+    heads of ``dk`` = ``ssm_state_dim``; value head ``h`` reads key head ``h //
+    (Hv / Hk)``:
+
+        [q | k | v | z] = h W_in                      (E -> Hk dk + Hk dk + Hv dv + Hv dv)
+        [b | a] = h W_ba                              (E -> Hv + Hv)
+        [q | k | v] = silu(conv([q | k | v]))         causal, depthwise, over all 2 Hk dk + Hv dv channels
+        beta = sigmoid(b),  g = -exp(A_log) * softplus(a + b_dt)      one scalar a value head each, float32
+        q = l2norm(q) * dk^-1/2,  k = l2norm(k)       within each head
+        S <- exp(g_t) S;  r = S^T k_t;  S <- S + k_t (x) (beta_t (v_t - r));  o_t = S^T q_t     a value head
+        o      = RMSNorm(o) * w * silu(z)             within each head of dv; the norm first, then the gate
+        out    = o W_out                              (Hv dv -> E)
+
+    The columns of ``W_in`` and ``W_ba`` are laid out flat, part by part (the
+    published checkpoint interleaves them by key head: the adapter that loads
+    it says how). The three call forms, the blocks and what a slot keeps are
+    :class:`SelectiveSSM`'s (the module's docstring); the recurrence is
+    ``ops/ssm.gdn_scan``, float32, its state ``Hv x dk x dv`` a slot a layer:
+    a pack walks its rows as a decode step walks its one."""
+
+    config: DecoderConfig
+    mesh: Optional[Mesh] = None
+    use_cache: bool = False
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, cache_positions=None, ragged_slots=None, slot_hist=None,
+                 kv_lengths=None, cache_layer=None):
+        cfg = self.config
+        e, d, dk, k = cfg.embed_dim, cfg.ssm_inner_dim, cfg.ssm_state_dim, cfg.ssm_conv_width
+        hv, dv, hk, cd = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_n_groups, cfg.ssm_conv_dim
+        kd = hk * dk
+        dt_, f32 = cfg.dtype, jnp.float32
+        b, s = x.shape[0], x.shape[1]
+        dense = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        part = nn.with_logical_partitioning
+        w_in = self.param("w_in", part(dense, ("embed", "mlp")), (e, cd + d))
+        w_ba = self.param("w_ba", part(dense, ("embed", None)), (e, 2 * hv))
+        conv_w = self.param("conv_w", part(dense, (None, "mlp")), (k, cd))
+        conv_b = self.param("conv_b", part(nn.initializers.zeros, ("mlp",)), (cd,)) if cfg.ssm_conv_bias else None
+        b_dt = self.param("b_dt", part(nn.initializers.ones, (None,)), (hv,), f32)
+        a_log = self.param("a_log", part(_a_log_uniform(1e-3, 16.0), (None,)), (hv,), f32)
+        norm_w = self.param("norm_w", part(nn.initializers.ones, ("norm",)), (dv,))
+        w_out = self.param("w_out", part(dense, ("mlp", "embed")), (d, e))
+
+        # the products' inputs are cfg.dtype, their sums float32; nothing is
+        # rounded between the projections and the recurrence, and the
+        # convolution's kept inputs are float32 like the state
+        mm = lambda v, w: jnp.matmul(v.astype(dt_), w.astype(dt_), preferred_element_type=f32)
+        qkvz, ba = mm(x, w_in), mm(x, w_ba)
+        qkv, z = qkvz[..., :cd], qkvz[..., cd:]
+
+        serving = self.use_cache and self.decode and cache_positions is not None
+        if serving and ragged_slots is None and s != 1:
+            raise NotImplementedError(
+                "a layer with a recurrent state decodes one token a slot: several would need "
+                "the state rolled back on a rejected draft (ROADMAP R12)")
+        state = conv = None
+        if self.use_cache:
+            state = self.variable("cache", "ssm_state", jnp.zeros, (b, hv, dk, dv), f32)
+            conv = self.variable("cache", "conv_state", jnp.zeros, (b, k - 1, cd), f32)
+        stacked = cache_layer is not None
+        read = lambda var: var.value[cache_layer] if stacked else var.value
+
+        nb, bt, slot, rows, fresh, blocks, halo = _blocks_and_halo(
+            cfg, qkv, conv, read, serving, self.use_cache and self.decode,
+            cache_positions, ragged_slots, slot_hist, kv_lengths)
+        ext = jnp.concatenate([halo, blocks], axis=1)                        # [nb, k-1+bt, cd]
+        acc = sum(ext[:, j:j + bt].astype(f32) * conv_w[j].astype(f32) for j in range(k))
+        if conv_b is not None:
+            acc = acc + conv_b.astype(f32)
+        qkv_c = jax.nn.silu(acc)                                             # [nb, bt, cd] float32
+        unit = lambda v: v * jax.lax.rsqrt(jnp.sum(jnp.square(v), axis=-1, keepdims=True) + 1e-6)
+        q = unit(qkv_c[..., :kd].reshape(nb, bt, hk, dk)) * dk ** -0.5
+        k_ = unit(qkv_c[..., kd:2 * kd].reshape(nb, bt, hk, dk))
+        v = qkv_c[..., 2 * kd:].reshape(nb, bt, hv, dv)
+        ba = ba.reshape(nb, bt, 2 * hv)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(ba[..., hv:] + b_dt)
+
+        packed = serving and ragged_slots is not None
+        stack, layer = _stack_and_layer(state, cache_layer, (nb, hv, dk, dv))
+        o, stack = gdn_scan(
+            q, k_, v, g, beta, stack, block_slot=slot, block_rows=rows, block_fresh=fresh, layer=layer,
+            impl=cfg.ssm_kernel if serving else "reference")
+        if state is not None:
+            state.value = stack if stacked else stack[0]
+            _keep_conv_tail(conv, read, ext, rows, slot, packed, cache_layer)
+
+        # the norm within each head first, then the gate
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+        o = o * norm_w.astype(f32) * jax.nn.silu(z.reshape(nb, bt, hv, dv))
+        return mm(o.reshape(nb, bt, d), w_out).reshape(b, s, e)  # float32, as the stream takes it

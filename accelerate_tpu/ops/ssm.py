@@ -438,3 +438,257 @@ def ssd_scan(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows, block_fre
                                   block_rows=block_rows, block_fresh=block_fresh, layer=layer)
     return _ssd_scan_call(u, dt, b, c, a, d_skip, state, block_slot, block_rows, block_fresh, layer,
                           mode == "interpret")
+
+
+# -- the gated delta rule (Gated DeltaNet) -------------------------------------
+#
+# One token advances a state ``S`` in R^(Hv x dk x dv) (``Hv`` value heads; a
+# key head serves ``Hv / Hk`` of them; ``dk``, ``dv`` the keys' and the values'
+# head widths), a value head:
+#
+#     S <- exp(g_t) S;  r = S^T k_t;  S <- S + k_t (x) (beta_t (v_t - r));  o_t = S^T q_t
+#
+# ``g_t`` (negative) and ``beta_t`` are one scalar a value head a token; ``q``
+# and ``k`` reach the recurrence normed and scaled. Unlike both recurrences
+# above the step is not a decay and an add: ``r`` reads the whole state before
+# the write. The state is laid out ``[layers, slots, Hv, dk, dv]``, the values'
+# width on lanes and the keys' down the sublanes, so that the read and the
+# output are sums over sublanes and the write a product with ``k`` down the
+# sublanes, the same in every lane (the turned tile of ``ssd_scan``, made once a
+# key head for the value heads it serves). The blocks' descriptors are the
+# ones above. Two forms give the same numbers:
+#
+# - **the row walk** (:func:`gdn_scan`: :func:`gdn_scan_reference`, and the
+#   kernel ``pallas_call(name="gdn_scan")``): a block's rows one after another,
+#   four passes over a head's state a row, on the vector unit; a decode step's
+#   one row a slot and a pack's blocks alike. It is what every program runs;
+# - **the chunked form** (:func:`gdn_chunked`, ``jax.numpy``; no program calls
+#   it: the tests' second derivation of the rule and the chip smoke's
+#   comparison, which the row walk won for a pack of 256 rows, PERF.md section
+#   6, PR 48; the form a training step would differentiate): ``C`` rows at a
+#   time. With ``G`` the running sum of ``g`` within the chunk, ``A[t, j] =
+#   beta_t exp(G_t - G_j) k_t . k_j`` below the diagonal, the rows' updates
+#   solve ``(I + A) U = beta (V - exp(G) K S_0)``, a unit lower triangular
+#   system; then ``O = exp(G) Q S_0 + tril(exp(G_t - G_j) Q K^T) U`` and ``S_C =
+#   exp(G_C) S_0 + (exp(G_C - G) K)^T U``: products for the matrix unit and one
+#   triangular solve a chunk a head, in float32 at the highest precision.
+
+
+def gdn_scan_reference(q, k, v, decay, beta, state, *, block_slot, block_rows, block_fresh, layer=0):
+    """The delta rule in ``jax.numpy``: blocks in order, rows in order. ``q``,
+    ``k`` [blocks, rows, Hk, dk]; ``v`` [blocks, rows, Hv, dv]; ``decay`` (=
+    ``exp(g)``) and ``beta`` [blocks, rows, Hv]; ``state`` [layers, slots, Hv,
+    dk, dv] float32. Returns ``(o [blocks, rows, Hv, dv] float32, state)``."""
+    f32 = jnp.float32
+    rows = q.shape[1]
+    per = v.shape[2] // k.shape[2]  # value heads a key head
+    stack = state[layer]
+
+    def block(stack, xs):
+        q_b, k_b, v_b, d_b, b_b, slot, n, fresh = xs
+        at = jnp.maximum(slot, 0)
+        before = stack[at]
+
+        def row(s, r):
+            q_t, k_t, v_t, d_t, b_t, i = r
+            live = i < n  # a padding row advances nothing
+            d_t, b_t = jnp.where(live, d_t, 1.0), jnp.where(live, b_t, 0.0)
+            q_t, k_t = jnp.repeat(q_t, per, axis=0), jnp.repeat(k_t, per, axis=0)   # [Hv, dk]
+            s = d_t[:, None, None] * s
+            read = jnp.sum(s * k_t[:, :, None], axis=1)                              # [Hv, dv]
+            s = s + k_t[:, :, None] * (b_t[:, None] * (v_t - read))[:, None, :]
+            return s, jnp.sum(s * q_t[:, :, None], axis=1)
+
+        s, o = jax.lax.scan(row, jnp.where(fresh > 0, 0.0, before),
+                            (q_b.astype(f32), k_b.astype(f32), v_b.astype(f32), d_b.astype(f32),
+                             b_b.astype(f32), jnp.arange(rows)))
+        keep = (slot < 0) | ((n <= 0) & (fresh <= 0))
+        return stack.at[at].set(jnp.where(keep, before, s)), o
+
+    stack, o = jax.lax.scan(block, stack, (q, k, v, decay, beta, block_slot, block_rows, block_fresh))
+    return o, state.at[layer].set(stack)
+
+
+def gdn_chunked(q, k, v, g, beta, state, *, block_slot, block_rows, block_fresh, layer=0, chunk: int = 64):
+    """The chunked form (the comment above) in ``jax.numpy``: the arguments of
+    :func:`gdn_scan_reference` but ``g`` itself, not its exponential (a chunk
+    works with its running sums). A block is walked in chunks of ``chunk`` rows;
+    where ``chunk`` does not divide the block's rows the block is padded with
+    rows that advance nothing. Returns ``(o, state)`` alike."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    nb, rows, hk, dk = q.shape
+    hv = v.shape[2]
+    per = hv // hk
+    c = min(chunk, rows)
+    padded = -(-rows // c) * c
+    stack = state[layer]
+    lower = jnp.tril(jnp.ones((c, c), bool), -1)
+    upto = jnp.tril(jnp.ones((c, c), bool))
+
+    def by_chunks(x):  # [rows, heads, ...] -> [chunks, Hv, c, ...], a key head's rows for each value head it serves
+        x = jnp.pad(x.astype(f32), ((0, padded - rows),) + ((0, 0),) * (x.ndim - 1))
+        if x.shape[1] == hk and per > 1:
+            x = jnp.repeat(x, per, axis=1)
+        x = x.reshape(padded // c, c, *x.shape[1:])
+        return jnp.moveaxis(x, 2, 1)
+
+    def block(stack, xs):
+        q_b, k_b, v_b, g_b, b_b, slot, n, fresh = xs
+        at = jnp.maximum(slot, 0)
+        before = stack[at]
+        live = jnp.arange(rows) < n
+        g_b = jnp.where(live[:, None], g_b, 0.0)   # decay 1 and beta 0: the state stays as it is
+        b_b = jnp.where(live[:, None], b_b, 0.0)
+
+        def one(s, xs):
+            qc, kc, vc, gc, bc = xs                                   # [Hv, c, dk | dv], [Hv, c]
+            run = jnp.cumsum(gc, axis=-1)                              # G
+            ratio = run[:, :, None] - run[:, None, :]                  # G_t - G_j
+            kk = jnp.einsum("htd,hjd->htj", kc, kc, precision=hi)
+            a = jnp.where(lower, bc[:, :, None] * jnp.exp(jnp.where(lower, ratio, 0.0)) * kk, 0.0)
+            grown = jnp.exp(run)[:, :, None]
+            rhs = bc[:, :, None] * (vc - grown * jnp.einsum("htd,hde->hte", kc, s, precision=hi))
+            u = jax.scipy.linalg.solve_triangular(a + jnp.eye(c, dtype=f32), rhs, lower=True, unit_diagonal=True)
+            qk = jnp.einsum("htd,hjd->htj", qc, kc, precision=hi)
+            qk = jnp.where(upto, jnp.exp(jnp.where(upto, ratio, 0.0)) * qk, 0.0)
+            o = grown * jnp.einsum("htd,hde->hte", qc, s, precision=hi) \
+                + jnp.einsum("htj,hje->hte", qk, u, precision=hi)
+            to_end = jnp.exp(run[:, -1:] - run)[:, :, None] * kc       # exp(G_C - G) K
+            s = jnp.exp(run[:, -1])[:, None, None] * s + jnp.einsum("htd,hte->hde", to_end, u, precision=hi)
+            return s, o
+
+        s, o = jax.lax.scan(one, jnp.where(fresh > 0, 0.0, before),
+                            tuple(by_chunks(x) for x in (q_b, k_b, v_b, g_b, b_b)))
+        o = jnp.moveaxis(o, 1, 2).reshape(padded, hv, -1)[:rows]
+        keep = (slot < 0) | ((n <= 0) & (fresh <= 0))
+        return stack.at[at].set(jnp.where(keep, before, s)), o
+
+    stack, o = jax.lax.scan(block, stack, (q, k, v, g, beta, block_slot, block_rows, block_fresh))
+    return o, state.at[layer].set(stack)
+
+
+_GDN_VMEM = 48 * 2 ** 20  # a slot's 2 MB state in and out, double-buffered, beside a 64-row block's rows
+
+
+def _gdn_scan_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
+                     q_ref, k_ref, v_ref, d_ref, b_ref, s_in_ref,
+                     o_ref, s_out_ref, kk, qq, *, group: int):
+    del layer_ref, slot_ref  # the block specs read them
+    f32 = jnp.float32
+    j = pl.program_id(0)
+    rows, hk, dk = q_ref.shape[1:]
+    hv, dv = v_ref.shape[2:]
+    per = hv // hk
+    n = rows_ref[j]
+
+    # as ssm_scan: the state stays in the output block while consecutive
+    # blocks continue one slot
+    @pl.when(cont_ref[j] == 0)
+    def _():
+        s_out_ref[...] = s_in_ref[...]
+
+    @pl.when(fresh_ref[j] == 1)
+    def _():
+        s_out_ref[...] = jnp.zeros(s_out_ref.shape, f32)
+
+    @pl.when(n < rows)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def walk(base):  # ``group`` rows from row ``base``, over every head
+        def of_key_head(g, carry):
+            # a row's key and query are vectors along the lanes; the state
+            # wants them down the sublanes, the same in every lane: the row
+            # spread over a tile and turned, once for the value heads it serves
+            for i in range(group):
+                for ref, tile in ((k_ref, kk), (q_ref, qq)):
+                    tile[i] = jnp.broadcast_to(ref[0, base + i, pl.ds(g, 1), :], (dv, dk)).T
+            for p in range(per):
+                h = g * per + p
+                s = s_out_ref[0, 0, h]                                              # [dk, dv]
+                for i in range(group):
+                    live = base + i < n  # a padding row: decay 1 and beta 0, no advance
+                    d_i = jnp.where(live, d_ref[0, base + i, pl.ds(h, 1), :], 1.0)   # [1, dv]
+                    b_i = jnp.where(live, b_ref[0, base + i, pl.ds(h, 1), :], 0.0)
+                    s = d_i * s
+                    read = jnp.sum(s * kk[i], axis=0, keepdims=True)
+                    s = s + kk[i] * (b_i * (v_ref[0, base + i, pl.ds(h, 1), :] - read))
+                    o_ref[0, base + i, pl.ds(h, 1), :] = jnp.sum(s * qq[i], axis=0, keepdims=True).astype(o_ref.dtype)
+                s_out_ref[0, 0, h] = s
+            return carry
+
+        jax.lax.fori_loop(0, hk, of_key_head, 0)
+
+    if rows == group:  # one group (a decode step's one row): no loop
+        pl.when(n > 0)(lambda: walk(0))
+    else:
+        def body(r, carry):
+            walk(r * group)
+            return carry
+
+        jax.lax.fori_loop(0, (n + group - 1) // group, body, 0)
+
+
+def _gdn_scan_call(q, k, v, decay, beta, state, block_slot, block_rows, block_fresh, layer, interpret: bool):
+    f32 = jnp.float32
+    nb, rows, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    group = 8 if rows % 8 == 0 else 1
+    if group == 1 and rows != 1:
+        raise ValueError(f"gdn_scan walks blocks of 1 row or of a multiple of 8, got {rows}")
+    if state.shape[2:] != (hv, dk, dv) or hv % hk:
+        raise ValueError(f"the state {state.shape} is not [layers, slots, {hv}, {dk}, {dv}]")
+    at, n_rows, fresh, cont = _block_descriptors(block_slot, block_rows, block_fresh)
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1), at, n_rows, fresh, cont)
+    a_channel = lambda x: jnp.broadcast_to(x.astype(f32)[..., None], (nb, rows, hv, dv))  # a head's scalar on its lanes
+
+    def a_block(j, *_):
+        return (j, 0, 0, 0)
+
+    def of_slot(j, ly, sl, *_):
+        return (ly[0], sl[j], 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, rows, hk, dk), a_block),          # q
+        pl.BlockSpec((1, rows, hk, dk), a_block),          # k
+        pl.BlockSpec((1, rows, hv, dv), a_block),          # v
+        pl.BlockSpec((1, rows, hv, dv), a_block),          # the decay, a channel
+        pl.BlockSpec((1, rows, hv, dv), a_block),          # beta, a channel
+        pl.BlockSpec((1, 1, hv, dk, dv), of_slot),         # the layers' states, this block's slot
+    ]
+    out_specs = [pl.BlockSpec((1, rows, hv, dv), a_block), pl.BlockSpec((1, 1, hv, dk, dv), of_slot)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars), grid=(nb,), in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((group, dk, dv), f32), pltpu.VMEM((group, dk, dv), f32)])
+    params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_GDN_VMEM)}
+    operands = (q.astype(f32), k.astype(f32), v.astype(f32), a_channel(decay), a_channel(beta), state)
+    o, state = pl.pallas_call(
+        functools.partial(_gdn_scan_kernel, group=group),
+        grid_spec=grid_spec, name="gdn_scan", interpret=interpret,
+        out_shape=[jax.ShapeDtypeStruct((nb, rows, hv, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={len(scalars) + len(operands) - 1: 1},
+        **params,
+    )(*scalars, *operands)
+    return o, state
+
+
+def gdn_scan(q, k, v, g, beta, state, *, block_slot, block_rows, block_fresh, layer=0,
+             impl: Optional[str] = None):
+    """The gated delta rule over blocks (the comment above): ``q``, ``k``
+    [blocks, rows, Hk, dk] normed and scaled, ``v`` [blocks, rows, Hv, dv],
+    ``g`` and ``beta`` [blocks, rows, Hv], ``state`` [layers, slots, Hv, dk, dv]
+    float32. Returns ``(o [blocks, rows, Hv, dv] float32, state)`` with this
+    ``layer``'s slots advanced: the row walk, by ``impl``
+    (:func:`resolve_ssm_kernel`; the kernel is ``pallas_call(name="gdn_scan")``)."""
+    mode = resolve_ssm_kernel(impl)
+    if state.dtype != jnp.float32:
+        raise ValueError(f"the recurrent state is float32, got {state.dtype}")
+    blocks = dict(block_slot=block_slot, block_rows=block_rows, block_fresh=block_fresh, layer=layer)
+    decay = jnp.exp(g.astype(jnp.float32))
+    if mode == "reference":
+        return gdn_scan_reference(q, k, v, decay, beta, state, **blocks)
+    return _gdn_scan_call(q, k, v, decay, beta, state, block_slot, block_rows, block_fresh, layer,
+                          mode == "interpret")

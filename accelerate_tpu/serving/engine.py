@@ -433,13 +433,13 @@ class ServingEngine:
         state_runs = [c for c in all_runs if c.has_state]
         run_cfgs = [c for c in all_runs if c.mixer == "attention"]  # the attention kinds
         # a state-space kind: whether its recurrence runs its kernel (ssm_scan
-        # for Mamba-1's, ssd_scan for the mixer with heads: the
-        # ssm_kernel_active and ssd_kernel_active gauges say which is engaged),
+        # for Mamba-1's, ssd_scan for the mixer with heads, gdn_scan for the
+        # delta rule's: the <name>_kernel_active gauges say which is engaged),
         # and whether both programs carry the layers' states whole and update
         # them in place (the state_in_place gauge)
         by_mixer = lambda name: [c for c in state_runs if c.mixer == name]
-        self._ssm_kernel_costed = bool(by_mixer("ssm")) and all(ssm_kernel_active(c) for c in by_mixer("ssm"))
-        self._ssd_kernel_costed = bool(by_mixer("ssd")) and all(ssm_kernel_active(c) for c in by_mixer("ssd"))
+        self._state_kernel_costed = {name: bool(by_mixer(name)) and all(ssm_kernel_active(c) for c in by_mixer(name))
+                                     for name in ("ssm", "ssd", "gdn")}
         self._state_in_place = bool(state_runs) and all(c.scan_layers for c in state_runs)
         self._kernel_costed = all(decode_kernel_active(c) for c in run_cfgs)
         # ... and whether that step updates the arena in place, the
@@ -3081,12 +3081,12 @@ class ServingEngine:
             out["serving/mla_kernel_active"] = int(self._mla_kernel_costed)
         if self._state_kind is not None:
             # the state a slot keeps beside its pages (of arena_bytes), which
-            # of the two recurrences' kernels is engaged (ssm_scan, ssd_scan),
+            # of the recurrences' kernels is engaged (ssm_scan, ssd_scan, gdn_scan),
             # and whether the programs hold one copy of the state
             out["serving/state_bytes"] = self.state_bytes
             out["serving/state_bytes_per_slot"] = self._state_kind.slot_bytes
-            out["serving/ssm_kernel_active"] = int(self._ssm_kernel_costed)
-            out["serving/ssd_kernel_active"] = int(self._ssd_kernel_costed)
+            for name, costed in self._state_kernel_costed.items():
+                out[f"serving/{name}_kernel_active"] = int(costed)
             out["serving/state_in_place"] = int(self._state_in_place)
         for kind in self._kinds[1:]:
             out[f"serving/pages_in_use.{kind.name}"] = kind.allocator.in_use

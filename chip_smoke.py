@@ -452,6 +452,131 @@ def ssd_phase(S: Sizes, seed: int, on_chip: bool) -> None:
                                    f"{int((np.asarray(sizes) > 0).sum())} with a pair, {impl}): max|y-ref|={err:.2e} of {scale:.2f}")
 
 
+def gdn_phase(S: Sizes, seed: int, on_chip: bool) -> None:
+    """The delta rule ISSUE 48 brought, both forms against the ``jax.numpy``
+    row rule at the published shapes of the Qwen3-Next cell (32 value heads
+    over 16 key heads of 128 x 128, 2 MB of state a slot, 128 slots; tiny and
+    interpreted in the rehearsal): the ``gdn_scan`` kernel compiled by Mosaic
+    for a decode step with dead slots between and for a pack of four 64-row
+    token blocks, and the chunked form (``ops/ssm.gdn_chunked``, which no
+    program calls: XLA's products, 64 rows a chunk) for the same pack, on a
+    stack of two layers of which the second is advanced; what no block
+    advances comes back bit for bit. Each form is timed with the stack donated
+    and handed on from call to call, as both served programs hold it; that
+    comparison is why a pack walks its rows. And the gated experts' two kernels,
+    each at rows on both sides of the size at which the program changes from
+    one to the other, against a loop over the experts by hand."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.ssm import gdn_chunked, gdn_scan, resolve_ssm_kernel
+
+    def timed(fn, args, line, carried=None):
+        # ``carried``: the argument the call hands on (the stack of states, donated); else the same arguments each
+        # call. The first call, which compiles, is not timed
+        if on_chip:
+            args = list(args)
+            if carried is not None:
+                args[carried] = jnp.copy(args[carried])
+            for i in range(21):
+                out = fn(*args)
+                if carried is not None:
+                    args[carried] = out[1]
+                if i == 0:
+                    jax.block_until_ready(out)
+                    t0 = time.perf_counter()
+            jax.block_until_ready(out)
+            line += f"; {1e6 * (time.perf_counter() - t0) / 20:.0f} us a call, host clock, dispatch included"
+        say(line)
+
+    hk, hv, dk, dv, slots, bt = (16, 32, 128, 128, 128, 64) if on_chip else (2, 4, 8, 8, 8, 8)
+    mode = resolve_ssm_kernel(S.kernel_mode)
+    live = (np.arange(slots) % 5 != 3).astype(np.int32)
+    shapes = {
+        "decode step": (np.arange(slots), live, np.zeros(slots, np.int32), 1),
+        "packed prefill": (np.array([2, 2, 0, -1]), np.array([bt, bt - 3, bt // 2 + 1, 0]), np.array([1, 0, 0, 0]), bt),
+    }
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+    for name, (slot, rows, fresh, block) in shapes.items():
+        key = jax.random.split(jax.random.key(seed + block), 6)
+        nb = len(slot)
+        args = (unit(jax.random.normal(key[0], (nb, block, hk, dk))) * dk ** -0.5,
+                unit(jax.random.normal(key[1], (nb, block, hk, dk))), jax.random.normal(key[2], (nb, block, hv, dv)),
+                -jax.nn.softplus(jax.random.normal(key[3], (nb, block, hv)) - 2.0),
+                jax.nn.sigmoid(jax.random.normal(key[4], (nb, block, hv))),
+                jax.random.normal(key[5], (2, slots, hv, dk, dv)))
+        kw = dict(block_slot=jnp.asarray(slot, jnp.int32), block_rows=jnp.asarray(rows, jnp.int32),
+                  block_fresh=jnp.asarray(fresh, jnp.int32), layer=1)
+        forms = {f"row walk, {mode}": lambda *a: gdn_scan(*a, impl=mode, **kw)}
+        if block > 1:
+            forms[f"chunked, {bt} rows a chunk"] = lambda *a: gdn_chunked(*a, chunk=bt, **kw)
+        o_ref, state_ref = jax.block_until_ready(jax.jit(lambda *a: gdn_scan(*a, impl="reference", **kw))(*args))
+        for form, fn in forms.items():
+            o, state = jax.block_until_ready(jax.jit(fn)(*args))
+            err_o = max(float(jnp.max(jnp.abs(o[j, :r] - o_ref[j, :r]))) for j, r in enumerate(rows) if slot[j] >= 0 and r)
+            err_s = float(jnp.max(jnp.abs(state - state_ref)))
+            # (compared on the device: a stack of states is 0.5 GB at the published shapes)
+            assert err_s <= 1e-4 * (1.0 + float(jnp.max(jnp.abs(state_ref)))) and err_o <= 2e-3, (form, err_s, err_o)
+            advanced = {int(s) for s, r, f in zip(slot, rows, fresh) if s >= 0 and (r or f)}
+            kept = jnp.asarray([i for i in range(slots) if i not in advanced])
+            assert bool(jnp.array_equal(state[0], args[-1][0]))
+            assert bool(jnp.array_equal(state[1, kept], args[-1][1, kept]))
+            timed(jax.jit(fn, donate_argnums=5), args,
+                  f"  gdn_scan {name} ({nb} blocks x {block} rows, {hv} heads over {hk} of {dk} x {dv}, {form}, "
+                  f"the stack donated): max|o-ref|={err_o:.2e} max|S-ref|={err_s:.2e}", carried=5)
+
+    # the gated experts' two kernels on either side of models/moe._ALL_ROWS_MAX (every expert over every row up to
+    # 256 rows, each expert's own row tiles past it), both timed at every size so that the constant stands beside a
+    # measurement: the cell's 64 narrow experts of 2,048 -> 512 -> 2,048 from a quarter of a decode step's rows to a
+    # pack's 640, and the two standing expert cells' shapes at the ends of the rows their calls have; out of a stack
+    # of two layers, against a loop over the experts by hand
+    from accelerate_tpu.models import moe
+
+    impl = "pallas" if on_chip else "interpret"
+    cases = ((("qwen3-next", 64, 2048, 512, (64, 128, 256, 320, 640)), ("mimo", 16, 4096, 2048, (64, 256)),
+              ("gigachat3", 8, 7168, 2048, (16, 128))) if on_chip else (("tiny", 8, 32, 128, (64, 320)),))
+    for model, held, d, m, sizes_of_rows in cases:
+        key = jax.random.split(jax.random.key(seed + 7), 4)
+        wg, wu = ((jax.random.normal(k, (2, held, d, m)) * d ** -0.5).astype(jnp.bfloat16) for k in key[:2])
+        wd = (jax.random.normal(key[2], (2, held, m, d)) * m ** -0.5).astype(jnp.bfloat16)
+        for rows in sizes_of_rows:
+            xs = jax.random.normal(key[3], (rows, d)).astype(jnp.bfloat16)
+            # half the rows filled, two experts without a pair, sizes uneven
+            load = np.random.default_rng(seed).multinomial(rows // 2, np.ones(held - 2) / (held - 2))
+            sizes = jnp.asarray(np.concatenate([load[:3], [0], load[3:-1], [0], load[-1:]]), jnp.int32)
+            want, lo, x32 = np.zeros((rows, d), np.float32), 0, np.asarray(xs, np.float32)
+            for e, n in enumerate(np.asarray(sizes)):
+                if n:
+                    gate, up = (x32[lo:lo + n] @ np.asarray(w[1, e], np.float32) for w in (wg, wu))
+                    hidden = np.asarray(jnp.asarray(gate / (1.0 + np.exp(-gate)) * up).astype(jnp.bfloat16), np.float32)
+                    want[lo:lo + n] = hidden @ np.asarray(wd[1, e], np.float32)
+                lo += n
+            scale = float(np.abs(want).max())
+            served = "every expert over every row" if rows <= moe._ALL_ROWS_MAX else "each expert's own row tiles"
+            forms = {"every expert over every row": lambda x, s, wg, wu, wd: moe._experts_all_rows_call(
+                         x, wg, wu, wd, s, jnp.int32(1), impl == "interpret"),
+                     "each expert's own row tiles": lambda x, s, wg, wu, wd: moe._experts2_kernel_call(
+                         x, wu, wd, s, jnp.int32(1), impl == "interpret", wg=wg)}
+            forms[served + ", as served"] = lambda x, s, wg, wu, wd: moe.grouped_mlp(
+                x, wg, wu, wd, s, impl, layer=jnp.int32(1))
+            del forms[served]
+            for form, fn in forms.items():
+                line = (f"  moe_experts {model} ({rows} rows, {held} experts of {d} -> {m} -> {d}, "
+                        f"{int((np.asarray(sizes) > 0).sum())} with a pair, {impl}, {form})")
+                kernel = jax.jit(fn)
+                try:
+                    got = np.asarray(jax.block_until_ready(kernel(xs, sizes, wg, wu, wd)))
+                except Exception as e:  # a form the chip's compiler refuses at a shape it does not serve
+                    assert "as served" not in form, e
+                    say(f"{line}: not compiled, {str(e).splitlines()[0][:160]}")
+                    continue
+                err = float(np.abs(got - want).max())
+                assert err <= 4e-3 * max(scale, 1.0), (form, err, scale)
+                assert not got[rows // 2:].any()
+                timed(kernel, (xs, sizes, wg, wu, wd), f"{line}: max|y-ref|={err:.2e} of {scale:.2f}")
+
+
 def eva_phase(S: Sizes, seed: int, on_chip: bool) -> None:
     """The ``eva_pool`` kernel against ``eva_pool_reference`` at the published
     widths of the closing-window cell (32 kv heads of 128, pages of 16, a
@@ -1007,6 +1132,7 @@ def main() -> int:
          ("closing-window pooling vs reference", eva_phase),
          ("latent attention's kernels vs their dense reads", latent_phase),
          ("ssd: the recurrence with heads and two-matrix experts vs their jax.numpy reads", ssd_phase),
+         ("gdn: the delta rule's two forms and many narrow experts vs their jax.numpy reads", gdn_phase),
          ("train", accelerator_train), ("serve", serve_phase)]
     )
     if args.phase:

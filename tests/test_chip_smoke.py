@@ -57,7 +57,8 @@ def runs():
 @pytest.mark.parametrize("name, chips, phases", [
     ("one_chip", 1, ("kernels vs references", "state-space scan vs reference", "closing-window pooling vs reference",
                     "latent attention's kernels vs their dense reads",
-                    "ssd: the recurrence with heads and two-matrix experts vs their jax.numpy reads", "train", "serve")),
+                    "ssd: the recurrence with heads and two-matrix experts vs their jax.numpy reads",
+                    "gdn: the delta rule's two forms and many narrow experts vs their jax.numpy reads", "train", "serve")),
     ("four_chips", 4, ("four chips: fsdp2 x tp2 trainer vs one device",)),
 ], ids=["one_chip", "four_chips"])
 def test_cpu_rehearsal_runs_every_phase(runs, name, chips, phases):

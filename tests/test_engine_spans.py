@@ -681,3 +681,66 @@ def test_a_state_with_heads_and_experts_in_a_latent_say_what_their_kernels_are_h
     # 4 experts a token of 32 outputs, 8 held: a step of two slots sends the held quarter a pair or two a layer
     assert any(a["experts_touched"] >= 1 for a in steps)
     assert sum(a["expert_pairs_all"] for a in steps) == sum(a["slots"] for a in steps) * 2 * 4
+
+
+@pytest.mark.parametrize("kernel", ["interpret", None], ids=["kernels_interpreted", "jax_numpy"])
+def test_a_delta_rule_state_and_narrow_experts_say_what_their_kernels_are_handed(kernel):
+    """A model of Gated DeltaNet and gated attention layers with experts in
+    every layer (the qwen3-next cell's, at its rehearsal's widths, kernels
+    interpreted): ``ssm_slots`` / ``ssm_rows`` on ``serving/decode_dispatch``
+    the slots whose delta-rule state a step advances; ``ssm_rows`` /
+    ``ssm_slots`` / ``ssm_fresh_slots`` on ``serving/prefill_dispatch`` a
+    pack's live rows, its slots and those it zeroes; on both ``expert_pairs``
+    the pairs sent to held experts, ``experts_idle`` the held experts of a
+    layer that got none, ``expert_chunks`` the grouped products made; the
+    gauges the state's bytes a slot and whether the delta rule's kernel is the
+    one engaged (not where ``jax.numpy`` walks the rows)."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import jax.numpy as jnp
+    import manifest
+    import weights
+
+    arch = manifest.load_arch("qwen3_next")
+    with open(os.path.join(root, "benchmarks", "configs", "qwen3-next-80b-serve-12l-ep8.json")) as f:
+        c = json.load(f)
+    c.update({k: v for k, v in c.pop("rehearsal").items() if not isinstance(v, dict)})
+    c["published"] = {"num_experts": 32}
+    cfg = dataclasses.replace(arch.decoder_config(c, max_seq_len=128, remat=False, decode_kernel=kernel,
+                                                  prefill_kernel=kernel),
+                              dtype=jnp.float32)
+    params = weights.make_jit(arch.reference, c, 3, jnp.float32, adapt=arch.to_program_tree(c))
+    eng = ServingEngine(arch.module(cfg), params, num_slots=2, max_cache_len=128, page_size=8,
+                        prefill_chunks=(16, 32), prefix_cache=False)
+    m = eng.metrics()
+    state = 8 * 8 * 8 * 4 + 3 * (2 * 4 * 8 + 8 * 8) * 4  # a layer: value heads x dk x dv, and the convolution's 3 rows
+    assert m["serving/state_bytes_per_slot"] == 3 * state == arch.slot_state_bytes(c) and m["serving/state_in_place"] == 1
+    on = int(kernel == "interpret")
+    assert (m["serving/gdn_kernel_active"], m["serving/ssd_kernel_active"], m["serving/ssm_kernel_active"]) == (on, 0, 0)
+    assert m["serving/experts_from_stack"] == on
+    eng.warmup()
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(3, 512, (n,)) for n in (45, 7, 20)]
+    mark = _mark()
+    reqs = [eng.submit(p, max_new_tokens=4, seed=i) for i, p in enumerate(prompts)]
+    eng.run()
+    assert all(r.outcome == "finished" for r in reqs)
+    new = _spans_since(mark)
+    packs = [s[5] for s in new if s[2] == "serving/prefill_dispatch"]
+    steps = [s[5] for s in new if s[2] == "serving/decode_dispatch"]
+    assert packs and steps
+    assert sum(a["ssm_rows"] for a in packs) == 45 + 7 + 20 and sum(a["ssm_fresh_slots"] for a in packs) == 3
+    assert all(1 <= a["ssm_slots"] <= 2 for a in packs)
+    assert all(a["ssm_slots"] == a["ssm_rows"] == a["slots"] for a in steps)
+    held = 4 * 8  # four layers of 8 held experts
+    for a in packs + steps:
+        assert a["experts_touched"] + a["experts_idle"] == held
+        assert a["experts_touched"] <= a["expert_pairs"] <= a["expert_pairs_all"]
+        assert (a["expert_pairs"] == 0) == (a["expert_chunks"] == 0)
+    assert any(a["experts_touched"] >= 1 for a in steps)
+    assert sum(a["expert_pairs_all"] for a in steps) == sum(a["slots"] for a in steps) * 4 * 4

@@ -716,3 +716,151 @@ def test_a_dense_relu2_mlp_is_two_matrices():
     want = np.square(np.maximum(np.asarray(x[0]) @ up, 0.0)) @ down
     np.testing.assert_allclose(np.asarray(DecoderMLP(cfg).apply({"params": params}, x)[0]), want, atol=1e-5)
     assert cfg.num_params == DecoderConfig.tiny().num_params - cfg.num_layers * cfg.embed_dim * cfg.mlp_dim
+
+
+# -- 10 of 512 narrow gated experts and a shared expert behind a gate of its own (ISSUE 48) ------------
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_10_of_512_like_softmax_routing_is_a_brute_force_choice(seed):
+    """5 of 64 softmax scores, no bias, no group stage, no scale: the same
+    experts as the choice by hand, the weights the chosen probabilities
+    normalised to sum to one; and the pairs sorted for a held eighth are that
+    eighth's, by expert."""
+    logits = 2.0 * jax.random.normal(jax.random.PRNGKey(seed), (48, 64))
+    scores = router_scores(logits, "softmax")
+    np.testing.assert_allclose(np.asarray(scores).sum(-1), 1.0, rtol=1e-6)
+    experts, weights = top_k_routing(scores, 5)
+    assert [sorted(map(int, row)) for row in np.asarray(experts)] == _brute_force_choice(scores, jnp.zeros(64), 5, 1, 1)
+    chosen = np.take_along_axis(np.asarray(scores), np.asarray(experts), axis=-1)
+    np.testing.assert_allclose(np.asarray(weights), chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
+    order, sizes, n_held = sort_pairs(experts, 8, 8)
+    flat = np.asarray(experts).reshape(-1)
+    held = [(e - 8, i) for i, e in enumerate(flat) if 8 <= e < 16]
+    assert int(n_held) == len(held) and list(np.asarray(sizes)) == [sum(1 for e, _ in held if e == j) for j in range(8)]
+    assert [int(i) for i in np.asarray(order)[:len(held)]] == [i for _, i in sorted(held)]
+    # what the program multiplies at once at the published shape: twice the expected pairs of the held eighth
+    assert expert_rows(128, 10, 64, 512) == 320 and expert_rows(256, 10, 64, 512) == 640
+
+
+def _qwen3_next_arch():
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import manifest
+
+    return manifest.load_arch("qwen3_next")
+
+
+def _qwen3_next_layer(experts: int):
+    """A one-layer configuration at the rehearsal's widths that holds
+    ``experts`` of 32, its reference weights and a batch of inputs."""
+    import json
+    import os
+
+    arch = _qwen3_next_arch()
+    import weights as W
+
+    with open(os.path.join(os.path.dirname(arch.__file__), "..", "configs", "qwen3-next-80b-serve-12l-ep8.json")) as f:
+        c = json.load(f)
+    c.update({k: v for k, v in c.pop("rehearsal").items() if not isinstance(v, dict)})
+    c = dict(c, num_hidden_layers=1, num_experts=experts, published={"num_experts": 32})
+    w = W.make_jit(arch.reference, c, 5, jnp.float32)
+    return arch.reference, c, arch.reference.layer_weights(c, w, 0), jax.random.normal(jax.random.PRNGKey(0), (24, c["hidden_size"]))
+
+
+def _share_params(lw, first, count):
+    return {"router": lw["router"], "w_gate": lw["gate_exp"][first:first + count], "w_up": lw["up_exp"][first:first + count],
+            "w_down": lw["down_exp"][first:first + count], "shared_gate": lw["gate_shared"], "shared_up": lw["up_shared"],
+            "shared_down": lw["down_shared"], "shared_out_gate": lw["shared_gate"]}
+
+
+def _share_config(c, first, count, impl=None, **over):
+    fields = dict(embed_dim=c["hidden_size"], mlp_dim=c["moe_intermediate_size"], moe_num_experts=count,
+                  moe_router_outputs=32, moe_experts_held=(first, count), moe_top_k=c["num_experts_per_tok"],
+                  moe_scoring="softmax", moe_shared_dim=c["shared_expert_intermediate_size"], moe_shared_gate=True,
+                  decode_kernel=impl)
+    fields.update(over)
+    return DecoderConfig.tiny(**fields)
+
+
+@pytest.mark.parametrize("impl", [None, "interpret"], ids=["ragged_dot", "kernel_interpreted"])
+def test_the_eight_shares_of_4_of_32_experts_add_up_to_the_uncut_layer(impl):
+    """The share test of the model-configs guide, section 4: 8 chips hold 4 of
+    32 gated experts each; the program's expert layer on each computes its own
+    experts' part under the router's full width and adds the shared expert
+    behind its gate whole; the eight parts less seven times the gated shared
+    expert's result equal what the reference gives for the whole layer with
+    all 32 held: router, shared expert and gate counted once."""
+    ref, whole, lw, u = _qwen3_next_layer(32)
+    want = np.asarray(ref.experts(whole, "float32", u, lw))
+    shared = np.asarray(jax.nn.sigmoid(u @ lw["shared_gate"]) * ref._gated_mlp(
+        u, lw["gate_shared"], lw["up_shared"], lw["down_shared"], "float32"))
+    parts = 0
+    for first in range(0, 32, 4):
+        cfg = _share_config(whole, first, 4, impl)
+        part, _ = MoeMLP(cfg, decode=impl is not None).apply({"params": _share_params(lw, first, 4)}, u[None])
+        # the reference given the same share says the same of it
+        share = dict(whole, num_experts=4, experts_first=first)
+        cut = dict(lw, gate_exp=lw["gate_exp"][first:first + 4], up_exp=lw["up_exp"][first:first + 4],
+                   down_exp=lw["down_exp"][first:first + 4])
+        np.testing.assert_allclose(np.asarray(part[0]), np.asarray(ref.experts(share, "float32", u, cut)), atol=3e-5)
+        parts = parts + np.asarray(part[0])
+    np.testing.assert_allclose(parts - 7 * shared, want, atol=6e-5)
+    assert np.abs(want - shared).max() > 0.05 and np.abs(shared).max() > 0.05  # both add something to be right about
+
+
+def test_the_shared_experts_gate_is_one_scalar_a_token():
+    """With the gate's weights at zero the shared expert comes in at a half;
+    without the field the layer has no such leaf and the shared expert comes in
+    whole: the routed part is the same in all three."""
+    ref, c, lw, u = _qwen3_next_layer(32)
+    params = _share_params(lw, 0, 32)
+    run = lambda cfg, p: np.asarray(MoeMLP(cfg).apply({"params": p}, u[None])[0][0])
+    shared = np.asarray(ref._gated_mlp(u, lw["gate_shared"], lw["up_shared"], lw["down_shared"], "float32"))
+    gate = np.asarray(jax.nn.sigmoid(u @ lw["shared_gate"]))
+    assert gate.shape == (24, 1) and gate.std() > 0.05
+    gated = run(_share_config(c, 0, 32), params)
+    halved = run(_share_config(c, 0, 32), dict(params, shared_out_gate=jnp.zeros_like(lw["shared_gate"])))
+    plain = {k: v for k, v in params.items() if k != "shared_out_gate"}
+    whole = run(_share_config(c, 0, 32, moe_shared_gate=False), plain)
+    np.testing.assert_allclose(gated - gate * shared, whole - shared, atol=3e-5)
+    np.testing.assert_allclose(halved - 0.5 * shared, whole - shared, atol=3e-5)
+    cfg = _share_config(c, 0, 32)
+    assert cfg.num_params - _share_config(c, 0, 32, moe_shared_gate=False).num_params == cfg.num_layers * cfg.embed_dim
+
+
+def _gated_loop(xs, wg, wu, wd, sizes):
+    """``(silu(x Wg_e) * x Wu_e) Wd_e`` expert by expert over rows sorted by expert."""
+    out, lo = np.zeros((xs.shape[0], wd.shape[-1]), np.float32), 0
+    for e, n in enumerate(np.asarray(sizes)):
+        rows = jnp.asarray(xs[lo:lo + n], jnp.float32)
+        out[lo:lo + n] = np.asarray((jax.nn.silu(rows @ wg[e]) * (rows @ wu[e])) @ wd[e])
+        lo += n
+    return out
+
+
+@pytest.mark.parametrize("sizes", [[30, 0, 90, 1, 0, 0, 33, 7], [0, 0, 0, 0, 0, 0, 0, 5], [300, 0, 0, 0, 0, 0, 0, 0],
+                                   [0] * 8], ids=["uneven", "the_last_expert_alone", "one_expert_over_row_tiles", "none"])
+def test_past_256_rows_the_gated_kernel_walks_each_experts_own_row_tiles(sizes):
+    """``grouped_mlp`` with more rows than every expert should multiply (320
+    here, a decode step of 128 slots at 10 of 512 with 64 held): the
+    ``moe_experts`` kernel interpreted takes each expert over the 16-row tiles
+    that hold its rows, out of the layers' stack, against a loop over the
+    experts; rows past the experts' sum are left zero. At 256 rows and under
+    the kernel is the one of before (every expert over every row)."""
+    from accelerate_tpu.models import moe
+
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    xs = jax.random.normal(k[0], (320, 32))
+    wg, wu = (jax.random.normal(kk, (2, 8, 32, 128)) * 32 ** -0.5 for kk in k[1:3])
+    wd = jax.random.normal(k[3], (2, 8, 128, 32)) * 128 ** -0.5
+    assert 320 > moe._ALL_ROWS_MAX >= 256
+    got = grouped_mlp(xs, wg, wu, wd, jnp.asarray(sizes, jnp.int32), "interpret", layer=jnp.int32(1))
+    np.testing.assert_allclose(np.asarray(got), _gated_loop(xs, wg[1], wu[1], wd[1], sizes), atol=3e-5)
+    assert not np.asarray(got[sum(sizes):]).any()
+    few = grouped_mlp(xs[:256], wg, wu, wd, jnp.minimum(jnp.asarray(sizes, jnp.int32), 32), "interpret", layer=jnp.int32(1))
+    np.testing.assert_allclose(np.asarray(few), _gated_loop(xs[:256], wg[1], wu[1], wd[1], np.minimum(sizes, 32)), atol=3e-5)

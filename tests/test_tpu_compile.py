@@ -202,6 +202,21 @@ def _ssd_scan(chip, *, blocks, rows, heads=128, head_dim=64, groups=8, n=128, la
                 per_block, per_block, per_block, S((), jnp.int32))
 
 
+def _gdn_scan(chip, *, blocks, rows, hk=16, hv=32, dk=128, dv=128, layers=3, slots=128):
+    """The delta rule's kernel over the layers' stack of states and a layer
+    index, the stack its output, at the qwen3-next cell's published shapes (a
+    slot's state in a layer is 32 heads of [128, 128] float32, 2 MB)."""
+    from accelerate_tpu.ops import ssm
+
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    fn = lambda q, k, v, g, beta, state, slot, n_rows, fresh, layer: ssm._gdn_scan_call(
+        q, k, v, g, beta, state, slot, n_rows, fresh, layer, False)
+    per_block = S((blocks,), jnp.int32)
+    return fn, (S((blocks, rows, hk, dk)), S((blocks, rows, hk, dk)), S((blocks, rows, hv, dv)),
+                S((blocks, rows, hv)), S((blocks, rows, hv)), S((layers, slots, hv, dk, dv)),
+                per_block, per_block, per_block, S((), jnp.int32))
+
+
 def _moe_experts_relu2(chip, *, rows, layers=3, held=128, d=1024, m=2688):
     """The two-matrix experts' kernel out of a run's stacks (the nemotron3
     cell's run of three expert layers, 128 held experts in a 1,024-wide
@@ -332,6 +347,18 @@ CASES = {
     "ssd_scan_pack_256_rows": (_ssd_scan, dict(blocks=4, rows=64)),
     "moe_experts_relu2_decode_rows": (_moe_experts_relu2, dict(rows=1056)),
     "moe_experts_relu2_prefill_rows": (_moe_experts_relu2, dict(rows=2816)),
+    # the delta rule's two forms, many narrow gated experts and attention at 2 kv heads of 256 (ISSUE 48), at the
+    # qwen3-next cell's published shapes: 128 slots, runs of three DeltaNet layers and of one attention layer
+    "gdn_scan_decode_step_128_slots": (_gdn_scan, dict(blocks=128, rows=1)),
+    "gdn_scan_pack_256_rows": (_gdn_scan, dict(blocks=4, rows=64)),
+    "moe_experts_fine_decode_rows": (_moe_experts, dict(rows=320, layers=3, held=64, d=2048, m=512)),
+    "moe_experts_fine_prefill_rows": (_moe_experts, dict(rows=640, layers=3, held=64, d=2048, m=512)),
+    "paged_decode_2_kv_heads_of_256_in_place": (
+        _paged_decode, dict(h=16, kvh=2, d=256, slots=128, pages=17408, table=1152, layers=1, write=True)),
+    "ragged_prefill_2_kv_heads_of_256_in_place": (
+        _ragged_prefill, dict(h=16, kvh=2, d=256, bt=64, cap=256, slots=128, pages=17408, table=1152, layers=1)),
+    "ragged_prefill_2_kv_heads_of_256_64_rows_in_place": (
+        _ragged_prefill, dict(h=16, kvh=2, d=256, bt=64, cap=64, slots=128, pages=17408, table=1152, layers=1)),
     "paged_decode_one_kv_head_group20_in_place": (
         _paged_decode, dict(h=20, kvh=1, slots=128, pages=16384, table=512, write=True)),
     "ragged_prefill_one_kv_head_group20": (
@@ -424,6 +451,9 @@ KERNEL_NAMES = {
     "ssm_scan_decode_step_128_slots": {"ssm_scan"},
     "ssm_scan_pack_256_rows": {"ssm_scan"},
     "eva_pool_decode_step_16_slots": {"eva_pool"},
+    "gdn_scan_decode_step_128_slots": {"gdn_scan"},
+    "gdn_scan_pack_256_rows": {"gdn_scan"},
+    "moe_experts_fine_decode_rows": {"moe_experts"},
 }
 
 
@@ -452,6 +482,7 @@ CELL_SHAPES = {
     "jamba_1x20": ((1, 128, None, 512, None, 20), 64, False, 32),
     "evabyte_32x1": ((32, 128, None, 1280, None, 32), 16, True, 8),
     "gigachat_latent_1x64": ((1, 640, 0, 1600, None, 64), 64, False, None),
+    "qwen3_next_2x8_of_256": ((2, 256, None, 1152, None, 16), 64, False, 32),
 }
 
 
@@ -903,6 +934,63 @@ def test_the_half_block_serving_programs_compile_at_the_published_widths(chip, m
     arena, weights_bytes = pages.arena_nbytes(eng._arena), 2 * arch.total_params(c)
     assert pages.state_nbytes(eng._arena) == 96 * 21_585_920 and mem.alias_size_in_bytes >= arena
     assert mem.temp_size_in_bytes < 128 * 2**20, mem.temp_size_in_bytes
+    assert weights_bytes + arena <= mem.argument_size_in_bytes < weights_bytes + arena + 64 * 2**20
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes,
+          "aliased", mem.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "packed_prefill"])
+def test_the_delta_rule_serving_programs_compile_at_the_published_widths(chip, monkeypatch, program):
+    """The qwen3-next cell's engine as its configuration file sizes it (12
+    published layers ``LLLF`` three times as 6 scans, 64 of 512 experts, 128
+    slots of 18,432; built from shapes alone, nothing is allocated), both
+    programs compiled for the described chip: the program's kernels are
+    ``gdn_scan``, ``moe_experts`` and the attention kernel; the arena (2.53 GB
+    of state, 1.71 GB of pages) is aliased to the program's output, and no
+    operation copies, slices or scatters a layer's states or the stack of
+    them; the arguments are the weights and the arena (10.1 GB), the
+    temporaries under 256 MB."""
+    import json
+    import os
+    import re
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "benchmarks")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import manifest
+    import weights
+    from accelerate_tpu.serving import ServingEngine, pages
+
+    with open(os.path.join(root, "benchmarks", "configs", "qwen3-next-80b-serve-12l-ep8.json")) as f:
+        c = json.load(f)
+    arch, s = manifest.load_arch(c["model_type"]), c["serving"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = arch.decoder_config(c, max_seq_len=s["max_cache_len"], remat=False)
+    assert cfg.num_params == arch.total_params(c) == 2_929_374_400
+    params = jax.eval_shape(
+        lambda k: arch.to_program_tree(c)(weights.make(arch.reference, c, k, jnp.bfloat16)), weights.seed_key(1))
+    eng = ServingEngine(arch.module(cfg), params, page_size=s["page_size"], num_slots=s["num_slots"],
+                        max_cache_len=s["max_cache_len"], num_pages=s["num_pages"], **s["engine_kwargs"])
+    m = eng.metrics()
+    assert m["serving/gdn_kernel_active"] == 1
+    assert m["serving/state_bytes_per_slot"] == arch.slot_state_bytes(c) == 9 * (2_097_152 + 3 * 8_192 * 4)
+    assert m["serving/arena_in_place"] == m["serving/prefill_arena_in_place"] == 1
+    assert m["serving/state_in_place"] == m["serving/experts_from_stack"] == 1
+    compiled = _compile_serving_program(eng, chip, program)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernel_names(text) == {"gdn_scan", "moe_experts",
+                                   {"decode_step": "attn", "packed_prefill": "ragged_prefill_attn"}[program]}
+    states = [x for p, x in jax.tree_util.tree_flatten_with_path(eng._arena)[0] if p[-1].key == "ssm_state"]
+    assert sorted(x.shape for x in states) == [(3, 128, 32, 128, 128)] * 3
+    shapes = {",".join(map(str, shp)) for x in states for shp in (x.shape, x.shape[1:])}
+    moved = re.compile(r"= \w+\[(%s)\]\S* (copy|copy-start|dynamic-slice|dynamic-update-slice|scatter)\("
+                       % "|".join(shapes))
+    assert not moved.search(text)
+    arena, weights_bytes = pages.arena_nbytes(eng._arena), 2 * arch.total_params(c)
+    assert pages.state_nbytes(eng._arena) == 128 * arch.slot_state_bytes(c) and mem.alias_size_in_bytes >= arena
+    assert mem.temp_size_in_bytes < 256 * 2**20, mem.temp_size_in_bytes
     assert weights_bytes + arena <= mem.argument_size_in_bytes < weights_bytes + arena + 64 * 2**20
     print(program, "arguments", mem.argument_size_in_bytes, "temporaries", mem.temp_size_in_bytes,
           "aliased", mem.alias_size_in_bytes)
