@@ -342,7 +342,9 @@ class GatedDeltaNet(nn.Module):
     it says how). The three call forms, the blocks and what a slot keeps are
     :class:`SelectiveSSM`'s (the module's docstring); the recurrence is
     ``ops/ssm.gdn_scan``, float32, its state ``Hv x dk x dv`` a slot a layer:
-    a pack walks its rows as a decode step walks its one."""
+    a decode step walks its one row a slot, a pack's token blocks take the
+    rule in chunked form on the matrix unit (the same kernel, by the block's
+    shape)."""
 
     config: DecoderConfig
     mesh: Optional[Mesh] = None

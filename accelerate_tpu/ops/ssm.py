@@ -456,22 +456,30 @@ def ssd_scan(u, dt, b, c, a, d_skip, state, *, block_slot, block_rows, block_fre
 # output are sums over sublanes and the write a product with ``k`` down the
 # sublanes, the same in every lane (the turned tile of ``ssd_scan``, made once a
 # key head for the value heads it serves). The blocks' descriptors are the
-# ones above. Two forms give the same numbers:
+# ones above. Two forms give the same numbers, and the one kernel
+# (``pallas_call(name="gdn_scan")``, :func:`gdn_scan`) runs each where it is
+# the shorter, chosen by the block's shape:
 #
-# - **the row walk** (:func:`gdn_scan`: :func:`gdn_scan_reference`, and the
-#   kernel ``pallas_call(name="gdn_scan")``): a block's rows one after another,
-#   four passes over a head's state a row, on the vector unit; a decode step's
-#   one row a slot and a pack's blocks alike. It is what every program runs;
-# - **the chunked form** (:func:`gdn_chunked`, ``jax.numpy``; no program calls
-#   it: the tests' second derivation of the rule and the chip smoke's
-#   comparison, which the row walk won for a pack of 256 rows, PERF.md section
-#   6, PR 48; the form a training step would differentiate): ``C`` rows at a
-#   time. With ``G`` the running sum of ``g`` within the chunk, ``A[t, j] =
-#   beta_t exp(G_t - G_j) k_t . k_j`` below the diagonal, the rows' updates
-#   solve ``(I + A) U = beta (V - exp(G) K S_0)``, a unit lower triangular
-#   system; then ``O = exp(G) Q S_0 + tril(exp(G_t - G_j) Q K^T) U`` and ``S_C =
-#   exp(G_C) S_0 + (exp(G_C - G) K)^T U``: products for the matrix unit and one
-#   triangular solve a chunk a head, in float32 at the highest precision.
+# - **the row walk** (:func:`gdn_scan_reference`, and the kernel for a block of
+#   one row, a decode step's slot): four passes over a head's state a row, on
+#   the vector unit, at the pace of the state's bytes;
+# - **the chunked form** (the kernel for a block of more rows, a pack's token
+#   block; :func:`gdn_chunked` is its ``jax.numpy`` mirror, the tests' second
+#   derivation of the rule and the form a training step would differentiate):
+#   ``C`` rows at a time, the slot's state in VMEM throughout. With ``G`` the
+#   running sum of ``g`` within the chunk, ``A[t, j] = beta_t exp(G_t - G_j)
+#   k_t . k_j`` below the diagonal, the rows' updates solve ``(I + A) U = beta
+#   (V - exp(G) K S_0)``, a unit lower triangular system; then ``O = exp(G) Q
+#   S_0 + tril(exp(G_t - G_j) Q K^T) U`` and ``S_C = exp(G_C) S_0 + (exp(G_C -
+#   G) K)^T U``: products for the matrix unit, float32 at the highest
+#   precision, and forward substitution. The kernel takes a block's live rows
+#   as one chunk (the least of 8, 16, 32 rows or the block's that holds them)
+#   and solves the system 32 rows at a time. Every sum of ``g``
+#   that is exponentiated (``G_t - G_j`` for ``t >= j``, ``G_t``, ``G_C -
+#   G_t``) is taken as a sum of its non-positive terms, never as a difference
+#   of running sums: ``g`` of -50 a row is in the configuration's range, and a
+#   ratio between two near rows must not inherit the rounding of a sum of
+#   hundreds. Nothing overflows and nothing is divided by a decay.
 
 
 def gdn_scan_reference(q, k, v, decay, beta, state, *, block_slot, block_rows, block_fresh, layer=0):
@@ -543,18 +551,22 @@ def gdn_chunked(q, k, v, g, beta, state, *, block_slot, block_rows, block_fresh,
 
         def one(s, xs):
             qc, kc, vc, gc, bc = xs                                   # [Hv, c, dk | dv], [Hv, c]
-            run = jnp.cumsum(gc, axis=-1)                              # G
-            ratio = run[:, :, None] - run[:, None, :]                  # G_t - G_j
+            # sums of g between rows, each a sum of non-positive terms and none a difference of running sums: the
+            # ratio between near rows keeps its digits however far the state has decayed before them
+            g_upto = jnp.where(upto, gc[:, None, :], 0.0)                                      # [Hv, t, i]: g_i, i <= t
+            ratio = jnp.einsum("hti,ij->htj", g_upto, lower.astype(f32), precision=hi)         # G_t - G_j, t >= j
+            run = jnp.sum(g_upto, axis=-1)                                                     # G
+            left = jnp.sum(jnp.where(upto, 0.0, gc[:, None, :]), axis=-1)                      # G_C - G
             kk = jnp.einsum("htd,hjd->htj", kc, kc, precision=hi)
-            a = jnp.where(lower, bc[:, :, None] * jnp.exp(jnp.where(lower, ratio, 0.0)) * kk, 0.0)
+            a = jnp.where(lower, bc[:, :, None] * jnp.exp(ratio) * kk, 0.0)
             grown = jnp.exp(run)[:, :, None]
             rhs = bc[:, :, None] * (vc - grown * jnp.einsum("htd,hde->hte", kc, s, precision=hi))
             u = jax.scipy.linalg.solve_triangular(a + jnp.eye(c, dtype=f32), rhs, lower=True, unit_diagonal=True)
             qk = jnp.einsum("htd,hjd->htj", qc, kc, precision=hi)
-            qk = jnp.where(upto, jnp.exp(jnp.where(upto, ratio, 0.0)) * qk, 0.0)
+            qk = jnp.where(upto, jnp.exp(ratio) * qk, 0.0)
             o = grown * jnp.einsum("htd,hde->hte", qc, s, precision=hi) \
                 + jnp.einsum("htj,hje->hte", qk, u, precision=hi)
-            to_end = jnp.exp(run[:, -1:] - run)[:, :, None] * kc       # exp(G_C - G) K
+            to_end = jnp.exp(left)[:, :, None] * kc                    # exp(G_C - G) K
             s = jnp.exp(run[:, -1])[:, None, None] * s + jnp.einsum("htd,hte->hde", to_end, u, precision=hi)
             return s, o
 
@@ -569,19 +581,13 @@ def gdn_chunked(q, k, v, g, beta, state, *, block_slot, block_rows, block_fresh,
 
 
 _GDN_VMEM = 48 * 2 ** 20  # a slot's 2 MB state in and out, double-buffered, beside a 64-row block's rows
+# rows of a chunk's triangular system solved by substitution at a time, the blocks of rows above them through a
+# product (on the chip a 64-row chunk takes 7% longer at 16 and half as long again at 64: PERF.md section 6, PR 49)
+_GDN_SOLVE = 32
+_GDN_CHUNKS = (8, 16, 32)  # a block of fewer live rows than it holds is one chunk of the least of these that holds them
 
 
-def _gdn_scan_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
-                     q_ref, k_ref, v_ref, d_ref, b_ref, s_in_ref,
-                     o_ref, s_out_ref, kk, qq, *, group: int):
-    del layer_ref, slot_ref  # the block specs read them
-    f32 = jnp.float32
-    j = pl.program_id(0)
-    rows, hk, dk = q_ref.shape[1:]
-    hv, dv = v_ref.shape[2:]
-    per = hv // hk
-    n = rows_ref[j]
-
+def _gdn_block_start(j, fresh_ref, cont_ref, s_in_ref, s_out_ref):
     # as ssm_scan: the state stays in the output block while consecutive
     # blocks continue one slot
     @pl.when(cont_ref[j] == 0)
@@ -590,89 +596,154 @@ def _gdn_scan_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
 
     @pl.when(fresh_ref[j] == 1)
     def _():
-        s_out_ref[...] = jnp.zeros(s_out_ref.shape, f32)
+        s_out_ref[...] = jnp.zeros(s_out_ref.shape, jnp.float32)
 
-    @pl.when(n < rows)
+
+def _gdn_step_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
+                     q_ref, k_ref, v_ref, d_ref, b_ref, s_in_ref, o_ref, s_out_ref):
+    """A block of one row (a decode step's slot): the row walk."""
+    del layer_ref, slot_ref  # the block specs read them
+    j = pl.program_id(0)
+    hk, dk = q_ref.shape[2:]
+    hv, dv = v_ref.shape[2:]
+    per = hv // hk
+    _gdn_block_start(j, fresh_ref, cont_ref, s_in_ref, s_out_ref)
+
+    @pl.when(rows_ref[j] < 1)
     def _():
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    def walk(base):  # ``group`` rows from row ``base``, over every head
+    @pl.when(rows_ref[j] > 0)
+    def _():
         def of_key_head(g, carry):
-            # a row's key and query are vectors along the lanes; the state
+            # the row's key and query are vectors along the lanes; the state
             # wants them down the sublanes, the same in every lane: the row
             # spread over a tile and turned, once for the value heads it serves
-            for i in range(group):
-                for ref, tile in ((k_ref, kk), (q_ref, qq)):
-                    tile[i] = jnp.broadcast_to(ref[0, base + i, pl.ds(g, 1), :], (dv, dk)).T
+            kk, qq = (jnp.broadcast_to(ref[0, 0, pl.ds(g, 1), :], (dv, dk)).T for ref in (k_ref, q_ref))
             for p in range(per):
                 h = g * per + p
-                s = s_out_ref[0, 0, h]                                              # [dk, dv]
-                for i in range(group):
-                    live = base + i < n  # a padding row: decay 1 and beta 0, no advance
-                    d_i = jnp.where(live, d_ref[0, base + i, pl.ds(h, 1), :], 1.0)   # [1, dv]
-                    b_i = jnp.where(live, b_ref[0, base + i, pl.ds(h, 1), :], 0.0)
-                    s = d_i * s
-                    read = jnp.sum(s * kk[i], axis=0, keepdims=True)
-                    s = s + kk[i] * (b_i * (v_ref[0, base + i, pl.ds(h, 1), :] - read))
-                    o_ref[0, base + i, pl.ds(h, 1), :] = jnp.sum(s * qq[i], axis=0, keepdims=True).astype(o_ref.dtype)
+                s = d_ref[0, 0, pl.ds(h, 1), :] * s_out_ref[0, 0, h]                   # [dk, dv]
+                read = jnp.sum(s * kk, axis=0, keepdims=True)
+                s = s + kk * (b_ref[0, 0, pl.ds(h, 1), :] * (v_ref[0, 0, pl.ds(h, 1), :] - read))
+                o_ref[0, 0, pl.ds(h, 1), :] = jnp.sum(s * qq, axis=0, keepdims=True).astype(o_ref.dtype)
                 s_out_ref[0, 0, h] = s
             return carry
 
         jax.lax.fori_loop(0, hk, of_key_head, 0)
 
-    if rows == group:  # one group (a decode step's one row): no loop
-        pl.when(n > 0)(lambda: walk(0))
-    else:
-        def body(r, carry):
-            walk(r * group)
+
+def _gdn_chunk_kernel(layer_ref, slot_ref, rows_ref, fresh_ref, cont_ref,
+                      q_ref, k_ref, v_ref, gb_ref, s_in_ref, o_ref, s_out_ref, u_ref):
+    """A block of several rows of one slot (a pack's token block): the chunked
+    form, a value head at a time, the slot's state in VMEM throughout. The
+    rows are read as they are computed, ``[rows x heads, width]`` (a head's
+    rows are every ``heads``-th); ``g`` and ``beta`` come as two rows a value
+    head, ``[Hv, 2, rows]``."""
+    del layer_ref, slot_ref  # the block specs read them
+    f32 = jnp.float32
+    j = pl.program_id(0)
+    hv, _, rows = gb_ref.shape[1:]
+    dk, dv = s_in_ref.shape[3:]
+    hk = q_ref.shape[1] // rows
+    per = hv // hk
+    n = rows_ref[j]
+    _gdn_block_start(j, fresh_ref, cont_ref, s_in_ref, s_out_ref)
+
+    @pl.when(n < rows)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    dot = functools.partial(jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST, preferred_element_type=f32)
+    mm = lambda x, y: dot(x, y, (((1,), (0,)), ((), ())))
+    nt, tn = (((1,), (1,)), ((), ())), (((0,), (0,)), ((), ()))  # x y^T, x^T y
+
+    def chunk(c):  # the block's first ``c`` rows, which hold its live ones
+        t = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)       # a row of the chunk, down the sublanes
+        i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)       # and along the lanes
+        upto, below, live = i <= t, i < t, i[:1] < n
+        after = below.astype(f32)                                 # [i, j]: row i comes after row j
+        live_rows = t[:, :1] < n
+        rows_of = lambda ref, head, heads: ref[0, pl.ds(head, c, stride=heads), :]
+
+        def of_key_head(g, carry):
+            k, q = rows_of(k_ref, g, hk), rows_of(q_ref, g, hk)                       # [c, dk]
+            kq = jnp.concatenate([k, q], axis=0)
+            kk, qk = dot(k, k, nt), dot(q, k, nt)                                     # [c, c]: k_t . k_j, q_t . k_j
+            for p in range(per):
+                h = g * per + p
+                s = s_out_ref[0, 0, h]                                                # [dk, dv]
+                g_row = jnp.where(live, gb_ref[0, h, 0:1, :c], 0.0)                   # [1, c]; a padding row: g 0
+                b_row = jnp.where(live, gb_ref[0, h, 1:2, :c], 0.0)                   # and beta 0
+                beta = jnp.sum(jnp.where(i == t, b_row, 0.0), axis=1, keepdims=True)  # [c, 1]: the row, turned
+                # sums of g between rows, each of non-positive terms (the comment above)
+                g_upto = jnp.where(upto, g_row, 0.0)
+                decay = jnp.exp(mm(g_upto, after))                                    # exp(G_t - G_j), t >= j
+                grown = jnp.exp(jnp.sum(g_upto, axis=1, keepdims=True))               # exp(G_t)
+                to_end = jnp.exp(jnp.sum(jnp.where(i > t, g_row, 0.0), axis=1, keepdims=True))  # exp(G_C - G_t)
+                from_s = mm(kq, s)                                                    # [2c, dv]: K S_0, Q S_0
+                a = jnp.where(below, beta * decay * kk, 0.0)
+                rhs = beta * (rows_of(v_ref, h, hv) - grown * from_s[:c])
+                # (I + A) U = rhs, a block of rows at a time: the rows above
+                # through one product, the block's own by substitution
+                u_ref[:c] = jnp.zeros((c, dv), f32)
+                for lo in range(0, c, _GDN_SOLVE):
+                    hi = min(lo + _GDN_SOLVE, c)
+                    r = rhs[lo:hi] - mm(a[lo:hi], u_ref[:c]) if lo else rhs[lo:hi]
+                    own = a[lo:hi, lo:hi]
+                    for x in range(hi - lo - 1):
+                        r = r - own[:, x:x + 1] * r[x:x + 1]
+                    u_ref[lo:hi] = r
+                u = u_ref[:c]
+                o = grown * from_s[c:] + mm(jnp.where(upto, decay * qk, 0.0), u)
+                o_ref[0, pl.ds(h, c, stride=hv), :] = jnp.where(live_rows, o, 0.0).astype(o_ref.dtype)
+                # (the chunk's whole decay as a scalar reduction: Mosaic spreads no [1, 1] slice over a tile)
+                s_out_ref[0, 0, h] = jnp.exp(jnp.sum(g_row)) * s + dot(to_end * k, u, tn)
             return carry
 
-        jax.lax.fori_loop(0, (n + group - 1) // group, body, 0)
+        jax.lax.fori_loop(0, hk, of_key_head, 0)
+
+    sizes = [*(c for c in _GDN_CHUNKS if c < rows), rows]
+    for fewer, c in zip([0, *sizes], sizes):
+        pl.when((n > fewer) & (n <= c))(functools.partial(chunk, c))
 
 
-def _gdn_scan_call(q, k, v, decay, beta, state, block_slot, block_rows, block_fresh, layer, interpret: bool):
+def _gdn_scan_call(q, k, v, g, beta, state, block_slot, block_rows, block_fresh, layer, interpret: bool):
+    """The kernel, its form by the block's shape: a block of one row walks
+    it, a block of more takes its live rows as one chunk."""
     f32 = jnp.float32
     nb, rows, hk, dk = q.shape
     hv, dv = v.shape[2:]
-    group = 8 if rows % 8 == 0 else 1
-    if group == 1 and rows != 1:
-        raise ValueError(f"gdn_scan walks blocks of 1 row or of a multiple of 8, got {rows}")
+    if rows != 1 and rows % 8:
+        raise ValueError(f"gdn_scan takes blocks of 1 row or of a multiple of 8, got {rows}")
     if state.shape[2:] != (hv, dk, dv) or hv % hk:
         raise ValueError(f"the state {state.shape} is not [layers, slots, {hv}, {dk}, {dv}]")
     at, n_rows, fresh, cont = _block_descriptors(block_slot, block_rows, block_fresh)
     scalars = (jnp.asarray(layer, jnp.int32).reshape(1), at, n_rows, fresh, cont)
-    a_channel = lambda x: jnp.broadcast_to(x.astype(f32)[..., None], (nb, rows, hv, dv))  # a head's scalar on its lanes
-
-    def a_block(j, *_):
-        return (j, 0, 0, 0)
-
-    def of_slot(j, ly, sl, *_):
-        return (ly[0], sl[j], 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, rows, hk, dk), a_block),          # q
-        pl.BlockSpec((1, rows, hk, dk), a_block),          # k
-        pl.BlockSpec((1, rows, hv, dv), a_block),          # v
-        pl.BlockSpec((1, rows, hv, dv), a_block),          # the decay, a channel
-        pl.BlockSpec((1, rows, hv, dv), a_block),          # beta, a channel
-        pl.BlockSpec((1, 1, hv, dk, dv), of_slot),         # the layers' states, this block's slot
-    ]
-    out_specs = [pl.BlockSpec((1, rows, hv, dv), a_block), pl.BlockSpec((1, 1, hv, dk, dv), of_slot)]
+    q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    if rows == 1:
+        kernel, scratch = _gdn_step_kernel, []
+        a_channel = lambda x: jnp.broadcast_to(x[..., None], (nb, 1, hv, dv))  # a head's scalar on its lanes
+        operands = (q, k, v, a_channel(jnp.exp(g)), a_channel(beta))
+    else:
+        kernel, scratch = _gdn_chunk_kernel, [pltpu.VMEM((rows, dv), f32)]
+        by_row = lambda x: x.reshape(nb, -1, x.shape[-1])  # [nb, rows x heads, width]: no copy
+        operands = (by_row(q), by_row(k), by_row(v), jnp.stack([g, beta], axis=-1).transpose(0, 2, 3, 1))
+    a_block = lambda x: pl.BlockSpec((1, *x.shape[1:]), lambda j, *_: (j,) + (0,) * (x.ndim - 1))
+    # the layers' states, this block's slot
+    of_slot = pl.BlockSpec((1, 1, hv, dk, dv), lambda j, ly, sl, *_: (ly[0], sl[j], 0, 0, 0))
+    o_shape = jax.ShapeDtypeStruct(operands[2].shape, f32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars), grid=(nb,), in_specs=in_specs, out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((group, dk, dv), f32), pltpu.VMEM((group, dk, dv), f32)])
+        num_scalar_prefetch=len(scalars), grid=(nb,), in_specs=[*map(a_block, operands), of_slot],
+        out_specs=[a_block(o_shape), of_slot], scratch_shapes=scratch)
     params = {} if interpret else {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("arbitrary",), vmem_limit_bytes=_GDN_VMEM)}
-    operands = (q.astype(f32), k.astype(f32), v.astype(f32), a_channel(decay), a_channel(beta), state)
     o, state = pl.pallas_call(
-        functools.partial(_gdn_scan_kernel, group=group),
-        grid_spec=grid_spec, name="gdn_scan", interpret=interpret,
-        out_shape=[jax.ShapeDtypeStruct((nb, rows, hv, dv), f32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={len(scalars) + len(operands) - 1: 1},
+        kernel, grid_spec=grid_spec, name="gdn_scan", interpret=interpret,
+        out_shape=[o_shape, jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={len(scalars) + len(operands): 1},
         **params,
-    )(*scalars, *operands)
-    return o, state
+    )(*scalars, *operands, state)
+    return o.reshape(nb, rows, hv, dv), state
 
 
 def gdn_scan(q, k, v, g, beta, state, *, block_slot, block_rows, block_fresh, layer=0,
@@ -681,14 +752,13 @@ def gdn_scan(q, k, v, g, beta, state, *, block_slot, block_rows, block_fresh, la
     [blocks, rows, Hk, dk] normed and scaled, ``v`` [blocks, rows, Hv, dv],
     ``g`` and ``beta`` [blocks, rows, Hv], ``state`` [layers, slots, Hv, dk, dv]
     float32. Returns ``(o [blocks, rows, Hv, dv] float32, state)`` with this
-    ``layer``'s slots advanced: the row walk, by ``impl``
-    (:func:`resolve_ssm_kernel`; the kernel is ``pallas_call(name="gdn_scan")``)."""
+    ``layer``'s slots advanced, by ``impl`` (:func:`resolve_ssm_kernel`; the
+    kernel is ``pallas_call(name="gdn_scan")``, the row walk for blocks of one
+    row and the chunked form for blocks of more)."""
     mode = resolve_ssm_kernel(impl)
     if state.dtype != jnp.float32:
         raise ValueError(f"the recurrent state is float32, got {state.dtype}")
     blocks = dict(block_slot=block_slot, block_rows=block_rows, block_fresh=block_fresh, layer=layer)
-    decay = jnp.exp(g.astype(jnp.float32))
     if mode == "reference":
-        return gdn_scan_reference(q, k, v, decay, beta, state, **blocks)
-    return _gdn_scan_call(q, k, v, decay, beta, state, block_slot, block_rows, block_fresh, layer,
-                          mode == "interpret")
+        return gdn_scan_reference(q, k, v, jnp.exp(g.astype(jnp.float32)), beta, state, **blocks)
+    return _gdn_scan_call(q, k, v, g, beta, state, block_slot, block_rows, block_fresh, layer, mode == "interpret")

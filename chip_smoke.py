@@ -453,19 +453,22 @@ def ssd_phase(S: Sizes, seed: int, on_chip: bool) -> None:
 
 
 def gdn_phase(S: Sizes, seed: int, on_chip: bool) -> None:
-    """The delta rule ISSUE 48 brought, both forms against the ``jax.numpy``
-    row rule at the published shapes of the Qwen3-Next cell (32 value heads
-    over 16 key heads of 128 x 128, 2 MB of state a slot, 128 slots; tiny and
-    interpreted in the rehearsal): the ``gdn_scan`` kernel compiled by Mosaic
-    for a decode step with dead slots between and for a pack of four 64-row
-    token blocks, and the chunked form (``ops/ssm.gdn_chunked``, which no
-    program calls: XLA's products, 64 rows a chunk) for the same pack, on a
-    stack of two layers of which the second is advanced; what no block
-    advances comes back bit for bit. Each form is timed with the stack donated
-    and handed on from call to call, as both served programs hold it; that
-    comparison is why a pack walks its rows. And the gated experts' two kernels,
-    each at rows on both sides of the size at which the program changes from
-    one to the other, against a loop over the experts by hand."""
+    """The delta rule ISSUE 48 brought, against the ``jax.numpy`` row rule at
+    the published shapes of the Qwen3-Next cell (32 value heads over 16 key
+    heads of 128 x 128, 2 MB of state a slot, 128 slots; tiny and interpreted
+    in the rehearsal), on a stack of two layers of which the second is
+    advanced; what no block advances comes back bit for bit. The ``gdn_scan``
+    kernel compiled by Mosaic in both its forms: a decode step with dead slots
+    between (a block of one row: the row walk), and a pack of four 64-row token
+    blocks (the chunked form on the matrix unit, ISSUE 49) at 64, 32, 16 and 8
+    live rows a block. Beside each pack, where the parent commit is unpacked
+    under ``.parent_tree`` (as a builder compares two commits in one call), the
+    parent's kernel on the same rows, and the chunked form through XLA
+    (``ops/ssm.gdn_chunked``, which no program calls). Each is timed with the
+    stack donated and handed on from call to call, as both served programs
+    hold it. And the gated experts' two kernels, each at rows on both sides of
+    the size at which the program changes from one to the other, against a
+    loop over the experts by hand."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -492,11 +495,21 @@ def gdn_phase(S: Sizes, seed: int, on_chip: bool) -> None:
 
     hk, hv, dk, dv, slots, bt = (16, 32, 128, 128, 128, 64) if on_chip else (2, 4, 8, 8, 8, 8)
     mode = resolve_ssm_kernel(S.kernel_mode)
+    served = lambda *a, **kw: gdn_scan(*a, impl=mode, **kw)
+    forms = {f"gdn_scan, {mode}": served}
+    parent = os.path.join(os.path.dirname(os.path.abspath(__file__)), ".parent_tree", "accelerate_tpu", "ops", "ssm.py")
+    if on_chip and os.path.exists(parent):
+        with open(parent) as f:
+            source = f.read().replace("from .attention import", "from accelerate_tpu.ops.attention import")
+        parent_ssm = type(os)("parent_ssm")
+        exec(compile(source, parent, "exec"), parent_ssm.__dict__)
+        forms["the parent's gdn_scan"] = lambda *a, **kw: parent_ssm.gdn_scan(*a, impl=mode, **kw)
+    forms[f"gdn_chunked through XLA, {bt} rows a chunk"] = lambda *a, **kw: gdn_chunked(*a, chunk=bt, **kw)
     live = (np.arange(slots) % 5 != 3).astype(np.int32)
-    shapes = {
-        "decode step": (np.arange(slots), live, np.zeros(slots, np.int32), 1),
-        "packed prefill": (np.array([2, 2, 0, -1]), np.array([bt, bt - 3, bt // 2 + 1, 0]), np.array([1, 0, 0, 0]), bt),
-    }
+    shapes = {"decode step": (np.arange(slots), live, np.zeros(slots, np.int32), 1)}
+    for n in sorted({bt, bt // 2, bt // 4, max(bt // 8, 1)}, reverse=True):
+        shapes[f"packed prefill, {n} live rows a block"] = (
+            np.array([2, 2, 0, -1]), np.array([n, n - n // 8, n // 2 + 1, 0]), np.array([1, 0, 0, 0]), bt)
     unit = lambda x: x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
     for name, (slot, rows, fresh, block) in shapes.items():
         key = jax.random.split(jax.random.key(seed + block), 6)
@@ -508,11 +521,12 @@ def gdn_phase(S: Sizes, seed: int, on_chip: bool) -> None:
                 jax.random.normal(key[5], (2, slots, hv, dk, dv)))
         kw = dict(block_slot=jnp.asarray(slot, jnp.int32), block_rows=jnp.asarray(rows, jnp.int32),
                   block_fresh=jnp.asarray(fresh, jnp.int32), layer=1)
-        forms = {f"row walk, {mode}": lambda *a: gdn_scan(*a, impl=mode, **kw)}
-        if block > 1:
-            forms[f"chunked, {bt} rows a chunk"] = lambda *a: gdn_chunked(*a, chunk=bt, **kw)
         o_ref, state_ref = jax.block_until_ready(jax.jit(lambda *a: gdn_scan(*a, impl="reference", **kw))(*args))
-        for form, fn in forms.items():
+        say(f"  gdn_scan {name} ({nb} blocks x {block} rows, {hv} heads over {hk} of {dk} x {dv}, the stack donated):")
+        for form, rule in forms.items():
+            if block == 1 and rule is not served:  # (the comparisons are a pack's)
+                continue
+            fn = lambda *a: rule(*a, **kw)
             o, state = jax.block_until_ready(jax.jit(fn)(*args))
             err_o = max(float(jnp.max(jnp.abs(o[j, :r] - o_ref[j, :r]))) for j, r in enumerate(rows) if slot[j] >= 0 and r)
             err_s = float(jnp.max(jnp.abs(state - state_ref)))
@@ -523,8 +537,7 @@ def gdn_phase(S: Sizes, seed: int, on_chip: bool) -> None:
             assert bool(jnp.array_equal(state[0], args[-1][0]))
             assert bool(jnp.array_equal(state[1, kept], args[-1][1, kept]))
             timed(jax.jit(fn, donate_argnums=5), args,
-                  f"  gdn_scan {name} ({nb} blocks x {block} rows, {hv} heads over {hk} of {dk} x {dv}, {form}, "
-                  f"the stack donated): max|o-ref|={err_o:.2e} max|S-ref|={err_s:.2e}", carried=5)
+                  f"    {form}: max|o - row rule|={err_o:.2e} max|S - row rule|={err_s:.2e}", carried=5)
 
     # the gated experts' two kernels on either side of models/moe._ALL_ROWS_MAX (every expert over every row up to
     # 256 rows, each expert's own row tiles past it), both timed at every size so that the constant stands beside a

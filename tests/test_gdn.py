@@ -91,13 +91,16 @@ def _args_since(mark: int, name: str) -> list:
 # -- the rule's forms against each other -------------------------------------
 
 
+def _unit(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+
 def _scan_case(slot, rows, fresh, layer, bt, hk=2, hv=4, dk=8, dv=8, layers=3, slots=4, seed=0):
     """Blocks as the mixer hands them over: q and k normed, g negative, beta
     in (0, 1), a state that is not zero."""
     k = jax.random.split(jax.random.key(seed), 6)
     nb = len(slot)
-    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-    args = (unit(jax.random.normal(k[0], (nb, bt, hk, dk))) * dk ** -0.5, unit(jax.random.normal(k[1], (nb, bt, hk, dk))),
+    args = (_unit(jax.random.normal(k[0], (nb, bt, hk, dk))) * dk ** -0.5, _unit(jax.random.normal(k[1], (nb, bt, hk, dk))),
             jax.random.normal(k[2], (nb, bt, hv, dv)), -jax.nn.softplus(jax.random.normal(k[3], (nb, bt, hv)) - 1.0),
             jax.nn.sigmoid(jax.random.normal(k[4], (nb, bt, hv))), jax.random.normal(k[5], (layers, slots, hv, dk, dv)))
     kw = dict(block_slot=jnp.asarray(slot, jnp.int32), block_rows=jnp.asarray(rows, jnp.int32),
@@ -144,6 +147,53 @@ def test_the_kernel_interpreted_is_the_row_rule_in_place_in_the_layers_stack(cas
     o1, s1 = jax.jit(lambda *a: S.gdn_scan(*a, impl="interpret", **kw))(*args)
     _same_where_live(spec, o0, o1, s0, s1, 2e-5)
     _untouched_is_bit_for_bit(spec, args[-1], s1)
+
+
+def _near_repeated_keys_beta_one(args):
+    """The triangular system at its worst: every row writes all of what it
+    read (beta 1) and consecutive keys are all but the same, so ``k_t . k_j``
+    is near 1 all over the chunk."""
+    q, k, v, g, beta, st = args
+    return q, _unit(k[:, :1] + 0.05 * k), v, g, jnp.ones_like(beta), st
+
+
+def _a_run_of_fast_decay_then_slow(args):
+    """``g`` of -50 a row for rows 5-28 (the configuration allows it: ``a_log``
+    up to log 16), -0.01 after: the running sum stands near -1,200 where the
+    ratios between rows 29-63 are differences of hundredths."""
+    q, k, v, g, beta, st = args
+    row = jnp.arange(g.shape[1])[None, :, None]
+    return q, k, v, jnp.where((row >= 5) & (row < 29), -50.0, jnp.where(row >= 29, -0.01, g)), beta, st
+
+
+# what the chunked form can get wrong and a walk could not: 64-row blocks (a chunk of 8, 16, 32 or 64 rows by the
+# block's live rows, its system solved 32 rows at a time)
+CHUNK_CASES = {
+    "near_repeated_keys_at_beta_1": (dict(slot=[1, 1], rows=[64, 64], fresh=[0, 0], layer=0, bt=64), _near_repeated_keys_beta_one),
+    "a_run_of_g_at_minus_50_then_minus_0.01": (
+        dict(slot=[2, 2], rows=[64, 40], fresh=[0, 0], layer=1, bt=64), _a_run_of_fast_decay_then_slow),
+    **{f"{n}_live_rows_of_64": (dict(slot=[3, 0], rows=[n, n], fresh=[0, 1], layer=2, bt=64), None) for n in (1, 15, 16, 17, 63)},
+    "slots_over_three_blocks_with_a_fresh_one_between": (
+        dict(slot=[2, 2, 2, 0, 3, 3, 3, -1], rows=[64, 64, 21, 30, 64, 64, 5, 0], fresh=[0, 0, 0, 1, 0, 0, 0, 0], layer=1,
+             bt=64), None),
+}
+
+
+@pytest.mark.parametrize("form", ["interpret", "chunked"])
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_a_64_row_block_in_chunked_form_is_the_row_rule(case, form):
+    """The kernel's form for a block of more than one row (``interpret``) and
+    its ``jax.numpy`` mirror, at the tolerance the row walk was held to."""
+    spec, alter = CHUNK_CASES[case]
+    args, kw = _scan_case(**spec)
+    args = alter(args) if alter else args
+    rule = functools.partial(S.gdn_scan, impl="interpret") if form == "interpret" else functools.partial(S.gdn_chunked, chunk=64)
+    o0, s0 = S.gdn_scan(*args, impl="reference", **kw)
+    o1, s1 = jax.jit(lambda *a: rule(*a, **kw))(*args)
+    _same_where_live(spec, o0, o1, s0, s1, 2e-5)
+    _untouched_is_bit_for_bit(spec, args[-1], s1)
+    if form == "interpret":  # a padding row's o is zero, not what the state would have answered
+        assert not any(np.asarray(o1[j, n:]).any() for j, n in enumerate(spec["rows"]))
 
 
 @pytest.mark.parametrize("chunk", [16, 8, 4, 5, 3, 64], ids=lambda c: f"chunks_of_{c}")

@@ -351,6 +351,7 @@ CASES = {
     # qwen3-next cell's published shapes: 128 slots, runs of three DeltaNet layers and of one attention layer
     "gdn_scan_decode_step_128_slots": (_gdn_scan, dict(blocks=128, rows=1)),
     "gdn_scan_pack_256_rows": (_gdn_scan, dict(blocks=4, rows=64)),
+    "gdn_scan_pack_of_8_row_blocks": (_gdn_scan, dict(blocks=4, rows=8)),  # (the rehearsal's packs, at published heads)
     "moe_experts_fine_decode_rows": (_moe_experts, dict(rows=320, layers=3, held=64, d=2048, m=512)),
     "moe_experts_fine_prefill_rows": (_moe_experts, dict(rows=640, layers=3, held=64, d=2048, m=512)),
     "paged_decode_2_kv_heads_of_256_in_place": (
@@ -453,6 +454,7 @@ KERNEL_NAMES = {
     "eva_pool_decode_step_16_slots": {"eva_pool"},
     "gdn_scan_decode_step_128_slots": {"gdn_scan"},
     "gdn_scan_pack_256_rows": {"gdn_scan"},
+    "gdn_scan_pack_of_8_row_blocks": {"gdn_scan"},
     "moe_experts_fine_decode_rows": {"moe_experts"},
 }
 
@@ -500,24 +502,46 @@ def test_the_walks_blocks_and_the_softmaxs_form_at_every_cells_shape(cell):
                                       window_pages=window_pages) == prefill_block
 
 
+def _scoped_vmem(compiled_text: str) -> tuple:
+    """``(the limits the program's one kernel call states, the bytes of VMEM it uses)``."""
+    import json
+    import re
+
+    (call,) = re.findall(r"[^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", compiled_text)
+    sizes = {key: [int(c["size"]) for c in json.loads(found)]
+             for key, found in re.findall(r'"((?:used_)?scoped_memory_configs)":(\[[^\]]*\])', call)}
+    (used,) = sizes["used_scoped_memory_configs"]
+    return sizes["scoped_memory_configs"], used
+
+
 @pytest.mark.parametrize("case", ["paged_decode_32_kv_heads_closing_cell_in_place", "paged_decode_serving_cell_in_place",
                                   "paged_decode_bf16_d128_sq5"])
 def test_the_gathered_forms_vmem_request_is_under_its_limit(chip, case):
     """The gathered form adds a tile of scores and one of PV products to the page buffers (2.2 MB at 32 kv heads of
     five rows, the most): what the kernel asks of VMEM stays under the compiler's own limit of 16 MiB, and the call
     states none of its own."""
-    import json
-    import re
+    build, kw = CASES[case]
+    fn, args = build(chip, **kw)
+    stated, used = _scoped_vmem(jax.jit(fn).lower(*args).compile().as_text())
+    assert stated == []  # a call that stated a limit would carry it here
+    assert 8 * 2**20 < used < 16 * 2**20
+
+
+@pytest.mark.parametrize("case", ["gdn_scan_pack_256_rows", "gdn_scan_pack_of_8_row_blocks", "gdn_scan_decode_step_128_slots"])
+def test_the_delta_rules_forms_are_one_kernel_under_its_vmem_limit(chip, case):
+    """A pack's blocks run the chunked form (a chunk's products and its
+    triangular system beside the slot's state in VMEM) and a decode step's the
+    row walk: one ``pallas_call`` by the one name either way, and what it asks
+    of VMEM is under the limit the call states (``_GDN_VMEM``)."""
+    from accelerate_tpu.ops import ssm
 
     build, kw = CASES[case]
     fn, args = build(chip, **kw)
     text = jax.jit(fn).lower(*args).compile().as_text()
-    (call,) = re.findall(r"[^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", text)
-    sizes = {key: [int(c["size"]) for c in json.loads(found)]
-             for key, found in re.findall(r'"((?:used_)?scoped_memory_configs)":(\[[^\]]*\])', call)}
-    assert sizes["scoped_memory_configs"] == []  # a call that stated a limit would carry it here
-    (used,) = sizes["used_scoped_memory_configs"]
-    assert 8 * 2**20 < used < 16 * 2**20
+    assert _kernel_names(text) == {"gdn_scan"}
+    stated, used = _scoped_vmem(text)
+    assert stated == [ssm._GDN_VMEM] and 4 * 2**20 < used < ssm._GDN_VMEM
+    print(case, "scoped VMEM", used)
 
 
 def _small_model(by_kind: bool, experts: bool = False):
